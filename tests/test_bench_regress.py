@@ -1,9 +1,11 @@
 """Bench-trajectory loader + regression gate (ISSUE 15 tentpole b:
 ``theanompi_tpu/obs/regress.py`` + ``scripts/bench_diff.py``).
 
-The judged properties: every on-disk ``BENCH_*.json`` format
-round-trips through the loader (including the truncated r05 tail
-salvage), the REAL trajectory gates clean (r07→r08 included), a
+The judged properties: every ``BENCH_*.json`` format the trajectory
+ever accumulated round-trips through the loader (the older ones —
+driver wrapper, truncated-tail salvage — as small fixtures of the same
+shape, since the records that carried them are gone), the REAL
+trajectory gates clean (r07→r08 included), a
 synthetic trajectory with an injected 20% slowdown is FLAGGED while
 the same move inside the row's own noise band is not, and the CLI's
 ``--gate`` exit codes follow.  Pure host-side logic, fast tier."""
@@ -12,6 +14,8 @@ import json
 import subprocess
 import sys
 from pathlib import Path
+
+import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
@@ -34,13 +38,78 @@ def _row(value, unit="images/sec/chip", spread=None, error=None):
     return r
 
 
-class TestLoaderRoundTrip:
-    """Every capture currently in the repo parses — the legacy-format
-    tolerance half of the ISSUE's test satellite."""
+def _bench_row(metric, value, unit, **extra):
+    return {"metric": metric, "value": value, "unit": unit,
+            "vs_baseline": None, **extra}
 
-    def test_every_on_disk_capture_loads(self):
-        paths = sorted(ROOT.glob("BENCH_*.json"))
-        assert len(paths) >= 9          # BASELINE + r01..r08
+
+@pytest.fixture()
+def formats_dir(tmp_path):
+    """One small capture per on-disk format, written as the driver
+    and the container wrote them: the key/value baseline, the driver
+    wrapper with its ``parsed`` record (r01), the wrapper whose record
+    line out-grew the tail window — ``parsed`` null, head cut
+    mid-row (r05) — and the in-container ``rows`` capture (r08)."""
+    def write(name, obj):
+        (tmp_path / f"BENCH_{name}.json").write_text(json.dumps(obj))
+
+    write("BASELINE", {
+        "_comment": "working baselines",
+        "ResNet50_images_per_sec_per_chip": 2291.69,
+        "ResNet50_config": "ResNet-50 v1.5 b128 bf16 BSP",
+        "Llama_tokens_per_sec_per_chip": 49217.52,
+    })
+    headline = _bench_row(
+        "ResNet50 images/sec/chip (BSP, bf16, b128)", 2195.31,
+        "images/sec/chip", vs_baseline=0.9579,
+    )
+    write("r01", {
+        "n": 1, "cmd": "python bench.py", "rc": 0,
+        "tail": "WARNING: a log line\n" + json.dumps(headline) + "\n",
+        "parsed": headline,
+    })
+    later_rows = {
+        "llama": _bench_row(
+            "Llama-8L-1024d tokens/sec/chip (BSP, bf16, b4, T2048)",
+            77555.42, "tokens/sec/chip", spread=0.006,
+        ),
+        "alexnet": _bench_row(
+            "AlexNet images/sec/chip (BSP, bf16, b128)", 9259.8,
+            "images/sec/chip", spread=0.037,
+        ),
+        "loader": _bench_row(
+            "native loader images/sec", 2900.0, "images/sec",
+        ),
+    }
+    whole = json.dumps(later_rows)
+    write("r05", {
+        "n": 5, "cmd": "python bench.py", "rc": 0,
+        # the head — record start, headline, the wresnet row's
+        # opening — fell outside the driver's tail window
+        "tail": 's_baseline": 1.0381, "spread": 0.0081, "mfu": 0.6025}, '
+                + whole[1:] + "}\n",
+        "parsed": None,
+    })
+    write("r08", {
+        "n": 8, "platform": "cpu-container", "note": "self-capture",
+        "rows": {"serving": _bench_row(
+            "continuous-batching Llama serving tokens/sec", 1975.71,
+            "tokens/sec",
+        )},
+    })
+    return tmp_path
+
+
+class TestLoaderRoundTrip:
+    """Every capture in the repo, and one of every older format,
+    parses — the legacy-format tolerance half of the ISSUE's test
+    satellite."""
+
+    def test_every_on_disk_capture_loads(self, formats_dir):
+        paths = sorted(ROOT.glob("BENCH_*.json")) + sorted(
+            formats_dir.glob("BENCH_*.json")
+        )
+        assert len(paths) >= 9   # BASELINE, r04, r06.. + the fixtures
         for p in paths:
             cap = regress.load_capture(p)
             assert cap is not None, p.name
@@ -48,19 +117,28 @@ class TestLoaderRoundTrip:
             for row in cap["rows"].values():
                 assert "value" in row
 
-    def test_format_detection(self):
-        by_name = {c["name"]: c for c in regress.load_history(ROOT)}
+    def test_format_detection(self, formats_dir):
+        by_name = {
+            c["name"]: c for c in regress.load_history(formats_dir)
+        }
         assert by_name["BASELINE"]["format"] == "baseline-kv"
         assert by_name["r01"]["format"] == "wrapper"
         assert by_name["r05"]["format"] == "tail-salvage"
         assert by_name["r08"]["format"] == "rows"
+        # and what is still on disk: the driver's last surviving
+        # wrapper and the container captures
+        on_disk = {c["name"]: c for c in regress.load_history(ROOT)}
+        assert on_disk["BASELINE"]["format"] == "baseline-kv"
+        assert on_disk["r04"]["format"] == "wrapper"
+        assert on_disk["r08"]["format"] == "rows"
 
-    def test_r05_tail_salvage_recovers_rows(self):
-        """r05 predates BENCH_HEADLINE and its record line was cut at
-        the head — the later rows still parse whole from the tail."""
-        cap = regress.load_capture(ROOT / "BENCH_r05.json")
+    def test_r05_tail_salvage_recovers_rows(self, formats_dir):
+        """A capture that predates BENCH_HEADLINE and whose record
+        line was cut at the head — the later rows still parse whole
+        from the tail."""
+        cap = regress.load_capture(formats_dir / "BENCH_r05.json")
         assert {"llama", "alexnet", "loader"} <= set(cap["rows"])
-        assert cap["rows"]["llama"]["value"] > 0
+        assert cap["rows"]["llama"]["value"] == 77555.42
 
     def test_trajectory_order(self):
         names = [c["name"] for c in regress.load_history(ROOT)]

@@ -157,7 +157,7 @@ class TestKVTransfer:
             np.testing.assert_array_equal(a["k"], b["k"])
             np.testing.assert_array_equal(a["v"], b["v"])
 
-    def test_compatible_refuses_geometry_mismatch(self, models):
+    def test_geometry_mismatch_is_refused(self, models):
         m1, _ = models
         dec8 = paged_decoder(m1)
         dec16 = paged_decoder(m1, block_size=16)

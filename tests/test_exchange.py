@@ -707,15 +707,17 @@ class TestBucketedTraining:
         n_epochs=1, seed=3, lr=1e-3,
     )
 
-    def _llama_losses(self, strategy, bucket_mb, steps, devices):
+    def _llama_losses(self, strategy, bucket_mb, steps, devices, tp=1):
         from theanompi_tpu.models.llama import Llama
         from theanompi_tpu.utils import Recorder
 
-        cfg = dict(self.LLAMA_CFG, exch_strategy=strategy,
+        cfg = dict(self.LLAMA_CFG, exch_strategy=strategy, tp=tp,
                    exchange_bucket_mb=bucket_mb, n_train=16 * steps)
         m = Llama(cfg)
-        m.build_model(n_replicas=8)
-        m.compile_iter_fns(mesh=make_mesh(data=8, devices=devices))
+        m.build_model(n_replicas=8 // tp)
+        m.compile_iter_fns(
+            mesh=make_mesh(data=8 // tp, model=tp, devices=devices)
+        )
         if bucket_mb:
             # the toy model must actually bucket, or the test is void
             assert m._bucket_elems > 0
@@ -732,6 +734,22 @@ class TestBucketedTraining:
         buck = self._llama_losses(strategy, 0.01, 25, devices8)
         assert np.all(np.isfinite(mono))
         np.testing.assert_array_equal(buck, mono)
+
+    @pytest.mark.parametrize("strategy", ["zero1", "ici16"])
+    def test_llama_bucketed_under_tensor_parallel(
+        self, devices8, strategy
+    ):
+        """dp=4 x tp=2 under the vma-checked Llama step: the flat
+        buckets mix model-sharded weights with norm weights that are
+        replicated over ``model``, and every leaf must come back out
+        typed as it went in (``exchange._narrow_vma``) — with the
+        exchange itself unchanged.  ``ici16`` at the default bucket
+        size is what the Llama proxy of bench.py and chip_smoke.py
+        runs."""
+        mono = self._llama_losses(strategy, 0, 6, devices8, tp=2)
+        buck = self._llama_losses(strategy, 0.01, 6, devices8, tp=2)
+        assert np.all(np.isfinite(mono))
+        np.testing.assert_allclose(buck, mono, rtol=1e-5)
 
     @pytest.mark.slow
     @pytest.mark.parametrize("strategy", ["zero1", "asa32"])

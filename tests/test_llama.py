@@ -223,10 +223,7 @@ class TestPipelineHeadCost:
             m = build(devices8, data=1, pp=2, pp_microbatches=8,
                       pp_head_scatter=scatter, **over)
             ca = m.train_step_cost_analysis()
-            flops[scatter] = (
-                sum(float(d.get("flops", 0)) for d in ca)
-                if isinstance(ca, list) else float(ca.get("flops", 0))
-            )
+            flops[scatter] = float(ca.get("flops", 0))
         assert m._pp_scatter is False  # knob respected on last build
         # per-device head cost (fwd matmul): 2 * n_tok * D * V; bwd
         # roughly doubles-to-triples it.  Scatter halves it at S=2, so

@@ -1,8 +1,10 @@
 """Fused Pallas paged-attention kernel (serving/paged_attention.py)
 vs the jnp gather oracle — the interpreter-mode testing story: the
 gather path IS the reference, the kernel must match it EXACTLY for
-fp32 (same op sequence by construction), and the same kernel code
-deploys on TPU with ``interpret=False``.
+fp32 (same op sequence by construction).  The interpreter is asked
+for explicitly (``interpret=True`` / ``pallas_interpret=True``); that
+the same kernel compiles for the chip is tests/test_chip_compile.py's
+to show.
 
 Covers the cases the block-table layout makes dangerous: positions
 ON block boundaries, ragged per-row lengths, trash-padded tables
@@ -25,9 +27,12 @@ from test_serving_paged import PROMPTS, build_paged, serve_one
 pytestmark = pytest.mark.serving
 
 
+@jax.jit
 def gather_oracle(q, kp, vp, tables, pos):
     """The decoder's gather path, op for op
-    (``PagedLlamaDecoder._paged_attend``'s else-branch)."""
+    (``PagedLlamaDecoder._paged_attend``'s else-branch) — jitted, as
+    the decoder always runs it: op-by-op dispatch fuses differently
+    and is off by an ulp on this XLA."""
     s, nq, hkv, rep, hd = q.shape
     mb = tables.shape[1]
     bs = kp.shape[2]
@@ -159,6 +164,13 @@ class TestDecoderIntegration:
         with pytest.raises(ValueError, match="paged_attend_impl"):
             build_paged(devices8, paged_attend_impl="fused")
 
+    def test_mosaic_kernel_refused_off_tpu(self, devices8):
+        """No interpreter by autodetect: on a CPU mesh the compiled
+        kernel is refused at construction, never swapped for the
+        interpreter or the gather path."""
+        with pytest.raises(ValueError, match="pallas_interpret=True"):
+            build_paged(devices8, paged_attend_impl="pallas")
+
     @pytest.mark.parametrize("tp", [1, 2])
     def test_pallas_decoder_matches_gather_end_to_end(
         self, devices8, tp
@@ -168,7 +180,8 @@ class TestDecoderIntegration:
         decoder's tokens — greedy and temperature."""
         dec_g = build_paged(devices8, tp=tp, max_slots=2)
         dec_p = build_paged(
-            devices8, tp=tp, max_slots=2, paged_attend_impl="pallas"
+            devices8, tp=tp, max_slots=2, paged_attend_impl="pallas",
+            pallas_interpret=True,
         )
         for seed, temp in ((0, 0.0), (7, 0.9)):
             ref = serve_one(
@@ -182,7 +195,9 @@ class TestDecoderIntegration:
             assert got == ref
 
     def test_pallas_batched_equals_single(self, devices8):
-        dec = build_paged(devices8, paged_attend_impl="pallas")
+        dec = build_paged(
+            devices8, paged_attend_impl="pallas", pallas_interpret=True
+        )
         ref = [serve_one(dec, PROMPTS[i], seed=i) for i in range(4)]
         eng = Engine(dec, prefix_caching=False)
         futs = [
@@ -197,12 +212,16 @@ class TestDecoderIntegration:
         inlined (interpreter-mode) ops under the ``paged_attend``
         named scope — the before/after ``paged_attend_frac`` datum
         depends on it."""
-        dec = build_paged(devices8, paged_attend_impl="pallas")
+        dec = build_paged(
+            devices8, paged_attend_impl="pallas", pallas_interpret=True
+        )
         ops = dec.decode_scope_op_names(("paged_attend",))
         assert ops, "pallas decode HLO lost the paged_attend scope"
 
     def test_compile_counters_stable(self, devices8):
-        dec = build_paged(devices8, paged_attend_impl="pallas")
+        dec = build_paged(
+            devices8, paged_attend_impl="pallas", pallas_interpret=True
+        )
         for i in range(3):
             serve_one(dec, PROMPTS[i], seed=i)
         assert dec.n_decode_compiles <= 2

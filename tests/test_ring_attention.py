@@ -135,6 +135,41 @@ class TestFlashKernel:
             )
 
 
+class TestFlashDispatch:
+    """``flash_attention`` picks its path from a device, and a dense
+    choice is logged once per shape (ISSUE 22 item 3)."""
+
+    def test_dense_choice_is_logged_once_per_shape(self, rng, caplog):
+        import logging
+
+        from theanompi_tpu.ops import attention
+
+        q, k, v = qkv(rng, t=40)
+        attention._log_dense_choice.cache_clear()
+        before = attention.dense_choices()
+        with caplog.at_level(logging.INFO, logger=attention.__name__):
+            for _ in range(2):
+                out = attention.flash_attention(q, k, v)
+        np.testing.assert_array_equal(
+            np.asarray(out), np.asarray(mha_reference(q, k, v))
+        )
+        lines = [r.getMessage() for r in caplog.records]
+        assert len(lines) == 1, lines
+        assert "T_q=40" in lines[0] and "not on TPU devices" in lines[0]
+        assert attention.dense_choices() - before == 2
+
+    def test_setting_alone_never_reports_a_tpu(self, monkeypatch):
+        """``TM_TPU_PLATFORM=tpu`` where JAX has no TPU: the platform
+        is read off a device, so the claim fails instead of steering
+        the kernels onto CPU devices."""
+        from theanompi_tpu.ops import attention
+
+        assert attention._on_tpu() is False
+        monkeypatch.setenv("TM_TPU_PLATFORM", "tpu")
+        with pytest.raises(RuntimeError):
+            attention._on_tpu()
+
+
 class TestRingFlash:
     """Flash-backed ring attention (per-hop Pallas kernels + logsumexp
     merge, ring-accumulated dK/dV backward) vs the dense ring path.

@@ -449,11 +449,8 @@ def test_bsp_efficiency_measured_anchor(devices8):
     BSP runs at worlds of 1/2/4 on this host (ROADMAP 3c /
     VERDICT #6).
 
-    This image's 0.4.x-shimmed jax refuses multi-PROCESS XLA
-    computations on the CPU backend ("Multiprocess computations
-    aren't implemented" — the same refusal that fails
-    ``test_distributed``'s slow two-process drill here), so the
-    measured worlds are the repo's standard stand-in: the virtual
+    The measured worlds are the repo's standard stand-in for
+    multi-PROCESS runs on the CPU backend: the virtual
     CPU mesh at 1/2/4 devices, which dispatches the IDENTICAL XLA
     collectives (``TestRealCollectives`` proves they are trace-
     attributable on this mesh).  On hardware the same protocol runs

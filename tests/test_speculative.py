@@ -120,7 +120,9 @@ class TestBitwiseEquivalence:
 
     def test_composes_with_pallas_kernel(self, devices8):
         dec_g = build_paged(devices8)
-        dec_p = build_paged(devices8, paged_attend_impl="pallas")
+        dec_p = build_paged(
+            devices8, paged_attend_impl="pallas", pallas_interpret=True
+        )
         ref, _, _ = serve(dec_g, PROMPTS[:4])
         got, _, eng = serve(dec_p, PROMPTS[:4], speculate_k=4)
         assert got == ref

@@ -1,6 +1,9 @@
 """Recorder + checkpoint unit tests (reference: lib/recorder.py,
 helper_funcs weight save/load)."""
 
+import os
+import subprocess
+import sys
 import time
 
 import jax.numpy as jnp
@@ -118,3 +121,39 @@ class TestResampleLabels:
         np.testing.assert_array_equal(
             resample_labels(y, 0.0, 10, seed=0, salt=3), y
         )
+
+
+class TestCompileCacheRule:
+    """``utils.enable_compile_cache``: whoever runs the program places
+    the cache (``JAX_COMPILATION_CACHE_DIR``); otherwise it is the one
+    fixed path under the checkout.  Each case is a fresh interpreter:
+    JAX reads the variable when it is imported."""
+
+    ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def _dirs(self, env_value):
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        if env_value is not None:
+            env["JAX_COMPILATION_CACHE_DIR"] = env_value
+        out = subprocess.run(
+            [sys.executable, "-c",
+             "import jax\n"
+             "from theanompi_tpu.utils import enable_compile_cache\n"
+             "print(enable_compile_cache())\n"
+             "print(jax.config.jax_compilation_cache_dir)\n"],
+            cwd=self.ROOT, env=env, capture_output=True, text=True,
+            timeout=120, check=True,
+        ).stdout.split()
+        return out
+
+    def test_variable_set_is_left_alone(self, tmp_path):
+        where = str(tmp_path / "placed_from_outside")
+        assert self._dirs(where) == [where, where]
+
+    def test_unset_is_the_fixed_checkout_path(self):
+        # never a temporary, pid- or time-derived path: the path is
+        # part of the cache key, and two runs must agree on it
+        fixed = os.path.join(self.ROOT, ".jax_cache")
+        assert self._dirs(None) == [fixed, fixed]
+        assert self._dirs(None) == [fixed, fixed]

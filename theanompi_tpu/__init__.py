@@ -23,10 +23,6 @@ is a `lax.pmean` inside the jitted train step, which XLA overlaps with
 backprop automatically.
 """
 
-from theanompi_tpu import compat as _compat
-
-_compat.install()  # older-jaxlib shims; no-op on current jax
-
 from theanompi_tpu.version import __version__
 from theanompi_tpu.rules import BSP, EASGD, GOSGD
 

@@ -184,6 +184,9 @@ def worker_main(run_fn) -> Any:
     ap.add_argument("--spec-json", required=True)
     ns = ap.parse_args()
     spec = json.loads(ns.spec_json)
+    from theanompi_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
     return run_fn(
         devices=spec.get("devices"),
         modelfile=spec["modelfile"],
@@ -306,6 +309,9 @@ def main(argv: Sequence[str] | None = None) -> int:
             "whole job"
         )
 
+    from theanompi_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
     init_distributed(ns.coordinator, ns.num_hosts, ns.host_id)
 
     import theanompi_tpu as tm

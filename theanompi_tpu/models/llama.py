@@ -991,7 +991,7 @@ class Llama(TMModel):
             logits = self._forward(params, x)
             return self._metrics(logits, y, top5=True)
 
-        # TPU compiler knobs (remote-compile safe; utils/xla_options).
+        # TPU compiler knobs (utils/xla_options).
         # A bucketed exchange also feeds the overlap preset (async
         # collectives + latency-hiding scheduler) — TPU meshes only
         # (the CPU client rejects unknown xla_tpu_* options) and only

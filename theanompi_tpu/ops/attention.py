@@ -15,7 +15,8 @@ for the long-context configs).  Design:
   ring results match single-device attention bit-for-bit in fp32.
 - ``flash_attention`` — fused Pallas kernel (grid over heads × query
   blocks, KV streamed through VMEM, f32 accumulators in scratch) with
-  the same signature; falls back to ``mha_reference`` off-TPU.
+  the same signature; ``mha_reference`` off-TPU and at lengths no
+  kernel block tiles (each such choice is logged once per shape).
 
 Shapes follow [B, H, T, D] (head-major, the TPU-friendly layout: the
 ``[Tq, D] x [D, Tk]`` score matmul and ``[Tq, Tk] x [Tk, D]`` value
@@ -25,9 +26,14 @@ matmul both hit the MXU per (batch, head) grid cell).
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+logger = logging.getLogger(__name__)
 
 NEG_INF = -1e30  # finite "-inf": keeps exp() NaN-free in masked blocks
 
@@ -208,32 +214,13 @@ def _flash_kernel(
         lse_ref[0] = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-30))
 
 
-try:  # pallas imports fail gracefully on backends without Mosaic
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+def _on_tpu() -> bool:
+    """True when the devices the framework builds its meshes from
+    (``parallel.mesh.default_devices``) are TPUs — read off a device,
+    never off a setting alone."""
+    from theanompi_tpu.parallel.mesh import default_devices
 
-    _HAVE_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAVE_PALLAS = False
-
-
-def _on_tpu(x=None) -> bool:
-    """True when the framework is executing on the TPU backend.
-
-    ``TM_TPU_PLATFORM`` (the framework's device-discovery override —
-    the test suite sets it to ``cpu`` to use the virtual host mesh even
-    though a TPU backend is registered) takes precedence over JAX's
-    default backend, which would otherwise claim 'tpu' for CPU meshes.
-    """
-    import os
-
-    plat = os.environ.get("TM_TPU_PLATFORM")
-    if plat:
-        return plat == "tpu"
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return default_devices()[0].platform == "tpu"
 
 
 def _flash_bwd_dkv_kernel(
@@ -517,11 +504,6 @@ def flash_attention_tpu(
     divisible by the block sizes — ``flash_attention`` dispatches away
     otherwise.  ``interpret=True`` runs the kernels in the Pallas
     interpreter (any backend; how the tests exercise them)."""
-    if not _HAVE_PALLAS:  # pragma: no cover
-        raise RuntimeError(
-            "Pallas is unavailable in this JAX install; use "
-            "flash_attention() which falls back to reference math"
-        )
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     # default blocks: largest that tile this T.  A length no aligned
@@ -594,15 +576,39 @@ def _auto_block(t: int, dtype=None) -> int | None:
     return None
 
 
+@functools.lru_cache(maxsize=None)
+def _log_dense_choice(t: int, t_k: int, dtype: str, on_tpu: bool) -> None:
+    """One log line per shape — a warning on TPU, where the dense path
+    is a performance cliff.  ``lru_cache`` is the once-only latch, and
+    counts the calls (``dense_choices``)."""
+    logger.log(
+        logging.WARNING if on_tpu else logging.INFO,
+        "flash_attention: dense mha_reference for T_q=%d T_k=%d %s — %s",
+        t, t_k, dtype,
+        "no aligned kernel block tiles this length" if on_tpu
+        else "not on TPU devices",
+    )
+
+
+def dense_choices() -> int:
+    """How many times ``flash_attention`` has chosen the dense path
+    in this process (once per trace, not per step)."""
+    info = _log_dense_choice.cache_info()
+    return info.hits + info.misses
+
+
 def flash_attention(q, k, v, *, causal=True, sm_scale=None):
     """Dispatch: Pallas kernels on TPU (shapes permitting), reference
     math elsewhere.  Differentiable on both paths — the TPU kernel
-    carries a custom_vjp with Pallas backward kernels."""
+    carries a custom_vjp with Pallas backward kernels.  A dense choice
+    is never silent: ``_log_dense_choice`` names the shape and why."""
     t, t_k = q.shape[2], k.shape[2]
     bq, bk = _auto_block(t, q.dtype), _auto_block(t_k, k.dtype)
-    if _HAVE_PALLAS and _on_tpu(q) and bq and bk:
+    on_tpu = _on_tpu()
+    if on_tpu and bq and bk:
         return flash_attention_tpu(
             q, k, v, causal=causal, sm_scale=sm_scale,
             block_q=bq, block_k=bk,
         )
+    _log_dense_choice(t, t_k, str(q.dtype), on_tpu)
     return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale)

@@ -27,6 +27,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+# The all-gather whose result is typed INVARIANT over the gathered
+# axes.  Under a vma-checked shard_map (the Llama step) a plain
+# ``lax.all_gather`` types its result as varying, so values every
+# shard holds identically — exchanged grads, gathered params — could
+# not leave through a replicated out_spec.  jax 0.9.0 does not export
+# it from ``jax.lax``.
+from jax._src.lax.parallel import all_gather_invariant as _all_gather
+
 PyTree = Any
 
 
@@ -96,11 +104,11 @@ def allreduce_mean(
                         part = lax.psum_scatter(
                             w, axes, scatter_dimension=0, tiled=True
                         )
-                        w = lax.all_gather(part, axes, axis=0, tiled=True)
+                        w = _all_gather(part, axes, axis=0, tiled=True)
                     else:
                         w = lax.psum(w, axes)
                     parts.append((w / n).astype(spec.dtype))
-            return flat_unpack(jnp.concatenate(parts), spec)
+            return flat_unpack(jnp.concatenate(parts), spec, tree)
 
     def one(x):
         orig = x.dtype
@@ -112,7 +120,7 @@ def allreduce_mean(
                 part = lax.psum_scatter(
                     w, axes, scatter_dimension=0, tiled=True
                 )
-                w = lax.all_gather(part, axes, axis=0, tiled=True)
+                w = _all_gather(part, axes, axis=0, tiled=True)
             else:
                 w = lax.psum(w, axes)
             return (w / n).astype(orig)
@@ -300,14 +308,35 @@ def flat_pack_bucket(tree: PyTree, spec: FlatSpec, i: int) -> jnp.ndarray:
     return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
 
 
-def flat_unpack(buf: jnp.ndarray, spec: FlatSpec) -> PyTree:
-    """Inverse of ``flat_pack`` (pad dropped, leaf dtypes restored)."""
+def _narrow_vma(x: jnp.ndarray, like) -> jnp.ndarray:
+    """Re-type ``x`` to ``like``'s varying axes under a vma-checked
+    shard_map.  A leaf that is replicated over some mesh axis (a norm
+    weight over ``model``) comes out of the flat buffer typed as
+    varying over every axis ANY packed leaf varies over, and could
+    not leave the step through its replicated out_spec.  ``pmax`` over
+    the surplus axes is the identity on values that are equal across
+    them (exact at any axis size, unlike a mean) and types the result
+    invariant; over a size-1 axis XLA drops it.  An unchecked
+    shard_map carries no vma, so nothing is inserted there."""
+    extra = jax.typeof(x).vma - jax.typeof(like).vma
+    return lax.pmax(x, tuple(sorted(extra))) if extra else x
+
+
+def flat_unpack(buf: jnp.ndarray, spec: FlatSpec,
+                like: PyTree | None = None) -> PyTree:
+    """Inverse of ``flat_pack`` (pad dropped, leaf dtypes restored).
+    ``like`` — the tree that was packed — gives every leaf its own
+    vma type back (``_narrow_vma``)."""
     out, off = [], 0
     for shape, dt in zip(spec.shapes, spec.dtypes):
         n = math.prod(shape)
         out.append(lax.slice_in_dim(buf, off, off + n).reshape(shape)
                    .astype(dt))
         off += n
+    if like is not None:
+        out = [
+            _narrow_vma(x, l) for x, l in zip(out, jax.tree.leaves(like))
+        ]
     return jax.tree_util.tree_unflatten(spec.treedef, out)
 
 
@@ -397,12 +426,11 @@ def _compressed_all_gather(
     EF residual."""
     wire, scales = quantize_chunks(shard[None, :], compression)
     decoded = dequantize_chunks(wire, scales)[0]
-    # gathered params/grads are identical on every shard — re-enter
-    # the step invariant where the vma-checked API exists (the same
-    # rule scatter_update_gather uses for its master-dtype gather)
-    gather = getattr(lax, "all_gather_invariant", lax.all_gather)
-    wg = gather(wire[0], axes, axis=0, tiled=True)
-    sg = gather(scales, axes, axis=0, tiled=True)
+    # gathered params/grads are identical on every shard — they
+    # re-enter the step invariant (the same rule
+    # scatter_update_gather uses for its master-dtype gather)
+    wg = _all_gather(wire[0], axes, axis=0, tiled=True)
+    sg = _all_gather(scales, axes, axis=0, tiled=True)
     full = dequantize_chunks(wg.reshape(n, -1), sg).reshape(-1)
     return full, decoded
 
@@ -467,7 +495,7 @@ def compressed_allreduce_mean(
             parts.append(full.astype(spec.dtype))
     buf = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
     return (
-        flat_unpack(buf, spec),
+        flat_unpack(buf, spec, tree),
         jnp.concatenate(r1_parts) if len(r1_parts) > 1 else (
             r1_parts[0] if r1_parts else None),
         jnp.concatenate(r2_parts) if len(r2_parts) > 1 else (
@@ -489,8 +517,8 @@ def _pvary(x, axes: tuple):
     """Idempotent invariant→varying cast over ``axes``: under a
     vma-checked shard_map the param pack enters dp-INVARIANT and the
     varying-index slice below would be rejected; outside checked mode
-    (and on shimmed 0.4.x jax) this is an identity."""
-    vma = getattr(jax.typeof(x), "vma", frozenset())
+    this is an identity."""
+    vma = jax.typeof(x).vma
     need = tuple(a for a in axes if a not in vma)
     return lax.pcast(x, need, to="varying") if need else x
 
@@ -594,10 +622,6 @@ def scatter_update_gather(
     if spec is None:
         spec = flat_spec(params, n, bucket_elems=bucket_elems)
     assert spec.n_shards == n, (spec.n_shards, n)
-    # all_gather_invariant (vma-checked jax): the gathered params are
-    # identical on every shard and must re-enter the step dp-INVARIANT
-    # to match the params' out_spec; plain all_gather on older jax
-    gather = getattr(lax, "all_gather_invariant", lax.all_gather)
 
     r1_new = None
     if spec.n_buckets == 1:
@@ -635,12 +659,12 @@ def scatter_update_gather(
             else:
                 new_p_shard, aux = opt_update(p_shard, g_shard, opt_state)
         with jax.named_scope("exchange_b0"):
-            p_new = gather(
+            p_new = _all_gather(
                 new_p_shard.astype(spec.dtype), axes, axis=0, tiled=True
             )
         if compression is not None:
-            return flat_unpack(p_new, spec), aux, r1_new
-        return flat_unpack(p_new, spec), aux
+            return flat_unpack(p_new, spec, params), aux, r1_new
+        return flat_unpack(p_new, spec, params), aux
 
     # -- bucketed pipeline ------------------------------------------------
     nb, bs = spec.n_buckets, spec.bucket_shard_len
@@ -720,11 +744,11 @@ def scatter_update_gather(
     for i, np_i in enumerate(new_p_buckets):
         with jax.named_scope(f"exchange_b{i}"):
             parts.append(
-                gather(np_i.astype(spec.dtype), axes, axis=0, tiled=True)
+                _all_gather(np_i.astype(spec.dtype), axes, axis=0, tiled=True)
             )
     if compression is not None:
-        return flat_unpack(jnp.concatenate(parts), spec), aux, r1_new
-    return flat_unpack(jnp.concatenate(parts), spec), aux
+        return flat_unpack(jnp.concatenate(parts), spec, params), aux, r1_new
+    return flat_unpack(jnp.concatenate(parts), spec, params), aux
 
 
 # ---------------------------------------------------------------------------

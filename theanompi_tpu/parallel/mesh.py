@@ -38,12 +38,12 @@ EXPERT_AXIS = "expert"
 
 
 def default_devices() -> list[jax.Device]:
-    """Devices the framework builds meshes from.
-
-    ``TM_TPU_PLATFORM`` overrides the platform (the test suite sets it
-    to ``cpu`` to use the virtual 8-device host mesh even when a TPU
-    backend is registered).
-    """
+    """Devices the framework builds meshes from: JAX's default
+    backend's, or — with ``TM_TPU_PLATFORM`` set — that backend's
+    (``cpu`` for a dry run on the virtual host mesh in a process whose
+    default backend is the chip; ``__graft_entry__`` does this).  The
+    answer is always a list of real devices: a platform JAX does not
+    have raises."""
     plat = os.environ.get("TM_TPU_PLATFORM")
     return jax.devices(plat) if plat else jax.devices()
 

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from theanompi_tpu.ops.attention import (
-    _HAVE_PALLAS,
     _auto_block,
     _flash_bwd_call,
     _flash_fwd_call,
@@ -198,7 +197,7 @@ def ring_attention(
     if impl is None:
         impl = (
             "flash"
-            if (_HAVE_PALLAS and _on_tpu(q) and _auto_block(t_loc, q.dtype))
+            if (_on_tpu() and _auto_block(t_loc, q.dtype))
             else "dense"
         )
     if impl == "flash":

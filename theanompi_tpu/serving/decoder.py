@@ -48,8 +48,7 @@ max-reduction trick and full-vocab Gumbel draws, which makes sampled
 ids bitwise layout-invariant across tp=1 vs tp>1 meshes.
 
 Everything runs in unchecked manual mode (``check_vma=False``) with
-explicit collectives only — the forward-only serving path works
-identically on the 0.4.x-shimmed jax (``compat.py``) and current jax.
+explicit collectives only.
 """
 
 from __future__ import annotations
@@ -69,7 +68,12 @@ from theanompi_tpu.models.llama import (
     rope_at,
 )
 from theanompi_tpu.ops.attention import NEG_INF, flash_attention
-from theanompi_tpu.parallel import MODEL_AXIS, dp_replicas, make_mesh
+from theanompi_tpu.parallel import (
+    MODEL_AXIS,
+    default_devices,
+    dp_replicas,
+    make_mesh,
+)
 from theanompi_tpu.parallel import tp as tp_lib
 from theanompi_tpu.serving.blocks import BlockManager
 from theanompi_tpu.serving.prefix_cache import PrefixCache
@@ -546,7 +550,7 @@ class PagedLlamaDecoder(LlamaDecoder):
         prefill_chunk: int | None = None,
         prefix_cache: bool = True,
         paged_attend_impl: str = "gather",
-        pallas_interpret: bool | None = None,
+        pallas_interpret: bool = False,
     ):
         from theanompi_tpu.serving.paged_attention import IMPLS
 
@@ -558,15 +562,21 @@ class PagedLlamaDecoder(LlamaDecoder):
             )
         # "gather" = the jnp block-table gather (the reference
         # oracle); "pallas" = the fused kernel
-        # (serving/paged_attention.py).  The kernel runs through the
-        # Pallas interpreter off-TPU (this CPU image) and compiles
-        # through Mosaic on a real TPU — pallas_interpret overrides
-        # the backend autodetect for tests
+        # (serving/paged_attention.py), compiled through Mosaic.
+        # pallas_interpret=True runs it in the Pallas interpreter
+        # instead (the CPU tests); it is never chosen for the caller
         self.paged_attend_impl = paged_attend_impl
-        self._pallas_interpret = (
-            bool(pallas_interpret) if pallas_interpret is not None
-            else jax.default_backend() != "tpu"
-        )
+        self._pallas_interpret = bool(pallas_interpret)
+        platform = self.mesh.devices.flat[0].platform
+        if (paged_attend_impl == "pallas" and not self._pallas_interpret
+                and platform != "tpu"):
+            raise ValueError(
+                f"paged_attend_impl='pallas' compiles through Mosaic "
+                f"and this decoder's mesh is on {platform!r} devices "
+                f"— pass pallas_interpret=True to run the kernel in "
+                f"the Pallas interpreter, or use "
+                f"paged_attend_impl='gather'"
+            )
         self.block_size = int(block_size)
         self.manager = BlockManager(
             n_blocks=None if n_blocks is None else int(n_blocks),
@@ -1210,13 +1220,24 @@ def decoder_from_checkpoint(
     and the validated/quarantine fallback path — and wrap it in a
     decoder (``paged=True`` → :class:`PagedLlamaDecoder`).  The
     checkpoint may come from any training layout; npz and sharded
-    formats both reload across layouts."""
+    formats both reload across layouts.
+
+    ``devices`` (or ``mesh``) says where the replica lives.  It may
+    be left out only when the process sees exactly the ``tp`` devices
+    the replica needs: "the first ``tp`` devices" as a default would
+    stack every replica of a fleet on chip 0."""
     model = Llama(config)
     if mesh is None:
-        mesh = make_mesh(
-            data=1, model=model.tp,
-            devices=devices,
-        )
+        if devices is None:
+            devices = default_devices()
+            if len(devices) > model.tp:
+                raise ValueError(
+                    f"decoder_from_checkpoint: {len(devices)} devices "
+                    f"are visible and tp={model.tp} — pass devices= "
+                    f"(or mesh=) to say which of them this replica "
+                    f"uses"
+                )
+        mesh = make_mesh(data=1, model=model.tp, devices=devices)
     model.build_model(n_replicas=dp_replicas(mesh))
     model.compile_iter_fns(mesh=mesh)
     if not model.load(directory):

@@ -690,6 +690,10 @@ def serve_replica_main(argv=None) -> None:
     Spec keys: ``config`` (model dict incl. ``tp``), ``checkpoint``
     (dir), ``paged`` (bool), ``decoder`` (decoder kwargs), ``engine``
     (Engine kwargs), ``name``/``index``, ``host``/``port``,
+    ``devices`` (indices into this process's visible devices for the
+    replica's mesh — required whenever the process sees more than the
+    ``tp`` devices it needs: replicas of one fleet must not default
+    onto the same chip),
     ``role`` (``unified``/``prefill``/``decode`` — serving v4),
     ``trace_sample`` (int, 0 = off — span tracing with this replica's
     name as the Perfetto process lane and its role as the thread
@@ -703,11 +707,18 @@ def serve_replica_main(argv=None) -> None:
     args = ap.parse_args(argv)
     spec = json.loads(args.spec_json)
 
+    from theanompi_tpu.parallel import default_devices
     from theanompi_tpu.serving.decoder import decoder_from_checkpoint
+    from theanompi_tpu.utils import enable_compile_cache
     from theanompi_tpu.utils.recorder import ServingRecorder
 
+    enable_compile_cache()
+    devices = spec.get("devices")
+    if devices is not None:
+        visible = default_devices()
+        devices = [visible[int(i)] for i in devices]
     dec = decoder_from_checkpoint(
-        dict(spec["config"]), spec["checkpoint"],
+        dict(spec["config"]), spec["checkpoint"], devices=devices,
         paged=bool(spec.get("paged", False)),
         **dict(spec.get("decoder", {})),
     )

@@ -1,45 +1,31 @@
-"""Persistent XLA compile-cache switch, shared by every entry point.
+"""Persistent XLA compile cache, switched on by every entry point.
 
-One helper so the gate (``__graft_entry__``), the bench, and the test
-suite agree on the cache location and thresholds: repeat runs
-deserialize executables instead of recompiling (the flagship train
-step is a multi-minute compile), and ``TM_TEST_CACHE`` redirects all
-of them at once.
+One rule, so that every process of a run — launcher, workers, replica
+servers, bench, tests, ``chip_smoke.py`` — shares one cache and a
+second run deserializes executables instead of recompiling (the
+flagship train step is a multi-minute compile):
+
+- ``JAX_COMPILATION_CACHE_DIR`` set: JAX already reads it; no
+  directory is set in code, so whoever runs the program places the
+  cache.
+- unset: ``<checkout>/.jax_cache`` — a fixed path, because the path is
+  part of the cache key and a directory that moves never hits.
 """
 
 from __future__ import annotations
 
 import os
+from pathlib import Path
+
+_CHECKOUT_CACHE = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
-def enable_compile_cache(default_dir: str | None = None) -> str | None:
-    """Point JAX's persistent compilation cache at ``TM_TEST_CACHE``
-    (env) or ``default_dir`` (fallback: ``.jax_cache`` next to the
-    repo root).  Returns the directory used, or None if the config
-    knobs are unavailable — the cache is an optimization, never a
-    failure."""
+def enable_compile_cache() -> str:
+    """Switch the persistent compilation cache on (see module
+    docstring for where it lives); returns the directory in use."""
     import jax
 
-    from theanompi_tpu import compat
-
-    if compat.SHIMMED and os.environ.get("TM_FORCE_COMPILE_CACHE") != "1":
-        # 0.4.x jaxlibs corrupt the heap (segfault / "corrupted
-        # double-linked list" abort, reproduced on this image's CPU
-        # backend) when persisting these shard_map executables; on a
-        # shimmed jax the cache is disabled — correctness over warm
-        # compiles.  TM_FORCE_COMPILE_CACHE=1 overrides.
-        return None
-
-    cache = os.environ.get("TM_TEST_CACHE")
-    if not cache:
-        cache = default_dir or os.path.join(
-            os.path.dirname(os.path.dirname(
-                os.path.dirname(os.path.abspath(__file__)))),
-            ".jax_cache",
-        )
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception:
-        return None
-    return cache
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(_CHECKOUT_CACHE))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    return jax.config.jax_compilation_cache_dir

@@ -69,11 +69,9 @@ CPU_WRAPPER_MARKERS = (
 
 # XLA:CPU execution-lane prefixes (the per-device client threads and
 # the intra-op pools where warm thunks actually run).  The client
-# class name varies with the runtime build — PjRtCpuClient on some
-# jax builds, TfrtCpuClient on this image's 0.4.x (verified: its
-# absence was why CPU-mesh traces reported n_cores == 0 and the bench
-# llama row emitted a null exposed_comm_frac) — so every known
-# spelling is matched.
+# class name varies with the runtime build (PjRtCpuClient,
+# TfrtCpuClient); a spelling missing here shows as CPU-mesh traces
+# reporting n_cores == 0, so every known one is matched.
 CPU_LANE_PREFIXES = (
     "tf_xlapjrtcpuclient",
     "tf_xlatfrtcpuclient",
@@ -190,15 +188,8 @@ def hlo_instruction_names(hlo_text: str) -> set[str]:
 
 
 def compiled_hlo_text(compiled) -> str:
-    """Optimized-HLO text of a jax ``Compiled`` across the API
-    variants this image's jax versions expose."""
-    try:
-        return "\n".join(
-            m.to_string()
-            for m in compiled.runtime_executable().hlo_modules()
-        )
-    except Exception:
-        return compiled.as_text()
+    """Optimized-HLO text of a jax ``Compiled``."""
+    return compiled.as_text()
 
 
 def quant_op_names(lowered) -> set[str]:
