@@ -95,34 +95,15 @@ def _require(cond: bool, message: str) -> None:
         raise CheckFailed(message)
 
 
-class CompileMeter:
-    """JAX's own account of compilation: seconds in the backend
-    compiler (or fetching from the persistent cache — tracing and
-    lowering are host time and stay in ``run_s``), and the cache's
-    hits and misses.  Listeners cannot be removed, so a process makes
-    one meter and reads differences."""
+def __getattr__(name: str):
+    """``chip_smoke.CompileMeter`` is ``theanompi_tpu.obs``'s now; the
+    name stays here, imported on use as everything of the package is
+    (the script alone must still print its last line)."""
+    if name == "CompileMeter":
+        from theanompi_tpu.obs import CompileMeter
 
-    def __init__(self) -> None:
-        import jax
-
-        self.compile_s = 0.0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._secs)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _secs(self, event: str, duration: float, **_) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += duration
-
-    def _event(self, event: str, **_) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def read(self) -> tuple[float, int, int]:
-        return self.compile_s, self.hits, self.misses
+        return CompileMeter
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 def run_phase(name: str, meter: CompileMeter, fn, *args, **kw) -> dict:
@@ -131,7 +112,7 @@ def run_phase(name: str, meter: CompileMeter, fn, *args, **kw) -> dict:
     dropped before the next one needs the HBM."""
     import jax
 
-    t0, (c0, h0, m0) = time.perf_counter(), meter.read()
+    t0, before = time.perf_counter(), meter.read()
     line = {"phase": name, "ok": True}
     try:
         line.update(fn(*args, **kw))
@@ -139,11 +120,11 @@ def run_phase(name: str, meter: CompileMeter, fn, *args, **kw) -> dict:
         traceback.print_exc()
         line.update(ok=False, error=f"{type(e).__name__}: {e}"[:2000])
     wall = time.perf_counter() - t0
-    c1, h1, m1 = meter.read()
+    new = meter.since(before)
     line.update(
-        wall_s=round(wall, 2), compile_s=round(c1 - c0, 2),
-        run_s=round(wall - (c1 - c0), 2),
-        cache_hits=h1 - h0, cache_misses=m1 - m0,
+        wall_s=round(wall, 2), compile_s=round(new["compile_s"], 2),
+        run_s=round(wall - new["compile_s"], 2),
+        cache_hits=new["cache_hits"], cache_misses=new["cache_misses"],
     )
     gc.collect()
     jax.clear_caches()
@@ -557,6 +538,7 @@ def main(argv=None) -> int:
         import jax
 
         from theanompi_tpu import native
+        from theanompi_tpu.obs import process_meter
         from theanompi_tpu.utils import enable_compile_cache
 
         devices = jax.devices()
@@ -566,7 +548,7 @@ def main(argv=None) -> int:
             "count": len(devices),
         }
         cache_dir = enable_compile_cache()
-        meter = CompileMeter()
+        meter = process_meter()
         t0 = time.perf_counter()
         native_lib = native.load_native()
         print(json.dumps({

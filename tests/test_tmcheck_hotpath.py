@@ -362,6 +362,46 @@ class TestTracerSpans:
         assert out == []
 
 
+class TestRecorderPhase:
+    """`Recorder.phase` (utils/recorder.py), the training path's span
+    call, is seeded like the tracer API: attributes are host values."""
+
+    def test_fence_inside_phase_attr_in_hot_loop_flagged(self):
+        # the known-bad twin: a loss read back to decorate the span
+        out = run("""
+            class Model:
+                def train_chunk(self, count, k, recorder):  # tmcheck: hot
+                    for j in range(k):
+                        loss = jnp.mean(self.losses[j])
+                        with recorder.phase(
+                            "dispatch", first=count, loss=float(loss)
+                        ):
+                            self.dispatch(j)
+        """)
+        assert "TM104" in rules_of(out)
+
+    def test_host_attrs_only_phase_clean(self):
+        out = run("""
+            class Model:
+                def train_chunk(self, count, k, recorder):  # tmcheck: hot
+                    for j in range(k):
+                        with recorder.phase("dispatch", first=count, k=k):
+                            self.dispatch(j)
+        """)
+        assert out == []
+
+    def test_phase_body_is_hot(self):
+        # a span call that fences a device value on entry is flagged
+        # without any caller involved
+        out = run("""
+            class Recorder:
+                def phase(self, name, value):
+                    self.last = value.item()
+                    return self
+        """)
+        assert rules_of(out) == ["TM104"]
+
+
 class TestLoaderProducerFences:
     """The streaming loader's producer loop (TM104 seeds "next"/
     "_produce", ISSUE 16): the whole point of the producer thread is

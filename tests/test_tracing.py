@@ -645,7 +645,7 @@ class TestTrainingRecorderTracing:
         spans = tr.spans()
         names = [s["name"] for s in spans]
         assert names.count("iteration") == 4
-        assert names.count("step") == 3 and names.count("load") == 3
+        assert names.count("dispatch") == 3 and names.count("load") == 3
         # each phase span parents under its iteration root
         for tid in {s["trace_id"] for s in spans}:
             assert span_tree(spans, tid)["connected"]

@@ -61,6 +61,9 @@ GUARDED_BY: dict[str, tuple[str | None, frozenset]] = {
 #: bodies must stay host-pure, and a device value fenced into a span
 #: attribute at a call site in a hot function is the same
 #: per-iteration round trip TM104 exists for (fixture-tested).
+#: ``phase`` is the training path's span call
+#: (``utils/recorder.Recorder.phase``): its attributes are host
+#: values for the same reason, around every dispatch of the step.
 #: The streaming loader's consumer/producer pair (data/pipeline.py
 #: ``next``/``_produce``) is seeded because the pipeline only
 #: overlaps if NEITHER side ever fences: one ``block_until_ready`` or
@@ -71,7 +74,7 @@ GUARDED_BY: dict[str, tuple[str | None, frozenset]] = {
 #: (native/__init__.py), whose body is host-pure by construction.
 HOT_EXACT = frozenset({
     "step", "decode", "decode_step", "prefill", "verify", "draft",
-    "span", "start_span", "end_span", "record_span",
+    "span", "start_span", "end_span", "record_span", "phase",
     "next", "_produce",
 })
 #: … and substrings (catches `_advance_prefill_slot`,

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 from theanompi_tpu.models.base import ClassifierModel
 from theanompi_tpu.models.data.imagenet import CROP, ImageNetData, N_CLASSES
+from theanompi_tpu.obs.setup import setup_phase
 from theanompi_tpu.ops import (
     FC,
     LRN,
@@ -66,12 +67,13 @@ class AlexNet(ClassifierModel):
         ])
         crop = int(self.config.get("crop", CROP))
         self.input_shape = (crop, crop, 3)
-        self.data = ImageNetData(
-            batch_size=self.config.get("batch_size", 128),
-            n_replicas=n_replicas,
-            crop=crop,
-            seed=self.seed,
-            n_train=self.config.get("n_train"),
-            n_val=self.config.get("n_val"),
-        )
+        with setup_phase("data"):
+            self.data = ImageNetData(
+                batch_size=self.config.get("batch_size", 128),
+                n_replicas=n_replicas,
+                crop=crop,
+                seed=self.seed,
+                n_train=self.config.get("n_train"),
+                n_val=self.config.get("n_val"),
+            )
         self._init_params()

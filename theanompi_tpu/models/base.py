@@ -28,6 +28,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from theanompi_tpu.obs.setup import setup_phase
 from theanompi_tpu.ops import optimizers as opt_lib
 from theanompi_tpu.ops.layers import accuracy, softmax_cross_entropy
 from theanompi_tpu.parallel import (
@@ -1000,7 +1001,8 @@ class ClassifierModel(TMModel):
         pre-batched hickle files (SURVEY §2.1 ImageNet data row), one
         level down the memory hierarchy."""
         get = getattr(self.data, "dataset_arrays", None)
-        arrays = get("train") if get is not None else None
+        with setup_phase("data"):   # a synthetic set is generated here
+            arrays = get("train") if get is not None else None
         if arrays is None:
             import warnings
 
@@ -1013,14 +1015,15 @@ class ClassifierModel(TMModel):
             return
         xs, ys = arrays
         rep = NamedSharding(self.mesh, P())
-        # floats ride in compute dtype (halves HBM); int inputs (token
-        # ids) keep their dtype
-        if np.issubdtype(np.asarray(xs).dtype, np.floating):
-            xs = jnp.asarray(xs, self.compute_dtype)
-        self._device_cache = (
-            jax.device_put(jnp.asarray(xs), rep),
-            jax.device_put(jnp.asarray(ys), rep),
-        )
+        with setup_phase("stage_data"):
+            # floats ride in compute dtype (halves HBM); int inputs
+            # (token ids) keep their dtype
+            if np.issubdtype(np.asarray(xs).dtype, np.floating):
+                xs = jnp.asarray(xs, self.compute_dtype)
+            self._device_cache = (
+                jax.device_put(jnp.asarray(xs), rep),
+                jax.device_put(jnp.asarray(ys), rep),
+            )
 
         gb = int(self.data.global_batch)
         n_shards = self.mesh.shape[DATA_AXIS]
@@ -1184,53 +1187,18 @@ class ClassifierModel(TMModel):
             for j in range(k):
                 self.train_iter(count + j, recorder)
             return
-        recorder.start()
-        self._stage_cached_inputs()
-        recorder.end("wait")
-        recorder.start()
-        (
-            self.params,
-            self.net_state,
-            self.opt_state,
-            self.ef_state,
-            self._step_dev,
-            losses,
-            errs,
-        ) = self._train_scan(
-            self.params,
-            self.net_state,
-            self.opt_state,
-            self.ef_state,
-            self._step_dev,
-            self._device_cache[0],
-            self._device_cache[1],
-            self._perm_dev,
-            self._lr_dev,
-            self._key0_dev,
-        )
-        recorder.end("calc")
-        # ONE vector record: k per-step metrics, one async D2H each
-        recorder.train_error(count, losses, errs)
-
-    def train_iter(self, count: int, recorder: Recorder) -> None:
-        if self._train_step_cached is not None:
-            # device-resident path: batches are ordered by the DEVICE
-            # step counter (calls must be sequential, as the worker
-            # loop's are); the only host work is restaging the epoch
-            # permutation / lr when they change
-            recorder.start()
+        with recorder.phase("load"):
             self._stage_cached_inputs()
-            recorder.end("wait")
-            recorder.start()
+        with recorder.phase("dispatch", first=count, k=k):
             (
                 self.params,
                 self.net_state,
                 self.opt_state,
                 self.ef_state,
                 self._step_dev,
-                loss,
-                err,
-            ) = self._train_step_cached(
+                losses,
+                errs,
+            ) = self._train_scan(
                 self.params,
                 self.net_state,
                 self.opt_state,
@@ -1242,47 +1210,76 @@ class ClassifierModel(TMModel):
                 self._lr_dev,
                 self._key0_dev,
             )
-            recorder.end("calc")
+        # ONE vector record: k per-step metrics, one async D2H each
+        recorder.train_error(count, losses, errs)
+
+    def train_iter(self, count: int, recorder: Recorder) -> None:
+        if self._train_step_cached is not None:
+            # device-resident path: batches are ordered by the DEVICE
+            # step counter (calls must be sequential, as the worker
+            # loop's are); the only host work is restaging the epoch
+            # permutation / lr when they change
+            with recorder.phase("load"):
+                self._stage_cached_inputs()
+            with recorder.phase("dispatch", first=count, k=1):
+                (
+                    self.params,
+                    self.net_state,
+                    self.opt_state,
+                    self.ef_state,
+                    self._step_dev,
+                    loss,
+                    err,
+                ) = self._train_step_cached(
+                    self.params,
+                    self.net_state,
+                    self.opt_state,
+                    self.ef_state,
+                    self._step_dev,
+                    self._device_cache[0],
+                    self._device_cache[1],
+                    self._perm_dev,
+                    self._lr_dev,
+                    self._key0_dev,
+                )
             recorder.train_error(count, loss, err)
             return
-        recorder.start()
-        if self._feed is not None:
-            # pipelined feed: this batch was fetched + staged by the
-            # producer thread UNDER the previous step's compute — the
-            # wait segment is a ring pop
-            x, y = self._feed.next(count)
-        else:
-            batch = self.data.train_batch(count)
-            x, y = self.put_batch(batch)
-        recorder.end("wait")
+        with recorder.phase("load"):
+            if self._feed is not None:
+                # pipelined feed: this batch was fetched + staged by
+                # the producer thread UNDER the previous step's
+                # compute — the wait segment is a ring pop
+                x, y = self._feed.next(count)
+            else:
+                batch = self.data.train_batch(count)
+                x, y = self.put_batch(batch)
 
-        recorder.start()
-        self._rng, step_key = jax.random.split(self._rng)
-        (
-            self.params,
-            self.net_state,
-            self.opt_state,
-            self.ef_state,
-            loss,
-            err,
-        ) = self._train_step(
-            self.params,
-            self.net_state,
-            self.opt_state,
-            self.ef_state,
-            x,
-            y,
-            jnp.float32(self.current_lr),
-            step_key,
-        )
         # NO per-step fence: the loss/err device scalars go to the
         # recorder unread and are materialized at the next print window
-        # or epoch end (Recorder.flush).  Reading the value here would
+        # or epoch end (Recorder.fence).  Reading the value here would
         # serialize dispatch — the device idles while the host reads
         # back and stages the next batch — costing ~4% throughput on
         # the r1 flagship bench.  The recorder's flush reads the
         # values, and that read is the fence.
-        recorder.end("calc")
+        with recorder.phase("dispatch", first=count, k=1):
+            self._rng, step_key = jax.random.split(self._rng)
+            (
+                self.params,
+                self.net_state,
+                self.opt_state,
+                self.ef_state,
+                loss,
+                err,
+            ) = self._train_step(
+                self.params,
+                self.net_state,
+                self.opt_state,
+                self.ef_state,
+                x,
+                y,
+                jnp.float32(self.current_lr),
+                step_key,
+            )
         recorder.train_error(count, loss, err)
 
     def val_iter(self, count: int, recorder: Recorder):

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from theanompi_tpu.models.base import ClassifierModel
 from theanompi_tpu.models.data.imagenet import CROP, ImageNetData, N_CLASSES
+from theanompi_tpu.obs.setup import setup_phase
 from theanompi_tpu.ops import (
     FC,
     LRN,
@@ -228,14 +229,15 @@ class GoogLeNet(ClassifierModel):
         )
         crop = int(self.config.get("crop", CROP))
         self.input_shape = (crop, crop, 3)
-        self.data = ImageNetData(
-            batch_size=self.config.get("batch_size", 32),
-            n_replicas=n_replicas,
-            crop=crop,
-            seed=self.seed,
-            n_train=self.config.get("n_train"),
-            n_val=self.config.get("n_val"),
-        )
+        with setup_phase("data"):
+            self.data = ImageNetData(
+                batch_size=self.config.get("batch_size", 32),
+                n_replicas=n_replicas,
+                crop=crop,
+                seed=self.seed,
+                n_train=self.config.get("n_train"),
+                n_val=self.config.get("n_val"),
+            )
         self._init_params()
 
     def load(self, directory, recorder=None):

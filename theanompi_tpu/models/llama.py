@@ -60,6 +60,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from theanompi_tpu.models.base import TMModel
 from theanompi_tpu.models.data.lm_synthetic import MarkovLMData
+from theanompi_tpu.obs.setup import setup_phase
 from theanompi_tpu.ops.attention import flash_attention
 from theanompi_tpu.ops import optimizers as opt_lib
 from theanompi_tpu.parallel import (
@@ -591,15 +592,16 @@ class Llama(TMModel):
     # -- contract ---------------------------------------------------------
 
     def build_model(self, n_replicas: int = 1) -> None:
-        self.data = MarkovLMData(
-            vocab=self.vocab,
-            seq_len=self.seq_len,
-            batch_size=int(self.config.get("batch_size", 8)),
-            n_replicas=n_replicas,
-            n_train=int(self.config.get("n_train", 2048)),
-            n_val=int(self.config.get("n_val", 256)),
-            seed=self.seed,
-        )
+        with setup_phase("data"):
+            self.data = MarkovLMData(
+                vocab=self.vocab,
+                seq_len=self.seq_len,
+                batch_size=int(self.config.get("batch_size", 8)),
+                n_replicas=n_replicas,
+                n_train=int(self.config.get("n_train", 2048)),
+                n_val=int(self.config.get("n_val", 256)),
+                seed=self.seed,
+            )
         # params materialize in compile_iter_fns, under jit with sharded
         # out_shardings — the full tree never lives on one device
         self.params = None
@@ -1201,38 +1203,38 @@ class Llama(TMModel):
         # never compiled.
         self._train_scan1 = make_scan(1)
         self._scan_k = k
-        self._seqs_dev = jax.device_put(
-            jnp.asarray(get(), jnp.int32), rep
-        )
+        with setup_phase("stage_data"):
+            self._seqs_dev = jax.device_put(
+                jnp.asarray(get(), jnp.int32), rep
+            )
         self._step_dev = jax.device_put(jnp.zeros((), jnp.int32), rep)
         self._perm_src = None
         self._perm_dev = None
         self._lr_val = None
         self._lr_dev = None
 
-    def _scan_dispatch(self, scan_fn, count: int, recorder: Recorder):
-        recorder.start()
-        self._stage_cached_inputs()
-        recorder.end("wait")
-        recorder.start()
-        (
-            self.params,
-            self.opt_state,
-            self.ef_state,
-            self._step_dev,
-            losses,
-            errs,
-        ) = scan_fn(
-            self.params, self.opt_state, self.ef_state,
-            self._step_dev, self._seqs_dev, self._perm_dev,
-            self._lr_dev,
-        )
-        recorder.end("calc")
+    def _scan_dispatch(self, scan_fn, count: int, k: int,
+                       recorder: Recorder):
+        with recorder.phase("load"):
+            self._stage_cached_inputs()
+        with recorder.phase("dispatch", first=count, k=k):
+            (
+                self.params,
+                self.opt_state,
+                self.ef_state,
+                self._step_dev,
+                losses,
+                errs,
+            ) = scan_fn(
+                self.params, self.opt_state, self.ef_state,
+                self._step_dev, self._seqs_dev, self._perm_dev,
+                self._lr_dev,
+            )
         recorder.train_error(count, losses, errs)
 
     def train_chunk(self, count: int, k: int, recorder: Recorder) -> None:
         if k == self._scan_k and self._train_scan is not None:
-            self._scan_dispatch(self._train_scan, count, recorder)
+            self._scan_dispatch(self._train_scan, count, k, recorder)
             return
         for j in range(k):
             self.train_iter(count + j, recorder)
@@ -1287,28 +1289,26 @@ class Llama(TMModel):
             # indexing and advances _step_dev, so per-step calls (an
             # epoch tail, mixed callers) can't desync the device
             # index from the host position
-            self._scan_dispatch(self._train_scan1, count, recorder)
+            self._scan_dispatch(self._train_scan1, count, 1, recorder)
             return
-        recorder.start()
-        if self._feed is not None:
-            # pipelined feed: fetched + staged by the producer thread
-            # under the previous step's compute
-            x, y = self._feed.next(count)
-        else:
-            x, y = self.put_batch(self.data.train_batch(count))
-        recorder.end("wait")
-        recorder.start()
-        (
-            self.params,
-            self.opt_state,
-            self.ef_state,
-            loss,
-            err,
-        ) = self._train_step(
-            self.params, self.opt_state, self.ef_state, x, y,
-            jnp.float32(self.current_lr),
-        )
-        recorder.end("calc")
+        with recorder.phase("load"):
+            if self._feed is not None:
+                # pipelined feed: fetched + staged by the producer
+                # thread under the previous step's compute
+                x, y = self._feed.next(count)
+            else:
+                x, y = self.put_batch(self.data.train_batch(count))
+        with recorder.phase("dispatch", first=count, k=1):
+            (
+                self.params,
+                self.opt_state,
+                self.ef_state,
+                loss,
+                err,
+            ) = self._train_step(
+                self.params, self.opt_state, self.ef_state, x, y,
+                jnp.float32(self.current_lr),
+            )
         # device scalars, materialized lazily at the next print window
         # or epoch end (Recorder.flush) — no per-step host fence
         recorder.train_error(count, loss, err)

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from theanompi_tpu.models.base import ClassifierModel
 from theanompi_tpu.models.data.imdb import ImdbData, N_CLASSES, PAD_ID
+from theanompi_tpu.obs.setup import setup_phase
 from theanompi_tpu.ops.layers import FC, Dropout, Layer
 from theanompi_tpu.ops.recurrent import LSTM as LSTMLayer
 from theanompi_tpu.ops.recurrent import Embedding
@@ -76,13 +77,14 @@ class LSTM(ClassifierModel):
             self.compute_dtype,
         )
         self.input_shape = (self.maxlen,)
-        self.data = ImdbData(
-            batch_size=self.config.get("batch_size", 32),
-            n_replicas=n_replicas,
-            maxlen=self.maxlen,
-            vocab=self.vocab,
-            seed=self.seed,
-            n_train=self.config.get("n_train"),
-            n_val=self.config.get("n_val"),
-        )
+        with setup_phase("data"):
+            self.data = ImdbData(
+                batch_size=self.config.get("batch_size", 32),
+                n_replicas=n_replicas,
+                maxlen=self.maxlen,
+                vocab=self.vocab,
+                seed=self.seed,
+                n_train=self.config.get("n_train"),
+                n_val=self.config.get("n_val"),
+            )
         self._init_params()

@@ -17,6 +17,7 @@ import jax
 
 from theanompi_tpu.models.base import ClassifierModel
 from theanompi_tpu.models.data.cifar10 import Cifar10Data, N_CLASSES, SHAPE
+from theanompi_tpu.obs.setup import setup_phase
 from theanompi_tpu.ops import BN, FC, Activation, Conv, GlobalAvgPool, Sequential, initializers
 from theanompi_tpu.ops.layers import Layer
 
@@ -94,15 +95,16 @@ class WResNet(ClassifierModel):
         layers += [BN(), Activation("relu"), GlobalAvgPool(), FC(N_CLASSES)]
         self.net = Sequential(layers)
         self.input_shape = SHAPE
-        self.data = Cifar10Data(
-            batch_size=self.config.get("batch_size", 128),
-            n_replicas=n_replicas,
-            seed=self.seed,
-            n_train=self.config.get("n_train"),
-            n_val=self.config.get("n_val"),
-            # convergence drills: flip a fraction of returned labels
-            # so the plateau sits off the floor (applies on both the
-            # synthetic and real-CIFAR paths)
-            label_noise=float(self.config.get("label_noise", 0.0)),
-        )
+        with setup_phase("data"):
+            self.data = Cifar10Data(
+                batch_size=self.config.get("batch_size", 128),
+                n_replicas=n_replicas,
+                seed=self.seed,
+                n_train=self.config.get("n_train"),
+                n_val=self.config.get("n_val"),
+                # convergence drills: flip a fraction of returned labels
+                # so the plateau sits off the floor (applies on both the
+                # synthetic and real-CIFAR paths)
+                label_noise=float(self.config.get("label_noise", 0.0)),
+            )
         self._init_params()

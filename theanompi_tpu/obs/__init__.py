@@ -1,7 +1,9 @@
 """Observability subsystem: distributed span tracing (bounded
 flight-recorder, Perfetto export with counter tracks, critical-path
 attribution), the step-phase profiler (``profiler.py``), the bench
-regression gate (``regress.py``), and Prometheus-style metrics text.
+regression gate (``regress.py``), Prometheus-style metrics text, the
+process's compile counter (``compile_meter.py``) and a training run's
+set-up phases (``setup.py``).
 See docs/OBSERVABILITY.md and docs/PERFORMANCE.md."""
 
 from theanompi_tpu.obs.tracer import (  # noqa: F401
@@ -10,6 +12,16 @@ from theanompi_tpu.obs.tracer import (  # noqa: F401
     child_context,
     force_sample,
     make_context,
+)
+from theanompi_tpu.obs.compile_meter import (  # noqa: F401
+    CompileMeter,
+    process_meter,
+)
+from theanompi_tpu.obs.setup import (  # noqa: F401
+    SetupRecord,
+    begin_setup,
+    last_setup_phases,
+    setup_phase,
 )
 from theanompi_tpu.obs.export import (  # noqa: F401
     chrome_trace,
@@ -31,9 +43,12 @@ from theanompi_tpu.obs.profiler import (  # noqa: F401
 )
 
 __all__ = [
+    "CompileMeter",
     "DEFAULT_TRACE_SAMPLE",
+    "SetupRecord",
     "StepProfile",
     "Tracer",
+    "begin_setup",
     "child_context",
     "chrome_trace",
     "critical_path",
@@ -41,10 +56,13 @@ __all__ = [
     "format_critical_path",
     "format_profile",
     "gap_attribution",
+    "last_setup_phases",
     "make_context",
+    "process_meter",
     "profile_scope_sets",
     "quantile_samples",
     "render_metrics",
+    "setup_phase",
     "span_tree",
     "step_profile",
     "write_chrome_trace",

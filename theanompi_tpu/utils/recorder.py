@@ -16,8 +16,20 @@ recorder therefore reports:
   rules, whose elastic/gossip exchanges are separate dispatches),
 - ``wait`` — input-pipeline stalls (waiting on the next batch).
 
-For intra-step comm attribution use ``jax.profiler`` traces
-(``Recorder.start_profiler``/``stop_profiler``).
+**Spans.**  ``Recorder.phase(name, **attrs)`` is the training path's
+one way to open a span.  It is always a
+``jax.profiler.TraceAnnotation`` named ``tm:worker.<name>`` — a no-op
+costing well under a microsecond without a profiler session, and an
+event on the profiler's own clock, beside the device's "XLA Modules"
+line, with one; it books the span's seconds to the segment above
+that the name belongs to (``load`` is wait, ``dispatch`` and ``fence``
+are calc, ``exchange`` is comm, any other name books nothing); and
+with a ring ``Tracer`` attached (``config["trace"]``) it records the
+same span under the iteration's root.  ``dispatch`` is the HOST's
+time inside the call of the jitted step — about a millisecond
+around a device program of any length under asynchronous dispatch —
+and ``fence`` is where the host waits for the device
+(docs/OBSERVABILITY.md, "Training spans and set-up phases").
 """
 
 from __future__ import annotations
@@ -33,10 +45,64 @@ import numpy as np
 
 MODES = ("calc", "comm", "wait")
 
-#: recorder segment -> span name in the training trace (the phases
-#: Theano-MPI's per-iteration breakdown named: load the batch, run
-#: the step, exchange the gradients)
-_MODE_SPAN = {"calc": "step", "comm": "exchange", "wait": "load"}
+#: span name -> the reference's segment its seconds are booked to
+#: (load the batch, hand the step to the device, wait for it,
+#: exchange on the host); every other span books nothing
+_PHASE_MODE = {"load": "wait", "dispatch": "calc", "fence": "calc",
+               "exchange": "comm"}
+#: segment -> ring-span name for the ``start()``/``end(mode)`` pairs
+#: the EASGD and GoSGD workers still use
+_MODE_SPAN = {"calc": "dispatch", "comm": "exchange", "wait": "load"}
+
+_TraceAnnotation = None
+
+
+def _annotation(name: str, attrs: dict):
+    """``jax.profiler.TraceAnnotation`` (a TraceMe), imported at the
+    first span so that importing this module stays free of JAX."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name, **attrs)
+
+
+class _Phase:
+    """One ``Recorder.phase`` span (see the module docstring)."""
+
+    __slots__ = ("rec", "name", "attrs", "ann", "t0", "t0_trace")
+
+    def __init__(self, rec: "Recorder", name: str, attrs: dict):
+        self.rec = rec
+        self.name = name
+        self.attrs = attrs
+
+    def __enter__(self) -> "_Phase":
+        rec = self.rec
+        self.ann = _annotation(f"tm:worker.{self.name}", self.attrs)
+        self.ann.__enter__()
+        self.t0 = time.perf_counter()
+        self.t0_trace = (
+            rec._tracer.clock()
+            if rec._tracer is not None and rec._iter_root is not None
+            else None
+        )
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        rec = self.rec
+        mode = _PHASE_MODE.get(self.name)
+        if mode is not None:
+            rec._book(mode, time.perf_counter() - self.t0)
+        if self.t0_trace is not None and rec._iter_root is not None:
+            rec._tracer.record_span(
+                rec._iter_ctx, self.name, self.t0_trace,
+                rec._tracer.clock(),
+                parent_id=rec._iter_root["span_id"], **self.attrs,
+            )
+        self.ann.__exit__(*exc)
+        return False
 
 
 class Recorder:
@@ -74,9 +140,12 @@ class Recorder:
         # checkpoints so the FINAL summary shows the whole run's
         # restart history, not just the last process's.
         self.restart_events: list[dict] = []
-        # span tracing (theanompi_tpu/obs): attach_tracer() turns the
-        # per-iteration calc/comm/wait segments into load/step/
-        # exchange spans riding the iteration-boundary heartbeat
+        #: ``time.monotonic()`` at the end of the run's first fence
+        #: (where the worker's set-up record ends)
+        self.first_fence_end: float | None = None
+        # span tracing (theanompi_tpu/obs): attach_tracer() also puts
+        # every phase() span (load/dispatch/fence/...) into the ring,
+        # under the root riding the iteration-boundary heartbeat
         self._tracer = None
         self._iter_ctx: dict | None = None
         self._iter_root: dict | None = None
@@ -86,9 +155,9 @@ class Recorder:
 
     def attach_tracer(self, tracer) -> None:
         """Record each sampled ITERATION as one trace (root span
-        ``iteration``) whose children are the load/step/exchange
-        phase spans the ``start()``/``end(mode)`` segments already
-        measure.  The tracer's own ``sample`` knob decides which
+        ``iteration``) whose children are the ``phase()`` spans
+        (and the load/dispatch/exchange segments of a
+        ``start()``/``end(mode)`` pair).  The tracer's own ``sample`` knob decides which
         iterations trace; call :meth:`trace_boundary` at the
         iteration boundary (next to the supervisor heartbeat)."""
         self._tracer = tracer
@@ -115,6 +184,28 @@ class Recorder:
 
     # -- wall-clock segments (reference: start()/end(mode)) ---------------
 
+    def phase(self, name: str, **attrs) -> _Phase:
+        """``with recorder.phase("dispatch", epoch=e, first=i, k=k):``
+        — the one span call of the training path (module docstring).
+        ``attrs`` are HOST values (tmcheck TM104 guards the call)."""
+        return _Phase(self, name, attrs)
+
+    def _book(self, mode: str, dt: float) -> None:
+        self.segments[mode] += dt
+        self.epoch_segments[mode] += dt
+        self.total_segments[mode] += dt
+
+    def fence(self) -> None:
+        """``flush`` where the loop means to block: the end of an
+        epoch, a print window, before a preemption save.  The wait is
+        booked to calc, so that figure is wall-clock-honest though
+        ``dispatch`` only saw the host's part."""
+        blocked = bool(self._pending)
+        with self.phase("fence"):
+            self.flush()
+        if blocked and self.first_fence_end is None:
+            self.first_fence_end = time.monotonic()
+
     def start(self) -> None:
         self._t0 = time.perf_counter()
         if self._tracer is not None and self._iter_root is not None:
@@ -124,10 +215,7 @@ class Recorder:
         assert mode in MODES, mode
         if self._t0 is None:
             return
-        dt = time.perf_counter() - self._t0
-        self.segments[mode] += dt
-        self.epoch_segments[mode] += dt
-        self.total_segments[mode] += dt
+        self._book(mode, time.perf_counter() - self._t0)
         self._t0 = None
         if (
             self._tracer is not None and self._iter_root is not None
@@ -198,16 +286,9 @@ class Recorder:
         if not self.verbose or self.n_iter < self._last_print + self.print_freq:
             return
         self._last_print = self.n_iter
-        # the flush below blocks until every step issued this window has
-        # actually finished on device — attribute that wait to calc so
-        # the window's calc figure is wall-clock-honest even though the
-        # per-iteration end('calc') only saw dispatch time
-        t0 = time.perf_counter()
-        self.flush()
-        dt = time.perf_counter() - t0
-        self.segments["calc"] += dt
-        self.epoch_segments["calc"] += dt
-        self.total_segments["calc"] += dt
+        # blocks until every step issued this window has finished on
+        # the device
+        self.fence()
         if not self._window:
             return
         losses, errs = zip(*self._window)
@@ -283,29 +364,25 @@ class Recorder:
     def end_epoch(self, epoch: int) -> None:
         if self._epoch_start is None:
             return
-        t0 = time.perf_counter()
-        self.flush()  # fence: epoch wall time includes all device work
-        dt = time.perf_counter() - t0
-        self.segments["calc"] += dt
-        self.epoch_segments["calc"] += dt
-        self.total_segments["calc"] += dt
-        wall = time.perf_counter() - self._epoch_start
-        self.epoch_times.append(wall)
-        if self.verbose:
-            seg = self.epoch_segments
-            val = self.val_records[-1] if self.val_records else {}
-            val_str = (
-                f" | val loss {val.get('loss', float('nan')):.4f}"
-                f" err {val.get('err', float('nan')):.4f}"
-                if val
-                else ""
-            )
-            print(
-                f"epoch {epoch}: {wall:.1f}s"
-                f" (calc {seg['calc']:.1f}s comm {seg['comm']:.1f}s"
-                f" wait {seg['wait']:.1f}s){val_str}",
-                flush=True,
-            )
+        self.fence()  # epoch wall time includes all device work
+        with self.phase("end_epoch", epoch=int(epoch)):
+            wall = time.perf_counter() - self._epoch_start
+            self.epoch_times.append(wall)
+            if self.verbose:
+                seg = self.epoch_segments
+                val = self.val_records[-1] if self.val_records else {}
+                val_str = (
+                    f" | val loss {val.get('loss', float('nan')):.4f}"
+                    f" err {val.get('err', float('nan')):.4f}"
+                    if val
+                    else ""
+                )
+                print(
+                    f"epoch {epoch}: {wall:.1f}s"
+                    f" (calc {seg['calc']:.1f}s comm {seg['comm']:.1f}s"
+                    f" wait {seg['wait']:.1f}s){val_str}",
+                    flush=True,
+                )
 
     def metrics_txt(self, prefix: str = "tm_train",
                     world_size: int | None = None) -> str:
@@ -348,18 +425,6 @@ class Recorder:
             (f"{p}_mttr_seconds", "gauge", [(None, self.mttr_s)]),
             (f"{p}_world_size", "gauge", [(None, world_size)]),
         ])
-
-    # -- profiler handoff (SURVEY §5.1 rebuild note) ----------------------
-
-    def start_profiler(self, logdir: str) -> None:
-        import jax
-
-        jax.profiler.start_trace(logdir)
-
-    def stop_profiler(self) -> None:
-        import jax
-
-        jax.profiler.stop_trace()
 
     # -- persistence (reference: save()/load() of record arrays) ----------
 

@@ -17,6 +17,7 @@ import os
 from typing import Any, Sequence
 
 from theanompi_tpu import launcher as _launcher
+from theanompi_tpu.obs.setup import begin_setup
 from theanompi_tpu.parallel import default_devices, dp_replicas, make_mesh
 from theanompi_tpu.utils import Recorder, faults as _faults
 from theanompi_tpu.utils import supervisor as _sup
@@ -284,6 +285,9 @@ def run(
     **extra: Any,
 ) -> dict:
     """Train ``modelclass`` under BSP; returns a summary dict."""
+    # set-up phases (obs/setup.py): from here to the first fence
+    setup = begin_setup()
+    meter = setup.meter
     Model = _resolve_model(modelfile, modelclass)
     cfg = dict(config or {})
     cfg.update(extra)
@@ -333,16 +337,19 @@ def run(
         _apply_elastic_policy(cfg, n_replicas, checkpoint_dir, verbose)
         if elastic and resume and checkpoint_dir else None
     )
-    model = Model(cfg)
-    model.build_model(n_replicas=n_replicas)
-    model.compile_iter_fns(mesh=mesh, exch_strategy=strat.name)
+    with setup.phase("build_model"):
+        model = Model(cfg)
+        model.build_model(n_replicas=n_replicas)
+    with setup.phase("compile_iter_fns"):
+        model.compile_iter_fns(mesh=mesh, exch_strategy=strat.name)
 
     recorder = Recorder(
         rank=0, size=n_replicas, print_freq=print_freq, verbose=verbose
     )
     # span tracing (theanompi_tpu/obs, config knob "trace"): each
-    # sampled iteration becomes one trace — load/step/exchange phase
-    # spans riding the iteration-boundary heartbeat below; dump with
+    # sampled iteration becomes one trace — the recorder.phase()
+    # spans (load, dispatch, fence, end_epoch, ...) under the root
+    # riding the iteration-boundary heartbeat below; dump with
     # config["trace_export"] = path (Perfetto-openable JSON)
     tracer = None
     if cfg.get("trace"):
@@ -358,9 +365,10 @@ def run(
     # graceful preemption: SIGTERM → checkpoint at the next iteration
     # boundary (meta stamps next_iter) and exit 0 — a planned
     # preemption loses zero steps instead of the whole epoch
-    start_iter, resumed_from = _sup.begin_resilient_run(
-        model, recorder, checkpoint_dir, resume, verbose=verbose
-    )
+    with setup.phase("resume"):
+        start_iter, resumed_from = _sup.begin_resilient_run(
+            model, recorder, checkpoint_dir, resume, verbose=verbose
+        )
     resharded = getattr(model, "resharded_from", None)
     if (
         elastic_note and elastic_note.get("lr_scale")
@@ -418,14 +426,45 @@ def run(
             flush=True,
         )
 
+    # a compile after the warm-up, with the iteration it was seen at:
+    # the program's own answer to "which step recompiled"
+    late_compiles: list[dict] = []
+    n_late_compiles = 0
+    compiled = meter.read()
+
+    def after_fence_and_compiles(epoch: int) -> None:
+        nonlocal compiled, n_late_compiles
+        if not setup.closed:
+            # the warm-up (and the set-up) ends at the first fence
+            if recorder.first_fence_end is not None:
+                setup.close(at=recorder.first_fence_end)
+                compiled = meter.read()
+            return
+        if meter.programs == compiled["programs"]:
+            return
+        new = meter.since(compiled)
+        compiled = meter.read()
+        n_late_compiles += new["programs"]
+        note = {"iteration": recorder.n_iter, "epoch": epoch,
+                "programs": new["programs"],
+                "compile_s": new["compile_s"],
+                "last_program": meter.last_program}
+        if len(late_compiles) < 64:
+            late_compiles.append(note)
+        with recorder.phase("compile", **note):
+            pass    # an instant on the profiler's clock and in the ring
+
+    setup.open_phase("warmup")
     preempted = False
     i = 0
     while model.epoch < model.n_epochs:
         epoch = model.epoch
         recorder.start_epoch()
         if hasattr(data, "shuffle"):
-            data.shuffle(epoch)  # same epoch → same permutation, so a
-            # mid-epoch resume continues the identical batch sequence
+            with recorder.phase("shuffle", epoch=epoch):
+                data.shuffle(epoch)  # same epoch → same permutation,
+                # so a mid-epoch resume continues the identical batch
+                # sequence
         nb = data.n_batch_train
         i = start_iter
         start_iter = 0
@@ -444,6 +483,7 @@ def run(
             _faults.maybe_inject_fault(epoch, i - k, i - 1,
                                        checkpoint_dir=checkpoint_dir,
                                        world=n_devices)
+            after_fence_and_compiles(epoch)
             recorder.trace_boundary()
             _sup.heartbeat(recorder.n_iter, epoch, i - 1,
                            resumed_from=resumed_from,
@@ -456,16 +496,18 @@ def run(
             break
 
         if data.n_batch_val:
-            tot_l = tot_e = tot_e5 = 0.0
-            for j in range(data.n_batch_val):
-                l, e, e5 = model.val_iter(j, recorder)
-                tot_l += l
-                tot_e += e
-                tot_e5 += e5
-            nv = data.n_batch_val
-            recorder.val_error(tot_l / nv, tot_e / nv, tot_e5 / nv)
+            with recorder.phase("validate", epoch=epoch):
+                tot_l = tot_e = tot_e5 = 0.0
+                for j in range(data.n_batch_val):
+                    l, e, e5 = model.val_iter(j, recorder)
+                    tot_l += l
+                    tot_e += e
+                    tot_e5 += e5
+                nv = data.n_batch_val
+                recorder.val_error(tot_l / nv, tot_e / nv, tot_e5 / nv)
 
         recorder.end_epoch(epoch)
+        after_fence_and_compiles(epoch)
         if os.environ.get("TM_DEBUG_SYNC") == "1":
             # SURVEY §5.2 debug mode: the chips must hold identical
             # replicated params after a full epoch of exchanges
@@ -475,16 +517,19 @@ def run(
             if verbose:
                 print(f"debug-sync epoch {epoch}: spread={spread:g}",
                       flush=True)
-        model.adjust_hyperp(epoch + 1)
+        with recorder.phase("adjust_hyperp", epoch=epoch + 1):
+            model.adjust_hyperp(epoch + 1)
         if checkpoint_dir:
-            model.save(checkpoint_dir, recorder)
+            with recorder.phase("checkpoint", epoch=epoch):
+                model.save(checkpoint_dir, recorder)
         model.epoch += 1
 
     if preempted:
         if checkpoint_dir:
-            recorder.flush()  # fence in-flight steps before the save
-            model.save(checkpoint_dir, recorder,
-                       extra_meta={"next_iter": i, "preempted": True})
+            recorder.fence()  # in-flight steps end before the save
+            with recorder.phase("checkpoint", epoch=model.epoch):
+                model.save(checkpoint_dir, recorder,
+                           extra_meta={"next_iter": i, "preempted": True})
         if verbose:
             print(
                 f"preempted: checkpointed epoch {model.epoch} iter {i}, "
@@ -499,6 +544,7 @@ def run(
                        resharded=bool(resharded))
     # give an in-process host its normal SIGTERM semantics back
     _sup.uninstall_preemption_handler()
+    setup.close()   # a run that never reached a fence ends it here
 
     # step-phase profiler (config knob "step_profile", ISSUE 15): one
     # profiled window AFTER training — per-scope decomposition with
@@ -577,6 +623,9 @@ def run(
         "elastic_resume": elastic_note,
         "resharded": bool(resharded),
         "trace_spans": trace_spans,
+        "setup_phases": setup.as_dict(),
+        "compiles_after_warmup": late_compiles,
+        "n_compiles_after_warmup": n_late_compiles,
         "step_profile": step_prof,
         "loader": loader_stats,
         "recorder": recorder,
