@@ -1,5 +1,7 @@
 """Unit tests for the exchange-rule math against numpy (SURVEY §4b)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -24,7 +26,11 @@ from theanompi_tpu.parallel import (
     make_mesh,
     scatter_update_gather,
 )
-from theanompi_tpu.parallel.exchange import flat_layout
+from theanompi_tpu.parallel.exchange import (
+    exchange_bucket_count,
+    flat_layout,
+)
+from theanompi_tpu.parallel.strategies import STRATEGIES
 from theanompi_tpu.parallel.exchange import (
     elastic_center_merge,
     replica_consistency_delta,
@@ -1000,6 +1006,180 @@ class TestZero1Training:
             res["asa32"]["final_train_loss"],
             rtol=1e-5,
         )
+
+
+def _exchange_scopes(lowered_text):
+    """The distinct ``exchange_b<i>`` scopes of a lowered program
+    (``.as_text(debug_info=True)``)."""
+    return set(re.findall(r"exchange_b\d+", lowered_text))
+
+
+def _spy_lowered(model):
+    """Make ``model._train_step`` keep the text it lowers to at its
+    first call; returns the one-element list that will hold it."""
+    real, seen = model._train_step, []
+
+    def spy(*args):
+        if not seen:
+            seen.append(real.lower(*args).as_text(debug_info=True))
+        return real(*args)
+
+    model._train_step = spy
+    return seen
+
+
+class TestGroupOfOne:
+    """A replica group of one exchanges nothing (ISSUE 25): the mean
+    over one member is the identity and there is no wire, so no flat
+    buffer, bucket, cast or unpack is traced; every non-zero1 strategy
+    is the same step there.  A group of more than one is untouched."""
+
+    TREE_SHAPES = TestBucketedExchange.TREE_SHAPES     # 196 elements
+    _tree = TestBucketedExchange._tree
+    PACK_OPS = ("concatenate", "reshape", "convert", "slice", "pad")
+
+    def _lowered(self, mesh, tree, strategy, bucket_elems, check_vma):
+        def body(t):
+            if check_vma:
+                # as the Llama step: grads are typed varying over the
+                # data axes and must leave typed invariant
+                t = jax.tree.map(
+                    lambda x: jax.lax.pcast(x, (DATA_AXIS,), to="varying"),
+                    t,
+                )
+            return get_strategy(strategy)(t, DATA_AXIS, bucket_elems)
+
+        fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P(),
+                               out_specs=P(), check_vma=check_vma))
+        return fn, fn.lower(tree).as_text(debug_info=True)
+
+    @pytest.mark.parametrize("check_vma", [True, False])
+    @pytest.mark.parametrize("bucket_elems", [0, 40])
+    @pytest.mark.parametrize(
+        "strategy", sorted(n for n, s in STRATEGIES.items() if not s.zero1)
+    )
+    def test_identity_without_packing(
+        self, devices8, rng, strategy, bucket_elems, check_vma
+    ):
+        tree = self._tree(rng)
+        tree["h"] = tree["b"].astype(jnp.bfloat16)
+        mesh = make_mesh(data=1, devices=devices8[:1])
+        fn, text = self._lowered(
+            mesh, tree, strategy, bucket_elems, check_vma
+        )
+        out = fn(tree)
+        for k in tree:
+            assert out[k].dtype == tree[k].dtype
+            np.testing.assert_array_equal(
+                np.asarray(out[k]), np.asarray(tree[k])
+            )
+        assert not _exchange_scopes(text)
+        assert {op: text.count(f"stablehlo.{op} ")
+                for op in self.PACK_OPS} == dict.fromkeys(self.PACK_OPS, 0)
+
+    @pytest.mark.parametrize("bucket_elems,n_scopes", [(0, 1), (40, 5)])
+    def test_eight_devices_scope_count(
+        self, mesh8, rng, bucket_elems, n_scopes
+    ):
+        """196 elements over 8 replicas: one ``exchange_b0`` per-leaf,
+        five 40-element buckets (200 padded) when bucketed — and the
+        packing the group of one does without is there."""
+        tree = self._tree(rng)
+        _, text = self._lowered(mesh8, tree, "ici16", bucket_elems, False)
+        assert len(_exchange_scopes(text)) == n_scopes
+        assert exchange_bucket_count(196, 8, bucket_elems) == n_scopes
+        assert exchange_bucket_count(196, 1, bucket_elems) == 0
+        assert text.count("stablehlo.convert ") > 0
+        assert bool(text.count("stablehlo.concatenate ")) == bool(
+            bucket_elems
+        )
+
+    def _llama(self, strategy, devices):
+        from theanompi_tpu.models.llama import Llama
+
+        n = len(devices)
+        m = Llama(dict(TestBucketedTraining.LLAMA_CFG, n_train=16 * n,
+                       exch_strategy=strategy, exchange_bucket_mb=0.01))
+        m.build_model(n_replicas=n)
+        m.compile_iter_fns(mesh=make_mesh(data=n, devices=devices))
+        return m
+
+    def _wresnet(self, strategy, devices):
+        from theanompi_tpu.models.wresnet import WResNet
+
+        n = len(devices)
+        m = WResNet({"batch_size": 4, "depth": 10, "widen": 1,
+                     "n_train": 8 * n, "n_val": 4 * n, "n_epochs": 1,
+                     "seed": 7, "exchange_bucket_mb": 0.02})
+        m.build_model(n_replicas=n)
+        m.compile_iter_fns(mesh=make_mesh(data=n, devices=devices),
+                           exch_strategy=strategy)
+        return m
+
+    def _two_steps(self, build, strategy, devices):
+        from theanompi_tpu.utils import Recorder
+
+        m = build(strategy, devices)
+        assert m._bucket_elems > 0
+        text = _spy_lowered(m)
+        rec = Recorder(verbose=False)
+        for i in range(2):
+            m.train_iter(i, rec)
+        rec.flush()
+        return m, text[0], jax.tree.map(np.asarray, m.params)
+
+    @pytest.mark.parametrize("family", ["_llama", "_wresnet"])
+    def test_model_step_on_one_device(self, devices8, family):
+        """The vma-checked Llama step and the unchecked classifier
+        step on one device: ``ici16`` compiles, has no exchange scope
+        and no flat buffer, and after 2 steps its parameters are
+        bitwise ``ici32``'s."""
+        build = getattr(self, family)
+        m, text, p16 = self._two_steps(build, "ici16", devices8[:1])
+        _, _, p32 = self._two_steps(build, "ici32", devices8[:1])
+        for a, b in zip(jax.tree.leaves(p16), jax.tree.leaves(p32)):
+            np.testing.assert_array_equal(a, b)
+        assert (m.exchange_replicas, m.exchange_buckets) == (1, 0)
+        assert not _exchange_scopes(text)
+        size = sum(x.size for x in jax.tree.leaves(p16))
+        padded, bucket_len = flat_layout(size, 1, m._bucket_elems)
+        assert bucket_len                       # it would have bucketed
+        assert f"tensor<{padded}x" not in text
+        assert f"tensor<{bucket_len}x" not in text
+
+    @pytest.mark.parametrize("family", ["_llama", "_wresnet"])
+    def test_model_step_on_eight_devices(self, devices8, family):
+        """The same models on eight devices still bucket, and the
+        summary's count is the number of scopes the step traced."""
+        m, text, params = self._two_steps(
+            getattr(self, family), "ici16", devices8
+        )
+        size = sum(x.size for x in jax.tree.leaves(params))
+        assert m.exchange_replicas == 8
+        assert m.exchange_buckets == exchange_bucket_count(
+            size, 8, m._bucket_elems
+        ) > 1
+        assert len(_exchange_scopes(text)) == m.exchange_buckets
+        assert f"tensor<{flat_layout(size, 8, m._bucket_elems)[1]}x" in text
+
+    @pytest.mark.parametrize("n_devices", [1, 8])
+    def test_worker_summary_counts(self, n_devices):
+        from theanompi_tpu.workers import bsp_worker
+
+        res = bsp_worker.run(
+            devices=list(range(n_devices)),
+            modelfile="theanompi_tpu.models.wresnet",
+            modelclass="WResNet",
+            config={"batch_size": 4, "depth": 10, "widen": 1, "lr": 0.05,
+                    "n_train": 4 * n_devices, "n_val": 4 * n_devices,
+                    "seed": 7, "n_epochs": 1, "exchange_bucket_mb": 0.02},
+            verbose=False, exch_strategy="ici16",
+        )
+        size = sum(x.size for x in jax.tree.leaves(res["model"].params))
+        k = exchange_bucket_count(size, n_devices, res["model"]._bucket_elems)
+        assert res["exchange_replicas"] == n_devices
+        assert res["exchange_buckets"] == k
+        assert (k == 0) if n_devices == 1 else (k > 1)
 
 
 class TestConsistencyCheck:

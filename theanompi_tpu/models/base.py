@@ -69,6 +69,11 @@ class TMModel:
     #: EF residual of a compressed exchange (empty when off); models
     #: that compile one overwrite this with device state
     ef_state: PyTree = {}
+    #: size of the replica group the gradient exchange reduces over,
+    #: and how many ``exchange_b*`` bodies the compiled step traced (0
+    #: when a group of one exchanges nothing); set by compile_iter_fns
+    exchange_replicas: int | None = None
+    exchange_buckets: int | None = None
 
     def build_model(self, n_replicas: int = 1) -> None:
         raise NotImplementedError
@@ -677,7 +682,10 @@ class ClassifierModel(TMModel):
             resolve_bucket_mb,
             resolve_compression,
         )
-        from theanompi_tpu.parallel.exchange import flat_layout
+        from theanompi_tpu.parallel.exchange import (
+            exchange_bucket_count,
+            flat_layout,
+        )
 
         bucket_elems = strat.bucket_elems(resolve_bucket_mb(self.config))
         self._bucket_elems = bucket_elems
@@ -702,6 +710,10 @@ class ClassifierModel(TMModel):
             math.prod(jnp.shape(l)) for l in jax.tree.leaves(self.params)
         )
         eff_bucket_len = flat_layout(n_elems, n_dp, bucket_elems)[1]
+        self.exchange_replicas = n_dp
+        self.exchange_buckets = exchange_bucket_count(
+            n_elems, n_dp, bucket_elems, flat=fspec is not None
+        )
         self._zero1_layout = (
             (zspec.padded, zspec.bucket_len) if strat.zero1 else None
         )

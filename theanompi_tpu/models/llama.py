@@ -705,7 +705,10 @@ class Llama(TMModel):
         # in-step flat_spec, the zero1 state sizing, and the overlap
         # gate below must all agree; tiny models degrade to
         # monolithic).  Shape-only eval, no compute.
-        from theanompi_tpu.parallel.exchange import flat_layout
+        from theanompi_tpu.parallel.exchange import (
+            exchange_bucket_count,
+            flat_layout,
+        )
 
         shapes = jax.eval_shape(
             self._init_full_params, jax.random.PRNGKey(0)
@@ -735,6 +738,12 @@ class Llama(TMModel):
             local_size, n_dp, bucket_elems
         )
         self._zero1_layout = (z_padded, z_bucket_len) if zero1 else None
+        self.exchange_replicas = n_dp
+        # the MoE exchange stays per-leaf (see step below)
+        self.exchange_buckets = exchange_bucket_count(
+            local_size, n_dp, 0 if self.n_experts else bucket_elems,
+            flat=bool(zero1 or comp),
+        )
         if zero1:
             if self.n_experts:
                 raise NotImplementedError(

@@ -83,11 +83,23 @@ def allreduce_mean(
     per-collective launches), large buffers split (earlier first
     dispatch).  When the tree fits in a single bucket the per-leaf
     monolithic path below runs unchanged.
+
+    A replica group of ONE exchanges nothing: the mean over one
+    member is the identity and a wire dtype describes bytes on a wire
+    that is not there, so no flat buffer, bucket, cast or unpack is
+    traced — every leaf comes back bitwise as it went in, whatever
+    ``wire_dtype``, ``two_phase`` and ``bucket_elems`` say.
     """
     axes = axis_name if isinstance(axis_name, tuple) else (axis_name,)
     n = 1
     for a in axes:
         n *= lax.axis_size(a)
+
+    if n == 1:
+        # the psum over the one-member group only re-types the leaf
+        # from varying to invariant for a vma-checked shard_map (the
+        # Llama step); XLA deletes it
+        return jax.tree.map(lambda x: lax.psum(x, axes), tree)
 
     if bucket_elems:
         spec = flat_spec(tree, n, bucket_elems=bucket_elems)
@@ -230,6 +242,21 @@ def flat_layout(size: int, n_shards: int,
     if bucket_len >= padded:
         return padded, 0              # one bucket = the monolithic path
     return -(-size // bucket_len) * bucket_len, bucket_len
+
+
+def exchange_bucket_count(size: int, n_replicas: int,
+                          bucket_elems: int = 0, *,
+                          flat: bool = False) -> int:
+    """How many ``exchange_b*`` bodies the gradient exchange of a
+    ``size``-element tree over ``n_replicas`` traces — the run
+    summary's ``exchange_buckets``: 0 when ``allreduce_mean`` meets a
+    group of one (nothing is exchanged), 1 for its per-leaf path, the
+    bucket count when bucketed.  ``flat``: the zero1 and compressed
+    exchanges, which pack at any group size."""
+    if n_replicas == 1 and not flat:
+        return 0
+    padded, bucket_len = flat_layout(size, n_replicas, bucket_elems)
+    return padded // bucket_len if bucket_len else 1
 
 
 def flat_spec(tree: PyTree, n_shards: int, dtype=None,

@@ -48,6 +48,10 @@ class ExchangeStrategy:
     config knob) buckets the exchange buffer so per-bucket collectives
     overlap with compute — see ``exchange.allreduce_mean`` /
     ``scatter_update_gather``; 0 keeps the monolithic exchange.
+
+    A replica group of one exchanges nothing (``allreduce_mean``
+    returns its input), so there all non-zero1 strategies are the
+    same step.
     """
 
     name: str

@@ -600,6 +600,8 @@ def run(
         "epochs": model.epoch,
         "exch_strategy": strat.name,
         "exchange_bucket_mb": bucket_mb,
+        "exchange_replicas": getattr(model, "exchange_replicas", None),
+        "exchange_buckets": getattr(model, "exchange_buckets", None),
         "exch_compression": compression or "none",
         "error_feedback": bool(compression) and error_feedback,
         "iterations": recorder.n_iter,
