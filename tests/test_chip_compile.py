@@ -97,3 +97,48 @@ def test_paged_attend_compiles_for_v5e(chip, dtype, hkv, rep, hd, nq):
         sds((slots, mb), jnp.int32), sds((slots, nq), jnp.int32),
     )
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_dropless_expert_layer_compiles_to_grouped_kernels(chip, direction):
+    """OLMoE's widths (2048 wide, 64 experts of 1024, 8 picks) at one
+    sequence of 4096: the v5e compiler must turn every grouped product
+    of ``moe_ffn``'s dropless path into a Mosaic kernel whose work
+    follows the rows — three forward, nine with the backward — and
+    keep no ``[E, N, D]`` capacity buffer and no product over all 64
+    experts for every row (the 8x this path exists to avoid)."""
+    import re
+
+    from theanompi_tpu.parallel.moe import moe_ffn
+
+    e, k, d, f, n = 64, 8, 2048, 1024, 4096
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def forward(x, router, wg, wu, wd):
+        y, aux = moe_ffn(
+            x, router, wg, wu, wd, n_experts=e, top_k=k,
+            capacity_factor=None, expert_axis=None, model_axis=None,
+            renormalize=False,
+        )
+        return jnp.sum(y.astype(jnp.float32)) + aux["lb"] + aux["z"]
+
+    fn = forward if direction == "forward" else jax.value_and_grad(
+        forward, argnums=(0, 1, 2, 3, 4)
+    )
+    text = _compiled_text(
+        fn, sds((1, n, d), jnp.bfloat16), sds((d, e), jnp.float32),
+        sds((e, d, f), jnp.float32), sds((e, d, f), jnp.float32),
+        sds((e, f, d), jnp.float32),
+    )
+    products = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and "ragged-dot" in line
+        and "ragged-dot-metadata" not in line.split("=", 1)[0]
+    ]
+    assert len(products) == (3 if direction == "forward" else 9)
+    # sorted rows [k*N, .] in, never a per-expert copy of the tokens
+    assert all(re.search(rf"\[({k * n},({d}|{f})|{e},\d+,\d+)\]", p)
+               for p in products)
+    assert not re.search(rf"\[{e},{n},{d}\]|\[{e},{k * n},", text)

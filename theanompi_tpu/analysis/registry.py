@@ -129,6 +129,12 @@ PROFILE_SCOPES: dict[str, str] = {
     # host→device batch staging (data/pipeline.py HostStager, PR 16):
     # the residual feed cost the streaming loader can't hide
     "host_load": "host_load",
+    # the four parts of the expert layer (parallel/moe.py, PR 26);
+    # benchmark/layer_metrics/_moe.py reads the same labels
+    "moe_route": "moe_route",
+    "moe_dispatch": "moe_dispatch",
+    "moe_experts": "moe_experts",
+    "moe_combine": "moe_combine",
 }
 
 #: label PREFIX -> leg family: labels carrying a per-instance index

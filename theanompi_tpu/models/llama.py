@@ -28,7 +28,10 @@ sequence parallelism, which shape this model's design:
   transpose already accumulated the ep group's token cotangents at
   each owner); everything else averages over ``(expert, data)`` —
   both through the configured wire strategy.  Knobs: ``n_experts,
-  moe_top_k, capacity_factor, ep, moe_aux_coef, moe_z_coef``.
+  moe_top_k, capacity_factor, ep, moe_aux_coef, moe_z_coef,
+  moe_renormalize``.  ``capacity_factor: null`` is the DROPLESS path
+  (every pick computed by grouped products over expert-sorted rows;
+  ``ep == 1``), a number the capacity buffers that drop.
 
 The WHOLE train step — embed, L layers, loss, backward, optimizer —
 is ONE vma-checked ``shard_map`` under ``jit``: XLA overlaps the TP
@@ -42,9 +45,11 @@ bounds activation memory for long sequences.  Params are initialized
 set never materializes on one device.
 
 Architecture per Llama-3: RMSNorm, RoPE, grouped-query attention,
-SwiGLU MLP, untied LM head.  The model satisfies the same worker
-contract as every zoo member, so ``BSP().init(modelfile=
-'theanompi_tpu.models.llama', modelclass='Llama')`` trains it.
+SwiGLU MLP, untied LM head; ``qk_norm`` adds an RMSNorm over the whole
+projected width of q and of k, before the head split and RoPE.  The
+model satisfies the same worker contract as every zoo member, so
+``BSP().init(modelfile='theanompi_tpu.models.llama',
+modelclass='Llama')`` trains it.
 """
 
 from __future__ import annotations
@@ -89,9 +94,19 @@ PyTree = Any
 
 # -- pure model math (runs on LOCAL shards inside shard_map) ----------------
 
-def rms_norm(x, w, eps=1e-5):
+def rms_norm(x, w, eps=1e-5, sharded_width=None):
+    """RMSNorm over the last dimension.  ``sharded_width``: that
+    dimension is the local shard of ``sharded_width`` channels
+    column-sharded over the model axis, and the statistic is over all
+    of them (QK-norm: the whole projection, not a head or a shard)."""
     xf = x.astype(jnp.float32)
-    scale = lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    if sharded_width is None:
+        ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    else:
+        ms = lax.psum(
+            jnp.sum(xf * xf, axis=-1, keepdims=True), MODEL_AXIS
+        ) / sharded_width
+    scale = lax.rsqrt(ms + eps)
     return (xf * scale).astype(x.dtype) * w.astype(x.dtype)
 
 
@@ -139,7 +154,13 @@ class Llama(TMModel):
     Config knobs: ``dim, n_layers, n_heads, n_kv_heads, ffn_dim,
     vocab, seq_len, batch_size, lr, tp, sp, remat, compute_dtype``.
     ``tp``/``sp`` set the model/seq mesh axis sizes; remaining devices
-    form the data axis.
+    form the data axis.  Architecture switches: ``qk_norm`` (bool,
+    off: learned RMSNorm of the full q and k projections); with
+    ``n_experts > 0`` the FFN is a mixture of experts of width
+    ``ffn_dim`` each: ``moe_top_k``, ``moe_renormalize`` (bool, on:
+    the picked gates rescaled to sum to one), ``capacity_factor`` (a
+    number: capacity buffers that drop; ``null``: dropless),
+    ``moe_aux_coef``, ``moe_z_coef``, ``ep``.
     """
 
     def __init__(self, config: dict | None = None):
@@ -159,7 +180,11 @@ class Llama(TMModel):
         # MoE knobs: n_experts=0 keeps the dense SwiGLU FFN
         self.n_experts = int(c.get("n_experts", 0))
         self.moe_top_k = int(c.get("moe_top_k", 2))
-        self.capacity_factor = float(c.get("capacity_factor", 1.25))
+        # a number sizes capacity buffers that drop; None is dropless
+        cf = c.get("capacity_factor", 1.25)
+        self.capacity_factor = None if cf is None else float(cf)
+        self.moe_renormalize = bool(c.get("moe_renormalize", True))
+        self.qk_norm = bool(c.get("qk_norm", False))
         self.ep = int(c.get("ep", 1))
         self.moe_aux_coef = float(c.get("moe_aux_coef", 0.01))
         self.moe_z_coef = float(c.get("moe_z_coef", 0.0))
@@ -209,6 +234,13 @@ class Llama(TMModel):
                 f"n_experts {self.n_experts} must divide by ep {self.ep}"
             )
             assert 0 < self.moe_top_k <= self.n_experts
+            if self.capacity_factor is None and self.ep > 1:
+                raise NotImplementedError(
+                    "dropless MoE (capacity_factor: null) does not yet "
+                    "compose with expert parallelism (ep > 1): the "
+                    "sorted rows of an expert would cross chips in a "
+                    "ragged all-to-all; give a capacity_factor or ep=1"
+                )
         else:
             assert self.ep == 1, "ep > 1 requires n_experts > 0"
         if self.pp > 1:
@@ -251,6 +283,9 @@ class Llama(TMModel):
             "wo": P(MODEL_AXIS, None),
             "mlp_norm": P(None),
         }
+        if self.qk_norm:
+            # over the whole projected width: sharded as its columns
+            layer.update({"q_norm": P(MODEL_AXIS), "k_norm": P(MODEL_AXIS)})
         if self.n_experts:
             # experts sharded over the expert axis, FFN dim over model
             layer.update({
@@ -297,6 +332,9 @@ class Llama(TMModel):
                 "wo": dense(next(keys), (self.n_heads * hd, d)),
                 "mlp_norm": jnp.ones((d,)),
             }
+            if self.qk_norm:
+                lp["q_norm"] = jnp.ones((self.n_heads * hd,))
+                lp["k_norm"] = jnp.ones((self.n_kv_heads * hd,))
             if self.n_experts:
                 e = self.n_experts
                 # per-expert fan-in/out scales (the generic shape-based
@@ -340,18 +378,23 @@ class Llama(TMModel):
         """One decoder block on local shards: x [B, T_loc, D].
 
         With MoE enabled returns ``(x, mom)`` where ``mom`` is the
-        fp32 [2E+1] vector of this layer's aux-loss MOMENTS
-        (pick fractions f, mean router probs p, z-loss) — kept linear
-        so microbatch splits average exactly; ``_aux_from_moments``
-        forms the losses.  Dense blocks return just ``x``."""
+        fp32 [2E+2] vector of this layer's aux-loss MOMENTS
+        (pick fractions f, mean router probs p, z-loss) and its count
+        of dropped picks — kept linear so microbatch splits average
+        exactly; ``_aux_from_moments`` forms the losses.  Dense blocks
+        return just ``x``."""
         cdtype = self.compute_dtype
         h_loc = self.n_heads // self.tp
         hkv_loc = self.n_kv_heads // self.tp
         hd = self.head_dim
 
         xn = rms_norm(x, p["attn_norm"])
-        q = _heads(tp_lib.col_parallel(xn, p["wq"]), h_loc, hd)
-        k = _heads(tp_lib.col_parallel(xn, p["wk"]), hkv_loc, hd)
+        q = tp_lib.col_parallel(xn, p["wq"])
+        k = tp_lib.col_parallel(xn, p["wk"])
+        if self.qk_norm:
+            q = rms_norm(q, p["q_norm"], sharded_width=self.n_heads * hd)
+            k = rms_norm(k, p["k_norm"], sharded_width=self.n_kv_heads * hd)
+        q, k = _heads(q, h_loc, hd), _heads(k, hkv_loc, hd)
         v = _heads(tp_lib.col_parallel(xn, p["wv"]), hkv_loc, hd)
         q = rope(q, pos)
         k = rope(k, pos)
@@ -388,9 +431,10 @@ class Llama(TMModel):
                 # aux losses globalize over the token-sharding axes
                 # (layout-invariant; set in compile_iter_fns)
                 batch_axes=(*self._dp_axes, SEQ_AXIS),
+                renormalize=self.moe_renormalize,
             )
             mom = jnp.concatenate(
-                [aux["f"], aux["p"], aux["z"][None]]
+                [aux["f"], aux["p"], aux["z"][None], aux["dropped"][None]]
             ).astype(jnp.float32)
             return x + y.astype(cdtype), mom
         gate = jax.nn.silu(tp_lib.col_parallel(xn, p["w_gate"]))
@@ -411,7 +455,8 @@ class Llama(TMModel):
 
         ``with_aux=True`` (train loss path) additionally returns the
         MoE aux pair [lb, z], averaged over layers and pipe-broadcast
-        (zeros when the model is dense)."""
+        (zeros when the model is dense), and the routing counters
+        ``[L, E+1]`` of ``_routing_counters`` (None when dense)."""
         cdtype = self.compute_dtype
         t_loc = ids.shape[1]
         seq_idx = lax.axis_index(SEQ_AXIS)
@@ -436,6 +481,7 @@ class Llama(TMModel):
 
         moe = bool(self.n_experts)
         aux = jnp.zeros((2,), jnp.float32)
+        routing = None
         if self.pp == 1:
             moms = []
             for p in params["layers"]:
@@ -445,7 +491,9 @@ class Llama(TMModel):
                 else:
                     x = layer(p, x, pos)
             if moe:
-                aux = self._aux_from_moments(jnp.stack(moms))
+                moms = jnp.stack(moms)
+                aux = self._aux_from_moments(moms)
+                routing = self._routing_counters(moms)
         else:
             # GPipe over the pipe axis: the embed above is replicated
             # compute (only stage 0's copy feeds the chain — backward
@@ -490,7 +538,7 @@ class Llama(TMModel):
                         (
                             self.pp_microbatches,
                             self.n_layers,
-                            2 * self.n_experts + 1,
+                            2 * self.n_experts + 2,
                         ),
                         jnp.float32,
                     ),
@@ -502,6 +550,10 @@ class Llama(TMModel):
                 # exactly the pp=1 numbers, any microbatch count
                 mom = last_stage_value(jnp.mean(ys["aux"], axis=0))
                 aux = self._aux_from_moments(mom)
+                # (a count summed, not averaged, over the microbatches)
+                routing = self._routing_counters(mom).at[:, -1].multiply(
+                    self.pp_microbatches
+                )
                 ys = ys["x"]
             x = merge_microbatches(ys)
             if self._pp_scatter:
@@ -520,7 +572,7 @@ class Llama(TMModel):
 
         x = rms_norm(x, params["final_norm"])
         if not head:
-            return (x, aux) if with_aux else x
+            return (x, aux, routing) if with_aux else x
         # logits stay in compute dtype: the xent/metric reductions
         # upcast to fp32 INSIDE their fused reads (tp.py), so an
         # .astype(f32) here would only materialize a second, 2x-wide
@@ -528,10 +580,19 @@ class Llama(TMModel):
         # proxy).  Same values either way — the matmul already ran in
         # compute dtype.
         logits = tp_lib.col_parallel(x, params["lm_head"])
-        return (logits, aux) if with_aux else logits
+        return (logits, aux, routing) if with_aux else logits
+
+    def _routing_counters(self, moms):
+        """[L, 2E+2] per-layer moments -> the step's routing counters
+        ``[L, E+1]``: each expert's share ``f`` of the picks (1/E at
+        balance) and, last, the picks no expert computed."""
+        e = self.n_experts
+        return lax.stop_gradient(
+            jnp.concatenate([moms[:, :e], moms[:, -1:]], axis=1)
+        )
 
     def _aux_from_moments(self, moms):
-        """[L, 2E+1] per-layer aux moments (f, p, z — see ``_layer``)
+        """[L, 2E+2] per-layer aux moments (f, p, z — see ``_layer``)
         -> fp32 [load-balance loss, z-loss], layer-averaged.  The
         product ``E·Σ f·p`` forms HERE, after any microbatch
         averaging, so pipeline microbatching never changes the loss."""
@@ -876,10 +937,12 @@ class Llama(TMModel):
                 # of autodiff (see cast above); SP/TP reductions remain
                 # part of the model math
                 yv = self._pp_targets(y)
+                routing = ()
                 if self.n_experts:
-                    h, aux = self._forward(
+                    h, aux, counters = self._forward(
                         p, x, head=False, with_aux=True
                     )
+                    routing = (counters,)
                 else:
                     h = self._forward(p, x, head=False)
                 h2 = h.reshape(-1, h.shape[-1])
@@ -913,13 +976,14 @@ class Llama(TMModel):
                         + self.moe_aux_coef * aux[0]
                         + self.moe_z_coef * aux[1]
                     )
-                return loss, err
+                return loss, (err, *routing)
 
             # check_vma=True autodiff returns exact grads for the TP/SP
             # layout (psum↔pvary transposes); the data-parallel mean is
             # THE exchange, routed through the strategy (bf16 wire on
             # ici16/nccl16 — reference: exchanger_strategy fp16 wire)
-            (loss, err), grads = jax.value_and_grad(
+            # (MoE: the step's routing counters ride out with the error)
+            (loss, (err, *routing)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(params_v)
             if self.n_experts:
@@ -996,7 +1060,7 @@ class Llama(TMModel):
                     )
             loss = lax.pmean(loss, dp_axes)
             err = lax.pmean(err, dp_axes)
-            return params, opt_state, ef, loss, err
+            return params, opt_state, ef, loss, err, *routing
 
         def val(params, x, y):
             logits = self._forward(params, x)
@@ -1012,6 +1076,8 @@ class Llama(TMModel):
         from theanompi_tpu.utils.xla_options import xla_compiler_options
 
         is_tpu = mesh.devices.flat[0].platform == "tpu"
+        # a MoE step also gives out its routing counters [L, E+1]
+        moe_out = self._moe_out_specs = (P(),) if self.n_experts else ()
         self._compiler_options = xla_compiler_options(
             self.config,
             overlap=bool(z_bucket_len) and not self.n_experts and is_tpu,
@@ -1022,7 +1088,7 @@ class Llama(TMModel):
                 mesh=mesh,
                 in_specs=(specs, opt_specs, ef_specs, batch_spec,
                           batch_spec, P()),
-                out_specs=(specs, opt_specs, ef_specs, P(), P()),
+                out_specs=(specs, opt_specs, ef_specs, P(), P(), *moe_out),
             ),
             donate_argnums=(0, 1, 2),
             compiler_options=self._compiler_options,
@@ -1156,6 +1222,7 @@ class Llama(TMModel):
 
         d_size = self.mesh.shape[DATA_AXIS]
         has_exp = EXPERT_AXIS in self.mesh.shape
+        moe_out = self._moe_out_specs
 
         def make_scan(length: int):
             def scan_steps(params, opt_state, ef, step, seqs, perm, lr):
@@ -1180,16 +1247,17 @@ class Llama(TMModel):
                     y = lax.dynamic_slice(
                         rows, (0, sme * t_loc + 1), (b_loc, t_loc)
                     )
-                    params, opt_state, ef, loss, err = shard_step(
+                    params, opt_state, ef, *per_step = shard_step(
                         params, opt_state, ef, x, y, lr
                     )
-                    return (params, opt_state, ef, st + 1), (loss, err)
+                    return (params, opt_state, ef, st + 1), tuple(per_step)
 
-                (params, opt_state, ef, step), (losses, errs) = lax.scan(
+                # per step: loss, err and, for a MoE, routing counters
+                (params, opt_state, ef, step), per_step = lax.scan(
                     body, (params, opt_state, ef, step), None,
                     length=length,
                 )
-                return params, opt_state, ef, step, losses, errs
+                return params, opt_state, ef, step, *per_step
 
             return jax.jit(
                 jax.shard_map(
@@ -1198,7 +1266,7 @@ class Llama(TMModel):
                     in_specs=(specs, opt_specs, ef_specs,
                               P(), P(), P(), P()),
                     out_specs=(specs, opt_specs, ef_specs,
-                               P(), P(), P()),
+                               P(), P(), P(), *moe_out),
                 ),
                 donate_argnums=(0, 1, 2, 3),
                 compiler_options=self._compiler_options,
@@ -1234,12 +1302,23 @@ class Llama(TMModel):
                 self._step_dev,
                 losses,
                 errs,
+                *routing,
             ) = scan_fn(
                 self.params, self.opt_state, self.ef_state,
                 self._step_dev, self._seqs_dev, self._perm_dev,
                 self._lr_dev,
             )
         recorder.train_error(count, losses, errs)
+        self._record_routing(recorder, routing)
+
+    def _record_routing(self, recorder: Recorder, routing) -> None:
+        """Hand a MoE step's routing counters (device values; read
+        with the loss at the recorder's next fence) to the recorder."""
+        if routing:
+            recorder.moe_routing(
+                routing[0],
+                picks=self.data.global_batch * self.seq_len * self.moe_top_k,
+            )
 
     def train_chunk(self, count: int, k: int, recorder: Recorder) -> None:
         if k == self._scan_k and self._train_scan is not None:
@@ -1314,6 +1393,7 @@ class Llama(TMModel):
                 self.ef_state,
                 loss,
                 err,
+                *routing,
             ) = self._train_step(
                 self.params, self.opt_state, self.ef_state, x, y,
                 jnp.float32(self.current_lr),
@@ -1321,6 +1401,7 @@ class Llama(TMModel):
         # device scalars, materialized lazily at the next print window
         # or epoch end (Recorder.flush) — no per-step host fence
         recorder.train_error(count, loss, err)
+        self._record_routing(recorder, routing)
 
     def val_iter(self, count: int, recorder: Recorder):
         x, y = self.put_batch(self.data.val_batch(count))

@@ -2,8 +2,9 @@
 flight-recorder, Perfetto export with counter tracks, critical-path
 attribution), the step-phase profiler (``profiler.py``), the bench
 regression gate (``regress.py``), Prometheus-style metrics text, the
-process's compile counter (``compile_meter.py``) and a training run's
-set-up phases (``setup.py``).
+process's compile counter (``compile_meter.py``), a training run's
+set-up phases (``setup.py``) and a MoE step's routing counters
+(``routing.py``).
 See docs/OBSERVABILITY.md and docs/PERFORMANCE.md."""
 
 from theanompi_tpu.obs.tracer import (  # noqa: F401
@@ -23,6 +24,7 @@ from theanompi_tpu.obs.setup import (  # noqa: F401
     last_setup_phases,
     setup_phase,
 )
+from theanompi_tpu.obs.routing import last_moe_counters  # noqa: F401
 from theanompi_tpu.obs.export import (  # noqa: F401
     chrome_trace,
     critical_path,
@@ -56,6 +58,7 @@ __all__ = [
     "format_critical_path",
     "format_profile",
     "gap_attribution",
+    "last_moe_counters",
     "last_setup_phases",
     "make_context",
     "process_meter",

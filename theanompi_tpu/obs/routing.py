@@ -1,0 +1,50 @@
+"""Routing counters of a mixture-of-experts training step.
+
+A MoE ``Llama`` step gives out, beside its loss, ``[L, E+1]`` numbers
+a layer: each expert's share ``f`` of the step's (token, pick) rows
+(1/E at balance; the ``f`` of the load-balance loss) and the picks no
+expert computed (0 on the dropless path by construction, counted on
+the capacity path).  ``Recorder.moe_routing`` holds the device value
+and reads it with the losses at its next fence — no fence and no host
+sync of its own — then keeps the LAST step's counters here:
+
+- ``moe_rows_per_expert`` ``[L][E]`` — routed rows of that step;
+- ``moe_load_max_over_mean`` — the fullest expert's rows over the
+  mean, worst layer (1.0 at balance; the grouped products' tail and,
+  with capacity buffers, the drops follow it);
+- ``moe_dropped_picks`` — summed over the layers.
+
+The run summary carries them (``"moe_counters"``) and they stay
+readable afterwards with :func:`last_moe_counters`.  Names are a
+contract (docs/OBSERVABILITY.md).
+"""
+
+from __future__ import annotations
+
+import numpy as np
+
+_LAST: dict | None = None
+
+
+def moe_counters(routing, picks: int) -> dict:
+    """``routing [L, E+1]`` (see the module docstring) of one step of
+    ``picks`` (token, pick) rows a layer -> the counters' dict; also
+    kept as the process's newest."""
+    global _LAST
+    a = np.asarray(routing, np.float64)
+    share, dropped = a[:, :-1], a[:, -1]
+    _LAST = {
+        "moe_picks_per_step": int(picks),
+        "moe_rows_per_expert": np.rint(share * picks).astype(int).tolist(),
+        "moe_load_max_over_mean": float(
+            np.max(share.max(axis=1) / share.mean(axis=1))
+        ),
+        "moe_dropped_picks": int(round(float(dropped.sum()))),
+    }
+    return _LAST
+
+
+def last_moe_counters() -> dict | None:
+    """The routing counters of the newest fenced MoE step of this
+    process, or None before any (a dense model never has one)."""
+    return _LAST

@@ -31,6 +31,29 @@ Design:
   router z-loss ``mean(logsumexp(logits)²)``, returned separately so
   the model applies its own coefficients.
 
+- **Dropless dispatch** (``capacity_factor=None``; ``ep == 1``): the
+  published fine-grained models (64 experts, 8 picks) compute every
+  pick, and zero drops on the capacity path would need ``C = N`` —
+  an [E, N, D] buffer and E/k times the useful products.  Instead
+  the ``k·N`` (token, pick) rows are sorted by expert id (stable, so
+  slot-major inside an expert: the result does not depend on a
+  tie-break), the rows gathered in that order, and the three SwiGLU
+  products run as GROUPED products (``lax.ragged_dot`` with the
+  per-expert row counts; the v5e compiler makes each a Mosaic kernel
+  whose work follows the rows, forward and both backward products —
+  PERF.md, PR 26).  Each row's gate multiplies its hidden activations
+  in fp32 inside the ``silu·up`` fusion (the down product is linear),
+  so the combine is the plain fp32 sum of a token's ``k`` rows, read
+  through the inverse permutation.  Dispatch (token rows out to
+  expert order) and combine (expert order back, summed) are each
+  other's transpose and carry each other as backward rule: gathers
+  both ways, never a scatter-add.
+- **Spans**: ``jax.named_scope``s ``moe_route`` (router, top-k, aux
+  moments), ``moe_dispatch`` (plan + row gather / capacity buffers),
+  ``moe_experts`` (the products) and ``moe_combine`` (un-permute,
+  gates) are in the metadata of every instruction of the layer,
+  forward and backward (docs/OBSERVABILITY.md).
+
 Capacity per device-expert is ``C = ceil(cf · k · N / E)`` rounded up
 to a multiple of 8 (TPU sublane) where ``N`` is the LOCAL token count:
 drops are layout-dependent exactly as in GShard (each shard ranks its
@@ -39,6 +62,8 @@ setting the cross-layout invariance tests use.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -110,6 +135,185 @@ def router_z_loss(logits, batch_axes=()):
     return lax.pmean(z, batch_axes) if batch_axes else z
 
 
+def _swiglu_experts(rows, we_gate, we_up, we_down, product, row_scale=None):
+    """The three expert products on rows already laid out for
+    ``product(lhs, w)`` (batched over capacity buffers, or grouped
+    over sorted rows).  ``row_scale`` (fp32, one per row) multiplies
+    the hidden activations inside their own fusion: the down product
+    is linear, so scaling its input rows scales its output rows."""
+    dt = rows.dtype
+    g = product(rows, we_gate.astype(dt))
+    u = product(rows, we_up.astype(dt))
+    h = jax.nn.silu(g) * u
+    if row_scale is not None:
+        h = (h.astype(jnp.float32) * row_scale[:, None]).astype(dt)
+    return product(h, we_down.astype(dt))
+
+
+# -- dropless: sorted rows, grouped products -------------------------------
+#
+# ``order`` lists the slot-major picks (pick ``j*N + t`` is token t's
+# j-th choice) in expert order; ``inv`` is its inverse permutation.
+# Dispatch and combine are each other's transpose, and each carries
+# the other as its backward rule: a gather by a permutation and a
+# gather from the N token rows, never the scatter-add autodiff would
+# write.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gather_sorted(x2, order, inv, k):
+    """``x2 [N, D]`` -> the ``k·N`` rows in expert order: row ``i`` is
+    token ``order[i] % N``."""
+    return x2[order % x2.shape[0]]
+
+
+def _gather_sorted_fwd(x2, order, inv, k):
+    return _gather_sorted(x2, order, inv, k), (order, inv)
+
+
+def _gather_sorted_bwd(k, res, ct):
+    with jax.named_scope("moe_dispatch"):
+        return _sum_picks(ct, *res, k).astype(ct.dtype), None, None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _sum_picks(rows, order, inv, k):
+    """Rows in expert order ``[k·N, D]`` -> each token's ``k`` rows
+    summed in fp32, ``[N, D]``."""
+    return jnp.sum(
+        rows[inv].reshape(k, -1, rows.shape[-1]).astype(jnp.float32), axis=0
+    )
+
+
+def _sum_picks_fwd(rows, order, inv, k):
+    # (an empty slice carries the rows' dtype to the backward rule)
+    return _sum_picks(rows, order, inv, k), (order, inv, rows[:0])
+
+
+def _sum_picks_bwd(k, res, ct):
+    order, inv, like = res
+    with jax.named_scope("moe_combine"):
+        return _gather_sorted(ct.astype(like.dtype), order, inv, k), None, None
+
+
+_gather_sorted.defvjp(_gather_sorted_fwd, _gather_sorted_bwd)
+_sum_picks.defvjp(_sum_picks_fwd, _sum_picks_bwd)
+
+
+@jax.custom_vjp
+def _permute(v, perm, inv_perm):
+    """``v[perm]`` for a permutation, with its inverse at hand."""
+    return v[perm]
+
+
+_permute.defvjp(
+    lambda v, perm, inv_perm: (v[perm], inv_perm),
+    lambda inv_perm, ct: (ct[inv_perm], None, None),
+)
+
+
+def _dropless_experts(x2, gates, eidx, we_gate, we_up, we_down, *,
+                      n_experts: int, model_axis):
+    """Every pick computed: sort, gather, grouped SwiGLU with each
+    row's gate folded into its hidden activations, and the sum of a
+    token's rows.  Returns ``y [N, D]`` fp32."""
+    n, _ = x2.shape
+    k = eidx.shape[1]
+    with jax.named_scope("moe_dispatch"):
+        flat_e = eidx.T.reshape(-1).astype(jnp.int32)     # slot-major [k*N]
+        picks = jnp.arange(k * n, dtype=jnp.int32)
+        # stable: inside an expert the rows stay slot-major, so the
+        # order (and the sums below) never hang on a tie-break
+        _, order = lax.sort((flat_e, picks), num_keys=1, is_stable=True)
+        _, inv = lax.sort((order, picks), num_keys=1)
+        group_sizes = jnp.sum(
+            flat_e[:, None] == jnp.arange(n_experts, dtype=jnp.int32)[None],
+            axis=0, dtype=jnp.int32,
+        )
+        rows = _gather_sorted(x2, order, inv, k)          # [k*N, D]
+        row_gate = _permute(gates.T.reshape(-1), order, inv)
+    with jax.named_scope("moe_experts"):
+        out = _swiglu_experts(
+            rows, we_gate, we_up, we_down,
+            lambda lhs, w: lax.ragged_dot(lhs, w, group_sizes),
+            row_scale=row_gate,
+        )
+        if model_axis is not None:
+            out = lax.psum(out, model_axis)               # close row-parallel
+    with jax.named_scope("moe_combine"):
+        return _sum_picks(out, order, inv, k)
+
+
+# -- capacity: fixed buffers, drops ------------------------------------------
+
+def _capacity_experts(x2, gates, eidx, we_gate, we_up, we_down, *,
+                      n_experts: int, capacity: int, ep: int, expert_axis,
+                      model_axis):
+    """GShard/Switch dispatch into ``[E, C, D]`` buffers; picks beyond
+    an expert's capacity are dropped.  Returns ``(y [N, D] fp32,
+    dropped picks of this shard)``."""
+    n, d = x2.shape
+    top_k = eidx.shape[1]
+    e, c = n_experts, capacity
+    with jax.named_scope("moe_dispatch"):
+        # -- slot-major dispatch plan (all int32, one cumsum) --------------
+        # slot-major flatten: slot j's block holds every token's j-th
+        # pick, so capacity ranks all 1st choices before any 2nd choice
+        flat_e = eidx.T.reshape(-1)                       # [k*N]
+        onehot = (
+            flat_e[:, None] == jnp.arange(e, dtype=flat_e.dtype)[None, :]
+        ).astype(jnp.int32)                               # [k*N, E]
+        pos = jnp.take_along_axis(
+            jnp.cumsum(onehot, axis=0) - 1, flat_e[:, None], axis=1
+        )[:, 0]                                           # rank within expert
+        keep = pos < c
+        dest = jnp.where(keep, flat_e * c + pos, e * c)   # e*c = drop sentinel
+        tok = jnp.arange(top_k * n, dtype=jnp.int32) % n  # slot-major token
+
+        # inverse plan: which token fills each (expert, capacity) slot
+        # (0 = empty; only the sentinel slot ever collides)
+        src = jnp.zeros((e * c + 1,), jnp.int32).at[dest].set(tok + 1)
+        src = src[: e * c]
+        filled = src > 0
+        buf = jnp.where(
+            filled[:, None],
+            x2[jnp.maximum(src - 1, 0)],
+            jnp.zeros((), x2.dtype),
+        ).reshape(e, c, d)
+
+        # -- ship buffers to the expert owners -----------------------------
+        if ep > 1:
+            # [E, C, D] -> [E/ep, ep*C, D]: each device keeps its own
+            # experts' rows from every peer in the expert group
+            buf = lax.all_to_all(
+                buf, expert_axis, split_axis=0, concat_axis=1, tiled=True
+            )
+
+    # -- expert SwiGLU (batched matmuls; TP over the FFN dim) --------------
+    with jax.named_scope("moe_experts"):
+        out = _swiglu_experts(
+            buf, we_gate, we_up, we_down,
+            lambda lhs, w: jnp.einsum("ecd,edf->ecf", lhs, w),
+        )
+        if model_axis is not None:
+            out = lax.psum(out, model_axis)               # close row-parallel
+
+    # -- ship outputs home + weighted combine ------------------------------
+    with jax.named_scope("moe_combine"):
+        if ep > 1:
+            out = lax.all_to_all(
+                out, expert_axis, split_axis=1, concat_axis=0, tiled=True
+            )
+        out_pad = jnp.concatenate(
+            [out.reshape(e * c, d), jnp.zeros((1, d), out.dtype)]
+        )
+        contrib = out_pad[dest].astype(jnp.float32)       # dropped -> zero row
+        w = gates.T.reshape(-1) * keep                    # [k*N] fp32
+        y = jnp.sum(
+            (contrib * w[:, None]).reshape(top_k, n, d), axis=0
+        )
+        return y, jnp.sum(~keep).astype(jnp.float32)
+
+
 def moe_ffn(
     x,
     w_router,
@@ -119,7 +323,7 @@ def moe_ffn(
     *,
     n_experts: int,
     top_k: int = 2,
-    capacity_factor: float = 1.25,
+    capacity_factor: float | None = 1.25,
     expert_axis: str | None = EXPERT_AXIS,
     model_axis: str | None = MODEL_AXIS,
     batch_axes: tuple = (),
@@ -134,10 +338,17 @@ def moe_ffn(
       [E_loc, F_loc, D] — expert-sharded over ``expert_axis``,
       FFN-dim-sharded over ``model_axis`` (either may be ``None`` /
       size-1 for a replicated layout).
+    - ``capacity_factor``: a number sizes the capacity buffers (picks
+      beyond them are dropped); ``None`` is the dropless path (every
+      pick computed; ``ep == 1`` only).
+    - ``renormalize``: selected gates rescaled to sum to one (Mixtral)
+      or left as the softmax gave them (OLMoE, ``norm_topk_prob``
+      false).
 
     Returns ``(y [B, T_loc, D], aux)`` with ``aux = {"lb": load
     balance loss, "z": router z-loss, "f": [E] pick fractions, "p":
-    [E] mean router probs}``, all globalized over ``batch_axes`` (the
+    [E] mean router probs, "dropped": picks no expert computed}``,
+    all globalized over ``batch_axes`` (the
     mesh axes sharding the token batch) so they are exactly
     layout-invariant — see ``load_balance_loss``.  ``f``/``p`` are the
     LINEAR moments behind ``lb``: a caller that splits one batch into
@@ -156,72 +367,36 @@ def moe_ffn(
         f"expert leaf holds {we_gate.shape[0]} experts, expected "
         f"{e}/{ep} = {e // ep}"
     )
-    c = moe_capacity(n, e, top_k, capacity_factor)
 
-    gates, eidx, probs, logits = router_topk(
-        x2, w_router, top_k, renormalize
-    )
-    f, p = aux_moments(eidx, probs, e, batch_axes)
-    aux = {
-        "f": f,
-        "p": p,
-        "lb": e * jnp.sum(f * p),
-        "z": router_z_loss(logits, batch_axes),
-    }
-
-    # -- slot-major dispatch plan (all int32, one cumsum) ------------------
-    # slot-major flatten: slot j's block holds every token's j-th pick,
-    # so capacity ranks all 1st choices before any 2nd choice
-    flat_e = eidx.T.reshape(-1)                       # [k*N]
-    onehot = (
-        flat_e[:, None] == jnp.arange(e, dtype=flat_e.dtype)[None, :]
-    ).astype(jnp.int32)                               # [k*N, E]
-    pos = jnp.take_along_axis(
-        jnp.cumsum(onehot, axis=0) - 1, flat_e[:, None], axis=1
-    )[:, 0]                                           # rank within expert
-    keep = pos < c
-    dest = jnp.where(keep, flat_e * c + pos, e * c)   # e*c = drop sentinel
-    tok = jnp.arange(top_k * n, dtype=jnp.int32) % n  # slot-major token id
-
-    # inverse plan: which token fills each (expert, capacity) slot
-    # (0 = empty; only the sentinel slot ever collides)
-    src = jnp.zeros((e * c + 1,), jnp.int32).at[dest].set(tok + 1)
-    src = src[: e * c]
-    filled = src > 0
-    buf = jnp.where(
-        filled[:, None],
-        x2[jnp.maximum(src - 1, 0)],
-        jnp.zeros((), x2.dtype),
-    ).reshape(e, c, d)
-
-    # -- ship buffers to the expert owners ---------------------------------
-    if ep > 1:
-        # [E, C, D] -> [E/ep, ep*C, D]: each device keeps its own
-        # experts' rows from every peer in the expert group
-        buf = lax.all_to_all(
-            buf, expert_axis, split_axis=0, concat_axis=1, tiled=True
+    with jax.named_scope("moe_route"):
+        gates, eidx, probs, logits = router_topk(
+            x2, w_router, top_k, renormalize
         )
+        f, p = aux_moments(eidx, probs, e, batch_axes)
+        aux = {
+            "f": f,
+            "p": p,
+            "lb": e * jnp.sum(f * p),
+            "z": router_z_loss(logits, batch_axes),
+        }
 
-    # -- expert SwiGLU (batched matmuls; TP over the FFN dim) --------------
-    g = jnp.einsum("ecd,edf->ecf", buf, we_gate.astype(buf.dtype))
-    u = jnp.einsum("ecd,edf->ecf", buf, we_up.astype(buf.dtype))
-    out = jnp.einsum(
-        "ecf,efd->ecd", jax.nn.silu(g) * u, we_down.astype(buf.dtype)
-    )
-    if model_axis is not None:
-        out = lax.psum(out, model_axis)               # close row-parallel
-
-    # -- ship outputs home + weighted combine ------------------------------
-    if ep > 1:
-        out = lax.all_to_all(
-            out, expert_axis, split_axis=1, concat_axis=0, tiled=True
+    if capacity_factor is None:
+        assert ep == 1, (
+            "dropless MoE (capacity_factor=None) runs with ep == 1 only: "
+            "expert parallelism needs a ragged all-to-all"
         )
-    out_pad = jnp.concatenate(
-        [out.reshape(e * c, d), jnp.zeros((1, d), out.dtype)]
-    )
-    contrib = out_pad[dest].astype(jnp.float32)       # dropped -> zero row
-    w = gates.T.reshape(-1) * keep                    # [k*N] fp32
-    y = jnp.sum(
-        (contrib * w[:, None]).reshape(top_k, n, d), axis=0
-    )
+        y = _dropless_experts(
+            x2, gates, eidx, we_gate, we_up, we_down,
+            n_experts=e, model_axis=model_axis,
+        )
+        dropped = jnp.zeros((), jnp.float32)    # by construction
+    else:
+        y, dropped = _capacity_experts(
+            x2, gates, eidx, we_gate, we_up, we_down, n_experts=e,
+            capacity=moe_capacity(n, e, top_k, capacity_factor), ep=ep,
+            expert_axis=expert_axis, model_axis=model_axis,
+        )
+        if batch_axes:
+            dropped = lax.psum(dropped, batch_axes)
+    aux["dropped"] = dropped
     return y.astype(x.dtype).reshape(b, t, d), aux

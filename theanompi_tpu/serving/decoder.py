@@ -103,7 +103,7 @@ class LlamaDecoder:
     - ``decode(tokens, lengths, keys, temps) -> next tokens [S]``
 
     Serving composes with tensor parallelism only: ``pp > 1``,
-    ``sp > 1`` and MoE models are not yet servable.
+    ``sp > 1``, MoE and QK-norm models are not yet servable.
     """
 
     paged = False
@@ -139,11 +139,13 @@ class LlamaDecoder:
                 "build_model() + compile_iter_fns() (then load() for "
                 "checkpoint weights) before serving"
             )
-        if model.pp > 1 or model.sp > 1 or model.n_experts:
+        if (model.pp > 1 or model.sp > 1 or model.n_experts
+                or model.qk_norm):
             raise NotImplementedError(
                 "serving composes with tensor parallelism only — "
                 f"pp={model.pp}, sp={model.sp}, "
-                f"n_experts={model.n_experts} are not yet servable"
+                f"n_experts={model.n_experts}, "
+                f"qk_norm={model.qk_norm} are not yet servable"
             )
         self.model = model
         self.mesh = model.mesh

@@ -132,6 +132,10 @@ class Recorder:
         self._epoch_start: Optional[float] = None
         self._window: list[tuple[float, float]] = []  # (loss, err) since last print
         self._pending: list[tuple] = []  # unread device scalars (lazy fence)
+        # a MoE step's routing counters (obs/routing.py): the newest
+        # unread device value with its picks a step, and the last read
+        self._pending_routing: tuple | None = None
+        self.moe_counters: dict | None = None
         self.n_iter = 0
         self._last_print = 0
         # resilience bookkeeping (utils/supervisor.py): one entry per
@@ -258,8 +262,27 @@ class Recorder:
         self._pending.append((loss, err))
         self.n_iter += int(np.shape(loss)[0]) if np.ndim(loss) else 1
 
+    def moe_routing(self, routing, picks: int) -> None:
+        """A MoE step's (or K-step chunk's) routing counters
+        ``[(K,) L, E+1]``, as :meth:`train_error` takes the loss:
+        a device value, its copy to the host started here and read at
+        the next fence.  Only the newest step's are kept."""
+        start = getattr(routing, "copy_to_host_async", None)
+        if start is not None:
+            start()
+        self._pending_routing = (routing, picks)
+
     def flush(self) -> None:
         """Materialize pending device values (this is the fence)."""
+        if self._pending_routing is not None:
+            from theanompi_tpu.obs.routing import moe_counters
+
+            routing, picks = self._pending_routing
+            a = np.asarray(routing, np.float64)
+            self.moe_counters = moe_counters(
+                a[-1] if a.ndim == 3 else a, picks
+            )
+            self._pending_routing = None
         for loss, err in self._pending:
             ls = np.asarray(loss, np.float64).ravel()
             es = np.asarray(err, np.float64).ravel()
@@ -424,6 +447,8 @@ class Recorder:
             (f"{p}_resharded_total", "counter", [(None, resharded)]),
             (f"{p}_mttr_seconds", "gauge", [(None, self.mttr_s)]),
             (f"{p}_world_size", "gauge", [(None, world_size)]),
+            *((f"{p}_{k}", "gauge", [(None, (self.moe_counters or {}).get(k))])
+              for k in ("moe_load_max_over_mean", "moe_dropped_picks")),
         ])
 
     # -- persistence (reference: save()/load() of record arrays) ----------

@@ -628,6 +628,8 @@ def run(
         "setup_phases": setup.as_dict(),
         "compiles_after_warmup": late_compiles,
         "n_compiles_after_warmup": n_late_compiles,
+        # routing counters of the last fenced MoE step (None if dense)
+        "moe_counters": recorder.moe_counters,
         "step_profile": step_prof,
         "loader": loader_stats,
         "recorder": recorder,
