@@ -142,3 +142,130 @@ def test_dropless_expert_layer_compiles_to_grouped_kernels(chip, direction):
     assert all(re.search(rf"\[({k * n},({d}|{f})|{e},\d+,\d+)\]", p)
                for p in products)
     assert not re.search(rf"\[{e},{n},{d}\]|\[{e},{k * n},", text)
+
+
+def _fusions(text):
+    """A compiled text as ``{computation: [(name, opcode, shape,
+    operand names, called computation or None)]}`` and its fusion
+    instructions as ``[(name, op_name, called computation)]``."""
+    import re
+
+    instr = re.compile(
+        r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\(.*?\)|\S+)\s+([\w\-]+)\((.*)$"
+    )
+    comps, calls, cur = {}, [], None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$",
+                        line)
+        if head and not line.startswith(" "):
+            cur = comps.setdefault(head.group(1), [])
+            continue
+        m = instr.match(line)
+        if cur is None or not m:
+            continue
+        name, shape, opcode, rest = m.groups()
+        called = re.search(r"calls=%?([\w.\-]+)", rest)
+        cur.append((name, opcode, shape,
+                    re.findall(r"%([\w.\-]+)", rest.split("),")[0]),
+                    called and called.group(1)))
+        op_name = re.search(r'op_name="([^"]*)"', rest)
+        if opcode == "fusion" and called:
+            calls.append((name, op_name.group(1) if op_name else "",
+                          called.group(1)))
+    return comps, calls
+
+
+def _operand_side_opcodes(comps, comp):
+    """For every ``convolution`` of a fused computation: its output
+    shape and the opcodes it is fed from, nested fusions opened."""
+    def opcodes(c):
+        out = []
+        for _, opcode, _, _, called in comps[c]:
+            out += opcodes(called) if called else [opcode]
+        return out
+
+    by_name = {name: (opcode, operands, called)
+               for name, opcode, _, operands, called in comps[comp]}
+    found = []
+    for name, opcode, shape, operands, called in comps[comp]:
+        if called:
+            found += _operand_side_opcodes(comps, called)
+        if opcode != "convolution":
+            continue
+        seen, todo, fed = set(), list(operands), []
+        while todo:
+            n = todo.pop()
+            if n in seen or n not in by_name:
+                continue
+            seen.add(n)
+            op, ops, sub = by_name[n]
+            fed += opcodes(sub) if sub else [op]
+            todo += ops
+        found.append((shape, fed))
+    return found
+
+
+def test_dense_mlp_backward_feeds_its_products_arrays(chip):
+    """One dense ``Llama`` block under remat, backward with an SGD
+    update, at small widths (256 wide, ffn 512, 2 x 256 tokens):
+    SwiGLU's gradient is computed once, under the ``mlp_act_grad``
+    scope, and no backward product reads an ``exponential`` through
+    its OPERANDS except ``w_down``'s weight gradient (whose operand is
+    the recomputed ``silu(g) * u``, left as it is: PERF.md, PR 27).
+
+    These widths do reproduce XLA's choice: with the plain
+    ``jax.nn.silu(g) * u`` in ``Llama._layer`` the same compile fuses
+    the activation's gradient into the operand of both ``[256, 512]``
+    weight gradients and both input-gradient products (4 fusions),
+    which is what Mistral's ``[4096, 14336]`` ones showed on the chip
+    (ledger, PR 26: 15.0 ms against 6.6 from shapes)."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from theanompi_tpu.models.llama import Llama
+    from theanompi_tpu.ops import attention
+    from theanompi_tpu.parallel import make_mesh
+
+    dim, ffn, t, b = 256, 512, 256, 2
+    mesh = make_mesh(data=1, devices=list(chip.device_set))
+    rep = NamedSharding(mesh, P())
+    model = Llama(dict(
+        dim=dim, n_layers=1, n_heads=2, n_kv_heads=2, ffn_dim=ffn,
+        vocab=256, seq_len=t, batch_size=b, compute_dtype="bfloat16",
+    ))
+    shapes = dict(
+        attn_norm=(dim,), mlp_norm=(dim,), wq=(dim, dim), wk=(dim, dim),
+        wv=(dim, dim), wo=(dim, dim), w_gate=(dim, ffn), w_up=(dim, ffn),
+        w_down=(ffn, dim),
+    )
+    p = {k: jax.ShapeDtypeStruct(s, jnp.float32, sharding=rep)
+         for k, s in shapes.items()}
+    x = jax.ShapeDtypeStruct((b, t, dim), jnp.bfloat16, sharding=rep)
+    layer = jax.checkpoint(model._layer)
+
+    def sgd_step(p, x):
+        def loss(p, x):
+            return layer(p, x, jnp.arange(t)).astype(jnp.float32).sum()
+        g, dx = jax.grad(loss, argnums=(0, 1))(p, x)
+        return jax.tree.map(lambda a, b: a - 0.1 * b, p, g), dx
+
+    on_tpu = attention._on_tpu
+    attention._on_tpu = lambda: True       # the kernel, as on the chip
+    try:
+        text = _compiled_text(jax.shard_map(
+            sgd_step, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
+        ), p, x)
+    finally:
+        attention._on_tpu = on_tpu
+    assert "mlp_act_grad" in text
+    comps, calls = _fusions(text)
+    fed_an_exponential = [
+        (name, shape)
+        for name, op_name, comp in calls if "transpose(" in op_name
+        for shape, fed in _operand_side_opcodes(comps, comp)
+        if "exponential" in fed
+    ]
+    # w_down's weight gradient is [ffn, dim]; nothing else may be here
+    assert all(re.match(rf"\w+\[{ffn},{dim}(,1)?\]", shape)
+               for _, shape in fed_an_exponential), fed_an_exponential

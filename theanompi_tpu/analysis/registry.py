@@ -135,6 +135,8 @@ PROFILE_SCOPES: dict[str, str] = {
     "moe_dispatch": "moe_dispatch",
     "moe_experts": "moe_experts",
     "moe_combine": "moe_combine",
+    # the dense MLP's activation gradient (ops/layers.py swiglu, PR 27)
+    "mlp_act_grad": "mlp_act_grad",
 }
 
 #: label PREFIX -> leg family: labels carrying a per-instance index

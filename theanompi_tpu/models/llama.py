@@ -67,6 +67,7 @@ from theanompi_tpu.models.base import TMModel
 from theanompi_tpu.models.data.lm_synthetic import MarkovLMData
 from theanompi_tpu.obs.setup import setup_phase
 from theanompi_tpu.ops.attention import flash_attention
+from theanompi_tpu.ops.layers import swiglu
 from theanompi_tpu.ops import optimizers as opt_lib
 from theanompi_tpu.parallel import (
     DATA_AXIS,
@@ -437,9 +438,11 @@ class Llama(TMModel):
                 [aux["f"], aux["p"], aux["z"][None], aux["dropped"][None]]
             ).astype(jnp.float32)
             return x + y.astype(cdtype), mom
-        gate = jax.nn.silu(tp_lib.col_parallel(xn, p["w_gate"]))
-        up = tp_lib.col_parallel(xn, p["w_up"])
-        x = x + tp_lib.row_parallel(gate * up, p["w_down"]).astype(cdtype)
+        h = swiglu(
+            tp_lib.col_parallel(xn, p["w_gate"]),
+            tp_lib.col_parallel(xn, p["w_up"]),
+        )
+        x = x + tp_lib.row_parallel(h, p["w_down"]).astype(cdtype)
         return x
 
     def _forward(self, params, ids, head=True, with_aux=False):

@@ -713,6 +713,49 @@ class Sequential(Layer):
 
 
 # ---------------------------------------------------------------------------
+# Gated-MLP activation (the dense decoder block of ``models/llama.py``)
+# ---------------------------------------------------------------------------
+
+def _silu_mul(g, u):
+    return jax.nn.silu(g) * u
+
+
+@jax.custom_vjp
+def swiglu(g, u):
+    """``silu(g) * u``, whose backward hands its consumers ARRAYS.
+
+    Forward: exactly ``jax.nn.silu(g) * u``.  Backward: autodiff of
+    that same expression, so the same values in the same dtype — but
+    returned through ``lax.optimization_barrier``, under the
+    ``mlp_act_grad`` scope.  Without the barrier XLA:TPU clones the
+    elementwise gradient (an ``exponential``, two ``divide``s, from
+    three [tokens, ffn] arrays) into the OPERAND of each product that
+    reads it — the ``w_gate``/``w_up`` weight gradients and the two
+    input-gradient products — where it is re-evaluated once per
+    visit of an operand tile.  With it ``(dg, du)`` are computed once
+    a layer, on the v5e as the epilogue of the product that makes
+    ``dh``, and the four products read plain arrays: Mistral's
+    [4096, 14336] ``w_gate`` gradient with its Adam update 14.98 ->
+    8.94 ms, the step 296.2 -> 275.3 (PERF.md §6, PR 27).  The
+    forward rule has no barrier on purpose: materialising ``h`` for
+    ``w_down``'s gradient was measured and lost 2 ms a step.
+    """
+    return _silu_mul(g, u)
+
+
+def _swiglu_fwd(g, u):
+    return _silu_mul(g, u), (g, u)
+
+
+def _swiglu_bwd(res, dh):
+    with jax.named_scope("mlp_act_grad"):
+        return lax.optimization_barrier(jax.vjp(_silu_mul, *res)[1](dh))
+
+
+swiglu.defvjp(_swiglu_fwd, _swiglu_bwd)
+
+
+# ---------------------------------------------------------------------------
 # Losses / metrics (reference: Softmax layer + negative_log_likelihood
 # + errors() inside layers2/models)
 # ---------------------------------------------------------------------------

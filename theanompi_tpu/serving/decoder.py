@@ -68,6 +68,7 @@ from theanompi_tpu.models.llama import (
     rope_at,
 )
 from theanompi_tpu.ops.attention import NEG_INF, flash_attention
+from theanompi_tpu.ops.layers import swiglu
 from theanompi_tpu.parallel import (
     MODEL_AXIS,
     default_devices,
@@ -195,11 +196,11 @@ class LlamaDecoder:
 
     def _mlp(self, p, x):
         xn = rms_norm(x, p["mlp_norm"])
-        gate = jax.nn.silu(tp_lib.col_parallel(xn, p["w_gate"]))
-        up = tp_lib.col_parallel(xn, p["w_up"])
-        return x + tp_lib.row_parallel(gate * up, p["w_down"]).astype(
-            x.dtype
+        h = swiglu(
+            tp_lib.col_parallel(xn, p["w_gate"]),
+            tp_lib.col_parallel(xn, p["w_up"]),
         )
+        return x + tp_lib.row_parallel(h, p["w_down"]).astype(x.dtype)
 
     def _sample(self, logits, keys, pos, temps, greedy: bool):
         """Token ids from [N, V/tp] logits.  ``greedy=True`` is the
