@@ -726,7 +726,7 @@ class TestBucketedTraining:
         )
         if bucket_mb:
             # the toy model must actually bucket, or the test is void
-            assert m._bucket_elems > 0
+            assert m.exchange.bucket_elems > 0
         rec = Recorder(verbose=False)
         for i in range(steps):
             m.train_iter(i, rec)
@@ -786,7 +786,7 @@ class TestBucketedTraining:
                 mesh=make_mesh(data=8, devices=devices8)
             )
             if bmb:
-                assert m._bucket_elems > 0
+                assert m.exchange.bucket_elems > 0
             rec = Recorder(verbose=False)
             for i in range(50):
                 m.train_iter(i, rec)
@@ -830,7 +830,7 @@ class TestBucketedTraining:
             return m
 
         m = build(cfg)
-        assert m._zero1_layout[1] > 0          # actually bucketed
+        assert m.exchange.zero1_layout[1] > 0          # actually bucketed
         m.save(str(tmp_path / "a"), Recorder(verbose=False))
 
         # same layout: resumes
@@ -843,11 +843,11 @@ class TestBucketedTraining:
         # (differing-padded mismatches are already refused by the
         # sharded-checkpoint shape check)
         m_mono = build(dict(cfg, exchange_bucket_mb=0))
-        padded = m_mono._zero1_layout[0]
+        padded = m_mono.exchange.zero1_layout[0]
         assert padded % 32 == 0                # 4 buckets, 8 shards
         coincide_mb = padded * 4 / 4 / 2**20   # padded/4 elems, fp32
         m5 = build(dict(cfg, exchange_bucket_mb=coincide_mb))
-        assert m5._zero1_layout == (padded, padded // 4)
+        assert m5.exchange.zero1_layout == (padded, padded // 4)
         m5.save(str(tmp_path / "b"), Recorder(verbose=False))
 
         # compile-then-load (THE supported zero1 resume order) across
@@ -1120,7 +1120,7 @@ class TestGroupOfOne:
         from theanompi_tpu.utils import Recorder
 
         m = build(strategy, devices)
-        assert m._bucket_elems > 0
+        assert m.exchange.bucket_elems > 0
         text = _spy_lowered(m)
         rec = Recorder(verbose=False)
         for i in range(2):
@@ -1142,7 +1142,7 @@ class TestGroupOfOne:
         assert (m.exchange_replicas, m.exchange_buckets) == (1, 0)
         assert not _exchange_scopes(text)
         size = sum(x.size for x in jax.tree.leaves(p16))
-        padded, bucket_len = flat_layout(size, 1, m._bucket_elems)
+        padded, bucket_len = flat_layout(size, 1, m.exchange.bucket_elems)
         assert bucket_len                       # it would have bucketed
         assert f"tensor<{padded}x" not in text
         assert f"tensor<{bucket_len}x" not in text
@@ -1157,10 +1157,10 @@ class TestGroupOfOne:
         size = sum(x.size for x in jax.tree.leaves(params))
         assert m.exchange_replicas == 8
         assert m.exchange_buckets == exchange_bucket_count(
-            size, 8, m._bucket_elems
+            size, 8, m.exchange.bucket_elems
         ) > 1
         assert len(_exchange_scopes(text)) == m.exchange_buckets
-        assert f"tensor<{flat_layout(size, 8, m._bucket_elems)[1]}x" in text
+        assert f"tensor<{flat_layout(size, 8, m.exchange.bucket_elems)[1]}x" in text
 
     @pytest.mark.parametrize("n_devices", [1, 8])
     def test_worker_summary_counts(self, n_devices):
@@ -1176,7 +1176,7 @@ class TestGroupOfOne:
             verbose=False, exch_strategy="ici16",
         )
         size = sum(x.size for x in jax.tree.leaves(res["model"].params))
-        k = exchange_bucket_count(size, n_devices, res["model"]._bucket_elems)
+        k = exchange_bucket_count(size, n_devices, res["model"].exchange.bucket_elems)
         assert res["exchange_replicas"] == n_devices
         assert res["exchange_buckets"] == k
         assert (k == 0) if n_devices == 1 else (k > 1)

@@ -24,9 +24,12 @@ class TestInventory:
             if e["message"] is not None
         ]
         # the ROADMAP item-4 matrix, found from the code itself
-        assert any("llama" in m and "zero1" in t for m, t in msgs)
-        assert any("llama" in m and "compression" in t.lower()
+        # (the MoE x zero1 / compression pair is refused where the
+        # exchange is planned, parallel/plan.py)
+        assert any("plan" in m and "zero1" in t for m, t in msgs)
+        assert any("plan" in m and "compression" in t.lower()
                    for m, t in msgs)
+        assert any("llama" in m and "dropless" in t for m, t in msgs)
         assert any("decoder" in m and "tensor parallelism" in t
                    for m, t in msgs)
         assert any("adapter" in m for m, t in msgs)

@@ -55,7 +55,7 @@ def _psize(m) -> int:
 def _gathered_opt(m, dp) -> list:
     """Every flat opt-state leaf in master (pack) order, live region
     only; non-flat leaves (scalars) pass through."""
-    padded, bl = m._zero1_layout
+    padded, bl = m.exchange.zero1_layout
     size = _psize(m)
     out = []
     for leaf in jax.tree.leaves(m.opt_state):
@@ -189,8 +189,8 @@ class TestModelReshard:
         # bitwise — the loader moves total * (n_new/n_old) onto
         # shard 0, so the next exchange injects total/n_old exactly
         # as the old world would have (the /n_new in the mean)
-        p8 = m8._ef_layout[1]
-        p4 = m4._ef_layout[1]
+        p8 = m8.exchange.ef_layout[1]
+        p4 = m4.exchange.ef_layout[1]
         r1_8 = np.asarray(m8.ef_state["r1"]).reshape(8, p8)
         r1_4 = np.asarray(m4.ef_state["r1"]).reshape(4, p4)
         np.testing.assert_array_equal(
@@ -261,7 +261,7 @@ class TestGroundTruth:
         }))
         _assert_params_equal(mono, buck)
         size = _psize(mono)
-        _, bl = buck._zero1_layout
+        _, bl = buck.exchange.zero1_layout
         assert bl > 0
         for a, b in zip(
             jax.tree.leaves(mono.opt_state),
@@ -292,8 +292,8 @@ class TestGroundTruth:
         assert m4.load(str(tmp_path))
         assert m4.resharded_from["groups"] == ["ef_state"]
         _assert_params_equal(m8, m4)
-        _, p8, b8 = m8._ef_layout
-        _, p4, b4 = m4._ef_layout
+        _, p8, b8 = m8.exchange.ef_layout
+        _, p4, b4 = m4.exchange.ef_layout
         np.testing.assert_array_equal(
             np.sum(
                 np.asarray(m8.ef_state["r1"]).reshape(8, p8)[:, :size],
@@ -332,7 +332,7 @@ class TestWorldChangeHazards:
         m8 = _train(_wresnet(8, devices8, cfg))
         m8.save(str(tmp_path))
         m4 = _wresnet(4, devices8, cfg)
-        assert tuple(m8._zero1_layout) == tuple(m4._zero1_layout)
+        assert tuple(m8.exchange.zero1_layout) == tuple(m4.exchange.zero1_layout)
         with pytest.raises(ValueError, match="reshard=True"):
             m4.load(str(tmp_path))
         assert m4.load(str(tmp_path), reshard=True)

@@ -119,7 +119,7 @@ PROFILE_SCOPES: dict[str, str] = {
     # compressed-exchange codec halves (parallel/exchange.py, PR 4)
     "quantize_wire": "quantize",
     "dequantize_wire": "quantize",
-    # optimizer update (models/base.py, models/llama.py,
+    # optimizer update (parallel/plan.py ExchangePlan.apply,
     # scatter_update_gather's per-bucket/monolithic update)
     "opt_update": "optimizer",
     # serving decode attribution (serving/decoder.py, PR 6)

@@ -19,7 +19,6 @@ as arguments.
 from __future__ import annotations
 
 import math
-import time
 from typing import Any, Optional
 
 import jax
@@ -33,12 +32,9 @@ from theanompi_tpu.ops import optimizers as opt_lib
 from theanompi_tpu.ops.layers import accuracy, softmax_cross_entropy
 from theanompi_tpu.parallel import (
     DATA_AXIS,
+    ExchangePlan,
     allreduce_mean,
-    compressed_allreduce_mean,
-    flat_spec,
-    get_strategy,
     make_mesh,
-    scatter_update_gather,
 )
 from theanompi_tpu.utils import (
     Recorder,
@@ -69,11 +65,32 @@ class TMModel:
     #: EF residual of a compressed exchange (empty when off); models
     #: that compile one overwrite this with device state
     ef_state: PyTree = {}
-    #: size of the replica group the gradient exchange reduces over,
-    #: and how many ``exchange_b*`` bodies the compiled step traced (0
-    #: when a group of one exchanges nothing); set by compile_iter_fns
-    exchange_replicas: int | None = None
-    exchange_buckets: int | None = None
+    #: how the gradients travel (``parallel.ExchangePlan``, bound to
+    #: the compiled step's layout); set by compile_iter_fns
+    exchange: ExchangePlan | None = None
+    #: what ``load`` attached from the newest checkpoint; a compile
+    #: AFTER a restore consults it through the plan
+    _restored: dict = {}
+
+    @property
+    def exchange_replicas(self) -> int | None:
+        """Size of the replica group the gradient exchange reduces
+        over (None before the compile)."""
+        return self.exchange and self.exchange.exchange_replicas
+
+    @property
+    def exchange_buckets(self) -> int | None:
+        """``exchange_b*`` bodies the compiled step traced (0 when a
+        group of one exchanges nothing)."""
+        return self.exchange and self.exchange.exchange_buckets
+
+    def _exchange_layouts(self) -> tuple:
+        """``(zero1_layout, ef_layout)`` of the compiled exchange —
+        the checkpoint stamps (None, None before a compile)."""
+        plan = self.exchange
+        if plan is None:
+            return None, None
+        return plan.zero1_layout, plan.ef_layout
 
     def build_model(self, n_replicas: int = 1) -> None:
         raise NotImplementedError
@@ -215,7 +232,7 @@ class TMModel:
         # stamp it so a resume under a different exchange_bucket_mb
         # refuses instead of silently pairing m/v rows with the wrong
         # params (the shapes alone can coincide across layouts)
-        z_layout = getattr(self, "_zero1_layout", None)
+        z_layout, ef_layout = self._exchange_layouts()
         if z_layout is not None:
             meta["zero1_layout"] = list(z_layout)
         # the error-feedback residual of a compressed exchange is part
@@ -223,7 +240,6 @@ class TMModel:
         # re-zeroed) it would break the interrupted==uninterrupted
         # bitwise guarantee, so its layout is stamped like the zero1
         # bucket layout and checked on load
-        ef_layout = getattr(self, "_ef_layout", None)
         if ef_layout is not None:
             meta["ef_layout"] = list(ef_layout)
         trees = self.checkpoint_trees()
@@ -291,8 +307,7 @@ class TMModel:
         exchange layouts (zero1 optimizer shards, EF residuals).
         ``None`` = layouts already match (or no layout-sensitive
         state) — the normal loader runs."""
-        cur_z = getattr(self, "_zero1_layout", None)
-        cur_ef = getattr(self, "_ef_layout", None)
+        cur_z, cur_ef = self._exchange_layouts()
         saved_z = meta.get("zero1_layout")
         saved_ef = meta.get("ef_layout")
         groups: dict[str, tuple] = {}
@@ -316,7 +331,7 @@ class TMModel:
             if tuple(saved_ef) != tuple(cur_ef) or world_changed:
                 groups["ef_state"] = (
                     (saved_ef[1], saved_ef[2]),
-                    (self._ef_layout[1], self._ef_layout[2]),
+                    (cur_ef[1], cur_ef[2]),
                 )
         if not groups:
             return None
@@ -421,7 +436,7 @@ class TMModel:
         # checkpoint), and — _world_hint's coinciding-stamp rule — a
         # bucketed layout under a DIFFERENT world is a mismatch even
         # when the stamps agree
-        cur = getattr(self, "_zero1_layout", None)
+        cur, cur_ef = self._exchange_layouts()
         if cur is not None and "opt_state" in like:
             saved = meta_hint.get("zero1_layout")
             saved = tuple(saved) if saved is not None else (cur[0], 0)
@@ -444,7 +459,6 @@ class TMModel:
         # flat order is (compression, padded, bucket_len)-dependent,
         # so a mismatched resume must refuse instead of re-injecting
         # rows against the wrong parameters
-        cur_ef = getattr(self, "_ef_layout", None)
         if cur_ef is not None and "ef_state" in like:
             saved_ef = meta_hint.get("ef_layout")
             # a checkpoint with NO residual at all (saved_ef None)
@@ -480,30 +494,30 @@ class TMModel:
         in the COMPILED layout, so the restored-layout markers record
         the current stamps, not the checkpoint's."""
         if resharded is None:
-            self._restored_ef_layout = meta.get("ef_layout")
-            self._restored_zero1_layout = meta.get("zero1_layout")
+            z_layout, ef_layout = (
+                meta.get("zero1_layout"), meta.get("ef_layout")
+            )
         else:
-            cur_ef = getattr(self, "_ef_layout", None)
-            cur_z = getattr(self, "_zero1_layout", None)
-            self._restored_ef_layout = (
-                list(cur_ef) if cur_ef is not None else None
-            )
-            self._restored_zero1_layout = (
-                list(cur_z) if cur_z is not None else None
-            )
-        self._restored_ef = "ef_state" in trees
-        # the checkpoint carries an EF residual (its layout is
-        # stamped) that this load did NOT attach — the model hasn't
-        # compiled its compressed exchange yet, so checkpoint_trees()
-        # had no ef_state slot.  Remember it: a later
-        # compile_iter_fns(exch_compression=...) must refuse instead
-        # of silently installing fresh zero residuals (compile-then-
-        # load is the supported order, as for zero1 state).
-        self._restored_ef_orphaned = (
-            resharded is None
-            and meta.get("ef_layout") is not None
-            and "ef_state" not in trees
-        )
+            z_layout, ef_layout = self._exchange_layouts()
+        # a later compile_iter_fns must neither zero a restored
+        # optimizer state or EF residual nor keep one in another
+        # layout (compile-then-load is the supported order): the
+        # plan's restore checks read this record
+        self._restored = {
+            "opt_state": "opt_state" in trees,
+            "ef_state": "ef_state" in trees,
+            "zero1_layout": z_layout,
+            "ef_layout": ef_layout,
+            # the checkpoint carries an EF residual (its layout is
+            # stamped) that this load did NOT attach — the model had
+            # not compiled its compressed exchange yet, so
+            # checkpoint_trees() had no ef_state slot
+            "ef_orphaned": (
+                resharded is None
+                and meta.get("ef_layout") is not None
+                and "ef_state" not in trees
+            ),
+        }
         # workers read this for resilience metadata the load() bool
         # can't carry: next_iter (mid-epoch preemption checkpoints),
         # preempted flag, restored recorder history, the saved world
@@ -511,10 +525,6 @@ class TMModel:
         self.resharded_from = resharded
         for group, tree in trees.items():
             setattr(self, group, tree)
-        # compile_iter_fns consults this: compiling with a zero1
-        # strategy AFTER a restore must not silently zero the restored
-        # optimizer state (cross-layout resume needs compile-then-load)
-        self._restored_opt = "opt_state" in trees
         self.epoch = int(meta.get("epoch", 0))
         self.current_lr = float(meta.get("lr", self.current_lr))
         if recorder is not None and "recorder" in meta:
@@ -659,163 +669,32 @@ class ClassifierModel(TMModel):
         if self.params is None:
             self._init_params()
         self.mesh = mesh if mesh is not None else make_mesh()
-        strat = get_strategy(
-            exch_strategy
-            or self.config.get("exch_strategy", "ici32")
-        )
         net = self.net
-        optimizer = self.optimizer
 
-        # ZeRO-1 (strat.zero1): optimizer state lives as a FLAT 1/N
-        # shard per data-axis device instead of a replicated pytree —
-        # the step body swaps allreduce-then-update for
-        # scatter_update_gather (reduce-scatter grads → update the
-        # shard → all-gather updated params).  Per-chip optimizer HBM
-        # drops ~1/N; the wire moves the same bytes as the two-phase
-        # allreduce.
-        # bucketed exchange (DDP-style overlap, Li et al. 2020):
-        # ``exchange_bucket_mb`` splits the grad/param exchange into
-        # fixed buckets whose collectives pipeline against compute;
-        # 0 keeps the monolithic exchange.  Default ~4 MiB — tiny
-        # models degrade to monolithic inside flat_spec.
-        from theanompi_tpu.parallel import (
-            resolve_bucket_mb,
-            resolve_compression,
+        # the per-device parameter pack is the whole tree (params are
+        # replicated), exchanged over the data axis, which is also the
+        # only axis the flat zero1 / EF buffers vary over
+        plan = self.exchange = ExchangePlan.from_config(
+            self.config, exch_strategy
+        ).bind(
+            self.mesh.shape,
+            n_elems=sum(
+                math.prod(jnp.shape(l))
+                for l in jax.tree.leaves(self.params)
+            ),
+            replica_axes=(DATA_AXIS,), flat_axes=(DATA_AXIS,),
+            optimizer=self.optimizer,
         )
-        from theanompi_tpu.parallel.exchange import (
-            exchange_bucket_count,
-            flat_layout,
+        opt_spec = self._opt_specs = (
+            plan.opt_state_specs if plan.zero1 else P()
         )
-
-        bucket_elems = strat.bucket_elems(resolve_bucket_mb(self.config))
-        self._bucket_elems = bucket_elems
-        # exch_compression: int8/fp8 quantized wire for the gradient
-        # exchange (per-bucket symmetric scales), with an
-        # error-feedback residual in worker state re-injecting the
-        # quantization error next step (parallel/exchange)
-        comp, use_ef = resolve_compression(self.config)
-        self._compression, self._error_feedback = comp, use_ef
-
-        n_dp = self.mesh.shape[DATA_AXIS]
-        fspec = (
-            flat_spec(self.params, n_dp, bucket_elems=bucket_elems)
-            if (strat.zero1 or comp) else None
-        )
-        zspec = fspec if strat.zero1 else None
-        # the layout the knob ACTUALLY produced (tiny models degrade
-        # to monolithic inside flat_layout) — gates the overlap
-        # preset and stamps zero1 checkpoints (a resumed bucket-major
-        # optimizer shard is only valid under the same bucket_len)
-        n_elems = sum(
-            math.prod(jnp.shape(l)) for l in jax.tree.leaves(self.params)
-        )
-        eff_bucket_len = flat_layout(n_elems, n_dp, bucket_elems)[1]
-        self.exchange_replicas = n_dp
-        self.exchange_buckets = exchange_bucket_count(
-            n_elems, n_dp, bucket_elems, flat=fspec is not None
-        )
-        self._zero1_layout = (
-            (zspec.padded, zspec.bucket_len) if strat.zero1 else None
-        )
-        if strat.zero1:
-            shard_state = optimizer.shard_state(zspec.shard_len)
-            if getattr(self, "_restored_opt", False):
-                # a restore happened BEFORE this compile.  Same-layout
-                # state (a zero1 checkpoint: flat [padded] buffers) is
-                # preserved; anything else would be silently zeroed
-                # below — refuse instead (compile-then-load is the
-                # supported resume order; cross-strategy resume is not)
-                saved = getattr(self, "_restored_zero1_layout", None)
-                saved = (
-                    tuple(saved) if saved is not None
-                    else (zspec.padded, 0)   # pre-bucketing: monolithic
-                )
-                zero1_layout = jax.tree.structure(
-                    self.opt_state
-                ) == jax.tree.structure(shard_state) and all(
-                    jnp.shape(l) == (zspec.padded,)
-                    for l in jax.tree.leaves(self.opt_state)
-                    if jnp.ndim(l)
-                ) and saved == (zspec.padded, zspec.bucket_len)
-                if not zero1_layout:
-                    raise ValueError(
-                        "compile_iter_fns(exch_strategy='zero1') "
-                        "after a checkpoint restore would silently "
-                        "discard the restored optimizer state (the "
-                        "zero1 layout is a flat 1/N shard, not the "
-                        "restored tree) — compile first, then "
-                        "load(); cross-strategy resume is not "
-                        "supported"
-                    )
-            else:
-                # global arrays: [padded] sharded over data (each
-                # device holds its own [padded/N] slice); scalars
-                # (adam's t) stay replicated
-                self.opt_state = jax.tree.map(
-                    lambda x: jnp.zeros((zspec.padded,), x.dtype)
-                    if jnp.ndim(x) else x,
-                    shard_state,
-                )
-            opt_spec = jax.tree.map(
-                lambda x: P(DATA_AXIS) if jnp.ndim(x) else P(),
-                shard_state,
-            )
-        else:
-            opt_spec = P()
-        self._opt_specs = opt_spec
-        self._zero1 = strat.zero1
-
-        # EF residual state: r1 is each device's own [padded] residual
-        # of the local-grad compression (global [n_dp*padded] sharded
-        # over data); r2 (non-zero1 only) the shard-owner residual of
-        # the reduced-mean compression ([shard_len] per device —
-        # zero1's param gather is uncompressed, so it has no phase-2
-        # residual).  error_feedback=False runs plain QSGD: no state.
-        ef_proto = {}
-        if comp and use_ef:
-            ef_proto["r1"] = jnp.zeros(
-                (n_dp * fspec.padded,), jnp.float32
-            )
-            if not strat.zero1:
-                ef_proto["r2"] = jnp.zeros((fspec.padded,), jnp.float32)
-        self._ef_layout = (
-            (comp, fspec.padded, fspec.bucket_len)
-            if comp and use_ef else None
-        )
-        if ef_proto and getattr(self, "_restored_ef_orphaned", False):
-            raise ValueError(
-                "a checkpoint restored BEFORE this compile carried an "
-                "EF residual (ef_layout stamped) that load() could "
-                "not attach — the model had no compressed exchange "
-                "yet.  Compiling now would silently zero the "
-                "residual; compile_iter_fns first, then load()"
-            )
-        if ef_proto and getattr(self, "_restored_ef", False):
-            saved = getattr(self, "_restored_ef_layout", None)
-            ok = (
-                isinstance(self.ef_state, dict)
-                and set(self.ef_state) == set(ef_proto)
-                and all(
-                    tuple(jnp.shape(self.ef_state[k]))
-                    == tuple(jnp.shape(v))
-                    for k, v in ef_proto.items()
-                )
-                and saved is not None
-                and tuple(saved) == self._ef_layout
-            )
-            if not ok:
-                raise ValueError(
-                    "compile_iter_fns with exch_compression after a "
-                    "checkpoint restore found an EF residual that "
-                    "does not match the compiled exchange layout "
-                    "(compression, padded, bucket_len) — compile "
-                    "first, then load(); cross-layout resume is not "
-                    "supported"
-                )
-        else:
-            self.ef_state = ef_proto
-        ef_spec = jax.tree.map(lambda _: P(DATA_AXIS), ef_proto)
-        self._ef_specs = ef_spec
+        if plan.zero1 and self._restored.get("opt_state"):
+            plan.check_restored_opt_state(self.opt_state, self._restored)
+        elif plan.zero1:
+            self.opt_state = plan.init_opt_state()
+        if not plan.keeps_restored_ef(self.ef_state, self._restored):
+            self.ef_state = plan.init_ef(self.mesh)
+        ef_spec = plan.ef_specs
 
         def loss_fn(params, net_state, x, y, rng):
             out, new_state = net.apply(
@@ -840,57 +719,9 @@ class ClassifierModel(TMModel):
             new_state = allreduce_mean(new_state, DATA_AXIS)
             loss = lax.pmean(loss, DATA_AXIS)
             err = lax.pmean(err, DATA_AXIS)
-            if strat.zero1:
-                # ZeRO-1 exchange: reduce-scatter grads, update the
-                # optimizer on this device's 1/N flat shard, all-gather
-                # the updated params (same wire bytes as two-phase
-                # allreduce, optimizer HBM /N).  With buckets the
-                # three phases pipeline per bucket (state sliced by
-                # scatter_update_gather — hence the 3-arg closure).
-                # With exch_compression the grad reduce-scatter ships
-                # 1-byte chunks + per-chunk scales; the param gather
-                # stays master-width (quantized params would corrupt
-                # the replicated masters).
-                def opt_upd(p_shard, g_shard, state):
-                    return optimizer.update(p_shard, g_shard, state, lr)
-
-                if comp:
-                    params, opt_state, r1n = scatter_update_gather(
-                        params, grads, opt_upd, DATA_AXIS,
-                        spec=zspec, opt_state=opt_state,
-                        compression=comp, r1=ef.get("r1"),
-                    )
-                    if "r1" in ef:
-                        ef = {"r1": r1n}
-                else:
-                    params, opt_state = scatter_update_gather(
-                        params, grads, opt_upd, DATA_AXIS,
-                        wire_dtype=strat.wire_dtype, spec=zspec,
-                        opt_state=opt_state,
-                    )
-            else:
-                # THE exchange: BSP allreduce folded into the step
-                # (reference: BSP_Exchanger.exchange between train
-                # iters), bucketed when exchange_bucket_mb says so;
-                # exch_compression swaps it for the quantized
-                # two-phase wire with the EF residual threaded through
-                # worker state.
-                if comp:
-                    grads, r1n, r2n = compressed_allreduce_mean(
-                        grads, DATA_AXIS, compression=comp,
-                        r1=ef.get("r1"), r2=ef.get("r2"),
-                        bucket_elems=bucket_elems,
-                    )
-                    if "r1" in ef:
-                        ef = {"r1": r1n, "r2": r2n}
-                else:
-                    grads = strat(grads, DATA_AXIS, bucket_elems)
-                # profiler scope (obs/profiler.py): the optimizer
-                # update is its own step-phase leg
-                with jax.named_scope("opt_update"):
-                    params, opt_state = optimizer.update(
-                        params, grads, opt_state, lr
-                    )
+            params, opt_state, ef = plan.apply(
+                params, grads, opt_state, ef, lr
+            )
             return params, new_state, opt_state, ef, loss, err
 
         def shard_val(params, net_state, x, y):
@@ -905,16 +736,15 @@ class ClassifierModel(TMModel):
 
         rep = P()
         dp = P(DATA_AXIS)
-        # TPU compiler knobs (utils/xla_options).
-        # A bucketed exchange additionally feeds the overlap preset
-        # (async collectives + latency-hiding scheduler) — TPU meshes
-        # only (the CPU client rejects unknown xla_tpu_* options) and
-        # only when the layout actually bucketed: a degraded-to-
-        # monolithic model must keep compiler_options None, or the
-        # jit call churns the compile-cache key for nothing.
+        # TPU compiler knobs (utils/xla_options).  A bucketed exchange
+        # adds the overlap preset (async collectives + latency-hiding
+        # scheduler) — TPU meshes only (the CPU client rejects unknown
+        # xla_tpu_* options), and only when the layout actually
+        # bucketed: compiler_options otherwise stays None, or the jit
+        # call churns the compile-cache key for nothing.
         is_tpu = self.mesh.devices.flat[0].platform == "tpu"
         self._compiler_options = xla_compiler_options(
-            self.config, overlap=bool(eff_bucket_len) and is_tpu
+            self.config, overlap=plan.bucketed and is_tpu
         )
         self._train_step = jax.jit(
             jax.shard_map(
@@ -954,7 +784,7 @@ class ClassifierModel(TMModel):
         self.opt_state = jax.tree.map(
             lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
             self.opt_state,
-            opt_spec if strat.zero1 else jax.tree.map(
+            opt_spec if plan.zero1 else jax.tree.map(
                 lambda _: P(), self.opt_state
             ),
         )
@@ -1058,7 +888,7 @@ class ClassifierModel(TMModel):
 
         rep_s, dp = P(), P(DATA_AXIS)
         osp = self._opt_specs  # zero1: data-sharded flat opt buffers
-        efsp = self._ef_specs  # compressed: data-sharded EF residuals
+        efsp = self.exchange.ef_specs  # data-sharded EF residuals
         self._train_step_cached = jax.jit(
             jax.shard_map(
                 shard_cached,
@@ -1337,5 +1167,5 @@ class ClassifierModel(TMModel):
                 lambda x, s: jax.device_put(
                     x, NamedSharding(self.mesh, s)
                 ),
-                self.ef_state, getattr(self, "_ef_specs", {}),
+                self.ef_state, self.exchange.ef_specs,
             )

@@ -75,13 +75,12 @@ from theanompi_tpu.parallel import (
     MODEL_AXIS,
     PIPE_AXIS,
     SEQ_AXIS,
-    compressed_allreduce_mean,
-    get_strategy,
+    ExchangePlan,
+    dp_replicas,
     last_stage_value,
     make_mesh,
     merge_microbatches,
     pipeline_apply,
-    scatter_update_gather,
     split_microbatches,
 )
 from theanompi_tpu.parallel.moe import moe_ffn
@@ -681,28 +680,8 @@ class Llama(TMModel):
             raise TypeError(
                 f"Llama.compile_iter_fns: unknown kwargs {sorted(unknown)}"
             )
-        # the DP gradient exchange honors the strategy knob (wire dtype
-        # x collective shape — ici16 is the reference's nccl16
-        # analogue); it applies to the data axis only, TP/SP
-        # collectives are part of the model math
-        strat = get_strategy(
-            exch_strategy or self.config.get("exch_strategy", "ici32")
-        )
-        # bucketed DP exchange (exchange_bucket_mb, default ~4 MiB;
-        # 0 = monolithic): per-bucket collectives pipeline against
-        # compute — see parallel/exchange.  Small models degrade to
-        # the monolithic path inside flat_spec.
-        from theanompi_tpu.parallel import (
-            resolve_bucket_mb,
-            resolve_compression,
-        )
-
-        bucket_elems = strat.bucket_elems(resolve_bucket_mb(self.config))
-        self._bucket_elems = bucket_elems
-        # exch_compression: int8/fp8 quantized DP gradient wire with
-        # error-feedback residuals in worker state (parallel/exchange)
-        comp, use_ef = resolve_compression(self.config)
-        self._compression, self._error_feedback = comp, use_ef
+        # validated before the mesh and the parameter shapes are built
+        plan = ExchangePlan.from_config(self.config, exch_strategy)
         if mesh is None:
             mesh = make_mesh(
                 model=self.tp, seq=self.sp, pipe=self.pp, expert=self.ep
@@ -719,8 +698,6 @@ class Llama(TMModel):
             f"mesh expert axis {mesh.shape.get(EXPERT_AXIS, 1)} != "
             f"ep {self.ep}"
         )
-        from theanompi_tpu.parallel import dp_replicas
-
         n_dp = dp_replicas(mesh)
         # the per-shard batch must be the configured batch_size: the
         # scattered head's token-slice guard (and the data pipeline's
@@ -754,26 +731,8 @@ class Llama(TMModel):
         else:  # momentum / nesterov velocity
             opt_specs = specs
 
-        # ZeRO-1 (strat.zero1): m/v become FLAT buffers holding each
-        # DP replica's 1/N shard of the (already tp/pp-sharded) local
-        # parameter pack — per-chip optimizer HBM divides by the DP
-        # replica count on top of the tp*pp model sharding.  The flat
-        # buffer varies over every non-seq mesh axis: (model, pipe)
-        # from the param sharding x (expert, data) from the zero1
-        # scatter.
-        zero1 = strat.zero1
-        z_shard_len = None
-        z_state_proto = None
-        # LOCAL (per-device) parameter-pack size + the bucket layout
-        # it actually produces (flat_layout is THE shared rule: the
-        # in-step flat_spec, the zero1 state sizing, and the overlap
-        # gate below must all agree; tiny models degrade to
-        # monolithic).  Shape-only eval, no compute.
-        from theanompi_tpu.parallel.exchange import (
-            exchange_bucket_count,
-            flat_layout,
-        )
-
+        # LOCAL (per-device) parameter-pack size: what the exchange
+        # packs.  Shape-only eval, no compute.
         shapes = jax.eval_shape(
             self._init_full_params, jax.random.PRNGKey(0)
         )
@@ -788,96 +747,40 @@ class Llama(TMModel):
                     dims[i] //= mesh.shape[a]
             return math.prod(dims)
 
-        local_size = sum(
-            _local_elems(l, s)
-            for l, s in zip(
-                jax.tree.leaves(shapes),
-                jax.tree.leaves(
-                    specs, is_leaf=lambda s: isinstance(s, P)
-                ),
-            )
-        )
-        n_dp = dp_replicas(mesh)
-        z_padded, z_bucket_len = flat_layout(
-            local_size, n_dp, bucket_elems
-        )
-        self._zero1_layout = (z_padded, z_bucket_len) if zero1 else None
-        self.exchange_replicas = n_dp
-        # the MoE exchange stays per-leaf (see step below)
-        self.exchange_buckets = exchange_bucket_count(
-            local_size, n_dp, 0 if self.n_experts else bucket_elems,
-            flat=bool(zero1 or comp),
-        )
-        if zero1:
-            if self.n_experts:
-                raise NotImplementedError(
-                    "exch_strategy='zero1' does not yet compose with "
-                    "MoE expert sharding (n_experts > 0): expert "
-                    "leaves exchange over data alone while dense "
-                    "leaves exchange over (expert, data) — two "
-                    "separate shard groups"
+        # The DP gradient exchange (wire dtype x collective shape x
+        # compression, ``parallel.ExchangePlan``) reduces over the DP
+        # axes only; TP/SP collectives are part of the model math.
+        # Its flat buffers (zero1 m/v, EF residuals) hold a shard of
+        # the already tp/pp-sharded local pack, so they vary over
+        # every non-seq mesh axis (param grads are psum'd over seq
+        # inside autodiff).  MoE: expert and dense leaves reduce over
+        # DIFFERENT axis sets, so that exchange is per leaf.
+        plan = self.exchange = plan.bind(
+            mesh.shape,
+            n_elems=sum(
+                _local_elems(l, s)
+                for l, s in zip(
+                    jax.tree.leaves(shapes),
+                    jax.tree.leaves(
+                        specs, is_leaf=lambda s: isinstance(s, P)
+                    ),
                 )
-            z_shard_len = z_padded // n_dp
-            z_flat_axes = tuple(
-                a for a in (PIPE_AXIS, EXPERT_AXIS, DATA_AXIS,
-                            MODEL_AXIS)
+            ),
+            replica_axes=dp_axes,
+            flat_axes=tuple(
+                a for a in (PIPE_AXIS, EXPERT_AXIS, DATA_AXIS, MODEL_AXIS)
                 if a in mesh.shape
-            )
-            z_global_len = z_shard_len
-            for a in z_flat_axes:
-                z_global_len *= mesh.shape[a]
-            z_state_proto = self.optimizer.shard_state(z_shard_len)
-            opt_specs = jax.tree.map(
-                lambda x: P(z_flat_axes) if jnp.ndim(x) else P(),
-                z_state_proto,
-            )
+            ),
+            optimizer=self.optimizer,
+            per_leaf=bool(self.n_experts),
+        )
+        if plan.zero1:
+            opt_specs = plan.opt_state_specs
+        ef_specs = plan.ef_specs
         self._specs, self._opt_specs = specs, opt_specs
-        self._zero1 = zero1
-
-        # EF residuals of the compressed exchange: flat per-device
-        # buffers (r1 [z_padded] — local-grad compression; r2
-        # [z_padded/n_dp], non-zero1 only — reduced-mean compression),
-        # varying over every non-seq mesh axis like the zero1 state
-        # (the packed local grads differ across tp/pp shards AND data
-        # replicas; they are seq-invariant — param grads are psum'd
-        # over seq inside autodiff).
-        if comp and self.n_experts:
-            raise NotImplementedError(
-                "exch_compression does not yet compose with MoE "
-                "expert sharding (n_experts > 0): expert and dense "
-                "leaves exchange over different shard groups, so "
-                "there is no single flat buffer to quantize (same "
-                "split that keeps MoE+zero1 NotImplementedError)"
-            )
-        ef_axes = tuple(
-            a for a in (PIPE_AXIS, EXPERT_AXIS, DATA_AXIS, MODEL_AXIS)
-            if a in mesh.shape
-        )
-        ef_proto, ef_specs = {}, {}
-        if comp and use_ef:
-            mult = 1
-            for a in ef_axes:
-                mult *= mesh.shape[a]
-            ef_proto["r1"] = jax.ShapeDtypeStruct(
-                (z_padded * mult,), jnp.float32
-            )
-            if not zero1:
-                ef_proto["r2"] = jax.ShapeDtypeStruct(
-                    (z_padded // n_dp * mult,), jnp.float32
-                )
-            ef_specs = jax.tree.map(
-                lambda _: P(ef_axes), ef_proto,
-                is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct),
-            )
-        self._ef_layout = (
-            (comp, z_padded, z_bucket_len) if comp and use_ef else None
-        )
-        self._ef_specs = ef_specs
         batch_spec = P(
             dp_axes if len(dp_axes) > 1 else dp_axes[0], SEQ_AXIS
         )
-        optimizer = self.optimizer
-
         # chunked-head resolution: the streamed head is a MEMORY
         # feature — at 8B-scale vocab the [N, V] logits don't fit
         # next to the activations — not a throughput one (benched on
@@ -914,7 +817,6 @@ class Llama(TMModel):
         expert_mask = jax.tree.map(
             _leaf_has_expert, specs, is_leaf=lambda s: isinstance(s, P)
         )
-        dp_spec = dp_axes if len(dp_axes) > 1 else dp_axes[0]
         ep = self.ep
 
         def step(params, opt_state, ef, x, y, lr):
@@ -989,78 +891,10 @@ class Llama(TMModel):
             (loss, (err, *routing)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(params_v)
-            if self.n_experts:
-                # expert-sharded grads: the all_to_all transpose
-                # already summed the ep group's token cotangents at
-                # each owner, so the global mean over e*d replicas is
-                # (mean over data) / ep; every other leaf averages
-                # over the full (expert, data) replica set.  The MoE
-                # exchange stays per-leaf/unbucketed: expert and
-                # dense leaves reduce over DIFFERENT axis sets, so
-                # one flat bucket buffer cannot span both groups
-                # (same split that keeps MoE+zero1 NotImplementedError)
-                def exch(g, is_exp):
-                    if is_exp:
-                        g = strat(g, DATA_AXIS)
-                        return (g / ep).astype(g.dtype) if ep > 1 else g
-                    return strat(g, dp_spec)
-
-                grads = jax.tree.map(exch, grads, expert_mask)
-                with jax.named_scope("opt_update"):
-                    params, opt_state = optimizer.update(
-                        params, grads, opt_state, lr
-                    )
-            elif zero1:
-                # ZeRO-1: reduce-scatter the packed local grads over
-                # the DP replica axes, update the optimizer on this
-                # device's flat 1/N shard (opt_state IS that shard —
-                # in_specs slice it), all-gather the updated params.
-                # Same wire bytes as the two-phase allreduce; the
-                # replicated fp32 m/v never exist.  With buckets the
-                # exchange pipelines per bucket (opt_state sliced
-                # inside scatter_update_gather — 3-arg closure).
-                # exch_compression quantizes the grad reduce-scatter
-                # (1-byte chunks + scales; param gather stays master
-                # width) with the EF residual threaded through ef.
-                def opt_upd(p_shard, g_shard, state):
-                    return optimizer.update(
-                        p_shard, g_shard, state, lr
-                    )
-
-                if comp:
-                    params, new_opt, r1n = scatter_update_gather(
-                        params, grads, opt_upd, dp_spec,
-                        opt_state=opt_state,
-                        bucket_elems=bucket_elems,
-                        compression=comp, r1=ef.get("r1"),
-                    )
-                    if "r1" in ef:
-                        ef = {"r1": r1n}
-                else:
-                    params, new_opt = scatter_update_gather(
-                        params, grads, opt_upd, dp_spec,
-                        wire_dtype=strat.wire_dtype,
-                        opt_state=opt_state,
-                        bucket_elems=bucket_elems,
-                    )
-                opt_state = new_opt
-            else:
-                if comp:
-                    grads, r1n, r2n = compressed_allreduce_mean(
-                        grads, dp_spec, compression=comp,
-                        r1=ef.get("r1"), r2=ef.get("r2"),
-                        bucket_elems=bucket_elems,
-                    )
-                    if "r1" in ef:
-                        ef = {"r1": r1n, "r2": r2n}
-                else:
-                    grads = strat(grads, dp_spec, bucket_elems)
-                # profiler scope (obs/profiler.py): the optimizer
-                # update is its own step-phase leg
-                with jax.named_scope("opt_update"):
-                    params, opt_state = optimizer.update(
-                        params, grads, opt_state, lr
-                    )
+            params, opt_state, ef = plan.apply(
+                params, grads, opt_state, ef, lr,
+                expert_mask=expert_mask, ep=ep,
+            )
             loss = lax.pmean(loss, dp_axes)
             err = lax.pmean(err, dp_axes)
             return params, opt_state, ef, loss, err, *routing
@@ -1069,13 +903,8 @@ class Llama(TMModel):
             logits = self._forward(params, x)
             return self._metrics(logits, y, top5=True)
 
-        # TPU compiler knobs (utils/xla_options).
-        # A bucketed exchange also feeds the overlap preset (async
-        # collectives + latency-hiding scheduler) — TPU meshes only
-        # (the CPU client rejects unknown xla_tpu_* options) and only
-        # when the layout ACTUALLY bucketed (degraded-to-monolithic
-        # models keep compiler_options None so compile-cache keys
-        # don't churn; the MoE per-leaf exchange never buckets).
+        # TPU compiler knobs (utils/xla_options); the overlap preset
+        # under the same gate as ClassifierModel.compile_iter_fns
         from theanompi_tpu.utils.xla_options import xla_compiler_options
 
         is_tpu = mesh.devices.flat[0].platform == "tpu"
@@ -1083,7 +912,7 @@ class Llama(TMModel):
         moe_out = self._moe_out_specs = (P(),) if self.n_experts else ()
         self._compiler_options = xla_compiler_options(
             self.config,
-            overlap=bool(z_bucket_len) and not self.n_experts and is_tpu,
+            overlap=plan.bucketed and is_tpu,
         )
         self._train_step = jax.jit(
             jax.shard_map(
@@ -1129,69 +958,19 @@ class Llama(TMModel):
 
             def init(key):
                 params = self._init_full_params(key)
-                if zero1:
-                    # shard-shaped zero1 state: flat zeros, sliced
-                    # onto the mesh by out_shardings (the full
-                    # replicated m/v never materialize)
-                    opt = jax.tree.map(
-                        lambda x: jnp.zeros((z_global_len,), x.dtype)
-                        if jnp.ndim(x) else x,
-                        z_state_proto,
-                    )
-                else:
-                    opt = self.optimizer.init(params)
-                return params, opt
+                # zero1: flat shards sliced onto the mesh by
+                # out_shardings (the replicated m/v never materialize)
+                return params, (
+                    plan.init_opt_state() if plan.zero1
+                    else self.optimizer.init(params)
+                )
 
             self.params, self.opt_state = jax.jit(
                 init, out_shardings=(shardings, opt_shardings),
                 compiler_options=self._compiler_options,
             )(jax.random.PRNGKey(self.seed))
-        # EF residuals: fresh zeros unless a checkpoint restore
-        # brought them in (then the layout must match — a residual in
-        # the wrong flat order would re-inject rows against the wrong
-        # parameters)
-        if ef_proto and getattr(self, "_restored_ef_orphaned", False):
-            raise ValueError(
-                "a checkpoint restored BEFORE this compile carried an "
-                "EF residual (ef_layout stamped) that load() could "
-                "not attach — the model had no compressed exchange "
-                "yet.  Compiling now would silently zero the "
-                "residual; compile_iter_fns first, then load()"
-            )
-        if ef_proto and getattr(self, "_restored_ef", False):
-            saved = getattr(self, "_restored_ef_layout", None)
-            ok = (
-                saved is not None
-                and tuple(saved) == self._ef_layout
-                and isinstance(self.ef_state, dict)
-                and set(self.ef_state) == set(ef_proto)
-                and all(
-                    tuple(jnp.shape(self.ef_state[k])) == tuple(v.shape)
-                    for k, v in ef_proto.items()
-                )
-            )
-            if not ok:
-                raise ValueError(
-                    "compile_iter_fns with exch_compression after a "
-                    "checkpoint restore found an EF residual that "
-                    "does not match the compiled exchange layout "
-                    "(compression, padded, bucket_len) — compile "
-                    "first, then load(); cross-layout resume is not "
-                    "supported"
-                )
-        elif ef_proto:
-            ef_shardings = jax.tree.map(
-                lambda s: NamedSharding(mesh, s), ef_specs,
-                is_leaf=lambda x: isinstance(x, P),
-            )
-            self.ef_state = jax.jit(
-                lambda: jax.tree.map(
-                    lambda sd: jnp.zeros(sd.shape, sd.dtype), ef_proto
-                ),
-                out_shardings=ef_shardings,
-            )()
-        else:
-            self.ef_state = {}
+        if not plan.keeps_restored_ef(self.ef_state, self._restored):
+            self.ef_state = plan.init_ef(mesh)
         self._batch_sharding = NamedSharding(mesh, batch_spec)
         self._init_feed(
             self._batch_sharding, dtypes=(jnp.int32, jnp.int32)
@@ -1220,7 +999,7 @@ class Llama(TMModel):
         # (mesh data axis x b_loc == gb already asserted by
         # compile_iter_fns before this runs)
         specs, opt_specs = self._specs, self._opt_specs
-        ef_specs = self._ef_specs
+        ef_specs = self.exchange.ef_specs
         rep = NamedSharding(self.mesh, P())
 
         d_size = self.mesh.shape[DATA_AXIS]
@@ -1446,7 +1225,7 @@ class Llama(TMModel):
         self.params = put(self.params, self._specs)
         self.opt_state = put(self.opt_state, self._opt_specs)
         if getattr(self, "ef_state", None):
-            self.ef_state = put(self.ef_state, self._ef_specs)
+            self.ef_state = put(self.ef_state, self.exchange.ef_specs)
 
 
 # Llama-3-8B shape (the BASELINE stretch config), for reference and

@@ -54,6 +54,7 @@ from theanompi_tpu.parallel.moe import (
     moe_ffn,
     router_topk,
 )
+from theanompi_tpu.parallel.plan import ExchangePlan
 from theanompi_tpu.parallel.strategies import (
     COMPRESSION_CHOICES,
     DEFAULT_BUCKET_MB,
@@ -101,6 +102,7 @@ __all__ = [
     "replica_consistency_delta",
     "COMPRESSION_CHOICES",
     "DEFAULT_BUCKET_MB",
+    "ExchangePlan",
     "ExchangeStrategy",
     "get_strategy",
     "resolve_bucket_mb",

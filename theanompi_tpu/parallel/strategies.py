@@ -36,11 +36,11 @@ from theanompi_tpu.parallel.exchange import allreduce_mean
 class ExchangeStrategy:
     """A named allreduce flavor: wire dtype + collective shape.
 
-    ``zero1=True`` marks the ZeRO-1 strategies: the models swap the
-    allreduce-then-replicated-update step body for
+    ``zero1=True`` marks the ZeRO-1 strategies: ``ExchangePlan.apply``
+    swaps the allreduce-then-replicated-update step body for
     ``exchange.scatter_update_gather`` (reduce-scatter grads → update
-    the optimizer on the 1/N shard → all-gather updated params) and
-    initialize SHARD-shaped optimizer state.  Calling a zero1 strategy
+    the optimizer on the 1/N shard → all-gather updated params) over
+    SHARD-shaped optimizer state.  Calling a zero1 strategy
     directly still allreduce-means (the two-phase wire it shares) —
     auxiliary exchanges like BN-stat sync route through it unchanged.
 
@@ -98,8 +98,8 @@ STRATEGIES: dict[str, ExchangeStrategy] = {
 
 # exchange_bucket_mb default: DDP-style ~4 MiB buckets (Li et al.
 # 2020's knee between per-collective launch overhead and overlap
-# granularity); 0 = monolithic.  ONE resolver so the worker's summary,
-# the models' step bodies, and the validation always agree.
+# granularity); 0 = monolithic.  ONE resolver, read through
+# ``plan.ExchangePlan`` by the workers and the models' compiles.
 DEFAULT_BUCKET_MB = 4.0
 
 
@@ -121,8 +121,7 @@ def resolve_bucket_mb(config: dict | None) -> float:
 # with an error-feedback residual carried in worker state so the
 # quantization error is re-injected next step (error_feedback=True,
 # the default; False drops it — plain QSGD, for A/B only).  ONE
-# resolver (the resolve_bucket_mb pattern) so worker validation,
-# model compile, and the run summary always agree.
+# resolver (the resolve_bucket_mb pattern).
 COMPRESSION_CHOICES = ("none", "int8", "fp8")
 
 

@@ -18,7 +18,12 @@ from typing import Any, Sequence
 
 from theanompi_tpu import launcher as _launcher
 from theanompi_tpu.obs.setup import begin_setup
-from theanompi_tpu.parallel import default_devices, dp_replicas, make_mesh
+from theanompi_tpu.parallel import (
+    ExchangePlan,
+    default_devices,
+    dp_replicas,
+    make_mesh,
+)
 from theanompi_tpu.utils import Recorder, faults as _faults
 from theanompi_tpu.utils import supervisor as _sup
 
@@ -291,25 +296,11 @@ def run(
     Model = _resolve_model(modelfile, modelclass)
     cfg = dict(config or {})
     cfg.update(extra)
-    # resolve the strategy BEFORE the (possibly multi-minute) model
-    # build so a typo'd name fails in milliseconds, and so the run
-    # summary can carry the resolved name (zero1 runs shard their
-    # optimizer state — the checkpoint format follows)
-    from theanompi_tpu.parallel import (
-        get_strategy,
-        resolve_bucket_mb,
-        resolve_compression,
-    )
-
-    strat = get_strategy(
-        exch_strategy or cfg.get("exch_strategy", "ici32")
-    )
-    # bucketed-exchange + compression knobs, validated here for the
-    # same reason as the strategy name: a bad value must fail before
-    # the model build (resolve_* are the ONE resolvers — the models'
-    # step bodies read the same rules, so summary and compile agree)
-    bucket_mb = resolve_bucket_mb(cfg)
-    compression, error_feedback = resolve_compression(cfg)
+    # resolve the exchange knobs (strategy, buckets, compression)
+    # BEFORE the (possibly multi-minute) model build so a typo fails
+    # in milliseconds, and so the run summary carries what the model's
+    # compile resolves by the same rules
+    exchange = ExchangePlan.from_config(cfg, exch_strategy)
     if str(cfg.get("elastic_batch_policy", "global")) \
             not in ELASTIC_BATCH_POLICIES:
         raise ValueError(
@@ -341,7 +332,9 @@ def run(
         model = Model(cfg)
         model.build_model(n_replicas=n_replicas)
     with setup.phase("compile_iter_fns"):
-        model.compile_iter_fns(mesh=mesh, exch_strategy=strat.name)
+        model.compile_iter_fns(
+            mesh=mesh, exch_strategy=exchange.strategy.name
+        )
 
     recorder = Recorder(
         rank=0, size=n_replicas, print_freq=print_freq, verbose=verbose
@@ -414,14 +407,15 @@ def run(
         print(
             f"BSP: {n_replicas} replicas, {data.n_batch_train} train batches"
             f" x {data.global_batch} global batch, "
-            f"exchange={strat.name}"
-            + (" (ZeRO-1 sharded optimizer)" if strat.zero1 else "")
-            + (f", buckets {bucket_mb:g} MiB" if bucket_mb else
+            f"exchange={exchange.strategy.name}"
+            + (" (ZeRO-1 sharded optimizer)" if exchange.zero1 else "")
+            + (f", buckets {exchange.bucket_mb:g} MiB"
+               if exchange.bucket_mb else
                ", monolithic exchange")
             + (
-                f", {compression} wire"
-                + ("+EF" if error_feedback else " (no EF)")
-                if compression else ""
+                f", {exchange.compression} wire"
+                + ("+EF" if exchange.error_feedback else " (no EF)")
+                if exchange.compression else ""
             ),
             flush=True,
         )
@@ -598,12 +592,12 @@ def run(
     last_val = recorder.val_records[-1] if recorder.val_records else {}
     return {
         "epochs": model.epoch,
-        "exch_strategy": strat.name,
-        "exchange_bucket_mb": bucket_mb,
+        "exch_strategy": exchange.strategy.name,
+        "exchange_bucket_mb": exchange.bucket_mb,
         "exchange_replicas": getattr(model, "exchange_replicas", None),
         "exchange_buckets": getattr(model, "exchange_buckets", None),
-        "exch_compression": compression or "none",
-        "error_feedback": bool(compression) and error_feedback,
+        "exch_compression": exchange.compression or "none",
+        "error_feedback": exchange.error_feedback,
         "iterations": recorder.n_iter,
         "final_train_loss": (
             recorder.train_losses[-1] if recorder.train_losses else None

@@ -445,15 +445,12 @@ def _run_distributed(
     # quantized codec (4x) — the worker carries a push-leg EF residual
     # inside the client so its time-averaged contribution to the
     # center stays unbiased.
-    from theanompi_tpu.parallel import get_strategy, resolve_compression
+    from theanompi_tpu.parallel import ExchangePlan
 
-    comp, use_ef = resolve_compression(cfg)
-    wire = comp or get_strategy(
-        cfg.get("exch_strategy", "ici32")
-    ).wire_dtype
+    exchange = ExchangePlan.from_config(cfg)
     tcp = EASGDCenterClient(
         (addr.rsplit(":", 1)[0], int(addr.rsplit(":", 1)[1])),
-        wire=wire, error_feedback=use_ef,
+        wire=exchange.wire, error_feedback=exchange.error_feedback,
     )
 
     data = model.data

@@ -423,11 +423,9 @@ def _run_distributed(
     # there is no single counterpart whose view a residual could
     # unbias; the score-weighted merge dilutes the per-push rounding
     # instead (documented in PERFORMANCE.md).
-    from theanompi_tpu.parallel import get_strategy, resolve_compression
+    from theanompi_tpu.parallel import ExchangePlan
 
-    wire = resolve_compression(cfg)[0] or get_strategy(
-        cfg.get("exch_strategy", "ici32")
-    ).wire_dtype
+    wire = ExchangePlan.from_config(cfg).wire
     recorder = Recorder(
         rank=pid, size=n_procs, print_freq=print_freq, verbose=verbose
     )

@@ -93,8 +93,8 @@ class ReplicaEngine:
         self.net_state = broadcast_stack(
             model.net_state, self.n_workers, self.stacked_sharding
         )
-        # a model compiled with a zero1 strategy (model._zero1, set by
-        # compile_iter_fns) holds a ZeRO-sharded FLAT optimizer buffer
+        # a model compiled with a zero1 strategy (model.exchange, set
+        # by compile_iter_fns) holds a ZeRO-sharded FLAT optimizer buffer
         # (1/N of the state per data-axis device) — the wrong shape
         # for the async rules, where every replica advances
         # independently and owns its whole state.  ONLY then re-init
@@ -102,9 +102,10 @@ class ReplicaEngine:
         # resumed EASGD/GoSGD run restores the checkpointed consensus
         # momentum into it — re-initing unconditionally would
         # silently train from cold momentum).
+        exchange = getattr(model, "exchange", None)
         opt_src = (
             model.optimizer.init(model.params)
-            if getattr(model, "_zero1", False)
+            if exchange is not None and exchange.zero1
             else model.opt_state
         )
         self.opt_state = broadcast_stack(
