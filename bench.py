@@ -213,9 +213,9 @@ def bench_llama(moe: bool = False, long: bool = False,
     cost of routing + dispatch (no baseline key; first captured r4).
 
     ``long=True`` (``TM_BENCH_MODEL=llama_long``): T=8192 at b1 —
-    the long-context single-chip datapoint (full per-layer remat; the
-    remat_save A/B at this length still favors full remat, 33.8k vs
-    32.2k tok/s measured).
+    the long-context single-chip datapoint (per-layer remat; the
+    33.8k vs 32.2k tok/s A/B recorded here compared full remat with
+    an option that skipped no kernel: PERF.md, PR 29).
 
     ``hd128=True`` (``TM_BENCH_MODEL=llama_hd128``): the 8B ATTENTION
     GEOMETRY at proxy depth — head_dim=128 (8 heads x 1024d) with GQA

@@ -269,3 +269,85 @@ def test_dense_mlp_backward_feeds_its_products_arrays(chip):
     # w_down's weight gradient is [ffn, dim]; nothing else may be here
     assert all(re.match(rf"\w+\[{ffn},{dim}(,1)?\]", shape)
                for _, shape in fed_an_exponential), fed_an_exponential
+
+
+def _flash_kernels(text):
+    """How many flash kernels of each kind a compiled text holds: the
+    ``tpu_custom_call``s whose ``op_name`` holds ``_flash_jit``, told
+    apart by what they return, as the benchmark's
+    ``flash_attention_roofline`` does: ``(out, logsumexp)`` is the
+    forward kernel, ``(dk, dv)`` and ``dq`` the two backward ones."""
+    import collections
+    import re
+
+    kinds = collections.Counter()
+    for line in text.splitlines():
+        if "tpu_custom_call" not in line or "_flash_jit" not in line:
+            continue
+        result = re.search(r"=\s*(\(.*?\)|\S+)\s+custom-call\(", line).group(1)
+        kinds["dq" if not result.startswith("(")
+              else "fwd" if "f32[" in result else "dkv"] += 1
+    return kinds
+
+
+@pytest.mark.parametrize(
+    "n_layers,moe", [(2, {}), (1, dict(n_experts=4, moe_top_k=2,
+                                       capacity_factor=None))],
+    ids=["dense_l2", "moe_l1"],
+)
+def test_layer_backward_replays_no_flash_forward_kernel(
+    chip, monkeypatch, n_layers, moe
+):
+    """A ``Llama`` at small widths (256 wide, 2 heads of 128, 2 x 256
+    tokens) through ``_forward``, a loss and ``jax.grad``: the
+    compiled text holds three flash kernels a layer — forward, dK/dV,
+    dQ — because the layer's remat keeps ``FLASH_RESIDUALS``.  With
+    the policy bypassed (full remat: the program before PR 29) the
+    same function holds one more forward kernel for every layer whose
+    replay XLA did not merge with its forward (it merges the LAST
+    layer's when the loss follows it directly; here the final norm and
+    the head lie between), so the count is taken against that compile,
+    not against 4 x layers."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from theanompi_tpu.models.llama import Llama
+    from theanompi_tpu.ops import attention
+    from theanompi_tpu.parallel import make_mesh
+
+    t, b = 256, 2
+    mesh = make_mesh(data=1, devices=list(chip.device_set))
+    batch = P("data", "seq")
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)  # as on the chip
+
+    def kernels(bypass_policy):
+        model = Llama(dict(
+            dim=256, n_layers=n_layers, n_heads=2, n_kv_heads=2,
+            ffn_dim=512, vocab=256, seq_len=t, batch_size=b,
+            compute_dtype="bfloat16", **moe,
+        ))
+        if bypass_policy:
+            model.remat_saves = ()
+
+        def grad(params, ids):
+            def loss(p):
+                logits, aux, _ = model._forward(p, ids, with_aux=True)
+                return logits.astype(jnp.float32).sum() + aux.sum()
+            return jax.grad(loss)(params)
+
+        params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=NamedSharding(mesh, P())
+            ),
+            jax.eval_shape(model._init_full_params, jax.random.key(0)),
+        )
+        ids = jax.ShapeDtypeStruct(
+            (b, t), jnp.int32, sharding=NamedSharding(mesh, batch)
+        )
+        return _flash_kernels(_compiled_text(jax.shard_map(
+            grad, mesh=mesh, in_specs=(P(), batch), out_specs=P(),
+        ), params, ids))
+
+    kept, full = kernels(False), kernels(True)
+    assert kept == dict(fwd=n_layers, dkv=n_layers, dq=n_layers)
+    assert 1 <= full["fwd"] - n_layers <= n_layers, full
+    assert (full["dkv"], full["dq"]) == (n_layers, n_layers), full

@@ -40,7 +40,8 @@ it makes autodiff insert the exactly-right collective transposes
 (psum↔pvary), so gradients of sharded AND replicated params come back
 correct for any mesh layout with no manual grad reduction (verified by
 the layout-invariance tests).  Per-layer ``jax.checkpoint`` (remat)
-bounds activation memory for long sequences.  Params are initialized
+bounds activation memory for long sequences; it keeps the layer's
+input and the flash kernel's two outputs.  Params are initialized
 *under jit with sharded out_shardings*, so the full 8B-scale parameter
 set never materializes on one device.
 
@@ -60,13 +61,12 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from theanompi_tpu.models.base import TMModel
 from theanompi_tpu.models.data.lm_synthetic import MarkovLMData
 from theanompi_tpu.obs.setup import setup_phase
-from theanompi_tpu.ops.attention import flash_attention
+from theanompi_tpu.ops.attention import FLASH_RESIDUALS, flash_attention
 from theanompi_tpu.ops.layers import swiglu
 from theanompi_tpu.ops import optimizers as opt_lib
 from theanompi_tpu.parallel import (
@@ -209,6 +209,9 @@ class Llama(TMModel):
             and (batch * (self.seq_len // self.sp)) % self.pp == 0
         )
         self.remat = bool(c.get("remat", True))
+        # what the per-layer remat keeps from the forward pass
+        # (``_forward``; the run summary's "remat_saves")
+        self.remat_saves = FLASH_RESIDUALS if self.remat else ()
         self.compute_dtype = jnp.dtype(c.get("compute_dtype", "bfloat16"))
         self.seed = int(c.get("seed", 42))
         self.n_epochs = int(c.get("n_epochs", 5))
@@ -413,10 +416,6 @@ class Llama(TMModel):
                 else ulysses_attention
             )
             o = attn(q, k, v, SEQ_AXIS, causal=True, kv_rep=rep)
-        # named for the remat policy: saving the attention output lets
-        # the backward replay skip re-running the flash kernel — the
-        # layer's costliest op — for [B, H_loc, T_loc, hd] of memory
-        o = checkpoint_name(o, "attn_out")
         x = x + tp_lib.row_parallel(_unheads(o), p["wo"]).astype(cdtype)
 
         xn = rms_norm(x, p["mlp_norm"])
@@ -468,18 +467,19 @@ class Llama(TMModel):
         x = x.astype(cdtype)
         layer = self._layer
         if self.remat:
-            # selective remat knob: remat_save=("attn_out",) keeps the
-            # flash output so backward skips replaying the kernel.
-            # Default FULL remat: measured on-chip (8L/1024d, T2048)
-            # the replay is cheaper than the extra HBM traffic
-            # (165.3 vs 168.3 ms/step); the knob exists for
-            # long-context configs where the tradeoff flips.
-            save = tuple(self.config.get("remat_save", ()))
-            policy = (
-                jax.checkpoint_policies.save_only_these_names(*save)
-                if save else None
+            # the replay recomputes everything but the flash forward
+            # kernel: its output and logsumexp (named in its forward
+            # rule, ``ops/attention.py``) are kept, [B, H_loc, T, hd]
+            # and 4 bytes a row, so the backward runs dK/dV and dQ
+            # only.  Where the kernel does not run (dense path;
+            # ``ring_attention``, whose own vjp calls the kernels
+            # unnamed) the names never occur: full remat.
+            layer = jax.checkpoint(
+                self._layer,
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    *self.remat_saves
+                ),
             )
-            layer = jax.checkpoint(self._layer, policy=policy)
 
         moe = bool(self.n_experts)
         aux = jnp.zeros((2,), jnp.float32)

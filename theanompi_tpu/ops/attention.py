@@ -30,12 +30,19 @@ import logging
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 logger = logging.getLogger(__name__)
 
 NEG_INF = -1e30  # finite "-inf": keeps exp() NaN-free in masked blocks
+
+# ``jax.ad_checkpoint.checkpoint_name``s of the two residuals the
+# forward kernel alone can give the backward rule of ``_flash``: its
+# output and the rows' logsumexp.  A ``jax.checkpoint`` whose policy
+# saves these two replays no forward kernel (``Llama._forward``).
+FLASH_RESIDUALS = ("flash_out", "flash_lse")
 
 
 def causal_mask(q_pos: jnp.ndarray, k_pos: jnp.ndarray) -> jnp.ndarray:
@@ -388,6 +395,15 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k,
     out, lse = _flash_fwd_call(
         q, k, v, causal, sm_scale, block_q, block_k, interpret
     )
+    # named HERE, on the values the backward rule reads: a name on the
+    # caller's copy of ``out`` saves an array and still replays the
+    # kernel for ``lse`` (PERF.md, PR 29)
+    out = checkpoint_name(out, FLASH_RESIDUALS[0])
+    # kept lane-dense, [B, H, T]: the kernel's [B*H, T, 1] tiles its
+    # trailing 1 to 128 lanes on the chip, 128x the bytes for every
+    # layer's forward-to-backward lifetime; ``_flash_bwd`` gives the
+    # kernels their view back
+    lse = checkpoint_name(lse.reshape(q.shape[:3]), FLASH_RESIDUALS[1])
     return out, (q, k, v, out, lse)
 
 

@@ -593,6 +593,9 @@ def run(
     return {
         "epochs": model.epoch,
         "exch_strategy": exchange.strategy.name,
+        # checkpoint names the model's per-layer remat keeps ([] when
+        # it keeps everything, or the model has no such remat)
+        "remat_saves": list(getattr(model, "remat_saves", ())),
         "exchange_bucket_mb": exchange.bucket_mb,
         "exchange_replicas": getattr(model, "exchange_replicas", None),
         "exchange_buckets": getattr(model, "exchange_buckets", None),
