@@ -135,6 +135,9 @@ PROFILE_SCOPES: dict[str, str] = {
     "moe_dispatch": "moe_dispatch",
     "moe_experts": "moe_experts",
     "moe_combine": "moe_combine",
+    # the tile plan of the grouped-product kernels, built once a layer
+    # call inside ``moe_experts`` (parallel/moe.py, PR 31)
+    "moe_tile_plan": "moe_experts",
     # the dense MLP's activation gradient (ops/layers.py swiglu, PR 27)
     "mlp_act_grad": "mlp_act_grad",
 }

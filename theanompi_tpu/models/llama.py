@@ -67,6 +67,7 @@ from theanompi_tpu.models.base import TMModel
 from theanompi_tpu.models.data.lm_synthetic import MarkovLMData
 from theanompi_tpu.obs.setup import setup_phase
 from theanompi_tpu.ops.attention import FLASH_RESIDUALS, flash_attention
+from theanompi_tpu.ops.grouped_matmul import TILE_PLAN_RESIDUAL
 from theanompi_tpu.ops.layers import swiglu
 from theanompi_tpu.ops import optimizers as opt_lib
 from theanompi_tpu.parallel import (
@@ -210,8 +211,13 @@ class Llama(TMModel):
         )
         self.remat = bool(c.get("remat", True))
         # what the per-layer remat keeps from the forward pass
-        # (``_forward``; the run summary's "remat_saves")
-        self.remat_saves = FLASH_RESIDUALS if self.remat else ()
+        # (``_forward``; the run summary's "remat_saves"): the flash
+        # kernel's two outputs and, for a dropless expert layer, the
+        # tile plan of its grouped products (a few KB)
+        saves = FLASH_RESIDUALS
+        if self.n_experts and self.capacity_factor is None:
+            saves += (TILE_PLAN_RESIDUAL,)
+        self.remat_saves = saves if self.remat else ()
         self.compute_dtype = jnp.dtype(c.get("compute_dtype", "bfloat16"))
         self.seed = int(c.get("seed", 42))
         self.n_epochs = int(c.get("n_epochs", 5))
@@ -473,7 +479,9 @@ class Llama(TMModel):
             # and 4 bytes a row, so the backward runs dK/dV and dQ
             # only.  Where the kernel does not run (dense path;
             # ``ring_attention``, whose own vjp calls the kernels
-            # unnamed) the names never occur: full remat.
+            # unnamed) the names never occur: full remat.  A dropless
+            # expert layer's tile plan (``parallel/moe.py``) is kept
+            # the same way: built once a layer call.
             layer = jax.checkpoint(
                 self._layer,
                 policy=jax.checkpoint_policies.save_only_these_names(

@@ -38,10 +38,15 @@ Design:
   the ``k·N`` (token, pick) rows are sorted by expert id (stable, so
   slot-major inside an expert: the result does not depend on a
   tie-break), the rows gathered in that order, and the three SwiGLU
-  products run as GROUPED products (``lax.ragged_dot`` with the
-  per-expert row counts; the v5e compiler makes each a Mosaic kernel
-  whose work follows the rows, forward and both backward products —
-  PERF.md, PR 26).  Each row's gate multiplies its hidden activations
+  products run as GROUPED products whose work follows the rows,
+  forward and both backward products.  On a TPU, where the shapes
+  tile, they are the kernels of ``ops/grouped_matmul.py`` over ONE
+  tile plan built from the per-expert row counts per layer call
+  (PERF.md, PR 31); elsewhere — CPU tests, the CPU mesh, odd shapes —
+  ``lax.ragged_dot`` with the same counts, for its CPU lowering,
+  autodiff and vma rules (``_grouped_product`` chooses, and logs a
+  ``ragged_dot`` choice once per shape).  Each row's gate
+  multiplies its hidden activations
   in fp32 inside the ``silu·up`` fusion (the down product is linear),
   so the combine is the plain fp32 sum of a token's ``k`` rows, read
   through the inverse permutation.  Dispatch (token rows out to
@@ -50,9 +55,10 @@ Design:
   both ways, never a scatter-add.
 - **Spans**: ``jax.named_scope``s ``moe_route`` (router, top-k, aux
   moments), ``moe_dispatch`` (plan + row gather / capacity buffers),
-  ``moe_experts`` (the products) and ``moe_combine`` (un-permute,
-  gates) are in the metadata of every instruction of the layer,
-  forward and backward (docs/OBSERVABILITY.md).
+  ``moe_experts`` (the products; inside it ``moe_tile_plan``, the
+  kernels' tile plan) and ``moe_combine`` (un-permute, gates) are in
+  the metadata of every instruction of the layer, forward and
+  backward (docs/OBSERVABILITY.md).
 
 Capacity per device-expert is ``C = ceil(cf · k · N / E)`` rounded up
 to a multiple of 8 (TPU sublane) where ``N`` is the LOCAL token count:
@@ -64,12 +70,18 @@ setting the cross-layout invariance tests use.
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
+from theanompi_tpu.ops import attention
+from theanompi_tpu.ops import grouped_matmul as gmm
 from theanompi_tpu.parallel.mesh import EXPERT_AXIS, MODEL_AXIS
+
+logger = logging.getLogger(__name__)
 
 
 def moe_capacity(
@@ -211,6 +223,39 @@ _permute.defvjp(
 )
 
 
+@functools.lru_cache(maxsize=None)
+def _log_ragged_choice(rows: int, d: int, f: int, dtype: str,
+                       on_tpu: bool) -> None:
+    """One log line per shape — a warning on TPU, where XLA's own
+    grouped kernels run at half the matrix unit's rate (PERF.md,
+    PR 31).  ``lru_cache`` is the once-only latch."""
+    logger.log(
+        logging.WARNING if on_tpu else logging.INFO,
+        "moe: lax.ragged_dot for %d rows x %d x %d %s — %s",
+        rows, d, f, dtype,
+        "no aligned tile divides these shapes" if on_tpu
+        else "not on TPU devices",
+    )
+
+
+def _grouped_product(group_sizes, rows: int, d: int, f: int, dtype):
+    """``product(lhs [rows, .], w [E, ., .])`` for the layer's three
+    grouped products: on a TPU, shapes permitting, the repo's kernels
+    over one tile plan built HERE, once, and shared by all of them,
+    forward, replay and backward; ``lax.ragged_dot`` otherwise."""
+    on_tpu = attention._on_tpu()
+    if (on_tpu and gmm.shapes_tile(rows, d, f, dtype)
+            and gmm.shapes_tile(rows, f, d, dtype)):
+        with jax.named_scope("moe_tile_plan"):
+            plan = checkpoint_name(
+                gmm.make_tile_plan(group_sizes, rows, gmm.tile_rows(rows)),
+                gmm.TILE_PLAN_RESIDUAL,
+            )
+        return lambda lhs, w: gmm.grouped_matmul(lhs, w, plan)
+    _log_ragged_choice(rows, d, f, str(dtype), on_tpu)
+    return lambda lhs, w: lax.ragged_dot(lhs, w, group_sizes)
+
+
 def _dropless_experts(x2, gates, eidx, we_gate, we_up, we_down, *,
                       n_experts: int, model_axis):
     """Every pick computed: sort, gather, grouped SwiGLU with each
@@ -234,7 +279,8 @@ def _dropless_experts(x2, gates, eidx, we_gate, we_up, we_down, *,
     with jax.named_scope("moe_experts"):
         out = _swiglu_experts(
             rows, we_gate, we_up, we_down,
-            lambda lhs, w: lax.ragged_dot(lhs, w, group_sizes),
+            _grouped_product(group_sizes, k * n, *we_gate.shape[1:],
+                             rows.dtype),
             row_scale=row_gate,
         )
         if model_axis is not None:
