@@ -76,6 +76,38 @@ def test_flash_attention_compiles_for_v5e(chip, shape, direction):
     assert "tpu_custom_call" in _compiled_text(fn, x, x, x)
 
 
+def test_cells_flash_shape_compiles_to_the_three_kernels_the_reader_knows(chip):
+    """The benchmark's two transformer cells hand the kernels
+    ``[2, 32, 4096, 128]`` bf16 (OLMoE ``[4, 16, ...]``: the same 64
+    batch-heads).  Forward and backward in one program compile to
+    exactly three ``_flash_jit`` custom calls, and the benchmark's
+    ``flash_attention_roofline`` reader tells them apart by what they
+    return — ``(out, f32 logsumexp)``, ``(dk, dv)``, ``dq`` — whatever
+    tiles the shape function chose."""
+    from benchmark import hlo_read
+    from benchmark.layer_metrics.flash_attention_roofline import kernel_kind
+
+    x = jax.ShapeDtypeStruct((2, 32, 4096, 128), jnp.bfloat16, sharding=chip)
+
+    def both(q, k, v):
+        out, vjp = jax.vjp(
+            lambda *a: flash_attention_tpu(*a, causal=True), q, k, v
+        )
+        return out, vjp(out)
+
+    text = _compiled_text(both, x, x, x)
+    kinds = sorted(
+        kernel_kind(line)
+        for line in hlo_read.custom_calls(text).values()
+        if "_flash_jit" in line
+    )
+    assert kinds == ["dkv", "dq", "fwd"], kinds
+    assert _flash_kernels(text) == dict(fwd=1, dkv=1, dq=1)
+    # the statistics cross the kernels' edge lane-dense: no
+    # [B*H, T, 1] array, whose tiles are 128 times its values
+    assert "f32[64,4096,1]" not in text and "f32[2,32,4096,1]" not in text
+
+
 @pytest.mark.parametrize("nq", [1, 4], ids=["decode", "verify4"])
 @pytest.mark.parametrize(
     "hkv,rep,hd", [(8, 2, 64), (2, 4, 128)], ids=["hd64", "hd128"]

@@ -1,0 +1,248 @@
+"""The three flash-attention kernels against dense attention, in the
+Pallas interpreter, over the tilings their shape function can choose:
+one block, several outer blocks, several score tiles inside a block —
+so that every case set holds a tile wholly under the diagonal (the
+body without a mask), one the diagonal crosses and one that is
+skipped — and the shape function itself.
+
+Tolerances are those of ``tests/test_ring_attention.py``.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from theanompi_tpu.ops import attention
+from theanompi_tpu.ops.attention import (
+    FlashPlan,
+    FlashTiles,
+    _auto_block,
+    _flash_bwd_call,
+    _flash_fwd_call,
+    _flash_tiles,
+    _sub_block_kind,
+    _walked_index,
+    flash_tiles_summary,
+    mha_reference,
+)
+
+B, H = 1, 2
+
+# (T_q, T_k, (rows, major, sub) of forward and dQ, of dK/dV)
+TILINGS = {
+    "one_block": (32, 32, (32, 32, 32), (32, 32, 32)),
+    "outer_blocks": (64, 64, (16, 16, 16), (16, 16, 16)),
+    "sub_tiles": (64, 64, (32, 64, 16), (32, 64, 16)),
+    "sub_tiles_wider_than_rows": (64, 64, (16, 64, 32), (16, 32, 32)),
+    "whole_axis_resident": (96, 96, (32, 96, 32), (48, 96, 16)),
+    # ring attention's visiting blocks and a decoder's prefix
+    "short_queries": (32, 64, (16, 32, 16), (16, 32, 16)),
+    "short_keys": (64, 32, (16, 32, 16), (16, 64, 16)),
+}
+
+
+def _kinds(t_rows, t_walk, tiles, rows_are_queries):
+    """Which bodies a causal kernel with these tiles takes."""
+    rows, _, sub = tiles
+    seen = set()
+    for r in range(0, t_rows, rows):
+        for lo in range(0, t_walk, sub):
+            clear, crossed = _sub_block_kind(
+                lo, sub, r, rows, rows_are_queries
+            )
+            seen.add("clear" if clear else "crossed" if crossed else "skipped")
+    return seen
+
+
+def test_case_sets_hold_every_kind_of_tile():
+    for name, (t_q, t_k, on_q, on_k) in TILINGS.items():
+        if name == "one_block":
+            continue
+        assert _kinds(t_q, t_k, on_q, True) == {"clear", "crossed", "skipped"}, name
+        assert _kinds(t_k, t_q, on_k, False) == {"clear", "crossed", "skipped"}, name
+
+
+@pytest.mark.parametrize("tiling", TILINGS, ids=str)
+def test_a_skipped_step_names_a_block_that_exists(tiling):
+    """The clamped index maps stay inside the walked axis (the
+    interpreter would clamp a stray index itself; the chip's DMA would
+    not) and never name a block a step with work would not."""
+    t_q, t_k, on_q, on_k = TILINGS[tiling]
+    for tiles, t_rows, t_walk, rows_are_queries in (
+        (FlashTiles(*on_q), t_q, t_k, True), (FlashTiles(*on_k), t_k, t_q, False),
+    ):
+        n = t_walk // tiles.major
+        index = _walked_index(True, tiles, rows_are_queries, n)
+        for r in range(t_rows // tiles.rows):
+            for w in range(n):
+                got = int(index(r, w))
+                assert 0 <= got < n
+                kinds = [
+                    _sub_block_kind(w * tiles.major + c, tiles.sub,
+                                    r * tiles.rows, tiles.rows, rows_are_queries)
+                    for c in range(0, tiles.major, tiles.sub)
+                ]
+                if any(clear or crossed for clear, crossed in kinds):
+                    assert got == w          # a step with work fetches its own
+
+
+def _operands(rng, t_q, t_k, d, dtype=jnp.float32):
+    def draw(t):
+        return jnp.asarray(rng.standard_normal((B, H, t, d)), dtype)
+    return draw(t_q), draw(t_k), draw(t_k), draw(t_q)
+
+
+def _dense(q, k, v, g, causal):
+    """Output, logsumexp and the three gradients of dense attention."""
+    out, vjp = jax.vjp(
+        lambda q, k, v: mha_reference(q, k, v, causal=causal), q, k, v
+    )
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * q.shape[-1] ** -0.5
+    if causal:
+        t_q, t_k = s.shape[-2:]
+        s = jnp.where(
+            jnp.arange(t_q)[:, None] >= jnp.arange(t_k)[None, :], s, -jnp.inf
+        )
+    return (out, jax.nn.logsumexp(s, axis=-1)) + vjp(g)
+
+
+def _kernels(q, k, v, g, causal, on_q, on_k):
+    sm = q.shape[-1] ** -0.5
+    out, lse = _flash_fwd_call(
+        q, k, v, causal, sm, FlashTiles(*on_q), True
+    )
+    delta = jnp.sum(
+        g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
+    )
+    dq, dk, dv = _flash_bwd_call(
+        q, k, v, g, lse, delta, causal, sm,
+        FlashTiles(*on_k), FlashTiles(*on_q), True,
+    )
+    return out, lse, dq, dk, dv
+
+
+@pytest.mark.parametrize("tiling", TILINGS, ids=str)
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("d", [64, 128], ids=["hd64", "hd128"])
+def test_kernels_match_dense_attention(rng, d, causal, tiling):
+    t_q, t_k, on_q, on_k = TILINGS[tiling]
+    q, k, v, g = _operands(rng, t_q, t_k, d)
+    got = _kernels(q, k, v, g, causal, on_q, on_k)
+    want = _dense(q, k, v, g, causal)
+    assert got[1].shape == (B, H, t_q)          # lane-dense at the edge
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), got, want):
+        tol = 2e-5 if name in ("out", "lse") else 2e-4
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=tol, atol=tol,
+            err_msg=f"{name} ({tiling})",
+        )
+
+
+@pytest.mark.parametrize("tiling", ["one_block", "sub_tiles"], ids=str)
+def test_bf16_gradients_stay_within_rounding_of_the_parents(rng, tiling):
+    """The backward feeds ``p`` and ``ds`` to its products in the input
+    dtype, as the forward always has (the parent cast the other operand
+    up instead, and the matrix unit rounded both: PERF.md, PR 32), and
+    the scale rounds into the query block.  Against the parent's
+    formula — the same bf16 inputs, ``p`` and ``ds`` kept float32 —
+    the gradients move by bf16 rounding of an operand, no more."""
+    t_q, t_k, on_q, on_k = TILINGS[tiling]
+    q, k, v, g = _operands(rng, t_q, t_k, 64, jnp.bfloat16)
+    out, _, dq, dk, dv = _kernels(q, k, v, g, True, on_q, on_k)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v, g)]
+    want = _dense(*f32, True)
+    for name, a, b in zip(("out", "dq", "dk", "dv"),
+                          (out, dq, dk, dv), want[:1] + want[2:]):
+        scale = float(jnp.max(jnp.abs(b)))
+        err = float(jnp.max(jnp.abs(a.astype(jnp.float32) - b)))
+        # 2**-8: half a bf16 ulp of the operands, with room for the
+        # result's own rounding
+        assert err <= 2 ** -6 * scale, (name, err, scale)
+
+
+# every (T, head_dim, dtype) the repo's models and tests hand the
+# shape function: the cells (4096 x 128), chip_smoke's proxy and the
+# chip-less compiles (2048 x 64 / 128, 256 x 128), the serving
+# decoder's prefill buckets, ring shards, the float32 test lengths
+MODEL_SHAPES = [
+    (t, d, dtype)
+    for dtype in ("bfloat16", "float32")
+    for d in (16, 64, 128)
+    for t in (8, 16, 24, 32, 40, 60, 64, 68, 96, 128, 192, 256, 512, 640,
+              1000, 1024, 1280, 2048, 3000, 4096, 8192, 16384, 32768)
+]
+
+
+@pytest.mark.parametrize("t,d,dtype", MODEL_SHAPES, ids=str)
+def test_shape_function(monkeypatch, t, d, dtype):
+    monkeypatch.setenv("TM_FLASH_BWD_BLOCKS", "128,128")  # read by nothing
+    plan = _flash_tiles(t, t, d, dtype)
+    if _auto_block(t, dtype) is None:
+        assert plan is None                    # the dense path, as before
+        return
+    sublane = 8 if dtype == "float32" else 16
+    for rows, major, sub in plan:
+        assert t % rows == 0 and t % major == 0 and major % sub == 0
+        for block in (rows, major, sub):
+            # Mosaic: a block dim is the whole axis or (8|16, 128)-aligned
+            assert block == t or block % 128 == 0
+            assert block % sublane == 0
+        # one fetched block of the walked axis stays a small part of
+        # the 16 MB of scoped VMEM, double-buffered, for K and V
+        assert major * d * np.dtype(dtype).itemsize <= 1 << 20 or major == 128
+    monkeypatch.delenv("TM_FLASH_BWD_BLOCKS")
+    assert _flash_tiles(t, t, d, dtype) == plan
+
+
+def test_dense_path_exactly_where_no_block_tiles():
+    for t, d, dtype in MODEL_SHAPES:
+        for t_k in (t, 2 * t):
+            want = bool(_auto_block(t, dtype) and _auto_block(t_k, dtype))
+            assert (_flash_tiles(t, t_k, d, dtype) is not None) == want
+
+
+def test_summary_counts_the_masked_tiles(monkeypatch):
+    assert flash_tiles_summary(4096, 4096, 128, "bfloat16") == {}  # off the TPU
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    assert flash_tiles_summary(68, 68, 128, "bfloat16") == {}      # no block
+    full = flash_tiles_summary(4096, 4096, 128, "bfloat16", causal=False)
+    assert {k["masked_share"] for k in full.values()} == {0.0}
+    monkeypatch.setattr(
+        attention, "_flash_tiles",
+        lambda *a: FlashPlan(FlashTiles(512, 4096, 512),
+                             FlashTiles(512, 4096, 256),
+                             FlashTiles(1024, 1024, 1024)),
+    )
+    got = flash_tiles_summary(4096, 4096, 128, "bfloat16")
+    assert got["fwd"] == {
+        # 8 row blocks see 1..8 tiles of 512 keys: 36, one crossed each
+        "outer": [512, 4096], "inner": [512, 512],
+        "masked_share": round(8 / 36, 4),
+    }
+    # 8 key blocks are seen by 2, 4, .. 16 tiles of 256 queries, two
+    # of them crossed by the diagonal
+    assert got["dkv"]["masked_share"] == round(16 / 72, 4)
+    # the parent's tiles: 4 of the 10 visited carry the diagonal
+    assert got["dq"]["masked_share"] == 0.4
+
+
+def test_worker_summary_names_the_tiles(monkeypatch):
+    from theanompi_tpu.workers import bsp_worker
+
+    config = dict(
+        dim=32, n_heads=2, n_kv_heads=1, ffn_dim=64, vocab=32, seq_len=32,
+        batch_size=2, n_train=8, n_val=4, compute_dtype="float32",
+        n_layers=1, n_epochs=1, seed=3,
+    )
+    res = bsp_worker.run(
+        devices=[0], modelfile="theanompi_tpu.models.llama",
+        modelclass="Llama", config=config, verbose=False,
+    )
+    assert res["flash_tiles"] == {}             # dense attention here
+    from theanompi_tpu.models.llama import Llama
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    tiles = Llama(dict(config, seq_len=4096, dim=256)).flash_tiles()
+    assert set(tiles) == {"fwd", "dkv", "dq"}
+    assert all(0 < k["masked_share"] < 0.5 for k in tiles.values())

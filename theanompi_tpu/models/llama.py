@@ -66,7 +66,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from theanompi_tpu.models.base import TMModel
 from theanompi_tpu.models.data.lm_synthetic import MarkovLMData
 from theanompi_tpu.obs.setup import setup_phase
-from theanompi_tpu.ops.attention import FLASH_RESIDUALS, flash_attention
+from theanompi_tpu.ops.attention import (
+    FLASH_RESIDUALS,
+    flash_attention,
+    flash_tiles_summary,
+)
 from theanompi_tpu.ops.grouped_matmul import TILE_PLAN_RESIDUAL
 from theanompi_tpu.ops.layers import swiglu
 from theanompi_tpu.ops import optimizers as opt_lib
@@ -382,6 +386,18 @@ class Llama(TMModel):
         }
 
     # -- forward (local shards) -------------------------------------------
+
+    def flash_tiles(self) -> dict:
+        """The run summary's ``"flash_tiles"``: for each flash kernel
+        the tiles ``ops.attention._flash_tiles`` chose for this
+        model's attention shape and the share of visited score tiles
+        that take the masked body; ``{}`` where attention takes the
+        dense path (off the TPU, or a length no block tiles)."""
+        # ring attention hands the kernels one shard's length a hop
+        t = self.seq_len // (self.sp if self.sp_mode == "ring" else 1)
+        return flash_tiles_summary(
+            t, t, self.head_dim, self.compute_dtype, causal=True
+        )
 
     def _layer(self, p, x, pos):
         """One decoder block on local shards: x [B, T_loc, D].

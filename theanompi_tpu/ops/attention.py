@@ -13,10 +13,12 @@ for the long-context configs).  Design:
   the ring-attention loop (``parallel/ring_attention.py``) and any
   sequential blockwise scan share this exact function, so cross-device
   ring results match single-device attention bit-for-bit in fp32.
-- ``flash_attention`` — fused Pallas kernel (grid over heads × query
-  blocks, KV streamed through VMEM, f32 accumulators in scratch) with
-  the same signature; ``mha_reference`` off-TPU and at lengths no
-  kernel block tiles (each such choice is logged once per shape).
+- ``flash_attention`` — fused Pallas kernels (forward, dK/dV, dQ: a
+  block of rows resident, the other axis walked in [rows, sub] score
+  tiles, f32 accumulators in scratch, tiles chosen from the shapes by
+  ``_flash_tiles``) with the same signature; ``mha_reference``
+  off-TPU and at lengths no kernel block tiles (each such choice is
+  logged once per shape).
 
 Shapes follow [B, H, T, D] (head-major, the TPU-friendly layout: the
 ``[Tq, D] x [D, Tk]`` score matmul and ``[Tq, Tk] x [Tk, D]`` value
@@ -27,6 +29,7 @@ from __future__ import annotations
 
 import functools
 import logging
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -129,96 +132,180 @@ def block_attn_finish(carry, dtype):
 # ---------------------------------------------------------------------------
 # Pallas TPU flash attention
 # ---------------------------------------------------------------------------
+#
+# Three kernels: forward, dK/dV, dQ.  Each keeps a block of ``rows``
+# resident (queries in the forward and dQ, keys in dK/dV), fetches the
+# other axis ``major`` positions a grid step and folds that block in
+# ``sub`` positions at a time, so the live score tile is [rows, sub]
+# however large the fetched block.  Under causality a sub-block wholly
+# on the visible side of the diagonal takes a body with no mask, one
+# the diagonal crosses the masked body, one wholly above it none; a
+# grid step with nothing to fold names the block already resident and
+# fetches nothing.  ``_flash_tiles`` chooses (rows, major, sub) per
+# kernel from the shapes.
+#
+# The forward and dQ hold queries on the tile's sublanes, so their
+# products stream the query block against a latched [sub, d] piece of
+# K or V; dK/dV holds the KEYS there (the tile is the transposed
+# scores, ``k @ q^T``), so its products stream the key block against
+# [sub, d] pieces of Q and dO and none contracts over a tile's rows.
+# The rows' statistics cross the kernels' edge lane-dense,
+# ``f32[B*H, 1, T]``: dK/dV's tile takes them as rows as they are.
 
-def _block_causal_mask(q_start, k_start, block_q, block_k):
-    """[block_q, block_k] bool mask from global block offsets."""
-    q_pos = q_start + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
-    )
-    k_pos = k_start + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
-    )
+_LANES = 128      # lanes of a vector register: width of the statistics' scratch
+_NT = (((1,), (1,)), ((), ()))    # a @ b^T, the matrix unit's native form
+_NN = (((1,), (0,)), ((), ()))    # a @ b
+
+
+class FlashTiles(NamedTuple):
+    """One kernel's tiling: ``rows`` of the resident block, ``major``
+    positions of the walked axis fetched a grid step, folded ``sub``
+    at a time."""
+    rows: int
+    major: int
+    sub: int
+
+
+class FlashPlan(NamedTuple):
+    """The three kernels' tilings for one attention shape."""
+    fwd: FlashTiles
+    dkv: FlashTiles
+    dq: FlashTiles
+
+
+def _scaled(x, sm_scale):
+    """``x * sm_scale`` in ``x``'s dtype: the scale goes into a
+    [block, d] operand once, not into every [rows, sub] score tile."""
+    return (x.astype(jnp.float32) * sm_scale).astype(x.dtype)
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _lanes(x, n):
+    """A lane-replicated [rows, _LANES] statistic at width ``n``."""
+    reps, rem = divmod(n, x.shape[1])
+    if rem:
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+    return jnp.tile(x, (1, reps)) if reps > 1 else x
+
+
+def _diagonal(n):
+    return (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+            == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+
+
+def _as_row(x):
+    """Lane-replicated [rows, _LANES] -> lane-dense [1, rows]."""
+    rows = x.shape[0]
+    if rows % _LANES == 0:
+        return x.T[:1]
+    # no aligned transpose for a ragged (single, small) block: pick the
+    # diagonal of the column broadcast along lanes — exact
+    wide = jnp.broadcast_to(x[:, :1], (rows, rows))
+    return jnp.sum(jnp.where(_diagonal(rows), wide, 0.0), axis=0,
+                   keepdims=True)
+
+
+def _as_col(x):
+    """Lane-dense [1, rows] -> lane-replicated [rows, _LANES]."""
+    rows = x.shape[1]
+    if rows % _LANES == 0:
+        return jnp.broadcast_to(x, (_LANES, rows)).T
+    wide = jnp.broadcast_to(x, (rows, rows))
+    col = jnp.sum(jnp.where(_diagonal(rows), wide, 0.0), axis=1,
+                  keepdims=True)
+    return jnp.broadcast_to(col, (rows, _LANES))
+
+
+def _visible(q_start, k_start, shape, q_axis):
+    """``shape`` bool tile: query position >= key position, queries
+    along ``q_axis`` of the tile and keys along the other."""
+    q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
     return q_pos >= k_pos
 
 
-def _recompute_p(q, k, lse, q_start, k_start, sm_scale, causal):
-    """Backward-pass recompute of the normalized softmax block:
-    p = exp(s − lse) with the causal mask re-applied."""
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * sm_scale                                  # [block_q, block_k]
-    p = jnp.exp(s - lse)
-    if causal:
-        p = jnp.where(
-            _block_causal_mask(q_start, k_start, *p.shape), p, 0.0
+def _sub_block_kind(lo, sub, row_start, rows, rows_are_queries):
+    """(clear, crossed) for the walked sub-block [lo, lo + sub) against
+    the resident rows [row_start, row_start + rows): ``clear`` when
+    every score of the tile is visible, ``crossed`` when only some are.
+    Plain arithmetic: the kernels call it on grid indices, the
+    ``flash_tiles`` counter on integers."""
+    hi, row_hi = lo + sub - 1, row_start + rows - 1
+    if rows_are_queries:          # walked: keys
+        return hi <= row_start, (hi > row_start) & (lo <= row_hi)
+    return lo >= row_hi, (lo < row_hi) & (hi >= row_start)
+
+
+def _walk(fold, n_sub, sub, causal, lo, row_start, rows, rows_are_queries):
+    """Fold the walked block's ``n_sub`` sub-blocks, each through the
+    body its place against the diagonal asks for (or none)."""
+    for c in range(n_sub):
+        if not causal:
+            fold(c, False)
+            continue
+        clear, crossed = _sub_block_kind(
+            lo + c * sub, sub, row_start, rows, rows_are_queries
         )
-    return p
+        pl.when(clear)(functools.partial(fold, c, False))
+        pl.when(crossed)(functools.partial(fold, c, True))
 
 
 def _flash_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-    *, sm_scale, causal
+    q_ref, k_ref, v_ref, o_ref, lse_ref, qs_ref, acc_ref, m_ref, l_ref,
+    *, sm_scale, causal, sub
 ):
     """One (batch*head, q-block, kv-block) grid cell.
 
-    The kv grid dim is sequential (``ARBITRARY`` semantics), so only a
-    ``block_k`` KV slice is VMEM-resident at a time — VMEM stays
-    O(block_q*d + block_k*d) however long the context — and the
-    online-softmax carry lives in VMEM scratch across kv steps.
+    The kv grid dim is sequential (``ARBITRARY`` semantics): the
+    online-softmax carry lives in VMEM scratch across kv steps, the
+    running max and sum lane-replicated ([rows, 128]) so a fold
+    broadcasts neither along lanes.
     """
     ki = pl.program_id(2)
-    n_k = pl.num_programs(2)
+    rows, d = acc_ref.shape
+    major = k_ref.shape[1]
+    q_start = pl.program_id(1) * rows
+    k_start = ki * major
 
     @pl.when(ki == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
+        qs_ref[...] = _scaled(q_ref[0], sm_scale)
 
-    q = q_ref[0]                                   # [block_q, d]
-    block_q, d = q.shape
-    block_k = k_ref.shape[1]
-    q_start = pl.program_id(1) * block_q
-    k_start = ki * block_k
-
-    # causal: blocks fully above the diagonal fold in nothing
-    needed = (not causal) or (q_start + block_q > k_start)
-
-    @pl.when(needed)
-    def _fold():
-        k_blk = k_ref[0]
-        v_blk = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale                               # [block_q, block_k]
-        if causal:
-            mask = _block_causal_mask(q_start, k_start, block_q, block_k)
-            s = jnp.where(mask, s, NEG_INF)
-        m, l = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        if causal:
-            p = jnp.where(mask, p, 0.0)
+    def fold(c, masked):
+        cols = pl.ds(c * sub, sub)
+        v_blk = v_ref[0, cols, :]
+        s = _dot(qs_ref[...], k_ref[0, cols, :], _NT)      # [rows, sub]
+        if masked:
+            # one select: every row's first folded key is key 0, which
+            # it sees, so ``m`` is finite from the first fold on and
+            # exp(NEG_INF - m) is already an exact 0
+            s = jnp.where(
+                _visible(q_start, k_start + c * sub, s.shape, 0), s, NEG_INF
+            )
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, sub))
         alpha = jnp.exp(m - m_new)
-        pv = jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
         m_ref[...] = m_new
-        l_ref[...] = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + pv
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, d) + _dot(
+            p.astype(v_blk.dtype), v_blk, _NN
+        )
 
-    @pl.when(ki == n_k - 1)
+    _walk(fold, major // sub, sub, causal, k_start, q_start, rows, True)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
     def _finish():
-        o_ref[0] = (
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        ).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = (acc_ref[...] / _lanes(l, d)).astype(o_ref.dtype)
         # logsumexp per query row — the backward kernels' residual
-        # (kept [block_q, 1]: Mosaic wants block dims (8k, 128k)-
-        # aligned or full, and a trailing singleton is always full)
-        lse_ref[0] = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-30))
+        lse_ref[0] = _as_row(m_ref[...] + jnp.log(l))
 
 
 def _on_tpu() -> bool:
@@ -232,110 +319,101 @@ def _on_tpu() -> bool:
 
 def _flash_bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_acc, dv_acc, *, sm_scale, causal
+    dk_acc, dv_acc, *, sm_scale, causal, sub
 ):
     """dK/dV for one kv block: grid (bh, kv-block, q-block), the q dim
-    sequential so the [block_k, d] accumulators live in scratch."""
+    sequential so the [rows, d] accumulators live in scratch.  The
+    tile is the TRANSPOSED scores, keys on its sublanes and ``sub``
+    queries on its lanes: logsumexp and delta come as the rows they
+    are stored as, and both accumulating products are plain
+    ``tile @ [sub, d]``."""
     qi = pl.program_id(2)
-    n_q = pl.num_programs(2)
+    rows = k_ref.shape[1]
+    major = q_ref.shape[1]
+    k_start = pl.program_id(1) * rows
+    q_start = qi * major
 
     @pl.when(qi == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    block_q = q_ref.shape[1]
-    block_k = k_ref.shape[1]
-    q_start = qi * block_q
-    k_start = pl.program_id(1) * block_k
+    def fold(c, masked):
+        cols = pl.ds(c * sub, sub)
+        qs = _scaled(q_ref[0, cols, :], sm_scale)          # [sub, d]
+        do = do_ref[0, cols, :]
+        p = jnp.exp(_dot(k_ref[0], qs, _NT) - lse_ref[0, :, cols])
+        if masked:
+            p = jnp.where(
+                _visible(q_start + c * sub, k_start, p.shape, 1), p, 0.0
+            )
+        dv_acc[...] += _dot(p.astype(do.dtype), do, _NN)    # p^T @ dO
+        dp = _dot(v_ref[0], do, _NT)                        # (dO @ V^T)^T
+        ds = p * (dp - delta_ref[0, :, cols])
+        # the scale rides in ``qs``: ds^T @ (scale * Q)
+        dk_acc[...] += _dot(ds.astype(qs.dtype), qs, _NN)
 
-    # causal: a kv block whose keys are all in this q block's future
-    # contributes nothing to these dK/dV rows
-    needed = (not causal) or (q_start + block_q > k_start)
+    _walk(fold, major // sub, sub, causal, q_start, k_start, rows, False)
 
-    @pl.when(needed)
-    def _fold():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        delta = delta_ref[0]                      # [block_q, 1]
-        p = _recompute_p(
-            q, k, lse_ref[0], q_start, k_start, sm_scale, causal
-        )
-        dv_acc[...] += jax.lax.dot_general(
-            p, do.astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                         # p^T @ dO
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                         # dO @ V^T
-        ds = p * (dp - delta) * sm_scale
-        dk_acc[...] += jax.lax.dot_general(
-            ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                         # ds^T @ Q
-
-    @pl.when(qi == n_q - 1)
+    @pl.when(qi == pl.num_programs(2) - 1)
     def _finish():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _flash_bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
-    *, sm_scale, causal
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+    qs_ref, lse_col, delta_col, dq_acc, *, sm_scale, causal, sub
 ):
-    """dQ for one q block: grid (bh, q-block, kv-block), kv sequential."""
+    """dQ for one q block: grid (bh, q-block, kv-block), kv sequential.
+    Queries on the tile's sublanes as in the forward; the block's
+    logsumexp and delta are turned from rows to lane-replicated
+    columns once a q block."""
     ki = pl.program_id(2)
-    n_k = pl.num_programs(2)
+    rows = q_ref.shape[1]
+    major = k_ref.shape[1]
+    q_start = pl.program_id(1) * rows
+    k_start = ki * major
 
     @pl.when(ki == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
+        qs_ref[...] = _scaled(q_ref[0], sm_scale)
+        lse_col[...] = _as_col(lse_ref[0])
+        delta_col[...] = _as_col(delta_ref[0])
 
-    block_q = q_ref.shape[1]
-    block_k = k_ref.shape[1]
-    q_start = pl.program_id(1) * block_q
-    k_start = ki * block_k
-    needed = (not causal) or (q_start + block_q > k_start)
+    def fold(c, masked):
+        cols = pl.ds(c * sub, sub)
+        k_blk = k_ref[0, cols, :]
+        s = _dot(qs_ref[...], k_blk, _NT)                   # [rows, sub]
+        p = jnp.exp(s - _lanes(lse_col[...], sub))
+        if masked:
+            p = jnp.where(
+                _visible(q_start, k_start + c * sub, p.shape, 0), p, 0.0
+            )
+        dp = _dot(do_ref[0], v_ref[0, cols, :], _NT)        # dO @ V^T
+        ds = p * (dp - _lanes(delta_col[...], sub))
+        dq_acc[...] += _dot(ds.astype(k_blk.dtype), k_blk, _NN)
 
-    @pl.when(needed)
-    def _fold():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        delta = delta_ref[0]                      # [block_q, 1]
-        p = _recompute_p(
-            q, k, lse_ref[0], q_start, k_start, sm_scale, causal
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta) * sm_scale
-        dq_acc[...] += jax.lax.dot_general(
-            ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                         # ds @ K
+    _walk(fold, major // sub, sub, causal, k_start, q_start, rows, True)
 
-    @pl.when(ki == n_k - 1)
+    @pl.when(ki == pl.num_programs(2) - 1)
     def _finish():
-        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+        # the scale once, on the float32 [rows, d] sum
+        dq_ref[0] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
 
 
-def _flash_dims(q, k, block_q, block_k):
+def _flash_dims(q, k, rows_of_q: bool, tiles: FlashTiles):
+    """Shapes of one kernel call, its tiling checked against them."""
     b, h, t, d = q.shape
     t_k = k.shape[2]
-    block_q = min(block_q, t)
-    block_k = min(block_k, t_k)
-    if t % block_q or t_k % block_k:
+    t_rows, t_walk = (t, t_k) if rows_of_q else (t_k, t)
+    if t_rows % tiles.rows or t_walk % tiles.major or tiles.major % tiles.sub:
         raise ValueError(
-            f"T={t}/T_k={t_k} not divisible by blocks ({block_q},{block_k})"
+            f"T={t}/T_k={t_k} not divisible by blocks {tuple(tiles)} "
+            f"(rows over {'T' if rows_of_q else 'T_k'})"
         )
-    return b, h, t, t_k, d, block_q, block_k
+    return b, h, t, t_k, d
 
 
 _SEM = lambda *names: pltpu.CompilerParams(  # noqa: E731
@@ -345,120 +423,148 @@ _SEM = lambda *names: pltpu.CompilerParams(  # noqa: E731
 )
 
 
-def _flash_fwd_call(q, k, v, causal, sm_scale, block_q, block_k, interpret):
-    b, h, t, t_k, d, block_q, block_k = _flash_dims(q, k, block_q, block_k)
+def _walked_index(causal, tiles: FlashTiles, rows_are_queries: bool, n_walked):
+    """Index of the walked block for grid cell (row block ``r``, step
+    ``w`` of ``n_walked``).  Under causality a step with nothing to
+    fold names the nearest block that has — the one already resident,
+    so nothing is fetched for it: queries see no key block past their
+    last row, keys are seen by no query block before their first (nor
+    by any, where the keys outrun the queries: the last block then)."""
+    if not causal:
+        return lambda r, w: w
+    if rows_are_queries:
+        return lambda r, w: jnp.minimum(
+            w, (r * tiles.rows + tiles.rows - 1) // tiles.major
+        )
+    return lambda r, w: jnp.maximum(
+        w, jnp.minimum((r * tiles.rows) // tiles.major, n_walked - 1)
+    )
+
+
+def _flash_fwd_call(q, k, v, causal, sm_scale, tiles, interpret):
+    """Forward kernel: ``(out [B,H,T,D], logsumexp f32[B,H,T])``."""
+    b, h, t, t_k, d = _flash_dims(q, k, True, tiles)
+    rows, major, sub = tiles
     qs = q.reshape(b * h, t, d)
     ks = k.reshape(b * h, t_k, d)
     vs = v.reshape(b * h, t_k, d)
     vma = jax.typeof(qs).vma
+    walked = _walked_index(causal, tiles, True, t_k // major)
+    q_spec = pl.BlockSpec((1, rows, d), lambda i, j, kk: (i, j, 0))
+    k_spec = pl.BlockSpec((1, major, d), lambda i, j, kk: (i, walked(j, kk), 0))
     out, lse = pl.pallas_call(
-        functools.partial(_flash_kernel, sm_scale=sm_scale, causal=causal),
+        functools.partial(
+            _flash_kernel, sm_scale=sm_scale, causal=causal, sub=sub
+        ),
         out_shape=(
             jax.ShapeDtypeStruct((b * h, t, d), q.dtype, vma=vma),
-            jax.ShapeDtypeStruct((b * h, t, 1), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32, vma=vma),
         ),
-        grid=(b * h, t // block_q, t_k // block_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j, kk: (i, kk, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j, kk: (i, kk, 0)),
-        ],
+        grid=(b * h, t // rows, t_k // major),
+        in_specs=[q_spec, k_spec, k_spec],
         out_specs=(
-            pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, j, kk: (i, j, 0)),
+            q_spec,
+            pl.BlockSpec((1, 1, rows), lambda i, j, kk: (i, 0, j)),
         ),
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((rows, d), q.dtype),
+            pltpu.VMEM((rows, d), jnp.float32),
+            pltpu.VMEM((rows, _LANES), jnp.float32),
+            pltpu.VMEM((rows, _LANES), jnp.float32),
         ],
         # kv dim carries the scratch accumulator -> sequential
         compiler_params=_SEM("PARALLEL", "PARALLEL", "ARBITRARY"),
         interpret=interpret,
     )(qs, ks, vs)
-    return out.reshape(b, h, t, d), lse
+    return out.reshape(b, h, t, d), lse.reshape(b, h, t)
 
 
-@functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9)
-)
-def _flash(q, k, v, causal, sm_scale, block_q, block_k,
-           bwd_block_q, bwd_block_k, interpret):
-    out, _ = _flash_fwd_call(
-        q, k, v, causal, sm_scale, block_q, block_k, interpret
-    )
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, sm_scale, plan, interpret):
+    out, _ = _flash_fwd_call(q, k, v, causal, sm_scale, plan.fwd, interpret)
     return out
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k,
-               bwd_block_q, bwd_block_k, interpret):
+def _flash_fwd(q, k, v, causal, sm_scale, plan, interpret):
     out, lse = _flash_fwd_call(
-        q, k, v, causal, sm_scale, block_q, block_k, interpret
+        q, k, v, causal, sm_scale, plan.fwd, interpret
     )
     # named HERE, on the values the backward rule reads: a name on the
     # caller's copy of ``out`` saves an array and still replays the
     # kernel for ``lse`` (PERF.md, PR 29)
     out = checkpoint_name(out, FLASH_RESIDUALS[0])
-    # kept lane-dense, [B, H, T]: the kernel's [B*H, T, 1] tiles its
-    # trailing 1 to 128 lanes on the chip, 128x the bytes for every
-    # layer's forward-to-backward lifetime; ``_flash_bwd`` gives the
-    # kernels their view back
-    lse = checkpoint_name(lse.reshape(q.shape[:3]), FLASH_RESIDUALS[1])
+    # lane-dense [B, H, T] as the kernel wrote it: 1 MB a layer at the
+    # cells' shape for its forward-to-backward lifetime
+    lse = checkpoint_name(lse, FLASH_RESIDUALS[1])
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd_call(
-    q, k, v, g, lse, delta, causal, sm_scale, block_q, block_k, interpret
+    q, k, v, g, lse, delta, causal, sm_scale, dkv_tiles, dq_tiles, interpret
 ):
     """Backward kernels against EXPLICIT (lse, delta) residuals
-    ([B,H,T,1] fp32).  Factored out of ``_flash_bwd`` so ring
+    (fp32 [B,H,T]).  Factored out of ``_flash_bwd`` so ring
     attention can run the same kernels per visiting KV block with the
     GLOBAL logsumexp/delta (the standard ring-attention backward)."""
-    b, h, t, t_k, d, block_q, block_k = _flash_dims(q, k, block_q, block_k)
+    b, h, t, t_k, d = _flash_dims(q, k, False, dkv_tiles)
+    _flash_dims(q, k, True, dq_tiles)
     qs = q.reshape(b * h, t, d)
     ks = k.reshape(b * h, t_k, d)
     vs = v.reshape(b * h, t_k, d)
     dos = g.reshape(b * h, t, d)
-    lse = lse.reshape(b * h, t, 1)
-    delta = delta.reshape(b * h, t, 1)
+    lse = lse.reshape(b * h, 1, t)
+    delta = delta.reshape(b * h, 1, t)
     vma = jax.typeof(qs).vma
-    q_spec = pl.BlockSpec((1, block_q, d), lambda i, kj, qi: (i, qi, 0))
-    k_spec = pl.BlockSpec((1, block_k, d), lambda i, kj, qi: (i, kj, 0))
-    r_spec = pl.BlockSpec((1, block_q, 1), lambda i, kj, qi: (i, qi, 0))
+
+    rows, major, sub = dkv_tiles
+    walked = _walked_index(causal, dkv_tiles, False, t // major)
+    q_spec = pl.BlockSpec(
+        (1, major, d), lambda i, kj, qi: (i, walked(kj, qi), 0)
+    )
+    k_spec = pl.BlockSpec((1, rows, d), lambda i, kj, qi: (i, kj, 0))
+    r_spec = pl.BlockSpec(
+        (1, 1, major), lambda i, kj, qi: (i, 0, walked(kj, qi))
+    )
     dk, dv = pl.pallas_call(
         functools.partial(
-            _flash_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal
+            _flash_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal, sub=sub
         ),
         out_shape=(
             jax.ShapeDtypeStruct((b * h, t_k, d), k.dtype, vma=vma),
             jax.ShapeDtypeStruct((b * h, t_k, d), v.dtype, vma=vma),
         ),
-        grid=(b * h, t_k // block_k, t // block_q),
+        grid=(b * h, t_k // rows, t // major),
         in_specs=[q_spec, k_spec, k_spec, q_spec, r_spec, r_spec],
-        out_specs=(
-            pl.BlockSpec((1, block_k, d), lambda i, kj, qi: (i, kj, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, kj, qi: (i, kj, 0)),
-        ),
+        out_specs=(k_spec, k_spec),
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((rows, d), jnp.float32),
+            pltpu.VMEM((rows, d), jnp.float32),
         ],
         compiler_params=_SEM("PARALLEL", "PARALLEL", "ARBITRARY"),
         interpret=interpret,
     )(qs, ks, vs, dos, lse, delta)
 
-    q_spec2 = pl.BlockSpec((1, block_q, d), lambda i, qi, kj: (i, qi, 0))
-    k_spec2 = pl.BlockSpec((1, block_k, d), lambda i, qi, kj: (i, kj, 0))
-    r_spec2 = pl.BlockSpec((1, block_q, 1), lambda i, qi, kj: (i, qi, 0))
+    rows, major, sub = dq_tiles
+    walked = _walked_index(causal, dq_tiles, True, t_k // major)
+    q_spec = pl.BlockSpec((1, rows, d), lambda i, qi, kj: (i, qi, 0))
+    k_spec = pl.BlockSpec(
+        (1, major, d), lambda i, qi, kj: (i, walked(qi, kj), 0)
+    )
+    r_spec = pl.BlockSpec((1, 1, rows), lambda i, qi, kj: (i, 0, qi))
     dq = pl.pallas_call(
         functools.partial(
-            _flash_bwd_dq_kernel, sm_scale=sm_scale, causal=causal
+            _flash_bwd_dq_kernel, sm_scale=sm_scale, causal=causal, sub=sub
         ),
         out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype, vma=vma),
-        grid=(b * h, t // block_q, t_k // block_k),
-        in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, r_spec2, r_spec2],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda i, qi, kj: (i, qi, 0)),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        grid=(b * h, t // rows, t_k // major),
+        in_specs=[q_spec, k_spec, k_spec, q_spec, r_spec, r_spec],
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((rows, d), q.dtype),
+            pltpu.VMEM((rows, _LANES), jnp.float32),
+            pltpu.VMEM((rows, _LANES), jnp.float32),
+            pltpu.VMEM((rows, d), jnp.float32),
+        ],
         compiler_params=_SEM("PARALLEL", "PARALLEL", "ARBITRARY"),
         interpret=interpret,
     )(qs, ks, vs, dos, lse, delta)
@@ -469,45 +575,18 @@ def _flash_bwd_call(
     )
 
 
-def _flash_bwd(causal, sm_scale, block_q, block_k,
-               bwd_block_q, bwd_block_k, interpret, res, g):
+def _flash_bwd(causal, sm_scale, plan, interpret, res, g):
     q, k, v, out, lse = res
-    # delta_i = rowsum(dO * O): the softmax-jacobian correction term
-    delta = jnp.sum(
-        g.astype(jnp.float32) * out.astype(jnp.float32),
-        axis=-1, keepdims=True,
-    )                                             # [b, h, t, 1], like lse
-    # backward kernels may tile differently from the forward: they
-    # hold more live VMEM per cell (dK/dV accumulators + 6 operand
-    # blocks), so their optimum can sit below the forward's
+    # delta_i = rowsum(dO * O): the softmax-jacobian correction term,
+    # [B, H, T] like the logsumexp
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     return _flash_bwd_call(
-        q, k, v, g, lse.reshape(q.shape[:3] + (1,)), delta,
-        causal, sm_scale, bwd_block_q or block_q,
-        bwd_block_k or block_k, interpret,
+        q, k, v, g, lse, delta, causal, sm_scale, plan.dkv, plan.dq,
+        interpret,
     )
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
-
-
-def _bwd_blocks_env():
-    """TM_FLASH_BWD_BLOCKS="q,k" (or one number for both): override
-    the BACKWARD kernel block sizes without touching the forward's
-    (sweep knob; VERDICT r3 #6).  Empty/unset = backward shares the
-    forward blocks."""
-    import os
-
-    v = os.environ.get("TM_FLASH_BWD_BLOCKS", "")
-    if not v:
-        return None, None
-    parts = v.split(",")
-    if len(parts) == 1:
-        parts = [v, v]
-    if len(parts) != 2 or not all(p.strip().isdigit() for p in parts):
-        raise ValueError(
-            f"TM_FLASH_BWD_BLOCKS must be 'q,k' integers (got {v!r})"
-        )
-    return int(parts[0]), int(parts[1])
 
 
 def flash_attention_tpu(
@@ -516,69 +595,56 @@ def flash_attention_tpu(
 ):
     """Fused flash attention, fully differentiable (custom_vjp with
     Pallas dQ and dK/dV kernels — the standard two-kernel backward with
-    the logsumexp residual).  q,k,v: [B, H, T, D]; T (and T_k) must be
-    divisible by the block sizes — ``flash_attention`` dispatches away
-    otherwise.  ``interpret=True`` runs the kernels in the Pallas
-    interpreter (any backend; how the tests exercise them)."""
+    the logsumexp residual).  q,k,v: [B, H, T, D].  The kernels' tiles
+    come from the shapes (``_flash_tiles``); explicit blocks are for
+    tests and sweeps and mean un-subdivided blocks of that size — T
+    (and T_k) must then be divisible by them.  ``interpret=True`` runs
+    the kernels in the Pallas interpreter (any backend; how the tests
+    exercise them)."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
-    # default blocks: largest that tile this T.  A length no aligned
-    # block divides is rejected HERE with an actionable error — the
-    # old `or 256` default let `min(block, t)` clamp back to the
-    # ragged t (e.g. a T_loc=68 ring shard) and fail deep in Mosaic
-    # lowering instead (ADVICE r2).
-    if block_q is None:
-        block_q = _auto_block(q.shape[2], q.dtype)
-    if block_k is None:
-        block_k = _auto_block(k.shape[2], k.dtype)
-    if not block_q or not block_k:
-        if interpret:
-            # the interpreter has no Mosaic alignment constraint; the
-            # full axis is always a valid (single) block, keeping
-            # ragged lengths runnable for off-TPU testing
-            block_q = block_q or q.shape[2]
-            block_k = block_k or k.shape[2]
-        else:
+    t, t_k = q.shape[2], k.shape[2]
+    explicit = block_q or block_k or bwd_block_q or bwd_block_k
+    plan = None if explicit else _flash_tiles(t, t_k, q.shape[3], q.dtype)
+    if plan is None:
+        if not (explicit or interpret):
+            # a length no aligned block divides is rejected HERE with
+            # an actionable error, not deep in Mosaic lowering (e.g. a
+            # T_loc=68 ring shard; ADVICE r2)
             raise ValueError(
                 f"flash kernel needs aligned sequence blocks; "
-                f"T_q={q.shape[2]}, T_k={k.shape[2]} have none (pad "
+                f"T_q={t}, T_k={t_k} have none (pad "
                 f"the sequence to a multiple of 16 — of 256 beyond "
                 f"1024 — or use mha_reference / flash_attention() "
                 f"which falls back to dense)"
             )
-    # the env override resolves HERE, outside the jitted body: read
-    # inside a traced function it would be captured at first trace and
-    # the jit cache (keyed on the static block args, not the env)
-    # would silently replay stale values across a sweep
-    if bwd_block_q is None and bwd_block_k is None:
-        bwd_block_q, bwd_block_k = _bwd_blocks_env()
-    if bwd_block_q:
-        bwd_block_q = min(int(bwd_block_q), q.shape[2])
-    if bwd_block_k:
-        bwd_block_k = min(int(bwd_block_k), k.shape[2])
-    return _flash_jit(q, k, v, causal, sm_scale, block_q, block_k,
-                      bwd_block_q, bwd_block_k, interpret)
+        # un-subdivided blocks: the caller's, or the whole axes — the
+        # interpreter has no Mosaic alignment constraint, which keeps
+        # ragged lengths runnable for off-TPU testing
+        bq, bk = min(block_q or t, t), min(block_k or t_k, t_k)
+        gq, gk = min(bwd_block_q or bq, t), min(bwd_block_k or bk, t_k)
+        plan = FlashPlan(
+            fwd=FlashTiles(bq, bk, bk), dkv=FlashTiles(gk, gq, gq),
+            dq=FlashTiles(gq, gk, gk),
+        )
+    return _flash_jit(q, k, v, causal, sm_scale, plan, interpret)
 
 
-@functools.partial(
-    jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9),
-)
-def _flash_jit(q, k, v, causal, sm_scale, block_q, block_k,
-               bwd_block_q, bwd_block_k, interpret):
-    return _flash(q, k, v, causal, sm_scale, block_q, block_k,
-                  bwd_block_q, bwd_block_k, interpret)
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _flash_jit(q, k, v, causal, sm_scale, plan, interpret):
+    return _flash(q, k, v, causal, sm_scale, plan, interpret)
 
 
 def _auto_block(t: int, dtype=None) -> int | None:
-    """Largest kernel block for a T: the full axis when it fits in one
-    block, else the biggest power-of-two divisor — measured on v5e
-    (8L/1024d, T2048): 1024-blocks run the train step 1.5x faster
-    than 256-blocks (110 vs 169 ms/step); 2048-blocks exceed VMEM.
+    """Largest aligned block that tiles a T — the full axis up to
+    1024, else the biggest power-of-two divisor from 1024 down to 256 —
+    or ``None`` where there is none: callers treat that as "use the
+    dense path" (ADVICE r2).  Whether an axis can go through the
+    kernels at all; ``_flash_tiles`` sizes the blocks.
 
     Only sublane-aligned blocks qualify: the block is a Mosaic tile
     dimension, and a ragged size (e.g. a T_loc=68 ring shard) can fail
-    lowering instead of falling back — callers treat ``None`` as "use
-    the dense path" (ADVICE r2).  The sublane tile is dtype-keyed
+    lowering instead of falling back.  The sublane tile is dtype-keyed
     (ADVICE r3): 8 rows for fp32, 16 for bf16 — so small fp32
     sequences like T=8/24/40 stay kernel-eligible."""
     import numpy as np
@@ -590,6 +656,81 @@ def _auto_block(t: int, dtype=None) -> int | None:
         if t % s == 0:
             return s
     return None
+
+
+# What ``_flash_tiles`` aims for, the same in all three kernels: the
+# sweep on a v5e at the cells' shape, 64 x 4096 x 128 bf16 (PERF.md,
+# PR 32).  Score tiles of [512, 512]: at 256 columns every kernel is
+# 25-40 % slower (twice the folds for the same scores), at 1024 the
+# forward and dQ compute more above the diagonal than they save; 256
+# rows lose 8-14 % to shorter products, 1024 compute a quarter of their
+# scores above the diagonal (512: an eighth).  The walked axis whole
+# where a block of it is at most 1 MiB (4096 x 128 bf16): K and V
+# cross HBM once a head, a query block takes one grid step.
+_ROWS = 512
+_SUB = 512
+_MAJOR_BYTES = 1 << 20
+
+
+def _fit(t: int, cap: int) -> int:
+    """The whole axis where it is at most ``cap`` or has no lane-
+    aligned divisor; else its largest divisor that is a multiple of
+    128 and at most ``cap``."""
+    if t <= cap or t % _LANES:
+        return t
+    return max(
+        (s for s in range(_LANES, cap + 1, _LANES) if t % s == 0), default=t
+    )
+
+
+def _flash_tiles(t_q, t_k, head_dim, dtype) -> FlashPlan | None:
+    """The three kernels' tiles for an attention shape, or ``None``
+    where an axis has no aligned block (``_auto_block``: the dense
+    path).  One rule for every kernel, from what the kernel can see:
+    the resident block is the axis cut to the swept row count, the
+    walked block as much of the other axis as the swept byte count
+    holds at this head dim and dtype, folded at the swept tile width."""
+    import numpy as np
+
+    if not _auto_block(t_q, dtype) or not _auto_block(t_k, dtype):
+        return None
+    row_bytes = head_dim * np.dtype(dtype).itemsize
+
+    def tiles(t_rows, t_walk):
+        major = _fit(t_walk, max(_MAJOR_BYTES // row_bytes, _LANES))
+        return FlashTiles(_fit(t_rows, _ROWS), major, _fit(major, _SUB))
+
+    on_q = tiles(t_q, t_k)
+    return FlashPlan(fwd=on_q, dkv=tiles(t_k, t_q), dq=on_q)
+
+
+def flash_tiles_summary(t_q, t_k, head_dim, dtype, causal=True) -> dict:
+    """For the run summary's ``"flash_tiles"``: per kernel the outer
+    tile ``[rows, major]``, the inner ``[rows, sub]`` and the share of
+    the score tiles it visits that take the masked body — static, from
+    the shapes.  Empty where ``flash_attention`` takes the dense path
+    (off the TPU, or a length no block tiles)."""
+    plan = _flash_tiles(t_q, t_k, head_dim, dtype)
+    if plan is None or not _on_tpu():
+        return {}
+    out = {}
+    for kernel, (rows, major, sub) in plan._asdict().items():
+        on_q = kernel != "dkv"
+        t_rows, t_walk = (t_q, t_k) if on_q else (t_k, t_q)
+        visited = masked = 0
+        for r in range(0, t_rows, rows):
+            for lo in range(0, t_walk, sub):
+                clear, crossed = (
+                    _sub_block_kind(lo, sub, r, rows, on_q)
+                    if causal else (True, False)
+                )
+                visited += bool(clear or crossed)
+                masked += bool(crossed)
+        out[kernel] = {
+            "outer": [rows, major], "inner": [rows, sub],
+            "masked_share": round(masked / visited, 4),
+        }
+    return out
 
 
 @functools.lru_cache(maxsize=None)
@@ -619,12 +760,8 @@ def flash_attention(q, k, v, *, causal=True, sm_scale=None):
     carries a custom_vjp with Pallas backward kernels.  A dense choice
     is never silent: ``_log_dense_choice`` names the shape and why."""
     t, t_k = q.shape[2], k.shape[2]
-    bq, bk = _auto_block(t, q.dtype), _auto_block(t_k, k.dtype)
     on_tpu = _on_tpu()
-    if on_tpu and bq and bk:
-        return flash_attention_tpu(
-            q, k, v, causal=causal, sm_scale=sm_scale,
-            block_q=bq, block_k=bk,
-        )
+    if on_tpu and _flash_tiles(t, t_k, q.shape[3], q.dtype):
+        return flash_attention_tpu(q, k, v, causal=causal, sm_scale=sm_scale)
     _log_dense_choice(t, t_k, str(q.dtype), on_tpu)
     return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale)

@@ -30,9 +30,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from theanompi_tpu.ops.attention import (
-    _auto_block,
     _flash_bwd_call,
     _flash_fwd_call,
+    _flash_tiles,
     _on_tpu,
     block_attn_finish,
     block_attn_init,
@@ -54,10 +54,10 @@ def _unrep(dx, r: int):
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _ring_flash(q, k, v, axis_name, causal, sm_scale, kv_rep, block,
+def _ring_flash(q, k, v, axis_name, causal, sm_scale, kv_rep, plan,
                 interpret):
     o, _ = _ring_flash_fwd(
-        q, k, v, axis_name, causal, sm_scale, kv_rep, block, interpret
+        q, k, v, axis_name, causal, sm_scale, kv_rep, plan, interpret
     )
     return o
 
@@ -68,7 +68,7 @@ def _hop_visible(my_idx, src, causal):
     return jnp.logical_or(jnp.asarray(not causal), src <= my_idx)
 
 
-def _ring_flash_fwd(q, k, v, axis_name, causal, sm_scale, kv_rep, block,
+def _ring_flash_fwd(q, k, v, axis_name, causal, sm_scale, kv_rep, plan,
                     interpret):
     """Per-hop Pallas flash fwd + online logsumexp merge.
 
@@ -92,9 +92,9 @@ def _ring_flash_fwd(q, k, v, axis_name, causal, sm_scale, kv_rep, block,
         visible = _hop_visible(my_idx, src, causal)
         o_i, lse_i = _flash_fwd_call(
             q, _rep(k_cur, kv_rep), _rep(v_cur, kv_rep),
-            causal and step == 0, sm_scale, block, block, interpret,
+            causal and step == 0, sm_scale, plan.fwd, interpret,
         )
-        lse_i = lse_i.reshape(b, h, t_loc, 1)
+        lse_i = lse_i[..., None]
         # merge: future blocks weigh 0; exp(m - m_new) is 0 on the
         # first (always-visible diagonal) fold, so no -inf arithmetic
         lse_eff = jnp.where(visible, lse_i, -jnp.inf)
@@ -114,7 +114,7 @@ def _ring_flash_fwd(q, k, v, axis_name, causal, sm_scale, kv_rep, block,
     return o, (q, k, v, o, lse_global)
 
 
-def _ring_flash_bwd(axis_name, causal, sm_scale, kv_rep, block,
+def _ring_flash_bwd(axis_name, causal, sm_scale, kv_rep, plan,
                     interpret, res, g):
     """Ring backward: each hop runs the flash dQ and dK/dV kernels
     against the GLOBAL (lse, delta) residuals; dK/dV accumulators
@@ -138,7 +138,7 @@ def _ring_flash_bwd(axis_name, causal, sm_scale, kv_rep, block,
         visible = _hop_visible(my_idx, src, causal)
         dq_i, dk_i, dv_i = _flash_bwd_call(
             q, _rep(k_cur, kv_rep), _rep(v_cur, kv_rep), g, lse, delta,
-            causal and step == 0, sm_scale, block, block, interpret,
+            causal and step == 0, sm_scale, plan.dkv, plan.dq, interpret,
         )
         dq = dq + jnp.where(visible, dq_i.astype(jnp.float32), 0.0)
         dk_cur = dk_cur + jnp.where(
@@ -194,22 +194,19 @@ def ring_attention(
     b, h, t_loc, d = q.shape
     if sm_scale is None:
         sm_scale = d**-0.5
+    # one plan for every hop: a visiting block is the shard's length
+    plan = _flash_tiles(t_loc, t_loc, d, q.dtype)
     if impl is None:
-        impl = (
-            "flash"
-            if (_on_tpu() and _auto_block(t_loc, q.dtype))
-            else "dense"
-        )
+        impl = "flash" if (_on_tpu() and plan) else "dense"
     if impl == "flash":
-        block = _auto_block(t_loc, q.dtype)
-        if block is None:
+        if plan is None:
             raise ValueError(
                 f"impl='flash' needs a blockable shard length; "
                 f"T_loc={t_loc} has no power-of-two kernel block "
                 f"(use impl='dense' or pad the sequence)"
             )
         return _ring_flash(
-            q, k, v, axis_name, causal, sm_scale, kv_rep, block,
+            q, k, v, axis_name, causal, sm_scale, kv_rep, plan,
             interpret,
         )
     s_size = lax.axis_size(axis_name)

@@ -596,6 +596,9 @@ def run(
         # checkpoint names the model's per-layer remat keeps ([] when
         # it keeps everything, or the model has no such remat)
         "remat_saves": list(getattr(model, "remat_saves", ())),
+        # the flash kernels' tiles for the model's attention shape
+        # ({} where no such kernel runs)
+        "flash_tiles": getattr(model, "flash_tiles", dict)(),
         "exchange_bucket_mb": exchange.bucket_mb,
         "exchange_replicas": getattr(model, "exchange_replicas", None),
         "exchange_buckets": getattr(model, "exchange_buckets", None),
