@@ -412,3 +412,66 @@ def test_layer_backward_replays_no_flash_forward_kernel(
     assert kept == dict(fwd=n_layers, dkv=n_layers, dq=n_layers)
     assert 1 <= full["fwd"] - n_layers <= n_layers, full
     assert (full["dkv"], full["dq"]) == (n_layers, n_layers), full
+
+
+def test_looped_decoder_step_compiles_with_its_scopes_and_one_exit_alive(
+    chip, monkeypatch
+):
+    """A looped ``Llama`` (2 layers x 3 passes, sandwich norms, 256
+    wide, 2 heads of 128, 2 x 256 tokens, vocabulary 4096) through
+    ``_forward``, ``_exit_loss`` over the dense head and ``jax.grad``,
+    compiled for the v5e: three flash kernels a layer CALL (the
+    layer's remat keeps ``FLASH_RESIDUALS`` in every pass), both
+    scopes the benchmark's readers look for in the text, forward and
+    backward, and the exits' ``[N, V]`` logits never stacked over the
+    passes (one exit's are alive at a time)."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from theanompi_tpu.models.llama import Llama
+    from theanompi_tpu.ops import attention
+    from theanompi_tpu.parallel import make_mesh
+    from theanompi_tpu.parallel import tp as tp_lib
+
+    t, b, v, passes, layers = 256, 2, 4096, 3, 2
+    mesh = make_mesh(data=1, devices=list(chip.device_set))
+    batch = P("data", "seq")
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)  # as on the chip
+    model = Llama(dict(
+        dim=256, n_layers=layers, n_heads=2, n_kv_heads=2, ffn_dim=512,
+        vocab=v, seq_len=t, batch_size=b, compute_dtype="bfloat16",
+        ut_steps=passes, sandwich_norm=True, exit_beta=0.1,
+        rope_theta=1e6, norm_eps=1e-6,
+    ))
+
+    def grad(params, ids, targets):
+        def loss(p):
+            exits = model._forward(p, ids, head=False)
+            yf = targets.reshape(-1)
+            head = jax.checkpoint(lambda z: tp_lib.dense_unembed_xent(
+                z, p["lm_head"], yf, v, "model"))
+            return model._exit_loss(
+                p, exits.reshape(passes, -1, exits.shape[-1]), yf, head)[0]
+        return jax.grad(loss)(params)
+
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, P())
+        ),
+        jax.eval_shape(model._init_full_params, jax.random.key(0)),
+    )
+    ids = jax.ShapeDtypeStruct(
+        (b, t), jnp.int32, sharding=NamedSharding(mesh, batch)
+    )
+    text = _compiled_text(jax.shard_map(
+        grad, mesh=mesh, in_specs=(P(), batch, batch), out_specs=P(),
+    ), params, ids, ids)
+    calls = passes * layers
+    assert _flash_kernels(text) == dict(fwd=calls, dkv=calls, dq=calls)
+    for scope in ("jvp(ut_stack)", "transpose(jvp(ut_stack))",
+                  "jvp(ut_exit)", "transpose(jvp(ut_exit))"):
+        assert scope in text, scope
+    n = b * t
+    assert re.search(rf"\[{n},{v}\]", text)
+    assert not re.search(rf"\[{passes},{n},{v}\]", text)

@@ -140,6 +140,11 @@ PROFILE_SCOPES: dict[str, str] = {
     "moe_tile_plan": "moe_experts",
     # the dense MLP's activation gradient (ops/layers.py swiglu, PR 27)
     "mlp_act_grad": "mlp_act_grad",
+    # a looped decoder's passes over its stack and its exits' heads
+    # and losses (models/llama.py, PR 33);
+    # benchmark/layer_metrics/_ut.py reads the same labels
+    "ut_stack": "ut_stack",
+    "ut_exit": "ut_exit",
 }
 
 #: label PREFIX -> leg family: labels carrying a per-instance index

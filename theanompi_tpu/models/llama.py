@@ -165,7 +165,16 @@ class Llama(TMModel):
     ``ffn_dim`` each: ``moe_top_k``, ``moe_renormalize`` (bool, on:
     the picked gates rescaled to sum to one), ``capacity_factor`` (a
     number: capacity buffers that drop; ``null``: dropless),
-    ``moe_aux_coef``, ``moe_z_coef``, ``ep``.
+    ``moe_aux_coef``, ``moe_z_coef``, ``ep``.  ``rope_theta`` (1e4)
+    and ``norm_eps`` (1e-5) reach every rotation and every RMSNorm;
+    ``sandwich_norm`` (bool, off) adds an RMSNorm on each branch's
+    OUTPUT, before the residual sum.  ``ut_steps`` R > 1 makes a
+    LOOPED decoder: the one stack of layers runs R times over the same
+    parameters, the final norm closes every pass, its output is both
+    that pass's exit and the next pass's input, and the training loss
+    weighs the R exits' cross-entropies a token by a learned exit
+    distribution less ``exit_beta`` times its entropy
+    (``_exit_loss``).
     """
 
     def __init__(self, config: dict | None = None):
@@ -190,6 +199,12 @@ class Llama(TMModel):
         self.capacity_factor = None if cf is None else float(cf)
         self.moe_renormalize = bool(c.get("moe_renormalize", True))
         self.qk_norm = bool(c.get("qk_norm", False))
+        self.rope_theta = float(c.get("rope_theta", 10000.0))
+        self.norm_eps = float(c.get("norm_eps", 1e-5))
+        self.sandwich_norm = bool(c.get("sandwich_norm", False))
+        # passes over the one stack of layers; > 1 is a looped decoder
+        self.ut_steps = int(c.get("ut_steps", 1))
+        self.exit_beta = float(c.get("exit_beta", 0.0))
         self.ep = int(c.get("ep", 1))
         self.moe_aux_coef = float(c.get("moe_aux_coef", 0.01))
         self.moe_z_coef = float(c.get("moe_z_coef", 0.0))
@@ -256,6 +271,14 @@ class Llama(TMModel):
                 )
         else:
             assert self.ep == 1, "ep > 1 requires n_experts > 0"
+        assert self.ut_steps >= 1, self.ut_steps
+        if self.ut_steps > 1 and self.pp > 1:
+            raise NotImplementedError(
+                "a looped decoder (ut_steps > 1) does not yet compose "
+                "with pipeline parallelism (pp > 1): every pass would "
+                "send the last stage's output back to the first, R "
+                "trips of the pipe a microbatch; use pp=1"
+            )
         if self.pp > 1:
             assert batch % self.pp_microbatches == 0, (
                 f"local batch {batch} must divide into "
@@ -299,6 +322,9 @@ class Llama(TMModel):
         if self.qk_norm:
             # over the whole projected width: sharded as its columns
             layer.update({"q_norm": P(MODEL_AXIS), "k_norm": P(MODEL_AXIS)})
+        if self.sandwich_norm:
+            # over the full width of each branch's (psum'd) output
+            layer.update({"attn_out_norm": P(None), "mlp_out_norm": P(None)})
         if self.n_experts:
             # experts sharded over the expert axis, FFN dim over model
             layer.update({
@@ -317,12 +343,16 @@ class Llama(TMModel):
             layers = {k: P(PIPE_AXIS, *s) for k, s in layer.items()}
         else:
             layers = [dict(layer) for _ in range(self.n_layers)]
-        return {
+        specs = {
             "embed": P(MODEL_AXIS, None),        # vocab-sharded rows
             "layers": layers,
             "final_norm": P(None),
             "lm_head": P(None, MODEL_AXIS),      # vocab-sharded cols
         }
+        if self.ut_steps > 1:
+            # the exit gate acts on the full width: replicated
+            specs.update({"exit_gate_w": P(None, None), "exit_gate_b": P(None)})
+        return specs
 
     def _init_full_params(self, key) -> PyTree:
         """Full (unsharded) init; device_put with NamedShardings slices
@@ -348,6 +378,9 @@ class Llama(TMModel):
             if self.qk_norm:
                 lp["q_norm"] = jnp.ones((self.n_heads * hd,))
                 lp["k_norm"] = jnp.ones((self.n_kv_heads * hd,))
+            if self.sandwich_norm:
+                lp["attn_out_norm"] = jnp.ones((d,))
+                lp["mlp_out_norm"] = jnp.ones((d,))
             if self.n_experts:
                 e = self.n_experts
                 # per-expert fan-in/out scales (the generic shape-based
@@ -378,12 +411,20 @@ class Llama(TMModel):
             # stack the SAME per-layer draws (pp is a layout choice,
             # not a math choice: init must match the pp=1 model)
             layers = jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
-        return {
+        params = {
             "embed": 0.02 * jax.random.normal(next(keys), (v, d), jnp.float32),
             "layers": layers,
             "final_norm": jnp.ones((d,)),
             "lm_head": dense(next(keys), (d, v)),
         }
+        if self.ut_steps > 1:
+            # a gate near zero: every pass but the last keeps about
+            # half of the mass that reaches it
+            params["exit_gate_w"] = 0.02 * jax.random.normal(
+                next(keys), (d, 1), jnp.float32
+            )
+            params["exit_gate_b"] = jnp.zeros((1,))
+        return params
 
     # -- forward (local shards) -------------------------------------------
 
@@ -413,16 +454,17 @@ class Llama(TMModel):
         hkv_loc = self.n_kv_heads // self.tp
         hd = self.head_dim
 
-        xn = rms_norm(x, p["attn_norm"])
+        eps = self.norm_eps
+        xn = rms_norm(x, p["attn_norm"], eps)
         q = tp_lib.col_parallel(xn, p["wq"])
         k = tp_lib.col_parallel(xn, p["wk"])
         if self.qk_norm:
-            q = rms_norm(q, p["q_norm"], sharded_width=self.n_heads * hd)
-            k = rms_norm(k, p["k_norm"], sharded_width=self.n_kv_heads * hd)
+            q = rms_norm(q, p["q_norm"], eps, self.n_heads * hd)
+            k = rms_norm(k, p["k_norm"], eps, self.n_kv_heads * hd)
         q, k = _heads(q, h_loc, hd), _heads(k, hkv_loc, hd)
         v = _heads(tp_lib.col_parallel(xn, p["wv"]), hkv_loc, hd)
-        q = rope(q, pos)
-        k = rope(k, pos)
+        q = rope(q, pos, self.rope_theta)
+        k = rope(k, pos, self.rope_theta)
         # GQA: KV stays compact on the wire; repeated only at compute
         rep = h_loc // hkv_loc
         if self.sp == 1:
@@ -438,9 +480,12 @@ class Llama(TMModel):
                 else ulysses_attention
             )
             o = attn(q, k, v, SEQ_AXIS, causal=True, kv_rep=rep)
-        x = x + tp_lib.row_parallel(_unheads(o), p["wo"]).astype(cdtype)
+        a = tp_lib.row_parallel(_unheads(o), p["wo"]).astype(cdtype)
+        if self.sandwich_norm:
+            a = rms_norm(a, p["attn_out_norm"], eps)
+        x = x + a
 
-        xn = rms_norm(x, p["mlp_norm"])
+        xn = rms_norm(x, p["mlp_norm"], eps)
         if self.n_experts:
             y, aux = moe_ffn(
                 xn, p["router"], p["we_gate"], p["we_up"], p["we_down"],
@@ -457,13 +502,18 @@ class Llama(TMModel):
             mom = jnp.concatenate(
                 [aux["f"], aux["p"], aux["z"][None], aux["dropped"][None]]
             ).astype(jnp.float32)
-            return x + y.astype(cdtype), mom
+            y = y.astype(cdtype)
+            if self.sandwich_norm:
+                y = rms_norm(y, p["mlp_out_norm"], eps)
+            return x + y, mom
         h = swiglu(
             tp_lib.col_parallel(xn, p["w_gate"]),
             tp_lib.col_parallel(xn, p["w_up"]),
         )
-        x = x + tp_lib.row_parallel(h, p["w_down"]).astype(cdtype)
-        return x
+        y = tp_lib.row_parallel(h, p["w_down"]).astype(cdtype)
+        if self.sandwich_norm:
+            y = rms_norm(y, p["mlp_out_norm"], eps)
+        return x + y
 
     def _forward(self, params, ids, head=True, with_aux=False):
         """ids [B_loc, T_loc] -> local vocab-shard logits [.., V/tp].
@@ -508,16 +558,39 @@ class Llama(TMModel):
         moe = bool(self.n_experts)
         aux = jnp.zeros((2,), jnp.float32)
         routing = None
+        exits = None
         if self.pp == 1:
-            moms = []
-            for p in params["layers"]:
-                if moe:
-                    x, mom = layer(p, x, pos)
-                    moms.append(mom)
-                else:
-                    x = layer(p, x, pos)
+            def stack(x):
+                moms = []
+                for p in params["layers"]:
+                    if moe:
+                        x, mom = layer(p, x, pos)
+                        moms.append(mom)
+                    else:
+                        x = layer(p, x, pos)
+                return x, (jnp.stack(moms) if moe else None)
+
+            if self.ut_steps == 1:
+                x, moms = stack(x)
+            else:
+                # the looped decoder: R passes over the SAME leaves
+                # (their gradients are sums over the passes), the
+                # final norm closing each; the normed output is that
+                # pass's exit and the next pass's input.  The passes
+                # are unrolled, as the layers are: as a ``lax.scan``
+                # the step kept 0.9 GiB more and ran 13 % slower at
+                # the cell's sizes (PERF.md, PR 33).
+                exits, moms = [], []
+                with jax.named_scope("ut_stack"):
+                    for _ in range(self.ut_steps):
+                        x, mom = stack(x)
+                        x = rms_norm(x, params["final_norm"], self.norm_eps)
+                        exits.append(x)
+                        moms.append(mom)
+                exits = jnp.stack(exits)
+                if moe:     # [R][L, ...] -> a row a layer call
+                    moms = jnp.concatenate(moms)
             if moe:
-                moms = jnp.stack(moms)
                 aux = self._aux_from_moments(moms)
                 routing = self._routing_counters(moms)
         else:
@@ -596,9 +669,13 @@ class Llama(TMModel):
                 # (_pp_value).
                 x = self._pp_slice_tokens(last_stage_value(x))
 
-        x = rms_norm(x, params["final_norm"])
+        if exits is None:
+            x = rms_norm(x, params["final_norm"], self.norm_eps)
         if not head:
-            return (x, aux, routing) if with_aux else x
+            # a looped decoder gives its R exits [R, B, T, D] (the
+            # last of them is ``x``): the loss reads them all
+            h = x if exits is None else exits
+            return (h, aux, routing) if with_aux else h
         # logits stay in compute dtype: the xent/metric reductions
         # upcast to fp32 INSIDE their fused reads (tp.py), so an
         # .astype(f32) here would only materialize a second, 2x-wide
@@ -607,6 +684,50 @@ class Llama(TMModel):
         # compute dtype.
         logits = tp_lib.col_parallel(x, params["lm_head"])
         return (logits, aux, routing) if with_aux else logits
+
+    def _exit_loss(self, params, exits, targets, head):
+        """The looped decoder's training loss from its R exits
+        ``[R, N, D]``: per token ``sum_t q_t * xent_t - beta * H(q)``,
+        where ``lam_t = sigmoid(z_t . w_g + b_g)`` (t < R) is the
+        share of the mass reaching exit t that leaves there, ``q_1 =
+        lam_1``, ``q_t = lam_t * prod_{j<t}(1 - lam_j)`` and ``q_R``
+        the rest.  ``head(z [N, D]) -> (loss_vec [N], pred [N])`` is
+        one of the fused heads; the exits go through it one at a time
+        (a ``lax.map``), so one exit's logits are alive at once, in
+        the forward and in the backward, and the exit weights reach
+        the head as the cotangent of its loss vector.
+
+        Returns ``(loss, err, counters)``: the local token means of
+        the loss and of the LAST exit's top-1 error, and the counters
+        ``[2R + 1]`` = mean ``q_t``, mean ``xent_t``, mean exit step
+        ``sum_t t * q_t`` (``obs/exits.py``)."""
+        r = exits.shape[0]
+        with jax.named_scope("ut_exit"):
+            z = exits[:-1].astype(jnp.float32)
+            a = (
+                jnp.sum(z * params["exit_gate_w"][:, 0], axis=-1)
+                + params["exit_gate_b"][0]
+            )                                           # [R-1, N]
+            stay = jnp.cumsum(jax.nn.log_sigmoid(-a), axis=0)
+            log_q = jnp.concatenate([
+                jax.nn.log_sigmoid(a)
+                + jnp.concatenate([jnp.zeros_like(stay[:1]), stay[:-1]]),
+                stay[-1:],
+            ])                                          # [R, N]
+            q = jnp.exp(log_q)
+            xent, pred = lax.map(head, exits)           # [R, N] each
+            entropy = -jnp.sum(q * log_q, axis=0)
+            loss = jnp.mean(
+                jnp.sum(q * xent, axis=0) - self.exit_beta * entropy
+            )
+            err = jnp.mean((pred[-1] != targets).astype(jnp.float32))
+            mass = jnp.mean(q, axis=1)
+            counters = lax.stop_gradient(jnp.concatenate([
+                mass,
+                jnp.mean(xent, axis=1),
+                jnp.sum(mass * jnp.arange(1, r + 1))[None],
+            ]))
+        return loss, err, counters
 
     def _routing_counters(self, moms):
         """[L, 2E+2] per-layer moments -> the step's routing counters
@@ -861,38 +982,56 @@ class Llama(TMModel):
 
             params_v = jax.tree.map(pvary_dp, params)
 
+            def head_xent(h2, yf, p):
+                """(loss_vec [N], pred [N]) of hidden rows [N, D]."""
+                if n_xent_chunks > 1:
+                    # chunked head: unembed + xent streamed over vocab
+                    # chunks — full logits never hit HBM (tp.py)
+                    return tp_lib.chunked_unembed_xent(
+                        h2, p["lm_head"], yf, self.vocab,
+                        n_xent_chunks, MODEL_AXIS,
+                    )
+                # dense custom head: logits saved once in compute
+                # dtype, grad matmuls get bf16 operands (autodiff
+                # handed them an fp32 dlogits — ~52% MXU on the
+                # lm_head dW, profiled r4)
+                return tp_lib.dense_unembed_xent(
+                    h2, p["lm_head"], yf, self.vocab, MODEL_AXIS,
+                )
+
             def loss_fn(p):
                 # LOCAL (per-data-shard) metrics: data axis stays out
                 # of autodiff (see cast above); SP/TP reductions remain
                 # part of the model math
                 yv = self._pp_targets(y)
-                routing = ()
+                counters = ()
                 if self.n_experts:
-                    h, aux, counters = self._forward(
+                    h, aux, routing = self._forward(
                         p, x, head=False, with_aux=True
                     )
-                    routing = (counters,)
+                    counters = (routing,)
                 else:
                     h = self._forward(p, x, head=False)
-                h2 = h.reshape(-1, h.shape[-1])
+                # [N, D] rows; a looped decoder's R exits [R, N, D]
+                h2 = h.reshape(*h.shape[:-3], -1, h.shape[-1])
                 yf = yv.reshape(-1)
-                if n_xent_chunks > 1:
-                    # chunked head: unembed + xent streamed over vocab
-                    # chunks — full logits never hit HBM (tp.py)
-                    loss_vec, pred = tp_lib.chunked_unembed_xent(
-                        h2, p["lm_head"], yf, self.vocab,
-                        n_xent_chunks, MODEL_AXIS,
+                if self.ut_steps > 1:
+                    def head(z):
+                        return head_xent(z, yf, p)
+
+                    if n_xent_chunks == 1:
+                        # the dense head keeps its [N, V] logits for
+                        # the backward: R sets of them; recompute an
+                        # exit's instead (the chunked head keeps none)
+                        head = jax.checkpoint(head)
+                    loss, err, exit_counters = self._exit_loss(
+                        p, h2, yf, head
                     )
+                    counters += (lax.pmean(exit_counters, SEQ_AXIS),)
                 else:
-                    # dense custom head: logits saved once in compute
-                    # dtype, grad matmuls get bf16 operands (autodiff
-                    # handed them an fp32 dlogits — ~52% MXU on the
-                    # lm_head dW, profiled r4)
-                    loss_vec, pred = tp_lib.dense_unembed_xent(
-                        h2, p["lm_head"], yf, self.vocab, MODEL_AXIS,
-                    )
-                loss = jnp.mean(loss_vec)
-                err = jnp.mean((pred != yf).astype(jnp.float32))
+                    loss_vec, pred = head_xent(h2, yf, p)
+                    loss = jnp.mean(loss_vec)
+                    err = jnp.mean((pred != yf).astype(jnp.float32))
                 loss = lax.pmean(self._pp_value(loss), SEQ_AXIS)
                 err = lax.pmean(self._pp_value(err), SEQ_AXIS)
                 if self.n_experts:
@@ -905,14 +1044,15 @@ class Llama(TMModel):
                         + self.moe_aux_coef * aux[0]
                         + self.moe_z_coef * aux[1]
                     )
-                return loss, (err, *routing)
+                return loss, (err, *counters)
 
             # check_vma=True autodiff returns exact grads for the TP/SP
             # layout (psum↔pvary transposes); the data-parallel mean is
             # THE exchange, routed through the strategy (bf16 wire on
             # ici16/nccl16 — reference: exchanger_strategy fp16 wire)
-            # (MoE: the step's routing counters ride out with the error)
-            (loss, (err, *routing)), grads = jax.value_and_grad(
+            # (the step's counters ride out with the error: a MoE's
+            # routing, then a looped decoder's exits)
+            (loss, (err, *counters)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(params_v)
             params, opt_state, ef = plan.apply(
@@ -921,7 +1061,9 @@ class Llama(TMModel):
             )
             loss = lax.pmean(loss, dp_axes)
             err = lax.pmean(err, dp_axes)
-            return params, opt_state, ef, loss, err, *routing
+            if self.ut_steps > 1:   # token means, as the loss is
+                counters[-1] = lax.pmean(counters[-1], dp_axes)
+            return params, opt_state, ef, loss, err, *counters
 
         def val(params, x, y):
             logits = self._forward(params, x)
@@ -932,8 +1074,11 @@ class Llama(TMModel):
         from theanompi_tpu.utils.xla_options import xla_compiler_options
 
         is_tpu = mesh.devices.flat[0].platform == "tpu"
-        # a MoE step also gives out its routing counters [L, E+1]
-        moe_out = self._moe_out_specs = (P(),) if self.n_experts else ()
+        # a MoE step also gives out its routing counters [L, E+1], a
+        # looped decoder's its exit counters [2R + 1]
+        counter_out = self._counter_out_specs = (P(),) * (
+            bool(self.n_experts) + (self.ut_steps > 1)
+        )
         self._compiler_options = xla_compiler_options(
             self.config,
             overlap=plan.bucketed and is_tpu,
@@ -944,7 +1089,7 @@ class Llama(TMModel):
                 mesh=mesh,
                 in_specs=(specs, opt_specs, ef_specs, batch_spec,
                           batch_spec, P()),
-                out_specs=(specs, opt_specs, ef_specs, P(), P(), *moe_out),
+                out_specs=(specs, opt_specs, ef_specs, P(), P(), *counter_out),
             ),
             donate_argnums=(0, 1, 2),
             compiler_options=self._compiler_options,
@@ -971,14 +1116,8 @@ class Llama(TMModel):
         if self.params is None:
             # sharded init: jit + out_shardings lets GSPMD partition the
             # RNG and slice each param straight onto its mesh shards
-            shardings = jax.tree.map(
-                lambda s: NamedSharding(mesh, s), specs,
-                is_leaf=lambda x: isinstance(x, P),
-            )
-            opt_shardings = jax.tree.map(
-                lambda s: NamedSharding(mesh, s), opt_specs,
-                is_leaf=lambda x: isinstance(x, P),
-            )
+            shardings = self._shardings(specs)
+            opt_shardings = self._shardings(opt_specs)
 
             def init(key):
                 params = self._init_full_params(key)
@@ -998,6 +1137,13 @@ class Llama(TMModel):
         self._batch_sharding = NamedSharding(mesh, batch_spec)
         self._init_feed(
             self._batch_sharding, dtypes=(jnp.int32, jnp.int32)
+        )
+
+    def _shardings(self, spec_tree) -> PyTree:
+        """The mesh's ``NamedSharding`` of every spec of a tree."""
+        return jax.tree.map(
+            lambda s: NamedSharding(self.mesh, s), spec_tree,
+            is_leaf=lambda s: isinstance(s, P),
         )
 
     def _init_device_cache(self, shard_step) -> None:
@@ -1028,7 +1174,7 @@ class Llama(TMModel):
 
         d_size = self.mesh.shape[DATA_AXIS]
         has_exp = EXPERT_AXIS in self.mesh.shape
-        moe_out = self._moe_out_specs
+        counter_out = self._counter_out_specs
 
         def make_scan(length: int):
             def scan_steps(params, opt_state, ef, step, seqs, perm, lr):
@@ -1058,7 +1204,7 @@ class Llama(TMModel):
                     )
                     return (params, opt_state, ef, st + 1), tuple(per_step)
 
-                # per step: loss, err and, for a MoE, routing counters
+                # per step: loss, err and the step's counters, if any
                 (params, opt_state, ef, step), per_step = lax.scan(
                     body, (params, opt_state, ef, step), None,
                     length=length,
@@ -1072,7 +1218,18 @@ class Llama(TMModel):
                     in_specs=(specs, opt_specs, ef_specs,
                               P(), P(), P(), P()),
                     out_specs=(specs, opt_specs, ef_specs,
-                               P(), P(), P(), *moe_out),
+                               P(), P(), P(), *counter_out),
+                ),
+                # the state comes back under the shardings it went in
+                # with, so the second dispatch finds the first one's
+                # trace: left to the compiler, equivalent specs come
+                # back spelled otherwise (``P()`` for ``P(None)``) and
+                # the jit traces and lowers the whole step once more,
+                # on the host, while the chip waits (1 s at 32 layer
+                # calls; PERF.md, PR 33)
+                out_shardings=(
+                    *map(self._shardings, (specs, opt_specs, ef_specs)),
+                    *[rep] * (3 + len(counter_out)),
                 ),
                 donate_argnums=(0, 1, 2, 3),
                 compiler_options=self._compiler_options,
@@ -1108,23 +1265,28 @@ class Llama(TMModel):
                 self._step_dev,
                 losses,
                 errs,
-                *routing,
+                *counters,
             ) = scan_fn(
                 self.params, self.opt_state, self.ef_state,
                 self._step_dev, self._seqs_dev, self._perm_dev,
                 self._lr_dev,
             )
         recorder.train_error(count, losses, errs)
-        self._record_routing(recorder, routing)
+        self._record_counters(recorder, counters)
 
-    def _record_routing(self, recorder: Recorder, routing) -> None:
-        """Hand a MoE step's routing counters (device values; read
-        with the loss at the recorder's next fence) to the recorder."""
-        if routing:
+    def _record_counters(self, recorder: Recorder, counters) -> None:
+        """Hand a step's counters (device values; read with the loss
+        at the recorder's next fence) to the recorder: a MoE's
+        routing, then a looped decoder's exits."""
+        counters = list(counters)
+        if self.n_experts:
             recorder.moe_routing(
-                routing[0],
+                counters.pop(0),
+                # picks a layer CALL: a looped MoE has R * L of them
                 picks=self.data.global_batch * self.seq_len * self.moe_top_k,
             )
+        if self.ut_steps > 1:
+            recorder.ut_exits(counters.pop(0))
 
     def train_chunk(self, count: int, k: int, recorder: Recorder) -> None:
         if k == self._scan_k and self._train_scan is not None:
@@ -1199,7 +1361,7 @@ class Llama(TMModel):
                 self.ef_state,
                 loss,
                 err,
-                *routing,
+                *counters,
             ) = self._train_step(
                 self.params, self.opt_state, self.ef_state, x, y,
                 jnp.float32(self.current_lr),
@@ -1207,7 +1369,7 @@ class Llama(TMModel):
         # device scalars, materialized lazily at the next print window
         # or epoch end (Recorder.flush) — no per-step host fence
         recorder.train_error(count, loss, err)
-        self._record_routing(recorder, routing)
+        self._record_counters(recorder, counters)
 
     def val_iter(self, count: int, recorder: Recorder):
         x, y = self.put_batch(self.data.val_batch(count))

@@ -3,8 +3,8 @@ flight-recorder, Perfetto export with counter tracks, critical-path
 attribution), the step-phase profiler (``profiler.py``), the bench
 regression gate (``regress.py``), Prometheus-style metrics text, the
 process's compile counter (``compile_meter.py``), a training run's
-set-up phases (``setup.py``) and a MoE step's routing counters
-(``routing.py``).
+set-up phases (``setup.py``), a MoE step's routing counters
+(``routing.py``) and a looped decoder's exit counters (``exits.py``).
 See docs/OBSERVABILITY.md and docs/PERFORMANCE.md."""
 
 from theanompi_tpu.obs.tracer import (  # noqa: F401
@@ -25,6 +25,7 @@ from theanompi_tpu.obs.setup import (  # noqa: F401
     setup_phase,
 )
 from theanompi_tpu.obs.routing import last_moe_counters  # noqa: F401
+from theanompi_tpu.obs.exits import last_ut_counters  # noqa: F401
 from theanompi_tpu.obs.export import (  # noqa: F401
     chrome_trace,
     critical_path,
@@ -60,6 +61,7 @@ __all__ = [
     "gap_attribution",
     "last_moe_counters",
     "last_setup_phases",
+    "last_ut_counters",
     "make_context",
     "process_meter",
     "profile_scope_sets",

@@ -53,6 +53,8 @@ explicit collectives only.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -148,6 +150,14 @@ class LlamaDecoder:
                 f"n_experts={model.n_experts}, "
                 f"qk_norm={model.qk_norm} are not yet servable"
             )
+        if model.ut_steps > 1 or model.sandwich_norm:
+            raise NotImplementedError(
+                "serving knows neither a looped decoder (ut_steps="
+                f"{model.ut_steps}: a KV cache per (pass, layer) and an "
+                "exit rule) nor sandwich norms (sandwich_norm="
+                f"{model.sandwich_norm}: an RMSNorm on each branch's "
+                "output) — not yet servable"
+            )
         self.model = model
         self.mesh = model.mesh
         self.max_slots = int(max_slots)
@@ -161,6 +171,10 @@ class LlamaDecoder:
         self._rep = self._h_loc // self._hkv_loc
         self._hd = model.head_dim
         self._cdtype = model.compute_dtype
+        # the training forward's helpers at the model's own constants
+        self._norm = functools.partial(rms_norm, eps=model.norm_eps)
+        self._rope = functools.partial(rope, theta=model.rope_theta)
+        self._rope_at = functools.partial(rope_at, theta=model.rope_theta)
         kv_spec = P(None, MODEL_AXIS, None, None)
         self._cache_specs = [
             {"k": kv_spec, "v": kv_spec} for _ in range(model.n_layers)
@@ -195,7 +209,7 @@ class LlamaDecoder:
     # -- device bodies (run on LOCAL shards inside shard_map) -------------
 
     def _mlp(self, p, x):
-        xn = rms_norm(x, p["mlp_norm"])
+        xn = self._norm(x, p["mlp_norm"])
         h = swiglu(
             tp_lib.col_parallel(xn, p["w_gate"]),
             tp_lib.col_parallel(xn, p["w_up"]),
@@ -245,12 +259,12 @@ class LlamaDecoder:
 
         new_cache = []
         for layer_cache, p in zip(cache, params["layers"]):
-            xn = rms_norm(x, p["attn_norm"])
+            xn = self._norm(x, p["attn_norm"])
             q = tp_lib.col_parallel(xn, p["wq"]).reshape(s, h_loc, hd)
             k = tp_lib.col_parallel(xn, p["wk"]).reshape(s, hkv_loc, hd)
             v = tp_lib.col_parallel(xn, p["wv"]).reshape(s, hkv_loc, hd)
-            q = rope_at(q, pos)
-            k = rope_at(k, pos)
+            q = self._rope_at(q, pos)
+            k = self._rope_at(k, pos)
             # append this token's K/V at each slot's own position
             write = jax.vmap(
                 lambda c, u, i: lax.dynamic_update_slice(
@@ -274,7 +288,7 @@ class LlamaDecoder:
             x = x + tp_lib.row_parallel(o, p["wo"]).astype(self._cdtype)
             x = self._mlp(p, x)
 
-        xf = rms_norm(x, params["final_norm"])
+        xf = self._norm(x, params["final_norm"])
         logits = tp_lib.col_parallel(xf, params["lm_head"])  # [S, V/tp]
         nxt = self._sample(logits, keys, pos, temps, greedy)
         return new_cache, nxt
@@ -299,12 +313,12 @@ class LlamaDecoder:
 
         new_cache = []
         for layer_cache, p in zip(cache, params["layers"]):
-            xn = rms_norm(x, p["attn_norm"])
+            xn = self._norm(x, p["attn_norm"])
             q = _heads(tp_lib.col_parallel(xn, p["wq"]), h_loc, hd)
             k = _heads(tp_lib.col_parallel(xn, p["wk"]), hkv_loc, hd)
             v = _heads(tp_lib.col_parallel(xn, p["wv"]), hkv_loc, hd)
-            q = rope(q, pos)
-            k = rope(k, pos)
+            q = self._rope(q, pos)
+            k = self._rope(k, pos)
             kc = k.astype(self._cdtype)
             vc = v.astype(self._cdtype)
             new_cache.append({
@@ -324,7 +338,7 @@ class LlamaDecoder:
             ).astype(self._cdtype)
             x = self._mlp(p, x)
 
-        xf = rms_norm(x, params["final_norm"])
+        xf = self._norm(x, params["final_norm"])
         # only the LAST PROMPT TOKEN's logits matter — slice before
         # the head so the [t, V] logits never materialize
         x_last = lax.dynamic_slice(
@@ -710,12 +724,12 @@ class PagedLlamaDecoder(LlamaDecoder):
 
         new_pools = []
         for layer_pool, p in zip(pools, params["layers"]):
-            xn = rms_norm(x, p["attn_norm"])
+            xn = self._norm(x, p["attn_norm"])
             q = tp_lib.col_parallel(xn, p["wq"]).reshape(s, h_loc, hd)
             k = tp_lib.col_parallel(xn, p["wk"]).reshape(s, hkv_loc, hd)
             v = tp_lib.col_parallel(xn, p["wv"]).reshape(s, hkv_loc, hd)
-            q = rope_at(q, pos)
-            k = rope_at(k, pos)
+            q = self._rope_at(q, pos)
+            k = self._rope_at(k, pos)
             lp = self._write_kv(layer_pool, k, v, wbid, woff)
             new_pools.append(lp)
             o = self._paged_attend(
@@ -724,7 +738,7 @@ class PagedLlamaDecoder(LlamaDecoder):
             x = x + tp_lib.row_parallel(o, p["wo"]).astype(self._cdtype)
             x = self._mlp(p, x)
 
-        xf = rms_norm(x, params["final_norm"])
+        xf = self._norm(x, params["final_norm"])
         logits = tp_lib.col_parallel(xf, params["lm_head"])  # [S, V/tp]
         nxt = self._sample(logits, keys, pos, temps, greedy)
         return new_pools, nxt
@@ -777,7 +791,7 @@ class PagedLlamaDecoder(LlamaDecoder):
 
         new_pools = []
         for layer_pool, p in zip(pools, params["layers"]):
-            xn = rms_norm(x, p["attn_norm"])
+            xn = self._norm(x, p["attn_norm"])
             q = tp_lib.col_parallel(xn, p["wq"]).reshape(
                 s, kq, h_loc, hd
             )
@@ -789,8 +803,8 @@ class PagedLlamaDecoder(LlamaDecoder):
             )
             # rope_at over the flattened rows: per-row rotation at
             # the row's own position, the same vmap decode uses
-            q = rope_at(flat(q), pos_f).reshape(s, kq, h_loc, hd)
-            k = rope_at(flat(k), pos_f).reshape(s, kq, hkv_loc, hd)
+            q = self._rope_at(flat(q), pos_f).reshape(s, kq, h_loc, hd)
+            k = self._rope_at(flat(k), pos_f).reshape(s, kq, hkv_loc, hd)
             lp = self._write_kv(
                 layer_pool, flat(k), flat(v),
                 wbid.reshape(-1), woff.reshape(-1),
@@ -800,7 +814,7 @@ class PagedLlamaDecoder(LlamaDecoder):
             x = x + tp_lib.row_parallel(o, p["wo"]).astype(self._cdtype)
             x = self._mlp(p, x)
 
-        xf = rms_norm(x, params["final_norm"])
+        xf = self._norm(x, params["final_norm"])
         logits = tp_lib.col_parallel(xf, params["lm_head"])
         keys_f = jnp.broadcast_to(
             keys[:, None, :], (s, kq, 2)
@@ -842,12 +856,12 @@ class PagedLlamaDecoder(LlamaDecoder):
 
         new_pools = []
         for layer_pool, p in zip(pools, params["layers"]):
-            xn = rms_norm(x, p["attn_norm"])
+            xn = self._norm(x, p["attn_norm"])
             q = tp_lib.col_parallel(xn, p["wq"]).reshape(c, h_loc, hd)
             k = tp_lib.col_parallel(xn, p["wk"]).reshape(c, hkv_loc, hd)
             v = tp_lib.col_parallel(xn, p["wv"]).reshape(c, hkv_loc, hd)
-            q = rope_at(q, pos)
-            k = rope_at(k, pos)
+            q = self._rope_at(q, pos)
+            k = self._rope_at(k, pos)
             lp = self._write_kv(layer_pool, k, v, wbid, woff)
             new_pools.append(lp)
             with jax.named_scope("paged_attend"):
@@ -866,7 +880,7 @@ class PagedLlamaDecoder(LlamaDecoder):
             x = x + tp_lib.row_parallel(o, p["wo"]).astype(self._cdtype)
             x = self._mlp(p, x)
 
-        xf = rms_norm(x, params["final_norm"])
+        xf = self._norm(x, params["final_norm"])
         # only the chunk's LAST VALID row matters for sampling
         x_last = lax.dynamic_slice(
             xf, (q_len - 1, 0), (1, xf.shape[-1])

@@ -105,6 +105,14 @@ class _Phase:
         return False
 
 
+def _start_host_copy(value) -> None:
+    """Start a device value's copy to the host (read at the next
+    fence); a host value has nothing to start."""
+    start = getattr(value, "copy_to_host_async", None)
+    if start is not None:
+        start()
+
+
 class Recorder:
     def __init__(
         self,
@@ -136,6 +144,9 @@ class Recorder:
         # unread device value with its picks a step, and the last read
         self._pending_routing: tuple | None = None
         self.moe_counters: dict | None = None
+        # a looped decoder's exit counters (obs/exits.py), likewise
+        self._pending_exits = None
+        self.ut_counters: dict | None = None
         self.n_iter = 0
         self._last_print = 0
         # resilience bookkeeping (utils/supervisor.py): one entry per
@@ -256,9 +267,7 @@ class Recorder:
         round trip each.
         """
         for v in (loss, err):
-            start = getattr(v, "copy_to_host_async", None)
-            if start is not None:
-                start()
+            _start_host_copy(v)
         self._pending.append((loss, err))
         self.n_iter += int(np.shape(loss)[0]) if np.ndim(loss) else 1
 
@@ -267,13 +276,24 @@ class Recorder:
         ``[(K,) L, E+1]``, as :meth:`train_error` takes the loss:
         a device value, its copy to the host started here and read at
         the next fence.  Only the newest step's are kept."""
-        start = getattr(routing, "copy_to_host_async", None)
-        if start is not None:
-            start()
+        _start_host_copy(routing)
         self._pending_routing = (routing, picks)
+
+    def ut_exits(self, exits) -> None:
+        """A looped decoder's exit counters ``[(K,) 2R + 1]`` of a
+        step (or K-step chunk), taken and read as :meth:`moe_routing`
+        does; only the newest step's are kept."""
+        _start_host_copy(exits)
+        self._pending_exits = exits
 
     def flush(self) -> None:
         """Materialize pending device values (this is the fence)."""
+        if self._pending_exits is not None:
+            from theanompi_tpu.obs.exits import ut_counters
+
+            a = np.asarray(self._pending_exits, np.float64)
+            self.ut_counters = ut_counters(a[-1] if a.ndim == 2 else a)
+            self._pending_exits = None
         if self._pending_routing is not None:
             from theanompi_tpu.obs.routing import moe_counters
 
@@ -449,6 +469,8 @@ class Recorder:
             (f"{p}_world_size", "gauge", [(None, world_size)]),
             *((f"{p}_{k}", "gauge", [(None, (self.moe_counters or {}).get(k))])
               for k in ("moe_load_max_over_mean", "moe_dropped_picks")),
+            (f"{p}_ut_mean_exit_step", "gauge",
+             [(None, (self.ut_counters or {}).get("ut_mean_exit_step"))]),
         ])
 
     # -- persistence (reference: save()/load() of record arrays) ----------

@@ -630,6 +630,8 @@ def run(
         "n_compiles_after_warmup": n_late_compiles,
         # routing counters of the last fenced MoE step (None if dense)
         "moe_counters": recorder.moe_counters,
+        # exit counters of a looped decoder's last fenced step
+        "ut_counters": recorder.ut_counters,
         "step_profile": step_prof,
         "loader": loader_stats,
         "recorder": recorder,
