@@ -247,6 +247,59 @@ class TestLayouts:
         assert exits.shape == (2 * R + 1,)
 
 
+class TestTheExitsHead:
+    """The dense head of a looped step computes its gradients in its
+    forward pass (``tp.exits_unembed_xent``): the evidence that it
+    engaged is static.  Vocabulary 160 and 2 x 16 tokens, so that no
+    other side of the model is as wide as either."""
+
+    V = 160
+
+    @pytest.fixture(scope="class")
+    def model(self, devices8):
+        return build(devices8, vocab=self.V, seq_len=16)
+
+    def wide_products(self, text):
+        return [ln for ln in text.splitlines()
+                if "dot_general" in ln and f"x{self.V}x" in ln]
+
+    @pytest.mark.parametrize("step, products", [("train", 3), ("val", 1)])
+    def test_head_products_in_the_lowered_step(self, model, step, products):
+        """Logits, dx and dW in the one loop body over the R exits
+        (the mapped dense head under its remat had a fourth, the
+        replayed logits); validation reads the last exit alone."""
+        batch = model.put_batch((np.zeros((2, 16), np.int32),) * 2)
+        if step == "train":
+            lowered = model._train_step.lower(
+                model.params, model.opt_state, model.ef_state, *batch,
+                jnp.float32(LR))
+        else:
+            lowered = model._val_step.lower(model.params, *batch)
+        assert len(self.wide_products(lowered.as_text())) == products
+
+    def test_no_logits_cross_to_the_backward(self, model):
+        """Of what the looped loss keeps for its backward pass, the
+        only arrays with a vocabulary-wide side are ``lm_head`` and
+        its gradient: no ``[N, V]`` logits, of one exit or of R."""
+        from jax.sharding import PartitionSpec as P
+
+        def loss(p, x, y):
+            exits = model._forward(p, x, head=False)
+            out = model._exit_loss(
+                p, exits.reshape(R, -1, exits.shape[-1]), y.reshape(-1))
+            return jax.lax.pmean(out[0], ("data", "seq"))
+
+        tokens = P("data", "seq")
+        x = np.zeros((2, 16), np.int32)
+        _, vjp = jax.vjp(
+            lambda p: jax.shard_map(
+                loss, mesh=model.mesh, in_specs=(model._specs, tokens, tokens),
+                out_specs=P())(p, x, x),
+            model.params)
+        wide = [a.shape for a in jax.tree.leaves(vjp) if self.V in a.shape]
+        assert wide == [(SMALL["dim"], self.V)] * 2, wide
+
+
 class TestDefaultsAreTodaysProgram:
     """``ut_steps`` 1 and the other new knobs at their defaults, on
     the Mistral cell's rehearsal sizes."""
@@ -284,6 +337,60 @@ class TestDefaultsAreTodaysProgram:
         # of the dense decoder (theta 1e4, eps 1e-5 written into it)
         want = float(plain_ref.loss(p0, *wide_batch, n_heads=4, n_kv_heads=2))
         assert float(plain[3]) == pytest.approx(want, rel=1e-6)
+
+    @pytest.mark.parametrize("cell", ["mistral7b_train_t4096",
+                                      "olmoe_train_t4096"])
+    def test_one_exit_never_meets_the_exits_head(self, devices8, cell,
+                                                 monkeypatch):
+        """The separation is by what the code observes, ``ut_steps``:
+        the step of the two cells that share ``loss_fn`` and ``tp.py``
+        (at their rehearsal sizes) lowers with ``exits_unembed_xent``
+        and ``_exit_loss`` made to raise, and its vocabulary-wide
+        products are those ``dense_unembed_xent`` lowers to by
+        itself."""
+        import re
+
+        from benchmark.drivers.train import program_config
+        from benchmark.run import load_cell
+        from theanompi_tpu.parallel import tp as tp_lib
+
+        config = load_cell(cell)["config"]
+        cfg = program_config(dict(config, **config["rehearsal"]),
+                             seed=0, n_replicas=1)
+        cfg.pop("device_data_cache")
+
+        def refuse(*a, **k):
+            raise AssertionError("the exits' head in a step of one exit")
+
+        monkeypatch.setattr(tp_lib, "exits_unembed_xent", refuse)
+        monkeypatch.setattr(Llama, "_exit_loss", refuse)
+        m = Llama(cfg)
+        m.build_model(n_replicas=1)
+        m.compile_iter_fns(mesh=make_mesh(model=1, devices=devices8[:1]))
+        assert m.ut_steps == 1
+        b = np.zeros((cfg["batch_size"], cfg["seq_len"]), np.int32)
+        step = m._train_step.lower(
+            m.params, m.opt_state, m.ef_state, *m.put_batch((b, b)),
+            jnp.float32(1e-4)).as_text()
+
+        def products(text):
+            """The vocabulary-wide products, as operand and result
+            types with their contracting dimensions."""
+            return sorted(
+                re.search(r"contracting_dims = \S+ x \S+", ln).group()
+                + ln.rsplit(" : ", 1)[1]
+                for ln in text.splitlines()
+                if "dot_general" in ln and f"x{m.vocab}x" in ln
+            )
+
+        n = cfg["batch_size"] * cfg["seq_len"]
+        head = jax.jit(jax.grad(
+            lambda x, w: jnp.sum(tp_lib.dense_unembed_xent(
+                x, w, jnp.zeros((n,), jnp.int32), m.vocab, None)[0]),
+            argnums=(0, 1),
+        )).lower(jnp.zeros((n, m.dim)), jnp.zeros((m.dim, m.vocab))).as_text()
+        assert len(products(head)) == 3
+        assert products(step) == products(head)
 
     @pytest.mark.parametrize("knob", [dict(rope_theta=1e6),
                                       dict(norm_eps=1e-2)], ids=str)

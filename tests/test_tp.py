@@ -5,6 +5,8 @@ upstream).  Every sharded op is checked against its dense single-device
 equivalent on the virtual 8-device CPU mesh.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,7 +14,7 @@ import pytest
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from theanompi_tpu.parallel import MODEL_AXIS, make_mesh
+from theanompi_tpu.parallel import MODEL_AXIS, SEQ_AXIS, make_mesh
 from theanompi_tpu.parallel import tp as tp_lib
 
 
@@ -171,3 +173,106 @@ class TestCustomHeads:
                 np.asarray(a), np.asarray(b), atol=2e-7,
                 err_msg=f"{head} {name}",
             )
+
+
+class TestExitsHead:
+    """``exits_unembed_xent`` (the R exits of a looped decoder through
+    one dense head that computes its gradients in its forward pass)
+    against what it replaced: a ``lax.map`` over ``dense_unembed_xent``
+    under its remat, the row weights reaching it as the cotangent of
+    its loss vector.  Under the step's own vma-checked ``shard_map``."""
+
+    R, N, D, V = 3, 24, 16, 64
+    G = 0.37        # a scalar cotangent other than 1
+    SPECS = (P(None, SEQ_AXIS), P(None, MODEL_AXIS), P(SEQ_AXIS),
+             P(None, SEQ_AXIS))        # xs, w, labels, row_w
+
+    @pytest.fixture
+    def data(self, rng):
+        return (
+            rng.standard_normal((self.R, self.N, self.D)).astype(np.float32),
+            rng.standard_normal((self.D, self.V)).astype(np.float32),
+            rng.integers(0, self.V, self.N).astype(np.int32),
+            rng.uniform(0.1, 1.0, (self.R, self.N)).astype(np.float32),
+        )
+
+    def exits_head(self, xs, w, y, row_w, axis=MODEL_AXIS):
+        return tp_lib.exits_unembed_xent(xs, w, y, row_w, self.V, axis)
+
+    def mapped_dense_head(self, xs, w, y, row_w, axis=MODEL_AXIS,
+                          remat=True):
+        def head(z):
+            return tp_lib.dense_unembed_xent(z, w, y, self.V, axis)
+
+        xent, pred = lax.map(jax.checkpoint(head) if remat else head, xs)
+        return jnp.sum(row_w * xent), xent, pred
+
+    def run(self, head, mesh, data):
+        """(G * total over all tokens, xent, pred), and the gradients
+        of the first to xs, w and row_w."""
+        def fn(xs, w, y, row_w):
+            def loss(xs, w, row_w):
+                total, xent, pred = head(xs, w, y, row_w)
+                return self.G * lax.psum(total, SEQ_AXIS), (xent, pred)
+
+            (total, aux), grads = jax.value_and_grad(
+                loss, argnums=(0, 1, 2), has_aux=True)(xs, w, row_w)
+            return (total, *aux), grads
+
+        xs, w, _, row_w = self.SPECS
+        return jax.jit(jax.shard_map(
+            fn, mesh=mesh, in_specs=self.SPECS,
+            out_specs=((P(), row_w, row_w), (xs, w, row_w)),
+        ))(*data)
+
+    @pytest.mark.parametrize("layout", [dict(), dict(model=2), dict(seq=2)],
+                             ids=["one_device", "tp2", "sp2"])
+    def test_matches_the_mapped_dense_head(self, devices8, data, layout):
+        want_out, want_grads = self.run(
+            self.mapped_dense_head, make_mesh(devices=devices8[:1]), data)
+        got_out, got_grads = self.run(
+            self.exits_head,
+            make_mesh(devices=devices8[:2 if layout else 1], **layout), data)
+        np.testing.assert_allclose(got_out[0], want_out[0], rtol=1e-6)
+        np.testing.assert_allclose(got_out[1], want_out[1], rtol=1e-5)
+        np.testing.assert_array_equal(got_out[2], want_out[2])
+        for name, a, b in zip(("dxs", "dw", "drow_w"), got_grads, want_grads):
+            assert float(jnp.max(jnp.abs(b))) > 1e-3, name
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6,
+                                       err_msg=name)
+
+    def residual_shapes(self, head, data):
+        xs, w, y, row_w = data
+        _, vjp = jax.vjp(
+            lambda xs, w, row_w: head(xs, w, y, row_w, axis=None)[0],
+            xs, w, row_w)
+        return [a.shape for a in jax.tree.leaves(vjp)]
+
+    def test_no_logits_cross_to_the_backward(self, data):
+        """What the forward keeps for the backward holds no [N, V]
+        array (the mapped dense head WITHOUT its remat keeps R)."""
+        logits = {(self.N, self.V), (self.R, self.N, self.V)}
+        kept = self.residual_shapes(self.exits_head, data)
+        assert not logits & set(kept), kept
+        assert (self.R, self.N, self.D) in kept and (self.D, self.V) in kept
+        assert (self.R, self.N, self.V) in self.residual_shapes(
+            functools.partial(self.mapped_dense_head, remat=False), data)
+
+    @pytest.mark.parametrize("differentiated, products", [(True, 3),
+                                                          (False, 1)])
+    def test_products_in_the_lowered_text(self, data, differentiated,
+                                          products):
+        """One loop body over the R exits: logits, dx and dW under
+        ``grad`` (the mapped dense head's two bodies held four), the
+        logits alone in an undifferentiated call (validation)."""
+        xs, w, y, row_w = data
+
+        def total(xs, w, row_w):
+            return self.exits_head(xs, w, y, row_w, axis=None)[0]
+
+        fn = jax.grad(total, argnums=(0, 1, 2)) if differentiated else total
+        text = jax.jit(fn).lower(xs, w, row_w).as_text()
+        wide = [ln for ln in text.splitlines()
+                if "dot_general" in ln and f"x{self.V}x" in ln]
+        assert len(wide) == products, wide
+        assert text.count("stablehlo.while") == 1

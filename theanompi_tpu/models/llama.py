@@ -685,17 +685,22 @@ class Llama(TMModel):
         logits = tp_lib.col_parallel(x, params["lm_head"])
         return (logits, aux, routing) if with_aux else logits
 
-    def _exit_loss(self, params, exits, targets, head):
+    def _exit_loss(self, params, exits, targets, head=None):
         """The looped decoder's training loss from its R exits
         ``[R, N, D]``: per token ``sum_t q_t * xent_t - beta * H(q)``,
         where ``lam_t = sigmoid(z_t . w_g + b_g)`` (t < R) is the
         share of the mass reaching exit t that leaves there, ``q_1 =
         lam_1``, ``q_t = lam_t * prod_{j<t}(1 - lam_j)`` and ``q_R``
-        the rest.  ``head(z [N, D]) -> (loss_vec [N], pred [N])`` is
-        one of the fused heads; the exits go through it one at a time
-        (a ``lax.map``), so one exit's logits are alive at once, in
-        the forward and in the backward, and the exit weights reach
-        the head as the cotangent of its loss vector.
+        the rest.  The exit weights are known before any head runs,
+        so the dense head (``head`` None) is GIVEN them and computes
+        its gradients in its forward pass (``tp.exits_unembed_xent``):
+        three head products an exit, one exit's logits alive at once,
+        none kept for the backward and none replayed there; the gate's
+        gradient comes back through the head's row weights.  Else
+        ``head(z [N, D]) -> (loss_vec [N], pred [N])`` is the streamed
+        head, which keeps no logits: the exits go through it one at a
+        time (a ``lax.map``) and the exit weights reach it as the
+        cotangent of its loss vector.
 
         Returns ``(loss, err, counters)``: the local token means of
         the loss and of the LAST exit's top-1 error, and the counters
@@ -715,11 +720,19 @@ class Llama(TMModel):
                 stay[-1:],
             ])                                          # [R, N]
             q = jnp.exp(log_q)
-            xent, pred = lax.map(head, exits)           # [R, N] each
             entropy = -jnp.sum(q * log_q, axis=0)
-            loss = jnp.mean(
-                jnp.sum(q * xent, axis=0) - self.exit_beta * entropy
-            )
+            if head is None:
+                # the token mean's 1 / N inside the row weights: the
+                # softmax gradient is rounded to the compute dtype
+                # once, after that product, as under autodiff's mean
+                weighted, xent, pred = tp_lib.exits_unembed_xent(
+                    exits, params["lm_head"], targets,
+                    q / exits.shape[1], self.vocab, MODEL_AXIS,
+                )       # xent, pred: no gradient; counters only
+            else:
+                xent, pred = lax.map(head, exits)       # [R, N] each
+                weighted = jnp.mean(jnp.sum(q * xent, axis=0))
+            loss = weighted - self.exit_beta * jnp.mean(entropy)
             err = jnp.mean((pred[-1] != targets).astype(jnp.float32))
             mass = jnp.mean(q, axis=1)
             counters = lax.stop_gradient(jnp.concatenate([
@@ -1016,14 +1029,15 @@ class Llama(TMModel):
                 h2 = h.reshape(*h.shape[:-3], -1, h.shape[-1])
                 yf = yv.reshape(-1)
                 if self.ut_steps > 1:
-                    def head(z):
-                        return head_xent(z, yf, p)
+                    # the dense head would keep R sets of [N, V]
+                    # logits for the backward, or replay each: the
+                    # exits' own head needs neither (``_exit_loss``);
+                    # the streamed head keeps none, an exit at a time
+                    head = None
+                    if n_xent_chunks > 1:
+                        def head(z):
+                            return head_xent(z, yf, p)
 
-                    if n_xent_chunks == 1:
-                        # the dense head keeps its [N, V] logits for
-                        # the backward: R sets of them; recompute an
-                        # exit's instead (the chunked head keeps none)
-                        head = jax.checkpoint(head)
                     loss, err, exit_counters = self._exit_loss(
                         p, h2, yf, head
                     )

@@ -442,6 +442,127 @@ def _dense_head_bwd(vocab, axis_name, res, cts):
 dense_unembed_xent.defvjp(_dense_head_fwd, _dense_head_bwd)
 
 
+# -- the R exits of a looped decoder through one dense head ----------------
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def exits_unembed_xent(xs, w, labels, row_w, vocab, axis_name):
+    """The dense head over ALL R exits of a looped decoder, its
+    gradients computed in its FORWARD pass.
+
+    ``dense_unembed_xent`` keeps its [N, V] logits for the backward;
+    R exits through it keep R sets, or replay each exit's logits under
+    a remat (a fourth head product an exit; PERF.md, PR 34).  The exit
+    weights are known before any head runs, so a head that is GIVEN
+    its row weights forms ``dlogits = (softmax - onehot) * row_w``
+    while an exit's logits are alive and runs both gradient products
+    there and then: three products an exit, one exit's [N, V] logits
+    and ``dlogits`` alive at a time (one loop body), none between
+    forward and backward.  What crosses to the backward is ``dx``
+    [R, N, D], one fp32 ``dW`` summed over the exits and ``xent``;
+    the backward scales them by the scalar cotangent.
+
+    xs: [R, N, D] exits (compute dtype), w: [D, V_loc] (fp32 master,
+    cast to compute dtype once a call), labels: [N] GLOBAL int ids,
+    row_w: [R, N] fp32.  Statistics, operands, dtypes, accumulation
+    and sharding semantics of ``dense_unembed_xent``, ``row_w[t]``
+    where its loss vector's cotangent stood.
+    Returns (total = sum(row_w * xent), xent [R, N] fp32, pred [R, N]
+    int32).  Only ``total`` carries a gradient (to xs, w and row_w):
+    ``xent`` and ``pred`` are outputs WITHOUT one — read them under
+    ``stop_gradient``.  Undifferentiated, no gradient product runs.
+    """
+    xent, pred, _, _ = _exits_head_loop(
+        xs, w, labels, row_w, vocab, axis_name, with_grads=False
+    )
+    return jnp.sum(row_w * xent), xent, pred
+
+
+def _exits_head_loop(xs, w, labels, row_w, vocab, axis_name, with_grads):
+    """(xent [R, N], pred [R, N], dx, dW): the gradients of
+    ``sum(row_w * xent)`` — dx [R, N, D] in xs' dtype, reduced to xs'
+    vma; dW [D, V_loc] fp32, this shard's partial — or None, None."""
+    v_loc = w.shape[1]
+    off = vocab_shard_info(vocab, axis_name)[1] if axis_name else 0
+    wc = w.astype(xs.dtype)                 # R (3 R) products read it
+    local = labels - off
+    hit = (local >= 0) & (local < v_loc)
+    safe = jnp.clip(local, 0, v_loc - 1)
+
+    def body(dw, exit_):
+        x2, rw = exit_
+        lg = x2 @ wc                                # [N, V_loc], bf16
+        # _dense_head_fwd_impl's statistics and values, each read
+        # from the compute-dtype logits themselves: its fp32 copy of
+        # them (1.6 GB at [8192, 49152], written for the target's
+        # gather alone) is here only ever a reduction's operand
+        m = jnp.max(lg, axis=-1).astype(jnp.float32)
+        tgt = jnp.take_along_axis(lg, safe[:, None], axis=-1)[:, 0]
+        tgt = jnp.where(hit, tgt.astype(jnp.float32), 0.0)
+        pred = jnp.argmax(lg, axis=-1) + off
+        if axis_name:
+            gm = lax.pmax(m, axis_name)
+            tgt = lax.psum(tgt, axis_name)
+            pred = lax.pmin(jnp.where(m >= gm, pred, vocab), axis_name)
+        else:
+            gm = m
+        s = jnp.sum(jnp.exp(lg.astype(jnp.float32) - gm[:, None]), axis=-1)
+        if axis_name:
+            s = lax.psum(s, axis_name)
+        lse = gm + jnp.log(jnp.maximum(s, 1e-30))
+        xent = lse - tgt
+        if not with_grads:
+            return None, (xent, pred, None)
+        # _dense_head_bwd's expression, the row weight for a cotangent
+        p = jnp.exp(lg.astype(jnp.float32) - lse[:, None])
+        onehot = (jnp.arange(v_loc)[None, :] == safe[:, None]) & hit[:, None]
+        dlgc = (
+            (p - onehot.astype(jnp.float32)) * rw[:, None]
+        ).astype(x2.dtype)                  # bf16 wire
+        # written once and read by both products, not re-evaluated as
+        # a producer inside each one's operand: on the chip at
+        # [8192, 49152] the pass costs 2.4 ms an exit and the products
+        # gain 2.3 (dx) + 0.7 (dW) (PERF.md, PR 34)
+        dlgc = lax.optimization_barrier(dlgc)
+        dw = dw + lax.dot_general(
+            x2, dlgc, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                   # [D, V_loc]
+        dx = lax.dot_general(
+            dlgc, wc, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                   # [N, D]
+        dx = _reduce_ct_to_primal(dx, x2).astype(x2.dtype)
+        return dw, (xent, pred, dx)
+
+    dw0 = None
+    if with_grads:
+        # (``local``: the shard's offset varies over the model axis
+        # even where ``w`` is replicated over it)
+        vma = _carry_vma(xs, w, local, row_w)
+        dw0 = _vary(jnp.zeros(w.shape, jnp.float32), vma)
+    dw, (xent, pred, dx) = lax.scan(body, dw0, (xs, row_w))
+    return xent, pred, dx, dw
+
+
+def _exits_head_fwd(xs, w, labels, row_w, vocab, axis_name):
+    xent, pred, dx, dw = _exits_head_loop(
+        xs, w, labels, row_w, vocab, axis_name, with_grads=True
+    )
+    return (jnp.sum(row_w * xent), xent, pred), (dx, dw, xent, w, row_w)
+
+
+def _exits_head_bwd(vocab, axis_name, res, cts):
+    g = cts[0].astype(jnp.float32)   # of total; xent, pred: no gradient
+    dx, dw, xent, w, row_w = res
+    # each shard's partial dW is scaled by ITS cotangent, then summed
+    dw = _reduce_ct_to_primal(g * dw, w)
+    d_row_w = _reduce_ct_to_primal(g * xent, row_w)
+    return (g * dx).astype(dx.dtype), dw.astype(w.dtype), None, d_row_w
+
+
+exits_unembed_xent.defvjp(_exits_head_fwd, _exits_head_bwd)
+
+
 # -- spec-aware gradient reduction ------------------------------------------
 
 def grad_sync(grads: PyTree, specs: PyTree,
