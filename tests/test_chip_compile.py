@@ -478,3 +478,203 @@ def test_looped_decoder_step_compiles_with_its_scopes_and_one_exit_alive(
             if " convolution(" in ln and "ut_exit" in ln]
     assert len(head) == 3, head
     assert not any("transpose(jvp(ut_exit))" in ln for ln in head), head
+
+
+# -- the step program's blocks (benchmark/layer_metrics/_blocks.py) ----------
+
+_KERNELS = {"flash_attention": {"hlo_part": "_flash_jit"},
+            "moe_grouped_matmul": {"hlo_part": "ragged-dot"}}
+# what does work at the top level of a compiled step: the rest
+# (constants, parameters, bitcasts, tuples) takes no device time
+_WORK = (" fusion(", " custom-call(", " convolution(", " copy(", " reduce(",
+         " reduce-window(", " scatter(", " gather(", " sort(",
+         " select-and-scatter(", " dynamic-update-slice(", " dynamic-slice(")
+
+
+def _step_blocks(text):
+    """``({(block, phase)}, {instruction: entry} of the top-level
+    instructions without a block that do work, the entries of the
+    Pallas kernels and of the fusions that hold a product)``."""
+    from benchmark.layer_metrics import _blocks
+
+    entries = _blocks.instruction_blocks(
+        {"hlo_text": text, "cell": {"config": {"kernels": _KERNELS}}})
+    comps = _blocks.computations(text)
+    fused = set()
+    top, kernels, products = {}, {}, {}
+    for lines in comps.values():
+        for line in lines:
+            called = _blocks._CALLS.search(line)
+            if called and " fusion(" in line:
+                fused.add(called.group(1))
+    for comp, lines in comps.items():
+        if comp in fused:
+            continue
+        for line in lines:
+            name = _blocks.hlo_read._INSTR.match(line).group(1)
+            entry = entries[name]
+            if "tpu_custom_call" in line:
+                kernels[name] = entry
+            called = _blocks._CALLS.search(line)
+            if called and any(_blocks._PRODUCT.search(ln)
+                              for ln in comps.get(called.group(1), ())):
+                products[name] = entry
+            if entry["block"] == "other" and any(w in line for w in _WORK):
+                top[name] = entry
+    have = {(e["block"], e["phase"]) for e in entries.values()}
+    return have, top, kernels, products
+
+
+def _llama_step_text(chip, monkeypatch, **knobs):
+    """The compiled text of a small ``Llama``'s real train step
+    (``compile_iter_fns``: ``value_and_grad`` of ``loss_fn``, then
+    ``ExchangePlan.apply``) for the v5e; the parameters are shapes, so
+    nothing is placed."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from theanompi_tpu.models.llama import Llama
+    from theanompi_tpu.ops import attention
+    from theanompi_tpu.parallel import make_mesh
+
+    t, b = 256, 2
+    mesh = make_mesh(data=1, devices=list(chip.device_set))
+    rep = NamedSharding(mesh, P())
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)  # as on the chip
+    model = Llama(dict(dict(
+        dim=256, n_layers=2, n_heads=2, n_kv_heads=2, ffn_dim=512,
+        vocab=4096, seq_len=t, batch_size=b, compute_dtype="bfloat16",
+        remat=True, optimizer="adam", xent_chunks=1,
+    ), **knobs))
+    model.build_model(n_replicas=1)
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+            tree)
+
+    model.params = shapes(
+        jax.eval_shape(model._init_full_params, jax.random.key(0)))
+    model.opt_state = shapes(
+        jax.eval_shape(model.optimizer.init, model.params))
+    model.compile_iter_fns(mesh=mesh)
+    ids = jax.ShapeDtypeStruct(
+        (b, t), jnp.int32, sharding=NamedSharding(mesh, P("data", "seq")))
+    lr = jax.ShapeDtypeStruct((), jnp.float32, sharding=rep)
+    return model._train_step.lower(
+        model.params, model.opt_state, model.ef_state, ids, ids, lr,
+    ).compile().as_text()
+
+
+# an ``op_name`` outside every layer and every block: the step's own
+# glue (the MoE aux moments of ``_forward``, the loss's aux terms, a
+# classifier's cast of its input)
+_GLUE = r"jit\(\w+\)/(jvp\(\)/|transpose\(jvp\(\)\)/)?[\w\-]+"
+
+
+@pytest.mark.parametrize("knobs, kernel_blocks, most_unnamed", [
+    (dict(), {"blk_attn"}, 20),
+    (dict(n_experts=8, moe_top_k=2, ffn_dim=256, capacity_factor=None),
+     {"blk_attn", "blk_ffn"}, 70),
+], ids=["dense", "dropless_moe"])
+def test_llama_step_names_its_blocks_in_every_phase(
+    chip, monkeypatch, knobs, kernel_blocks, most_unnamed
+):
+    """A plain ``Llama`` (2 layers under remat, the dense head, Adam)
+    and a dropless MoE one through the model's own train step,
+    compiled for the v5e: every block in each phase it has (the head
+    and the embedding are outside the remat: no replay), every flash
+    and grouped-product kernel and every fusion that holds a product
+    under a block, the optimizer's instructions under ``opt_update``,
+    and what is left without a block either XLA's own (no ``op_name``:
+    copies, slices, ``ConcatBitcast``) or the step's glue outside
+    every layer."""
+    import re
+
+    have, top, kernels, products = _step_blocks(
+        _llama_step_text(chip, monkeypatch, **knobs))
+    expected = {
+        (block, phase)
+        for block in ("blk_attn", "blk_ffn")
+        for phase in ("fwd", "replay", "bwd")
+    } | {("blk_embed", "fwd"), ("blk_embed", "bwd"), ("blk_head", "fwd"),
+         ("blk_head", "bwd"), ("opt_update", "fwd")}
+    assert expected <= have, expected - have
+    assert not {p for b, p in have if b in ("blk_head", "blk_embed")} & {"replay"}
+    assert kernels and {e["block"] for e in kernels.values()} == kernel_blocks
+    assert {e["phase"] for e in kernels.values()} >= {"fwd", "bwd"}
+    assert products and "other" not in {
+        e["block"] for e in products.values()}, products
+    # a weight gradient fused with its Adam update: once under its
+    # block, flagged for ``opt_update_ms``
+    assert any(e["carries_opt"] and e["block"].startswith("blk_")
+               for e in products.values())
+    named = [e["op_name"] for e in top.values() if e["op_name"]]
+    assert all(re.fullmatch(_GLUE, n) for n in named), named
+    assert len(top) <= most_unnamed, sorted(top)
+
+
+def test_classifier_step_names_conv_and_batch_norm(chip, monkeypatch):
+    """A three-block ``ClassifierModel`` (conv, batch norm, relu,
+    pool; twice; pool, FC) through its own train step compiled for
+    the v5e: ``blk_conv`` and ``blk_bn`` forward and backward (the
+    scope around ``_bn_train``'s call reaches its backward rule),
+    ``blk_pool``, ``blk_head`` with the loss, the optimizer."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from theanompi_tpu.models.base import ClassifierModel
+    from theanompi_tpu.ops.layers import (BN, FC, Activation, Conv,
+                                          GlobalAvgPool, Pool, Sequential)
+    from theanompi_tpu.parallel import make_mesh
+
+    class ThreeBlocks(ClassifierModel):
+        def build_model(self, n_replicas=1):
+            self.net = Sequential([
+                Conv(128, 3, pad=1, bias=False), BN(), Activation("relu"),
+                Pool(2),
+                Conv(128, 3, pad=1, bias=False), BN(), Activation("relu"),
+                GlobalAvgPool(), FC(10),
+            ])
+            self.input_shape = (32, 32, 8)
+            self.data = None
+
+    mesh = make_mesh(data=1, devices=list(chip.device_set))
+    model = ThreeBlocks(dict(batch_size=16, compute_dtype="bfloat16",
+                             optimizer="momentum"))
+    model.build_model()
+    # the parameters stay where they were made: a described device
+    # holds nothing, and the step is lowered from their shapes
+    monkeypatch.setattr(jax, "device_put", lambda x, *a, **k: x)
+    model.compile_iter_fns(mesh=mesh)
+    rep, dp = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+
+    def shapes(tree, sharding=rep):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                jnp.shape(a), jnp.result_type(a), sharding=sharding), tree)
+
+    x = jax.ShapeDtypeStruct((16, 32, 32, 8), jnp.float32, sharding=dp)
+    y = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=dp)
+    lr = jax.ShapeDtypeStruct((), jnp.float32, sharding=rep)
+    text = model._train_step.lower(
+        shapes(model.params), shapes(model.net_state),
+        shapes(model.opt_state), model.ef_state, x, y, lr,
+        shapes(jax.random.PRNGKey(0)),
+    ).compile().as_text()
+    have, top, _, products = _step_blocks(text)
+    expected = {
+        (block, phase)
+        for block in ("blk_conv", "blk_bn", "blk_pool", "blk_head")
+        for phase in ("fwd", "bwd")
+    } | {("opt_update", "fwd")}
+    assert expected <= have, expected - have
+    assert "replay" not in {p for _, p in have}
+    # the convolutions (and the classifier's product) decide their
+    # fusions' blocks, whatever XLA fused into them
+    assert {e["block"] for e in products.values()} == {"blk_conv", "blk_head"}
+    assert {e["phase"] for e in products.values()
+            if e["block"] == "blk_conv"} == {"fwd", "bwd"}
+    import re
+
+    named = [e["op_name"] for e in top.values() if e["op_name"]]
+    assert all(re.fullmatch(_GLUE, n) for n in named), named
+    assert len(top) <= 10, sorted(top)

@@ -174,3 +174,32 @@ class TestRegistryProfilerCoupling:
 
     def test_rule_in_catalog(self):
         assert "TM107" in core.RULES
+
+
+class TestBlockScopes:
+    """The step program's block names (PR 35): registered, and the
+    files that carry them clean under TM107."""
+
+    BLOCKS = ("blk_embed", "blk_attn", "blk_ffn", "blk_head",
+              "blk_conv", "blk_bn", "blk_pool")
+
+    def test_every_block_label_is_registered_under_its_own_leg(self):
+        for label in self.BLOCKS:
+            assert scopes.label_registered(label), label
+            assert PROFILE_SCOPES[label] == label
+
+    def test_the_files_that_name_blocks_are_clean(self):
+        import pathlib
+        import re
+
+        root = pathlib.Path(__file__).resolve().parents[1]
+        used = set()
+        for rel in ("theanompi_tpu/models/llama.py",
+                    "theanompi_tpu/models/base.py",
+                    "theanompi_tpu/ops/layers.py"):
+            src = (root / rel).read_text()
+            used |= set(re.findall(r'named_scope\("(blk_\w+)"\)', src))
+            out = core.collect([core.SourceFile(src, rel)],
+                               rule_fns=(scopes.check_file,))
+            assert out == [], "\n".join(str(f) for f in out)
+        assert used == set(self.BLOCKS)

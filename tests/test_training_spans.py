@@ -305,3 +305,54 @@ def test_a_forced_recompile_is_reported_with_its_iteration():
     assert first["epoch"] == 1 and first["iteration"] == 6
     assert first["programs"] >= 1 and first["compile_s"] > 0.0
     assert isinstance(first["last_program"], str)
+
+
+# -- the seconds before the worker -------------------------------------------
+
+
+def test_process_stamps_are_monotone_beside_the_phases():
+    """Process start <= import start <= import end <= the worker's
+    entry, on ``time.monotonic``; the summary carries the three
+    differences beside the phase dict, whose keys are what they
+    were."""
+    import time
+
+    import theanompi_tpu
+    from theanompi_tpu.obs import last_process_phases
+    from theanompi_tpu.obs.setup import process_start
+
+    res = _run(n_epochs=1)
+    got = res["process_phases"]
+    assert list(got) == ["before_import", "import", "before_worker"]
+    assert all(v is not None and v >= 0 for v in got.values()), got
+    t0, t1 = theanompi_tpu._IMPORT_SPAN
+    assert process_start() <= t0 <= t1 <= time.monotonic()
+    assert got["import"] == t1 - t0
+    assert got["before_import"] == pytest.approx(t0 - process_start())
+    assert last_process_phases() == got
+    # beside the phases, not among them
+    assert sorted(res["setup_phases"]) == sorted(SETUP_NAMES)
+    assert sorted(last_setup_phases()) == sorted(SETUP_NAMES)
+    for phase in last_setup_phases().values():
+        assert sorted(phase) == ["cache_hits", "cache_misses", "compile_s",
+                                 "programs", "s", "self_s", "t0", "t1"]
+
+
+def test_process_stamps_without_a_readable_start(monkeypatch):
+    from theanompi_tpu.obs import setup as setup_mod
+
+    def unreadable(*a, **k):
+        raise OSError("no /proc here")
+
+    # the module's own name for ``open`` shadows the builtin
+    monkeypatch.setattr(setup_mod, "open", unreadable, raising=False)
+    setup_mod.process_start.cache_clear()
+    try:
+        assert setup_mod.process_start() is None
+        got = SetupRecord(process_meter()).process_phases()
+    finally:
+        monkeypatch.undo()
+        setup_mod.process_start.cache_clear()
+    assert got["before_import"] is None
+    assert got["import"] >= 0 and got["before_worker"] >= 0
+    assert setup_mod.process_start() is not None

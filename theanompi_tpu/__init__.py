@@ -23,7 +23,15 @@ is a `lax.pmean` inside the jitted train step, which XLA overlaps with
 backprop automatically.
 """
 
-from theanompi_tpu.version import __version__
-from theanompi_tpu.rules import BSP, EASGD, GOSGD
+import time as _time
+
+_t_import = _time.monotonic()
+
+from theanompi_tpu.version import __version__  # noqa: E402
+from theanompi_tpu.rules import BSP, EASGD, GOSGD  # noqa: E402
+
+#: start and end of this import on ``time.monotonic``: two of the
+#: process stamps ``obs/setup.py`` reports (``process_phases``)
+_IMPORT_SPAN = (_t_import, _time.monotonic())
 
 __all__ = ["BSP", "EASGD", "GOSGD", "__version__"]

@@ -145,6 +145,18 @@ PROFILE_SCOPES: dict[str, str] = {
     # benchmark/layer_metrics/_ut.py reads the same labels
     "ut_stack": "ut_stack",
     "ut_exit": "ut_exit",
+    # the step program's blocks (models/llama.py ``_forward`` /
+    # ``_layer`` / ``loss_fn``, ops/layers.py, models/base.py, PR 35):
+    # with ``opt_update`` and ``exchange_b<i>`` every instruction of a
+    # step lies under one; the scopes above nest inside them.
+    # benchmark/layer_metrics/_blocks.py reads any ``blk_`` label
+    "blk_embed": "blk_embed",
+    "blk_attn": "blk_attn",
+    "blk_ffn": "blk_ffn",
+    "blk_head": "blk_head",
+    "blk_conv": "blk_conv",
+    "blk_bn": "blk_bn",
+    "blk_pool": "blk_pool",
 }
 
 #: label PREFIX -> leg family: labels carrying a per-instance index

@@ -700,8 +700,9 @@ class ClassifierModel(TMModel):
             out, new_state = net.apply(
                 params, net_state, self.prep_input(x), train=True, rng=rng
             )
-            loss = self.compute_loss(out, y)
-            err = 1.0 - accuracy(self.primary_logits(out), y)
+            with jax.named_scope("blk_head"):
+                loss = self.compute_loss(out, y)
+                err = 1.0 - accuracy(self.primary_logits(out), y)
             return loss, (new_state, err)
 
         def shard_train(params, net_state, opt_state, ef, x, y, lr, rng):

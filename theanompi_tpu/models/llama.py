@@ -455,65 +455,72 @@ class Llama(TMModel):
         hd = self.head_dim
 
         eps = self.norm_eps
-        xn = rms_norm(x, p["attn_norm"], eps)
-        q = tp_lib.col_parallel(xn, p["wq"])
-        k = tp_lib.col_parallel(xn, p["wk"])
-        if self.qk_norm:
-            q = rms_norm(q, p["q_norm"], eps, self.n_heads * hd)
-            k = rms_norm(k, p["k_norm"], eps, self.n_kv_heads * hd)
-        q, k = _heads(q, h_loc, hd), _heads(k, hkv_loc, hd)
-        v = _heads(tp_lib.col_parallel(xn, p["wv"]), hkv_loc, hd)
-        q = rope(q, pos, self.rope_theta)
-        k = rope(k, pos, self.rope_theta)
-        # GQA: KV stays compact on the wire; repeated only at compute
-        rep = h_loc // hkv_loc
-        if self.sp == 1:
-            # no sequence sharding: skip the ring/all_to_all machinery
-            # and hit the fused kernel (reference math off-TPU) directly
-            if rep != 1:
-                k = jnp.repeat(k, rep, axis=1)
-                v = jnp.repeat(v, rep, axis=1)
-            o = flash_attention(q, k, v, causal=True)
-        else:
-            attn = (
-                ring_attention if self.sp_mode == "ring"
-                else ulysses_attention
-            )
-            o = attn(q, k, v, SEQ_AXIS, causal=True, kv_rep=rep)
-        a = tp_lib.row_parallel(_unheads(o), p["wo"]).astype(cdtype)
-        if self.sandwich_norm:
-            a = rms_norm(a, p["attn_out_norm"], eps)
-        x = x + a
+        # the block names of the step program (``blk_*``, PERF.md §3):
+        # metadata only; ``benchmark/layer_metrics/_blocks.py`` joins
+        # them with a trace's device time
+        with jax.named_scope("blk_attn"):
+            xn = rms_norm(x, p["attn_norm"], eps)
+            q = tp_lib.col_parallel(xn, p["wq"])
+            k = tp_lib.col_parallel(xn, p["wk"])
+            if self.qk_norm:
+                q = rms_norm(q, p["q_norm"], eps, self.n_heads * hd)
+                k = rms_norm(k, p["k_norm"], eps, self.n_kv_heads * hd)
+            q, k = _heads(q, h_loc, hd), _heads(k, hkv_loc, hd)
+            v = _heads(tp_lib.col_parallel(xn, p["wv"]), hkv_loc, hd)
+            q = rope(q, pos, self.rope_theta)
+            k = rope(k, pos, self.rope_theta)
+            # GQA: KV stays compact on the wire; repeated only at compute
+            rep = h_loc // hkv_loc
+            if self.sp == 1:
+                # no sequence sharding: skip the ring/all_to_all
+                # machinery and hit the fused kernel (reference math
+                # off-TPU) directly
+                if rep != 1:
+                    k = jnp.repeat(k, rep, axis=1)
+                    v = jnp.repeat(v, rep, axis=1)
+                o = flash_attention(q, k, v, causal=True)
+            else:
+                attn = (
+                    ring_attention if self.sp_mode == "ring"
+                    else ulysses_attention
+                )
+                o = attn(q, k, v, SEQ_AXIS, causal=True, kv_rep=rep)
+            a = tp_lib.row_parallel(_unheads(o), p["wo"]).astype(cdtype)
+            if self.sandwich_norm:
+                a = rms_norm(a, p["attn_out_norm"], eps)
+            x = x + a
 
-        xn = rms_norm(x, p["mlp_norm"], eps)
-        if self.n_experts:
-            y, aux = moe_ffn(
-                xn, p["router"], p["we_gate"], p["we_up"], p["we_down"],
-                n_experts=self.n_experts,
-                top_k=self.moe_top_k,
-                capacity_factor=self.capacity_factor,
-                expert_axis=EXPERT_AXIS,
-                model_axis=MODEL_AXIS,
-                # aux losses globalize over the token-sharding axes
-                # (layout-invariant; set in compile_iter_fns)
-                batch_axes=(*self._dp_axes, SEQ_AXIS),
-                renormalize=self.moe_renormalize,
+        with jax.named_scope("blk_ffn"):
+            xn = rms_norm(x, p["mlp_norm"], eps)
+            if self.n_experts:
+                y, aux = moe_ffn(
+                    xn, p["router"], p["we_gate"], p["we_up"], p["we_down"],
+                    n_experts=self.n_experts,
+                    top_k=self.moe_top_k,
+                    capacity_factor=self.capacity_factor,
+                    expert_axis=EXPERT_AXIS,
+                    model_axis=MODEL_AXIS,
+                    # aux losses globalize over the token-sharding axes
+                    # (layout-invariant; set in compile_iter_fns)
+                    batch_axes=(*self._dp_axes, SEQ_AXIS),
+                    renormalize=self.moe_renormalize,
+                )
+                mom = jnp.concatenate(
+                    [aux["f"], aux["p"], aux["z"][None],
+                     aux["dropped"][None]]
+                ).astype(jnp.float32)
+                y = y.astype(cdtype)
+                if self.sandwich_norm:
+                    y = rms_norm(y, p["mlp_out_norm"], eps)
+                return x + y, mom
+            h = swiglu(
+                tp_lib.col_parallel(xn, p["w_gate"]),
+                tp_lib.col_parallel(xn, p["w_up"]),
             )
-            mom = jnp.concatenate(
-                [aux["f"], aux["p"], aux["z"][None], aux["dropped"][None]]
-            ).astype(jnp.float32)
-            y = y.astype(cdtype)
+            y = tp_lib.row_parallel(h, p["w_down"]).astype(cdtype)
             if self.sandwich_norm:
                 y = rms_norm(y, p["mlp_out_norm"], eps)
-            return x + y, mom
-        h = swiglu(
-            tp_lib.col_parallel(xn, p["w_gate"]),
-            tp_lib.col_parallel(xn, p["w_up"]),
-        )
-        y = tp_lib.row_parallel(h, p["w_down"]).astype(cdtype)
-        if self.sandwich_norm:
-            y = rms_norm(y, p["mlp_out_norm"], eps)
-        return x + y
+            return x + y
 
     def _forward(self, params, ids, head=True, with_aux=False):
         """ids [B_loc, T_loc] -> local vocab-shard logits [.., V/tp].
@@ -535,8 +542,9 @@ class Llama(TMModel):
         seq_idx = lax.axis_index(SEQ_AXIS)
         pos = seq_idx * t_loc + jnp.arange(t_loc)
 
-        x = tp_lib.embed_lookup(ids, params["embed"], self.vocab)
-        x = x.astype(cdtype)
+        with jax.named_scope("blk_embed"):
+            x = tp_lib.embed_lookup(ids, params["embed"], self.vocab)
+            x = x.astype(cdtype)
         layer = self._layer
         if self.remat:
             # the replay recomputes everything but the flash forward
@@ -670,7 +678,8 @@ class Llama(TMModel):
                 x = self._pp_slice_tokens(last_stage_value(x))
 
         if exits is None:
-            x = rms_norm(x, params["final_norm"], self.norm_eps)
+            with jax.named_scope("blk_head"):
+                x = rms_norm(x, params["final_norm"], self.norm_eps)
         if not head:
             # a looped decoder gives its R exits [R, B, T, D] (the
             # last of them is ``x``): the loss reads them all
@@ -682,7 +691,8 @@ class Llama(TMModel):
         # copy of [N, V] in HBM (profiled at ~1 GB/step on the bench
         # proxy).  Same values either way — the matmul already ran in
         # compute dtype.
-        logits = tp_lib.col_parallel(x, params["lm_head"])
+        with jax.named_scope("blk_head"):
+            logits = tp_lib.col_parallel(x, params["lm_head"])
         return (logits, aux, routing) if with_aux else logits
 
     def _exit_loss(self, params, exits, targets, head=None):
@@ -1028,26 +1038,30 @@ class Llama(TMModel):
                 # [N, D] rows; a looped decoder's R exits [R, N, D]
                 h2 = h.reshape(*h.shape[:-3], -1, h.shape[-1])
                 yf = yv.reshape(-1)
-                if self.ut_steps > 1:
-                    # the dense head would keep R sets of [N, V]
-                    # logits for the backward, or replay each: the
-                    # exits' own head needs neither (``_exit_loss``);
-                    # the streamed head keeps none, an exit at a time
-                    head = None
-                    if n_xent_chunks > 1:
-                        def head(z):
-                            return head_xent(z, yf, p)
+                with jax.named_scope("blk_head"):
+                    if self.ut_steps > 1:
+                        # the dense head would keep R sets of [N, V]
+                        # logits for the backward, or replay each:
+                        # the exits' own head needs neither
+                        # (``_exit_loss``); the streamed head keeps
+                        # none, an exit at a time
+                        head = None
+                        if n_xent_chunks > 1:
+                            def head(z):
+                                return head_xent(z, yf, p)
 
-                    loss, err, exit_counters = self._exit_loss(
-                        p, h2, yf, head
-                    )
-                    counters += (lax.pmean(exit_counters, SEQ_AXIS),)
-                else:
-                    loss_vec, pred = head_xent(h2, yf, p)
-                    loss = jnp.mean(loss_vec)
-                    err = jnp.mean((pred != yf).astype(jnp.float32))
-                loss = lax.pmean(self._pp_value(loss), SEQ_AXIS)
-                err = lax.pmean(self._pp_value(err), SEQ_AXIS)
+                        loss, err, exit_counters = self._exit_loss(
+                            p, h2, yf, head
+                        )
+                        counters += (
+                            lax.pmean(exit_counters, SEQ_AXIS),
+                        )
+                    else:
+                        loss_vec, pred = head_xent(h2, yf, p)
+                        loss = jnp.mean(loss_vec)
+                        err = jnp.mean((pred != yf).astype(jnp.float32))
+                    loss = lax.pmean(self._pp_value(loss), SEQ_AXIS)
+                    err = lax.pmean(self._pp_value(err), SEQ_AXIS)
                 if self.n_experts:
                     # MoE aux losses (layer-averaged in _forward,
                     # already globally token-averaged inside moe_ffn):

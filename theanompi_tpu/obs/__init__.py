@@ -21,6 +21,7 @@ from theanompi_tpu.obs.compile_meter import (  # noqa: F401
 from theanompi_tpu.obs.setup import (  # noqa: F401
     SetupRecord,
     begin_setup,
+    last_process_phases,
     last_setup_phases,
     setup_phase,
 )
@@ -60,6 +61,7 @@ __all__ = [
     "format_profile",
     "gap_attribution",
     "last_moe_counters",
+    "last_process_phases",
     "last_setup_phases",
     "last_ut_counters",
     "make_context",

@@ -31,6 +31,19 @@ root's ``s``, the ``compile_s`` to the set-up's compile seconds.
 The record goes into ``run``'s summary (``"setup_phases"``) and stays
 readable afterwards with :func:`last_setup_phases`.
 
+The seconds BEFORE the worker's entry stand beside the phases, not
+among them (:meth:`SetupRecord.process_phases`, the summary's
+``"process_phases"``, :func:`last_process_phases`): three stamps on
+the same clock —
+
+- ``before_import`` — the process's start (``/proc/self/stat``) to
+  the start of ``import theanompi_tpu``: the interpreter, and what
+  the caller imported and did first (``import jax``, the devices);
+  ``None`` where the start cannot be read;
+- ``import`` — ``import theanompi_tpu`` itself;
+- ``before_worker`` — its end to the worker's entry: the model's
+  module, the rule's ``init``.
+
 A dozen stamps a process: always on.  Each phase is also a
 ``jax.profiler.TraceAnnotation`` (``tm:setup.<name>``), for a
 profiler session that covers the start of a process.
@@ -38,6 +51,8 @@ profiler session that covers the start of a process.
 
 from __future__ import annotations
 
+import functools
+import os
 import time
 from contextlib import contextmanager, nullcontext
 
@@ -45,6 +60,21 @@ from theanompi_tpu.obs.compile_meter import CompileMeter, process_meter
 
 ROOT = "setup"
 _COUNTS = ("compile_s", "programs", "cache_hits", "cache_misses")
+
+
+@functools.cache
+def process_start() -> float | None:
+    """The process's start on ``time.monotonic``'s clock, from its
+    age by ``/proc/self/stat`` (field 22, ticks since boot); ``None``
+    where that cannot be read."""
+    try:
+        with open("/proc/self/stat") as f:
+            after_comm = f.read().rsplit(")", 1)[1].split()
+        started = int(after_comm[19]) / os.sysconf("SC_CLK_TCK")
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - started
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return time.monotonic() - age if age >= 0 else None
 
 
 class SetupRecord:
@@ -111,6 +141,20 @@ class SetupRecord:
         if _CURRENT is self:
             _CURRENT = None
 
+    def process_phases(self) -> dict:
+        """``{before_import, import, before_worker}``: the seconds
+        from the process's start to this record's ``t0`` (see the
+        module docstring); ``before_import`` may be ``None``."""
+        import theanompi_tpu
+
+        started = process_start()
+        t0, t1 = theanompi_tpu._IMPORT_SPAN
+        return {
+            "before_import": None if started is None else t0 - started,
+            "import": t1 - t0,
+            "before_worker": self.t0 - t1,
+        }
+
     def as_dict(self) -> dict:
         """``{phase: {t0, t1, s, self_s, compile_s, programs,
         cache_hits, cache_misses}}``, times in seconds from the
@@ -151,3 +195,10 @@ def last_setup_phases() -> dict | None:
     """The set-up phases of the newest run of this process (the form
     of :meth:`SetupRecord.as_dict`), or None before any."""
     return None if _LAST is None else _LAST.as_dict()
+
+
+def last_process_phases() -> dict | None:
+    """The seconds before the worker's entry of the newest run of
+    this process (:meth:`SetupRecord.process_phases`), or None before
+    any."""
+    return None if _LAST is None else _LAST.process_phases()

@@ -178,25 +178,26 @@ class Conv(Layer):
         return params, {}, (out_h, out_w, self.out_ch)
 
     def apply(self, params, state, x, *, train=False, rng=None):
-        if self.s2d and _s2d_applicable(x.shape, self.kernel[0],
-                                        self.stride[0], self.pad):
-            y = _s2d_conv(x, params["w"].astype(x.dtype),
-                          self.stride[0], self.pad)
-        else:
-            pad = self.pad
-            if isinstance(pad, int):
-                pad = [(pad, pad), (pad, pad)]
-            y = lax.conv_general_dilated(
-                x,
-                params["w"].astype(x.dtype),
-                window_strides=self.stride,
-                padding=pad,
-                dimension_numbers=("NHWC", "HWIO", "NHWC"),
-                feature_group_count=self.groups,
-            )
-        if self.bias:
-            y = y + params["b"].astype(y.dtype)
-        return y, state
+        with jax.named_scope("blk_conv"):
+            if self.s2d and _s2d_applicable(x.shape, self.kernel[0],
+                                            self.stride[0], self.pad):
+                y = _s2d_conv(x, params["w"].astype(x.dtype),
+                              self.stride[0], self.pad)
+            else:
+                pad = self.pad
+                if isinstance(pad, int):
+                    pad = [(pad, pad), (pad, pad)]
+                y = lax.conv_general_dilated(
+                    x,
+                    params["w"].astype(x.dtype),
+                    window_strides=self.stride,
+                    padding=pad,
+                    dimension_numbers=("NHWC", "HWIO", "NHWC"),
+                    feature_group_count=self.groups,
+                )
+            if self.bias:
+                y = y + params["b"].astype(y.dtype)
+            return y, state
 
 
 def _pool_explicit_pad(shape, size, stride, pad):
@@ -400,23 +401,24 @@ class Pool(Layer):
         return {}, {}, (out_h, out_w, c)
 
     def apply(self, params, state, x, *, train=False, rng=None):
-        dims = (1, *self.size, 1)
-        strides = (1, *self.stride, 1)
-        if self.mode == "max":
-            if self.bwd == "tiesplit":
-                return (
-                    maxpool_tiesplit(x, self.size, self.stride, self.pad),
-                    state,
+        with jax.named_scope("blk_pool"):
+            dims = (1, *self.size, 1)
+            strides = (1, *self.stride, 1)
+            if self.mode == "max":
+                if self.bwd == "tiesplit":
+                    return (
+                        maxpool_tiesplit(x, self.size, self.stride, self.pad),
+                        state,
+                    )
+                y = lax.reduce_window(
+                    x, -jnp.inf, lax.max, dims, strides, self.pad
                 )
-            y = lax.reduce_window(
-                x, -jnp.inf, lax.max, dims, strides, self.pad
-            )
-        else:
-            summed = lax.reduce_window(
-                x, 0.0, lax.add, dims, strides, self.pad
-            )
-            y = summed / (self.size[0] * self.size[1])
-        return y, state
+            else:
+                summed = lax.reduce_window(
+                    x, 0.0, lax.add, dims, strides, self.pad
+                )
+                y = summed / (self.size[0] * self.size[1])
+            return y, state
 
 
 class LRN(Layer):
@@ -557,29 +559,33 @@ class BN(Layer):
         return params, state, in_shape
 
     def apply(self, params, state, x, *, train=False, rng=None):
-        axes = self.axis if self.axis is not None else tuple(range(x.ndim - 1))
-        if isinstance(axes, int):  # bare-int axis stays valid (jnp did)
-            axes = (axes,)
-        # normalize negatives: the probe index in _bn_stats matches
-        # positions positionally, and axes are a static jit constant
-        axes = tuple(a % x.ndim for a in axes)
-        if train:
-            # y comes back already in x.dtype (see _bn_train: keeping
-            # the cast inside the vjp keeps the cotangent bf16)
-            y, mean, var = _bn_train(
-                x, params["scale"], params["offset"], axes, self.eps
-            )
-            m = self.momentum
-            state = {
-                "mean": m * state["mean"] + (1 - m) * mean,
-                "var": m * state["var"] + (1 - m) * var,
-            }
-            return y, state
-        xf = x.astype(jnp.float32)
-        mean, var = state["mean"], state["var"]
-        y = (xf - mean) * lax.rsqrt(var + self.eps)
-        y = y * params["scale"] + params["offset"]
-        return y.astype(x.dtype), state
+        # around the custom_vjp call, so that its backward rule's
+        # instructions carry the name too
+        with jax.named_scope("blk_bn"):
+            axes = (self.axis if self.axis is not None
+                    else tuple(range(x.ndim - 1)))
+            if isinstance(axes, int):  # bare-int axis stays valid (jnp did)
+                axes = (axes,)
+            # normalize negatives: the probe index in _bn_stats matches
+            # positions positionally, and axes are a static jit constant
+            axes = tuple(a % x.ndim for a in axes)
+            if train:
+                # y comes back already in x.dtype (see _bn_train: keeping
+                # the cast inside the vjp keeps the cotangent bf16)
+                y, mean, var = _bn_train(
+                    x, params["scale"], params["offset"], axes, self.eps
+                )
+                m = self.momentum
+                state = {
+                    "mean": m * state["mean"] + (1 - m) * mean,
+                    "var": m * state["var"] + (1 - m) * var,
+                }
+                return y, state
+            xf = x.astype(jnp.float32)
+            mean, var = state["mean"], state["var"]
+            y = (xf - mean) * lax.rsqrt(var + self.eps)
+            y = y * params["scale"] + params["offset"]
+            return y.astype(x.dtype), state
 
 
 class FC(Layer):
@@ -607,10 +613,11 @@ class FC(Layer):
         return params, {}, (self.out_dim,)
 
     def apply(self, params, state, x, *, train=False, rng=None):
-        y = x @ params["w"].astype(x.dtype)
-        if self.bias:
-            y = y + params["b"].astype(y.dtype)
-        return y, state
+        with jax.named_scope("blk_head"):
+            y = x @ params["w"].astype(x.dtype)
+            if self.bias:
+                y = y + params["b"].astype(y.dtype)
+            return y, state
 
 
 class Dropout(Layer):
@@ -671,7 +678,8 @@ class GlobalAvgPool(Layer):
         return {}, {}, (in_shape[-1],)
 
     def apply(self, params, state, x, *, train=False, rng=None):
-        return jnp.mean(x, axis=(1, 2)), state
+        with jax.named_scope("blk_pool"):
+            return jnp.mean(x, axis=(1, 2)), state
 
 
 class Flatten(Layer):

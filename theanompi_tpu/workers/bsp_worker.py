@@ -626,6 +626,8 @@ def run(
         "resharded": bool(resharded),
         "trace_spans": trace_spans,
         "setup_phases": setup.as_dict(),
+        # the seconds before this function's entry (obs/setup.py)
+        "process_phases": setup.process_phases(),
         "compiles_after_warmup": late_compiles,
         "n_compiles_after_warmup": n_late_compiles,
         # routing counters of the last fenced MoE step (None if dense)
