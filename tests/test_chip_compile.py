@@ -525,11 +525,13 @@ def _step_blocks(text):
     return have, top, kernels, products
 
 
-def _llama_step_text(chip, monkeypatch, **knobs):
+def _llama_step_text(chip, monkeypatch, n_keep=0, **knobs):
     """The compiled text of a small ``Llama``'s real train step
     (``compile_iter_fns``: ``value_and_grad`` of ``loss_fn``, then
     ``ExchangePlan.apply``) for the v5e; the parameters are shapes, so
-    nothing is placed."""
+    nothing is placed.  ``n_keep`` stands in for the device's memory
+    (a described device reports none: 0 calls keep the MLP's
+    products)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from theanompi_tpu.models.llama import Llama
@@ -540,6 +542,7 @@ def _llama_step_text(chip, monkeypatch, **knobs):
     mesh = make_mesh(data=1, devices=list(chip.device_set))
     rep = NamedSharding(mesh, P())
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)  # as on the chip
+    monkeypatch.setattr(Llama, "remat_keep_calls", lambda self, limit: n_keep)
     model = Llama(dict(dict(
         dim=256, n_layers=2, n_heads=2, n_kv_heads=2, ffn_dim=512,
         vocab=4096, seq_len=t, batch_size=b, compute_dtype="bfloat16",
@@ -611,6 +614,23 @@ def test_llama_step_names_its_blocks_in_every_phase(
     named = [e["op_name"] for e in top.values() if e["op_name"]]
     assert all(re.fullmatch(_GLUE, n) for n in named), named
     assert len(top) <= most_unnamed, sorted(top)
+
+
+@pytest.mark.parametrize("n_keep", [0, 1, 2])
+def test_kept_calls_replay_no_gate_or_up_product(chip, monkeypatch, n_keep):
+    """The same step with the last ``n_keep`` of its 2 layer calls
+    keeping ``MLP_RESIDUALS``: the compiled text holds a gate and an
+    up product (a ``[.., 512]`` result under ``blk_ffn``) in the
+    replay of the calls that keep neither, and in no other; the three
+    flash kernels a layer stay."""
+    text = _llama_step_text(chip, monkeypatch, n_keep=n_keep)
+    replayed = [
+        ln for ln in text.splitlines()
+        if " convolution(" in ln and "rematted_computation" in ln
+        and "blk_ffn" in ln and ",512]" in ln.split(" convolution(")[0]
+    ]
+    assert len(replayed) == 2 * (2 - n_keep), replayed
+    assert _flash_kernels(text) == dict(fwd=2, dkv=2, dq=2)
 
 
 def test_classifier_step_names_conv_and_batch_norm(chip, monkeypatch):

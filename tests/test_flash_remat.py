@@ -10,7 +10,17 @@ jaxpr (``pallas_call`` equations; the kernels themselves run in the
 Pallas interpreter), for a bare layer function and for the model's
 own train step; ``tests/test_chip_compile.py`` counts the same in a
 text compiled for the chip.
+
+
+The same remat keeps, for the LAST ``remat_kept_calls`` layer calls,
+the dense MLP's gate and up products (``ops.layers.MLP_RESIDUALS``):
+as many calls as ``Llama.remat_keep_calls`` finds room for between
+its estimate of the step's peak and the device's memory.  The second
+half of this file counts the replayed products, holds all-kept
+against none-kept, and pins the rule and the estimate.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,25 +28,30 @@ import numpy as np
 import pytest
 from jax.ad_checkpoint import checkpoint_name
 
+from theanompi_tpu.models import llama
 from theanompi_tpu.models.llama import Llama
 from theanompi_tpu.ops import attention
 from theanompi_tpu.ops.attention import FLASH_RESIDUALS, flash_attention_tpu
+from theanompi_tpu.ops.layers import MLP_RESIDUALS
 from theanompi_tpu.parallel import make_mesh
 
 B, H, T, D = 2, 2, 32, 16
 
 
-def _count(jaxpr, primitive="pallas_call"):
-    """Equations of ``primitive`` in a jaxpr, sub-jaxprs opened."""
-    n = 0
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, sub-jaxprs opened."""
     for eqn in jaxpr.eqns:
-        n += eqn.primitive.name == primitive
+        yield eqn
         for value in eqn.params.values():
             for sub in value if isinstance(value, (list, tuple)) else [value]:
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    n += _count(sub, primitive)
-    return n
+                    yield from _eqns(sub)
+
+
+def _count(jaxpr, primitive="pallas_call"):
+    """Equations of ``primitive`` in a jaxpr, sub-jaxprs opened."""
+    return sum(eqn.primitive.name == primitive for eqn in _eqns(jaxpr))
 
 
 def _layer(causal, t_k, name_after_call=None):
@@ -181,3 +196,206 @@ def test_worker_summary_of_a_model_without_layer_remat():
         verbose=False,
     )
     assert res["remat_saves"] == []
+
+
+# -- the MLP's two products, kept for the calls the memory holds -------------
+
+GIB = 1 << 30
+FFN = TINY["ffn_dim"]
+
+
+def _replayed_mlp_products(jaxpr):
+    """Products with an ``[.., ffn_dim]`` result in the remat's replay
+    (``rematted_computation`` in the equation's name stack): the gate
+    and the up projection of a call that keeps neither."""
+    return sum(
+        eqn.primitive.name == "dot_general"
+        and eqn.outvars[0].aval.shape[-1] == FFN
+        and "rematted_computation" in str(eqn.source_info.name_stack)
+        for eqn in _eqns(jaxpr)
+    )
+
+
+def _step_model(n_keep, tp=1, **over):
+    """A TINY model with its step built and ``n_keep`` forced (the CPU
+    reports no memory limit: ``compile_iter_fns`` leaves 0)."""
+    model = Llama(dict(TINY, n_layers=2, optimizer="sgd", lr=1.0, tp=tp,
+                       n_kv_heads=tp, **over))
+    model.build_model(n_replicas=1)
+    model.compile_iter_fns(
+        mesh=make_mesh(data=1, model=tp, devices=jax.devices()[:tp]))
+    assert model.remat_kept_calls == 0
+    model.remat_kept_calls = (
+        model.remat_calls if n_keep == "all" else n_keep)
+    return model
+
+
+def _step_args(model):
+    x, y = model.put_batch(model.data.train_batch(0))
+    return (model.params, model.opt_state, model.ef_state, x, y,
+            jnp.float32(model.current_lr))
+
+
+DECODERS = pytest.mark.parametrize(
+    "over", [{}, {"ut_steps": 4, "exit_beta": 0.1}], ids=["plain", "looped"])
+
+
+@DECODERS
+@pytest.mark.parametrize("n_keep", [0, 1, "all"])
+def test_kept_calls_replay_no_gate_or_up_product(n_keep, over):
+    model = _step_model(n_keep, **over)
+    jaxpr = jax.make_jaxpr(model.train_step_fn)(*_step_args(model))
+    replayed = model.remat_calls - model.remat_kept_calls
+    assert model.remat_calls == 2 * over.get("ut_steps", 1)
+    assert _replayed_mlp_products(jaxpr.jaxpr) == 2 * replayed
+
+
+@pytest.mark.parametrize(
+    "over", [{}, {"n_experts": 4, "moe_top_k": 2, "capacity_factor": None}],
+    ids=["dense", "moe"])
+def test_names_alone_leave_the_lowered_step_as_it_was(monkeypatch, over):
+    """No call kept: ONE policy, the parent's; ``checkpoint_name``
+    lowers to nothing, so the step's text is the text without the two
+    names.  An expert layer has none to begin with."""
+    def text():
+        model = _step_model(0, **over)
+        text = model._train_step.lower(*_step_args(model)).as_text()
+        # (the lowering numbers its private functions as it meets them)
+        return re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
+
+    named = text()
+    monkeypatch.setattr(llama, "checkpoint_name", lambda x, name: x)
+    assert named == text()
+
+
+@DECODERS
+@pytest.mark.parametrize("tp", [1, 2])
+def test_all_calls_kept_is_bitwise_none_kept(tp, over):
+    """Loss and gradients (plain SGD at lr 1: the step's parameter
+    change) with every call's products kept against every call's
+    replayed."""
+    def step(n_keep):
+        model = _step_model(n_keep, tp=tp, **over)
+        before = jax.tree.map(np.asarray, model.params)
+        params, _, _, loss, *_ = model.train_step_fn(*_step_args(model))
+        grads = jax.tree.map(lambda a, b: a - np.asarray(b), before, params)
+        return float(loss), grads
+
+    (loss_kept, kept), (loss_none, none) = step("all"), step(0)
+    assert loss_kept == loss_none
+    for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(none)):
+        assert np.array_equal(a, b)
+    assert float(np.abs(kept["layers"][0]["w_gate"]).max()) > 0
+
+
+def _cell_model(cell):
+    """The ``Llama`` of a benchmark cell, from its configuration's
+    program block (nothing is placed: parameters materialise in
+    ``compile_iter_fns``)."""
+    from benchmark.drivers.train import program_config
+    from benchmark.run import load_cell
+
+    return Llama(program_config(load_cell(cell)["config"], seed=0,
+                                n_replicas=1))
+
+
+# ``peak_hbm_gib`` of the three transformer cells (ledger, PR 35)
+LEDGER_PEAKS = {"mistral7b_train_t4096": 11.209,
+                "olmoe_train_t4096": 12.668,
+                "ouro_train_t4096": 12.97}
+
+
+@pytest.mark.parametrize("cell", sorted(LEDGER_PEAKS))
+def test_estimate_reads_the_cells_peaks(cell):
+    estimate = _cell_model(cell).step_peak_estimate() / GIB
+    assert abs(estimate - LEDGER_PEAKS[cell]) < 0.6, estimate
+
+
+@pytest.mark.parametrize("cell, limit_gib, lo, hi", [
+    ("mistral7b_train_t4096", 15.75, 2, 2),     # ample: all calls
+    ("mistral7b_train_t4096", 12.0, 0, 0),      # below the estimate
+    ("mistral7b_train_t4096", None, 0, 0),      # no device limit
+    ("ouro_train_t4096", 15.75, 6, 14),
+    ("ouro_train_t4096", 64.0, 32, 32),
+    ("olmoe_train_t4096", 64.0, 0, 0),          # an expert layer
+], ids=str)
+def test_keep_rule_from_shapes_and_the_limit(cell, limit_gib, lo, hi):
+    model = _cell_model(cell)
+    limit = None if limit_gib is None else int(limit_gib * GIB)
+    n_keep = model.remat_keep_calls(limit)
+    assert lo <= n_keep <= hi, n_keep
+    if limit and 0 < n_keep < model.remat_calls:
+        # the next call's copies would not have fitted
+        room = (limit - llama.REMAT_RESERVE_BYTES
+                - model.step_peak_estimate())
+        assert (n_keep * model.remat_kept_bytes_per_call <= room
+                < (n_keep + 1) * model.remat_kept_bytes_per_call)
+
+
+def test_expert_layer_names_no_product():
+    model = _cell_model("olmoe_train_t4096")
+    assert model.remat_kept_bytes_per_call == 0
+    assert not set(MLP_RESIDUALS) & set(model.remat_saves)
+
+
+@pytest.mark.parametrize("over, keeps", [
+    ({}, 2), ({"remat": False}, 0), ({"pp": 2}, 0),
+    ({"n_experts": 4, "moe_top_k": 2}, 0),
+], ids=["remat", "no_remat", "pipeline", "moe"])
+def test_keep_rule_bypasses(over, keeps):
+    model = Llama(dict(TINY, n_layers=2, **over))
+    assert model.remat_keep_calls(64 * GIB) == keeps
+
+
+def test_compile_reads_the_devices_limit(monkeypatch):
+    """What ``compile_iter_fns`` sets is the rule at the least limit
+    the mesh's devices report."""
+    seen = []
+
+    def limit(devices):
+        seen.extend(devices)
+        return 64 * GIB
+
+    monkeypatch.setattr(llama, "_device_bytes_limit", limit)
+    model = Llama(dict(TINY, n_layers=2))
+    model.build_model(n_replicas=1)
+    model.compile_iter_fns(mesh=make_mesh(data=1, devices=jax.devices()[:1]))
+    assert (model.remat_kept_calls, seen) == (2, jax.devices()[:1])
+
+
+class _Device:
+    def __init__(self, stats):
+        self._stats = stats
+
+    def memory_stats(self):
+        if isinstance(self._stats, Exception):
+            raise self._stats
+        return self._stats
+
+
+@pytest.mark.parametrize("stats, limit", [
+    ([{"bytes_limit": 7}, {"bytes_limit": 5}], 5),
+    ([{"bytes_limit": 7}, None], None),         # the CPU reports none
+    ([{"bytes_limit": 7}, {}], None),
+    ([jax.errors.JaxRuntimeError("described device")], None),
+], ids=["least", "cpu", "no_key", "described"])
+def test_device_bytes_limit(stats, limit):
+    assert llama._device_bytes_limit(map(_Device, stats)) == limit
+
+
+@pytest.mark.parametrize("limit_gib, kept", [(None, 0), (64, 1)])
+def test_worker_summary_counts_the_kept_calls(monkeypatch, limit_gib, kept):
+    from theanompi_tpu.workers import bsp_worker
+
+    if limit_gib:
+        monkeypatch.setattr(llama, "_device_bytes_limit",
+                            lambda devices: limit_gib * GIB)
+    res = bsp_worker.run(
+        devices=[0], modelfile="theanompi_tpu.models.llama",
+        modelclass="Llama",
+        config=dict(TINY, n_layers=1, n_epochs=1, seed=3), verbose=False,
+    )
+    per_call = 2 * TINY["batch_size"] * T * FFN * 4
+    assert (res["remat_calls"], res["remat_kept_calls"],
+            res["remat_kept_bytes"]) == (1, kept, kept * per_call)
+    assert res["remat_saves"] == list(FLASH_RESIDUALS)

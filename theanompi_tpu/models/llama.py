@@ -41,7 +41,9 @@ it makes autodiff insert the exactly-right collective transposes
 correct for any mesh layout with no manual grad reduction (verified by
 the layout-invariance tests).  Per-layer ``jax.checkpoint`` (remat)
 bounds activation memory for long sequences; it keeps the layer's
-input and the flash kernel's two outputs.  Params are initialized
+input and the flash kernel's two outputs and, for as many of the last
+layer calls as the device's memory holds, the dense MLP's gate and up
+products (``Llama.remat_keep_calls``).  Params are initialized
 *under jit with sharded out_shardings*, so the full 8B-scale parameter
 set never materializes on one device.
 
@@ -61,6 +63,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from theanompi_tpu.models.base import TMModel
@@ -72,7 +75,7 @@ from theanompi_tpu.ops.attention import (
     flash_tiles_summary,
 )
 from theanompi_tpu.ops.grouped_matmul import TILE_PLAN_RESIDUAL
-from theanompi_tpu.ops.layers import swiglu
+from theanompi_tpu.ops.layers import MLP_RESIDUALS, swiglu
 from theanompi_tpu.ops import optimizers as opt_lib
 from theanompi_tpu.parallel import (
     DATA_AXIS,
@@ -95,6 +98,27 @@ from theanompi_tpu.parallel import tp as tp_lib
 from theanompi_tpu.utils import Recorder
 
 PyTree = Any
+
+# device memory ``Llama.remat_keep_calls`` leaves free beside its
+# estimate of the step's peak: the estimate counts no executable (0.1
+# to 1.0 GB in the benchmark's cells), no staged data, no copy of a
+# weight in compute dtype and no gap between the allocator's buffers
+# (PERF.md, PR 36, has the chip-less compiles it was fixed from)
+REMAT_RESERVE_BYTES = 1 << 30
+
+
+def _device_bytes_limit(devices) -> int | None:
+    """The least ``bytes_limit`` the devices' runtimes report; None
+    where one reports none (the CPU; a described device, which has no
+    runtime to ask)."""
+    limits = []
+    for d in devices:
+        try:
+            stats = d.memory_stats() or {}
+        except jax.errors.JaxRuntimeError:
+            return None
+        limits.append(stats.get("bytes_limit"))
+    return min(limits) if limits and all(limits) else None
 
 
 # -- pure model math (runs on LOCAL shards inside shard_map) ----------------
@@ -237,6 +261,10 @@ class Llama(TMModel):
         if self.n_experts and self.capacity_factor is None:
             saves += (TILE_PLAN_RESIDUAL,)
         self.remat_saves = saves if self.remat else ()
+        # how many of the LAST layer calls also keep ``MLP_RESIDUALS``
+        # ("remat_kept_calls" of the summary): ``compile_iter_fns``
+        # sets it from the shapes and the device's memory
+        self.remat_kept_calls = 0
         self.compute_dtype = jnp.dtype(c.get("compute_dtype", "bfloat16"))
         self.seed = int(c.get("seed", 42))
         self.n_epochs = int(c.get("n_epochs", 5))
@@ -440,6 +468,105 @@ class Llama(TMModel):
             t, t, self.head_dim, self.compute_dtype, causal=True
         )
 
+    # -- what the per-layer remat keeps -----------------------------------
+
+    @property
+    def remat_calls(self) -> int:
+        """Layer calls a step runs under ``jax.checkpoint``: a looped
+        decoder's are (pass, layer)."""
+        return self.n_layers * self.ut_steps if self.remat else 0
+
+    @property
+    def remat_kept_bytes_per_call(self) -> int:
+        """Bytes of ``MLP_RESIDUALS`` one layer call keeps on a device:
+        two ``[B_loc, T_loc, ffn_dim / tp]`` in compute dtype; 0 for an
+        expert layer, which names neither."""
+        if self.n_experts:
+            return 0
+        n_tok = int(self.config.get("batch_size", 8)) * (
+            self.seq_len // self.sp
+        )
+        return (
+            2 * n_tok * (self.ffn_dim // self.tp)
+            * self.compute_dtype.itemsize
+        )
+
+    @property
+    def remat_kept_bytes(self) -> int:
+        """Bytes of ``MLP_RESIDUALS`` the kept calls hold on a device."""
+        return self.remat_kept_calls * self.remat_kept_bytes_per_call
+
+    def _local_params(self, axis_sizes) -> tuple[int, int]:
+        """(elements, bytes) of the parameters ONE device holds under
+        ``param_specs`` on a mesh of ``axis_sizes``.  Shape-only eval,
+        no compute."""
+        shapes = jax.eval_shape(
+            self._init_full_params, jax.random.PRNGKey(0)
+        )
+        specs = jax.tree.leaves(
+            self.param_specs(), is_leaf=lambda s: isinstance(s, P)
+        )
+        elems = nbytes = 0
+        for leaf, spec in zip(jax.tree.leaves(shapes), specs):
+            dims = list(leaf.shape)
+            for i, ax in enumerate(tuple(spec)):
+                if ax is None:
+                    continue
+                for a in ax if isinstance(ax, (tuple, list)) else (ax,):
+                    dims[i] //= axis_sizes[a]
+            elems += math.prod(dims)
+            nbytes += math.prod(dims) * leaf.dtype.itemsize
+        return elems, nbytes
+
+    def step_peak_estimate(self) -> int:
+        """Bytes one device holds at the train step's peak when no
+        call keeps ``MLP_RESIDUALS``, from shapes alone: every local
+        parameter's master, gradient and optimizer state; what each
+        layer call keeps for its replay (its input, the flash kernel's
+        output and logsumexp); the head's live set — one set of local
+        logits and their gradient in compute dtype
+        (``tp.dense_unembed_xent``, or one loop body of
+        ``tp.exits_unembed_xent`` with the exits' stack and its
+        gradient beside it; a streamed head holds a chunk of each).
+        The benchmark's three transformer cells read within 0.45 GiB
+        of it on the chip (``tests/test_flash_remat.py``)."""
+        isz = self.compute_dtype.itemsize
+        batch = int(self.config.get("batch_size", 8))
+        t_loc = self.seq_len // self.sp
+        n_tok = batch * t_loc
+        _, param_bytes = self._local_params(
+            {MODEL_AXIS: self.tp, PIPE_AXIS: self.pp, EXPERT_AXIS: self.ep}
+        )
+        opt_copies = {"adam": 2, "sgd": 0}.get(self.opt_name, 1)
+        h_loc = self.n_heads // self.tp
+        per_call = (
+            n_tok * self.dim * isz
+            + n_tok * h_loc * self.head_dim * isz
+            + batch * h_loc * t_loc * 4
+        )
+        head = 2 * n_tok * (
+            self.vocab // self.tp // self._xent_chunks()
+        ) * isz
+        if self.ut_steps > 1:
+            head += 2 * self.ut_steps * n_tok * self.dim * isz
+        return (
+            param_bytes * (2 + opt_copies)
+            + self.n_layers * self.ut_steps // self.pp * per_call
+            + head
+        )
+
+    def remat_keep_calls(self, bytes_limit: int | None) -> int:
+        """How many of the last layer calls keep ``MLP_RESIDUALS``: as
+        many as fit between the step's estimated peak and the device's
+        ``bytes_limit`` less ``REMAT_RESERVE_BYTES``.  0 without a
+        limit (the CPU), without remat, for an expert layer and on the
+        pipeline path, whose stage function keeps the plain policy."""
+        per_call = self.remat_kept_bytes_per_call
+        if not (self.remat and bytes_limit and per_call) or self.pp > 1:
+            return 0
+        free = bytes_limit - REMAT_RESERVE_BYTES - self.step_peak_estimate()
+        return int(min(max(free // per_call, 0), self.remat_calls))
+
     def _layer(self, p, x, pos):
         """One decoder block on local shards: x [B, T_loc, D].
 
@@ -513,10 +640,15 @@ class Llama(TMModel):
                 if self.sandwich_norm:
                     y = rms_norm(y, p["mlp_out_norm"], eps)
                 return x + y, mom
-            h = swiglu(
-                tp_lib.col_parallel(xn, p["w_gate"]),
-                tp_lib.col_parallel(xn, p["w_up"]),
+            # named for the layer's remat (``_forward``), which keeps
+            # the two products of the layer calls the memory holds
+            g = checkpoint_name(
+                tp_lib.col_parallel(xn, p["w_gate"]), MLP_RESIDUALS[0]
             )
+            u = checkpoint_name(
+                tp_lib.col_parallel(xn, p["w_up"]), MLP_RESIDUALS[1]
+            )
+            h = swiglu(g, u)
             y = tp_lib.row_parallel(h, p["w_down"]).astype(cdtype)
             if self.sandwich_norm:
                 y = rms_norm(y, p["mlp_out_norm"], eps)
@@ -545,37 +677,50 @@ class Llama(TMModel):
         with jax.named_scope("blk_embed"):
             x = tp_lib.embed_lookup(ids, params["embed"], self.vocab)
             x = x.astype(cdtype)
-        layer = self._layer
+        layer = kept_layer = self._layer
         if self.remat:
-            # the replay recomputes everything but the flash forward
-            # kernel: its output and logsumexp (named in its forward
-            # rule, ``ops/attention.py``) are kept, [B, H_loc, T, hd]
-            # and 4 bytes a row, so the backward runs dK/dV and dQ
-            # only.  Where the kernel does not run (dense path;
+            # every call's replay skips the flash forward kernel: its
+            # output and logsumexp (named in its forward rule,
+            # ``ops/attention.py``) are kept, [B, H_loc, T, hd] and 4
+            # bytes a row, so the backward runs dK/dV and dQ only.
+            # Where the kernel does not run (dense path;
             # ``ring_attention``, whose own vjp calls the kernels
-            # unnamed) the names never occur: full remat.  A dropless
-            # expert layer's tile plan (``parallel/moe.py``) is kept
-            # the same way: built once a layer call.
-            layer = jax.checkpoint(
-                self._layer,
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    *self.remat_saves
-                ),
-            )
+            # unnamed) the names never occur.  A dropless expert
+            # layer's tile plan (``parallel/moe.py``) is kept the same
+            # way: built once a layer call.  The LAST
+            # ``remat_kept_calls`` calls (the first the backward
+            # reaches, so their copies live shortest) also keep the
+            # dense MLP's gate and up products; the norms, the
+            # projections around the kernel, ``swiglu`` and the down
+            # projection are replayed in every call.
+            def remat(*names):
+                return jax.checkpoint(
+                    self._layer,
+                    policy=jax.checkpoint_policies.save_only_these_names(
+                        *names
+                    ),
+                )
+
+            layer = kept_layer = remat(*self.remat_saves)
+            if self.remat_kept_calls:
+                kept_layer = remat(*self.remat_saves, *MLP_RESIDUALS)
 
         moe = bool(self.n_experts)
         aux = jnp.zeros((2,), jnp.float32)
         routing = None
         exits = None
         if self.pp == 1:
-            def stack(x):
+            first_kept = self.remat_calls - self.remat_kept_calls
+
+            def stack(x, first_call=0):
                 moms = []
-                for p in params["layers"]:
+                for call, p in enumerate(params["layers"], first_call):
+                    fn = layer if call < first_kept else kept_layer
                     if moe:
-                        x, mom = layer(p, x, pos)
+                        x, mom = fn(p, x, pos)
                         moms.append(mom)
                     else:
-                        x = layer(p, x, pos)
+                        x = fn(p, x, pos)
                 return x, (jnp.stack(moms) if moe else None)
 
             if self.ut_steps == 1:
@@ -590,8 +735,8 @@ class Llama(TMModel):
                 # the cell's sizes (PERF.md, PR 33).
                 exits, moms = [], []
                 with jax.named_scope("ut_stack"):
-                    for _ in range(self.ut_steps):
-                        x, mom = stack(x)
+                    for step in range(self.ut_steps):
+                        x, mom = stack(x, step * self.n_layers)
                         x = rms_norm(x, params["final_norm"], self.norm_eps)
                         exits.append(x)
                         moms.append(mom)
@@ -838,6 +983,23 @@ class Llama(TMModel):
         self.params = None
         self.opt_state = None
 
+    def _xent_chunks(self) -> int:
+        """Vocab chunks of the training head (1: the dense head), from
+        the ``xent_chunks`` key and the local vocab."""
+        xc = self.config.get("xent_chunks", "auto")
+        v_loc = self.vocab // self.tp
+        if xc == "auto":
+            return tp_lib.pick_xent_chunks(v_loc) if v_loc >= 65536 else 1
+        n_xent_chunks = max(1, int(xc or 1))
+        if v_loc % n_xent_chunks:
+            raise ValueError(
+                f"xent_chunks={n_xent_chunks} must divide the "
+                f"local vocab {v_loc} (vocab {self.vocab} / tp "
+                f"{self.tp}) — a ragged chunking would silently "
+                f"drop the tail vocab columns from the loss"
+            )
+        return n_xent_chunks
+
     def compile_iter_fns(
         self,
         mesh: Mesh | None = None,
@@ -899,22 +1061,6 @@ class Llama(TMModel):
         else:  # momentum / nesterov velocity
             opt_specs = specs
 
-        # LOCAL (per-device) parameter-pack size: what the exchange
-        # packs.  Shape-only eval, no compute.
-        shapes = jax.eval_shape(
-            self._init_full_params, jax.random.PRNGKey(0)
-        )
-
-        def _local_elems(leaf, spec):
-            dims = list(leaf.shape)
-            for i, ax in enumerate(tuple(spec)):
-                if ax is None:
-                    continue
-                for a in (ax if isinstance(ax, (tuple, list))
-                          else (ax,)):
-                    dims[i] //= mesh.shape[a]
-            return math.prod(dims)
-
         # The DP gradient exchange (wire dtype x collective shape x
         # compression, ``parallel.ExchangePlan``) reduces over the DP
         # axes only; TP/SP collectives are part of the model math.
@@ -925,15 +1071,9 @@ class Llama(TMModel):
         # DIFFERENT axis sets, so that exchange is per leaf.
         plan = self.exchange = plan.bind(
             mesh.shape,
-            n_elems=sum(
-                _local_elems(l, s)
-                for l, s in zip(
-                    jax.tree.leaves(shapes),
-                    jax.tree.leaves(
-                        specs, is_leaf=lambda s: isinstance(s, P)
-                    ),
-                )
-            ),
+            # LOCAL (per-device) parameter-pack size: what the
+            # exchange packs
+            n_elems=self._local_params(mesh.shape)[0],
             replica_axes=dp_axes,
             flat_axes=tuple(
                 a for a in (PIPE_AXIS, EXPERT_AXIS, DATA_AXIS, MODEL_AXIS)
@@ -956,22 +1096,10 @@ class Llama(TMModel):
         # costs one extra head matmul).  "auto" therefore chunks only
         # when the LOCAL vocab is >= 64k; an int pins the chunk
         # count; 0/1 forces the dense head.
-        xc = self.config.get("xent_chunks", "auto")
-        v_loc = self.vocab // self.tp
-        if xc == "auto":
-            n_xent_chunks = (
-                tp_lib.pick_xent_chunks(v_loc) if v_loc >= 65536 else 1
-            )
-        else:
-            n_xent_chunks = max(1, int(xc or 1))
-            if v_loc % n_xent_chunks:
-                raise ValueError(
-                    f"xent_chunks={n_xent_chunks} must divide the "
-                    f"local vocab {v_loc} (vocab {self.vocab} / tp "
-                    f"{self.tp}) — a ragged chunking would silently "
-                    f"drop the tail vocab columns from the loss"
-                )
-        self._n_xent_chunks = n_xent_chunks
+        n_xent_chunks = self._n_xent_chunks = self._xent_chunks()
+        self.remat_kept_calls = self.remat_keep_calls(
+            _device_bytes_limit(mesh.devices.flat)
+        )
 
         # expert-sharded leaves exchange differently (see step below);
         # identified once from the specs

@@ -724,6 +724,12 @@ class Sequential(Layer):
 # Gated-MLP activation (the dense decoder block of ``models/llama.py``)
 # ---------------------------------------------------------------------------
 
+# ``jax.ad_checkpoint.checkpoint_name``s of ``swiglu``'s two operands,
+# the dense MLP's gate and up projection outputs (``Llama._layer``): a
+# ``jax.checkpoint`` whose policy saves them replays neither product
+MLP_RESIDUALS = ("mlp_gate", "mlp_up")
+
+
 def _silu_mul(g, u):
     return jax.nn.silu(g) * u
 

@@ -596,6 +596,12 @@ def run(
         # checkpoint names the model's per-layer remat keeps ([] when
         # it keeps everything, or the model has no such remat)
         "remat_saves": list(getattr(model, "remat_saves", ())),
+        # of its "remat_calls" layer calls a step, the last
+        # "remat_kept_calls" also keep the dense MLP's gate and up
+        # products, "remat_kept_bytes" on a device in all
+        "remat_calls": getattr(model, "remat_calls", 0),
+        "remat_kept_calls": getattr(model, "remat_kept_calls", 0),
+        "remat_kept_bytes": getattr(model, "remat_kept_bytes", 0),
         # the flash kernels' tiles for the model's attention shape
         # ({} where no such kernel runs)
         "flash_tiles": getattr(model, "flash_tiles", dict)(),
