@@ -525,8 +525,10 @@ def _step_blocks(text):
     return have, top, kernels, products
 
 
-def _llama_step_text(chip, monkeypatch, n_keep=0, **knobs):
-    """The compiled text of a small ``Llama``'s real train step
+def _llama_step_text(chip, monkeypatch, n_keep=0, lowered=False, **knobs):
+    """The compiled text (``lowered``: the lowered text, which names
+    no source line outside the kernels' bodies) of a small ``Llama``'s
+    real train step
     (``compile_iter_fns``: ``value_and_grad`` of ``loss_fn``, then
     ``ExchangePlan.apply``) for the v5e; the parameters are shapes, so
     nothing is placed.  ``n_keep`` stands in for the device's memory
@@ -559,13 +561,17 @@ def _llama_step_text(chip, monkeypatch, n_keep=0, **knobs):
         jax.eval_shape(model._init_full_params, jax.random.key(0)))
     model.opt_state = shapes(
         jax.eval_shape(model.optimizer.init, model.params))
+    if model.moe_select_bias:
+        model.net_state = shapes(jax.eval_shape(model._init_net_state))
     model.compile_iter_fns(mesh=mesh)
     ids = jax.ShapeDtypeStruct(
         (b, t), jnp.int32, sharding=NamedSharding(mesh, P("data", "seq")))
     lr = jax.ShapeDtypeStruct((), jnp.float32, sharding=rep)
-    return model._train_step.lower(
+    step = model._train_step.lower(
         model.params, model.opt_state, model.ef_state, ids, ids, lr,
-    ).compile().as_text()
+        *model._state_args(),
+    )
+    return step.as_text() if lowered else step.compile().as_text()
 
 
 # an ``op_name`` outside every layer and every block: the step's own
@@ -614,6 +620,120 @@ def test_llama_step_names_its_blocks_in_every_phase(
     named = [e["op_name"] for e in top.values() if e["op_name"]]
     assert all(re.fullmatch(_GLUE, n) for n in named), named
     assert len(top) <= most_unnamed, sorted(top)
+
+
+# a decoder with every mechanism of the ``glm4_moe_lite`` cell at small
+# widths: latent attention whose kernels see a head dim of 256, a
+# leading dense layer, expert layers with 2 of 8 experts held under a
+# sigmoid router with a selection bias, a shared expert, an MTP module
+_MLA_MOE = dict(
+    n_layers=2, attention="mla", q_lora_rank=128, kv_lora_rank=128,
+    qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+    n_experts=8, moe_top_k=2, ffn_dim=256, capacity_factor=None,
+    moe_scoring="sigmoid", moe_route_scale=1.8, moe_bias_rate=0.001,
+    moe_experts_held=2, moe_shared_experts=1, first_k_dense=1,
+    dense_ffn_dim=512, mtp_depth=1, moe_aux_coef=0.0,
+)
+
+
+def test_held_share_step_compiles_with_its_kernels_and_scopes(
+    chip, monkeypatch
+):
+    """The cell's kind of step (``_MLA_MOE``: a dense call, an expert
+    call and the MTP module's expert call) compiled for the v5e: three
+    flash kernels a layer CALL at head dim 256 (the remat keeps the
+    kernel's outputs in the MTP block too); the grouped kernels of the
+    two expert calls over the WHOLE ``k * N`` buffer's static grid —
+    the held range shortens the visits at run time, not the grid —
+    against leaves of the 2 experts held; one tile plan a call, none
+    in a replay; the new scopes in every phase they have; every block
+    named; and the step gives the selection bias back."""
+    import re
+
+    from benchmark.layer_metrics import _scopes
+
+    text = _llama_step_text(chip, monkeypatch, **_MLA_MOE)
+    assert _flash_kernels(text) == dict(fwd=3, dkv=3, dq=3)
+    flash = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "_flash_jit" in ln]
+    assert all(re.search(r"bf16\[\d+,256,256\]", ln) for ln in flash), flash
+    products = [ln for ln in text.splitlines()
+                if "tpu_custom_call" in ln and "ragged-dot" in ln]
+    # 2 expert calls x (3 forward + 2 replayed + 3 dlhs + 3 drhs)
+    assert len(products) == 22
+    rows = 2 * 2 * 256          # k * N: the static buffer
+    assert all(re.search(rf"\[({rows},256|2,256,256)\]", p)
+               for p in products), products[:2]
+    assert not re.search(r"\[8,256,256\]", text)    # no leaf of all 8
+    plan = [ln for ln in text.splitlines() if "moe_tile_plan" in ln]
+    assert plan and not any("rematted_computation" in ln for ln in plan)
+    have, top, kernels, products = _step_blocks(text)
+    for block in ("blk_attn", "blk_ffn"):
+        assert {(block, ph) for ph in ("fwd", "replay", "bwd")} <= have
+    assert {("blk_mtp_in", "fwd"), ("blk_mtp_in", "bwd")} <= have
+    assert "other" not in {e["block"] for e in products.values()}
+    for scope, phases in (("mla_proj", 3), ("moe_shared", 3), ("mtp", 3)):
+        names = _scopes._under(text, scope)
+        lines = [ln for ln in text.splitlines()
+                 if (m := re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", ln))
+                 and m.group(1) in names]
+        seen = {"replay" if "rematted_computation" in ln
+                else "bwd" if "transpose(" in ln else "fwd" for ln in lines}
+        assert len(seen) == phases, (scope, seen)
+    # the MTP module's kernels lie under its scope
+    assert sum("jvp(mtp)" in ln for ln in flash) == 3
+
+
+# three small decoders that take the paths of the Mistral, OLMoE and
+# Ouro cells: knobs, the layer calls that keep their MLP products
+_OLDER_DECODERS = {
+    "dense": (dict(), 1),
+    "dropless_moe": (
+        dict(n_experts=8, moe_top_k=2, ffn_dim=256, capacity_factor=None,
+             qk_norm=True, moe_renormalize=False, moe_z_coef=0.001), 0),
+    "looped": (dict(ut_steps=3, exit_beta=0.1, sandwich_norm=True), 1),
+}
+
+
+def _text_census(text):
+    """What a lowered step text is made of, in a form a failed
+    comparison can show: its lines, how often each operation stands in
+    it and how often each tensor type."""
+    import collections
+    import re
+
+    count = lambda pattern: dict(sorted(collections.Counter(
+        re.findall(pattern, text)).items()))
+    return {"lines": len(text.splitlines()),
+            "ops": count(r"\b(?:stablehlo|func|sdy|mhlo)\.[a-z_]+"),
+            "types": count(r"tensor<[^>]*>")}
+
+
+@pytest.mark.parametrize("name", _OLDER_DECODERS, ids=str)
+def test_older_decoders_lower_to_the_text_they_had(chip, monkeypatch, name):
+    """Latent attention, layer kinds, the sigmoid router, the held
+    range, the selection bias and the MTP module are all behind knobs
+    whose defaults leave the lowered step of a plain, a dropless
+    expert and a looped decoder as it was: the census of each text
+    (``tests/data/older_step_census.json``, taken on the parent of
+    PR 37, 30388345, where the whole texts were equal, the kernels'
+    serialized bodies and the lowering's numbering of its private
+    functions apart) is what it was.  After a change that is MEANT to
+    move one of them, or another jax, the assertion shows what moved;
+    record anew with ``_text_census``."""
+    import json
+    from pathlib import Path
+
+    knobs, n_keep = _OLDER_DECODERS[name]
+    text = _llama_step_text(chip, monkeypatch, n_keep=n_keep, lowered=True,
+                            **knobs)
+    want = json.loads(
+        (Path(__file__).parent / "data" / "older_step_census.json")
+        .read_text())[name]
+    got = _text_census(text)
+    assert got["ops"] == want["ops"]
+    assert got["types"] == want["types"]
+    assert got["lines"] == want["lines"]
 
 
 @pytest.mark.parametrize("n_keep", [0, 1, 2])

@@ -99,6 +99,106 @@ def test_value_and_both_gradients_match_ragged_dot(groups, dtype):
         assert not np.asarray(got[2], np.float32)[empty].any()
 
 
+# a HELD range of the experts: the groups cover a prefix of the 512
+# rows (each list: the held groups' sizes; the rest of the rows belong
+# to groups that are not here)
+PREFIXES = {
+    "no_rows_held": [0, 0],
+    "one_tile": [100, 28],
+    "ragged_count": [70, 0, 131],
+    "ends_at_a_tile_edge": [128, 128],
+    "all_rows": [200, 312],
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("held", PREFIXES, ids=str)
+def test_prefix_plan_computes_the_held_rows_and_zeros_past_them(held, dtype):
+    """Forward and both backward kernels under a plan whose groups
+    cover only the first rows: the value and the input gradient are
+    ``ragged_dot``'s on the prefix and exactly zero past it, the
+    weight gradient ignores the rows past it — whatever those rows
+    hold (here NaN in the cotangent's and huge values in the
+    operand's)."""
+    sizes = PREFIXES[held]
+    n_held = sum(sizes)
+    lhs, rhs, ct, _ = _operands([512], dtype)
+    rhs = jnp.tile(rhs, (len(sizes), 1, 1)) * (
+        1 + jnp.arange(len(sizes), dtype=jnp.float32)[:, None, None]
+    ).astype(dtype)
+    sizes = jnp.asarray(sizes, jnp.int32)
+    plan = gm.make_tile_plan(sizes, 512, TM, prefix=True)
+    assert plan.prefix and int(plan.group_offsets[-1]) == n_held
+    # the visits end with the prefix: no tile past it is named
+    real = int(plan.n_visits[0])
+    assert int(np.max(np.asarray(plan.tile_ids))) <= max(n_held - 1, 0) // TM
+    assert (np.asarray(plan.tile_ids)[real:]
+            == np.asarray(plan.tile_ids)[real - 1]).all()
+    got = _value_and_grads(
+        lambda a, b: gm.grouped_matmul(a, b, plan, interpret=True),
+        lhs, rhs, ct,
+    )
+    want = _value_and_grads(
+        lambda a, b: lax.ragged_dot(a, b, sizes), lhs, rhs, ct
+    )
+    for g, w in zip(got, want):
+        _close(g, w, TOL[dtype])
+    for rows in got[:2]:
+        assert not np.asarray(rows[n_held:], np.float32).any()
+    # what lies past the prefix never reaches a result
+    tail = jnp.arange(512)[:, None] >= n_held
+    dirty = _value_and_grads(
+        lambda a, b: gm.grouped_matmul(a, b, plan, interpret=True),
+        jnp.where(tail, jnp.asarray(1e30, dtype), lhs), rhs,
+        jnp.where(tail, jnp.asarray(jnp.nan, dtype), ct),
+    )
+    if n_held < 512:
+        for g, d in zip(got, dirty):
+            np.testing.assert_array_equal(
+                np.asarray(g, np.float32), np.asarray(d, np.float32))
+
+
+def test_full_plan_is_the_prefix_plan_of_all_rows():
+    """A plan made without ``prefix`` masks nothing (its text is the
+    one the all-experts layer always had) and visits what the prefix
+    plan of the same sizes visits."""
+    sizes = jnp.asarray(GROUPS["skewed"], jnp.int32)
+    full = gm.make_tile_plan(sizes, 512, TM)
+    held = gm.make_tile_plan(sizes, 512, TM, prefix=True)
+    assert not full.prefix and held.prefix
+    for a, b in zip(gm._plan_arrays(full), gm._plan_arrays(held)):
+        np.testing.assert_array_equal(a, b)
+    out = jnp.ones((512, N))
+    assert gm._held_rows(out, full) is out          # nothing added
+    assert gm._held_rows(out, held) is not out
+
+
+def test_held_layer_on_the_kernels_matches_ragged_dot(
+    kernels_in_the_interpreter,
+):
+    """The expert layer with 4 of its 8 experts held, on the kernels
+    (in the interpreter) against ``lax.ragged_dot``: value and the
+    gradients of every operand."""
+    def loss(x, router, wg, wu, wd):
+        y, aux = moe.moe_ffn(
+            x, router, wg[:4], wu[:4], wd[:4], n_experts=E, top_k=TOP_K,
+            capacity_factor=None, expert_axis=None, model_axis=None,
+            held=4,
+        )
+        return jnp.sum(jnp.sin(y)) + aux["lb"]
+
+    grads = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))
+    args = _layer_args()
+    want = grads(*args)                     # off the TPU: ragged_dot
+    kernels_in_the_interpreter(True)
+    got = grads(*args)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        _close(g, w, 2e-5)
+    assert np.asarray(got[1][2][:4]).any()
+    assert not np.asarray(got[1][2][4:]).any()      # experts not held
+
+
 @pytest.mark.parametrize("groups", GROUPS, ids=str)
 def test_plan_visits_every_tile_of_every_group_in_order(groups):
     sizes = np.asarray(GROUPS[groups])

@@ -181,7 +181,7 @@ class TestBlockScopes:
     files that carry them clean under TM107."""
 
     BLOCKS = ("blk_embed", "blk_attn", "blk_ffn", "blk_head",
-              "blk_conv", "blk_bn", "blk_pool")
+              "blk_conv", "blk_bn", "blk_pool", "blk_mtp_in")
 
     def test_every_block_label_is_registered_under_its_own_leg(self):
         for label in self.BLOCKS:

@@ -241,6 +241,61 @@ class TestExitsHead:
             np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6,
                                        err_msg=name)
 
+    @pytest.mark.parametrize("layout", [dict(), dict(model=2)],
+                             ids=["one_device", "tp2"])
+    def test_a_row_of_labels_an_exit_equals_a_dense_head_an_exit(
+        self, devices8, data, rng, layout
+    ):
+        """``labels [R, N]`` (a multi-token-prediction module's exit is
+        held to another token than the main one): total, xent, pred
+        and all three gradients equal R calls of the dense head, each
+        on its own labels."""
+        xs, w, _, row_w = data
+        labels = rng.integers(0, self.V, (self.R, self.N)).astype(np.int32)
+        specs = (self.SPECS[0], self.SPECS[1], P(None, SEQ_AXIS),
+                 self.SPECS[3])
+
+        def dense_heads(xs, w, ys, row_w, axis=MODEL_AXIS):
+            xent, pred = zip(*(
+                tp_lib.dense_unembed_xent(xs[r], w, ys[r], self.V, axis)
+                for r in range(self.R)))
+            xent, pred = jnp.stack(xent), jnp.stack(pred)
+            return jnp.sum(row_w * xent), xent, pred
+
+        def run(head, mesh):
+            def fn(xs, w, ys, row_w):
+                def loss(xs, w, row_w):
+                    total, xent, pred = head(xs, w, ys, row_w)
+                    return self.G * lax.psum(total, SEQ_AXIS), (xent, pred)
+
+                (total, aux), grads = jax.value_and_grad(
+                    loss, argnums=(0, 1, 2), has_aux=True)(xs, w, row_w)
+                return (total, *aux), grads
+
+            return jax.jit(jax.shard_map(
+                fn, mesh=mesh, in_specs=specs,
+                out_specs=((P(), specs[3], specs[3]),
+                           (specs[0], specs[1], specs[3])),
+            ))(xs, w, labels, row_w)
+
+        want_out, want_grads = run(dense_heads, make_mesh(devices=devices8[:1]))
+        got_out, got_grads = run(
+            self.exits_head,
+            make_mesh(devices=devices8[:2 if layout else 1], **layout))
+        np.testing.assert_allclose(got_out[0], want_out[0], rtol=1e-6)
+        np.testing.assert_allclose(got_out[1], want_out[1], rtol=1e-5)
+        np.testing.assert_array_equal(got_out[2], want_out[2])
+        for name, a, b in zip(("dxs", "dw", "drow_w"), got_grads, want_grads):
+            assert float(jnp.max(jnp.abs(b))) > 1e-3, name
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6,
+                                       err_msg=name)
+        # one row of labels for all exits is the [N] form
+        same = np.tile(labels[:1], (self.R, 1))
+        a = self.exits_head(xs, w, same, row_w, axis=None)
+        b = self.exits_head(xs, w, labels[0], row_w, axis=None)
+        for x_, y_ in zip(a, b):
+            np.testing.assert_array_equal(x_, y_)
+
     def residual_shapes(self, head, data):
         xs, w, y, row_w = data
         _, vjp = jax.vjp(

@@ -145,6 +145,13 @@ PROFILE_SCOPES: dict[str, str] = {
     # benchmark/layer_metrics/_ut.py reads the same labels
     "ut_stack": "ut_stack",
     "ut_exit": "ut_exit",
+    # latent attention's projections inside ``blk_attn``, the shared
+    # expert inside ``blk_ffn`` and the multi-token-prediction module
+    # with its own blocks inside (models/llama.py, parallel/moe.py,
+    # PR 37); benchmark/layer_metrics/_scopes.py reads the same labels
+    "mla_proj": "mla_proj",
+    "moe_shared": "moe_shared",
+    "mtp": "mtp",
     # the step program's blocks (models/llama.py ``_forward`` /
     # ``_layer`` / ``loss_fn``, ops/layers.py, models/base.py, PR 35):
     # with ``opt_update`` and ``exchange_b<i>`` every instruction of a
@@ -154,6 +161,7 @@ PROFILE_SCOPES: dict[str, str] = {
     "blk_attn": "blk_attn",
     "blk_ffn": "blk_ffn",
     "blk_head": "blk_head",
+    "blk_mtp_in": "blk_mtp_in",
     "blk_conv": "blk_conv",
     "blk_bn": "blk_bn",
     "blk_pool": "blk_pool",

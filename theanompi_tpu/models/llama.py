@@ -57,6 +57,7 @@ modelclass='Llama')`` trains it.
 
 from __future__ import annotations
 
+import itertools
 import math
 from typing import Any
 
@@ -91,7 +92,11 @@ from theanompi_tpu.parallel import (
     pipeline_apply,
     split_microbatches,
 )
-from theanompi_tpu.parallel.moe import moe_ffn
+from theanompi_tpu.parallel.moe import (
+    moe_ffn,
+    select_bias_step,
+    shared_expert,
+)
 from theanompi_tpu.parallel.ring_attention import ring_attention
 from theanompi_tpu.parallel.ulysses import ulysses_attention
 from theanompi_tpu.parallel import tp as tp_lib
@@ -198,7 +203,19 @@ class Llama(TMModel):
     that pass's exit and the next pass's input, and the training loss
     weighs the R exits' cross-entropies a token by a learned exit
     distribution less ``exit_beta`` times its entropy
-    (``_exit_loss``).
+    (``_exit_loss``).  ``attention: "mla"`` is latent attention
+    (``q_lora_rank, kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim,
+    v_head_dim``; ``_mla_qkv``); ``first_k_dense`` leading layers of an
+    expert model are dense SwiGLUs of ``dense_ffn_dim``;
+    ``moe_scoring: "sigmoid"`` with ``moe_route_scale`` and
+    ``moe_bias_rate`` is the router whose selection bias is state
+    (``net_state``); ``moe_shared_experts`` adds a dense expert every
+    token goes through; ``moe_experts_held`` holds experts ``[0,
+    held)`` alone (one expert-parallel rank's share by itself);
+    ``mtp_depth: 1`` adds a multi-token-prediction module
+    (``mtp_coef``; ``_mtp_hidden``, ``_mtp_loss``).  These compose
+    with ``tp`` and data parallelism and are refused under ``pp``,
+    ``sp`` and ``ut_steps > 1``.
     """
 
     def __init__(self, config: dict | None = None):
@@ -212,6 +229,27 @@ class Llama(TMModel):
         self.vocab = int(c.get("vocab", 256))
         self.seq_len = int(c.get("seq_len", 256))
         self.head_dim = self.dim // self.n_heads
+        # "gqa": wq/wk/wv/wo at one head dim.  "mla": latent attention
+        # (``_mla_qkv``): q and k/v each through a low-rank
+        # down-projection with its own RMSNorm; a head's q and k are a
+        # no-position part and a rotary part, and the rotary part of k
+        # is ONE vector a token, shared by all heads
+        self.attention = str(c.get("attention", "gqa"))
+        assert self.attention in ("gqa", "mla"), self.attention
+        if self.attention == "mla":
+            self.q_lora_rank = int(c["q_lora_rank"])
+            self.kv_lora_rank = int(c["kv_lora_rank"])
+            self.qk_nope_head_dim = int(c["qk_nope_head_dim"])
+            self.qk_rope_head_dim = int(c["qk_rope_head_dim"])
+            self.v_head_dim = int(c["v_head_dim"])
+            # what the attention kernels see: q and k rows
+            self.head_dim = self.qk_nope_head_dim + self.qk_rope_head_dim
+            if self.head_dim != self.v_head_dim:
+                raise NotImplementedError(
+                    f"latent attention with q/k rows of {self.head_dim} "
+                    f"and value rows of {self.v_head_dim}: the flash "
+                    f"kernels take one head dim for q, k and v"
+                )
         self.tp = int(c.get("tp", 1))
         self.sp = int(c.get("sp", 1))
         self.pp = int(c.get("pp", 1))
@@ -232,6 +270,43 @@ class Llama(TMModel):
         self.ep = int(c.get("ep", 1))
         self.moe_aux_coef = float(c.get("moe_aux_coef", 0.01))
         self.moe_z_coef = float(c.get("moe_z_coef", 0.0))
+        # the router's form (``parallel/moe.router_topk``): "softmax",
+        # or "sigmoid" scores picked under a selection bias that is
+        # STATE (``net_state["moe_bias"]``, one row an expert layer
+        # call): no gradient, moved ``moe_bias_rate`` a step toward
+        # balance after the optimizer, outside it and the exchange
+        self.moe_scoring = str(c.get("moe_scoring", "softmax"))
+        assert self.moe_scoring in ("softmax", "sigmoid"), self.moe_scoring
+        self.moe_route_scale = float(c.get("moe_route_scale", 1.0))
+        self.moe_bias_rate = float(c.get("moe_bias_rate", 0.0))
+        self.moe_select_bias = bool(
+            self.n_experts and self.moe_scoring == "sigmoid"
+            and self.moe_bias_rate
+        )
+        # experts [0, held) of the n_experts routed over are here: one
+        # expert-parallel rank's share of every expert layer, by itself
+        held = c.get("moe_experts_held")
+        self.moe_experts_held = None if held is None else int(held)
+        # shared experts: a dense SwiGLU of that many expert widths
+        # that every token goes through, beside the routed ones
+        self.moe_shared_experts = int(c.get("moe_shared_experts", 0))
+        # the first layers of an expert model that are dense SwiGLUs
+        # of ``dense_ffn_dim`` (an expert's width is ``ffn_dim``)
+        self.first_k_dense = int(c.get("first_k_dense", 0))
+        self.dense_ffn_dim = int(c.get("dense_ffn_dim", self.ffn_dim))
+        # the kind of every layer, "moe" or "dense"
+        self.layer_kinds = tuple(
+            "moe" if self.n_experts and i >= self.first_k_dense else "dense"
+            for i in range(self.n_layers)
+        )
+        # multi-token prediction: ``mtp_depth`` (0 or 1) more blocks
+        # of the last layer's kind after the stack, which predict the
+        # token after next from the stack's output and the next
+        # token's embedding through the model's own head; their loss
+        # weighs ``mtp_coef``
+        self.mtp_depth = int(c.get("mtp_depth", 0))
+        self.mtp_coef = float(c.get("mtp_coef", 0.3))
+        assert self.mtp_depth in (0, 1), self.mtp_depth
         # token-sharding axes for MoE aux-moment globalization; set
         # for real in compile_iter_fns — initialized here so tracing
         # _forward before compile agrees with loss_and_err's fallback
@@ -258,7 +333,7 @@ class Llama(TMModel):
         # kernel's two outputs and, for a dropless expert layer, the
         # tile plan of its grouped products (a few KB)
         saves = FLASH_RESIDUALS
-        if self.n_experts and self.capacity_factor is None:
+        if "moe" in self.layer_kinds and self.capacity_factor is None:
             saves += (TILE_PLAN_RESIDUAL,)
         self.remat_saves = saves if self.remat else ()
         # how many of the LAST layer calls also keep ``MLP_RESIDUALS``
@@ -275,7 +350,7 @@ class Llama(TMModel):
             self.opt_name, weight_decay=float(c.get("weight_decay", 0.0))
         )
 
-        assert self.dim % self.n_heads == 0
+        assert self.attention == "mla" or self.dim % self.n_heads == 0
         assert self.n_heads % self.n_kv_heads == 0, (
             "n_heads must be a multiple of n_kv_heads (GQA groups)"
         )
@@ -283,6 +358,10 @@ class Llama(TMModel):
         assert self.n_kv_heads % self.tp == 0, "n_kv_heads must divide by tp"
         assert self.vocab % self.tp == 0, "vocab must divide by tp"
         assert self.ffn_dim % self.tp == 0, "ffn_dim must divide by tp"
+        assert self.dense_ffn_dim % self.tp == 0, (
+            "dense_ffn_dim must divide by tp"
+        )
+        assert 0 <= self.first_k_dense <= self.n_layers
         assert self.seq_len % self.sp == 0, "seq_len must divide by sp"
         assert self.n_layers % self.pp == 0, "n_layers must divide by pp"
         if self.n_experts:
@@ -297,9 +376,42 @@ class Llama(TMModel):
                     "sorted rows of an expert would cross chips in a "
                     "ragged all-to-all; give a capacity_factor or ep=1"
                 )
+            if self.moe_experts_held is not None and (
+                self.capacity_factor is not None or self.ep > 1
+                or not 0 < self.moe_experts_held <= self.n_experts
+            ):
+                raise NotImplementedError(
+                    "moe_experts_held (one expert-parallel rank's share "
+                    "by itself) is the dropless path's: give "
+                    "capacity_factor null, ep 1 and 1..n_experts held"
+                )
         else:
             assert self.ep == 1, "ep > 1 requires n_experts > 0"
+            assert not (self.first_k_dense or self.moe_shared_experts
+                        or self.moe_experts_held), (
+                "first_k_dense, moe_shared_experts and moe_experts_held "
+                "need n_experts > 0"
+            )
         assert self.ut_steps >= 1, self.ut_steps
+        mixed = [
+            name for name, on in (
+                ("attention: mla", self.attention == "mla"),
+                ("first_k_dense", len(set(self.layer_kinds)) > 1),
+                ("mtp_depth", self.mtp_depth),
+                ("a selection bias", self.moe_select_bias),
+            ) if on
+        ]
+        if mixed and (self.pp > 1 or self.sp > 1 or self.ut_steps > 1):
+            # pp stacks the layers' leaves on one leading dimension
+            # (one kind of layer) and runs the head apart from them;
+            # sp shards the positions the MTP labels shift over and
+            # has only been run with the one attention path
+            raise NotImplementedError(
+                f"{', '.join(mixed)} does not yet compose with "
+                f"pipeline parallelism, sequence parallelism or a "
+                f"looped stack (pp {self.pp}, sp {self.sp}, ut_steps "
+                f"{self.ut_steps}): use pp=1, sp=1, ut_steps=1"
+            )
         if self.ut_steps > 1 and self.pp > 1:
             raise NotImplementedError(
                 "a looped decoder (ut_steps > 1) does not yet compose "
@@ -323,6 +435,7 @@ class Llama(TMModel):
 
         self.params: PyTree = None
         self.opt_state: PyTree = None
+        self.net_state: PyTree = None
         self.mesh: Mesh | None = None
         self._train_step = None
         self._val_step = None
@@ -339,38 +452,13 @@ class Llama(TMModel):
         so each pipeline stage's device holds exactly its own
         ``n_layers/pp`` consecutive layers (contiguous mesh reshape =
         consecutive stages)."""
-        layer = {
-            "attn_norm": P(None),
-            "wq": P(None, MODEL_AXIS),
-            "wk": P(None, MODEL_AXIS),
-            "wv": P(None, MODEL_AXIS),
-            "wo": P(MODEL_AXIS, None),
-            "mlp_norm": P(None),
-        }
-        if self.qk_norm:
-            # over the whole projected width: sharded as its columns
-            layer.update({"q_norm": P(MODEL_AXIS), "k_norm": P(MODEL_AXIS)})
-        if self.sandwich_norm:
-            # over the full width of each branch's (psum'd) output
-            layer.update({"attn_out_norm": P(None), "mlp_out_norm": P(None)})
-        if self.n_experts:
-            # experts sharded over the expert axis, FFN dim over model
-            layer.update({
-                "router": P(None, None),
-                "we_gate": P(EXPERT_AXIS, None, MODEL_AXIS),
-                "we_up": P(EXPERT_AXIS, None, MODEL_AXIS),
-                "we_down": P(EXPERT_AXIS, MODEL_AXIS, None),
-            })
-        else:
-            layer.update({
-                "w_gate": P(None, MODEL_AXIS),
-                "w_up": P(None, MODEL_AXIS),
-                "w_down": P(MODEL_AXIS, None),
-            })
         if self.pp > 1:
-            layers = {k: P(PIPE_AXIS, *s) for k, s in layer.items()}
+            layers = {
+                k: P(PIPE_AXIS, *s)
+                for k, s in self._layer_specs(self.layer_kinds[0]).items()
+            }
         else:
-            layers = [dict(layer) for _ in range(self.n_layers)]
+            layers = [self._layer_specs(kind) for kind in self.layer_kinds]
         specs = {
             "embed": P(MODEL_AXIS, None),        # vocab-sharded rows
             "layers": layers,
@@ -380,7 +468,64 @@ class Llama(TMModel):
         if self.ut_steps > 1:
             # the exit gate acts on the full width: replicated
             specs.update({"exit_gate_w": P(None, None), "exit_gate_b": P(None)})
+        if self.mtp_depth:
+            # the two norms and the projection act on the full width
+            specs["mtp"] = {
+                "enorm": P(None), "hnorm": P(None),
+                "eh_proj": P(None, None),
+                "block": self._layer_specs(self.layer_kinds[-1]),
+                "head_norm": P(None),
+            }
         return specs
+
+    def _layer_specs(self, kind: str) -> dict:
+        """PartitionSpec per leaf of one layer of ``kind``."""
+        layer = {"attn_norm": P(None), "mlp_norm": P(None)}
+        if self.attention == "mla":
+            # heads over the model axis (the up-projections' columns,
+            # the output projection's rows); the two down-projections
+            # and their norms act on the full width: replicated
+            layer.update({
+                "wq_a": P(None, None), "q_a_norm": P(None),
+                "wq_b": P(None, MODEL_AXIS),
+                "wkv_a": P(None, None), "kv_a_norm": P(None),
+                "wkv_b": P(None, MODEL_AXIS),
+                "wo": P(MODEL_AXIS, None),
+            })
+        else:
+            layer.update({
+                "wq": P(None, MODEL_AXIS),
+                "wk": P(None, MODEL_AXIS),
+                "wv": P(None, MODEL_AXIS),
+                "wo": P(MODEL_AXIS, None),
+            })
+        if self.qk_norm:
+            # over the whole projected width: sharded as its columns
+            layer.update({"q_norm": P(MODEL_AXIS), "k_norm": P(MODEL_AXIS)})
+        if self.sandwich_norm:
+            # over the full width of each branch's (psum'd) output
+            layer.update({"attn_out_norm": P(None), "mlp_out_norm": P(None)})
+        if kind == "moe":
+            # experts sharded over the expert axis, FFN dim over model
+            layer.update({
+                "router": P(None, None),
+                "we_gate": P(EXPERT_AXIS, None, MODEL_AXIS),
+                "we_up": P(EXPERT_AXIS, None, MODEL_AXIS),
+                "we_down": P(EXPERT_AXIS, MODEL_AXIS, None),
+            })
+            if self.moe_shared_experts:
+                layer.update({
+                    "ws_gate": P(None, MODEL_AXIS),
+                    "ws_up": P(None, MODEL_AXIS),
+                    "ws_down": P(MODEL_AXIS, None),
+                })
+        else:
+            layer.update({
+                "w_gate": P(None, MODEL_AXIS),
+                "w_up": P(None, MODEL_AXIS),
+                "w_down": P(MODEL_AXIS, None),
+            })
+        return layer
 
     def _init_full_params(self, key) -> PyTree:
         """Full (unsharded) init; device_put with NamedShardings slices
@@ -393,48 +538,94 @@ class Llama(TMModel):
             return scale * jax.random.normal(key, shape, jnp.float32)
 
         keys = iter(jax.random.split(key, 4 + 9 * self.n_layers))
-        layers = []
-        for _ in range(self.n_layers):
-            lp = {
-                "attn_norm": jnp.ones((d,)),
+        # leaves the first decoders did not have (latent attention, a
+        # shared expert, the MTP module) draw from a stream of their
+        # own: the older leaves keep their values under any knob
+        more = iter(jax.random.split(
+            jax.random.fold_in(key, 1), 8 * (self.n_layers + 1) + 1
+        ))
+
+        def attention():
+            if self.attention == "mla":
+                hq = self.n_heads * self.head_dim
+                hkv = self.n_heads * (
+                    self.qk_nope_head_dim + self.v_head_dim
+                )
+                return {
+                    "wq_a": dense(next(more), (d, self.q_lora_rank)),
+                    "q_a_norm": jnp.ones((self.q_lora_rank,)),
+                    "wq_b": dense(next(more), (self.q_lora_rank, hq)),
+                    "wkv_a": dense(next(more), (
+                        d, self.kv_lora_rank + self.qk_rope_head_dim
+                    )),
+                    "kv_a_norm": jnp.ones((self.kv_lora_rank,)),
+                    "wkv_b": dense(next(more), (self.kv_lora_rank, hkv)),
+                    "wo": dense(
+                        next(more), (self.n_heads * self.v_head_dim, d)
+                    ),
+                }
+            return {
                 "wq": dense(next(keys), (d, self.n_heads * hd)),
                 "wk": dense(next(keys), (d, self.n_kv_heads * hd)),
                 "wv": dense(next(keys), (d, self.n_kv_heads * hd)),
                 "wo": dense(next(keys), (self.n_heads * hd, d)),
+            }
+
+        def one_layer(kind, keys):
+            lp = {
+                "attn_norm": jnp.ones((d,)),
+                **attention(),
                 "mlp_norm": jnp.ones((d,)),
             }
+            if self.attention == "mla":
+                for _ in range(4):
+                    next(keys)  # keep key budget aligned (9 per layer)
             if self.qk_norm:
                 lp["q_norm"] = jnp.ones((self.n_heads * hd,))
                 lp["k_norm"] = jnp.ones((self.n_kv_heads * hd,))
             if self.sandwich_norm:
                 lp["attn_out_norm"] = jnp.ones((d,))
                 lp["mlp_out_norm"] = jnp.ones((d,))
-            if self.n_experts:
+            if kind == "moe":
                 e = self.n_experts
+                # the leaves hold the experts that are here
+                eh = e if self.moe_experts_held is None else (
+                    self.moe_experts_held
+                )
                 # per-expert fan-in/out scales (the generic shape-based
                 # scale would key on E instead of D/F for 3-D tensors)
                 lp.update({
                     "router": dense(next(keys), (d, e)),
                     "we_gate": dense(
-                        next(keys), (e, d, f), (2.0 / (d + f)) ** 0.5
+                        next(keys), (eh, d, f), (2.0 / (d + f)) ** 0.5
                     ),
                     "we_up": dense(
-                        next(keys), (e, d, f), (2.0 / (d + f)) ** 0.5
+                        next(keys), (eh, d, f), (2.0 / (d + f)) ** 0.5
                     ),
                     "we_down": dense(
-                        next(keys), (e, f, d), (2.0 / (f + d)) ** 0.5
+                        next(keys), (eh, f, d), (2.0 / (f + d)) ** 0.5
                     ),
                 })
                 next(keys)  # keep key budget aligned (9 per layer)
+                if self.moe_shared_experts:
+                    fs = self.moe_shared_experts * f
+                    lp.update({
+                        "ws_gate": dense(next(more), (d, fs)),
+                        "ws_up": dense(next(more), (d, fs)),
+                        "ws_down": dense(next(more), (fs, d)),
+                    })
             else:
+                fd = self.dense_ffn_dim
                 lp.update({
-                    "w_gate": dense(next(keys), (d, f)),
-                    "w_up": dense(next(keys), (d, f)),
-                    "w_down": dense(next(keys), (f, d)),
+                    "w_gate": dense(next(keys), (d, fd)),
+                    "w_up": dense(next(keys), (d, fd)),
+                    "w_down": dense(next(keys), (fd, d)),
                 })
                 for _ in range(2):
                     next(keys)  # keep key budget aligned (9 per layer)
-            layers.append(lp)
+            return lp
+
+        layers = [one_layer(kind, keys) for kind in self.layer_kinds]
         if self.pp > 1:
             # stack the SAME per-layer draws (pp is a layout choice,
             # not a math choice: init must match the pp=1 model)
@@ -452,7 +643,37 @@ class Llama(TMModel):
                 next(keys), (d, 1), jnp.float32
             )
             params["exit_gate_b"] = jnp.zeros((1,))
+        if self.mtp_depth:
+            params["mtp"] = {
+                "enorm": jnp.ones((d,)), "hnorm": jnp.ones((d,)),
+                "eh_proj": dense(next(more), (2 * d, d)),
+                "block": one_layer(
+                    self.layer_kinds[-1],
+                    iter(jax.random.split(jax.random.fold_in(key, 2), 9)),
+                ),
+                "head_norm": jnp.ones((d,)),
+            }
         return params
+
+    def _init_net_state(self) -> PyTree:
+        """What a step reads and moves that is neither a parameter nor
+        optimizer state: the routers' selection bias, a row ``[E]`` an
+        expert layer call (the stack's, then the MTP block's), zeros
+        at the start.  ``None`` for a model without one."""
+        if not self.moe_select_bias:
+            return None
+        return {"moe_bias": jnp.zeros(
+            (self.moe_calls, self.n_experts), jnp.float32
+        )}
+
+    @property
+    def moe_calls(self) -> int:
+        """Expert layer calls a step runs: each gives one row of
+        moments, of routing counters and of selection bias."""
+        kinds = self.layer_kinds * self.ut_steps
+        if self.mtp_depth:
+            kinds += self.layer_kinds[-1:]
+        return kinds.count("moe")
 
     # -- forward (local shards) -------------------------------------------
 
@@ -478,16 +699,17 @@ class Llama(TMModel):
 
     @property
     def remat_kept_bytes_per_call(self) -> int:
-        """Bytes of ``MLP_RESIDUALS`` one layer call keeps on a device:
-        two ``[B_loc, T_loc, ffn_dim / tp]`` in compute dtype; 0 for an
-        expert layer, which names neither."""
-        if self.n_experts:
+        """Bytes of ``MLP_RESIDUALS`` one DENSE layer call keeps on a
+        device: two ``[B_loc, T_loc, dense_ffn_dim / tp]`` in compute
+        dtype; 0 for a model of expert layers alone, which name
+        neither."""
+        if "dense" not in self.layer_kinds:
             return 0
         n_tok = int(self.config.get("batch_size", 8)) * (
             self.seq_len // self.sp
         )
         return (
-            2 * n_tok * (self.ffn_dim // self.tp)
+            2 * n_tok * (self.dense_ffn_dim // self.tp)
             * self.compute_dtype.itemsize
         )
 
@@ -547,28 +769,61 @@ class Llama(TMModel):
         head = 2 * n_tok * (
             self.vocab // self.tp // self._xent_chunks()
         ) * isz
-        if self.ut_steps > 1:
-            head += 2 * self.ut_steps * n_tok * self.dim * isz
-        return (
-            param_bytes * (2 + opt_copies)
-            + self.n_layers * self.ut_steps // self.pp * per_call
-            + head
-        )
+        exits = self.ut_steps if self.ut_steps > 1 else 1 + self.mtp_depth
+        if exits > 1:
+            head += 2 * exits * n_tok * self.dim * isz
+        calls = self.n_layers * self.ut_steps // self.pp + self.mtp_depth
+        return param_bytes * (2 + opt_copies) + calls * per_call + head
 
     def remat_keep_calls(self, bytes_limit: int | None) -> int:
         """How many of the last layer calls keep ``MLP_RESIDUALS``: as
         many as fit between the step's estimated peak and the device's
-        ``bytes_limit`` less ``REMAT_RESERVE_BYTES``.  0 without a
-        limit (the CPU), without remat, for an expert layer and on the
-        pipeline path, whose stage function keeps the plain policy."""
+        ``bytes_limit`` less ``REMAT_RESERVE_BYTES``, of the calls
+        that are dense.  0 without a limit (the CPU), without remat,
+        without a dense layer and on the pipeline path, whose stage
+        function keeps the plain policy."""
         per_call = self.remat_kept_bytes_per_call
         if not (self.remat and bytes_limit and per_call) or self.pp > 1:
             return 0
         free = bytes_limit - REMAT_RESERVE_BYTES - self.step_peak_estimate()
-        return int(min(max(free // per_call, 0), self.remat_calls))
+        dense_calls = self.ut_steps * self.layer_kinds.count("dense")
+        return int(min(max(free // per_call, 0), dense_calls))
 
-    def _layer(self, p, x, pos):
-        """One decoder block on local shards: x [B, T_loc, D].
+    def _mla_qkv(self, p, xn, pos):
+        """Latent attention's projections, ``xn [B, T, D]`` -> ``q, k,
+        v [B, H_loc, T, head_dim]`` for the attention kernels, under
+        the scope ``mla_proj``: q through ``wq_a``, an RMSNorm and
+        ``wq_b``; k's no-position part and v through ``wkv_a``, an
+        RMSNorm and ``wkv_b``; RoPE on the last ``qk_rope_head_dim``
+        of a head's q and on the ONE rotary key vector a token, which
+        ``wkv_a`` gives beside the latent and every head shares."""
+        eps, theta = self.norm_eps, self.rope_theta
+        h_loc = self.n_heads // self.tp
+        nope, rank = self.qk_nope_head_dim, self.kv_lora_rank
+        with jax.named_scope("mla_proj"):
+            cq = rms_norm(xn @ p["wq_a"].astype(xn.dtype), p["q_a_norm"], eps)
+            q = _heads(tp_lib.col_parallel(cq, p["wq_b"]), h_loc,
+                       self.head_dim)
+            q = jnp.concatenate(
+                [q[..., :nope], rope(q[..., nope:], pos, theta)], axis=-1
+            )
+            ckv = xn @ p["wkv_a"].astype(xn.dtype)
+            k_rope = rope(ckv[:, None, :, rank:], pos, theta)
+            ckv = rms_norm(ckv[..., :rank], p["kv_a_norm"], eps)
+            kv = _heads(tp_lib.col_parallel(ckv, p["wkv_b"]), h_loc,
+                        nope + self.v_head_dim)
+            k = jnp.concatenate([
+                kv[..., :nope],
+                jnp.broadcast_to(
+                    k_rope, (*kv.shape[:3], self.qk_rope_head_dim)
+                ),
+            ], axis=-1)
+            return q, k, kv[..., nope:]
+
+    def _layer(self, p, x, pos, select_bias=None):
+        """One decoder block on local shards: x [B, T_loc, D]; an
+        expert block where ``p`` holds a router (``select_bias``: its
+        row ``[E]`` of the selection bias, if the model has one).
 
         With MoE enabled returns ``(x, mom)`` where ``mom`` is the
         fp32 [2E+2] vector of this layer's aux-loss MOMENTS
@@ -577,41 +832,20 @@ class Llama(TMModel):
         exactly; ``_aux_from_moments`` forms the losses.  Dense blocks
         return just ``x``."""
         cdtype = self.compute_dtype
-        h_loc = self.n_heads // self.tp
-        hkv_loc = self.n_kv_heads // self.tp
         hd = self.head_dim
-
         eps = self.norm_eps
         # the block names of the step program (``blk_*``, PERF.md §3):
         # metadata only; ``benchmark/layer_metrics/_blocks.py`` joins
         # them with a trace's device time
         with jax.named_scope("blk_attn"):
             xn = rms_norm(x, p["attn_norm"], eps)
-            q = tp_lib.col_parallel(xn, p["wq"])
-            k = tp_lib.col_parallel(xn, p["wk"])
-            if self.qk_norm:
-                q = rms_norm(q, p["q_norm"], eps, self.n_heads * hd)
-                k = rms_norm(k, p["k_norm"], eps, self.n_kv_heads * hd)
-            q, k = _heads(q, h_loc, hd), _heads(k, hkv_loc, hd)
-            v = _heads(tp_lib.col_parallel(xn, p["wv"]), hkv_loc, hd)
-            q = rope(q, pos, self.rope_theta)
-            k = rope(k, pos, self.rope_theta)
-            # GQA: KV stays compact on the wire; repeated only at compute
-            rep = h_loc // hkv_loc
-            if self.sp == 1:
-                # no sequence sharding: skip the ring/all_to_all
-                # machinery and hit the fused kernel (reference math
-                # off-TPU) directly
-                if rep != 1:
-                    k = jnp.repeat(k, rep, axis=1)
-                    v = jnp.repeat(v, rep, axis=1)
-                o = flash_attention(q, k, v, causal=True)
-            else:
-                attn = (
-                    ring_attention if self.sp_mode == "ring"
-                    else ulysses_attention
+            if self.attention == "mla":
+                q, k, v = self._mla_qkv(p, xn, pos)
+                o = flash_attention(
+                    q, k, v, causal=True, sm_scale=hd ** -0.5
                 )
-                o = attn(q, k, v, SEQ_AXIS, causal=True, kv_rep=rep)
+            else:
+                o = self._gqa(p, xn, pos)
             a = tp_lib.row_parallel(_unheads(o), p["wo"]).astype(cdtype)
             if self.sandwich_norm:
                 a = rms_norm(a, p["attn_out_norm"], eps)
@@ -619,7 +853,7 @@ class Llama(TMModel):
 
         with jax.named_scope("blk_ffn"):
             xn = rms_norm(x, p["mlp_norm"], eps)
-            if self.n_experts:
+            if "router" in p:
                 y, aux = moe_ffn(
                     xn, p["router"], p["we_gate"], p["we_up"], p["we_down"],
                     n_experts=self.n_experts,
@@ -631,12 +865,21 @@ class Llama(TMModel):
                     # (layout-invariant; set in compile_iter_fns)
                     batch_axes=(*self._dp_axes, SEQ_AXIS),
                     renormalize=self.moe_renormalize,
+                    scoring=self.moe_scoring,
+                    select_bias=select_bias,
+                    route_scale=self.moe_route_scale,
+                    held=self.moe_experts_held,
                 )
                 mom = jnp.concatenate(
                     [aux["f"], aux["p"], aux["z"][None],
                      aux["dropped"][None]]
                 ).astype(jnp.float32)
                 y = y.astype(cdtype)
+                if "ws_gate" in p:
+                    y = y + shared_expert(
+                        xn, p["ws_gate"], p["ws_up"], p["ws_down"],
+                        MODEL_AXIS,
+                    ).astype(cdtype)
                 if self.sandwich_norm:
                     y = rms_norm(y, p["mlp_out_norm"], eps)
                 return x + y, mom
@@ -654,8 +897,51 @@ class Llama(TMModel):
                 y = rms_norm(y, p["mlp_out_norm"], eps)
             return x + y
 
-    def _forward(self, params, ids, head=True, with_aux=False):
+    def _gqa(self, p, xn, pos):
+        """Grouped-query attention of ``xn [B, T_loc, D]`` (inside
+        ``_layer``'s ``blk_attn``): the three projections, QK-norm,
+        RoPE, the kernel or the sequence-parallel ring;
+        ``[B, H_loc, T_loc, hd]``."""
+        eps = self.norm_eps
+        h_loc = self.n_heads // self.tp
+        hkv_loc = self.n_kv_heads // self.tp
+        hd = self.head_dim
+        q = tp_lib.col_parallel(xn, p["wq"])
+        k = tp_lib.col_parallel(xn, p["wk"])
+        if self.qk_norm:
+            q = rms_norm(q, p["q_norm"], eps, self.n_heads * hd)
+            k = rms_norm(k, p["k_norm"], eps, self.n_kv_heads * hd)
+        q, k = _heads(q, h_loc, hd), _heads(k, hkv_loc, hd)
+        v = _heads(tp_lib.col_parallel(xn, p["wv"]), hkv_loc, hd)
+        q = rope(q, pos, self.rope_theta)
+        k = rope(k, pos, self.rope_theta)
+        # GQA: KV stays compact on the wire; repeated only at compute
+        rep = h_loc // hkv_loc
+        if self.sp == 1:
+            # no sequence sharding: skip the ring/all_to_all
+            # machinery and hit the fused kernel (reference math
+            # off-TPU) directly
+            if rep != 1:
+                k = jnp.repeat(k, rep, axis=1)
+                v = jnp.repeat(v, rep, axis=1)
+            return flash_attention(q, k, v, causal=True)
+        attn = (
+            ring_attention if self.sp_mode == "ring"
+            else ulysses_attention
+        )
+        return attn(q, k, v, SEQ_AXIS, causal=True, kv_rep=rep)
+
+    def _forward(self, params, ids, head=True, with_aux=False,
+                 net_state=None, mtp_ids=None):
         """ids [B_loc, T_loc] -> local vocab-shard logits [.., V/tp].
+
+        ``net_state``: the step's state beside the parameters
+        (``_init_net_state``), read without a gradient.  ``mtp_ids``
+        (with ``head=False``; the train loss path of a model with an
+        MTP module): the next token at every position; the hidden
+        states come back as TWO exits ``[2, B, T, D]``, the main
+        model's and the MTP module's (``_mtp_hidden``), each closed
+        by its own norm, for the one shared head.
 
         With ``pp > 1`` and the default scattered head, logits are a
         VALID 1/S TOKEN SLICE on every stage ([n_tok/S, V/tp]) —
@@ -705,26 +991,38 @@ class Llama(TMModel):
             if self.remat_kept_calls:
                 kept_layer = remat(*self.remat_saves, *MLP_RESIDUALS)
 
-        moe = bool(self.n_experts)
+        moe = "moe" in self.layer_kinds
         aux = jnp.zeros((2,), jnp.float32)
         routing = None
-        exits = None
+        exits = mtp_x = None
+        # a row of selection bias an expert layer call, in call order
+        # (None for a model without one)
+        bias_rows = (
+            iter(net_state["moe_bias"]) if self.moe_select_bias
+            else itertools.repeat(None)
+        )
         if self.pp == 1:
-            first_kept = self.remat_calls - self.remat_kept_calls
+            kept = self._kept_calls()
 
             def stack(x, first_call=0):
                 moms = []
                 for call, p in enumerate(params["layers"], first_call):
-                    fn = layer if call < first_kept else kept_layer
-                    if moe:
-                        x, mom = fn(p, x, pos)
+                    fn = kept_layer if call in kept else layer
+                    if "router" in p:
+                        x, mom = fn(p, x, pos, next(bias_rows))
                         moms.append(mom)
                     else:
                         x = fn(p, x, pos)
-                return x, (jnp.stack(moms) if moe else None)
+                return x, (jnp.stack(moms) if moms else None)
 
             if self.ut_steps == 1:
                 x, moms = stack(x)
+                if mtp_ids is not None:
+                    mtp_x, mom = self._mtp_hidden(
+                        params, x, mtp_ids, pos, layer, bias_rows
+                    )
+                    if mom is not None:
+                        moms = jnp.concatenate([moms, mom[None]])
             else:
                 # the looped decoder: R passes over the SAME leaves
                 # (their gradients are sums over the passes), the
@@ -825,6 +1123,8 @@ class Llama(TMModel):
         if exits is None:
             with jax.named_scope("blk_head"):
                 x = rms_norm(x, params["final_norm"], self.norm_eps)
+            if mtp_x is not None:
+                exits = jnp.stack([x, mtp_x])
         if not head:
             # a looped decoder gives its R exits [R, B, T, D] (the
             # last of them is ``x``): the loss reads them all
@@ -839,6 +1139,84 @@ class Llama(TMModel):
         with jax.named_scope("blk_head"):
             logits = tp_lib.col_parallel(x, params["lm_head"])
         return (logits, aux, routing) if with_aux else logits
+
+    def _kept_calls(self) -> frozenset:
+        """The layer calls whose remat also keeps ``MLP_RESIDUALS``:
+        the last ``remat_kept_calls`` of the calls that ARE dense (an
+        expert call names neither product)."""
+        kinds = self.layer_kinds * self.ut_steps
+        dense = [i for i, kind in enumerate(kinds) if kind == "dense"]
+        return frozenset(dense[len(dense) - self.remat_kept_calls:])
+
+    def _mtp_hidden(self, params, x, next_ids, pos, layer, bias_rows):
+        """The MTP module (depth 1) on the stack's output ``x [B, T,
+        D]`` (BEFORE the final norm) and the next tokens ``next_ids
+        [B, T]``, under the scope ``mtp``: the main model's embedding
+        of the next token and ``x``, each through its own RMSNorm,
+        side by side through ``eh_proj [2D, D]``; one more block of
+        the last layer's kind with weights (and selection bias) of
+        its own; the module's closing norm.  What comes back goes
+        through the main model's head against the token AFTER next.
+        Returns ``(hidden [B, T, D], the block's moments or None)``."""
+        mp = params["mtp"]
+        eps = self.norm_eps
+        with jax.named_scope("mtp"):
+            with jax.named_scope("blk_embed"):
+                emb = tp_lib.embed_lookup(
+                    next_ids, params["embed"], self.vocab
+                ).astype(x.dtype)
+            with jax.named_scope("blk_mtp_in"):
+                both = jnp.concatenate([
+                    rms_norm(emb, mp["enorm"], eps),
+                    rms_norm(x, mp["hnorm"], eps),
+                ], axis=-1)
+                h = both @ mp["eh_proj"].astype(x.dtype)
+            mom = None
+            if "router" in mp["block"]:
+                h, mom = layer(mp["block"], h, pos, next(bias_rows))
+            else:
+                h = layer(mp["block"], h, pos)
+            with jax.named_scope("blk_head"):
+                return rms_norm(h, mp["head_norm"], eps), mom
+
+    def _mtp_loss(self, params, exits, y, head_xent=None):
+        """The training loss of a model with an MTP module from its
+        two exits ``[2, N, D]`` and the next tokens ``y [B, T]``: the
+        main exit's mean cross-entropy against ``y`` plus ``mtp_coef``
+        times the module's against the token after next (``y`` one
+        place further on; a sequence's last position has none and
+        weighs nothing, and the mean stays over all ``N``).  Both go
+        through the one head as the looped decoder's exits do
+        (``tp.exits_unembed_xent``, labels ``[2, N]``), or, with
+        ``head_xent(h [N, D], labels [N]) -> (loss_vec, pred)`` given,
+        through the streamed head one after the other.  Returns
+        ``(loss, err)``: local token means, ``err`` the main exit's."""
+        b, t = y.shape
+        n = b * t
+        yf = y.reshape(-1)
+        labels = jnp.stack([
+            yf, jnp.concatenate([y[:, 1:], y[:, -1:]], axis=1).reshape(-1),
+        ])
+        row_w = jnp.stack([
+            jnp.full((n,), 1.0 / n, jnp.float32),
+            jnp.tile(
+                jnp.where(jnp.arange(t) < t - 1, self.mtp_coef / n, 0.0)
+                .astype(jnp.float32), b,
+            ),
+        ])
+        if head_xent is None:
+            loss, _, pred = tp_lib.exits_unembed_xent(
+                exits, params["lm_head"], labels, row_w, self.vocab,
+                MODEL_AXIS,
+            )
+            pred = pred[0]
+        else:
+            (main, pred), (after, _) = (
+                head_xent(exits[i], labels[i]) for i in range(2)
+            )
+            loss = jnp.sum(row_w[0] * main) + jnp.sum(row_w[1] * after)
+        err = jnp.mean((pred != yf).astype(jnp.float32))
+        return loss, err
 
     def _exit_loss(self, params, exits, targets, head=None):
         """The looped decoder's training loss from its R exits
@@ -1115,7 +1493,16 @@ class Llama(TMModel):
         )
         ep = self.ep
 
-        def step(params, opt_state, ef, x, y, lr):
+        # the state beside parameters and optimizer state (a selection
+        # bias): an argument more of the step, and its first result
+        # after ``ef``, only for a model that has one
+        state_specs = self._state_specs = (
+            (jax.tree.map(lambda _: P(), self._init_net_state()),)
+            if self.moe_select_bias else ()
+        )
+        picks = self.data.global_batch * self.seq_len * self.moe_top_k
+
+        def step(params, opt_state, ef, x, y, lr, *state):
             # Pre-cast params to DP-VARYING before autodiff: if they
             # stayed invariant, the vma transpose of their broadcast
             # into the data-varying compute would insert an implicit
@@ -1156,13 +1543,16 @@ class Llama(TMModel):
                 # part of the model math
                 yv = self._pp_targets(y)
                 counters = ()
+                more = dict(net_state=state[0]) if state else {}
+                if self.mtp_depth:
+                    more["mtp_ids"] = y
                 if self.n_experts:
                     h, aux, routing = self._forward(
-                        p, x, head=False, with_aux=True
+                        p, x, head=False, with_aux=True, **more
                     )
                     counters = (routing,)
                 else:
-                    h = self._forward(p, x, head=False)
+                    h = self._forward(p, x, head=False, **more)
                 # [N, D] rows; a looped decoder's R exits [R, N, D]
                 h2 = h.reshape(*h.shape[:-3], -1, h.shape[-1])
                 yf = yv.reshape(-1)
@@ -1183,6 +1573,14 @@ class Llama(TMModel):
                         )
                         counters += (
                             lax.pmean(exit_counters, SEQ_AXIS),
+                        )
+                    elif self.mtp_depth:
+                        # the main exit and the MTP module's through
+                        # the one head (``_mtp_loss``)
+                        loss, err = self._mtp_loss(
+                            p, h2, yv,
+                            (lambda z, labels: head_xent(z, labels, p))
+                            if n_xent_chunks > 1 else None,
                         )
                     else:
                         loss_vec, pred = head_xent(h2, yf, p)
@@ -1219,7 +1617,18 @@ class Llama(TMModel):
             err = lax.pmean(err, dp_axes)
             if self.ut_steps > 1:   # token means, as the loss is
                 counters[-1] = lax.pmean(counters[-1], dp_axes)
-            return params, opt_state, ef, loss, err, *counters
+            if state:
+                # after the optimizer, outside it and the exchange:
+                # each router's selection bias a step toward balance,
+                # from the pick fractions the step counted (global
+                # means already: every replica moves alike)
+                bias = select_bias_step(
+                    state[0]["moe_bias"], counters[0][:, :self.n_experts],
+                    picks, self.moe_bias_rate,
+                )
+                state = ({"moe_bias": bias},)
+                counters.insert(1, jnp.max(jnp.abs(bias), axis=-1))
+            return params, opt_state, ef, *state, loss, err, *counters
 
         def val(params, x, y):
             logits = self._forward(params, x)
@@ -1230,10 +1639,11 @@ class Llama(TMModel):
         from theanompi_tpu.utils.xla_options import xla_compiler_options
 
         is_tpu = mesh.devices.flat[0].platform == "tpu"
-        # a MoE step also gives out its routing counters [L, E+1], a
-        # looped decoder's its exit counters [2R + 1]
+        # a MoE step also gives out its routing counters [L, E+1]
+        # (and each selection bias's largest size [L], where it has
+        # them), a looped decoder's its exit counters [2R + 1]
         counter_out = self._counter_out_specs = (P(),) * (
-            bool(self.n_experts) + (self.ut_steps > 1)
+            bool(self.n_experts) + len(state_specs) + (self.ut_steps > 1)
         )
         self._compiler_options = xla_compiler_options(
             self.config,
@@ -1244,10 +1654,11 @@ class Llama(TMModel):
                 step,
                 mesh=mesh,
                 in_specs=(specs, opt_specs, ef_specs, batch_spec,
-                          batch_spec, P()),
-                out_specs=(specs, opt_specs, ef_specs, P(), P(), *counter_out),
+                          batch_spec, P(), *state_specs),
+                out_specs=(specs, opt_specs, ef_specs, *state_specs,
+                           P(), P(), *counter_out),
             ),
-            donate_argnums=(0, 1, 2),
+            donate_argnums=(0, 1, 2, *range(6, 6 + len(state_specs))),
             compiler_options=self._compiler_options,
         )
 
@@ -1290,6 +1701,10 @@ class Llama(TMModel):
             )(jax.random.PRNGKey(self.seed))
         if not plan.keeps_restored_ef(self.ef_state, self._restored):
             self.ef_state = plan.init_ef(mesh)
+        if state_specs and self.net_state is None:
+            self.net_state = jax.device_put(
+                self._init_net_state(), NamedSharding(mesh, P())
+            )
         self._batch_sharding = NamedSharding(mesh, batch_spec)
         self._init_feed(
             self._batch_sharding, dtypes=(jnp.int32, jnp.int32)
@@ -1332,8 +1747,12 @@ class Llama(TMModel):
         has_exp = EXPERT_AXIS in self.mesh.shape
         counter_out = self._counter_out_specs
 
+        state_specs = self._state_specs
+        n_state = len(state_specs)
+
         def make_scan(length: int):
-            def scan_steps(params, opt_state, ef, step, seqs, perm, lr):
+            def scan_steps(params, opt_state, ef, step, seqs, perm, lr,
+                           *state):
                 # flat DP replica index, expert-major — must match the
                 # batch spec's (expert, data) shard ordering
                 dme = lax.axis_index(DATA_AXIS)
@@ -1343,7 +1762,7 @@ class Llama(TMModel):
                 nb = perm.shape[0] // gb
 
                 def body(carry, _):
-                    params, opt_state, ef, st = carry
+                    params, opt_state, ef, st, *state = carry
                     i = (st % nb).astype(jnp.int32)
                     idx = lax.dynamic_slice(
                         perm, (i * gb + dme * b_loc,), (b_loc,)
@@ -1356,25 +1775,29 @@ class Llama(TMModel):
                         rows, (0, sme * t_loc + 1), (b_loc, t_loc)
                     )
                     params, opt_state, ef, *per_step = shard_step(
-                        params, opt_state, ef, x, y, lr
+                        params, opt_state, ef, x, y, lr, *state
                     )
-                    return (params, opt_state, ef, st + 1), tuple(per_step)
+                    state, per_step = per_step[:n_state], per_step[n_state:]
+                    return (
+                        (params, opt_state, ef, st + 1, *state),
+                        tuple(per_step),
+                    )
 
                 # per step: loss, err and the step's counters, if any
-                (params, opt_state, ef, step), per_step = lax.scan(
-                    body, (params, opt_state, ef, step), None,
+                (params, opt_state, ef, step, *state), per_step = lax.scan(
+                    body, (params, opt_state, ef, step, *state), None,
                     length=length,
                 )
-                return params, opt_state, ef, step, *per_step
+                return params, opt_state, ef, step, *state, *per_step
 
             return jax.jit(
                 jax.shard_map(
                     scan_steps,
                     mesh=self.mesh,
                     in_specs=(specs, opt_specs, ef_specs,
-                              P(), P(), P(), P()),
-                    out_specs=(specs, opt_specs, ef_specs,
-                               P(), P(), P(), *counter_out),
+                              P(), P(), P(), P(), *state_specs),
+                    out_specs=(specs, opt_specs, ef_specs, P(),
+                               *state_specs, P(), P(), *counter_out),
                 ),
                 # the state comes back under the shardings it went in
                 # with, so the second dispatch finds the first one's
@@ -1385,9 +1808,10 @@ class Llama(TMModel):
                 # calls; PERF.md, PR 33)
                 out_shardings=(
                     *map(self._shardings, (specs, opt_specs, ef_specs)),
-                    *[rep] * (3 + len(counter_out)),
+                    rep, *map(self._shardings, state_specs),
+                    *[rep] * (2 + len(counter_out)),
                 ),
-                donate_argnums=(0, 1, 2, 3),
+                donate_argnums=(0, 1, 2, 3, *range(7, 7 + n_state)),
                 compiler_options=self._compiler_options,
             )
 
@@ -1419,27 +1843,43 @@ class Llama(TMModel):
                 self.opt_state,
                 self.ef_state,
                 self._step_dev,
-                losses,
-                errs,
-                *counters,
+                *rest,
             ) = scan_fn(
                 self.params, self.opt_state, self.ef_state,
                 self._step_dev, self._seqs_dev, self._perm_dev,
-                self._lr_dev,
+                self._lr_dev, *self._state_args(),
             )
+            losses, errs, *counters = self._take_state(rest)
         recorder.train_error(count, losses, errs)
         self._record_counters(recorder, counters)
+
+    def _state_args(self) -> tuple:
+        """``net_state`` as the step's last arguments: ``()`` for a
+        model that has none."""
+        return (self.net_state,) if self._state_specs else ()
+
+    def _take_state(self, results):
+        """A step's results after ``ef`` (or the scan's after its step
+        counter): the new ``net_state`` kept, the rest given back."""
+        if self._state_specs:
+            self.net_state, *results = results
+        return results
 
     def _record_counters(self, recorder: Recorder, counters) -> None:
         """Hand a step's counters (device values; read with the loss
         at the recorder's next fence) to the recorder: a MoE's
-        routing, then a looped decoder's exits."""
+        routing (with its selection biases' sizes), then a looped
+        decoder's exits."""
         counters = list(counters)
         if self.n_experts:
             recorder.moe_routing(
                 counters.pop(0),
                 # picks a layer CALL: a looped MoE has R * L of them
                 picks=self.data.global_batch * self.seq_len * self.moe_top_k,
+                **(dict(held=self.moe_experts_held)
+                   if self.moe_experts_held is not None else {}),
+                **(dict(bias_abs_max=counters.pop(0))
+                   if self._state_specs else {}),
             )
         if self.ut_steps > 1:
             recorder.ut_exits(counters.pop(0))
@@ -1467,7 +1907,7 @@ class Llama(TMModel):
         x, y = self.put_batch(self.data.train_batch(0))
         return self._train_step.lower(
             self.params, self.opt_state, self.ef_state, x, y,
-            jnp.float32(self.current_lr),
+            jnp.float32(self.current_lr), *self._state_args(),
         ).compile().cost_analysis()
 
     def train_step_hlo_text(self):
@@ -1485,13 +1925,13 @@ class Llama(TMModel):
             lowered = self._train_scan.lower(
                 self.params, self.opt_state, self.ef_state,
                 self._step_dev, self._seqs_dev, self._perm_dev,
-                self._lr_dev,
+                self._lr_dev, *self._state_args(),
             )
         else:
             x, y = self.put_batch(self.data.train_batch(0))
             lowered = self._train_step.lower(
                 self.params, self.opt_state, self.ef_state, x, y,
-                jnp.float32(self.current_lr),
+                jnp.float32(self.current_lr), *self._state_args(),
             )
         return compiled_hlo_text(lowered.compile())
 
@@ -1515,13 +1955,12 @@ class Llama(TMModel):
                 self.params,
                 self.opt_state,
                 self.ef_state,
-                loss,
-                err,
-                *counters,
+                *rest,
             ) = self._train_step(
                 self.params, self.opt_state, self.ef_state, x, y,
-                jnp.float32(self.current_lr),
+                jnp.float32(self.current_lr), *self._state_args(),
             )
+            loss, err, *counters = self._take_state(rest)
         # device scalars, materialized lazily at the next print window
         # or epoch end (Recorder.flush) — no per-step host fence
         recorder.train_error(count, loss, err)
@@ -1552,6 +1991,13 @@ class Llama(TMModel):
         trees = {"params": self.params, "opt_state": self.opt_state}
         if getattr(self, "ef_state", None):
             trees["ef_state"] = self.ef_state
+        if self.net_state is not None or self.moe_select_bias:
+            # (a slot before the step is compiled too, so that a
+            # checkpoint's selection bias is loaded, not dropped)
+            trees["net_state"] = (
+                self.net_state if self.net_state is not None
+                else self._init_net_state()
+            )
         return trees
 
     def _place_restored(self) -> None:
@@ -1568,6 +2014,8 @@ class Llama(TMModel):
         self.opt_state = put(self.opt_state, self._opt_specs)
         if getattr(self, "ef_state", None):
             self.ef_state = put(self.ef_state, self.exchange.ef_specs)
+        if self.net_state is not None:
+            self.net_state = put(self.net_state, self._state_specs[0])
 
 
 # Llama-3-8B shape (the BASELINE stretch config), for reference and

@@ -12,7 +12,16 @@ sync of its own — then keeps the LAST step's counters here:
 - ``moe_load_max_over_mean`` — the fullest expert's rows over the
   mean, worst layer (1.0 at balance; the grouped products' tail and,
   with capacity buffers, the drops follow it);
-- ``moe_dropped_picks`` — summed over the layers.
+- ``moe_dropped_picks`` — summed over the layers;
+- ``moe_experts_held`` and ``moe_rows_held`` ``[L]`` — where the
+  layers' leaves hold only experts ``[0, held)`` of the ``E`` routed
+  over (one expert-parallel rank's share): how many, and the rows
+  those experts computed in that step, a layer (``picks * held / E``
+  at balance).  ``f`` and with it ``moe_load_max_over_mean`` stay
+  over all ``E``: the router is whole;
+- ``moe_bias_abs_max`` — a sigmoid router's selection bias after
+  that step, its largest size over layers and experts (0 at the
+  start, a rate a step at most).
 
 The run summary carries them (``"moe_counters"``) and they stay
 readable afterwards with :func:`last_moe_counters`.  Names are a
@@ -26,21 +35,29 @@ import numpy as np
 _LAST: dict | None = None
 
 
-def moe_counters(routing, picks: int) -> dict:
+def moe_counters(routing, picks: int, *, held: int | None = None,
+                 bias_abs_max=None) -> dict:
     """``routing [L, E+1]`` (see the module docstring) of one step of
     ``picks`` (token, pick) rows a layer -> the counters' dict; also
-    kept as the process's newest."""
+    kept as the process's newest.  ``held``, ``bias_abs_max [L]``:
+    what the last counters are made from, where the model has them."""
     global _LAST
     a = np.asarray(routing, np.float64)
     share, dropped = a[:, :-1], a[:, -1]
+    rows = np.rint(share * picks).astype(int)
     _LAST = {
         "moe_picks_per_step": int(picks),
-        "moe_rows_per_expert": np.rint(share * picks).astype(int).tolist(),
+        "moe_rows_per_expert": rows.tolist(),
         "moe_load_max_over_mean": float(
             np.max(share.max(axis=1) / share.mean(axis=1))
         ),
         "moe_dropped_picks": int(round(float(dropped.sum()))),
     }
+    if held is not None:
+        _LAST["moe_experts_held"] = int(held)
+        _LAST["moe_rows_held"] = rows[:, :held].sum(axis=1).tolist()
+    if bias_abs_max is not None:
+        _LAST["moe_bias_abs_max"] = float(np.max(bias_abs_max))
     return _LAST
 
 

@@ -44,8 +44,13 @@ Tiles are a function of shapes and dtype (``tile_rows``,
 ``_sub_rows``, ``_rows_tiles``, ``_weights_tiles``; measured on the
 v5e, PERF.md PR 31), as ``ops.attention._auto_block`` is for flash —
 not a knob.
-``sum(group_sizes)`` must equal ``M`` (every row has a group), as it
-does for the expert layer's ``k * N`` picks.
+``sum(group_sizes)`` equals ``M`` (every row has a group), as it
+does for the expert layer's ``k * N`` picks — or, under a plan made
+with ``prefix=True``, is at most ``M``: the groups are a held range of
+the experts, whose rows are a prefix of the sorted picks.  The
+kernels then run over that prefix alone (device time follows the rows
+held, not ``M``), (a) and (b) give zeros past it and (c) never reads
+past it; the kernels' own text is the same for both.
 """
 
 from __future__ import annotations
@@ -67,7 +72,7 @@ TILE_PLAN_RESIDUAL = "moe_tile_plan"
 @functools.partial(
     jax.tree_util.register_dataclass,
     data_fields=["group_offsets", "group_ids", "tile_ids", "n_visits"],
-    meta_fields=["block_rows"],
+    meta_fields=["block_rows", "prefix"],
 )
 @dataclasses.dataclass(frozen=True)
 class TilePlan:
@@ -77,19 +82,32 @@ class TilePlan:
     ``group_ids [V]`` / ``tile_ids [V]``: the group and the row tile
     of visit ``v``, the last real visit repeated up to the static
     ``V = M / block_rows + E - 1``; ``n_visits [1]``: how many are
-    real.  All int32."""
+    real.  All int32.  ``prefix``: the groups need not cover all the
+    rows, only the first ``group_offsets[-1]`` of them (static: it
+    decides whether the products mask their outputs)."""
 
     group_offsets: jax.Array
     group_ids: jax.Array
     tile_ids: jax.Array
     n_visits: jax.Array
     block_rows: int
+    prefix: bool = False
 
 
-def make_tile_plan(group_sizes, n_rows: int, block_rows: int) -> TilePlan:
+def make_tile_plan(group_sizes, n_rows: int, block_rows: int, *,
+                   prefix: bool = False) -> TilePlan:
     """The visits for ``n_rows`` rows sorted into groups of
     ``group_sizes [E]`` (int32, summing to ``n_rows``), cut into row
-    tiles of ``block_rows`` (which divides ``n_rows``)."""
+    tiles of ``block_rows`` (which divides ``n_rows``).
+
+    ``prefix``: the sizes may sum to LESS than ``n_rows`` — the groups
+    held here are the first of a longer sorted list, and the rows past
+    ``sum(group_sizes)`` belong to none of them.  The visits are the
+    same function of the sizes: they end with the last group's last
+    tile, the static grid's remaining steps repeat that visit (they
+    compute nothing and, their blocks' indices unchanged, fetch
+    nothing), and no tile past the prefix is visited at all.  What
+    the products give for those rows is ``grouped_matmul``'s to say."""
     if n_rows % block_rows:
         raise ValueError(
             f"{n_rows} rows do not divide into tiles of {block_rows}"
@@ -116,6 +134,7 @@ def make_tile_plan(group_sizes, n_rows: int, block_rows: int) -> TilePlan:
         tile_ids=tid.astype(jnp.int32),
         n_visits=n_visits[None],
         block_rows=block_rows,
+        prefix=prefix,
     )
 
 
@@ -445,22 +464,44 @@ def _weights_call(lhs, dout, plan, out_dtype, *, tiles=None,
     )(*_plan_arrays(plan), lhs, dout)
 
 
+def _held_rows(out, plan):
+    """``out [M, .]`` of (a) / (b) under a prefix plan: the kernels
+    never visit a tile past the groups' rows and write only a group's
+    rows of the tile the prefix ends in, so what lies past it is
+    whatever the buffer held; zeros, by a select the consumer fuses
+    (a product of a NaN there would not do).  (c) needs none: a row
+    past the prefix is in no visit's group."""
+    if not plan.prefix:
+        return out
+    rows = jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
+    return jnp.where(rows < plan.group_offsets[-1], out,
+                     jnp.zeros((), out.dtype))
+
+
+def _product(lhs, rhs, plan, interpret):
+    return _held_rows(
+        _rows_call(lhs, rhs, plan, transpose_rhs=False, interpret=interpret),
+        plan,
+    )
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _grouped(lhs, rhs, plan, interpret):
-    return _rows_call(lhs, rhs, plan, transpose_rhs=False,
-                      interpret=interpret)
+    return _product(lhs, rhs, plan, interpret)
 
 
 def _grouped_fwd(lhs, rhs, plan, interpret):
-    out = _rows_call(lhs, rhs, plan, transpose_rhs=False,
-                     interpret=interpret)
-    return out, (lhs, rhs, plan)
+    return _product(lhs, rhs, plan, interpret), (lhs, rhs, plan)
 
 
 def _grouped_bwd(interpret, res, g):
     lhs, rhs, plan = res
     return (
-        _rows_call(g, rhs, plan, transpose_rhs=True, interpret=interpret),
+        _held_rows(
+            _rows_call(g, rhs, plan, transpose_rhs=True,
+                       interpret=interpret),
+            plan,
+        ),
         _weights_call(lhs, g, plan, rhs.dtype, interpret=interpret),
         None,
     )

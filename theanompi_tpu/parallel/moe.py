@@ -79,6 +79,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from theanompi_tpu.ops import attention
 from theanompi_tpu.ops import grouped_matmul as gmm
+from theanompi_tpu.ops.layers import swiglu
 from theanompi_tpu.parallel.mesh import EXPERT_AXIS, MODEL_AXIS
 
 logger = logging.getLogger(__name__)
@@ -98,15 +99,50 @@ def moe_capacity(
     return max(8, min(c, -(-n_tokens // 8) * 8))
 
 
-def router_topk(x2, w_router, top_k: int, renormalize: bool = True):
+def router_topk(x2, w_router, top_k: int, renormalize: bool = True, *,
+                scoring: str = "softmax", select_bias=None,
+                scale: float = 1.0):
     """fp32 router: returns (gates [N,k], expert ids [N,k], probs
-    [N,E], logits [N,E]).  ``x2`` is [N, D]."""
+    [N,E], logits [N,E]).  ``x2`` is [N, D].
+
+    ``scoring="softmax"``: the top-k of a softmax over the experts.
+    ``scoring="sigmoid"``: every expert's score is its own sigmoid;
+    the picks are the top-k of ``score + select_bias`` (``[E]``, read
+    without a gradient: it is state a rule moves, see
+    ``select_bias_step``), the gates the picked SCORES, without the
+    bias, renormalised (``+ 1e-20``) and times ``scale``; ``probs``
+    are then the scores."""
     logits = x2.astype(jnp.float32) @ w_router.astype(jnp.float32)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        chosen = scores
+        if select_bias is not None:
+            chosen = scores + lax.stop_gradient(
+                select_bias.astype(jnp.float32)
+            )
+        _, eidx = lax.top_k(chosen, top_k)
+        gates = jnp.take_along_axis(scores, eidx, axis=-1)
+        if renormalize:
+            gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+        return gates * scale, eidx, scores, logits
+    assert scoring == "softmax", scoring
     probs = jax.nn.softmax(logits, axis=-1)
     gates, eidx = lax.top_k(probs, top_k)          # [N, k]
     if renormalize:
         gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
     return gates, eidx, probs, logits
+
+
+def select_bias_step(bias, f, picks: int, rate: float):
+    """The selection bias after a step: ``bias [.., E]`` moved by
+    ``rate`` toward balance, ``+`` for an expert that got fewer than
+    the mean of the step's ``picks`` (token, pick) rows and ``-`` for
+    one that got more, by the sign alone.  ``f [.., E]`` are the pick
+    fractions ``moe_ffn`` gives (global over the batch axes, so every
+    replica moves alike); the counts are whole numbers again before
+    they are compared, so an expert exactly at the mean stays."""
+    counts = jnp.round(f * picks)
+    return bias + rate * jnp.sign(picks / f.shape[-1] - counts)
 
 
 def aux_moments(eidx, probs, n_experts: int, batch_axes=()):
@@ -160,6 +196,19 @@ def _swiglu_experts(rows, we_gate, we_up, we_down, product, row_scale=None):
     if row_scale is not None:
         h = (h.astype(jnp.float32) * row_scale[:, None]).astype(dt)
     return product(h, we_down.astype(dt))
+
+
+def shared_expert(x, w_gate, w_up, w_down, model_axis=MODEL_AXIS):
+    """The expert EVERY token goes through, beside the routed ones: a
+    dense SwiGLU with no routing and no gate, under the scope
+    ``moe_shared``.  ``w_gate``/``w_up`` ``[D, F_loc]`` column-sharded
+    and ``w_down`` ``[F_loc, D]`` row-sharded over ``model_axis``
+    (``None``: replicated)."""
+    with jax.named_scope("moe_shared"):
+        dt = x.dtype
+        h = swiglu(x @ w_gate.astype(dt), x @ w_up.astype(dt))
+        y = h @ w_down.astype(dt)
+        return lax.psum(y, model_axis) if model_axis is not None else y
 
 
 # -- dropless: sorted rows, grouped products -------------------------------
@@ -238,31 +287,59 @@ def _log_ragged_choice(rows: int, d: int, f: int, dtype: str,
     )
 
 
-def _grouped_product(group_sizes, rows: int, d: int, f: int, dtype):
+def _grouped_product(group_sizes, rows: int, d: int, f: int, dtype,
+                     prefix: bool = False):
     """``product(lhs [rows, .], w [E, ., .])`` for the layer's three
     grouped products: on a TPU, shapes permitting, the repo's kernels
     over one tile plan built HERE, once, and shared by all of them,
-    forward, replay and backward; ``lax.ragged_dot`` otherwise."""
+    forward, replay and backward; ``lax.ragged_dot`` otherwise.
+    ``prefix``: the groups cover only the first ``sum(group_sizes)``
+    rows (a held range of the experts); the products of the rows past
+    them are zero, forward and backward (the kernels' prefix plan; off
+    the TPU ``lax.ragged_dot``'s own lowering; refused on a TPU whose
+    shapes do not tile)."""
     on_tpu = attention._on_tpu()
     if (on_tpu and gmm.shapes_tile(rows, d, f, dtype)
             and gmm.shapes_tile(rows, f, d, dtype)):
         with jax.named_scope("moe_tile_plan"):
             plan = checkpoint_name(
-                gmm.make_tile_plan(group_sizes, rows, gmm.tile_rows(rows)),
+                gmm.make_tile_plan(group_sizes, rows, gmm.tile_rows(rows),
+                                   prefix=prefix),
                 gmm.TILE_PLAN_RESIDUAL,
             )
         return lambda lhs, w: gmm.grouped_matmul(lhs, w, plan)
+    if prefix and on_tpu:
+        # XLA's own grouped kernels on the chip compute the rows past
+        # ``sum(group_sizes)`` with the last group's weights (PERF.md,
+        # PR 37), where the CPU's lowering gives zeros
+        raise NotImplementedError(
+            f"a held range of the experts on a TPU needs shapes the "
+            f"grouped kernels tile (rows {rows}, widths {d} and {f}, "
+            f"{dtype}): lax.ragged_dot there does not leave the rows "
+            f"past its groups zero"
+        )
     _log_ragged_choice(rows, d, f, str(dtype), on_tpu)
     return lambda lhs, w: lax.ragged_dot(lhs, w, group_sizes)
 
 
 def _dropless_experts(x2, gates, eidx, we_gate, we_up, we_down, *,
-                      n_experts: int, model_axis):
+                      n_experts: int, model_axis, held: int | None = None):
     """Every pick computed: sort, gather, grouped SwiGLU with each
     row's gate folded into its hidden activations, and the sum of a
-    token's rows.  Returns ``y [N, D]`` fp32."""
+    token's rows.  Returns ``y [N, D]`` fp32.
+
+    ``held``: only experts ``[0, held)`` of the ``n_experts`` routed
+    over are here (one expert-parallel rank's share, by itself).  The
+    picks are sorted over ALL experts as ever, so the held experts'
+    rows are a prefix of the sorted rows; the groups are those
+    experts' alone, the products compute that prefix and give zeros
+    past it, and a pick of an expert not held adds exactly zero to
+    its token's sum, forward and backward.  Nothing stands in for the
+    ranks that hold the rest."""
     n, _ = x2.shape
     k = eidx.shape[1]
+    if held is not None:
+        n_experts = held        # the groups: the experts that are here
     with jax.named_scope("moe_dispatch"):
         flat_e = eidx.T.reshape(-1).astype(jnp.int32)     # slot-major [k*N]
         picks = jnp.arange(k * n, dtype=jnp.int32)
@@ -280,7 +357,7 @@ def _dropless_experts(x2, gates, eidx, we_gate, we_up, we_down, *,
         out = _swiglu_experts(
             rows, we_gate, we_up, we_down,
             _grouped_product(group_sizes, k * n, *we_gate.shape[1:],
-                             rows.dtype),
+                             rows.dtype, prefix=held is not None),
             row_scale=row_gate,
         )
         if model_axis is not None:
@@ -374,6 +451,10 @@ def moe_ffn(
     model_axis: str | None = MODEL_AXIS,
     batch_axes: tuple = (),
     renormalize: bool = True,
+    scoring: str = "softmax",
+    select_bias=None,
+    route_scale: float = 1.0,
+    held: int | None = None,
 ):
     """MoE SwiGLU FFN on local token shards (call inside shard_map).
 
@@ -390,6 +471,16 @@ def moe_ffn(
     - ``renormalize``: selected gates rescaled to sum to one (Mixtral)
       or left as the softmax gave them (OLMoE, ``norm_topk_prob``
       false).
+    - ``scoring``, ``select_bias``, ``route_scale``: the router's form
+      (``router_topk``): a softmax top-k, or sigmoid scores picked
+      under a selection bias ``[E]`` and scaled.
+    - ``held``: the leaves hold experts ``[0, held)`` of the
+      ``n_experts`` routed over — one expert-parallel rank's share of
+      the layer, computed by itself (dropless, no ``expert`` axis):
+      the returned ``y`` is the held experts' part of every token's
+      sum, and ``aux["f"]`` stays the pick fractions over ALL experts.
+      With ``held < n_experts`` the gates carry no gradient to the
+      router (below): a share by itself holds it.
 
     Returns ``(y [B, T_loc, D], aux)`` with ``aux = {"lb": load
     balance loss, "z": router z-loss, "f": [E] pick fractions, "p":
@@ -409,15 +500,30 @@ def moe_ffn(
 
     ep = lax.axis_size(expert_axis) if expert_axis is not None else 1
     assert e % ep == 0, f"n_experts {e} must divide by ep {ep}"
-    assert we_gate.shape[0] == e // ep, (
-        f"expert leaf holds {we_gate.shape[0]} experts, expected "
-        f"{e}/{ep} = {e // ep}"
-    )
+    if held is None:
+        assert we_gate.shape[0] == e // ep, (
+            f"expert leaf holds {we_gate.shape[0]} experts, expected "
+            f"{e}/{ep} = {e // ep}"
+        )
+    else:
+        assert capacity_factor is None and ep == 1, (
+            "a held range of the experts runs dropless and by itself "
+            "(capacity_factor=None, no expert axis)"
+        )
+        assert we_gate.shape[0] == held <= e, (we_gate.shape, held, e)
 
     with jax.named_scope("moe_route"):
         gates, eidx, probs, logits = router_topk(
-            x2, w_router, top_k, renormalize
+            x2, w_router, top_k, renormalize, scoring=scoring,
+            select_bias=select_bias, scale=route_scale,
         )
+        if held is not None and held < e:
+            # on a rank of a group the gates' gradient comes back
+            # from all ``e`` experts; of a share by itself only from
+            # the held ones, and every such gradient says "prefer
+            # these" (two thirds of the picks within 70 steps: PERF.md,
+            # PR 37).  A part of that gradient is worse than none
+            gates = lax.stop_gradient(gates)
         f, p = aux_moments(eidx, probs, e, batch_axes)
         aux = {
             "f": f,
@@ -433,7 +539,7 @@ def moe_ffn(
         )
         y = _dropless_experts(
             x2, gates, eidx, we_gate, we_up, we_down,
-            n_experts=e, model_axis=model_axis,
+            n_experts=e, model_axis=model_axis, held=held,
         )
         dropped = jnp.zeros((), jnp.float32)    # by construction
     else:

@@ -462,8 +462,10 @@ def exits_unembed_xent(xs, w, labels, row_w, vocab, axis_name):
     the backward scales them by the scalar cotangent.
 
     xs: [R, N, D] exits (compute dtype), w: [D, V_loc] (fp32 master,
-    cast to compute dtype once a call), labels: [N] GLOBAL int ids,
-    row_w: [R, N] fp32.  Statistics, operands, dtypes, accumulation
+    cast to compute dtype once a call), labels: [N] GLOBAL int ids
+    that every exit is held to, or [R, N], a row of them an exit (a
+    multi-token-prediction module's exit is held to the token after
+    next), row_w: [R, N] fp32.  Statistics, operands, dtypes, accumulation
     and sharding semantics of ``dense_unembed_xent``, ``row_w[t]``
     where its loss vector's cotangent stood.
     Returns (total = sum(row_w * xent), xent [R, N] fp32, pred [R, N]
@@ -487,9 +489,15 @@ def _exits_head_loop(xs, w, labels, row_w, vocab, axis_name, with_grads):
     local = labels - off
     hit = (local >= 0) & (local < v_loc)
     safe = jnp.clip(local, 0, v_loc - 1)
+    own_labels = labels.ndim == 2       # [R, N]: a row of them an exit
+    exit_labels = (hit, safe)
 
     def body(dw, exit_):
-        x2, rw = exit_
+        if own_labels:
+            x2, rw, hit, safe = exit_
+        else:
+            x2, rw = exit_
+            hit, safe = exit_labels
         lg = x2 @ wc                                # [N, V_loc], bf16
         # _dense_head_fwd_impl's statistics and values, each read
         # from the compute-dtype logits themselves: its fp32 copy of
@@ -540,7 +548,9 @@ def _exits_head_loop(xs, w, labels, row_w, vocab, axis_name, with_grads):
         # even where ``w`` is replicated over it)
         vma = _carry_vma(xs, w, local, row_w)
         dw0 = _vary(jnp.zeros(w.shape, jnp.float32), vma)
-    dw, (xent, pred, dx) = lax.scan(body, dw0, (xs, row_w))
+    dw, (xent, pred, dx) = lax.scan(
+        body, dw0, (xs, row_w, *exit_labels) if own_labels else (xs, row_w)
+    )
     return xent, pred, dx, dw
 
 

@@ -142,6 +142,18 @@ class LlamaDecoder:
                 "build_model() + compile_iter_fns() (then load() for "
                 "checkpoint weights) before serving"
             )
+        if (model.attention == "mla" or model.moe_shared_experts
+                or model.mtp_depth):
+            raise NotImplementedError(
+                "serving has one attention path and one cache: latent "
+                f"attention (attention={model.attention!r}: a paged "
+                "cache of the compressed latent and the shared rotary "
+                "key, and prefill and decode paths of their own), "
+                f"shared experts (moe_shared_experts="
+                f"{model.moe_shared_experts}) and a multi-token-"
+                f"prediction module (mtp_depth={model.mtp_depth}: "
+                "speculative drafts from it) are not yet servable"
+            )
         if (model.pp > 1 or model.sp > 1 or model.n_experts
                 or model.qk_norm):
             raise NotImplementedError(

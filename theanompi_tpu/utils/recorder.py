@@ -271,13 +271,19 @@ class Recorder:
         self._pending.append((loss, err))
         self.n_iter += int(np.shape(loss)[0]) if np.ndim(loss) else 1
 
-    def moe_routing(self, routing, picks: int) -> None:
+    def moe_routing(self, routing, picks: int, *, held: int | None = None,
+                    bias_abs_max=None) -> None:
         """A MoE step's (or K-step chunk's) routing counters
         ``[(K,) L, E+1]``, as :meth:`train_error` takes the loss:
         a device value, its copy to the host started here and read at
-        the next fence.  Only the newest step's are kept."""
+        the next fence.  Only the newest step's are kept.  ``held``:
+        the layers' leaves hold experts ``[0, held)`` alone;
+        ``bias_abs_max [(K,) L]``: the largest size of each router's
+        selection bias after the step, a device value as well."""
         _start_host_copy(routing)
-        self._pending_routing = (routing, picks)
+        if bias_abs_max is not None:
+            _start_host_copy(bias_abs_max)
+        self._pending_routing = (routing, picks, held, bias_abs_max)
 
     def ut_exits(self, exits) -> None:
         """A looped decoder's exit counters ``[(K,) 2R + 1]`` of a
@@ -297,10 +303,14 @@ class Recorder:
         if self._pending_routing is not None:
             from theanompi_tpu.obs.routing import moe_counters
 
-            routing, picks = self._pending_routing
+            routing, picks, held, bias = self._pending_routing
             a = np.asarray(routing, np.float64)
+            if bias is not None:
+                bias = np.asarray(bias, np.float64)
+                bias = bias[-1] if bias.ndim == 2 else bias
             self.moe_counters = moe_counters(
-                a[-1] if a.ndim == 3 else a, picks
+                a[-1] if a.ndim == 3 else a, picks, held=held,
+                bias_abs_max=bias,
             )
             self._pending_routing = None
         for loss, err in self._pending:

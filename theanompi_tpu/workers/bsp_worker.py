@@ -605,6 +605,12 @@ def run(
         # the flash kernels' tiles for the model's attention shape
         # ({} where no such kernel runs)
         "flash_tiles": getattr(model, "flash_tiles", dict)(),
+        # the attention path, the experts an expert layer's leaves
+        # hold (None: all it routes over) and the depth of the
+        # multi-token-prediction module
+        "attention": getattr(model, "attention", None),
+        "experts_held": getattr(model, "moe_experts_held", None),
+        "mtp_depth": getattr(model, "mtp_depth", 0),
         "exchange_bucket_mb": exchange.bucket_mb,
         "exchange_replicas": getattr(model, "exchange_replicas", None),
         "exchange_buckets": getattr(model, "exchange_buckets", None),
