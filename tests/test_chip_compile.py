@@ -684,6 +684,60 @@ def test_held_share_step_compiles_with_its_kernels_and_scopes(
     assert sum("jvp(mtp)" in ln for ln in flash) == 3
 
 
+def test_latent_attention_hands_the_kernels_products_not_joins(
+    chip, monkeypatch
+):
+    """The same step: latent attention's projections cut and join the
+    WEIGHT ``wkv_b``, not the activations (PR 38).  No array of
+    ``qk_nope_head_dim + v_head_dim`` = 448 a head exists but the
+    weight-shaped ones (``wkv_b`` itself, its gradient and its Adam
+    state, ``[128, 2 * 448(, 1)]`` or ``[128, 2, 448]``): ``wkv_b``'s
+    product is never written whole and cut at lane 192, and its
+    gradient never re-assembled.  The three flash kernels a block
+    take the operands they took — q, k, v (and the gradients)
+    ``[B * H, T, 256]`` — and ``mla_proj``'s instructions, the
+    products among them, show in the forward, the replay and the
+    backward."""
+    import re
+
+    from benchmark.layer_metrics import _scopes
+
+    text = _llama_step_text(chip, monkeypatch, **_MLA_MOE)
+    rank, heads = _MLA_MOE["kv_lora_rank"], 2
+    width = _MLA_MOE["qk_nope_head_dim"] + _MLA_MOE["v_head_dim"]
+    joined = {
+        dims for dims in re.findall(r"\b(?:bf16|f32)\[([\d,]+)\]", text)
+        if {str(width), str(heads * width)} & set(dims.split(","))
+    }
+    assert joined and joined <= {
+        f"{rank},{heads * width}", f"{rank},{heads * width},1",
+        f"{rank},{heads},{width}"}, joined
+    assert _flash_kernels(text) == dict(fwd=3, dkv=3, dq=3)
+    operand = rf"bf16\[{2 * heads},256,256\]"       # [B * H, T, hd]
+    for ln in text.splitlines():
+        if "tpu_custom_call" in ln and "_flash_jit" in ln:
+            took = re.search(
+                r"operand_layout_constraints=\{((?:[^{}]|\{[^{}]*\})*)\}", ln
+            ).group(1)
+            # fwd: q k v; dK/dV and dQ: q k v and the output's
+            # gradient; the float32 statistics beside them
+            assert len(re.findall(operand, took)) in (3, 4), took
+            assert set(re.findall(r"bf16\[[\d,]+\]", took)) == {
+                f"bf16[{2 * heads},256,256]"}, took
+    names = _scopes._under(text, "mla_proj")
+    products = _step_blocks(text)[3]
+    phases, product_phases = set(), set()
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", ln)
+        if m and m.group(1) in names:
+            phase = ("replay" if "rematted_computation" in ln
+                     else "bwd" if "transpose(" in ln else "fwd")
+            phases.add(phase)
+            if m.group(1) in products:
+                product_phases.add(products[m.group(1)]["phase"])
+    assert phases == product_phases == {"fwd", "replay", "bwd"}
+
+
 # three small decoders that take the paths of the Mistral, OLMoE and
 # Ouro cells: knobs, the layer calls that keep their MLP products
 _OLDER_DECODERS = {

@@ -29,8 +29,9 @@ from jax.sharding import PartitionSpec as P
 
 from benchmark.reference import glm_moe_lite as ref
 from benchmark.run import program_knobs
-from theanompi_tpu.models.llama import Llama
+from theanompi_tpu.models.llama import Llama, _heads, rms_norm, rope, rope_tail
 from theanompi_tpu.parallel import make_mesh, moe
+from theanompi_tpu.parallel import tp as tp_lib
 from theanompi_tpu.utils import Recorder
 
 LOSS_RTOL = 1e-6
@@ -234,6 +235,131 @@ def test_mla_layer_forward_and_gradients():
     want, want_g = jax.value_and_grad(reference, argnums=(0, 1))(lp, x)
     assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want)) + 1e-5
     assert_grads_close(jax.device_get(got_g), want_g)
+
+
+def _mla_qkv_sliced(model, p, xn, pos):
+    """The oracle: latent attention's projections as ``_mla_qkv`` built
+    them until PR 38, by slicing, rotating and re-joining the
+    ACTIVATIONS — ``wkv_b``'s product one ``[.., nope + v]`` array cut
+    at ``nope``, RoPE on a slice of q, the rotary key broadcast over
+    the heads and concatenated in."""
+    eps, theta = model.norm_eps, model.rope_theta
+    h, nope, rank = model.n_heads, model.qk_nope_head_dim, model.kv_lora_rank
+    cq = rms_norm(xn @ p["wq_a"].astype(xn.dtype), p["q_a_norm"], eps)
+    q = _heads(cq @ p["wq_b"].astype(xn.dtype), h, model.head_dim)
+    q = jnp.concatenate(
+        [q[..., :nope], rope(q[..., nope:], pos, theta)], axis=-1)
+    ckv = xn @ p["wkv_a"].astype(xn.dtype)
+    k_rope = rope(ckv[:, None, :, rank:], pos, theta)
+    ckv = rms_norm(ckv[..., :rank], p["kv_a_norm"], eps)
+    kv = _heads(ckv @ p["wkv_b"].astype(xn.dtype), h, nope + model.v_head_dim)
+    k = jnp.concatenate([
+        kv[..., :nope],
+        jnp.broadcast_to(k_rope, (*kv.shape[:3], model.qk_rope_head_dim)),
+    ], axis=-1)
+    return q, k, kv[..., nope:]
+
+
+_MLA_LEAVES = ("wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_a_norm", "wkv_b")
+
+
+@pytest.mark.parametrize("dtype, tol", [("float32", GRAD_TOL),
+                                        ("bfloat16", 2e-2)])
+def test_mla_operands_equal_the_sliced_form(dtype, tol):
+    """``_mla_qkv`` writes the kernels' operands from products over
+    cuts of the WEIGHTS; the sliced form above is the same
+    mathematics.  q, k, v and, under random cotangents, the gradients
+    of ``xn`` and of all six leaves; in bfloat16 k's rotary part (one
+    input times 1, accumulated in float32) and v (the same product,
+    column for column) equal the oracle's bit for bit."""
+    model, params, x, _ = _small_layer(compute_dtype=dtype)
+    lp = {name: params["layers"][0][name] for name in _MLA_LEAVES}
+    xn = x.astype(dtype)
+    pos = jnp.arange(x.shape[1])
+    cts = [jax.random.normal(jax.random.key(7 + i),
+                             (1, model.n_heads, x.shape[1], model.head_dim))
+           for i in range(3)]
+
+    def run(form):
+        def scalar(lp, xn):
+            qkv = form(lp, xn)
+            total = sum(jnp.sum(a.astype(jnp.float32) * c)
+                        for a, c in zip(qkv, cts))
+            return total, qkv
+        return _in_shard_map(
+            model, lambda lp, xn: jax.value_and_grad(
+                scalar, argnums=(0, 1), has_aux=True)(lp, xn), lp, xn)
+
+    (_, got), got_g = run(lambda lp, xn: model._mla_qkv(lp, xn, pos))
+    (_, want), want_g = run(
+        lambda lp, xn: _mla_qkv_sliced(model, lp, xn, pos))
+    nope = model.qk_nope_head_dim
+    for name, g, w in zip("qkv", got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype == jnp.dtype(dtype)
+        g, w = (np.asarray(a, np.float32) for a in (g, w))
+        assert np.abs(g - w).max() <= tol * np.abs(w).max(), name
+    if dtype == "bfloat16":
+        np.testing.assert_array_equal(
+            np.asarray(got[1], np.float32)[..., nope:],
+            np.asarray(want[1], np.float32)[..., nope:])
+        np.testing.assert_array_equal(
+            np.asarray(got[2], np.float32), np.asarray(want[2], np.float32))
+    assert set(got_g[0]) == set(_MLA_LEAVES)
+    as_f32 = lambda tree: jax.tree.map(
+        lambda a: np.asarray(a, np.float32), jax.device_get(tree))
+    assert_grads_close(as_f32(got_g), as_f32(want_g), tol)
+
+
+@pytest.mark.parametrize("nope", [0, 12, 16])
+def test_rope_tail_is_rope_on_the_tail(nope):
+    """One pass over the whole row against ``rope`` on a slice, joined
+    back: values, and the gradient — the rotation by the negative
+    angle — against autodiff of the sliced form."""
+    x = jax.random.normal(jax.random.key(11), (2, 3, 8, 16), jnp.float32)
+    ct = jax.random.normal(jax.random.key(12), x.shape, jnp.float32)
+    pos = jnp.arange(5, 13)
+
+    def sliced(x):
+        return jnp.concatenate(
+            [x[..., :nope], rope(x[..., nope:], pos, 1e4)], axis=-1)
+
+    got, got_vjp = jax.vjp(lambda x: rope_tail(x, pos, 1e4, nope), x)
+    want, want_vjp = jax.vjp(sliced, x)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(got[..., :nope], x[..., :nope])
+    np.testing.assert_allclose(
+        got_vjp(ct)[0], want_vjp(ct)[0], rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("tp", [1, 2])
+def test_col_parallel_heads_is_col_parallel_then_heads(tp):
+    """``tp.col_parallel_heads`` against ``col_parallel`` and the head
+    transpose, under the vma-checked ``shard_map`` the step uses:
+    values and both gradients (``x`` replicated over the model axis,
+    ``w`` split by whole heads)."""
+    heads, hd = 4, 8
+    x = jax.random.normal(jax.random.key(13), (2, 16, 24), jnp.float32)
+    w = jax.random.normal(jax.random.key(14), (24, heads * hd), jnp.float32)
+    ct = jax.random.normal(jax.random.key(15), (2, heads, 16, hd))
+    mesh = make_mesh(devices=jax.devices()[:tp], model=tp)
+    col = P(None, "model")
+
+    def run(form):
+        def scalar(x, w, ct):
+            return jax.lax.psum(jnp.sum(form(x, w) * ct), "model")
+        return jax.jit(jax.shard_map(
+            jax.value_and_grad(scalar, argnums=(0, 1)), mesh=mesh,
+            in_specs=(P(), col, P(None, "model")),
+            out_specs=(P(), (P(), col)),
+        ))(x, w, ct)
+
+    got, got_g = run(
+        lambda x, w: tp_lib.col_parallel_heads(x, w, heads // tp))
+    want, want_g = run(
+        lambda x, w: _heads(tp_lib.col_parallel(x, w), heads // tp, hd))
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for g, w_ in zip(got_g, want_g):
+        np.testing.assert_allclose(g, w_, rtol=1e-5, atol=1e-5)
 
 
 def test_one_rotary_key_for_all_heads():

@@ -57,12 +57,14 @@ modelclass='Llama')`` trains it.
 
 from __future__ import annotations
 
+import functools
 import itertools
 import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -182,6 +184,59 @@ def _unheads(x):
     return x.transpose(0, 2, 1, 3).reshape(b, t, n * d)
 
 
+def _rotate_tail(x, pos, theta, nope, sign):
+    """``x [B, H, T, D]`` with the channels from ``nope`` on rotated
+    by ``sign`` times RoPE's angles, in one pass over the whole row:
+    ``x * cos + swap(x) * sin`` with cos 1 / sin 0 on the first
+    ``nope`` channels and ``swap`` the signed exchange of each rotary
+    pair, ``(x1, x2) -> (-x2, x1)``: a constant ``[D, D]`` product on
+    the matrix unit (one input times +-1: exact in ``x``'s dtype)."""
+    d = x.shape[-1]
+    r = d - nope
+    inv = 1.0 / (theta ** (jnp.arange(0, r, 2, dtype=jnp.float32) / r))
+    ang = pos.astype(jnp.float32)[:, None] * inv[None, :]    # [T, r/2]
+    ang = jnp.repeat(ang, 2, axis=-1)         # a pair shares its angle
+    cos = jnp.pad(jnp.cos(ang), ((0, 0), (nope, 0)), constant_values=1.0)
+    sin = jnp.pad(sign * jnp.sin(ang), ((0, 0), (nope, 0)))
+    swap = np.zeros((d, d), np.float32)
+    even = np.arange(nope, d, 2)
+    swap[even + 1, even] = -1.0               # (x @ swap)[2i] = -x[2i+1]
+    swap[even, even + 1] = 1.0                # (x @ swap)[2i+1] = x[2i]
+    swapped = jnp.matmul(
+        x, jnp.asarray(swap, x.dtype), precision=lax.Precision.HIGHEST
+    )
+    return (
+        x.astype(jnp.float32) * cos + swapped.astype(jnp.float32) * sin
+    ).astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def rope_tail(x, pos, theta, nope):
+    """``rope`` on the channels of ``x [B, H, T, D]`` from ``nope``
+    on, the first ``nope`` as they are (latent attention's q: a
+    no-position part and a rotary part in one row), WITHOUT the slice
+    at ``nope``, ``rope``'s stride-2 lane slices and the
+    concatenation back (``_rotate_tail``): on the chip ``rope`` on the
+    slice, joined back, took 2.0 ms of a ``[2, 20, 8192, 256]`` row's
+    3.6 ms projection and this pass takes 0.7 (PERF.md §6, PR 38).
+    The rotation in float32, as ``rope`` does it.  Backward: a
+    rotation's transpose is the rotation by the negative angle — the
+    same pass over the gradient, rounded once, where autodiff's form
+    rounds ``dy * sin`` before the swap."""
+    return _rotate_tail(x, pos, theta, nope, 1.0)
+
+
+def _rope_tail_fwd(x, pos, theta, nope):
+    return _rotate_tail(x, pos, theta, nope, 1.0), pos
+
+
+def _rope_tail_bwd(theta, nope, pos, dy):
+    return _rotate_tail(dy, pos, theta, nope, -1.0), None
+
+
+rope_tail.defvjp(_rope_tail_fwd, _rope_tail_bwd)
+
+
 class Llama(TMModel):
     """Contract-conforming Llama-style causal LM.
 
@@ -205,8 +260,13 @@ class Llama(TMModel):
     distribution less ``exit_beta`` times its entropy
     (``_exit_loss``).  ``attention: "mla"`` is latent attention
     (``q_lora_rank, kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim,
-    v_head_dim``; ``_mla_qkv``); ``first_k_dense`` leading layers of an
-    expert model are dense SwiGLUs of ``dense_ffn_dim``;
+    v_head_dim``; ``_mla_qkv``): q, k and v each leave a product in
+    the attention kernels' layout (``tp.col_parallel_heads``) — v and
+    k over column cuts of the weight ``wkv_b``, k's with an identity
+    block that carries the one rotary key into every head, q rotated
+    in one pass over its whole row (``rope_tail``) — so no activation
+    is sliced, broadcast or concatenated; ``first_k_dense`` leading
+    layers of an expert model are dense SwiGLUs of ``dense_ffn_dim``;
     ``moe_scoring: "sigmoid"`` with ``moe_route_scale`` and
     ``moe_bias_rate`` is the router whose selection bias is state
     (``net_state``); ``moe_shared_experts`` adds a dense expert every
@@ -796,29 +856,52 @@ class Llama(TMModel):
         ``wq_b``; k's no-position part and v through ``wkv_a``, an
         RMSNorm and ``wkv_b``; RoPE on the last ``qk_rope_head_dim``
         of a head's q and on the ONE rotary key vector a token, which
-        ``wkv_a`` gives beside the latent and every head shares."""
+        ``wkv_a`` gives beside the latent and every head shares.
+
+        Every operand leaves as the output of a product
+        (``tp.col_parallel_heads``), q through one more pass
+        (``rope_tail``): what is cut and joined is the WEIGHT
+        ``wkv_b`` (``[rank, H_loc, nope + v]``: 18 MB at the
+        published widths), not the 168-294 MB activations.  v is the
+        latent times the weight's value columns.  k is
+        ``[latent | rotary key] [B, T, rank +
+        rope]`` times ``[[W_nope, 0], [0, I]]``: rows ``[0, rank)``
+        carry the weight's no-position columns into each head's first
+        ``nope`` channels, rows ``[rank, rank + rope)`` an identity
+        into each head's last ``rope`` — one input times 1,
+        accumulated in float32, so the rotary part equals a broadcast
+        bit for bit; the matrix unit does the broadcast over the heads
+        and the join, and the backward's transposed product returns
+        the rotary key's gradient already summed over the heads."""
         eps, theta = self.norm_eps, self.rope_theta
         h_loc = self.n_heads // self.tp
         nope, rank = self.qk_nope_head_dim, self.kv_lora_rank
+        rope_dim = self.qk_rope_head_dim
         with jax.named_scope("mla_proj"):
             cq = rms_norm(xn @ p["wq_a"].astype(xn.dtype), p["q_a_norm"], eps)
-            q = _heads(tp_lib.col_parallel(cq, p["wq_b"]), h_loc,
-                       self.head_dim)
-            q = jnp.concatenate(
-                [q[..., :nope], rope(q[..., nope:], pos, theta)], axis=-1
+            q = rope_tail(
+                tp_lib.col_parallel_heads(cq, p["wq_b"], h_loc),
+                pos, theta, nope,
             )
             ckv = xn @ p["wkv_a"].astype(xn.dtype)
-            k_rope = rope(ckv[:, None, :, rank:], pos, theta)
+            k_rope = rope(ckv[:, None, :, rank:], pos, theta)[:, 0]
             ckv = rms_norm(ckv[..., :rank], p["kv_a_norm"], eps)
-            kv = _heads(tp_lib.col_parallel(ckv, p["wkv_b"]), h_loc,
-                        nope + self.v_head_dim)
-            k = jnp.concatenate([
-                kv[..., :nope],
-                jnp.broadcast_to(
-                    k_rope, (*kv.shape[:3], self.qk_rope_head_dim)
+            w = p["wkv_b"].reshape(rank, h_loc, nope + self.v_head_dim)
+            w_k = jnp.concatenate([
+                jnp.pad(w[..., :nope], ((0, 0), (0, 0), (0, rope_dim))),
+                jnp.pad(
+                    jnp.broadcast_to(
+                        jnp.eye(rope_dim, dtype=w.dtype)[:, None],
+                        (rope_dim, h_loc, rope_dim),
+                    ),
+                    ((0, 0), (0, 0), (nope, 0)),
                 ),
-            ], axis=-1)
-            return q, k, kv[..., nope:]
+            ])
+            k = tp_lib.col_parallel_heads(
+                jnp.concatenate([ckv, k_rope], axis=-1), w_k, h_loc
+            )
+            v = tp_lib.col_parallel_heads(ckv, w[..., nope:], h_loc)
+            return q, k, v
 
     def _layer(self, p, x, pos, select_bias=None):
         """One decoder block on local shards: x [B, T_loc, D]; an

@@ -32,6 +32,47 @@ def col_parallel(x, w, axis_name: str = MODEL_AXIS):
     return x @ w.astype(x.dtype)
 
 
+def col_parallel_heads(x, w, n_heads: int):
+    """``col_parallel`` for an attention operand: ``x [B, T, D]`` times
+    the ``n_heads`` local heads' columns ``w [D, n_heads * hd]`` (or
+    ``[D, n_heads, hd]``) -> ``[B, n_heads, T, hd]``, the layout the
+    attention kernels take, WITHOUT a head transpose in either
+    direction.  Forward: exactly ``(x @ w).reshape(B, T, n_heads,
+    hd).transpose(0, 2, 1, 3)``, which XLA:TPU lowers to one product
+    that writes ``[B, n_heads, T, hd]``.  Backward: autodiff's two
+    products of that expression, the same values in the same dtype,
+    written as einsums over ``dy [B, n_heads, T, hd]``; autodiff's own
+    form (``dy`` transposed and flattened to ``[B, T, n_heads * hd]``
+    first) makes XLA relay every ``dy`` through a copy: 0.4 ms for
+    each of q, k and v a block at ``[2, 20, 8192, 256]`` (PERF.md §6,
+    PR 38)."""
+    return _heads_product(
+        x, w.reshape(w.shape[0], n_heads, -1).astype(x.dtype)
+    )
+
+
+@jax.custom_vjp
+def _heads_product(x, w):
+    b, t, _ = x.shape
+    r, h, d = w.shape
+    y = (x @ w.reshape(r, h * d)).reshape(b, t, h, d)
+    return y.transpose(0, 2, 1, 3)
+
+
+def _heads_product_fwd(x, w):
+    return _heads_product(x, w), (x, w)
+
+
+def _heads_product_bwd(res, dy):
+    x, w = res
+    dx = jnp.einsum("bhtd,rhd->btr", dy, w)
+    dw = jnp.einsum("btr,bhtd->rhd", x, dy)
+    return _reduce_ct_to_primal(dx, x), _reduce_ct_to_primal(dw, w)
+
+
+_heads_product.defvjp(_heads_product_fwd, _heads_product_bwd)
+
+
 def row_parallel(x, w, axis_name: str = MODEL_AXIS):
     """[..., F/tp] x [F/tp, D] -> [..., D] via partial matmul + psum."""
     return lax.psum(x @ w.astype(x.dtype), axis_name)
