@@ -738,6 +738,118 @@ def test_latent_attention_hands_the_kernels_products_not_joins(
     assert phases == product_phases == {"fwd", "replay", "bwd"}
 
 
+# -- window and full attention layers mixed (PR 41) ---------------------------
+
+
+def _window_kernels(text):
+    """``_flash_kernels`` for the window calls: the custom calls under
+    ``_flash_window_jit``, by what they return."""
+    return _flash_kernels("\n".join(
+        ln.replace("_flash_window_jit", "_flash_jit")
+        for ln in text.splitlines() if "_flash_window_jit" in ln
+    ))
+
+
+def test_cells_window_shape_compiles_to_three_kernels_of_their_own_name(chip):
+    """The ``mellum`` cell's window layers hand the kernels ``[2, 32,
+    8192, 128]`` bf16 under a window of 1024.  Forward and backward in
+    one program compile for the v5e to exactly three custom calls
+    under ``_flash_window_jit`` — and none whose line holds
+    ``_flash_jit``, the name ``flash_attention_roofline`` holds every
+    call it matches to the causal triangle's count by — told apart by
+    what they return as the full calls are."""
+    from benchmark import hlo_read
+    from benchmark.layer_metrics.flash_attention_roofline import kernel_kind
+
+    x = jax.ShapeDtypeStruct((2, 32, 8192, 128), jnp.bfloat16, sharding=chip)
+
+    def both(q, k, v):
+        out, vjp = jax.vjp(
+            lambda *a: flash_attention_tpu(*a, causal=True, window=1024),
+            q, k, v,
+        )
+        return out, vjp(out)
+
+    text = _compiled_text(both, x, x, x)
+    calls = hlo_read.custom_calls(text)
+    assert len(calls) == 3
+    assert not any("_flash_jit" in line for line in calls.values())
+    kinds = sorted(
+        kernel_kind(line) for line in calls.values()
+        if "_flash_window_jit" in line
+    )
+    assert kinds == ["dkv", "dq", "fwd"], kinds
+    assert _window_kernels(text) == dict(fwd=1, dkv=1, dq=1)
+    assert "f32[64,8192,1]" not in text
+
+
+# two layers, one of each kind, heads of 128 over a width of 256 (4 x
+# 128 = 512: the projections are not square), a window under T, a
+# rotary table a kind, 2 of 8 softmax-routed experts held
+_WINDOW_MOE = dict(
+    n_layers=2, n_heads=4, n_kv_heads=2, head_dim=128,
+    layer_types=["sliding_attention", "full_attention", "sliding_attention"],
+    sliding_window=128,
+    rope_parameters={
+        "full_attention": {
+            "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+            "original_max_position_embeddings": 64, "beta_fast": 32,
+            "beta_slow": 1, "attention_factor": 1.2772588722239782},
+        "sliding_attention": {"rope_type": "default", "rope_theta": 500000},
+    },
+    n_experts=8, moe_top_k=2, ffn_dim=256, capacity_factor=None,
+    moe_experts_held=2, moe_aux_coef=0.001,
+)
+
+
+def test_mixed_attention_step_compiles_with_each_kinds_kernels_and_scopes(
+    chip, monkeypatch
+):
+    """The cell's kind of step compiled for the v5e: the full layer's
+    three flash kernels under ``_flash_jit`` and the window layer's
+    three under ``_flash_window_jit`` (no fourth: the remat keeps both
+    kinds' forward outputs), each against operands of the PUBLISHED
+    head dim; every kernel, window or full, forward and backward,
+    carries ``blk_attn`` and its kind's scope in its own ``op_name``
+    (the benchmark's block join knows no ``window_attention`` kernel
+    and reads the block there); ``attn_sliding`` and ``attn_full``
+    hold instructions in the forward, the replay and the backward;
+    every block named."""
+    import re
+
+    from benchmark.layer_metrics import _scopes
+
+    text = _llama_step_text(chip, monkeypatch, **_WINDOW_MOE)
+    assert _flash_kernels(text) == dict(fwd=1, dkv=1, dq=1)
+    assert _window_kernels(text) == dict(fwd=1, dkv=1, dq=1)
+    flash = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "_flash" in ln]
+    assert len(flash) == 6
+    assert all(re.search(r"bf16\[8,256,128\]", ln) for ln in flash), flash
+    for ln in flash:
+        op_name = re.search(r'op_name="([^"]*)"', ln).group(1)
+        scope = ("attn_sliding" if "_flash_window_jit" in ln
+                 else "attn_full")
+        assert "blk_attn" in op_name and scope in op_name, op_name
+        assert ("_flash_jit" in ln) != ("_flash_window_jit" in ln)
+    have, top, kernels, products = _step_blocks(text)
+    for block in ("blk_attn", "blk_ffn"):
+        assert {(block, ph) for ph in ("fwd", "replay", "bwd")} <= have
+    assert "other" not in {e["block"] for e in kernels.values()}
+    assert "other" not in {e["block"] for e in products.values()}
+    for scope in ("attn_sliding", "attn_full"):
+        names = _scopes._under(text, scope)
+        lines = [ln for ln in text.splitlines()
+                 if (m := re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", ln))
+                 and m.group(1) in names]
+        seen = {"replay" if "rematted_computation" in ln
+                else "bwd" if "transpose(" in ln else "fwd" for ln in lines}
+        assert seen == {"fwd", "replay", "bwd"}, (scope, seen)
+        # three kernels a kind lie under its scope
+        assert sum("tpu_custom_call" in ln and "_flash" in ln
+                   for ln in lines) == 3, scope
+
+
 # three small decoders that take the paths of the Mistral, OLMoE and
 # Ouro cells: knobs, the layer calls that keep their MLP products
 _OLDER_DECODERS = {

@@ -3,7 +3,11 @@ Pallas interpreter, over the tilings their shape function can choose:
 one block, several outer blocks, several score tiles inside a block —
 so that every case set holds a tile wholly under the diagonal (the
 body without a mask), one the diagonal crosses and one that is
-skipped — and the shape function itself.
+skipped — and the shape function itself.  Under a WINDOW (a query
+sees itself and the ``window - 1`` keys before it) the same kernels
+walk a band: the cases hold windows under a score tile's width, equal
+to it, between it and a row block, a multiple of the fetched block,
+and one no query's reach falls short of.
 
 Tolerances are those of ``tests/test_ring_attention.py``.
 """
@@ -18,13 +22,17 @@ from theanompi_tpu.ops.attention import (
     FlashPlan,
     FlashTiles,
     _auto_block,
+    _band,
+    _band_steps,
     _flash_bwd_call,
     _flash_fwd_call,
     _flash_tiles,
     _sub_block_kind,
     _walked_index,
+    flash_attention_tpu,
     flash_tiles_summary,
     mha_reference,
+    walked_tiles,
 )
 
 B, H = 1, 2
@@ -63,28 +71,94 @@ def test_case_sets_hold_every_kind_of_tile():
         assert _kinds(t_k, t_q, on_k, False) == {"clear", "crossed", "skipped"}, name
 
 
-@pytest.mark.parametrize("tiling", TILINGS, ids=str)
-def test_a_skipped_step_names_a_block_that_exists(tiling):
+# windows against the tilings' score tiles (16 or 32 wide) and row
+# blocks: under a tile, a tile, between tile and rows, a multiple of
+# the fetched block, and past every query's reach
+WINDOWS = (5, 16, 24, 32, 200)
+
+
+def _band_cases():
+    """(tiling, window): every query keeps a key to see (where the
+    queries outrun the keys, a window that reaches back to them)."""
+    for name, (t_q, t_k, _, _) in TILINGS.items():
+        for window in WINDOWS:
+            if t_q - t_k < window:
+                yield name, window
+        if t_q > t_k:
+            yield name, t_q - t_k + 8
+
+
+BAND_CASES = list(_band_cases())
+
+
+def test_band_cases_hold_every_kind_of_tile():
+    """Over the cases: a tile the band's lower edge crosses, one both
+    edges cross (a window under a tile's width), clear ones between
+    the edges and skipped ones on either side."""
+    seen = set()
+    for name, window in BAND_CASES:
+        t_q, t_k, on_q, _ = TILINGS[name]
+        rows, _, sub = on_q
+        i = np.arange(t_q)[:, None]
+        j = np.arange(t_k)[None, :]
+        for r in range(0, t_q, rows):
+            for lo in range(0, t_k, sub):
+                tile = np.s_[r:r + rows, lo:lo + sub]
+                diag = (j <= i)[tile]
+                edge = (i - j < window)[tile]
+                if not (diag & edge).any():
+                    seen.add("before" if not edge.any() else "after")
+                elif (diag & edge).all():
+                    seen.add("clear")
+                else:
+                    seen.add(("diag" if not diag.all() else "")
+                             + ("edge" if not edge.all() else ""))
+    assert seen == {"before", "after", "clear", "diag", "edge", "diagedge"}
+
+
+@pytest.mark.parametrize(
+    "tiling,window",
+    [(name, None) for name in TILINGS] + BAND_CASES, ids=str,
+)
+def test_a_skipped_step_names_a_block_that_exists(tiling, window):
     """The clamped index maps stay inside the walked axis (the
     interpreter would clamp a stray index itself; the chip's DMA would
-    not) and never name a block a step with work would not."""
+    not) and never name a block a step with work would not; under a
+    window a step past the band's last block names that block again
+    (already resident: nothing is fetched) and folds nothing."""
     t_q, t_k, on_q, on_k = TILINGS[tiling]
     for tiles, t_rows, t_walk, rows_are_queries in (
         (FlashTiles(*on_q), t_q, t_k, True), (FlashTiles(*on_k), t_k, t_q, False),
     ):
         n = t_walk // tiles.major
-        index = _walked_index(True, tiles, rows_are_queries, n)
+        index = _walked_index(True, tiles, rows_are_queries, n, window)
+        steps = n if window is None else _band_steps(
+            t_rows, t_walk, tiles, rows_are_queries, window)
+        assert 1 <= steps <= n
         for r in range(t_rows // tiles.rows):
-            for w in range(n):
-                got = int(index(r, w))
-                assert 0 <= got < n
+            first, last = (0, n - 1) if window is None else _band(
+                r, tiles, rows_are_queries, window, n)
+            assert 0 <= first <= last < n
+            with_work = set()
+            for w in range(n):          # over the WHOLE axis
                 kinds = [
                     _sub_block_kind(w * tiles.major + c, tiles.sub,
-                                    r * tiles.rows, tiles.rows, rows_are_queries)
+                                    r * tiles.rows, tiles.rows,
+                                    rows_are_queries, window)
                     for c in range(0, tiles.major, tiles.sub)
                 ]
                 if any(clear or crossed for clear, crossed in kinds):
-                    assert got == w          # a step with work fetches its own
+                    with_work.add(w)
+            # every block with work lies in the band, within the steps
+            assert with_work <= set(range(first, min(first + steps, last + 1)))
+            for w in range(steps):
+                got = int(index(r, w))
+                assert 0 <= got < n
+                block = w if window is None else first + w
+                if block in with_work:
+                    assert got == block      # a step with work fetches its own
+                elif window is not None:
+                    assert first <= got <= last
 
 
 def _operands(rng, t_q, t_k, d, dtype=jnp.float32):
@@ -93,33 +167,43 @@ def _operands(rng, t_q, t_k, d, dtype=jnp.float32):
     return draw(t_q), draw(t_k), draw(t_k), draw(t_q)
 
 
-def _dense(q, k, v, g, causal):
+def _dense(q, k, v, g, causal, window=None):
     """Output, logsumexp and the three gradients of dense attention."""
     out, vjp = jax.vjp(
-        lambda q, k, v: mha_reference(q, k, v, causal=causal), q, k, v
+        lambda q, k, v: mha_reference(q, k, v, causal=causal, window=window),
+        q, k, v,
     )
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * q.shape[-1] ** -0.5
     if causal:
         t_q, t_k = s.shape[-2:]
-        s = jnp.where(
-            jnp.arange(t_q)[:, None] >= jnp.arange(t_k)[None, :], s, -jnp.inf
-        )
+        i, j = jnp.arange(t_q)[:, None], jnp.arange(t_k)[None, :]
+        mask = i >= j if window is None else (i >= j) & (i - j < window)
+        s = jnp.where(mask, s, -jnp.inf)
     return (out, jax.nn.logsumexp(s, axis=-1)) + vjp(g)
 
 
-def _kernels(q, k, v, g, causal, on_q, on_k):
+def _kernels(q, k, v, g, causal, on_q, on_k, window=None):
     sm = q.shape[-1] ** -0.5
     out, lse = _flash_fwd_call(
-        q, k, v, causal, sm, FlashTiles(*on_q), True
+        q, k, v, causal, sm, FlashTiles(*on_q), True, window
     )
     delta = jnp.sum(
         g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
     )
     dq, dk, dv = _flash_bwd_call(
         q, k, v, g, lse, delta, causal, sm,
-        FlashTiles(*on_k), FlashTiles(*on_q), True,
+        FlashTiles(*on_k), FlashTiles(*on_q), True, window,
     )
     return out, lse, dq, dk, dv
+
+
+def _assert_close(got, want, label):
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), got, want):
+        tol = 2e-5 if name in ("out", "lse") else 2e-4
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=tol, atol=tol,
+            err_msg=f"{name} ({label})",
+        )
 
 
 @pytest.mark.parametrize("tiling", TILINGS, ids=str)
@@ -131,12 +215,70 @@ def test_kernels_match_dense_attention(rng, d, causal, tiling):
     got = _kernels(q, k, v, g, causal, on_q, on_k)
     want = _dense(q, k, v, g, causal)
     assert got[1].shape == (B, H, t_q)          # lane-dense at the edge
-    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), got, want):
-        tol = 2e-5 if name in ("out", "lse") else 2e-4
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=tol, atol=tol,
-            err_msg=f"{name} ({tiling})",
-        )
+    _assert_close(got, want, tiling)
+
+
+@pytest.mark.parametrize("tiling,window", BAND_CASES, ids=str)
+def test_window_kernels_match_dense_attention(rng, tiling, window):
+    """Forward and the three gradients under a window, over the band's
+    own grid axis, against dense attention with the explicit mask; a
+    window past every query's reach gives what no window gives."""
+    t_q, t_k, on_q, on_k = TILINGS[tiling]
+    q, k, v, g = _operands(rng, t_q, t_k, 64)
+    got = _kernels(q, k, v, g, True, on_q, on_k, window)
+    _assert_close(got, _dense(q, k, v, g, True, window), (tiling, window))
+    if window >= t_q:
+        _assert_close(got, _kernels(q, k, v, g, True, on_q, on_k),
+                      (tiling, "no window"))
+
+
+@pytest.mark.parametrize("tiling,window", BAND_CASES, ids=str)
+def test_the_walk_visits_exactly_the_bands_tiles(tiling, window):
+    """For each of the three kernels the score tiles the walk folds
+    (``walked_tiles``: the kernels' own ``_band`` and
+    ``_sub_block_kind`` on integers) are exactly those that hold a
+    visible pair of the explicit ``[T_q, T_k]`` mask — none outside
+    the band, none inside it missed — and the masked body goes to
+    exactly those the mask does not fill."""
+    t_q, t_k, on_q, on_k = TILINGS[tiling]
+    i, j = np.arange(t_q)[:, None], np.arange(t_k)[None, :]
+    mask = (j <= i) & (i - j < window)
+    for tiles, rows_are_queries in ((FlashTiles(*on_q), True),
+                                    (FlashTiles(*on_k), False)):
+        rows, _, sub = tiles
+        seen = mask if rows_are_queries else mask.T     # [rows axis, walked]
+        want = []
+        for r in range(seen.shape[0] // rows):
+            for lo in range(0, seen.shape[1], sub):
+                tile = seen[r * rows:(r + 1) * rows, lo:lo + sub]
+                if tile.any():
+                    want.append((r, lo, not tile.all()))
+        t_rows, t_walk = seen.shape
+        got = walked_tiles(t_rows, t_walk, tiles, rows_are_queries,
+                           True, window)
+        assert got == want
+
+
+def test_the_dispatch_takes_a_window_that_binds_and_drops_one_that_cannot(
+    rng,
+):
+    q, k, v, _ = _operands(rng, 64, 64, 64)
+    blocks = dict(block_q=16, block_k=16, interpret=True)
+    bound = flash_attention_tpu(q, k, v, window=24, **blocks)
+    np.testing.assert_allclose(
+        np.asarray(bound), np.asarray(mha_reference(q, k, v, window=24)),
+        rtol=2e-5, atol=2e-5)
+    # window >= T IS the plain causal call, under the plain name
+    text = jax.jit(
+        lambda *a: flash_attention_tpu(*a, window=64, **blocks)
+    ).lower(q, k, v).as_text()
+    assert "_flash_jit" in text and "_flash_window_jit" not in text
+    text = jax.jit(
+        lambda *a: flash_attention_tpu(*a, window=63, **blocks)
+    ).lower(q, k, v).as_text()
+    assert "_flash_window_jit" in text and "_flash_jit" not in text
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention_tpu(q, k, v, causal=False, window=8, **blocks)
 
 
 @pytest.mark.parametrize("tiling", ["one_block", "sub_tiles"], ids=str)
@@ -217,7 +359,7 @@ def test_summary_counts_the_masked_tiles(monkeypatch):
     got = flash_tiles_summary(4096, 4096, 128, "bfloat16")
     assert got["fwd"] == {
         # 8 row blocks see 1..8 tiles of 512 keys: 36, one crossed each
-        "outer": [512, 4096], "inner": [512, 512],
+        "outer": [512, 4096], "inner": [512, 512], "tiles": 36,
         "masked_share": round(8 / 36, 4),
     }
     # 8 key blocks are seen by 2, 4, .. 16 tiles of 256 queries, two
@@ -225,6 +367,29 @@ def test_summary_counts_the_masked_tiles(monkeypatch):
     assert got["dkv"]["masked_share"] == round(16 / 72, 4)
     # the parent's tiles: 4 of the 10 visited carry the diagonal
     assert got["dq"]["masked_share"] == 0.4
+
+
+def test_summary_counts_the_bands_tiles(monkeypatch):
+    """The cell's window layers, T 8192 and window 1024: a row block
+    of 512 folds three ``[512, 512]`` tiles (two crossed, one clear),
+    the first two blocks one and two; a fetched block is a tile."""
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    got = flash_tiles_summary(8192, 8192, 128, "bfloat16", window=1024)
+    for kernel in ("fwd", "dkv", "dq"):
+        assert got[kernel] == {
+            "outer": [512, 512], "inner": [512, 512],
+            "tiles": 1 + 2 + 14 * 3,
+            "masked_share": round((1 + 1 + 14 * 2) / 45, 4),
+        }, kernel
+    plan = _flash_tiles(8192, 8192, 128, "bfloat16", 1024)
+    assert _band_steps(8192, 8192, plan.fwd, True, 1024) == 3
+    assert _band_steps(8192, 8192, plan.dkv, False, 1024) == 3
+    # the full layer beside them walks the triangle: 136 tiles
+    full = flash_tiles_summary(8192, 8192, 128, "bfloat16")
+    assert full["fwd"]["tiles"] == 16 * 17 // 2
+    # a window no query's reach falls short of is no window
+    assert flash_tiles_summary(
+        8192, 8192, 128, "bfloat16", window=8192) == full
 
 
 def test_worker_summary_names_the_tiles(monkeypatch):

@@ -421,6 +421,27 @@ def test_shapes_tile_and_tiles_follow_shapes():
         assert c % tk == 0 and o % tn == 0
 
 
+@pytest.mark.parametrize("c, o, dtype, want", [
+    # the cells' shapes: OLMoE's and GLM's tile as PR 31 measured them
+    (2048, 1024, "bfloat16", (2048, 1024)),
+    (1024, 2048, "bfloat16", (1024, 2048)),
+    (2048, 1536, "bfloat16", (2048, 768)),
+    (1536, 2048, "bfloat16", (1536, 1024)),
+    # Mellum's 2304 x 896: 4.13 MB, the WHOLE block (a contraction cut
+    # in two re-reads the weights every visit: 39 % of the roofline
+    # against 84 %, PERF.md, PR 41), either way round
+    (2304, 896, "bfloat16", (2304, 896)),
+    (896, 2304, "bfloat16", (896, 2304)),
+    # past 4 MiB the old rule: the contraction whole up to 2048, then
+    # the output columns by bytes
+    (2304, 896, "float32", (1152, 896)),
+    (4096, 14336, "bfloat16", (2048, 1024)),
+], ids=str)
+def test_a_weight_block_is_whole_where_it_fits(c, o, dtype, want):
+    assert gm._rows_tiles(c, o, jnp.dtype(dtype)) == want
+    assert gm.shapes_tile(131072, c, o, jnp.dtype(dtype))
+
+
 def test_plan_refuses_rows_its_tile_does_not_divide():
     with pytest.raises(ValueError, match="do not divide"):
         gm.make_tile_plan(jnp.asarray([100, 100], jnp.int32), 200, 128)

@@ -152,6 +152,12 @@ PROFILE_SCOPES: dict[str, str] = {
     "mla_proj": "mla_proj",
     "moe_shared": "moe_shared",
     "mtp": "mtp",
+    # a layer's attention under its kind's name, inside ``blk_attn``,
+    # for a model described layer by layer (models/llama.py ``_gqa``,
+    # PR 41); benchmark/layer_metrics/attn_sliding_ms.py reads the
+    # window layers'
+    "attn_sliding": "attn_sliding",
+    "attn_full": "attn_full",
     # the step program's blocks (models/llama.py ``_forward`` /
     # ``_layer`` / ``loss_fn``, ops/layers.py, models/base.py, PR 35):
     # with ``opt_update`` and ``exchange_b<i>`` every instruction of a

@@ -146,16 +146,73 @@ def rms_norm(x, w, eps=1e-5, sharded_width=None):
     return (xf * scale).astype(x.dtype) * w.astype(x.dtype)
 
 
-def rope(x, pos, theta=10000.0):
-    """Rotary embedding. x: [B, H, T, D], pos: [T] global positions."""
+def rope(x, pos, theta=10000.0, inv_freq=None, factor=1.0):
+    """Rotary embedding. x: [B, H, T, D], pos: [T] global positions.
+    ``inv_freq`` ``[D/2]``: the pairs' frequencies where they are not
+    ``theta ** (-2i / D)`` (a scaled table: ``rope_table``); ``factor``
+    multiplies cos and sin (YaRN's attention factor)."""
     d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if inv_freq is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    else:
+        inv = jnp.asarray(inv_freq, jnp.float32)
     ang = pos.astype(jnp.float32)[:, None] * inv[None, :]    # [T, D/2]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if factor != 1.0:
+        cos, sin = factor * cos, factor * sin
     x1, x2 = x[..., 0::2], x[..., 1::2]
     y1 = x1 * cos - x2 * sin
     y2 = x1 * sin + x2 * cos
     return jnp.stack([y1, y2], axis=-1).reshape(x.shape).astype(x.dtype)
+
+
+def rope_table(params: dict, head_dim: int):
+    """``(inv_freq float32[head_dim / 2], factor)`` of one entry of a
+    published ``rope_parameters``: ``rope_type`` ``"default"`` is
+    ``f_i = rope_theta ** (-2i / head_dim)`` and factor 1; ``"yarn"``
+    (Peng et al. 2023, as the Hugging Face rotary utilities compute
+    it) blends each pair between ``f_i`` and ``f_i / factor`` by how
+    many turns it makes over ``original_max_position_embeddings``::
+
+        d(n) = head_dim ln(original / (2 pi n)) / (2 ln rope_theta)
+        lo, hi = floor(d(beta_fast)), ceil(d(beta_slow))
+        r_i = clip((i - lo) / (hi - lo), 0, 1)
+        w_i = (f_i / factor) r_i + f_i (1 - r_i)
+
+    with cos and sin times ``attention_factor`` (``0.1 ln(factor) +
+    1`` where none is given).  Static, at every length."""
+    kind = params.get("rope_type", "default")
+    theta = float(params["rope_theta"])
+    half = head_dim // 2
+    f = theta ** (-np.arange(half, dtype=np.float64) * 2 / head_dim)
+    if kind == "default":
+        return f.astype(np.float32), 1.0
+    if kind != "yarn":
+        raise NotImplementedError(
+            f"rope_type {kind!r}: the rotary tables here are 'default' "
+            f"and 'yarn'"
+        )
+    scale = float(params["factor"])
+    original = float(params["original_max_position_embeddings"])
+
+    def turns_dim(n):
+        return head_dim * math.log(original / (2 * math.pi * n)) / (
+            2 * math.log(theta)
+        )
+
+    lo = max(math.floor(turns_dim(float(params.get("beta_fast", 32)))), 0)
+    hi = min(math.ceil(turns_dim(float(params.get("beta_slow", 1)))),
+             head_dim - 1)
+    ramp = np.clip(
+        (np.arange(half) - lo) / max(hi - lo, 1e-3), 0.0, 1.0
+    )
+    factor = params.get("attention_factor")
+    if factor is None:
+        factor = 0.1 * math.log(scale) + 1.0
+    return (
+        ((f / scale) * ramp + f * (1 - ramp)).astype(np.float32),
+        float(factor),
+    )
 
 
 def rope_at(x, pos, theta=10000.0):
@@ -273,7 +330,14 @@ class Llama(TMModel):
     token goes through; ``moe_experts_held`` holds experts ``[0,
     held)`` alone (one expert-parallel rank's share by itself);
     ``mtp_depth: 1`` adds a multi-token-prediction module
-    (``mtp_coef``; ``_mtp_hidden``, ``_mtp_loss``).  These compose
+    (``mtp_coef``; ``_mtp_hidden``, ``_mtp_loss``).  ``head_dim``
+    where it is not ``dim // n_heads``; ``layer_types`` (the
+    published list: ``"full_attention"`` / ``"sliding_attention"`` a
+    layer, the first ``n_layers`` entries), ``sliding_window`` (a
+    query of a sliding layer sees itself and the ``sliding_window -
+    1`` keys before it) and ``rope_parameters`` (the published dict:
+    a rotary table a kind, ``rope_table``) describe the attention
+    layer by layer (``attn_kinds``, ``window_of``).  These compose
     with ``tp`` and data parallelism and are refused under ``pp``,
     ``sp`` and ``ut_steps > 1``.
     """
@@ -288,7 +352,9 @@ class Llama(TMModel):
         self.ffn_dim = int(c.get("ffn_dim", self.dim * 4))
         self.vocab = int(c.get("vocab", 256))
         self.seq_len = int(c.get("seq_len", 256))
-        self.head_dim = self.dim // self.n_heads
+        # a configuration value where the published one is not dim /
+        # n_heads (wq [dim, n_heads * head_dim], wo back to dim)
+        self.head_dim = int(c.get("head_dim", self.dim // self.n_heads))
         # "gqa": wq/wk/wv/wo at one head dim.  "mla": latent attention
         # (``_mla_qkv``): q and k/v each through a low-rank
         # down-projection with its own RMSNorm; a head's q and k are a
@@ -359,6 +425,48 @@ class Llama(TMModel):
             "moe" if self.n_experts and i >= self.first_k_dense else "dense"
             for i in range(self.n_layers)
         )
+        # the attention kind of every layer, beside ``layer_kinds``
+        # (the FFN's): the published ``layer_types`` — its first
+        # ``n_layers`` entries, for a stack cut in depth —
+        # ``"full_attention"`` or ``"sliding_attention"`` (a query sees
+        # itself and the ``sliding_window - 1`` keys before it); each
+        # kind rotates by its own entry of ``rope_parameters`` where
+        # that is given (``rope_table``), else by ``rope_theta``
+        types = c.get("layer_types")
+        self.attn_kinds = (
+            ("full_attention",) * self.n_layers if types is None
+            else tuple(str(t) for t in types[:self.n_layers])
+        )
+        window = c.get("sliding_window")
+        self.sliding_window = None if window is None else int(window)
+        self.rope_parameters = c.get("rope_parameters")
+        # the stack's attention is described layer by layer (as the
+        # published decoders with two kinds of layer do), not by
+        # ``rope_theta`` and the one causal call alone
+        self.attn_per_layer = not (
+            types is None and window is None and self.rope_parameters is None
+        )
+        unknown = set(self.attn_kinds) - {"full_attention",
+                                          "sliding_attention"}
+        if unknown or len(self.attn_kinds) != self.n_layers:
+            raise ValueError(
+                f"layer_types names each of the {self.n_layers} layers "
+                f"'full_attention' or 'sliding_attention'; got "
+                f"{len(self.attn_kinds)} entries, unknown {sorted(unknown)}"
+            )
+        if "sliding_attention" in self.attn_kinds and not self.sliding_window:
+            raise ValueError(
+                "layer_types has 'sliding_attention' layers: give "
+                "sliding_window, the keys a query sees (itself included)"
+            )
+        # (inv_freq, factor) a kind: (None, 1.0) is ``rope_theta``'s
+        self._rope_tables = {
+            kind: (
+                (None, 1.0) if self.rope_parameters is None
+                else rope_table(self.rope_parameters[kind], self.head_dim)
+            )
+            for kind in set(self.attn_kinds)
+        }
         # multi-token prediction: ``mtp_depth`` (0 or 1) more blocks
         # of the last layer's kind after the stack, which predict the
         # token after next from the stack's output and the next
@@ -410,7 +518,8 @@ class Llama(TMModel):
             self.opt_name, weight_decay=float(c.get("weight_decay", 0.0))
         )
 
-        assert self.attention == "mla" or self.dim % self.n_heads == 0
+        assert (self.attention == "mla" or "head_dim" in c
+                or self.dim % self.n_heads == 0)
         assert self.n_heads % self.n_kv_heads == 0, (
             "n_heads must be a multiple of n_kv_heads (GQA groups)"
         )
@@ -459,13 +568,23 @@ class Llama(TMModel):
                 ("first_k_dense", len(set(self.layer_kinds)) > 1),
                 ("mtp_depth", self.mtp_depth),
                 ("a selection bias", self.moe_select_bias),
+                ("layer_types (an attention kind, a window or a rotary "
+                 "table per layer)", self.attn_per_layer),
             ) if on
         ]
+        if self.attn_per_layer and self.attention == "mla":
+            raise NotImplementedError(
+                "attention: mla does not yet compose with layer_types, "
+                "sliding_window or rope_parameters: latent attention's "
+                "projections (_mla_qkv) rotate by the one rope_theta "
+                "and call the kernels without a window"
+            )
         if mixed and (self.pp > 1 or self.sp > 1 or self.ut_steps > 1):
             # pp stacks the layers' leaves on one leading dimension
             # (one kind of layer) and runs the head apart from them;
             # sp shards the positions the MTP labels shift over and
-            # has only been run with the one attention path
+            # has only been run with the one attention path (the ring
+            # and the all-to-all know no window and one rotary table)
             raise NotImplementedError(
                 f"{', '.join(mixed)} does not yet compose with "
                 f"pipeline parallelism, sequence parallelism or a "
@@ -742,12 +861,32 @@ class Llama(TMModel):
         the tiles ``ops.attention._flash_tiles`` chose for this
         model's attention shape and the share of visited score tiles
         that take the masked body; ``{}`` where attention takes the
-        dense path (off the TPU, or a length no block tiles)."""
+        dense path (off the TPU, or a length no block tiles).  A model
+        described layer by layer (``attn_per_layer``) gives one such
+        summary an attention kind, under the kind's name."""
         # ring attention hands the kernels one shard's length a hop
         t = self.seq_len // (self.sp if self.sp_mode == "ring" else 1)
-        return flash_tiles_summary(
-            t, t, self.head_dim, self.compute_dtype, causal=True
-        )
+
+        def tiles(kind):
+            return flash_tiles_summary(
+                t, t, self.head_dim, self.compute_dtype, causal=True,
+                window=self.window_of(kind),
+            )
+
+        if not self.attn_per_layer:
+            return tiles("full_attention")
+        # a model described layer by layer: a summary a kind
+        return {kind: tiles(kind) for kind in sorted(set(self.attn_kinds))}
+
+    def window_of(self, kind: str) -> int | None:
+        """The keys a query of a layer of ``kind`` sees, itself
+        included; None: all before it."""
+        return self.sliding_window if kind == "sliding_attention" else None
+
+    @property
+    def attention_kinds(self) -> dict:
+        """The run summary's ``"attention_kinds"``: layers of each."""
+        return {k: self.attn_kinds.count(k) for k in sorted(set(self.attn_kinds))}
 
     # -- what the per-layer remat keeps -----------------------------------
 
@@ -903,10 +1042,12 @@ class Llama(TMModel):
             v = tp_lib.col_parallel_heads(ckv, w[..., nope:], h_loc)
             return q, k, v
 
-    def _layer(self, p, x, pos, select_bias=None):
+    def _layer(self, p, x, pos, select_bias=None, *,
+               attn_kind="full_attention"):
         """One decoder block on local shards: x [B, T_loc, D]; an
         expert block where ``p`` holds a router (``select_bias``: its
-        row ``[E]`` of the selection bias, if the model has one).
+        row ``[E]`` of the selection bias, if the model has one);
+        ``attn_kind`` (static): the layer's entry of ``attn_kinds``.
 
         With MoE enabled returns ``(x, mom)`` where ``mom`` is the
         fp32 [2E+2] vector of this layer's aux-loss MOMENTS
@@ -928,7 +1069,7 @@ class Llama(TMModel):
                     q, k, v, causal=True, sm_scale=hd ** -0.5
                 )
             else:
-                o = self._gqa(p, xn, pos)
+                o = self._gqa(p, xn, pos, attn_kind)
             a = tp_lib.row_parallel(_unheads(o), p["wo"]).astype(cdtype)
             if self.sandwich_norm:
                 a = rms_norm(a, p["attn_out_norm"], eps)
@@ -980,11 +1121,26 @@ class Llama(TMModel):
                 y = rms_norm(y, p["mlp_out_norm"], eps)
             return x + y
 
-    def _gqa(self, p, xn, pos):
+    def _gqa(self, p, xn, pos, kind="full_attention"):
         """Grouped-query attention of ``xn [B, T_loc, D]`` (inside
         ``_layer``'s ``blk_attn``): the three projections, QK-norm,
         RoPE, the kernel or the sequence-parallel ring;
-        ``[B, H_loc, T_loc, hd]``."""
+        ``[B, H_loc, T_loc, hd]``.  ``kind``: the layer's attention
+        kind — its rotary table and, for ``"sliding_attention"``, the
+        window; a model described layer by layer (``attn_per_layer``)
+        runs each kind under a scope of its own, ``attn_full`` /
+        ``attn_sliding``."""
+        if self.attn_per_layer:
+            scope = (
+                jax.named_scope("attn_sliding")
+                if kind == "sliding_attention"
+                else jax.named_scope("attn_full")
+            )
+            with scope:
+                return self._gqa_kind(p, xn, pos, kind)
+        return self._gqa_kind(p, xn, pos, kind)
+
+    def _gqa_kind(self, p, xn, pos, kind):
         eps = self.norm_eps
         h_loc = self.n_heads // self.tp
         hkv_loc = self.n_kv_heads // self.tp
@@ -996,8 +1152,9 @@ class Llama(TMModel):
             k = rms_norm(k, p["k_norm"], eps, self.n_kv_heads * hd)
         q, k = _heads(q, h_loc, hd), _heads(k, hkv_loc, hd)
         v = _heads(tp_lib.col_parallel(xn, p["wv"]), hkv_loc, hd)
-        q = rope(q, pos, self.rope_theta)
-        k = rope(k, pos, self.rope_theta)
+        inv_freq, factor = self._rope_tables[kind]
+        q = rope(q, pos, self.rope_theta, inv_freq, factor)
+        k = rope(k, pos, self.rope_theta, inv_freq, factor)
         # GQA: KV stays compact on the wire; repeated only at compute
         rep = h_loc // hkv_loc
         if self.sp == 1:
@@ -1007,7 +1164,9 @@ class Llama(TMModel):
             if rep != 1:
                 k = jnp.repeat(k, rep, axis=1)
                 v = jnp.repeat(v, rep, axis=1)
-            return flash_attention(q, k, v, causal=True)
+            return flash_attention(
+                q, k, v, causal=True, window=self.window_of(kind)
+            )
         attn = (
             ring_attention if self.sp_mode == "ring"
             else ulysses_attention
@@ -1046,8 +1205,8 @@ class Llama(TMModel):
         with jax.named_scope("blk_embed"):
             x = tp_lib.embed_lookup(ids, params["embed"], self.vocab)
             x = x.astype(cdtype)
-        layer = kept_layer = self._layer
-        if self.remat:
+
+        def remat(fn, *names):
             # every call's replay skips the flash forward kernel: its
             # output and logsumexp (named in its forward rule,
             # ``ops/attention.py``) are kept, [B, H_loc, T, hd] and 4
@@ -1062,17 +1221,31 @@ class Llama(TMModel):
             # dense MLP's gate and up products; the norms, the
             # projections around the kernel, ``swiglu`` and the down
             # projection are replayed in every call.
-            def remat(*names):
-                return jax.checkpoint(
-                    self._layer,
-                    policy=jax.checkpoint_policies.save_only_these_names(
-                        *names
-                    ),
-                )
+            return jax.checkpoint(
+                fn,
+                policy=jax.checkpoint_policies.save_only_these_names(*names),
+            )
 
-            layer = kept_layer = remat(*self.remat_saves)
-            if self.remat_kept_calls:
-                kept_layer = remat(*self.remat_saves, *MLP_RESIDUALS)
+        kinds = set(self.attn_kinds)
+
+        def variants(kind):
+            """(the layer call of an attention kind, the call that
+            also keeps the MLP's products)."""
+            # a model of one plain kind calls the method itself
+            fn = self._layer if kinds == {"full_attention"} else (
+                functools.partial(self._layer, attn_kind=kind)
+            )
+            if not self.remat:
+                return fn, fn
+            plain = remat(fn, *self.remat_saves)
+            if not self.remat_kept_calls:
+                return plain, plain
+            return plain, remat(fn, *self.remat_saves, *MLP_RESIDUALS)
+
+        layers = {kind: variants(kind) for kind in kinds}
+        # the last layer's kind: the MTP block's, and the pipeline's
+        # (one kind of layer there)
+        layer = layers[self.attn_kinds[-1]][0]
 
         moe = "moe" in self.layer_kinds
         aux = jnp.zeros((2,), jnp.float32)
@@ -1089,8 +1262,10 @@ class Llama(TMModel):
 
             def stack(x, first_call=0):
                 moms = []
-                for call, p in enumerate(params["layers"], first_call):
-                    fn = kept_layer if call in kept else layer
+                for call, (p, kind) in enumerate(
+                    zip(params["layers"], self.attn_kinds), first_call
+                ):
+                    fn = layers[kind][call in kept]
                     if "router" in p:
                         x, mom = fn(p, x, pos, next(bias_rows))
                         moms.append(mom)

@@ -18,7 +18,9 @@ for the long-context configs).  Design:
   tiles, f32 accumulators in scratch, tiles chosen from the shapes by
   ``_flash_tiles``) with the same signature; ``mha_reference``
   off-TPU and at lengths no kernel block tiles (each such choice is
-  logged once per shape).
+  logged once per shape).  All three take a ``window`` beside the
+  causal mask (a query sees itself and the ``window - 1`` keys before
+  it): the kernels then walk the band alone.
 
 Shapes follow [B, H, T, D] (head-major, the TPU-friendly layout: the
 ``[Tq, D] x [D, Tk]`` score matmul and ``[Tq, Tk] x [Tk, D]`` value
@@ -48,9 +50,15 @@ NEG_INF = -1e30  # finite "-inf": keeps exp() NaN-free in masked blocks
 FLASH_RESIDUALS = ("flash_out", "flash_lse")
 
 
-def causal_mask(q_pos: jnp.ndarray, k_pos: jnp.ndarray) -> jnp.ndarray:
-    """[Tq, Tk] bool — query may attend to keys at <= its position."""
-    return q_pos[:, None] >= k_pos[None, :]
+def causal_mask(q_pos: jnp.ndarray, k_pos: jnp.ndarray,
+                window: int | None = None) -> jnp.ndarray:
+    """[Tq, Tk] bool — query may attend to keys at <= its position
+    and, under a ``window``, to the last ``window`` of them alone
+    (itself and the ``window - 1`` before it)."""
+    mask = q_pos[:, None] >= k_pos[None, :]
+    if window is not None:
+        mask &= q_pos[:, None] - k_pos[None, :] < window
+    return mask
 
 
 def mha_reference(
@@ -62,19 +70,23 @@ def mha_reference(
     q_offset: int | jnp.ndarray = 0,
     k_offset: int | jnp.ndarray = 0,
     sm_scale: float | None = None,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Dense softmax attention, f32 softmax.  q,k,v: [B, H, T, D].
 
     ``q_offset``/``k_offset`` are the *global* positions of element 0,
-    so sharded callers can mask correctly on local blocks.
+    so sharded callers can mask correctly on local blocks.  ``window``
+    (causal only): a query sees itself and the ``window - 1`` keys
+    before it (``causal_mask``).
     """
+    assert causal or window is None, "a window is a causal mask's"
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * sm_scale
     if causal:
         q_pos = q_offset + jnp.arange(q.shape[2])
         k_pos = k_offset + jnp.arange(k.shape[2])
-        s = jnp.where(causal_mask(q_pos, k_pos), s, NEG_INF)
+        s = jnp.where(causal_mask(q_pos, k_pos, window), s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
 
@@ -88,18 +100,20 @@ def block_attn_update(
     q_pos: jnp.ndarray | None,
     k_pos: jnp.ndarray | None,
     sm_scale: float,
+    window: int | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Fold one KV block into the online-softmax carry.
 
     carry = (acc [B,H,Tq,D] f32, m [B,H,Tq] f32 running max,
     l [B,H,Tq] f32 running sum).  Pass ``q_pos``/``k_pos`` (global
-    positions) for causal masking, or None for full attention.
+    positions) for causal masking (under a ``window`` too:
+    ``causal_mask``), or None for full attention.
     """
     acc, m, l = carry
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k_blk).astype(jnp.float32)
     s = s * sm_scale
     if q_pos is not None:
-        mask = causal_mask(q_pos, k_pos)
+        mask = causal_mask(q_pos, k_pos, window)
         s = jnp.where(mask, s, NEG_INF)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1))
     # exp of masked entries: s=NEG_INF, m_new >= old max; use explicit
@@ -143,6 +157,14 @@ def block_attn_finish(carry, dtype):
 # grid step with nothing to fold names the block already resident and
 # fetches nothing.  ``_flash_tiles`` chooses (rows, major, sub) per
 # kernel from the shapes.
+#
+# Under a WINDOW (a query sees itself and the ``window - 1`` keys
+# before it) the visible scores are a band: a sub-block can be crossed
+# by the diagonal, by the band's lower edge, or by both, and lies
+# outside on either side.  The walked grid axis is then as long as the
+# band a row block sees (``_band_steps``), not as the walked axis, and
+# starts at the band's first block (``_band``): queries walk the keys
+# behind them, keys the queries ahead of them.
 #
 # The forward and dQ hold queries on the tile's sublanes, so their
 # products stream the query block against a latched [sub, d] piece of
@@ -219,43 +241,120 @@ def _as_col(x):
     return jnp.broadcast_to(col, (rows, _LANES))
 
 
-def _visible(q_start, k_start, shape, q_axis):
-    """``shape`` bool tile: query position >= key position, queries
-    along ``q_axis`` of the tile and keys along the other."""
+def _visible(q_start, k_start, shape, q_axis, window=None):
+    """``shape`` bool tile: query position >= key position (and less
+    than ``window`` past it), queries along ``q_axis`` of the tile and
+    keys along the other."""
     q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
     k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
-    return q_pos >= k_pos
+    if window is None:
+        return q_pos >= k_pos
+    return (q_pos >= k_pos) & (q_pos - k_pos < window)
 
 
-def _sub_block_kind(lo, sub, row_start, rows, rows_are_queries):
+def _sub_block_kind(lo, sub, row_start, rows, rows_are_queries, window=None):
     """(clear, crossed) for the walked sub-block [lo, lo + sub) against
     the resident rows [row_start, row_start + rows): ``clear`` when
-    every score of the tile is visible, ``crossed`` when only some are.
+    every score of the tile is visible, ``crossed`` when only some are
+    (the diagonal runs through it, or under a ``window`` the band's
+    lower edge, or both), neither when it lies outside on either side.
     Plain arithmetic: the kernels call it on grid indices, the
     ``flash_tiles`` counter on integers."""
     hi, row_hi = lo + sub - 1, row_start + rows - 1
     if rows_are_queries:          # walked: keys
-        return hi <= row_start, (hi > row_start) & (lo <= row_hi)
-    return lo >= row_hi, (lo < row_hi) & (hi >= row_start)
+        if window is None:
+            return hi <= row_start, (hi > row_start) & (lo <= row_hi)
+        # the last query against the first key is the pair furthest
+        # apart, the first query against the last key the nearest
+        clear = (hi <= row_start) & (row_hi - lo < window)
+        some = (lo <= row_hi) & (row_start - hi < window)
+    else:                         # walked: queries
+        if window is None:
+            return lo >= row_hi, (lo < row_hi) & (hi >= row_start)
+        clear = (lo >= row_hi) & (hi - row_start < window)
+        some = (hi >= row_start) & (lo - row_hi < window)
+    return clear, some != clear     # (clear implies some)
 
 
-def _walk(fold, n_sub, sub, causal, lo, row_start, rows, rows_are_queries):
+def _least(a, b):
+    both = isinstance(a, int) and isinstance(b, int)
+    return min(a, b) if both else jnp.minimum(a, b)
+
+
+def _most(a, b):
+    both = isinstance(a, int) and isinstance(b, int)
+    return max(a, b) if both else jnp.maximum(a, b)
+
+
+def _band(r, tiles, rows_are_queries, window, n_walked):
+    """``(first, last)`` of the walked axis' ``n_walked`` blocks (of
+    ``tiles.major``) that hold a position the resident row block ``r``
+    sees through the band: queries ``[lo, hi]`` see the keys
+    ``(lo - window, hi]``, keys are seen by the queries ``[lo, hi +
+    window)``; both cut to the axis (a key block past the last query
+    names the last block, where nothing is visible to fold).  On
+    integers or on grid indices."""
+    lo = r * tiles.rows
+    hi = lo + tiles.rows - 1
+    if rows_are_queries:
+        first, last = _most(lo - window + 1, 0), hi
+    else:
+        first, last = lo, hi + window - 1
+    last = _least(last // tiles.major, n_walked - 1)
+    return _least(first // tiles.major, last), last
+
+
+def _band_steps(t_rows, t_walk, tiles, rows_are_queries, window) -> int:
+    """The walked grid axis under a window: the most blocks any row
+    block's band holds (``ceil((rows + window - 1) / major) + 1`` at
+    most, and never more than the axis has)."""
+    n_walked = t_walk // tiles.major
+    spans = (
+        _band(r, tiles, rows_are_queries, window, n_walked)
+        for r in range(t_rows // tiles.rows)
+    )
+    return max(last - first + 1 for first, last in spans)
+
+
+def _walk(fold, n_sub, sub, causal, lo, row_start, rows, rows_are_queries,
+          window=None, live=None):
     """Fold the walked block's ``n_sub`` sub-blocks, each through the
-    body its place against the diagonal asks for (or none)."""
+    body its place against the diagonal (and the band's lower edge)
+    asks for, or none.  ``live``: whether this grid step names a block
+    of its own at all (a step past the band's last block names that
+    block again and folds nothing)."""
     for c in range(n_sub):
         if not causal:
             fold(c, False)
             continue
         clear, crossed = _sub_block_kind(
-            lo + c * sub, sub, row_start, rows, rows_are_queries
+            lo + c * sub, sub, row_start, rows, rows_are_queries, window
         )
+        if live is not None:
+            clear, crossed = clear & live, crossed & live
         pl.when(clear)(functools.partial(fold, c, False))
         pl.when(crossed)(functools.partial(fold, c, True))
 
 
+def _walked_start(window, n_walked, rows_are_queries, rows, major, sub):
+    """``(first position of the walked block this grid step names,
+    live)`` for grid cell (row block ``program_id(1)``, step
+    ``program_id(2)``): step ``w`` names block ``w``, or under a window
+    the band's ``first + w`` (``live`` while that is no further than
+    its last)."""
+    w = pl.program_id(2)
+    if window is None:
+        return w * major, None
+    first, last = _band(
+        pl.program_id(1), FlashTiles(rows, major, sub), rows_are_queries,
+        window, n_walked,
+    )
+    return (first + w) * major, first + w <= last
+
+
 def _flash_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, qs_ref, acc_ref, m_ref, l_ref,
-    *, sm_scale, causal, sub
+    *, sm_scale, causal, sub, window=None, n_walked=None
 ):
     """One (batch*head, q-block, kv-block) grid cell.
 
@@ -268,7 +367,7 @@ def _flash_kernel(
     rows, d = acc_ref.shape
     major = k_ref.shape[1]
     q_start = pl.program_id(1) * rows
-    k_start = ki * major
+    k_start, live = _walked_start(window, n_walked, True, rows, major, sub)
 
     @pl.when(ki == 0)
     def _init():
@@ -284,9 +383,14 @@ def _flash_kernel(
         if masked:
             # one select: every row's first folded key is key 0, which
             # it sees, so ``m`` is finite from the first fold on and
-            # exp(NEG_INF - m) is already an exact 0
+            # exp(NEG_INF - m) is already an exact 0.  (Under a window
+            # a row's first tile may hold no key it sees: ``m`` stays
+            # NEG_INF, ``p`` is 1 there, and the first key the row does
+            # see — itself, at the latest — wipes that with ``alpha =
+            # exp(NEG_INF - m)``, an exact 0 too.)
             s = jnp.where(
-                _visible(q_start, k_start + c * sub, s.shape, 0), s, NEG_INF
+                _visible(q_start, k_start + c * sub, s.shape, 0, window),
+                s, NEG_INF,
             )
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
@@ -298,7 +402,8 @@ def _flash_kernel(
             p.astype(v_blk.dtype), v_blk, _NN
         )
 
-    _walk(fold, major // sub, sub, causal, k_start, q_start, rows, True)
+    _walk(fold, major // sub, sub, causal, k_start, q_start, rows, True,
+          window, live)
 
     @pl.when(ki == pl.num_programs(2) - 1)
     def _finish():
@@ -319,7 +424,7 @@ def _on_tpu() -> bool:
 
 def _flash_bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_acc, dv_acc, *, sm_scale, causal, sub
+    dk_acc, dv_acc, *, sm_scale, causal, sub, window=None, n_walked=None
 ):
     """dK/dV for one kv block: grid (bh, kv-block, q-block), the q dim
     sequential so the [rows, d] accumulators live in scratch.  The
@@ -331,7 +436,7 @@ def _flash_bwd_dkv_kernel(
     rows = k_ref.shape[1]
     major = q_ref.shape[1]
     k_start = pl.program_id(1) * rows
-    q_start = qi * major
+    q_start, live = _walked_start(window, n_walked, False, rows, major, sub)
 
     @pl.when(qi == 0)
     def _init():
@@ -345,7 +450,8 @@ def _flash_bwd_dkv_kernel(
         p = jnp.exp(_dot(k_ref[0], qs, _NT) - lse_ref[0, :, cols])
         if masked:
             p = jnp.where(
-                _visible(q_start + c * sub, k_start, p.shape, 1), p, 0.0
+                _visible(q_start + c * sub, k_start, p.shape, 1, window),
+                p, 0.0,
             )
         dv_acc[...] += _dot(p.astype(do.dtype), do, _NN)    # p^T @ dO
         dp = _dot(v_ref[0], do, _NT)                        # (dO @ V^T)^T
@@ -353,7 +459,8 @@ def _flash_bwd_dkv_kernel(
         # the scale rides in ``qs``: ds^T @ (scale * Q)
         dk_acc[...] += _dot(ds.astype(qs.dtype), qs, _NN)
 
-    _walk(fold, major // sub, sub, causal, q_start, k_start, rows, False)
+    _walk(fold, major // sub, sub, causal, q_start, k_start, rows, False,
+          window, live)
 
     @pl.when(qi == pl.num_programs(2) - 1)
     def _finish():
@@ -363,7 +470,8 @@ def _flash_bwd_dkv_kernel(
 
 def _flash_bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-    qs_ref, lse_col, delta_col, dq_acc, *, sm_scale, causal, sub
+    qs_ref, lse_col, delta_col, dq_acc, *, sm_scale, causal, sub,
+    window=None, n_walked=None
 ):
     """dQ for one q block: grid (bh, q-block, kv-block), kv sequential.
     Queries on the tile's sublanes as in the forward; the block's
@@ -373,7 +481,7 @@ def _flash_bwd_dq_kernel(
     rows = q_ref.shape[1]
     major = k_ref.shape[1]
     q_start = pl.program_id(1) * rows
-    k_start = ki * major
+    k_start, live = _walked_start(window, n_walked, True, rows, major, sub)
 
     @pl.when(ki == 0)
     def _init():
@@ -389,13 +497,15 @@ def _flash_bwd_dq_kernel(
         p = jnp.exp(s - _lanes(lse_col[...], sub))
         if masked:
             p = jnp.where(
-                _visible(q_start, k_start + c * sub, p.shape, 0), p, 0.0
+                _visible(q_start, k_start + c * sub, p.shape, 0, window),
+                p, 0.0,
             )
         dp = _dot(do_ref[0], v_ref[0, cols, :], _NT)        # dO @ V^T
         ds = p * (dp - _lanes(delta_col[...], sub))
         dq_acc[...] += _dot(ds.astype(k_blk.dtype), k_blk, _NN)
 
-    _walk(fold, major // sub, sub, causal, k_start, q_start, rows, True)
+    _walk(fold, major // sub, sub, causal, k_start, q_start, rows, True,
+          window, live)
 
     @pl.when(ki == pl.num_programs(2) - 1)
     def _finish():
@@ -423,15 +533,25 @@ _SEM = lambda *names: pltpu.CompilerParams(  # noqa: E731
 )
 
 
-def _walked_index(causal, tiles: FlashTiles, rows_are_queries: bool, n_walked):
+def _walked_index(causal, tiles: FlashTiles, rows_are_queries: bool, n_walked,
+                  window=None):
     """Index of the walked block for grid cell (row block ``r``, step
     ``w`` of ``n_walked``).  Under causality a step with nothing to
     fold names the nearest block that has — the one already resident,
     so nothing is fetched for it: queries see no key block past their
     last row, keys are seen by no query block before their first (nor
-    by any, where the keys outrun the queries: the last block then)."""
+    by any, where the keys outrun the queries: the last block then).
+    Under a ``window`` step ``w`` (of ``_band_steps``) names the
+    band's ``w``-th block, held to its last from above: the band's
+    first block bounds it from below by construction."""
     if not causal:
         return lambda r, w: w
+    if window is not None:
+        def walked(r, w):
+            first, last = _band(r, tiles, rows_are_queries, window, n_walked)
+            return _least(first + w, last)
+
+        return walked
     if rows_are_queries:
         return lambda r, w: jnp.minimum(
             w, (r * tiles.rows + tiles.rows - 1) // tiles.major
@@ -441,7 +561,19 @@ def _walked_index(causal, tiles: FlashTiles, rows_are_queries: bool, n_walked):
     )
 
 
-def _flash_fwd_call(q, k, v, causal, sm_scale, tiles, interpret):
+def _walked_axis(t_rows, t_walk, tiles, rows_are_queries, window):
+    """The walked grid axis of one kernel call: ``(its length, the
+    kernel's keywords to place a step on it)`` — the whole axis in
+    blocks of ``tiles.major``, or under a window the band's
+    ``_band_steps``."""
+    n_walked = t_walk // tiles.major
+    if window is None:
+        return n_walked, {}
+    steps = _band_steps(t_rows, t_walk, tiles, rows_are_queries, window)
+    return steps, dict(window=window, n_walked=n_walked)
+
+
+def _flash_fwd_call(q, k, v, causal, sm_scale, tiles, interpret, window=None):
     """Forward kernel: ``(out [B,H,T,D], logsumexp f32[B,H,T])``."""
     b, h, t, t_k, d = _flash_dims(q, k, True, tiles)
     rows, major, sub = tiles
@@ -449,18 +581,20 @@ def _flash_fwd_call(q, k, v, causal, sm_scale, tiles, interpret):
     ks = k.reshape(b * h, t_k, d)
     vs = v.reshape(b * h, t_k, d)
     vma = jax.typeof(qs).vma
-    walked = _walked_index(causal, tiles, True, t_k // major)
+    steps, band = _walked_axis(t, t_k, tiles, True, window)
+    walked = _walked_index(causal, tiles, True, t_k // major, window)
     q_spec = pl.BlockSpec((1, rows, d), lambda i, j, kk: (i, j, 0))
     k_spec = pl.BlockSpec((1, major, d), lambda i, j, kk: (i, walked(j, kk), 0))
     out, lse = pl.pallas_call(
         functools.partial(
-            _flash_kernel, sm_scale=sm_scale, causal=causal, sub=sub
+            _flash_kernel, sm_scale=sm_scale, causal=causal, sub=sub,
+            **band,
         ),
         out_shape=(
             jax.ShapeDtypeStruct((b * h, t, d), q.dtype, vma=vma),
             jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32, vma=vma),
         ),
-        grid=(b * h, t // rows, t_k // major),
+        grid=(b * h, t // rows, steps),
         in_specs=[q_spec, k_spec, k_spec],
         out_specs=(
             q_spec,
@@ -479,15 +613,17 @@ def _flash_fwd_call(q, k, v, causal, sm_scale, tiles, interpret):
     return out.reshape(b, h, t, d), lse.reshape(b, h, t)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, sm_scale, plan, interpret):
-    out, _ = _flash_fwd_call(q, k, v, causal, sm_scale, plan.fwd, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, sm_scale, plan, interpret, window=None):
+    out, _ = _flash_fwd_call(
+        q, k, v, causal, sm_scale, plan.fwd, interpret, window
+    )
     return out
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, plan, interpret):
+def _flash_fwd(q, k, v, causal, sm_scale, plan, interpret, window):
     out, lse = _flash_fwd_call(
-        q, k, v, causal, sm_scale, plan.fwd, interpret
+        q, k, v, causal, sm_scale, plan.fwd, interpret, window
     )
     # named HERE, on the values the backward rule reads: a name on the
     # caller's copy of ``out`` saves an array and still replays the
@@ -500,7 +636,8 @@ def _flash_fwd(q, k, v, causal, sm_scale, plan, interpret):
 
 
 def _flash_bwd_call(
-    q, k, v, g, lse, delta, causal, sm_scale, dkv_tiles, dq_tiles, interpret
+    q, k, v, g, lse, delta, causal, sm_scale, dkv_tiles, dq_tiles, interpret,
+    window=None,
 ):
     """Backward kernels against EXPLICIT (lse, delta) residuals
     (fp32 [B,H,T]).  Factored out of ``_flash_bwd`` so ring
@@ -517,7 +654,8 @@ def _flash_bwd_call(
     vma = jax.typeof(qs).vma
 
     rows, major, sub = dkv_tiles
-    walked = _walked_index(causal, dkv_tiles, False, t // major)
+    steps, band = _walked_axis(t_k, t, dkv_tiles, False, window)
+    walked = _walked_index(causal, dkv_tiles, False, t // major, window)
     q_spec = pl.BlockSpec(
         (1, major, d), lambda i, kj, qi: (i, walked(kj, qi), 0)
     )
@@ -527,13 +665,14 @@ def _flash_bwd_call(
     )
     dk, dv = pl.pallas_call(
         functools.partial(
-            _flash_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal, sub=sub
+            _flash_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal, sub=sub,
+            **band,
         ),
         out_shape=(
             jax.ShapeDtypeStruct((b * h, t_k, d), k.dtype, vma=vma),
             jax.ShapeDtypeStruct((b * h, t_k, d), v.dtype, vma=vma),
         ),
-        grid=(b * h, t_k // rows, t // major),
+        grid=(b * h, t_k // rows, steps),
         in_specs=[q_spec, k_spec, k_spec, q_spec, r_spec, r_spec],
         out_specs=(k_spec, k_spec),
         scratch_shapes=[
@@ -545,7 +684,8 @@ def _flash_bwd_call(
     )(qs, ks, vs, dos, lse, delta)
 
     rows, major, sub = dq_tiles
-    walked = _walked_index(causal, dq_tiles, True, t_k // major)
+    steps, band = _walked_axis(t, t_k, dq_tiles, True, window)
+    walked = _walked_index(causal, dq_tiles, True, t_k // major, window)
     q_spec = pl.BlockSpec((1, rows, d), lambda i, qi, kj: (i, qi, 0))
     k_spec = pl.BlockSpec(
         (1, major, d), lambda i, qi, kj: (i, walked(qi, kj), 0)
@@ -553,10 +693,11 @@ def _flash_bwd_call(
     r_spec = pl.BlockSpec((1, 1, rows), lambda i, qi, kj: (i, 0, qi))
     dq = pl.pallas_call(
         functools.partial(
-            _flash_bwd_dq_kernel, sm_scale=sm_scale, causal=causal, sub=sub
+            _flash_bwd_dq_kernel, sm_scale=sm_scale, causal=causal, sub=sub,
+            **band,
         ),
         out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype, vma=vma),
-        grid=(b * h, t // rows, t_k // major),
+        grid=(b * h, t // rows, steps),
         in_specs=[q_spec, k_spec, k_spec, q_spec, r_spec, r_spec],
         out_specs=q_spec,
         scratch_shapes=[
@@ -575,14 +716,14 @@ def _flash_bwd_call(
     )
 
 
-def _flash_bwd(causal, sm_scale, plan, interpret, res, g):
+def _flash_bwd(causal, sm_scale, plan, interpret, window, res, g):
     q, k, v, out, lse = res
     # delta_i = rowsum(dO * O): the softmax-jacobian correction term,
     # [B, H, T] like the logsumexp
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     return _flash_bwd_call(
         q, k, v, g, lse, delta, causal, sm_scale, plan.dkv, plan.dq,
-        interpret,
+        interpret, window,
     )
 
 
@@ -591,7 +732,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention_tpu(
     q, k, v, *, causal=True, sm_scale=None, block_q=None, block_k=None,
-    bwd_block_q=None, bwd_block_k=None, interpret=False,
+    bwd_block_q=None, bwd_block_k=None, interpret=False, window=None,
 ):
     """Fused flash attention, fully differentiable (custom_vjp with
     Pallas dQ and dK/dV kernels — the standard two-kernel backward with
@@ -600,12 +741,21 @@ def flash_attention_tpu(
     tests and sweeps and mean un-subdivided blocks of that size — T
     (and T_k) must then be divisible by them.  ``interpret=True`` runs
     the kernels in the Pallas interpreter (any backend; how the tests
-    exercise them)."""
+    exercise them).  ``window`` (causal only): a query sees itself
+    and the ``window - 1`` keys before it; one no query's reach falls
+    short of (``window >= T``) is the plain causal call.  The window
+    calls stand under a name of their own in a compiled text
+    (``_flash_window_jit``; the plain ones under ``_flash_jit``):
+    their kernels compute a band, not a triangle, and whoever counts
+    a call's operations has to know which (docs/OBSERVABILITY.md)."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     t, t_k = q.shape[2], k.shape[2]
+    window = _binding_window(window, t, causal)
     explicit = block_q or block_k or bwd_block_q or bwd_block_k
-    plan = None if explicit else _flash_tiles(t, t_k, q.shape[3], q.dtype)
+    plan = None if explicit else _flash_tiles(
+        t, t_k, q.shape[3], q.dtype, window
+    )
     if plan is None:
         if not (explicit or interpret):
             # a length no aligned block divides is rejected HERE with
@@ -627,12 +777,34 @@ def flash_attention_tpu(
             fwd=FlashTiles(bq, bk, bk), dkv=FlashTiles(gk, gq, gq),
             dq=FlashTiles(gq, gk, gk),
         )
+    if window is not None:
+        return _flash_window_jit(
+            q, k, v, causal, sm_scale, plan, interpret, window
+        )
     return _flash_jit(q, k, v, causal, sm_scale, plan, interpret)
 
 
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
 def _flash_jit(q, k, v, causal, sm_scale, plan, interpret):
     return _flash(q, k, v, causal, sm_scale, plan, interpret)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+def _flash_window_jit(q, k, v, causal, sm_scale, plan, interpret, window):
+    return _flash(q, k, v, causal, sm_scale, plan, interpret, window)
+
+
+def _binding_window(window, t_q: int, causal: bool) -> int | None:
+    """``window`` where it hides a key from some query, else None: the
+    furthest pair a causal mask shows is ``t_q - 1`` apart."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError(
+            f"a window ({window}) is a causal mask's second edge: it "
+            f"needs causal=True and at least the query itself"
+        )
+    return int(window) if window < t_q else None
 
 
 def _auto_block(t: int, dtype=None) -> int | None:
@@ -683,13 +855,20 @@ def _fit(t: int, cap: int) -> int:
     )
 
 
-def _flash_tiles(t_q, t_k, head_dim, dtype) -> FlashPlan | None:
+def _flash_tiles(t_q, t_k, head_dim, dtype, window=None) -> FlashPlan | None:
     """The three kernels' tiles for an attention shape, or ``None``
     where an axis has no aligned block (``_auto_block``: the dense
     path).  One rule for every kernel, from what the kernel can see:
     the resident block is the axis cut to the swept row count, the
     walked block as much of the other axis as the swept byte count
-    holds at this head dim and dtype, folded at the swept tile width."""
+    holds at this head dim and dtype, folded at the swept tile width.
+    Under a ``window`` the walked block is at most half the window
+    (and a score tile at least): a row block's band is ``rows + window
+    - 1`` positions wherever it lies, and a fetched block that
+    straddles its edge moves positions nothing folds (at window 1024
+    and 512 rows, three ``[512, 512]`` tiles a row block, two crossed
+    and one clear, where a block of 4096 would move 4096 keys to fold
+    1535)."""
     import numpy as np
 
     if not _auto_block(t_q, dtype) or not _auto_block(t_k, dtype):
@@ -697,38 +876,63 @@ def _flash_tiles(t_q, t_k, head_dim, dtype) -> FlashPlan | None:
     row_bytes = head_dim * np.dtype(dtype).itemsize
 
     def tiles(t_rows, t_walk):
-        major = _fit(t_walk, max(_MAJOR_BYTES // row_bytes, _LANES))
+        most = max(_MAJOR_BYTES // row_bytes, _LANES)
+        if window is not None:
+            most = min(most, max(window // 2, _SUB))
+        major = _fit(t_walk, most)
         return FlashTiles(_fit(t_rows, _ROWS), major, _fit(major, _SUB))
 
     on_q = tiles(t_q, t_k)
     return FlashPlan(fwd=on_q, dkv=tiles(t_k, t_q), dq=on_q)
 
 
-def flash_tiles_summary(t_q, t_k, head_dim, dtype, causal=True) -> dict:
+def walked_tiles(t_rows, t_walk, tiles: FlashTiles, rows_are_queries,
+                 causal=True, window=None) -> list[tuple[int, int, bool]]:
+    """``(row block, first position of the sub-block, crossed)`` of
+    every score tile one kernel folds, in grid order: the kernel's own
+    walk (``_band``'s blocks, ``_walk``'s kinds) on integers."""
+    rows, major, sub = tiles
+    n_walked = t_walk // major
+    out = []
+    for r in range(t_rows // rows):
+        first, last = (0, n_walked - 1) if window is None else _band(
+            r, tiles, rows_are_queries, window, n_walked)
+        for block in range(first, last + 1):
+            for lo in range(block * major, (block + 1) * major, sub):
+                clear, crossed = (
+                    _sub_block_kind(lo, sub, r * rows, rows,
+                                    rows_are_queries, window)
+                    if causal else (True, False)
+                )
+                if clear or crossed:
+                    out.append((r, lo, bool(crossed)))
+    return out
+
+
+def flash_tiles_summary(t_q, t_k, head_dim, dtype, causal=True,
+                        window=None) -> dict:
     """For the run summary's ``"flash_tiles"``: per kernel the outer
-    tile ``[rows, major]``, the inner ``[rows, sub]`` and the share of
-    the score tiles it visits that take the masked body — static, from
-    the shapes.  Empty where ``flash_attention`` takes the dense path
-    (off the TPU, or a length no block tiles)."""
-    plan = _flash_tiles(t_q, t_k, head_dim, dtype)
+    tile ``[rows, major]``, the inner ``[rows, sub]``, the score tiles
+    it visits (a batch-head) and the share of them that take the
+    masked body — static, from the shapes.  Empty where
+    ``flash_attention`` takes the dense path (off the TPU, or a length
+    no block tiles)."""
+    window = _binding_window(window, t_q, causal)
+    plan = _flash_tiles(t_q, t_k, head_dim, dtype, window)
     if plan is None or not _on_tpu():
         return {}
     out = {}
-    for kernel, (rows, major, sub) in plan._asdict().items():
+    for kernel, tiles in plan._asdict().items():
         on_q = kernel != "dkv"
         t_rows, t_walk = (t_q, t_k) if on_q else (t_k, t_q)
-        visited = masked = 0
-        for r in range(0, t_rows, rows):
-            for lo in range(0, t_walk, sub):
-                clear, crossed = (
-                    _sub_block_kind(lo, sub, r, rows, on_q)
-                    if causal else (True, False)
-                )
-                visited += bool(clear or crossed)
-                masked += bool(crossed)
+        visited = walked_tiles(t_rows, t_walk, tiles, on_q, causal, window)
         out[kernel] = {
-            "outer": [rows, major], "inner": [rows, sub],
-            "masked_share": round(masked / visited, 4),
+            "outer": [tiles.rows, tiles.major],
+            "inner": [tiles.rows, tiles.sub],
+            "tiles": len(visited),
+            "masked_share": round(
+                sum(crossed for _, _, crossed in visited) / len(visited), 4
+            ),
         }
     return out
 
@@ -754,14 +958,21 @@ def dense_choices() -> int:
     return info.hits + info.misses
 
 
-def flash_attention(q, k, v, *, causal=True, sm_scale=None):
+def flash_attention(q, k, v, *, causal=True, window=None, sm_scale=None):
     """Dispatch: Pallas kernels on TPU (shapes permitting), reference
     math elsewhere.  Differentiable on both paths — the TPU kernel
     carries a custom_vjp with Pallas backward kernels.  A dense choice
-    is never silent: ``_log_dense_choice`` names the shape and why."""
+    is never silent: ``_log_dense_choice`` names the shape and why.
+    ``window`` (causal only): a query sees itself and the ``window -
+    1`` keys before it, on both paths."""
     t, t_k = q.shape[2], k.shape[2]
+    window = _binding_window(window, t, causal)
     on_tpu = _on_tpu()
     if on_tpu and _flash_tiles(t, t_k, q.shape[3], q.dtype):
-        return flash_attention_tpu(q, k, v, causal=causal, sm_scale=sm_scale)
+        return flash_attention_tpu(
+            q, k, v, causal=causal, sm_scale=sm_scale, window=window
+        )
     _log_dense_choice(t, t_k, str(q.dtype), on_tpu)
-    return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale)
+    return mha_reference(
+        q, k, v, causal=causal, sm_scale=sm_scale, window=window
+    )

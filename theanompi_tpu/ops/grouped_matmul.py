@@ -324,8 +324,15 @@ def _sub_rows(block_rows: int) -> int:
 
 def _rows_tiles(c: int, o: int, dtype) -> tuple[int, int] | None:
     """``(contracting tile, output-column tile)`` of (a) / (b)'s
-    ``[c, o]`` weight block: all of ``c`` up to 2048, then as much of
-    ``o`` as keeps the block in ``_BLOCK_BYTES``."""
+    ``[c, o]`` weight block: the whole block where it fits
+    ``_BLOCK_BYTES`` (2304 x 896 in bf16: a contraction cut in two
+    changes the block's index every grid step, so the weights cross
+    HBM once a VISIT and not once an expert; PERF.md, PR 41); else all
+    of ``c`` up to 2048, then as much of ``o`` as keeps the block in
+    ``_BLOCK_BYTES``."""
+    if (c % _LANE == 0 and o % _LANE == 0
+            and c * o * jnp.dtype(dtype).itemsize <= _BLOCK_BYTES):
+        return c, o
     tc = _divisor_tile(c, 2048)
     if not tc:
         return None

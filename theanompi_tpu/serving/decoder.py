@@ -154,6 +154,18 @@ class LlamaDecoder:
                 f"prediction module (mtp_depth={model.mtp_depth}: "
                 "speculative drafts from it) are not yet servable"
             )
+        if getattr(model, "attn_per_layer", False):
+            raise NotImplementedError(
+                "serving has one cache lifetime and one rotary table: "
+                f"a stack described layer by layer (attention_kinds="
+                f"{model.attention_kinds}, sliding_window="
+                f"{model.sliding_window}, rope_parameters "
+                f"{'given' if model.rope_parameters else 'not given'}) "
+                "needs a cache whose window layers free blocks behind "
+                "the window, a paged kernel that walks the band, and a "
+                "table a layer kind in prefill and decode — not yet "
+                "servable"
+            )
         if (model.pp > 1 or model.sp > 1 or model.n_experts
                 or model.qk_norm):
             raise NotImplementedError(

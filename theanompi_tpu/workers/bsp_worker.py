@@ -604,7 +604,12 @@ def run(
         "remat_kept_bytes": getattr(model, "remat_kept_bytes", 0),
         # the flash kernels' tiles for the model's attention shape
         # ({} where no such kernel runs)
+        # (a model whose attention is described layer by layer: a
+        # summary an attention kind; "attention_kinds" counts the
+        # layers of each, "sliding_window" is the window layers' reach)
         "flash_tiles": getattr(model, "flash_tiles", dict)(),
+        "attention_kinds": getattr(model, "attention_kinds", None),
+        "sliding_window": getattr(model, "sliding_window", None),
         # the attention path, the experts an expert layer's leaves
         # hold (None: all it routes over) and the depth of the
         # multi-token-prediction module
