@@ -205,6 +205,64 @@ def test_dropless_expert_layer_compiles_to_grouped_kernels(
     assert not any("rematted_computation" in line for line in plan)
 
 
+def test_held_layer_too_large_to_gather_from_sums_in_the_kernel(
+    chip, monkeypatch
+):
+    """Mellum's expert layer as its cell runs it (2304 wide, 16 of 64
+    experts of 896 held, 8 picks, 16384 tokens), with the backward:
+    the window of 65536 sorted rows is a 302 MB source, so each
+    token's rows are summed by ``ops/held_rows_sum.py``'s kernel —
+    the combine, and the dispatch's transpose — over rows sorted by
+    (expert, token), in the layer's loop and under its scopes, and no
+    gather fetches a slot's 16384 rows.  The same layer at a quarter of the
+    tokens (a 75 MB window) keeps XLA's gathers and has no such
+    kernel."""
+    import re
+
+    from theanompi_tpu.ops import attention
+    from theanompi_tpu.parallel.moe import moe_ffn
+
+    e, held, k, d, f = 64, 16, 8, 2304, 896
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)  # as on the chip
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def loss(x, router, wg, wu, wd):
+        y, aux = moe_ffn(
+            x, router, wg, wu, wd, n_experts=e, top_k=k,
+            capacity_factor=None, expert_axis=None, model_axis=None,
+            held=held,
+        )
+        return jnp.sum(y.astype(jnp.float32)) + aux["lb"]
+
+    def text(n):
+        return _compiled_text(
+            jax.value_and_grad(loss, argnums=(0, 2, 3, 4)),
+            sds((1, n, d), jnp.bfloat16), sds((d, e), jnp.float32),
+            sds((held, d, f), jnp.float32), sds((held, d, f), jnp.float32),
+            sds((held, f, d), jnp.float32),
+        )
+
+    def sums(text):
+        return [ln for ln in text.splitlines()
+                if "tpu_custom_call" in ln and "held-rows-sum" in ln]
+
+    def slot_gathers(text, n):      # a slot's N rows out of the window
+        return re.findall(rf"= bf16\[(?:1,)?{n},{d}\]\S* gather\(", text)
+
+    big = text(16384)
+    assert [re.search(r"moe_(combine|dispatch)/jit\(_sum_jit\)", ln).group(1)
+            for ln in sums(big)] in (["combine", "dispatch"],
+                                     ["dispatch", "combine"])
+    assert all("while/body" in ln and f"f32[16384,{d}]" in ln
+               for ln in sums(big))
+    assert not slot_gathers(big, 16384)
+    small = text(4096)
+    assert not sums(small)
+    assert slot_gathers(small, 4096)
+
+
 def _fusions(text):
     """A compiled text as ``{computation: [(name, opcode, shape,
     operand names, called computation or None)]}`` and its fusion
@@ -643,12 +701,18 @@ def test_held_share_step_compiles_with_its_kernels_and_scopes(
     call and the MTP module's expert call) compiled for the v5e: three
     flash kernels a layer CALL at head dim 256 (the remat keeps the
     kernel's outputs in the MTP block too); the grouped kernels of the
-    two expert calls over the WHOLE ``k * N`` buffer's static grid —
-    the held range shortens the visits at run time, not the grid —
-    against leaves of the 2 experts held; one tile plan a call, none
-    in a replay; the new scopes in every phase they have; every block
-    named; and the step gives the selection bias back."""
+    two expert calls against leaves of the 2 experts held, over the
+    static bound of 512 of the ``k * N`` = 1024 sorted rows (the held
+    range shortens the visits at run time, not the grid) — three
+    forward, two replayed and six backward a call, all of them in the
+    loop over the windows of 512 rows, whose first pass every routing
+    runs and whose second only a routing past the bound; none over
+    1024 rows anywhere, and no second set beside the loop; window 0's
+    tile plan made outside the loop, once a call and not in a replay;
+    the new scopes in every phase they have; every block named; and
+    the step gives the selection bias back."""
     import re
+    from collections import Counter
 
     from benchmark.layer_metrics import _scopes
 
@@ -659,13 +723,23 @@ def test_held_share_step_compiles_with_its_kernels_and_scopes(
     assert all(re.search(r"bf16\[\d+,256,256\]", ln) for ln in flash), flash
     products = [ln for ln in text.splitlines()
                 if "tpu_custom_call" in ln and "ragged-dot" in ln]
-    # 2 expert calls x (3 forward + 2 replayed + 3 dlhs + 3 drhs)
-    assert len(products) == 22
-    rows = 2 * 2 * 256          # k * N: the static buffer
-    assert all(re.search(rf"\[({rows},256|2,256,256)\]", p)
-               for p in products), products[:2]
+
+    def phase(ln):
+        return ("replay" if "rematted_computation" in ln
+                else "bwd" if "transpose(" in ln else "fwd")
+
+    def in_loop(ln):                # the step itself is no loop here
+        return "while/body/" in ln
+
+    assert all(map(in_loop, products))
+    assert Counter(map(phase, products)) == dict(
+        fwd=2 * 3, replay=2 * 2, bwd=2 * 6)
+    rows = 2 * 2 * 256              # k * N; the bound is half of them
+    assert all(re.search(rf"\[{rows // 2},256\]", ln) for ln in products)
+    assert not any(re.search(rf"\[{rows},256\]", ln) for ln in products)
     assert not re.search(r"\[8,256,256\]", text)    # no leaf of all 8
-    plan = [ln for ln in text.splitlines() if "moe_tile_plan" in ln]
+    plan = [ln for ln in text.splitlines()
+            if "moe_tile_plan" in ln and not in_loop(ln)]
     assert plan and not any("rematted_computation" in ln for ln in plan)
     have, top, kernels, products = _step_blocks(text)
     for block in ("blk_attn", "blk_ffn"):

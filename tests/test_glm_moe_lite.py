@@ -578,6 +578,30 @@ def test_tp2_composes(stepped):
     np.testing.assert_array_equal(routing, stepped["routing"])
 
 
+@pytest.mark.parametrize("layout", [dict(), dict(model=2), dict(data=2)],
+                         ids=str)
+def test_step_over_the_bounded_rows_equals_the_full_length_step(
+    layout, monkeypatch
+):
+    """48 tokens x 2 picks, 2 of 8 experts held: the expert calls lay
+    out 48 of their 96 sorted rows (24 of 48 a data shard) under the
+    step's scan, remat and checked ``shard_map``.  Loss, every leaf's
+    gradient and the counters equal those of the same layout's step
+    whose bound is all its rows."""
+    knobs, _ = rehearsal(seq_len=24)
+    assert moe.held_rows_bound(96, 2, 8) == 48
+    assert moe.held_rows_bound(48, 2, 8) == 24
+    model = build(knobs, **layout)
+    batch = model.data.train_batch(0)
+    loss, grads, routing, _ = one_step(model, batch)
+    monkeypatch.setattr(moe, "held_rows_bound", lambda picks, *_: picks)
+    want_loss, want, want_routing, _ = one_step(
+        build(knobs, **layout), batch)
+    assert abs(loss - want_loss) <= 2e-6 * want_loss
+    assert_grads_close(grads, want)
+    np.testing.assert_array_equal(routing, want_routing)
+
+
 @pytest.mark.parametrize("layout", [dict(pp=2), dict(sp=2),
                                     dict(ut_steps=2)], ids=str)
 def test_layouts_it_does_not_compose_with_are_refused(layout):

@@ -22,6 +22,7 @@ from jax.sharding import PartitionSpec as P
 
 from theanompi_tpu.ops import attention
 from theanompi_tpu.ops import grouped_matmul as gm
+from theanompi_tpu.ops import held_rows_sum as hrs
 from theanompi_tpu.parallel import make_mesh, moe
 
 TM = 128            # row tile of these tests: 4 tiles of 512 rows
@@ -197,6 +198,55 @@ def test_held_layer_on_the_kernels_matches_ragged_dot(
         _close(g, w, 2e-5)
     assert np.asarray(got[1][2][:4]).any()
     assert not np.asarray(got[1][2][4:]).any()      # experts not held
+
+
+@pytest.mark.parametrize("sums", ["gathered", "in_the_kernel"])
+@pytest.mark.parametrize("lean", [0.0, 40.0],
+                         ids=["within_the_bound", "past_the_bound"])
+def test_held_windows_on_the_kernels_match_ragged_dot(
+    kernels_in_the_interpreter, monkeypatch, lean, sums
+):
+    """One of 8 experts held, 1024 tokens x 2 picks: the layer lays out
+    512 of its 2048 sorted rows (``moe.held_rows_bound``) under a plan
+    of 512, and a router that leans on the held expert sends it more —
+    the loop's further windows, each under a plan of its own.  Value
+    and the gradients of every operand, the kernels (in the
+    interpreter) against ``lax.ragged_dot``; the tokens' sums as XLA
+    gathers them, and in ``held_rows_sum``'s kernel over rows sorted
+    by (expert, token), as for a window too large to gather from."""
+    assert moe.held_rows_bound(2 * 1024, 1, E) == 512
+    summed, in_kernel = [], hrs.held_rows_sum
+    if sums == "in_the_kernel":
+        monkeypatch.setattr(moe, "_ON_CHIP_SOURCE_BYTES", 0)
+    monkeypatch.setattr(
+        hrs, "held_rows_sum", lambda rows, *a: (
+            summed.append(rows.shape), in_kernel(rows, *a, interpret=True)
+        )[1],
+    )
+    ks = jax.random.split(jax.random.key(2), 3)
+    x = jax.random.normal(ks[0], (1, 1024, D)).at[..., 0].set(1.0)
+    router = jax.random.normal(ks[1], (D, E)).at[0, 0].add(lean)
+    _, _, wg, wu, wd = _layer_args()
+
+    def loss(x, router, wg, wu, wd):
+        y, aux = moe.moe_ffn(
+            x, router, wg[:1], wu[:1], wd[:1], n_experts=E, top_k=TOP_K,
+            capacity_factor=None, expert_axis=None, model_axis=None,
+            held=1,
+        )
+        return jnp.sum(jnp.sin(y)) + aux["lb"], aux["f"][0] * 2 * 1024
+
+    grads = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)
+    want = grads(x, router, wg, wu, wd)     # off the TPU: ragged_dot
+    rows_held = float(want[0][1])
+    assert (rows_held > 512) == bool(lean) and 64 < rows_held < 2048
+    kernels_in_the_interpreter(True)
+    got = grads(x, router, wg, wu, wd)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        _close(g, w, 2e-5)
+    assert np.asarray(got[1][2][:1]).any()
+    # (forward, its replay, and the dispatch's transpose)
+    assert set(summed) == ({(512, D)} if sums == "in_the_kernel" else set())
 
 
 @pytest.mark.parametrize("groups", GROUPS, ids=str)

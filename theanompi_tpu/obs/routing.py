@@ -19,6 +19,16 @@ sync of its own — then keeps the LAST step's counters here:
   those experts computed in that step, a layer (``picks * held / E``
   at balance).  ``f`` and with it ``moe_load_max_over_mean`` stay
   over all ``E``: the router is whole;
+- ``moe_rows_bound`` and ``moe_held_overflow_layers`` — beside them:
+  the static length ``R`` a held layer call lays its rows out at
+  (``parallel.moe.held_rows_bound``, the function the layer itself
+  uses, of the step's picks a layer) and how many layer calls of that
+  step held more rows than ``R``, so took more than one pass of
+  ``moe._held_windows``' loop over windows of ``R`` rows (0 near
+  balance).  Exact where one shard holds the step's tokens; over
+  ``dp`` data shards each lays out its own ``picks / dp`` rows and
+  only their sums come here, so a call that ONE shard alone took
+  further may not show;
 - ``moe_bias_abs_max`` — a sigmoid router's selection bias after
   that step, its largest size over layers and experts (0 at the
   start, a rate a step at most).
@@ -54,8 +64,15 @@ def moe_counters(routing, picks: int, *, held: int | None = None,
         "moe_dropped_picks": int(round(float(dropped.sum()))),
     }
     if held is not None:
+        # (imported here: this module is read before any model is)
+        from theanompi_tpu.parallel.moe import held_rows_bound
+
+        rows_held = rows[:, :held].sum(axis=1)
+        bound = held_rows_bound(int(picks), int(held), share.shape[1])
         _LAST["moe_experts_held"] = int(held)
-        _LAST["moe_rows_held"] = rows[:, :held].sum(axis=1).tolist()
+        _LAST["moe_rows_held"] = rows_held.tolist()
+        _LAST["moe_rows_bound"] = bound
+        _LAST["moe_held_overflow_layers"] = int((rows_held > bound).sum())
     if bias_abs_max is not None:
         _LAST["moe_bias_abs_max"] = float(np.max(bias_abs_max))
     return _LAST

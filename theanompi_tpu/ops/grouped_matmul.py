@@ -47,10 +47,10 @@ not a knob.
 ``sum(group_sizes)`` equals ``M`` (every row has a group), as it
 does for the expert layer's ``k * N`` picks — or, under a plan made
 with ``prefix=True``, is at most ``M``: the groups are a held range of
-the experts, whose rows are a prefix of the sorted picks.  The
-kernels then run over that prefix alone (device time follows the rows
-held, not ``M``), (a) and (b) give zeros past it and (c) never reads
-past it; the kernels' own text is the same for both.
+the experts and ``M`` the layer's static bound on their sorted rows
+(``moe.held_rows_bound``; all ``k * N`` past it).  The kernels' time
+follows the rows held, not ``M``; (a) and (b) give zeros past them,
+(c) never reads past them; the kernels' own text is the same for both.
 """
 
 from __future__ import annotations
@@ -361,7 +361,7 @@ def shapes_tile(n_rows: int, k: int, n: int, dtype) -> bool:
     )
 
 
-def _same_vma(*trees):
+def same_vma(*trees):
     """Every array of ``trees`` typed varying over the union of their
     varying mesh axes (the checked ``shard_map``): a kernel's operands
     and outputs share one type.  Done OUTSIDE the ``custom_vjp``, so
@@ -529,5 +529,5 @@ def grouped_matmul(lhs, rhs, plan: TilePlan, *, interpret: bool = False):
     ``rhs``; the backward reuses ``plan``.  ``interpret=True`` runs
     the kernels in the Pallas interpreter (how the CPU tests do)."""
     return _grouped_jit(
-        *_same_vma(lhs, rhs.astype(lhs.dtype), plan), interpret
+        *same_vma(lhs, rhs.astype(lhs.dtype), plan), interpret
     )

@@ -44,7 +44,7 @@ Design:
   tile plan built from the per-expert row counts per layer call
   (PERF.md, PR 31); elsewhere — CPU tests, the CPU mesh, odd shapes —
   ``lax.ragged_dot`` with the same counts, for its CPU lowering,
-  autodiff and vma rules (``_grouped_product`` chooses, and logs a
+  autodiff and vma rules (``_tile_plan`` chooses, and logs a
   ``ragged_dot`` choice once per shape).  Each row's gate
   multiplies its hidden activations
   in fp32 inside the ``silu·up`` fusion (the down product is linear),
@@ -53,6 +53,19 @@ Design:
   expert order) and combine (expert order back, summed) are each
   other's transpose and carry each other as backward rule: gathers
   both ways, never a scatter-add.
+- **A held range of the experts** (``held``: one expert-parallel
+  rank's share of the layer, by itself): its rows are a prefix of the
+  sorted picks, a quarter or an eighth of them at balance.  Only the
+  sort's int32 arrays stay ``k·N`` long; every float array is laid
+  out at ``R = held_rows_bound(k·N, held, E)`` rows, twice the
+  balanced share, and a token's sum is formed slot by slot from
+  those.  Exact for every routing: the layer runs in a loop over
+  windows of ``R`` sorted rows that near balance makes ONE pass and
+  for a routing past ``R`` as many as hold its rows
+  (``_held_windows``); no pick is ever skipped.  Where a window is a
+  larger source than XLA gathers fast from (``_sum_in_kernel``) the
+  picks are sorted by (expert, token) and a token's sum is formed by
+  the kernel of ``ops/held_rows_sum.py`` over the window's rows.
 - **Spans**: ``jax.named_scope``s ``moe_route`` (router, top-k, aux
   moments), ``moe_dispatch`` (plan + row gather / capacity buffers),
   ``moe_experts`` (the products; inside it ``moe_tile_plan``, the
@@ -79,6 +92,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from theanompi_tpu.ops import attention
 from theanompi_tpu.ops import grouped_matmul as gmm
+from theanompi_tpu.ops import held_rows_sum as hrs
 from theanompi_tpu.ops.layers import swiglu
 from theanompi_tpu.parallel.mesh import EXPERT_AXIS, MODEL_AXIS
 
@@ -220,40 +234,79 @@ def shared_expert(x, w_gate, w_up, w_down, model_axis=MODEL_AXIS):
 # gather from the N token rows, never the scatter-add autodiff would
 # write.
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _gather_sorted(x2, order, inv, k):
-    """``x2 [N, D]`` -> the ``k·N`` rows in expert order: row ``i`` is
-    token ``order[i] % N``."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _gather_sorted(x2, order, inv, k, groups=0):
+    """``x2 [N, D]`` -> the rows ``order`` lists (all ``k·N`` picks, or
+    a window of ``R`` of them) in expert order: row ``i`` is token
+    ``order[i] % N``.  ``groups``: ``_sum_picks``'."""
     return x2[order % x2.shape[0]]
 
 
-def _gather_sorted_fwd(x2, order, inv, k):
-    return _gather_sorted(x2, order, inv, k), (order, inv)
+def _gather_sorted_fwd(x2, order, inv, k, groups):
+    return _gather_sorted(x2, order, inv, k, groups), (order, inv)
 
 
-def _gather_sorted_bwd(k, res, ct):
+def _gather_sorted_bwd(k, groups, res, ct):
     with jax.named_scope("moe_dispatch"):
-        return _sum_picks(ct, *res, k).astype(ct.dtype), None, None
+        return _sum_picks(ct, *res, k, groups).astype(ct.dtype), None, None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _sum_picks(rows, order, inv, k):
-    """Rows in expert order ``[k·N, D]`` -> each token's ``k`` rows
-    summed in fp32, ``[N, D]``."""
-    return jnp.sum(
-        rows[inv].reshape(k, -1, rows.shape[-1]).astype(jnp.float32), axis=0
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _sum_picks(rows, order, inv, k, groups=0):
+    """Rows in expert order -> each token's ``k`` rows summed in fp32,
+    ``[N, D]``.  ``rows`` are all ``k·N`` sorted rows, or a window of
+    ``R`` of them (``order`` is then as short, and ``inv [k·N]`` each
+    pick's place in the window): a pick sorted outside it adds a zero
+    row, and the sum is formed slot by slot, a gather of ``N`` rows
+    each, so nothing ``k·N`` rows long is written.  ``groups`` > 0
+    (``_sum_in_kernel``): the window's rows are sorted by (expert,
+    token) over that many experts, ``order`` is -1 for a row that is
+    no held pick, and the sum runs in ``ops/held_rows_sum.py``'s
+    kernel."""
+    r, n = rows.shape[0], inv.shape[0] // k
+    if groups:
+        rows, tok = gmm.same_vma(
+            (rows, jnp.where(order >= 0, order % n, -1))
+        )[0]
+        return hrs.held_rows_sum(rows, tok, n, groups)
+    if r == k * n:
+        return jnp.sum(
+            rows[inv].reshape(k, -1, rows.shape[-1]).astype(jnp.float32),
+            axis=0,
+        )
+    # (plain lax on arrays kept [1, N, .], and as few operations a slot
+    # as there can be: the set-up seconds follow their number, three
+    # sums a layer call and k slots a sum; PERF.md, PR 44)
+    d = rows.shape[-1]
+    at = inv.reshape(k, n, 1)
+    here = lax.split((at >= 0) & (at < r), (1,) * k)
+    at = lax.split(lax.clamp(0, at, r - 1), (1,) * k)
+    rows_of = lax.GatherDimensionNumbers(
+        offset_dims=(2,), collapsed_slice_dims=(0,), start_index_map=(0,))
+    # (typed as the rows are ONCE, not by every slot's select and add)
+    (zero, y), _ = gmm.same_vma(
+        (jnp.zeros((1, n, d), rows.dtype), jnp.zeros((1, n, d), jnp.float32)),
+        rows,
     )
+    for at_j, here_j in zip(at, here):      # slot order, as the sum above
+        row = lax.gather(rows, at_j, rows_of, (1, d),
+                         mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+        row = lax.select(lax.broadcast_in_dim(here_j, row.shape, (0, 1, 2)),
+                         row, zero)
+        y = y + row.astype(jnp.float32)
+    return y.reshape(n, d)
 
 
-def _sum_picks_fwd(rows, order, inv, k):
+def _sum_picks_fwd(rows, order, inv, k, groups):
     # (an empty slice carries the rows' dtype to the backward rule)
-    return _sum_picks(rows, order, inv, k), (order, inv, rows[:0])
+    return _sum_picks(rows, order, inv, k, groups), (order, inv, rows[:0])
 
 
-def _sum_picks_bwd(k, res, ct):
+def _sum_picks_bwd(k, groups, res, ct):
     order, inv, like = res
     with jax.named_scope("moe_combine"):
-        return _gather_sorted(ct.astype(like.dtype), order, inv, k), None, None
+        return (_gather_sorted(ct.astype(like.dtype), order, inv, k, groups),
+                None, None)
 
 
 _gather_sorted.defvjp(_gather_sorted_fwd, _gather_sorted_bwd)
@@ -287,27 +340,52 @@ def _log_ragged_choice(rows: int, d: int, f: int, dtype: str,
     )
 
 
-def _grouped_product(group_sizes, rows: int, d: int, f: int, dtype,
-                     prefix: bool = False):
-    """``product(lhs [rows, .], w [E, ., .])`` for the layer's three
-    grouped products: on a TPU, shapes permitting, the repo's kernels
-    over one tile plan built HERE, once, and shared by all of them,
-    forward, replay and backward; ``lax.ragged_dot`` otherwise.
-    ``prefix``: the groups cover only the first ``sum(group_sizes)``
-    rows (a held range of the experts); the products of the rows past
-    them are zero, forward and backward (the kernels' prefix plan; off
-    the TPU ``lax.ragged_dot``'s own lowering; refused on a TPU whose
-    shapes do not tile)."""
+def _window_sizes(group_sizes, start, rows: int):
+    """How many of each group's sorted rows lie in ``[start, start +
+    rows)``."""
+    ends = jnp.cumsum(group_sizes) - start
+    return (jnp.clip(ends, 0, rows)
+            - jnp.clip(ends - group_sizes, 0, rows)).astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _window_plan(group_sizes, window, rows: int, block_rows: int):
+    """The prefix tile plan of window ``window`` (``rows`` sorted rows
+    each) of a held layer call.  One jitted function for the plan
+    made outside the layer's loop and the one in it, forward and
+    backward, of every layer call: traced once a process and lowered
+    once a program, where each call site traced ``make_tile_plan``'s
+    ninety operations again (the set-up seconds follow them; PERF.md,
+    PR 44)."""
+    return gmm.make_tile_plan(
+        _window_sizes(group_sizes, window * rows, rows), rows, block_rows,
+        prefix=True,
+    )
+
+
+def _tile_plan(group_sizes, rows: int, d: int, f: int, dtype,
+               prefix: bool = False, *, window=None):
+    """The ONE tile plan of a layer call's three grouped products over
+    ``rows`` sorted rows, shared forward, replay and backward — on a
+    TPU whose shapes the repo's kernels tile; ``None`` where
+    ``lax.ragged_dot`` runs them.  ``prefix``: the groups cover only
+    the first ``sum(group_sizes)`` rows (a held range of the experts);
+    the products of the rows past them are zero, forward and backward
+    (the kernels' prefix plan; off the TPU ``lax.ragged_dot``'s own
+    lowering; refused on a TPU whose shapes do not tile).  ``window``:
+    the plan of sorted rows ``[window * rows, (window + 1) * rows)`` of
+    a held layer call's groups (``_window_plan``)."""
     on_tpu = attention._on_tpu()
     if (on_tpu and gmm.shapes_tile(rows, d, f, dtype)
             and gmm.shapes_tile(rows, f, d, dtype)):
         with jax.named_scope("moe_tile_plan"):
-            plan = checkpoint_name(
+            plan = (
                 gmm.make_tile_plan(group_sizes, rows, gmm.tile_rows(rows),
-                                   prefix=prefix),
-                gmm.TILE_PLAN_RESIDUAL,
+                                   prefix=prefix)
+                if window is None else
+                _window_plan(group_sizes, window, rows, gmm.tile_rows(rows))
             )
-        return lambda lhs, w: gmm.grouped_matmul(lhs, w, plan)
+            return checkpoint_name(plan, gmm.TILE_PLAN_RESIDUAL)
     if prefix and on_tpu:
         # XLA's own grouped kernels on the chip compute the rows past
         # ``sum(group_sizes)`` with the last group's weights (PERF.md,
@@ -319,7 +397,184 @@ def _grouped_product(group_sizes, rows: int, d: int, f: int, dtype,
             f"past its groups zero"
         )
     _log_ragged_choice(rows, d, f, str(dtype), on_tpu)
+    return None
+
+
+def _product_over(group_sizes, plan):
+    """``product(lhs [rows, .], w [E, ., .])`` of a layer call: the
+    kernels over ``plan``, or ``lax.ragged_dot`` where there is none
+    (``_tile_plan``)."""
+    if plan is not None:
+        return lambda lhs, w: gmm.grouped_matmul(lhs, w, plan)
     return lambda lhs, w: lax.ragged_dot(lhs, w, group_sizes)
+
+
+def _grouped_product(group_sizes, rows: int, d: int, f: int, dtype,
+                     prefix: bool = False):
+    """``_product_over`` the plan ``_tile_plan`` builds HERE."""
+    return _product_over(
+        group_sizes, _tile_plan(group_sizes, rows, d, f, dtype, prefix)
+    )
+
+
+# A held range's rows are a prefix of the sorted picks, ``k·N · held /
+# E`` of them at balance.  Twice that is the static length every float
+# array of the layer has (``held_rows_bound``): a router that keeps
+# within a few percent of balance (a share by itself holds its router;
+# PERF.md, PR 37) never passes it, and for one that does the layer's
+# loop takes further passes over the rows past it (``_held_windows``).
+_BOUND_OVER_BALANCE = 2
+
+
+def held_rows_bound(picks: int, held: int | None, n_experts: int) -> int:
+    """``R``: how many of a layer call's ``picks`` sorted (token, pick)
+    rows the dropless layer lays out at a time when it holds experts
+    ``[0, held)`` of ``n_experts`` — ``_BOUND_OVER_BALANCE`` times the
+    held experts' rows at balance, rounded up to the grouped kernels'
+    row tile, and ``picks`` where that leaves nothing to skip (``held``
+    is ``None``, every expert is held, the held ones are half of them
+    or more).  A function of what the layer is called with, not a
+    knob; ``obs/routing.py`` counts a step's layer calls against it."""
+    if held is None or _BOUND_OVER_BALANCE * held >= n_experts:
+        return picks
+    tile = gmm.tile_rows(picks) or 8
+    rows = -(-_BOUND_OVER_BALANCE * picks * held // n_experts)
+    return min(picks, -(-rows // tile) * tile)
+
+
+# XLA gathers at the memory's rate only from a source its memory-space
+# assignment keeps on the chip: on the v5e the step lies between 108
+# and 126 MB, and past it a row costs 41 ns, real or masked (PERF.md,
+# PR 44).  A window of sorted rows larger than this is summed by the
+# kernel of ``ops/held_rows_sum.py``.
+_ON_CHIP_SOURCE_BYTES = 96 * 2 ** 20
+
+
+def _sum_in_kernel(rows: int, n: int, d: int, dtype) -> bool:
+    """Whether a held layer call sums its tokens' rows out of a window
+    of ``rows`` sorted rows in the kernel: on a TPU, where the window
+    is a larger source than XLA gathers fast from and the kernel's
+    blocks divide the shapes.  The sort then goes by (expert, token),
+    which is what the kernel's static grid counts on."""
+    return (attention._on_tpu()
+            and rows * d * jnp.dtype(dtype).itemsize > _ON_CHIP_SOURCE_BYTES
+            and hrs.shapes_tile(rows, n, d))
+
+
+def _sorted_experts(x2, gates, we_gate, we_up, we_down, order, inv, product,
+                    *, k: int, model_axis, groups: int = 0):
+    """The sorted rows ``order`` lists through the layer: gathered,
+    the grouped SwiGLU ``product()`` gives with each row's gate folded
+    into its hidden activations, and each token's rows summed.  ``[N,
+    D]`` fp32.  ``order`` is all ``k·N`` picks with ``inv`` its
+    inverse, or a window of ``R`` of them with ``inv`` each pick's
+    place in that window (a pick outside it adds nothing, and the
+    gates get no gradient).  Every float array is as long as
+    ``order``."""
+    with jax.named_scope("moe_dispatch"):
+        rows = _gather_sorted(x2, order, inv, k, groups)
+        row_gate = _permute(gates.T.reshape(-1), order, inv)
+    with jax.named_scope("moe_experts"):
+        out = _swiglu_experts(
+            rows, we_gate, we_up, we_down, product(), row_scale=row_gate,
+        )
+        if model_axis is not None:
+            out = lax.psum(out, model_axis)               # close row-parallel
+    with jax.named_scope("moe_combine"):
+        return _sum_picks(out, order, inv, k, groups)
+
+
+def _window(w, floats, rest, *, k: int, bound: int, groups: int):
+    """Sorted rows ``[w * bound, (w + 1) * bound)`` of a held layer
+    call through ``_sorted_experts``, over ``(floats, rest)`` = ``((x2,
+    we_gate, we_up, we_down), (gates, order, inv, group_sizes,
+    plan))``: ``order`` padded to whole windows, ``plan`` window 0's,
+    made where the layer's remat keeps it (a later window makes its
+    own).  ``w`` is the loop's counter; ``groups``: ``_sum_picks``'."""
+    x2, we_gate, *leaves = floats
+    gates, order, inv, sizes, plan = rest
+    with jax.named_scope("moe_dispatch"):
+        order = lax.dynamic_slice(order, (w * bound,), (bound,))
+        inv = inv - w * bound
+        if groups:          # the kernel's sum: no token past the held rows
+            place = w * bound + jnp.arange(bound, dtype=jnp.int32)
+            order = jnp.where(place < jnp.sum(sizes), order, -1)
+
+    def product():
+        if plan is None:                # off the TPU: lax.ragged_dot
+            return _product_over(_window_sizes(sizes, w * bound, bound), None)
+        return _product_over(None, lax.cond(
+            w == 0, lambda: plan, lambda: _tile_plan(
+                sizes, bound, *we_gate.shape[1:], x2.dtype, prefix=True,
+                window=w)))
+
+    return _sorted_experts(x2, gates, we_gate, *leaves, order, inv, product,
+                           k=k, model_axis=None, groups=groups)
+
+
+def _window_bwd(w, floats, rest, ct, **how):
+    """``_window`` replayed and transposed: ``ct``'s gradient to
+    ``floats``."""
+    replay = jax.checkpoint(
+        lambda floats: _window(w, floats, rest, **how), prevent_cse=False,
+    )
+    return jax.vjp(replay, floats)[1](ct)[0]
+
+
+def _over_windows(one, zero, floats, rest, *more, bound: int, **how):
+    """``zero`` plus ``one(w, floats, rest, *more)`` over the windows
+    of ``bound`` sorted rows that hold rows of the held experts:
+    window 0 always, the others only for a routing past the bound.
+    ONE loop and one traced body for both: a second body for the
+    windows past the first — traced, lowered, its kernels compiled and
+    loaded beside the first, never run near balance — was 3 to 4 s of
+    a run's set-up (PERF.md, PR 44)."""
+    *_, group_sizes, _ = rest
+    windows = jnp.maximum(1, -(-jnp.sum(group_sizes) // bound))
+
+    def another(carry):
+        w, acc = carry
+        return w + 1, jax.tree.map(
+            lax.add, acc, one(w, floats, rest, *more, bound=bound, **how))
+
+    # (the sums typed as the operands are, under a checked shard_map)
+    zero = gmm.same_vma(zero, floats)[0]
+    return lax.while_loop(
+        lambda carry: carry[0] < windows, another,
+        (jnp.zeros((), jnp.int32), zero),
+    )[1]
+
+
+def _all_windows(k, bound, groups, floats, rest):
+    """A held layer call, exact for every routing: the first ``bound``
+    sorted rows through the layer, and — only where the held experts'
+    rows pass them — the next ``bound`` and so on, each window's part
+    of every token's sum added (``_over_windows``).  Near balance the
+    loop runs one pass; at worst (every pick held) ``k·N / bound`` of
+    them.  No array is ever longer than ``bound``.
+
+    Differentiated by a rule of its own (``_held_windows``; a
+    ``while_loop`` has none): every window taken is replayed and
+    transposed, the windows' gradients summed in the same order.
+    ``rest`` gets none: the gates of a held share carry no gradient
+    (``moe_ffn``)."""
+    x2 = floats[0]
+    return _over_windows(_window, jnp.zeros(x2.shape, jnp.float32),
+                         floats, rest, k=k, bound=bound, groups=groups)
+
+
+def _held_windows_fwd(k, bound, groups, floats, rest):
+    return _all_windows(k, bound, groups, floats, rest), (floats, rest)
+
+
+def _held_windows_bwd(k, bound, groups, res, ct):
+    floats, _ = res
+    return _over_windows(_window_bwd, jax.tree.map(jnp.zeros_like, floats),
+                         *res, ct, k=k, bound=bound, groups=groups), None
+
+
+_held_windows = jax.custom_vjp(_all_windows, nondiff_argnums=(0, 1, 2))
+_held_windows.defvjp(_held_windows_fwd, _held_windows_bwd)
 
 
 def _dropless_experts(x2, gates, eidx, we_gate, we_up, we_down, *,
@@ -335,35 +590,76 @@ def _dropless_experts(x2, gates, eidx, we_gate, we_up, we_down, *,
     experts' alone, the products compute that prefix and give zeros
     past it, and a pick of an expert not held adds exactly zero to
     its token's sum, forward and backward.  Nothing stands in for the
-    ranks that hold the rest."""
+    ranks that hold the rest.
+
+    What is ``k·N`` long: the sort's three int32 arrays (``order``,
+    ``inv``, the picks' expert ids).  Every float array — the gathered
+    rows ``[., D]``, their gates, the products' ``[., F]`` and ``[.,
+    D]``, their gradients — is ``R = held_rows_bound(k·N, held,
+    n_experts)`` long: ``k·N`` with all experts here, and under a held
+    range twice that range's rows at balance.  The held rows of a
+    routing that passes ``R`` are not skipped: the loop the layer
+    runs in (``_held_windows``; one pass near balance) goes on over
+    the next ``R`` sorted rows, and the next, until it has them all,
+    so ``aux["dropped"]`` stays 0 by construction.  Inside an expert
+    the rows stay slot-major; where the tokens' sums run in the kernel
+    (``_sum_in_kernel``) they go token by token instead, the order its
+    visits count on."""
     n, _ = x2.shape
     k = eidx.shape[1]
+    bound = held_rows_bound(k * n, held, n_experts)
     if held is not None:
         n_experts = held        # the groups: the experts that are here
+    # where the tokens' sums run in the kernel: how many experts' rows
+    # it counts on, sorted by (expert, token)
+    groups = (n_experts if bound < k * n
+              and _sum_in_kernel(bound, *x2.shape, x2.dtype) else 0)
     with jax.named_scope("moe_dispatch"):
         flat_e = eidx.T.reshape(-1).astype(jnp.int32)     # slot-major [k*N]
         picks = jnp.arange(k * n, dtype=jnp.int32)
-        # stable: inside an expert the rows stay slot-major, so the
-        # order (and the sums below) never hang on a tie-break
-        _, order = lax.sort((flat_e, picks), num_keys=1, is_stable=True)
+        # stable: inside an expert the rows stay slot-major (or, for
+        # the kernel, go token by token), so the order (and the sums
+        # below) never hang on a tie-break
+        _, order = lax.sort(
+            (flat_e * n + picks % n if groups else flat_e, picks),
+            num_keys=1, is_stable=True)
         _, inv = lax.sort((order, picks), num_keys=1)
         group_sizes = jnp.sum(
             flat_e[:, None] == jnp.arange(n_experts, dtype=jnp.int32)[None],
             axis=0, dtype=jnp.int32,
         )
-        rows = _gather_sorted(x2, order, inv, k)          # [k*N, D]
-        row_gate = _permute(gates.T.reshape(-1), order, inv)
-    with jax.named_scope("moe_experts"):
-        out = _swiglu_experts(
-            rows, we_gate, we_up, we_down,
-            _grouped_product(group_sizes, k * n, *we_gate.shape[1:],
-                             rows.dtype, prefix=held is not None),
-            row_scale=row_gate,
+    shape = (*we_gate.shape[1:], x2.dtype)
+    if bound == k * n:
+        return _sorted_experts(
+            x2, gates, we_gate, we_up, we_down, order, inv,
+            lambda: _grouped_product(group_sizes, k * n, *shape,
+                                     prefix=held is not None),
+            k=k, model_axis=model_axis,
         )
-        if model_axis is not None:
-            out = lax.psum(out, model_axis)               # close row-parallel
-    with jax.named_scope("moe_combine"):
-        return _sum_picks(out, order, inv, k)
+    if (k * n) % bound:
+        with jax.named_scope("moe_dispatch"):
+            order = jnp.pad(order, (0, -(k * n) % bound))     # whole windows
+    with jax.named_scope("moe_experts"):
+        # the first window's plan, made where the layer's remat keeps
+        # it (a later window makes its own, in the loop)
+        plan = _tile_plan(group_sizes, bound, *shape, prefix=True, window=0)
+    # one type for every operand and every window's result under a
+    # checked shard_map, cast OUTSIDE the backward rule: the cast's own
+    # transpose sums a gradient over the axes its operand did not vary
+    # on (``gmm.same_vma``), and no collective runs in the loop.  The
+    # leaves are cast out here too: their gradients then come back as
+    # the kernels write them, in the compute dtype
+    floats, gates = gmm.same_vma(
+        (x2, *(w.astype(x2.dtype) for w in (we_gate, we_up, we_down))),
+        lax.stop_gradient(gates),
+    )
+    y = _held_windows(
+        k, bound, groups, floats, (gates, order, inv, group_sizes, plan)
+    )
+    if model_axis is not None:
+        with jax.named_scope("moe_experts"):
+            y = lax.psum(y, model_axis)                   # close row-parallel
+    return y
 
 
 # -- capacity: fixed buffers, drops ------------------------------------------
@@ -480,7 +776,12 @@ def moe_ffn(
       the returned ``y`` is the held experts' part of every token's
       sum, and ``aux["f"]`` stays the pick fractions over ALL experts.
       With ``held < n_experts`` the gates carry no gradient to the
-      router (below): a share by itself holds it.
+      router (below): a share by itself holds it.  With less than
+      half the experts held the layer's float arrays are
+      ``held_rows_bound(k·N, held, n_experts)`` rows long, not
+      ``k·N``; a routing that sends the held experts more rows than
+      that is computed all the same, ``R`` rows at a time
+      (``_dropless_experts``), and ``aux["dropped"]`` stays 0.
 
     Returns ``(y [B, T_loc, D], aux)`` with ``aux = {"lb": load
     balance loss, "z": router z-loss, "f": [E] pick fractions, "p":
