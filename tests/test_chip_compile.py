@@ -812,6 +812,52 @@ def test_latent_attention_hands_the_kernels_products_not_joins(
     assert phases == product_phases == {"fwd", "replay", "bwd"}
 
 
+def test_grouped_query_attention_hands_the_kernels_products_not_relays(
+    chip, monkeypatch
+):
+    """A small GQA 2:1 decoder's step (PR 45): between ``attn_norm``
+    and the flash kernels no activation is re-laid or lane-sliced.
+    Under ``gqa_proj`` — the three products, the two rotations, the
+    repeat — stand instructions of the forward, the replay and the
+    backward, products among them in each, and none of them is a
+    ``copy`` or a ``transpose`` of a ``[B, H, T, hd]`` activation
+    (what is copied there is a weight's bf16 cast or a ``[T, hd]``
+    rotary table); the whole text holds no stride-2 ``slice`` and no
+    array of a row's halves; three flash kernels a layer take what
+    they took."""
+    import re
+
+    from benchmark.layer_metrics import _scopes
+
+    heads, kv_heads, hd, b, t = 4, 2, 128, 2, 256
+    text = _llama_step_text(chip, monkeypatch, dim=512, n_heads=heads,
+                            n_kv_heads=kv_heads)
+    assert _flash_kernels(text) == dict(fwd=2, dkv=2, dq=2)
+    # ``rope``'s old ``x[..., 0::2]`` / ``x[..., 1::2]``: a stride-2
+    # slice, which XLA:TPU runs over arrays of the pairs' halves
+    assert not re.findall(r"slice=\{[^}]*\[\d+:\d+:2\][^}]*\}", text)
+    halves = re.findall(rf"\b(?:bf16|f32)\[{b},\d+,{t},{hd // 2}\b[^\]]*\]", text)
+    assert not halves, sorted(set(halves))
+    names = _scopes._under(text, "gqa_proj")
+    products = _step_blocks(text)[3]
+    activations = {tuple(sorted((b, h, t, hd))) for h in (heads, kv_heads)}
+    phases, product_phases, relays = set(), set(), []
+    for ln in text.splitlines():
+        m = re.match(
+            r"\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(", ln)
+        if not (m and m.group(1) in names):
+            continue
+        phases.add("replay" if "rematted_computation" in ln
+                   else "bwd" if "transpose(" in ln else "fwd")
+        if m.group(1) in products:
+            product_phases.add(products[m.group(1)]["phase"])
+        dims = tuple(sorted(int(d) for d in m.group(2).split(",") if d))
+        if m.group(3) in ("copy", "transpose") and dims in activations:
+            relays.append(ln.strip()[:160])
+    assert phases == product_phases == {"fwd", "replay", "bwd"}
+    assert not relays, relays
+
+
 # -- window and full attention layers mixed (PR 41) ---------------------------
 
 
@@ -958,9 +1004,13 @@ def test_older_decoders_lower_to_the_text_they_had(chip, monkeypatch, name):
     (``tests/data/older_step_census.json``, taken on the parent of
     PR 37, 30388345, where the whole texts were equal, the kernels'
     serialized bodies and the lowering's numbering of its private
-    functions apart) is what it was.  After a change that is MEANT to
-    move one of them, or another jax, the assertion shows what moved;
-    record anew with ``_text_census``."""
+    functions apart; recorded anew in PR 45, which MEANT to move all
+    three: grouped-query attention's products write the kernels'
+    layout and the rotation is one pass, so the gathers, scatters and
+    half-row arrays of the stride-2 slices went) is what it was.
+    After a change that is MEANT to move one of them, or another jax,
+    the assertion shows what moved; record anew with
+    ``_text_census``."""
     import json
     from pathlib import Path
 

@@ -29,7 +29,8 @@ from jax.sharding import PartitionSpec as P
 
 from benchmark.reference import glm_moe_lite as ref
 from benchmark.run import program_knobs
-from theanompi_tpu.models.llama import Llama, _heads, rms_norm, rope, rope_tail
+from test_gqa_proj import rope_stride2
+from theanompi_tpu.models.llama import Llama, _heads, rms_norm, rope_tail
 from theanompi_tpu.parallel import make_mesh, moe
 from theanompi_tpu.parallel import tp as tp_lib
 from theanompi_tpu.utils import Recorder
@@ -248,9 +249,9 @@ def _mla_qkv_sliced(model, p, xn, pos):
     cq = rms_norm(xn @ p["wq_a"].astype(xn.dtype), p["q_a_norm"], eps)
     q = _heads(cq @ p["wq_b"].astype(xn.dtype), h, model.head_dim)
     q = jnp.concatenate(
-        [q[..., :nope], rope(q[..., nope:], pos, theta)], axis=-1)
+        [q[..., :nope], rope_stride2(q[..., nope:], pos, theta)], axis=-1)
     ckv = xn @ p["wkv_a"].astype(xn.dtype)
-    k_rope = rope(ckv[:, None, :, rank:], pos, theta)
+    k_rope = rope_stride2(ckv[:, None, :, rank:], pos, theta)
     ckv = rms_norm(ckv[..., :rank], p["kv_a_norm"], eps)
     kv = _heads(ckv @ p["wkv_b"].astype(xn.dtype), h, nope + model.v_head_dim)
     k = jnp.concatenate([
@@ -312,16 +313,18 @@ def test_mla_operands_equal_the_sliced_form(dtype, tol):
 
 @pytest.mark.parametrize("nope", [0, 12, 16])
 def test_rope_tail_is_rope_on_the_tail(nope):
-    """One pass over the whole row against ``rope`` on a slice, joined
-    back: values, and the gradient — the rotation by the negative
-    angle — against autodiff of the sliced form."""
+    """One pass over the whole row against the stride-2 rotation
+    (``rope`` as it was until PR 45: the oracle of
+    ``tests/test_gqa_proj.py``) on a slice, joined back: values, and
+    the gradient — the rotation by the negative angle — against
+    autodiff of the sliced form."""
     x = jax.random.normal(jax.random.key(11), (2, 3, 8, 16), jnp.float32)
     ct = jax.random.normal(jax.random.key(12), x.shape, jnp.float32)
     pos = jnp.arange(5, 13)
 
     def sliced(x):
         return jnp.concatenate(
-            [x[..., :nope], rope(x[..., nope:], pos, 1e4)], axis=-1)
+            [x[..., :nope], rope_stride2(x[..., nope:], pos, 1e4)], axis=-1)
 
     got, got_vjp = jax.vjp(lambda x: rope_tail(x, pos, 1e4, nope), x)
     want, want_vjp = jax.vjp(sliced, x)

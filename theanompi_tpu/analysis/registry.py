@@ -158,6 +158,11 @@ PROFILE_SCOPES: dict[str, str] = {
     # window layers'
     "attn_sliding": "attn_sliding",
     "attn_full": "attn_full",
+    # grouped-query attention between ``attn_norm`` and the flash
+    # kernels, inside ``blk_attn`` and a kind's scope (models/llama.py
+    # ``_gqa_qkv``, PR 45); benchmark/layer_metrics/gqa_proj_ms.py
+    # reads the label
+    "gqa_proj": "gqa_proj",
     # the step program's blocks (models/llama.py ``_forward`` /
     # ``_layer`` / ``loss_fn``, ops/layers.py, models/base.py, PR 35):
     # with ``opt_update`` and ``exchange_b<i>`` every instruction of a

@@ -49,7 +49,10 @@ set never materializes on one device.
 
 Architecture per Llama-3: RMSNorm, RoPE, grouped-query attention,
 SwiGLU MLP, untied LM head; ``qk_norm`` adds an RMSNorm over the whole
-projected width of q and of k, before the head split and RoPE.  The
+projected width of q and of k, before RoPE.  q, k and v leave their
+products in the attention kernels' ``[B, H, T, hd]`` layout and RoPE is
+one pass over the whole row (``_gqa_qkv``, ``rope``): no activation is
+re-laid or lane-sliced between the block's norm and the kernels.  The
 model satisfies the same worker contract as every zoo member, so
 ``BSP().init(modelfile='theanompi_tpu.models.llama',
 modelclass='Llama')`` trains it.
@@ -130,40 +133,93 @@ def _device_bytes_limit(devices) -> int | None:
 
 # -- pure model math (runs on LOCAL shards inside shard_map) ----------------
 
-def rms_norm(x, w, eps=1e-5, sharded_width=None):
-    """RMSNorm over the last dimension.  ``sharded_width``: that
-    dimension is the local shard of ``sharded_width`` channels
+def rms_norm(x, w, eps=1e-5, sharded_width=None, axes=-1):
+    """RMSNorm over the last dimension.  ``sharded_width``: the
+    normalised channels are the local shard of ``sharded_width``
     column-sharded over the model axis, and the statistic is over all
-    of them (QK-norm: the whole projection, not a head or a shard)."""
+    of them (QK-norm: the whole projection, not a head or a shard).
+    ``axes``: where those channels lie when not in the last dimension
+    alone — ``(1, 3)`` for a projection in the attention kernels'
+    ``[B, H_loc, T, hd]`` layout, ``w`` then shaped to broadcast
+    (``[H_loc, 1, hd]``): the same float32 expression as on the flat
+    ``[B, T, H_loc * hd]`` row, the squares summed in place."""
     xf = x.astype(jnp.float32)
     if sharded_width is None:
-        ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
+        ms = jnp.mean(xf * xf, axis=axes, keepdims=True)
     else:
         ms = lax.psum(
-            jnp.sum(xf * xf, axis=-1, keepdims=True), MODEL_AXIS
+            jnp.sum(xf * xf, axis=axes, keepdims=True), MODEL_AXIS
         ) / sharded_width
     scale = lax.rsqrt(ms + eps)
     return (xf * scale).astype(x.dtype) * w.astype(x.dtype)
 
 
-def rope(x, pos, theta=10000.0, inv_freq=None, factor=1.0):
+def rope(x, pos, theta=10000.0, inv_freq=None, factor=1.0, nope=0):
     """Rotary embedding. x: [B, H, T, D], pos: [T] global positions.
-    ``inv_freq`` ``[D/2]``: the pairs' frequencies where they are not
-    ``theta ** (-2i / D)`` (a scaled table: ``rope_table``); ``factor``
-    multiplies cos and sin (YaRN's attention factor)."""
-    d = x.shape[-1]
+    ``inv_freq`` ``[(D - nope) / 2]``: the pairs' frequencies where
+    they are not ``theta ** (-2i / (D - nope))`` (a scaled table:
+    ``rope_table``); ``factor`` multiplies cos and sin (YaRN's
+    attention factor); the first ``nope`` channels stay as they are
+    (cos 1 / sin 0: latent attention's q, ``rope_tail``).
+
+    ONE pass over the whole row, ``x * cos + (x @ swap) * sin`` in
+    float32 (``_rotate``): each pair ``(x1, x2)`` becomes ``(x1 cos -
+    x2 sin, x2 cos + x1 sin)`` with no stride-2 lane slice, no stack
+    and no reshape of the activation; on the chip the sliced form
+    took 4.7 ms of a ``[2, 20, 8192, 256]`` row where this pass takes
+    1.9 with its product (PERF.md §6, PRs 38 and 45)."""
+    r = x.shape[-1] - nope
     if inv_freq is None:
-        inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+        inv = 1.0 / (theta ** (jnp.arange(0, r, 2, dtype=jnp.float32) / r))
     else:
         inv = jnp.asarray(inv_freq, jnp.float32)
-    ang = pos.astype(jnp.float32)[:, None] * inv[None, :]    # [T, D/2]
+    ang = pos.astype(jnp.float32)[:, None] * inv[None, :]    # [T, r/2]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     if factor != 1.0:
         cos, sin = factor * cos, factor * sin
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    y1 = x1 * cos - x2 * sin
-    y2 = x1 * sin + x2 * cos
-    return jnp.stack([y1, y2], axis=-1).reshape(x.shape).astype(x.dtype)
+    # a pair shares its angle
+    cos, sin = jnp.repeat(cos, 2, axis=-1), jnp.repeat(sin, 2, axis=-1)
+    if nope:
+        cos = jnp.pad(cos, ((0, 0), (nope, 0)), constant_values=1.0)
+        sin = jnp.pad(sin, ((0, 0), (nope, 0)))
+    return _rotate(x, cos, sin)
+
+
+@jax.custom_vjp
+def _rotate(x, cos, sin):
+    """``x [B, H, T, D]`` with each channel pair turned by its angle,
+    ``cos`` / ``sin`` ``float32[T, D]`` (a pair's two entries equal):
+    ``x * cos + swap(x) * sin`` with ``swap`` the signed exchange of
+    each pair, ``(x1, x2) -> (-x2, x1)``: a constant ``[D, D]``
+    product on the matrix unit (one input times +-1: exact in ``x``'s
+    dtype; a pair at cos 1 / sin 0 passes as it is).  Backward: a
+    rotation's transpose is the rotation by the negative angle — the
+    same pass over the gradient, rounded once, where autodiff's form
+    rounds ``dy * sin`` before the swap; the tables get no gradient
+    (positions are not learnt)."""
+    d = x.shape[-1]
+    swap = np.zeros((d, d), np.float32)
+    even = np.arange(0, d, 2)
+    swap[even + 1, even] = -1.0               # (x @ swap)[2i] = -x[2i+1]
+    swap[even, even + 1] = 1.0                # (x @ swap)[2i+1] = x[2i]
+    swapped = jnp.matmul(
+        x, jnp.asarray(swap, x.dtype), precision=lax.Precision.HIGHEST
+    )
+    return (
+        x.astype(jnp.float32) * cos + swapped.astype(jnp.float32) * sin
+    ).astype(x.dtype)
+
+
+def _rotate_fwd(x, cos, sin):
+    return _rotate(x, cos, sin), (cos, sin)
+
+
+def _rotate_bwd(res, dy):
+    cos, sin = res
+    return _rotate(dy, cos, -sin), None, None
+
+
+_rotate.defvjp(_rotate_fwd, _rotate_bwd)
 
 
 def rope_table(params: dict, head_dim: int):
@@ -241,57 +297,12 @@ def _unheads(x):
     return x.transpose(0, 2, 1, 3).reshape(b, t, n * d)
 
 
-def _rotate_tail(x, pos, theta, nope, sign):
-    """``x [B, H, T, D]`` with the channels from ``nope`` on rotated
-    by ``sign`` times RoPE's angles, in one pass over the whole row:
-    ``x * cos + swap(x) * sin`` with cos 1 / sin 0 on the first
-    ``nope`` channels and ``swap`` the signed exchange of each rotary
-    pair, ``(x1, x2) -> (-x2, x1)``: a constant ``[D, D]`` product on
-    the matrix unit (one input times +-1: exact in ``x``'s dtype)."""
-    d = x.shape[-1]
-    r = d - nope
-    inv = 1.0 / (theta ** (jnp.arange(0, r, 2, dtype=jnp.float32) / r))
-    ang = pos.astype(jnp.float32)[:, None] * inv[None, :]    # [T, r/2]
-    ang = jnp.repeat(ang, 2, axis=-1)         # a pair shares its angle
-    cos = jnp.pad(jnp.cos(ang), ((0, 0), (nope, 0)), constant_values=1.0)
-    sin = jnp.pad(sign * jnp.sin(ang), ((0, 0), (nope, 0)))
-    swap = np.zeros((d, d), np.float32)
-    even = np.arange(nope, d, 2)
-    swap[even + 1, even] = -1.0               # (x @ swap)[2i] = -x[2i+1]
-    swap[even, even + 1] = 1.0                # (x @ swap)[2i+1] = x[2i]
-    swapped = jnp.matmul(
-        x, jnp.asarray(swap, x.dtype), precision=lax.Precision.HIGHEST
-    )
-    return (
-        x.astype(jnp.float32) * cos + swapped.astype(jnp.float32) * sin
-    ).astype(x.dtype)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
 def rope_tail(x, pos, theta, nope):
     """``rope`` on the channels of ``x [B, H, T, D]`` from ``nope``
     on, the first ``nope`` as they are (latent attention's q: a
-    no-position part and a rotary part in one row), WITHOUT the slice
-    at ``nope``, ``rope``'s stride-2 lane slices and the
-    concatenation back (``_rotate_tail``): on the chip ``rope`` on the
-    slice, joined back, took 2.0 ms of a ``[2, 20, 8192, 256]`` row's
-    3.6 ms projection and this pass takes 0.7 (PERF.md §6, PR 38).
-    The rotation in float32, as ``rope`` does it.  Backward: a
-    rotation's transpose is the rotation by the negative angle — the
-    same pass over the gradient, rounded once, where autodiff's form
-    rounds ``dy * sin`` before the swap."""
-    return _rotate_tail(x, pos, theta, nope, 1.0)
-
-
-def _rope_tail_fwd(x, pos, theta, nope):
-    return _rotate_tail(x, pos, theta, nope, 1.0), pos
-
-
-def _rope_tail_bwd(theta, nope, pos, dy):
-    return _rotate_tail(dy, pos, theta, nope, -1.0), None
-
-
-rope_tail.defvjp(_rope_tail_fwd, _rope_tail_bwd)
+    no-position part and a rotary part in one row): the same one pass,
+    WITHOUT a slice at ``nope`` and a concatenation back."""
+    return rope(x, pos, theta, nope=nope)
 
 
 class Llama(TMModel):
@@ -1141,29 +1152,11 @@ class Llama(TMModel):
         return self._gqa_kind(p, xn, pos, kind)
 
     def _gqa_kind(self, p, xn, pos, kind):
-        eps = self.norm_eps
-        h_loc = self.n_heads // self.tp
-        hkv_loc = self.n_kv_heads // self.tp
-        hd = self.head_dim
-        q = tp_lib.col_parallel(xn, p["wq"])
-        k = tp_lib.col_parallel(xn, p["wk"])
-        if self.qk_norm:
-            q = rms_norm(q, p["q_norm"], eps, self.n_heads * hd)
-            k = rms_norm(k, p["k_norm"], eps, self.n_kv_heads * hd)
-        q, k = _heads(q, h_loc, hd), _heads(k, hkv_loc, hd)
-        v = _heads(tp_lib.col_parallel(xn, p["wv"]), hkv_loc, hd)
-        inv_freq, factor = self._rope_tables[kind]
-        q = rope(q, pos, self.rope_theta, inv_freq, factor)
-        k = rope(k, pos, self.rope_theta, inv_freq, factor)
-        # GQA: KV stays compact on the wire; repeated only at compute
-        rep = h_loc // hkv_loc
+        q, k, v = self._gqa_qkv(p, xn, pos, kind)
         if self.sp == 1:
             # no sequence sharding: skip the ring/all_to_all
             # machinery and hit the fused kernel (reference math
             # off-TPU) directly
-            if rep != 1:
-                k = jnp.repeat(k, rep, axis=1)
-                v = jnp.repeat(v, rep, axis=1)
             return flash_attention(
                 q, k, v, causal=True, window=self.window_of(kind)
             )
@@ -1171,7 +1164,42 @@ class Llama(TMModel):
             ring_attention if self.sp_mode == "ring"
             else ulysses_attention
         )
+        rep = self.n_heads // self.n_kv_heads
         return attn(q, k, v, SEQ_AXIS, causal=True, kv_rep=rep)
+
+    def _gqa_qkv(self, p, xn, pos, kind):
+        """Grouped-query attention's projections, ``xn [B, T, D]`` ->
+        ``q [B, H_loc, T, hd]``, ``k`` and ``v`` for the attention
+        kernels (``sp == 1``: the key/value heads repeated to
+        ``H_loc``) or the sequence-parallel ring (``[B, Hkv_loc, T,
+        hd]``: KV stays compact on the wire), under the scope
+        ``gqa_proj``.  Between ``attn_norm`` and the kernels no
+        activation is re-laid or lane-sliced: each product writes the
+        kernels' layout (``tp.col_parallel_heads``), QK-norm's
+        statistic is taken over that layout (``rms_norm(.., axes=(1,
+        3))``), the
+        rotation by the kind's table is one pass over the whole row
+        (``rope``)."""
+        eps = self.norm_eps
+        h_loc = self.n_heads // self.tp
+        hkv_loc = self.n_kv_heads // self.tp
+        hd = self.head_dim
+        with jax.named_scope("gqa_proj"):
+            q = tp_lib.col_parallel_heads(xn, p["wq"], h_loc)
+            k = tp_lib.col_parallel_heads(xn, p["wk"], hkv_loc)
+            v = tp_lib.col_parallel_heads(xn, p["wv"], hkv_loc)
+            if self.qk_norm:
+                q = rms_norm(q, p["q_norm"].reshape(h_loc, 1, hd), eps,
+                             self.n_heads * hd, axes=(1, 3))
+                k = rms_norm(k, p["k_norm"].reshape(hkv_loc, 1, hd), eps,
+                             self.n_kv_heads * hd, axes=(1, 3))
+            inv_freq, factor = self._rope_tables[kind]
+            q = rope(q, pos, self.rope_theta, inv_freq, factor)
+            k = rope(k, pos, self.rope_theta, inv_freq, factor)
+            if self.sp == 1 and h_loc != hkv_loc:
+                k = jnp.repeat(k, h_loc // hkv_loc, axis=1)
+                v = jnp.repeat(v, h_loc // hkv_loc, axis=1)
+            return q, k, v
 
     def _forward(self, params, ids, head=True, with_aux=False,
                  net_state=None, mtp_ids=None):
