@@ -10,8 +10,8 @@ check of what comes out.
    published width, batch 128, bf16, 224², synthetic data from a seed:
    one ``steps_per_call`` scan chunk, two single steps, one validation
    batch.
-2. ``train_llama`` — the same rule and worker on the Llama proxy as
-   ``bench.build_llama`` defines it (8 layers x 1024, 16/8 heads of
+2. ``train_llama`` — the same rule and worker on the Llama proxy
+   (8 layers x 1024, 16/8 heads of
    64, ffn 2816, vocab 32000, T 2048, batch 4, remat, ``ici16``); the
    compiled step must hold the flash kernels (``tpu_custom_call``),
    and the epoch ends in a checkpoint.
@@ -63,7 +63,7 @@ RESNET50 = dict(
     config=dict(batch_size=128, crop=224, exch_strategy="ici16"),
     n_batches=6, steps_per_call=4,
 )
-#: the bench's Llama proxy (bench.build_llama), widths untouched.
+#: the Llama proxy, widths untouched.
 #: The learning rate is the smoke's own: at the model's default (3e-3,
 #: no warm-up) six steps collapse it onto ONE token whatever the
 #: prompt (first chip run, PR 22), and a model that says one thing

@@ -37,7 +37,7 @@ import sys
 from pathlib import Path
 
 REPO = Path(__file__).resolve().parent.parent
-TARGETS = ["theanompi_tpu", "tests", "scripts", "bench.py"]
+TARGETS = ["theanompi_tpu", "tests", "scripts"]
 
 
 def _external_linter() -> int | None:

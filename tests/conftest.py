@@ -27,7 +27,7 @@ if "xla_force_host_platform_device_count" not in _flags:
 import jax  # noqa: E402
 
 # persistent compile cache: repeat suite runs skip most XLA compiles;
-# shared location with bench/gate so all entry points warm each other
+# shared location with the other entry points so all warm each other
 from theanompi_tpu.utils import enable_compile_cache  # noqa: E402
 
 enable_compile_cache()

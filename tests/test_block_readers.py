@@ -4,7 +4,10 @@ two older scope readers (``_moe.py``, ``_ut.py``) on ``op_name``s that
 nest their names inside the block names: they read what they read
 without them."""
 
+import importlib
+import json
 import re
+from pathlib import Path
 
 import pytest
 
@@ -169,16 +172,31 @@ def test_the_three_phase_forms(op_name, phase):
     assert _blocks.phase_of(op_name) == phase
 
 
-@pytest.mark.parametrize("reader", [
-    attn_block_ms, ffn_block_ms, head_loss_ms, remat_replay_ms,
-    opt_update_ms, bn_block_ms, block_named_share,
-], ids=lambda m: m.__name__.rsplit(".", 1)[-1])
-def test_nothing_to_read_is_none_not_an_exception(reader):
+BENCH = json.loads(
+    (Path(__file__).resolve().parents[1] / "BENCHMARK.json").read_text())
+BLOCK_READERS = (attn_block_ms, ffn_block_ms, head_loss_ms, remat_replay_ms,
+                 opt_update_ms, bn_block_ms, block_named_share)
+
+
+@pytest.mark.parametrize("metric", BENCH["per_layer"],
+                         ids=lambda m: m["name"])
+def test_nothing_to_read_is_none_not_an_exception(metric):
+    """Every per-layer metric of ``BENCHMARK.json``: a reader file of
+    its name, cells that exist, an end-to-end metric it is said to
+    move, and ``None`` — no exception — from a run with nothing to
+    read (the harness then leaves the metric out of the line)."""
+    reader = importlib.import_module(
+        f"benchmark.layer_metrics.{metric['name']}")
+    cells = {w["name"] for w in BENCH["workloads"]}
+    assert metric["workloads"] and set(metric["workloads"]) <= cells
+    assert metric["moves"] in {m["name"] for m in BENCH["end_to_end"]}
+    assert reader.read({"cell": _facts()["cell"]}) is None
+    if reader not in BLOCK_READERS:
+        return
     # an older program, or a cached executable of one: no ``blk_`` name
     old = TEXT.replace("blk_", "b1k_")
     assert reader.read(_facts(old)) is None
     assert reader.read(dict(_facts(), trace=None)) is None
-    assert reader.read({"cell": _facts()["cell"]}) is None
     # another program's trace (a rehearsal reads a recorded one)
     other = _facts()
     for op in other["trace"]["devices"]["/device:TPU:0"]["ops"]:

@@ -4,7 +4,7 @@ The TPU compiler ships with jaxlib and compiles for a DESCRIBED
 topology (``v5e:2x2``), so Mosaic's refusals — a slice not aligned to
 the tiling, a 16-bit matmul accumulator, too much VMEM — show up here
 at no chip time, where the Pallas interpreter accepts anything.  Every
-shape is one the Llama proxy of ``chip_smoke.py`` / ``bench.py`` runs:
+shape is one the Llama proxy of ``chip_smoke.py`` runs:
 training at T 2048 (hd 64, and the hd-128 GQA 4:1 variant), serving
 with 8 slots, block 16, a 2048-token table, decode (Q=1) and a
 speculative verify window (Q=4).

@@ -750,8 +750,7 @@ class TestBucketedTraining:
         replicated over ``model``, and every leaf must come back out
         typed as it went in (``exchange._narrow_vma``) — with the
         exchange itself unchanged.  ``ici16`` at the default bucket
-        size is what the Llama proxy of bench.py and chip_smoke.py
-        runs."""
+        size is what the Llama proxy of chip_smoke.py runs."""
         mono = self._llama_losses(strategy, 0, 6, devices8, tp=2)
         buck = self._llama_losses(strategy, 0.01, 6, devices8, tp=2)
         assert np.all(np.isfinite(mono))

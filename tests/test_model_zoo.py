@@ -53,7 +53,7 @@ def test_stage1_width_pad_is_exact():
     """``stage1_width=128`` with the 64-wide params zero-embedded into
     the padded tree computes EXACTLY the standard network — the
     correctness half of the retired channel-padding lever
-    (docs/PERFORMANCE.md "r5 closes the last named lever": the A/B
+    (the A/B before PR 1
     measured −15.7%, so the knob survives as a measured record, and
     this test keeps its equivalence claim honest)."""
     import jax

@@ -1,8 +1,8 @@
 """tmcheck hot-path sanitizer (theanompi_tpu/analysis/hotpath.py):
 TM104 host-sync fences, TM105 value-dependent shapes, TM106
 trace-time wall-clock/RNG.  The headline regression fixture is the
-PR 6 per-chunk ``int()`` fence in chunked prefill (the bug
-docs/PERFORMANCE.md's "no per-step value fences" lever retired) —
+PR 6 per-chunk ``int()`` fence in chunked prefill (the bug the
+"no per-step value fences" rule retired) —
 re-introducing it must be caught, while the post-fix shape (ONE
 fence after the loop) stays clean.
 """

@@ -311,8 +311,8 @@ ENTRY %main {
     def test_tfrt_cpu_lanes_recognized(self):
         """The XLA:CPU thunk lanes on this image are named
         tf_XLATfrtCpuClient/... — their absence from the lane filter
-        was why CPU-mesh traces reported zero cores (the BENCH_r05
-        null exposed_comm_frac)."""
+        was why CPU-mesh traces reported zero cores (and a null
+        exposed_comm_frac)."""
         from theanompi_tpu.utils.trace_comm import CPU_LANE_PREFIXES
 
         for lane in (

@@ -3,7 +3,9 @@
 counts and names only — a time is a chip run's to give."""
 
 import glob
+import importlib
 import json
+import os
 import sys
 import types
 
@@ -184,6 +186,66 @@ def test_a_profiler_session_finds_the_span_in_the_host_plane(traced, name):
     if name == "tm:worker.dispatch":
         assert len(found) == CHUNKS
         assert {"first", "k"} <= {k for k, _ in found[0].stats}
+
+
+# -- what the benchmark's readers look for -----------------------------------
+
+SPAN_READERS = ["boundary_fence_ms", "boundary_host_ms", "dispatch_host_ms",
+                "gap_named_share"]
+SETUP_READERS = ["setup_data_s", "setup_init_s", "setup_warmup_s",
+                 "setup_compile_s", "setup_before_worker_s"]
+
+
+def _reader(metric):
+    return importlib.import_module(f"benchmark.layer_metrics.{metric}")
+
+
+@pytest.mark.parametrize("metric", SPAN_READERS)
+def test_the_spans_a_reader_names_are_spans_the_worker_opened(traced, metric):
+    """The ``tm:`` names are taken from the reader's own module and
+    looked up in the session as the benchmark reads it
+    (``_program_spans._read_xplane``): a renamed span, another prefix
+    or another plane makes the metric ``None`` on the chip."""
+    from benchmark.layer_metrics import _program_spans as ps
+
+    _, spans = ps._read_xplane(traced["xplane"],
+                               os.stat(traced["xplane"]).st_mtime_ns)
+    opened = {name for name, _, _ in spans}
+    named = {n for v in vars(_reader(metric)).values()
+             if isinstance(v, tuple) for n in v
+             if isinstance(n, str) and n.startswith(ps.PROGRAM_SPAN_PREFIX)}
+    assert named <= opened, sorted(named - opened)
+    if named:
+        return
+    # ``gap_named_share`` takes any leaf: a device idle for the length
+    # of one is idle under a name
+    _, start, end = ps.leaves([list(s) for s in spans])[0]
+    ops = [["a", start - 10, start], ["b", end, end + 10]]
+    trace = {"devices": {"/device:TPU:0": {"ops": ops, "modules": []}},
+             "program": [list(s) for s in spans]}
+    assert end - start >= 2_000_000      # the reader's floor, in ps
+    assert _reader(metric).read({"trace": trace}) == 1.0
+
+
+@pytest.mark.parametrize("metric", SETUP_READERS)
+def test_the_phases_a_reader_names_are_phases_the_worker_recorded(
+        traced, metric, monkeypatch):
+    """``setup_seconds`` adds up the phases it finds among those it is
+    given and says nothing of the rest: a renamed phase reads 0.0, not
+    ``None``.  So the names each reader hands it are held to the
+    record itself."""
+    reader = _reader(metric)
+    recorded = traced["res"]["setup_phases"]
+    asked = []
+    if hasattr(reader, "setup_seconds"):
+        with monkeypatch.context() as patched:
+            patched.setattr(reader, "setup_seconds",
+                            lambda facts, names: asked.extend(names))
+            reader.read({"scan_k": 2})
+        assert asked and set(asked) <= set(recorded), (asked, sorted(recorded))
+    facts = {"scan_k": 2, "trace": {"setup_phases": recorded}}
+    got = reader.read(facts)
+    assert got is not None and got >= 0.0
 
 
 # -- set-up phases -----------------------------------------------------------

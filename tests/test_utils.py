@@ -97,8 +97,8 @@ class TestCheckpoint:
 
 class TestResampleLabels:
     """Label-noise helper shared by the synthetic and real-CIFAR data
-    paths (convergence drills' noise floor — docs/PERFORMANCE.md
-    "Convergence equivalence", r5 retune)."""
+    paths (the noise floor of ``tests/test_convergence.py``'s
+    drills)."""
 
     def test_deterministic_and_fraction(self):
         from theanompi_tpu.models.data.synthetic import resample_labels

@@ -6,7 +6,7 @@ Two scopes, two failure modes:
 (``registry.HOT_EXACT``/``HOT_SUBSTR``: the decode/prefill/step
 family) or marked ``# tmcheck: hot`` drive jitted executables from
 Python.  The discipline PR 6's chunked-prefill postmortem bought
-(docs/PERFORMANCE.md "no per-step value fences"): dispatch stays
+(no per-step value fences): dispatch stays
 async; at most ONE host sync per call, after the loop.  So:
 
 - TM104 fires on a host-sync fence — ``int()``/``float()`` of a

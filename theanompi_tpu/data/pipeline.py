@@ -5,9 +5,8 @@ process per training process (``proc_load_mpi``), batches arriving
 over shared memory so the GPU never waited on disk or augmentation.
 Our SPMD reproduction lost that: every ``train_iter`` fetched and
 staged its batch INLINE, and PR 13's step-phase profiler priced the
-loss precisely (BENCH_r09 ``profile`` row: 0.092 s of a 0.109 s step
-— ~84% — attributed to the ``host_gap`` leg, dwarfing geometry and
-exposed comm combined).
+loss precisely: most of a step went to the ``host_gap`` leg,
+dwarfing geometry and exposed comm combined.
 
 Two pieces restore the overlap:
 
@@ -41,7 +40,7 @@ Two pieces restore the overlap:
   drill) degrades to a synchronous fetch with a ``starved`` counter
   instead of deadlocking.
 
-Fencing discipline (docs/PERFORMANCE.md "no per-step value fences"):
+Fencing discipline (no per-step value fences; ``tmcheck`` TM104/TM105):
 neither the producer nor ``next()`` ever reads a device value — the
 ring bounds in-flight transfers by COUNT, and the consumer's compute
 waits on the data dependency, not on a host fence.

@@ -12,8 +12,8 @@ from __future__ import annotations
 
 import importlib
 
-# Flagship preference order shared by bench.py and __graft_entry__:
-# (modelfile, modelclass, bench config, per-chip bench batch).
+# Flagship preference order of __graft_entry__:
+# (modelfile, modelclass, config, per-chip batch).
 FLAGSHIP_CANDIDATES = [
     (
         "theanompi_tpu.models.resnet50",

@@ -44,8 +44,7 @@ class Bottleneck(Layer):
     zero-initialized the function is exactly the 64-wide one
     (asserted by ``test_model_zoo.py::test_stage1_width_pad_is_exact``;
     the on-chip A/B measured −15.7%, so the knob is a measured
-    retirement record, not a recommended setting — see
-    docs/PERFORMANCE.md "Known ceilings")."""
+    retirement record, not a recommended setting)."""
 
     def __init__(self, ch: int, stride: int = 1, out_ch: int | None = None):
         self.ch = ch

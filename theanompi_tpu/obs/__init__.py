@@ -1,11 +1,12 @@
-"""Observability subsystem: distributed span tracing (bounded
-flight-recorder, Perfetto export with counter tracks, critical-path
-attribution), the step-phase profiler (``profiler.py``), the bench
-regression gate (``regress.py``), Prometheus-style metrics text, the
-process's compile counter (``compile_meter.py``), a training run's
-set-up phases (``setup.py``), a MoE step's routing counters
+"""Observability subsystem: distributed span tracing (``tracer.py``:
+bounded flight-recorder; ``export.py``: Perfetto export with counter
+tracks, critical-path attribution), the step-phase profiler
+(``profiler.py``), Prometheus-style metrics text (``metrics.py``),
+the process's compile counter (``compile_meter.py``), a training
+run's set-up phases (``setup.py``), a MoE step's routing counters
 (``routing.py``) and a looped decoder's exit counters (``exits.py``).
-See docs/OBSERVABILITY.md and docs/PERFORMANCE.md."""
+See docs/OBSERVABILITY.md; what reads these on the chip is under
+``benchmark/`` (PERF.md section 3)."""
 
 from theanompi_tpu.obs.tracer import (  # noqa: F401
     DEFAULT_TRACE_SAMPLE,

@@ -37,7 +37,7 @@ peak)``, the step's gap ``measured - ideal`` decomposes into
 - ``host``         — the host gap.
 
 Every leg is measured, so the attribution SUMS: ``coverage`` ≈ 1 is
-asserted by the bench (within 5%, the acceptance bar).
+asserted by ``tests/test_profiler.py``.
 
 The profile exports into the PR-12 Perfetto timeline: ``spans()``
 renders the decomposition as one span tree and ``counter_tracks()``
@@ -312,8 +312,8 @@ def step_profile(
     trace_dir: str | None = None,
 ) -> StepProfile:
     """Capture ONE profiled window of ``run_fn`` (which must run
-    ``n_steps`` training steps and fence its own device work — the
-    bench's value-read discipline) and decompose it.
+    ``n_steps`` training steps and fence its own device work by a
+    value read) and decompose it.
 
     ``hlo_text`` — optimized HLO of the step executable
     (``trace_comm.compiled_hlo_text``), the source of the per-scope
@@ -322,8 +322,7 @@ def step_profile(
     stage_hlo_text()``), collision-filtered per
     ``profile_scope_sets``.  ``peak_flops`` — per-device peak (the
     MFU denominator); ``step_flops``/``step_bytes`` — one step's
-    total FLOPs/bytes across devices (XLA ``cost_analysis``, the
-    bench's ``_step_flops`` derivation).
+    total FLOPs/bytes across devices (XLA ``cost_analysis``).
 
     ``leg_costs`` — optional ``{leg: {"flops": f, "bytes": b}}``
     pricing individual legs (wire bytes from

@@ -59,8 +59,8 @@ import time
 from collections import OrderedDict, deque
 from contextlib import contextmanager
 
-#: default 1/N trace sampling rate (the bench's traced A/B arm runs
-#: at this rate; shed/failover/SLO-miss force-sample regardless)
+#: default 1/N trace sampling rate (shed/failover/SLO-miss
+#: force-sample regardless)
 DEFAULT_TRACE_SAMPLE = 16
 
 

@@ -486,8 +486,8 @@ def _bn_train(x, scale, offset, axes, eps):
     backward reductions over the full activation (d_scale, d_offset,
     d_mean, d_var) scheduled behind a chain of sequential dependencies
     (var depends on mean), which on v5e materialized as ~20% of the
-    ResNet-50 step in two-pass reduction reads (docs/PERFORMANCE.md
-    "Known ceilings", r3).  The custom backward needs only TWO
+    ResNet-50 step in two-pass reduction reads (measured before
+    PR 1).  The custom backward needs only TWO
     channel reductions — sum(dy) and sum(dy*x_hat) — computed
     adjacently so XLA multi-output-fuses them into ONE read of dy,
     then one elementwise pass for dx.  Math is the standard BN

@@ -34,7 +34,7 @@ traffic" workload): KV-cache decode for Llama + a slot-based engine.
   capacity and spawns/retires replicas with hysteresis; scale-down
   drains through the failover path (never drops a request), and
   spawn/retire events feed ``FleetRecorder.replica_seconds`` — the
-  cost metric of the ``serving_autoscale`` bench.
+  cost metric of an autoscaled fleet (``tests/test_autoscaler.py``).
 
 See docs/SERVING.md for lifecycle, knobs and telemetry.
 """

@@ -39,8 +39,8 @@ conserved across the membership change) and forgets it; the
 
 **Accounting.**  Every spawn/retire lands in the fleet recorder's
 scale-event log; ``FleetRecorder.replica_seconds()`` integrates it —
-the cost metric the ``serving_autoscale`` bench row compares against
-a statically peak-provisioned fleet under the same diurnal trace.
+the cost metric an autoscaled fleet is compared by against a
+statically peak-provisioned one under the same trace.
 
 **Drills.**  Each tick runs ``maybe_inject_fault(index, tick)`` on
 the autoscaler's own clock: the ``spike_load`` action

@@ -3,8 +3,8 @@
 
 The jnp gather path (``PagedLlamaDecoder._gather_kv``) materializes
 the block-table read as a ``[S, Hkv, MB*bs, hd]`` tensor per layer —
-PR 6's decode-cost attribution (``paged_attend_frac`` in the
-``serving_paged`` bench row) puts most of decode time there, and on
+PR 6's decode-cost attribution (``paged_attend_frac``, from a trace
+of the CPU mesh) puts most of decode time there, and on
 real hardware that tensor is an HBM round trip: the pool rows are
 READ, WRITTEN back as the gathered copy, and READ again by the
 attention matmuls (~3x the padded window's bytes).  This kernel fuses

@@ -38,8 +38,8 @@ Two transports share that loop:
   "every future resolves" guarantee.
 
 ``python -m theanompi_tpu.serving.replica --spec-json '{...}'``
-hosts a checkpoint-restored decoder as a replica child (the bench's
-multi-process fleet and the ``serving_fleet`` smoke use it); it
+hosts a checkpoint-restored decoder as a replica child
+(``tests/test_router.py``'s multi-process fleet uses it); it
 prints ``REPLICA_READY <port>`` once serving.
 """
 

@@ -1,7 +1,7 @@
 """Persistent XLA compile cache, switched on by every entry point.
 
 One rule, so that every process of a run — launcher, workers, replica
-servers, bench, tests, ``chip_smoke.py`` — shares one cache and a
+servers, tests, ``chip_smoke.py``, ``benchmark/`` — shares one cache and a
 second run deserializes executables instead of recompiling (the
 flagship train step is a multi-minute compile):
 

@@ -6,7 +6,7 @@ host), so this module carries the honest stand-in the judge asked for
 (VERDICT r3 #7): a per-step exchange-bytes / compute-FLOPs model that
 predicts BSP scaling efficiency at 8/16/64 chips from quantities we
 CAN measure on one chip (step FLOPs from XLA ``cost_analysis``, step
-time from the bench, parameter bytes from the model tree) plus public
+time from a chip run, parameter bytes from the model tree) plus public
 v5e datasheet numbers.  When real multi-chip hardware exists, the
 predictions in docs/PODS.md are checkable against it line by line.
 
@@ -51,7 +51,6 @@ class ChipSpec:
     hbm_bw: float           # HBM bandwidth, bytes/s
     ici_link_bw: float      # per ICI link, per direction, bytes/s
     ici_links: int          # torus links per chip (2D torus: 4)
-    dcn_bw_per_chip: float  # bytes/s of DCN egress per chip (host NIC / 8)
 
 
 V5E = ChipSpec(
@@ -61,11 +60,10 @@ V5E = ChipSpec(
     hbm_bw=819e9,
     ici_link_bw=45e9,
     ici_links=4,
-    dcn_bw_per_chip=3.125e9,   # 200 Gbps NIC per 8-chip host
 )
 
 #: peak dense bf16 FLOP/s per chip by PJRT device_kind prefix — THE
-#: MFU denominator (bench.py and the step-phase profiler share it)
+#: MFU denominator of the step-phase profiler
 PEAK_BF16 = {
     "TPU v2": 45e12,
     "TPU v3": 123e12,
@@ -94,8 +92,7 @@ def peak_flops_per_chip(devices) -> float | None:
 def cost_analysis_totals(ca, n_devices: int) -> tuple[float, float]:
     """``(total_flops, total_bytes_accessed)`` across ALL devices
     from an XLA ``cost_analysis()`` result — THE one normalizer
-    (bench.py, the step-phase profiler, and the BSP worker's
-    ``step_profile`` knob all read it).  The dict API reports the
+    (the BSP worker's ``step_profile`` knob reads it).  The dict API reports the
     PER-DEVICE partitioned module (verified on this image: a
     4-way-sharded 4.19M-FLOP matmul reports 1.05M), so it scales by
     ``n_devices``."""
@@ -156,7 +153,7 @@ def exchange_wire_bytes(
     for a ``param_bytes`` fp32 gradient pack.  The compressed wire
     (``int8``/``fp8``) ships 1 byte per element plus one f32 scale
     per (bucket x shard) chunk — the scale overhead is what makes
-    tiny buckets lose (PERFORMANCE.md: when int8 loses)."""
+    tiny buckets lose."""
     n_elems = param_bytes / 4.0
     per_elem = WIRE_ELEM_BYTES[wire]
     payload = n_elems * per_elem
@@ -164,63 +161,6 @@ def exchange_wire_bytes(
         n_buckets = max(1.0, math.ceil(param_bytes / bucket_bytes))
         payload += 4.0 * n_buckets * n_shards
     return payload
-
-
-def compression_table(
-    *,
-    step_time_1chip: float,
-    param_bytes: float,
-    wire: str = "int8",
-    baseline_wire: str = "fp32",
-    chip_counts=(8, 16, 64),
-    transport: str = "ici",
-    chip: ChipSpec = V5E,
-    overlap_frac: float = 2.0 / 3.0,
-    bucket_bytes: float = 4 * 2**20,
-) -> list[dict]:
-    """Predicted win of the quantized wire over ``baseline_wire`` at
-    8/16/64 chips — the ISSUE's motivating number: at 16-64 chips
-    over DCN the baseline's ``exposed_comm_frac`` dominates the step,
-    and cutting wire bytes 4x shrinks it directly.
-
-    ``transport="dcn"`` rings over the hosts' NIC share
-    (``chip.dcn_bw_per_chip``) instead of ICI — the multi-host regime
-    the compression is FOR (ICI at 8 chips usually hides the fp32
-    wire already; the model shows exactly that).
-
-    One row per chip count::
-
-        {"n_chips", "wire_mb", "wire_mb_baseline",
-         "wire_reduction", "t_exposed_ms", "t_exposed_baseline_ms",
-         "efficiency", "efficiency_baseline", "speedup"}
-    """
-    rows = []
-    for n in chip_counts:
-        bw = chip.dcn_bw_per_chip if transport == "dcn" else None
-        out = {}
-        for label, w in (("", wire), ("_baseline", baseline_wire)):
-            wb = exchange_wire_bytes(
-                param_bytes, wire=w, n_shards=n,
-                bucket_bytes=bucket_bytes,
-            )
-            t_ar = allreduce_time(wb, n, chip, bw=bw)
-            exposed = max(0.0, t_ar - overlap_frac * step_time_1chip)
-            out[f"wire_mb{label}"] = wb / 2**20
-            out[f"t_exposed{label}_ms"] = exposed * 1e3
-            out[f"efficiency{label}"] = step_time_1chip / (
-                step_time_1chip + exposed
-            )
-        rows.append({
-            "n_chips": n,
-            "transport": transport,
-            "wire": wire,
-            "wire_reduction": (
-                out["wire_mb_baseline"] / out["wire_mb"]
-            ),
-            "speedup": out["efficiency"] / out["efficiency_baseline"],
-            **out,
-        })
-    return rows
 
 
 def bsp_efficiency(
@@ -309,8 +249,7 @@ def bucketed_overlap(
       ``bucket_mb=0`` config path).
 
     Returns the predicted ``exposed_comm_frac`` for both arms — the
-    quantity ``bench.py``'s bucketed A/B row and ``trace_comm`` then
-    measure.
+    quantity ``trace_comm.comm_report`` measures.
     """
     if n_chips <= 1 or wire_bytes <= 0:
         return {
@@ -380,8 +319,7 @@ def loader_pipeline(
       spends blocked (the loader's degrade path makes this a
       synchronous fetch, never a deadlock).
 
-    Returns ms legs + fracs in the house predictor shape; the bench
-    ``loader`` row measures the same quantities.
+    Returns ms legs + fracs in the house predictor shape.
     """
     if depth < 2:
         raise ValueError(f"depth must be >= 2, got {depth}")
@@ -525,7 +463,7 @@ def llama_hbm_per_chip(
       With ``zero1=True`` (the ``zero1`` exchange strategy) the m+v
       buffers additionally shard 1/dp over the data axis — the ZeRO-1
       win: per-chip optimizer bytes divide by the DP replica count,
-      so predicted max batch RISES with N (``llama_max_batch``).
+      so the batch that fits at fixed HBM RISES with N.
     - gradients: one fp32 shadow of the shard (transient but peak;
       zero1 reduce-scatters them on the wire but the pre-exchange
       local grads still exist at peak, so they do NOT divide by dp).
@@ -568,46 +506,6 @@ def llama_hbm_per_chip(
     }
 
 
-def llama_max_batch(
-    cfg: dict,
-    *,
-    tp: int = 1,
-    sp: int = 1,
-    pp: int = 1,
-    dp: int = 1,
-    zero1: bool = False,
-    seq_len: int | None = None,
-    remat: bool = True,
-    optimizer: str = "adam",
-    chip: ChipSpec = V5E,
-    limit: int = 65536,
-) -> int:
-    """Largest per-replica batch whose predicted per-chip HBM fits the
-    chip (the max-batch-at-fixed-HBM half of the zero1 A/B: freeing
-    ~opt_bytes*(1-1/dp) of HBM converts directly into batch — the
-    lever on the memory-limited zoo rows).  0 = even batch 1 spills."""
-
-    def fits(b: int) -> bool:
-        return (
-            llama_hbm_per_chip(
-                cfg, tp=tp, sp=sp, pp=pp, dp=dp, zero1=zero1,
-                batch_per_replica=b, seq_len=seq_len, remat=remat,
-                optimizer=optimizer,
-            )["total_gb"] * 2**30 < chip.hbm_bytes
-        )
-
-    if not fits(1):
-        return 0
-    lo, hi = 1, 2
-    while hi < limit and fits(hi):
-        lo, hi = hi, hi * 2
-    hi = min(hi, limit)
-    while lo + 1 < hi:
-        mid = (lo + hi) // 2
-        lo, hi = (mid, hi) if fits(mid) else (lo, mid)
-    return lo
-
-
 def llama_kv_bytes_per_token(cfg: dict, *, kv_dtype_bytes: int = 2) -> int:
     """Bytes ONE cached token occupies (K + V, all layers, compact
     GQA heads — the serving cache layout, serving/decoder.py)."""
@@ -646,10 +544,9 @@ def serving_roofline(
 
     ``crossover_batch`` is where the batch's KV reads equal the
     weight reads — past it, adding slots stops being ~free and
-    tokens/s per slot degrades toward the KV-bandwidth bound.  The
-    bench row's measured tokens/s at each offered load is the
-    CPU-mesh analogue of this curve; on real v5e the prediction is
-    checkable against the datasheet 819 GB/s.
+    tokens/s per slot degrades toward the KV-bandwidth bound.  On
+    real v5e the prediction is checkable against the datasheet
+    819 GB/s.
 
     Paged extensions (serving v2, ``serving/blocks.py``), emitted
     when ``block_size`` is given:
@@ -706,8 +603,7 @@ def serving_roofline(
         # K/V once, at `context` tokens.  Intensity sits far below
         # the chip's ridge — the kernel is bandwidth-bound by
         # construction, so bytes saved convert directly into step
-        # time (`paged_attend_frac` in the serving_paged row is the
-        # measured check).
+        # time (not measured on the chip: serving has no cell).
         n_heads = int(cfg["n_heads"])
         hd = int(cfg["dim"]) // n_heads
         L = int(cfg["n_layers"])
@@ -802,8 +698,7 @@ def fleet_roofline(
     ``target_util`` — past the knee, adding replicas buys headroom,
     not latency.  Each row carries the M/M/1-style queue-wait
     inflation ``1 / (1 - rho)`` (rho < 1): the TTFT p95 proxy that
-    explodes as a replica count SATURATES, which is what the bench's
-    offered-load sweep shows on the CPU mesh and an operator checks
+    explodes as a replica count SATURATES, which an operator checks
     against the real chip's datasheet capacity.
 
     An infeasible fleet (rho >= 1) reports ``queue_inflation=None``:

@@ -105,8 +105,8 @@ def report_of(fn: Callable[[], Any], top_n: int = 15,
               quant_ops: set | None = None,
               scopes: dict | None = None) -> dict:
     """Capture ``fn`` into a temp dir and return its ``comm_report``
-    — the one-shot capture-and-attribute recipe shared by bench.py
-    and the multichip gate (``fn`` must fence its own device work,
+    — the one-shot capture-and-attribute recipe of the multichip
+    gate (``fn`` must fence its own device work,
     e.g. by a value read).  ``quant_ops`` — instruction names from
     ``scope_op_names`` to attribute as quantize/dequantize compute;
     ``scopes`` — the profiler's ordered per-leg op-name sets."""
@@ -190,13 +190,6 @@ def hlo_instruction_names(hlo_text: str) -> set[str]:
 def compiled_hlo_text(compiled) -> str:
     """Optimized-HLO text of a jax ``Compiled``."""
     return compiled.as_text()
-
-
-def quant_op_names(lowered) -> set[str]:
-    """``scope_op_names`` of a jax ``Lowered`` (compiles it — with the
-    persistent compile cache this deserializes the already-built
-    executable)."""
-    return scope_op_names(compiled_hlo_text(lowered.compile()))
 
 
 def _latest_xplanes(trace_dir: str) -> list[str]:

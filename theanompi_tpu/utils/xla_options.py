@@ -3,7 +3,7 @@
 TPU options travel per jit as ``compiler_options``, not as
 ``XLA_FLAGS``: the flags are process-wide, and the CPU client — which
 every TPU process also creates — aborts on an unknown ``--xla_tpu_*``
-flag.  One helper so every compile site (models, bench, workers)
+flag.  One helper so every compile site (models, workers)
 honors the same knobs:
 
 - ``config["xla_options"]`` — dict of option name → value, or a

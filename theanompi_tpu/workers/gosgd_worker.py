@@ -422,7 +422,7 @@ def _run_distributed(
     # a gossip push's receiver set is random and unacknowledged, so
     # there is no single counterpart whose view a residual could
     # unbias; the score-weighted merge dilutes the per-push rounding
-    # instead (documented in PERFORMANCE.md).
+    # instead.
     from theanompi_tpu.parallel import ExchangePlan
 
     wire = ExchangePlan.from_config(cfg).wire
