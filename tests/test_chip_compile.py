@@ -55,7 +55,9 @@ def _compiled_text(fn, *shapes) -> str:
 
 # [B, H, T, hd] after the GQA repeat — what Llama._layer hands the
 # kernel: the proxy (16 heads of 64) and its hd-128 variant (8 of 128)
-FLASH_SHAPES = [(4, 16, 2048, 64), (4, 8, 2048, 128)]
+# ... and what the hybrid cell hands it: ONE attention layer's 32
+# heads of 64 over 8192 positions (rows of half a register's lanes)
+FLASH_SHAPES = [(4, 16, 2048, 64), (4, 8, 2048, 128), (1, 32, 8192, 64)]
 
 
 @pytest.mark.parametrize("shape", FLASH_SHAPES, ids=str)
@@ -678,6 +680,51 @@ def test_llama_step_names_its_blocks_in_every_phase(
     named = [e["op_name"] for e in top.values() if e["op_name"]]
     assert all(re.fullmatch(_GLUE, n) for n in named), named
     assert len(top) <= most_unnamed, sorted(top)
+
+
+# a hybrid stack at small widths: two mamba layers around an attention
+# layer at head dim 64 without rotation, the four multipliers, a tied
+# head; two chunks of the scan
+_HYBRID = dict(
+    n_layers=3, n_heads=4, n_kv_heads=2,
+    layer_types=["mamba", "attention", "mamba"], mamba_n_heads=8,
+    mamba_d_head=64, mamba_d_state=128, mamba_n_groups=1, mamba_d_conv=4,
+    mamba_expand=2, mamba_chunk_size=128, embedding_multiplier=12,
+    residual_multiplier=0.22, attention_multiplier=0.015625,
+    logits_scaling=8, tie_word_embeddings=True,
+    position_embedding_type="nope",
+)
+
+
+def test_hybrid_step_compiles_with_the_mixers_scopes_in_every_phase(
+    chip, monkeypatch
+):
+    """A stack with mamba layers through the model's own train step,
+    compiled for the v5e: the mixer's block in all three phases beside
+    the attention layer's, its four scopes in the text, the ONE
+    attention layer's three flash kernels at head dim 64, every fusion
+    that holds a product under a block, and no rotation anywhere."""
+    from benchmark import hlo_read
+
+    text = _llama_step_text(chip, monkeypatch, **_HYBRID)
+    have, top, kernels, products = _step_blocks(text)
+    expected = {
+        (block, phase)
+        for block in ("blk_ssm", "blk_attn", "blk_ffn")
+        for phase in ("fwd", "replay", "bwd")
+    } | {("blk_embed", "fwd"), ("blk_embed", "bwd"), ("blk_head", "fwd"),
+         ("blk_head", "bwd"), ("opt_update", "fwd")}
+    assert expected <= have, expected - have
+    for scope in ("ssm_proj", "ssm_conv", "ssd_scan", "ssm_gate_norm"):
+        assert f"blk_ssm/{scope}/" in text or f"blk_ssm)/{scope}/" in text
+    calls = hlo_read.custom_calls(text)
+    assert len([ln for ln in calls.values() if "_flash_jit" in ln]) == 3
+    assert {e["block"] for e in kernels.values()} == {"blk_attn"}
+    assert products and "other" not in {
+        e["block"] for e in products.values()}, products
+    assert " cosine(" not in text and " sine(" not in text
+    # the tied matrix: one leaf, so one Adam update of [vocab, dim]
+    assert "lm_head" not in text
 
 
 # a decoder with every mechanism of the ``glm4_moe_lite`` cell at small

@@ -305,6 +305,24 @@ LEDGER_PEAKS = {"mistral7b_train_t4096": 11.209,
                 "ouro_train_t4096": 12.97}
 
 
+def test_estimate_knows_a_mamba_call_keeps_its_input_alone():
+    """The hybrid cell read 14.04-14.12 GiB with all ten calls keeping
+    their MLP products (my chip runs A and B, PR 47): the estimate
+    (no product kept) and the kept bytes add up to it within the
+    0.6 GiB the older cells' estimates lie within."""
+    model = _cell_model("granite4h_micro_train_t8192")
+    assert model.mixer_kinds.count("mamba") == 9
+    kept = 10 * model.remat_kept_bytes_per_call
+    assert abs((model.step_peak_estimate() + kept) / GIB - 14.1) < 0.7
+    # parameters at 16 B, ten inputs, ONE attention call's flash
+    # outputs (a mamba call keeps its input alone) and the head
+    n_tok, isz = 8192, 2
+    assert model.step_peak_estimate() == (
+        16 * 772_160_448 + 10 * n_tok * 2048 * isz
+        + n_tok * 32 * 64 * isz + 32 * 8192 * 4
+        + 2 * n_tok * 12544 * isz)
+
+
 @pytest.mark.parametrize("cell", sorted(LEDGER_PEAKS))
 def test_estimate_reads_the_cells_peaks(cell):
     estimate = _cell_model(cell).step_peak_estimate() / GIB
@@ -318,6 +336,9 @@ def test_estimate_reads_the_cells_peaks(cell):
     ("ouro_train_t4096", 15.75, 6, 14),
     ("ouro_train_t4096", 64.0, 32, 32),
     ("olmoe_train_t4096", 64.0, 0, 0),          # an expert layer
+    # nine mamba calls and the attention call, every SwiGLU dense
+    ("granite4h_micro_train_t8192", 15.75, 10, 10),
+    ("granite4h_micro_train_t8192", 13.5, 0, 1),
 ], ids=str)
 def test_keep_rule_from_shapes_and_the_limit(cell, limit_gib, lo, hi):
     model = _cell_model(cell)

@@ -103,6 +103,9 @@ def step_facts(config_name: str) -> dict:
     return {
         "hlo_text": text, "scan_k": 1,
         "cell": {"name": config_name, "config": config},
+        # a roofline over a scope (``ssd_scan_roofline``) reads them
+        "peaks": json.loads(
+            (ROOT / "benchmark" / "peaks.json").read_text())["TPU v5 lite"],
         "trace": {"devices": {"/device:TPU:0": {
             "ops": ops,
             "modules": [["jit_step(1)", 0, (len(ops) + 2) * us]],

@@ -181,7 +181,7 @@ class TestBlockScopes:
     files that carry them clean under TM107."""
 
     BLOCKS = ("blk_embed", "blk_attn", "blk_ffn", "blk_head",
-              "blk_conv", "blk_bn", "blk_pool", "blk_mtp_in")
+              "blk_conv", "blk_bn", "blk_pool", "blk_mtp_in", "blk_ssm")
 
     def test_every_block_label_is_registered_under_its_own_leg(self):
         for label in self.BLOCKS:
@@ -196,7 +196,8 @@ class TestBlockScopes:
         used = set()
         for rel in ("theanompi_tpu/models/llama.py",
                     "theanompi_tpu/models/base.py",
-                    "theanompi_tpu/ops/layers.py"):
+                    "theanompi_tpu/ops/layers.py",
+                    "theanompi_tpu/ops/ssd.py"):
             src = (root / rel).read_text()
             used |= set(re.findall(r'named_scope\("(blk_\w+)"\)', src))
             out = core.collect([core.SourceFile(src, rel)],

@@ -163,6 +163,16 @@ PROFILE_SCOPES: dict[str, str] = {
     # ``_gqa_qkv``, PR 45); benchmark/layer_metrics/gqa_proj_ms.py
     # reads the label
     "gqa_proj": "gqa_proj",
+    # a state-space (mamba) layer's mixer under its block and the four
+    # scopes inside it (models/llama.py ``_mamba_block``, ops/ssd.py
+    # ``mamba_mixer``, PR 47); benchmark/layer_metrics/ssm_block_ms.py,
+    # ssd_scan_ms.py, ssd_scan_roofline.py and ssm_conv_ms.py read
+    # the labels
+    "blk_ssm": "blk_ssm",
+    "ssm_proj": "ssm_proj",
+    "ssm_conv": "ssm_conv",
+    "ssd_scan": "ssd_scan",
+    "ssm_gate_norm": "ssm_gate_norm",
     # the step program's blocks (models/llama.py ``_forward`` /
     # ``_layer`` / ``loss_fn``, ops/layers.py, models/base.py, PR 35):
     # with ``opt_update`` and ``exchange_b<i>`` every instruction of a

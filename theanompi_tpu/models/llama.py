@@ -48,7 +48,8 @@ products (``Llama.remat_keep_calls``).  Params are initialized
 set never materializes on one device.
 
 Architecture per Llama-3: RMSNorm, RoPE, grouped-query attention,
-SwiGLU MLP, untied LM head; ``qk_norm`` adds an RMSNorm over the whole
+SwiGLU MLP, untied LM head (tied under ``tie_word_embeddings``);
+``qk_norm`` adds an RMSNorm over the whole
 projected width of q and of k, before RoPE.  q, k and v leave their
 products in the attention kernels' ``[B, H, T, hd]`` layout and RoPE is
 one pass over the whole row (``_gqa_qkv``, ``rope``): no activation is
@@ -82,6 +83,7 @@ from theanompi_tpu.ops.attention import (
 )
 from theanompi_tpu.ops.grouped_matmul import TILE_PLAN_RESIDUAL
 from theanompi_tpu.ops.layers import MLP_RESIDUALS, swiglu
+from theanompi_tpu.ops import ssd
 from theanompi_tpu.ops import optimizers as opt_lib
 from theanompi_tpu.parallel import (
     DATA_AXIS,
@@ -350,7 +352,19 @@ class Llama(TMModel):
     a rotary table a kind, ``rope_table``) describe the attention
     layer by layer (``attn_kinds``, ``window_of``).  These compose
     with ``tp`` and data parallelism and are refused under ``pp``,
-    ``sp`` and ``ut_steps > 1``.
+    ``sp`` and ``ut_steps > 1``.  ``layer_types`` may also name a
+    MIXER kind a layer, ``"mamba"`` / ``"attention"``
+    (``mixer_kinds``): a mamba layer runs a Mamba-2 state-space mixer
+    in attention's place (``mamba_n_heads, mamba_d_head,
+    mamba_d_state, mamba_n_groups, mamba_d_conv, mamba_expand,
+    mamba_chunk_size``; ``_mamba_block``, ``ops/ssd.py``), under data
+    parallelism alone.  ``position_embedding_type: "nope"``: q and k
+    are not rotated; ``embedding_multiplier``, ``residual_multiplier``,
+    ``attention_multiplier`` (the scores' scale in place of ``head_dim
+    ** -0.5``) and ``logits_scaling`` (divides the logits) are the
+    hybrid decoders' four scalars; ``tie_word_embeddings``: ONE matrix
+    is embedding and head (``_head_weight``).  ``validate: false``:
+    the run holds no validation set.
     """
 
     def __init__(self, config: dict | None = None):
@@ -444,10 +458,21 @@ class Llama(TMModel):
         # kind rotates by its own entry of ``rope_parameters`` where
         # that is given (``rope_table``), else by ``rope_theta``
         types = c.get("layer_types")
+        # ``"attention"`` (the hybrid decoders' name for a full layer)
+        # is ``"full_attention"``; ``"mamba"`` is no attention at all
+        # but a state-space MIXER (``mixer_kinds``, ``ops/ssd.py``)
         self.attn_kinds = (
             ("full_attention",) * self.n_layers if types is None
-            else tuple(str(t) for t in types[:self.n_layers])
+            else tuple(
+                "full_attention" if t == "attention" else str(t)
+                for t in types[:self.n_layers]
+            )
         )
+        # the mixer of every layer, "attention" or "mamba"
+        self.mixer_kinds = tuple(
+            "mamba" if k == "mamba" else "attention" for k in self.attn_kinds
+        )
+        self.has_mamba = "mamba" in self.mixer_kinds
         window = c.get("sliding_window")
         self.sliding_window = None if window is None else int(window)
         self.rope_parameters = c.get("rope_parameters")
@@ -458,25 +483,61 @@ class Llama(TMModel):
             types is None and window is None and self.rope_parameters is None
         )
         unknown = set(self.attn_kinds) - {"full_attention",
-                                          "sliding_attention"}
+                                          "sliding_attention", "mamba"}
         if unknown or len(self.attn_kinds) != self.n_layers:
             raise ValueError(
                 f"layer_types names each of the {self.n_layers} layers "
-                f"'full_attention' or 'sliding_attention'; got "
-                f"{len(self.attn_kinds)} entries, unknown {sorted(unknown)}"
+                f"'full_attention' (or 'attention'), 'sliding_attention' "
+                f"or 'mamba'; got {len(self.attn_kinds)} entries, unknown "
+                f"{sorted(unknown)}"
             )
         if "sliding_attention" in self.attn_kinds and not self.sliding_window:
             raise ValueError(
                 "layer_types has 'sliding_attention' layers: give "
                 "sliding_window, the keys a query sees (itself included)"
             )
+        if self.has_mamba:
+            # the Mamba-2 mixer's sizes (``ops/ssd.py``'s names), from
+            # the published keys
+            self._mamba = dict(
+                n_heads=int(c["mamba_n_heads"]),
+                head_dim=int(c["mamba_d_head"]),
+                d_state=int(c["mamba_d_state"]),
+                n_groups=int(c.get("mamba_n_groups", 1)),
+            )
+            self.mamba_d_conv = int(c.get("mamba_d_conv", 4))
+            self.mamba_chunk_size = int(c.get("mamba_chunk_size", 256))
+            inner = ssd.mamba_sizes(**self._mamba)[0]
+            expand = int(c.get("mamba_expand", 2))
+            assert inner == expand * self.dim, (
+                f"mamba_n_heads x mamba_d_head = {inner} is not "
+                f"mamba_expand {expand} x dim {self.dim}"
+            )
+        # the hybrid decoders' scalars: ``embedding_multiplier`` on
+        # the looked-up rows, ``residual_multiplier`` on each branch
+        # before its residual add, ``attention_multiplier`` in place of
+        # ``head_dim ** -0.5`` on the scores (None: that default),
+        # ``logits_scaling`` DIVIDES the logits;
+        # ``tie_word_embeddings``: ONE matrix is embedding and head;
+        # ``position_embedding_type`` "nope": q and k are not rotated
+        self.embedding_multiplier = float(c.get("embedding_multiplier", 1.0))
+        self.residual_multiplier = float(c.get("residual_multiplier", 1.0))
+        scale = c.get("attention_multiplier")
+        self.attention_multiplier = None if scale is None else float(scale)
+        self.logits_scaling = float(c.get("logits_scaling", 1.0))
+        self.tie_word_embeddings = bool(c.get("tie_word_embeddings", False))
+        self.position_embedding_type = str(
+            c.get("position_embedding_type", "rope"))
+        assert self.position_embedding_type in ("rope", "nope"), (
+            self.position_embedding_type
+        )
         # (inv_freq, factor) a kind: (None, 1.0) is ``rope_theta``'s
         self._rope_tables = {
             kind: (
                 (None, 1.0) if self.rope_parameters is None
                 else rope_table(self.rope_parameters[kind], self.head_dim)
             )
-            for kind in set(self.attn_kinds)
+            for kind in set(self.attn_kinds) - {"mamba"}
         }
         # multi-token prediction: ``mtp_depth`` (0 or 1) more blocks
         # of the last layer's kind after the stack, which predict the
@@ -581,8 +642,45 @@ class Llama(TMModel):
                 ("a selection bias", self.moe_select_bias),
                 ("layer_types (an attention kind, a window or a rotary "
                  "table per layer)", self.attn_per_layer),
+                ("a multiplier (embedding_multiplier, residual_multiplier, "
+                 "attention_multiplier, logits_scaling)",
+                 (self.embedding_multiplier, self.residual_multiplier,
+                  self.logits_scaling) != (1.0, 1.0, 1.0)
+                 or self.attention_multiplier is not None),
+                ("tie_word_embeddings", self.tie_word_embeddings),
+                ("position_embedding_type: nope",
+                 self.position_embedding_type == "nope"),
             ) if on
         ]
+        if self.has_mamba and (
+            self.tp > 1 or self.n_experts or self.attention == "mla"
+            or "sliding_attention" in self.attn_kinds or self.mtp_depth
+        ):
+            raise NotImplementedError(
+                "a mamba layer (layer_types) does not yet compose with "
+                "tensor parallelism, expert layers (so none with expert "
+                "parallelism), latent attention, sliding-window layers or "
+                f"a multi-token-prediction module (tp {self.tp}, "
+                f"n_experts {self.n_experts}, ep {self.ep}, attention "
+                f"{self.attention}, kinds {sorted(set(self.attn_kinds))}, "
+                f"mtp_depth {self.mtp_depth}): the scan's heads and its "
+                "state are not sharded over the model axis, and the "
+                "hybrid stack has been run with dense SwiGLUs and "
+                "grouped-query full layers alone; use tp=1, n_experts=0, "
+                "attention gqa, no sliding_attention layer and mtp_depth 0"
+            )
+        if self.position_embedding_type == "nope" and self.attention == "mla":
+            raise NotImplementedError(
+                "position_embedding_type: nope is grouped-query "
+                "attention's: latent attention (attention: mla) rotates a "
+                "part of every head by construction"
+            )
+        if self.logits_scaling != 1.0 and self.mtp_depth:
+            raise NotImplementedError(
+                "logits_scaling does not yet compose with a multi-token-"
+                "prediction module (mtp_depth): the module's exit goes "
+                "through the shared head unscaled"
+            )
         if self.attn_per_layer and self.attention == "mla":
             raise NotImplementedError(
                 "attention: mla does not yet compose with layer_types, "
@@ -648,13 +746,19 @@ class Llama(TMModel):
                 for k, s in self._layer_specs(self.layer_kinds[0]).items()
             }
         else:
-            layers = [self._layer_specs(kind) for kind in self.layer_kinds]
+            layers = [
+                self._layer_specs(kind, mixer)
+                for kind, mixer in zip(self.layer_kinds, self.mixer_kinds)
+            ]
         specs = {
             "embed": P(MODEL_AXIS, None),        # vocab-sharded rows
             "layers": layers,
             "final_norm": P(None),
             "lm_head": P(None, MODEL_AXIS),      # vocab-sharded cols
         }
+        if self.tie_word_embeddings:
+            # the head IS the embedding, transposed (``_head_weight``)
+            del specs["lm_head"]
         if self.ut_steps > 1:
             # the exit gate acts on the full width: replicated
             specs.update({"exit_gate_w": P(None, None), "exit_gate_b": P(None)})
@@ -668,10 +772,19 @@ class Llama(TMModel):
             }
         return specs
 
-    def _layer_specs(self, kind: str) -> dict:
-        """PartitionSpec per leaf of one layer of ``kind``."""
+    def _layer_specs(self, kind: str, mixer: str = "attention") -> dict:
+        """PartitionSpec per leaf of one layer of FFN ``kind`` and
+        ``mixer`` kind."""
         layer = {"attn_norm": P(None), "mlp_norm": P(None)}
-        if self.attention == "mla":
+        if mixer == "mamba":
+            # refused under tp > 1: every leaf whole on every device
+            layer.update({
+                "ssm_in": P(None, None), "ssm_conv_w": P(None, None),
+                "ssm_conv_b": P(None), "ssm_dt_bias": P(None),
+                "ssm_a_log": P(None), "ssm_d": P(None),
+                "ssm_norm": P(None), "ssm_out": P(None, None),
+            })
+        elif self.attention == "mla":
             # heads over the model axis (the up-projections' columns,
             # the output projection's rows); the two down-projections
             # and their norms act on the full width: replicated
@@ -689,7 +802,7 @@ class Llama(TMModel):
                 "wv": P(None, MODEL_AXIS),
                 "wo": P(MODEL_AXIS, None),
             })
-        if self.qk_norm:
+        if self.qk_norm and mixer != "mamba":
             # over the whole projected width: sharded as its columns
             layer.update({"q_norm": P(MODEL_AXIS), "k_norm": P(MODEL_AXIS)})
         if self.sandwich_norm:
@@ -761,16 +874,24 @@ class Llama(TMModel):
                 "wo": dense(next(keys), (self.n_heads * hd, d)),
             }
 
-        def one_layer(kind, keys):
+        def mamba(i):
+            return ssd.mamba_init(
+                # a stream of its own, a key a layer (fold_in 1 and 2
+                # are ``more``'s and the MTP block's)
+                jax.random.fold_in(jax.random.fold_in(key, 3), i), d,
+                d_conv=self.mamba_d_conv, dense=dense, **self._mamba,
+            )
+
+        def one_layer(kind, keys, mixer="attention", i=0):
             lp = {
                 "attn_norm": jnp.ones((d,)),
-                **attention(),
+                **(mamba(i) if mixer == "mamba" else attention()),
                 "mlp_norm": jnp.ones((d,)),
             }
-            if self.attention == "mla":
+            if self.attention == "mla" or mixer == "mamba":
                 for _ in range(4):
                     next(keys)  # keep key budget aligned (9 per layer)
-            if self.qk_norm:
+            if self.qk_norm and mixer != "mamba":
                 lp["q_norm"] = jnp.ones((self.n_heads * hd,))
                 lp["k_norm"] = jnp.ones((self.n_kv_heads * hd,))
             if self.sandwich_norm:
@@ -815,7 +936,10 @@ class Llama(TMModel):
                     next(keys)  # keep key budget aligned (9 per layer)
             return lp
 
-        layers = [one_layer(kind, keys) for kind in self.layer_kinds]
+        layers = [
+            one_layer(kind, keys, mixer, i) for i, (kind, mixer) in
+            enumerate(zip(self.layer_kinds, self.mixer_kinds))
+        ]
         if self.pp > 1:
             # stack the SAME per-layer draws (pp is a layout choice,
             # not a math choice: init must match the pp=1 model)
@@ -826,6 +950,8 @@ class Llama(TMModel):
             "final_norm": jnp.ones((d,)),
             "lm_head": dense(next(keys), (d, v)),
         }
+        if self.tie_word_embeddings:
+            del params["lm_head"]
         if self.ut_steps > 1:
             # a gate near zero: every pass but the last keeps about
             # half of the mass that reaches it
@@ -887,7 +1013,8 @@ class Llama(TMModel):
         if not self.attn_per_layer:
             return tiles("full_attention")
         # a model described layer by layer: a summary a kind
-        return {kind: tiles(kind) for kind in sorted(set(self.attn_kinds))}
+        return {kind: tiles(kind)
+                for kind in sorted(set(self.attn_kinds) - {"mamba"})}
 
     def window_of(self, kind: str) -> int | None:
         """The keys a query of a layer of ``kind`` sees, itself
@@ -896,8 +1023,22 @@ class Llama(TMModel):
 
     @property
     def attention_kinds(self) -> dict:
-        """The run summary's ``"attention_kinds"``: layers of each."""
-        return {k: self.attn_kinds.count(k) for k in sorted(set(self.attn_kinds))}
+        """The run summary's ``"attention_kinds"``: layers of each
+        (a mamba layer is no attention: ``mixer_kinds_count``)."""
+        return {k: self.attn_kinds.count(k)
+                for k in sorted(set(self.attn_kinds) - {"mamba"})}
+
+    @property
+    def mixer_kinds_count(self) -> dict:
+        """The run summary's ``"mixer_kinds"``: layers of each."""
+        return {k: self.mixer_kinds.count(k)
+                for k in sorted(set(self.mixer_kinds))}
+
+    @property
+    def ssd_chunk(self) -> int | None:
+        """The run summary's ``"ssd_chunk"``: positions a chunk of
+        the state-space scan; None without a mamba layer."""
+        return self.mamba_chunk_size if self.has_mamba else None
 
     # -- what the per-layer remat keeps -----------------------------------
 
@@ -971,10 +1112,11 @@ class Llama(TMModel):
         )
         opt_copies = {"adam": 2, "sgd": 0}.get(self.opt_name, 1)
         h_loc = self.n_heads // self.tp
-        per_call = (
-            n_tok * self.dim * isz
-            + n_tok * h_loc * self.head_dim * isz
-            + batch * h_loc * t_loc * 4
+        # every call keeps its input; an attention call the flash
+        # kernel's two outputs beside it, a mamba call nothing more
+        kept_input = n_tok * self.dim * isz
+        kept_flash = (
+            n_tok * h_loc * self.head_dim * isz + batch * h_loc * t_loc * 4
         )
         head = 2 * n_tok * (
             self.vocab // self.tp // self._xent_chunks()
@@ -983,7 +1125,11 @@ class Llama(TMModel):
         if exits > 1:
             head += 2 * exits * n_tok * self.dim * isz
         calls = self.n_layers * self.ut_steps // self.pp + self.mtp_depth
-        return param_bytes * (2 + opt_copies) + calls * per_call + head
+        attn_calls = calls - self.mixer_kinds.count("mamba")
+        return (
+            param_bytes * (2 + opt_copies) + calls * kept_input
+            + attn_calls * kept_flash + head
+        )
 
     def remat_keep_calls(self, bytes_limit: int | None) -> int:
         """How many of the last layer calls keep ``MLP_RESIDUALS``: as
@@ -1058,7 +1204,10 @@ class Llama(TMModel):
         """One decoder block on local shards: x [B, T_loc, D]; an
         expert block where ``p`` holds a router (``select_bias``: its
         row ``[E]`` of the selection bias, if the model has one);
-        ``attn_kind`` (static): the layer's entry of ``attn_kinds``.
+        ``attn_kind`` (static): the layer's entry of ``attn_kinds``;
+        ``"mamba"`` runs the state-space mixer in attention's place
+        (``_mamba_block``) and returns ``(x, stats)``, the scan's two
+        counters.
 
         With MoE enabled returns ``(x, mom)`` where ``mom`` is the
         fp32 [2E+2] vector of this layer's aux-loss MOMENTS
@@ -1072,6 +1221,9 @@ class Llama(TMModel):
         # the block names of the step program (``blk_*``, PERF.md §3):
         # metadata only; ``benchmark/layer_metrics/_blocks.py`` joins
         # them with a trace's device time
+        if attn_kind == "mamba":
+            x, stats = self._mamba_block(p, x)
+            return self._dense_ffn(p, x), stats
         with jax.named_scope("blk_attn"):
             xn = rms_norm(x, p["attn_norm"], eps)
             if self.attention == "mla":
@@ -1084,40 +1236,56 @@ class Llama(TMModel):
             a = tp_lib.row_parallel(_unheads(o), p["wo"]).astype(cdtype)
             if self.sandwich_norm:
                 a = rms_norm(a, p["attn_out_norm"], eps)
-            x = x + a
+            x = x + self._branch(a)
 
+        if "router" not in p:
+            return self._dense_ffn(p, x)
         with jax.named_scope("blk_ffn"):
             xn = rms_norm(x, p["mlp_norm"], eps)
-            if "router" in p:
-                y, aux = moe_ffn(
-                    xn, p["router"], p["we_gate"], p["we_up"], p["we_down"],
-                    n_experts=self.n_experts,
-                    top_k=self.moe_top_k,
-                    capacity_factor=self.capacity_factor,
-                    expert_axis=EXPERT_AXIS,
-                    model_axis=MODEL_AXIS,
-                    # aux losses globalize over the token-sharding axes
-                    # (layout-invariant; set in compile_iter_fns)
-                    batch_axes=(*self._dp_axes, SEQ_AXIS),
-                    renormalize=self.moe_renormalize,
-                    scoring=self.moe_scoring,
-                    select_bias=select_bias,
-                    route_scale=self.moe_route_scale,
-                    held=self.moe_experts_held,
-                )
-                mom = jnp.concatenate(
-                    [aux["f"], aux["p"], aux["z"][None],
-                     aux["dropped"][None]]
-                ).astype(jnp.float32)
-                y = y.astype(cdtype)
-                if "ws_gate" in p:
-                    y = y + shared_expert(
-                        xn, p["ws_gate"], p["ws_up"], p["ws_down"],
-                        MODEL_AXIS,
-                    ).astype(cdtype)
-                if self.sandwich_norm:
-                    y = rms_norm(y, p["mlp_out_norm"], eps)
-                return x + y, mom
+            y, aux = moe_ffn(
+                xn, p["router"], p["we_gate"], p["we_up"], p["we_down"],
+                n_experts=self.n_experts,
+                top_k=self.moe_top_k,
+                capacity_factor=self.capacity_factor,
+                expert_axis=EXPERT_AXIS,
+                model_axis=MODEL_AXIS,
+                # aux losses globalize over the token-sharding axes
+                # (layout-invariant; set in compile_iter_fns)
+                batch_axes=(*self._dp_axes, SEQ_AXIS),
+                renormalize=self.moe_renormalize,
+                scoring=self.moe_scoring,
+                select_bias=select_bias,
+                route_scale=self.moe_route_scale,
+                held=self.moe_experts_held,
+            )
+            mom = jnp.concatenate(
+                [aux["f"], aux["p"], aux["z"][None],
+                 aux["dropped"][None]]
+            ).astype(jnp.float32)
+            y = y.astype(cdtype)
+            if "ws_gate" in p:
+                y = y + shared_expert(
+                    xn, p["ws_gate"], p["ws_up"], p["ws_down"],
+                    MODEL_AXIS,
+                ).astype(cdtype)
+            if self.sandwich_norm:
+                y = rms_norm(y, p["mlp_out_norm"], eps)
+            return x + self._branch(y), mom
+
+    def _branch(self, y):
+        """A block's branch as it enters the residual sum: times
+        ``residual_multiplier`` where the model has one."""
+        if self.residual_multiplier == 1.0:
+            return y
+        return (self.residual_multiplier * y).astype(self.compute_dtype)
+
+    def _dense_ffn(self, p, x):
+        """A layer's dense SwiGLU on the residual stream ``x``, from
+        ``mlp_norm`` to the residual add (``blk_ffn``)."""
+        cdtype = self.compute_dtype
+        eps = self.norm_eps
+        with jax.named_scope("blk_ffn"):
+            xn = rms_norm(x, p["mlp_norm"], eps)
             # named for the layer's remat (``_forward``), which keeps
             # the two products of the layer calls the memory holds
             g = checkpoint_name(
@@ -1130,7 +1298,22 @@ class Llama(TMModel):
             y = tp_lib.row_parallel(h, p["w_down"]).astype(cdtype)
             if self.sandwich_norm:
                 y = rms_norm(y, p["mlp_out_norm"], eps)
-            return x + y
+            return x + self._branch(y)
+
+    def _mamba_block(self, p, x):
+        """A mamba layer's mixer on the residual stream ``x [B, T,
+        D]``, from ``attn_norm`` (the published ``input_layernorm``)
+        to the residual add, under ``blk_ssm``; the maths is
+        ``ops/ssd.py``'s.  Returns ``(x, float32[2])``: the scan's
+        counters (``ssd.ssd_scan``)."""
+        cdtype = self.compute_dtype
+        with jax.named_scope("blk_ssm"):
+            xn = rms_norm(x, p["attn_norm"], self.norm_eps)
+            m, stats = ssd.mamba_mixer(
+                p, xn, chunk=self.mamba_chunk_size, eps=self.norm_eps,
+                **self._mamba,
+            )
+            return x + self._branch(m.astype(cdtype)), stats
 
     def _gqa(self, p, xn, pos, kind="full_attention"):
         """Grouped-query attention of ``xn [B, T_loc, D]`` (inside
@@ -1158,7 +1341,9 @@ class Llama(TMModel):
             # machinery and hit the fused kernel (reference math
             # off-TPU) directly
             return flash_attention(
-                q, k, v, causal=True, window=self.window_of(kind)
+                q, k, v, causal=True, window=self.window_of(kind),
+                **({} if self.attention_multiplier is None
+                   else {"sm_scale": self.attention_multiplier}),
             )
         attn = (
             ring_attention if self.sp_mode == "ring"
@@ -1193,16 +1378,25 @@ class Llama(TMModel):
                              self.n_heads * hd, axes=(1, 3))
                 k = rms_norm(k, p["k_norm"].reshape(hkv_loc, 1, hd), eps,
                              self.n_kv_heads * hd, axes=(1, 3))
-            inv_freq, factor = self._rope_tables[kind]
-            q = rope(q, pos, self.rope_theta, inv_freq, factor)
-            k = rope(k, pos, self.rope_theta, inv_freq, factor)
+            if self.position_embedding_type == "rope":
+                inv_freq, factor = self._rope_tables[kind]
+                q = rope(q, pos, self.rope_theta, inv_freq, factor)
+                k = rope(k, pos, self.rope_theta, inv_freq, factor)
             if self.sp == 1 and h_loc != hkv_loc:
                 k = jnp.repeat(k, h_loc // hkv_loc, axis=1)
                 v = jnp.repeat(v, h_loc // hkv_loc, axis=1)
             return q, k, v
 
+    def _head_weight(self, params):
+        """The head's ``[D, V/tp]``: the embedding transposed where
+        ``tie_word_embeddings`` (ONE leaf, whose gradient is then the
+        lookup's scatter-add plus the head's ``dW``)."""
+        if self.tie_word_embeddings:
+            return params["embed"].T
+        return params["lm_head"]
+
     def _forward(self, params, ids, head=True, with_aux=False,
-                 net_state=None, mtp_ids=None):
+                 net_state=None, mtp_ids=None, with_ssm=False):
         """ids [B_loc, T_loc] -> local vocab-shard logits [.., V/tp].
 
         ``net_state``: the step's state beside the parameters
@@ -1224,7 +1418,9 @@ class Llama(TMModel):
         ``with_aux=True`` (train loss path) additionally returns the
         MoE aux pair [lb, z], averaged over layers and pipe-broadcast
         (zeros when the model is dense), and the routing counters
-        ``[L, E+1]`` of ``_routing_counters`` (None when dense)."""
+        ``[L, E+1]`` of ``_routing_counters`` (None when dense).
+        ``with_ssm=True`` (a stack with mamba layers): the scans'
+        counters ``[L_mamba, 2]`` come back last (``obs/ssm.py``)."""
         cdtype = self.compute_dtype
         t_loc = ids.shape[1]
         seq_idx = lax.axis_index(SEQ_AXIS)
@@ -1232,6 +1428,8 @@ class Llama(TMModel):
 
         with jax.named_scope("blk_embed"):
             x = tp_lib.embed_lookup(ids, params["embed"], self.vocab)
+            if self.embedding_multiplier != 1.0:
+                x = self.embedding_multiplier * x
             x = x.astype(cdtype)
 
         def remat(fn, *names):
@@ -1285,6 +1483,7 @@ class Llama(TMModel):
             iter(net_state["moe_bias"]) if self.moe_select_bias
             else itertools.repeat(None)
         )
+        ssm = []        # a mamba layer's scan counters, in layer order
         if self.pp == 1:
             kept = self._kept_calls()
 
@@ -1297,6 +1496,9 @@ class Llama(TMModel):
                     if "router" in p:
                         x, mom = fn(p, x, pos, next(bias_rows))
                         moms.append(mom)
+                    elif kind == "mamba":
+                        x, stats = fn(p, x, pos)
+                        ssm.append(stats)
                     else:
                         x = fn(p, x, pos)
                 return x, (jnp.stack(moms) if moms else None)
@@ -1409,12 +1611,17 @@ class Llama(TMModel):
         if exits is None:
             with jax.named_scope("blk_head"):
                 x = rms_norm(x, params["final_norm"], self.norm_eps)
+                if self.logits_scaling != 1.0:
+                    # the logits divided: the head is linear in its rows
+                    x = (x / self.logits_scaling).astype(cdtype)
             if mtp_x is not None:
                 exits = jnp.stack([x, mtp_x])
         if not head:
             # a looped decoder gives its R exits [R, B, T, D] (the
             # last of them is ``x``): the loss reads them all
             h = x if exits is None else exits
+            if with_ssm:
+                return h, jnp.stack(ssm)
             return (h, aux, routing) if with_aux else h
         # logits stay in compute dtype: the xent/metric reductions
         # upcast to fp32 INSIDE their fused reads (tp.py), so an
@@ -1423,7 +1630,7 @@ class Llama(TMModel):
         # proxy).  Same values either way — the matmul already ran in
         # compute dtype.
         with jax.named_scope("blk_head"):
-            logits = tp_lib.col_parallel(x, params["lm_head"])
+            logits = tp_lib.col_parallel(x, self._head_weight(params))
         return (logits, aux, routing) if with_aux else logits
 
     def _kept_calls(self) -> frozenset:
@@ -1492,7 +1699,7 @@ class Llama(TMModel):
         ])
         if head_xent is None:
             loss, _, pred = tp_lib.exits_unembed_xent(
-                exits, params["lm_head"], labels, row_w, self.vocab,
+                exits, self._head_weight(params), labels, row_w, self.vocab,
                 MODEL_AXIS,
             )
             pred = pred[0]
@@ -1545,7 +1752,7 @@ class Llama(TMModel):
                 # softmax gradient is rounded to the compute dtype
                 # once, after that product, as under autodiff's mean
                 weighted, xent, pred = tp_lib.exits_unembed_xent(
-                    exits, params["lm_head"], targets,
+                    exits, self._head_weight(params), targets,
                     q / exits.shape[1], self.vocab, MODEL_AXIS,
                 )       # xent, pred: no gradient; counters only
             else:
@@ -1639,7 +1846,11 @@ class Llama(TMModel):
                 batch_size=int(self.config.get("batch_size", 8)),
                 n_replicas=n_replicas,
                 n_train=int(self.config.get("n_train", 2048)),
-                n_val=int(self.config.get("n_val", 256)),
+                # ``validate: false``: no validation set, so the
+                # worker's per-epoch validation pass does not run (a
+                # set smaller than one global batch is none either)
+                n_val=int(self.config.get("n_val", 256))
+                if self.config.get("validate", True) else 0,
                 seed=self.seed,
             )
         # params materialize in compile_iter_fns, under jit with sharded
@@ -1787,6 +1998,7 @@ class Llama(TMModel):
             if self.moe_select_bias else ()
         )
         picks = self.data.global_batch * self.seq_len * self.moe_top_k
+        has_ssm = self.has_mamba
 
         def step(params, opt_state, ef, x, y, lr, *state):
             # Pre-cast params to DP-VARYING before autodiff: if they
@@ -1812,7 +2024,7 @@ class Llama(TMModel):
                     # chunked head: unembed + xent streamed over vocab
                     # chunks — full logits never hit HBM (tp.py)
                     return tp_lib.chunked_unembed_xent(
-                        h2, p["lm_head"], yf, self.vocab,
+                        h2, self._head_weight(p), yf, self.vocab,
                         n_xent_chunks, MODEL_AXIS,
                     )
                 # dense custom head: logits saved once in compute
@@ -1820,7 +2032,7 @@ class Llama(TMModel):
                 # handed them an fp32 dlogits — ~52% MXU on the
                 # lm_head dW, profiled r4)
                 return tp_lib.dense_unembed_xent(
-                    h2, p["lm_head"], yf, self.vocab, MODEL_AXIS,
+                    h2, self._head_weight(p), yf, self.vocab, MODEL_AXIS,
                 )
 
             def loss_fn(p):
@@ -1837,6 +2049,10 @@ class Llama(TMModel):
                         p, x, head=False, with_aux=True, **more
                     )
                     counters = (routing,)
+                elif has_ssm:
+                    h, ssm_stats = self._forward(
+                        p, x, head=False, with_ssm=True, **more
+                    )
                 else:
                     h = self._forward(p, x, head=False, **more)
                 # [N, D] rows; a looped decoder's R exits [R, N, D]
@@ -1874,6 +2090,8 @@ class Llama(TMModel):
                         err = jnp.mean((pred != yf).astype(jnp.float32))
                     loss = lax.pmean(self._pp_value(loss), SEQ_AXIS)
                     err = lax.pmean(self._pp_value(err), SEQ_AXIS)
+                if has_ssm:
+                    counters += (ssm_stats,)
                 if self.n_experts:
                     # MoE aux losses (layer-averaged in _forward,
                     # already globally token-averaged inside moe_ffn):
@@ -1903,6 +2121,14 @@ class Llama(TMModel):
             err = lax.pmean(err, dp_axes)
             if self.ut_steps > 1:   # token means, as the loss is
                 counters[-1] = lax.pmean(counters[-1], dp_axes)
+            if has_ssm:
+                # [L_mamba, 2]: the extreme over the replicas'
+                # sequences, the mean of their states' sizes
+                every = (*dp_axes, SEQ_AXIS)
+                counters[-1] = jnp.stack([
+                    lax.pmin(counters[-1][:, 0], every),
+                    lax.pmean(counters[-1][:, 1], every),
+                ], axis=1)
             if state:
                 # after the optimizer, outside it and the exchange:
                 # each router's selection bias a step toward balance,
@@ -1930,6 +2156,7 @@ class Llama(TMModel):
         # them), a looped decoder's its exit counters [2R + 1]
         counter_out = self._counter_out_specs = (P(),) * (
             bool(self.n_experts) + len(state_specs) + (self.ut_steps > 1)
+            + has_ssm
         )
         self._compiler_options = xla_compiler_options(
             self.config,
@@ -2169,6 +2396,8 @@ class Llama(TMModel):
             )
         if self.ut_steps > 1:
             recorder.ut_exits(counters.pop(0))
+        if self.has_mamba:
+            recorder.ssm_scan(counters.pop(0))
 
     def train_chunk(self, count: int, k: int, recorder: Recorder) -> None:
         if k == self._scan_k and self._train_scan is not None:

@@ -4,7 +4,8 @@ tracks, critical-path attribution), the step-phase profiler
 (``profiler.py``), Prometheus-style metrics text (``metrics.py``),
 the process's compile counter (``compile_meter.py``), a training
 run's set-up phases (``setup.py``), a MoE step's routing counters
-(``routing.py``) and a looped decoder's exit counters (``exits.py``).
+(``routing.py``), a looped decoder's exit counters (``exits.py``) and
+a mamba stack's scan counters (``ssm.py``).
 See docs/OBSERVABILITY.md; what reads these on the chip is under
 ``benchmark/`` (PERF.md section 3)."""
 
@@ -28,6 +29,7 @@ from theanompi_tpu.obs.setup import (  # noqa: F401
 )
 from theanompi_tpu.obs.routing import last_moe_counters  # noqa: F401
 from theanompi_tpu.obs.exits import last_ut_counters  # noqa: F401
+from theanompi_tpu.obs.ssm import last_ssm_counters  # noqa: F401
 from theanompi_tpu.obs.export import (  # noqa: F401
     chrome_trace,
     critical_path,
@@ -64,6 +66,7 @@ __all__ = [
     "last_moe_counters",
     "last_process_phases",
     "last_setup_phases",
+    "last_ssm_counters",
     "last_ut_counters",
     "make_context",
     "process_meter",

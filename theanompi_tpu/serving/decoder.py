@@ -154,6 +154,24 @@ class LlamaDecoder:
                 f"prediction module (mtp_depth={model.mtp_depth}: "
                 "speculative drafts from it) are not yet servable"
             )
+        multipliers = (
+            model.embedding_multiplier, model.residual_multiplier,
+            model.logits_scaling, model.attention_multiplier,
+        ) != (1.0, 1.0, 1.0, None)
+        if (model.has_mamba or model.tie_word_embeddings or multipliers
+                or model.position_embedding_type == "nope"):
+            raise NotImplementedError(
+                "serving has no recurrent state: a stack with a mamba "
+                f"layer (mixer_kinds={model.mixer_kinds_count}) needs a "
+                "convolution window and a scan state a layer and slot "
+                "beside the KV blocks, two kinds of cache in one "
+                "manager; nor does it know position_embedding_type "
+                f"{model.position_embedding_type!r}, a tied head "
+                f"(tie_word_embeddings={model.tie_word_embeddings}) or "
+                "the embedding, residual, attention and logits "
+                f"multipliers (given: {multipliers}) — not yet "
+                "servable"
+            )
         if getattr(model, "attn_per_layer", False):
             raise NotImplementedError(
                 "serving has one cache lifetime and one rotary table: "

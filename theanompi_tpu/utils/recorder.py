@@ -147,6 +147,9 @@ class Recorder:
         # a looped decoder's exit counters (obs/exits.py), likewise
         self._pending_exits = None
         self.ut_counters: dict | None = None
+        # a mamba stack's scan counters (obs/ssm.py), likewise
+        self._pending_ssm = None
+        self.ssm_counters: dict | None = None
         self.n_iter = 0
         self._last_print = 0
         # resilience bookkeeping (utils/supervisor.py): one entry per
@@ -292,8 +295,21 @@ class Recorder:
         _start_host_copy(exits)
         self._pending_exits = exits
 
+    def ssm_scan(self, stats) -> None:
+        """A mamba stack's scan counters ``[(K,) L_mamba, 2]`` of a
+        step (or K-step chunk), taken and read as :meth:`moe_routing`
+        does; only the newest step's are kept."""
+        _start_host_copy(stats)
+        self._pending_ssm = stats
+
     def flush(self) -> None:
         """Materialize pending device values (this is the fence)."""
+        if self._pending_ssm is not None:
+            from theanompi_tpu.obs.ssm import ssm_counters
+
+            a = np.asarray(self._pending_ssm, np.float64)
+            self.ssm_counters = ssm_counters(a[-1] if a.ndim == 3 else a)
+            self._pending_ssm = None
         if self._pending_exits is not None:
             from theanompi_tpu.obs.exits import ut_counters
 

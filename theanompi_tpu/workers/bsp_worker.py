@@ -616,6 +616,10 @@ def run(
         "attention": getattr(model, "attention", None),
         "experts_held": getattr(model, "moe_experts_held", None),
         "mtp_depth": getattr(model, "mtp_depth", 0),
+        # layers of each mixer kind ("attention", "mamba") and the
+        # state-space scan's chunk (None without a mamba layer)
+        "mixer_kinds": getattr(model, "mixer_kinds_count", None),
+        "ssd_chunk": getattr(model, "ssd_chunk", None),
         "exchange_bucket_mb": exchange.bucket_mb,
         "exchange_replicas": getattr(model, "exchange_replicas", None),
         "exchange_buckets": getattr(model, "exchange_buckets", None),
@@ -651,6 +655,8 @@ def run(
         "moe_counters": recorder.moe_counters,
         # exit counters of a looped decoder's last fenced step
         "ut_counters": recorder.ut_counters,
+        # scan counters of a mamba stack's last fenced step
+        "ssm_counters": recorder.ssm_counters,
         "step_profile": step_prof,
         "loader": loader_stats,
         "recorder": recorder,
