@@ -702,8 +702,14 @@ def test_hybrid_step_compiles_with_the_mixers_scopes_in_every_phase(
     """A stack with mamba layers through the model's own train step,
     compiled for the v5e: the mixer's block in all three phases beside
     the attention layer's, its four scopes in the text, the ONE
-    attention layer's three flash kernels at head dim 64, every fusion
-    that holds a product under a block, and no rotation anywhere."""
+    attention layer's three flash kernels at head dim 64, the scan's
+    two named kernels under ``blk_ssm/ssd_scan`` in forward, replay
+    and backward with no ``[.., chunk, chunk]``-a-head array left
+    beside them, every fusion that holds a product under a block, and
+    no rotation anywhere."""
+    import re
+    from collections import Counter
+
     from benchmark import hlo_read
 
     text = _llama_step_text(chip, monkeypatch, **_HYBRID)
@@ -719,12 +725,68 @@ def test_hybrid_step_compiles_with_the_mixers_scopes_in_every_phase(
         assert f"blk_ssm/{scope}/" in text or f"blk_ssm)/{scope}/" in text
     calls = hlo_read.custom_calls(text)
     assert len([ln for ln in calls.values() if "_flash_jit" in ln]) == 3
-    assert {e["block"] for e in kernels.values()} == {"blk_attn"}
+    assert {e["block"] for e in kernels.values()} == {"blk_attn", "blk_ssm"}
+    # the scan: one forward kernel a mamba layer in the forward and one
+    # in the replay, one backward kernel, each under the mixer's block
+    # AND the scan's scope (the backward rule inherits its call's)
+    scans = Counter(
+        (name.rsplit(".", 1)[0], e["phase"])
+        for name, e in kernels.items() if e["block"] == "blk_ssm"
+        and re.search(r"blk_ssm\)?/ssd_scan/", e["op_name"]))
+    assert scans == {("ssd-chunk-fwd", "fwd"): 2, ("ssd-chunk-fwd", "replay"): 2,
+                     ("ssd-chunk-bwd", "bwd"): 2}, scans
+    # the decay mask, the masked scores and their cotangents are
+    # ``[B, (G,) H, chunks, chunk, chunk]``: none is an array of the
+    # step (the parent wrote 98 such under the scope, float32 and bf16)
+    chunk = _HYBRID["mamba_chunk_size"]
+    masks = [
+        ln for ln in text.splitlines() if "ssd_scan" in ln
+        and re.search(rf"\[(\d+,){{2,}}{chunk},{chunk}\]", ln)]
+    assert not masks, masks[:3]
     assert products and "other" not in {
         e["block"] for e in products.values()}, products
     assert " cosine(" not in text and " sine(" not in text
     # the tied matrix: one leaf, so one Adam update of [vocab, dim]
     assert "lm_head" not in text
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_scan_kernels_compile_for_v5e_at_the_cells_widths(chip, direction):
+    """``ssd-chunk-fwd`` / ``ssd-chunk-bwd`` at the hybrid cell's
+    published shapes (1 x 8192 tokens, 64 heads of 64 over a state of
+    128 in one group, chunks of 256): Mosaic takes the lane slices,
+    the transposes and the VMEM the kernels ask for."""
+    from theanompi_tpu.ops import ssd, ssd_kernel
+
+    b, t, h, p, g, n, chunk = 1, 8192, 64, 64, 1, 128, 256
+    tiles = ssd_kernel.scan_tiles(t, chunk, p, n, h // g, h)
+    assert tiles == ssd_kernel.ScanTiles(256, 16, 128)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=chip)
+
+    args = (shape((b, t, h, p), jnp.bfloat16), shape((b, t, h), jnp.float32),
+            shape((h,), jnp.float32), shape((b, t, g, n), jnp.bfloat16),
+            shape((b, t, g, n), jnp.bfloat16), shape((h,), jnp.float32))
+
+    def forward(*a):
+        return ssd._scan_on_kernels(*a, tiles, with_stats=True)
+
+    def backward(*a):
+        return jax.grad(
+            lambda *a: forward(*a)[0].astype(jnp.float32).sum(),
+            argnums=range(6))(*a)
+
+    text = _compiled_text(forward if direction == "forward" else backward,
+                          *args)
+    names = {"forward": ["ssd-chunk-fwd"],
+             "backward": ["ssd-chunk-fwd", "ssd-chunk-bwd"]}[direction]
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == len(names)
+    for name in names:
+        assert any(name in ln for ln in calls), name
+    # nothing of a head's [chunk, chunk] in HBM
+    assert f"{chunk},{chunk}]" not in text
 
 
 # a decoder with every mechanism of the ``glm4_moe_lite`` cell at small

@@ -245,3 +245,31 @@ def test_serving_refuses_a_tied_head_without_a_mamba_layer():
     model.compile_iter_fns(mesh=make_mesh(data=1, devices=jax.devices()[:1]))
     with pytest.raises(NotImplementedError, match="a tied head"):
         model.make_decoder(max_slots=2, max_seq=16)
+
+
+def test_the_summary_says_which_form_of_the_scan_ran(monkeypatch):
+    """``"ssd_kernel"`` beside ``"ssd_chunk"``: the tiles the scan's
+    kernels took, ``{}`` where XLA's form runs — here, off the TPU;
+    ``Llama.ssd_kernel()`` answers from shapes and the device alone,
+    before anything is built."""
+    from benchmark.drivers.train import program_config
+    from benchmark.run import load_cell
+    from theanompi_tpu.ops import attention
+    from theanompi_tpu.workers import bsp_worker
+
+    assert Llama(_small()).ssd_kernel() == {}
+    assert Llama({}).ssd_kernel() == {}         # no mamba layer
+    res = bsp_worker.run(
+        devices=[0], modelfile="theanompi_tpu.models.llama",
+        modelclass="Llama", verbose=False,
+        config=dict(_small(), n_train=2, n_val=1, n_epochs=1),
+    )
+    assert res["ssd_kernel"] == {} and res["ssd_chunk"] == 8
+    # at the published widths on a TPU: 16 of the 64 heads a grid
+    # step, the mask in blocks of 128 positions
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    published = Llama(program_config(
+        load_cell(CELL)["config"], seed=SEED, n_replicas=1))
+    assert published.ssd_kernel() == dict(chunk=256, heads=16, sub=128)
+    # the rehearsal's sizes tile nothing: XLA's form there too
+    assert Llama(_small()).ssd_kernel() == {}

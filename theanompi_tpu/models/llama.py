@@ -1040,6 +1040,21 @@ class Llama(TMModel):
         the state-space scan; None without a mamba layer."""
         return self.mamba_chunk_size if self.has_mamba else None
 
+    def ssd_kernel(self) -> dict:
+        """The run summary's ``"ssd_kernel"``: the tiles the scan's
+        Pallas kernels take for this model's shapes
+        (``ssd.scan_kernel_tiles``) — ``{}`` where XLA's form runs
+        (off the TPU, a shape no tile divides) or without a mamba
+        layer.  Static, from shapes and the device."""
+        if not self.has_mamba:
+            return {}
+        m = self._mamba
+        tiles = ssd.scan_kernel_tiles(
+            self.seq_len, self.mamba_chunk_size, m["head_dim"], m["d_state"],
+            m["n_heads"] // m["n_groups"], m["n_heads"],
+        )
+        return {} if tiles is None else tiles._asdict()
+
     # -- what the per-layer remat keeps -----------------------------------
 
     @property

@@ -28,7 +28,12 @@ operands are in the input's dtype and accumulate in float32; inside
 the scan every array is head-major, so no product relays an operand.  The
 backward is autodiff's of this form (under the layer's remat in
 ``models/llama.py``, so one layer's decay mask ``[B, chunks, H, chunk,
-chunk]`` and its cotangent are alive at a time).  ``ssd_reference`` is
+chunk]`` and its cotangent are alive at a time).  ON A TPU, where the
+shapes tile (``scan_kernel_tiles``), the same scan runs in
+``ops/ssd_kernel.py``'s two Pallas kernels, which build the mask in
+VMEM and carry the state between chunks there, with a backward of
+their own; this form stays the path everywhere else and the form
+``tests/test_ssd.py`` holds to the recurrence.  ``ssd_reference`` is
 the recurrence itself, a ``lax.scan`` over tokens, as
 ``ops.attention.mha_reference`` stands beside the flash kernels.
 
@@ -47,6 +52,9 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from theanompi_tpu.ops import ssd_kernel
+from theanompi_tpu.ops.grouped_matmul import same_vma
 
 F32 = jnp.float32
 
@@ -92,6 +100,48 @@ def _carried_states(states, total):
     )
 
 
+def scan_kernel_tiles(t, chunk, p, n, heads_a_group, n_heads):
+    """The tiles ``ssd_scan`` hands its Pallas kernels for a shape
+    (``ssd_kernel.scan_tiles``), or ``None`` where XLA's form runs:
+    off the TPU (``ops.attention._on_tpu``: read off a device), or a
+    shape the kernels do not take."""
+    from theanompi_tpu.ops.attention import _on_tpu
+
+    tiles = ssd_kernel.scan_tiles(t, chunk, p, n, heads_a_group, n_heads)
+    return tiles if tiles is not None and _on_tpu() else None
+
+
+def _scan_on_kernels(x, dt, A, B, C, D, tiles, *, with_stats=False,
+                     interpret=False):
+    """``ssd_scan`` through ``ssd_kernel.ssd_chunks``, which wants the
+    positions on the lanes: ``x`` as ``[B, H P, T]``, ``B`` / ``C`` as
+    ``[B, G N, T]``, ``dt`` as ``[B, H, T]`` (where XLA keeps a batch
+    of one sequence with the positions minor, these transposes are
+    bitcasts).  The cumulative sums a chunk (small, float32) stay
+    XLA's."""
+    b, t, h, p = x.shape
+    g, n = B.shape[2], B.shape[3]
+    ln = tiles.chunk
+    dt_rows = dt.astype(F32).transpose(0, 2, 1)             # [B, H, T]
+    cum = jnp.cumsum(
+        (dt_rows * A.astype(F32)[:, None]).reshape(b, h, t // ln, ln), axis=-1
+    )                                                       # [B, H, Z, L]
+    y, s_in = ssd_kernel.ssd_chunks(*same_vma(
+        x.reshape(b, t, h * p).transpose(0, 2, 1), dt_rows,
+        cum.reshape(b, h, t), D.astype(F32),
+        B.reshape(b, t, g * n).transpose(0, 2, 1),
+        C.reshape(b, t, g * n).transpose(0, 2, 1),
+    ), n, tiles, interpret)
+    y = y.transpose(0, 2, 1).reshape(x.shape)
+    if not with_stats:
+        return y
+    stats = lax.stop_gradient(jnp.stack([
+        jnp.min(cum[..., -1]),
+        jnp.sqrt(jnp.mean(jnp.square(s_in[:, -1]))),
+    ]))
+    return y, stats
+
+
 def ssd_scan(x, dt, A, B, C, D, chunk, *, with_stats=False):
     """The chunked state-space scan.  ``x [B, T, H, P]``, ``dt [B, T,
     H]`` (positive: after its softplus), ``A [H]`` (negative), ``B``
@@ -109,10 +159,18 @@ def ssd_scan(x, dt, A, B, C, D, chunk, *, with_stats=False):
     transpose in and the one out are of ``x`` and ``y`` in their own
     dtype (with the tokens leading, XLA relaid float32 ``[T, H P]``
     arrays around every product: some thirty 0.65 ms copies a layer
-    at the benchmark's sizes; PERF.md section 6, PR 47)."""
+    at the benchmark's sizes; PERF.md section 6, PR 47).
+
+    Which form runs is read off the device and the shapes
+    (``scan_kernel_tiles``): the Pallas kernels on a TPU where chunk,
+    state and heads tile, the products below everywhere else."""
     b, t, h, p = x.shape
     g, n = B.shape[2], B.shape[3]
     r = h // g
+    tiles = scan_kernel_tiles(t, chunk, p, n, r, h)
+    if tiles is not None:
+        return _scan_on_kernels(
+            x, dt, A, B, C, D, tiles, with_stats=with_stats)
     cd = x.dtype
     ln = min(int(chunk), t)
     pad = -t % ln

@@ -620,6 +620,8 @@ def run(
         # state-space scan's chunk (None without a mamba layer)
         "mixer_kinds": getattr(model, "mixer_kinds_count", None),
         "ssd_chunk": getattr(model, "ssd_chunk", None),
+        # the tiles the scan's kernels took ({} where XLA's form runs)
+        "ssd_kernel": getattr(model, "ssd_kernel", dict)(),
         "exchange_bucket_mb": exchange.bucket_mb,
         "exchange_replicas": getattr(model, "exchange_replicas", None),
         "exchange_buckets": getattr(model, "exchange_buckets", None),
