@@ -585,15 +585,16 @@ def _step_blocks(text):
     return have, top, kernels, products
 
 
-def _llama_step_text(chip, monkeypatch, n_keep=0, lowered=False, **knobs):
+def _llama_step_text(chip, monkeypatch, n_keep=0, lowered=False,
+                     n_keep_attn=0, **knobs):
     """The compiled text (``lowered``: the lowered text, which names
     no source line outside the kernels' bodies) of a small ``Llama``'s
     real train step
     (``compile_iter_fns``: ``value_and_grad`` of ``loss_fn``, then
     ``ExchangePlan.apply``) for the v5e; the parameters are shapes, so
-    nothing is placed.  ``n_keep`` stands in for the device's memory
-    (a described device reports none: 0 calls keep the MLP's
-    products)."""
+    nothing is placed.  ``n_keep`` and ``n_keep_attn`` stand in for
+    the device's memory (a described device reports none: 0 calls
+    keep the MLP's products, 0 attention's)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from theanompi_tpu.models.llama import Llama
@@ -604,7 +605,8 @@ def _llama_step_text(chip, monkeypatch, n_keep=0, lowered=False, **knobs):
     mesh = make_mesh(data=1, devices=list(chip.device_set))
     rep = NamedSharding(mesh, P())
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)  # as on the chip
-    monkeypatch.setattr(Llama, "remat_keep_calls", lambda self, limit: n_keep)
+    monkeypatch.setattr(Llama, "remat_keep_calls",
+                        lambda self, limit: (n_keep, n_keep_attn))
     model = Llama(dict(dict(
         dim=256, n_layers=2, n_heads=2, n_kv_heads=2, ffn_dim=512,
         vocab=4096, seq_len=t, batch_size=b, compute_dtype="bfloat16",
@@ -1149,6 +1151,36 @@ def test_kept_calls_replay_no_gate_or_up_product(chip, monkeypatch, n_keep):
         and "blk_ffn" in ln and ",512]" in ln.split(" convolution(")[0]
     ]
     assert len(replayed) == 2 * (2 - n_keep), replayed
+    assert _flash_kernels(text) == dict(fwd=2, dkv=2, dq=2)
+
+
+@pytest.mark.parametrize("knobs, n_keep_attn", [
+    (dict(), 0), (dict(), 1), (dict(), 2), (dict(qk_norm=True), 2),
+    (dict(position_embedding_type="nope"), 2),
+], ids=["rope-0", "rope-1", "rope-2", "qk_norm-2", "nope-2"])
+def test_kept_attention_calls_replay_no_projection(
+    chip, monkeypatch, knobs, n_keep_attn
+):
+    """A GQA 2:1 step with the last ``n_keep_attn`` of its 2 layer
+    calls keeping ``ATTN_RESIDUALS``: the compiled text holds the
+    three projections (products under ``gqa_proj`` at the default
+    precision; the rotation's own is at the highest) and ``wo`` (a
+    ``[B, T, D]`` result under ``blk_attn``) in the replay of the
+    calls that keep none, and in no other; the three flash kernels a
+    layer stay."""
+    text = _llama_step_text(chip, monkeypatch, n_keep_attn=n_keep_attn,
+                            dim=512, n_heads=4, n_kv_heads=2, **knobs)
+    replayed = [
+        ln for ln in text.splitlines()
+        if " convolution(" in ln
+        and "rematted_computation/blk_attn" in ln
+    ]
+    projections = [ln for ln in replayed if "/gqa_proj/" in ln
+                   and "operand_precision={highest" not in ln]
+    wo = [ln for ln in replayed if "/gqa_proj/" not in ln
+          and "[2,256,512]" in ln.split(" convolution(")[0]]
+    assert (len(projections), len(wo)) == (
+        3 * (2 - n_keep_attn), 2 - n_keep_attn), replayed
     assert _flash_kernels(text) == dict(fwd=2, dkv=2, dq=2)
 
 

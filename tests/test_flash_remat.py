@@ -12,12 +12,15 @@ own train step; ``tests/test_chip_compile.py`` counts the same in a
 text compiled for the chip.
 
 
-The same remat keeps, for the LAST ``remat_kept_calls`` layer calls,
-the dense MLP's gate and up products (``ops.layers.MLP_RESIDUALS``):
-as many calls as ``Llama.remat_keep_calls`` finds room for between
-its estimate of the step's peak and the device's memory.  The second
-half of this file counts the replayed products, holds all-kept
-against none-kept, and pins the rule and the estimate.
+The same remat keeps, for the LAST ``remat_kept_calls`` dense layer
+calls, the MLP's gate and up products (``ops.layers.MLP_RESIDUALS``)
+and, for the last ``remat_kept_attn_calls`` grouped-query attention
+calls, q, k, v and the attention block's output
+(``models.llama.ATTN_RESIDUALS``): as many calls as
+``Llama.remat_keep_calls`` finds room for between its estimate of the
+step's peak and the device's memory, the MLP's copies first.  The
+second half of this file counts the replayed products, holds
+all-kept against none-kept, and pins the rule and the estimate.
 """
 
 import re
@@ -29,7 +32,7 @@ import pytest
 from jax.ad_checkpoint import checkpoint_name
 
 from theanompi_tpu.models import llama
-from theanompi_tpu.models.llama import Llama
+from theanompi_tpu.models.llama import ATTN_RESIDUALS, Llama
 from theanompi_tpu.ops import attention
 from theanompi_tpu.ops.attention import FLASH_RESIDUALS, flash_attention_tpu
 from theanompi_tpu.ops.layers import MLP_RESIDUALS
@@ -183,6 +186,8 @@ def test_worker_summary_names_what_remat_keeps(remat):
         verbose=False,
     )
     assert res["remat_saves"] == (list(FLASH_RESIDUALS) if remat else [])
+    # the CPU reports no memory limit: no call keeps more
+    assert (res["remat_kept_calls"], res["remat_kept_attn_calls"]) == (0, 0)
 
 
 def test_worker_summary_of_a_model_without_layer_remat():
@@ -196,6 +201,7 @@ def test_worker_summary_of_a_model_without_layer_remat():
         verbose=False,
     )
     assert res["remat_saves"] == []
+    assert (res["remat_kept_attn_calls"], res["remat_kept_bytes"]) == (0, 0)
 
 
 # -- the MLP's two products, kept for the calls the memory holds -------------
@@ -216,17 +222,21 @@ def _replayed_mlp_products(jaxpr):
     )
 
 
-def _step_model(n_keep, tp=1, **over):
-    """A TINY model with its step built and ``n_keep`` forced (the CPU
-    reports no memory limit: ``compile_iter_fns`` leaves 0)."""
+def _step_model(n_keep, tp=1, n_keep_attn=0, **over):
+    """A TINY model with its step built and ``n_keep`` (calls that
+    keep ``MLP_RESIDUALS``) and ``n_keep_attn`` (``ATTN_RESIDUALS``)
+    forced (the CPU reports no memory limit: ``compile_iter_fns``
+    leaves 0 and 0)."""
     model = Llama(dict(TINY, n_layers=2, optimizer="sgd", lr=1.0, tp=tp,
                        n_kv_heads=tp, **over))
     model.build_model(n_replicas=1)
     model.compile_iter_fns(
         mesh=make_mesh(data=1, model=tp, devices=jax.devices()[:tp]))
-    assert model.remat_kept_calls == 0
+    assert (model.remat_kept_calls, model.remat_kept_attn_calls) == (0, 0)
     model.remat_kept_calls = (
         model.remat_calls if n_keep == "all" else n_keep)
+    model.remat_kept_attn_calls = (
+        model.remat_calls if n_keep_attn == "all" else n_keep_attn)
     return model
 
 
@@ -250,13 +260,82 @@ def test_kept_calls_replay_no_gate_or_up_product(n_keep, over):
     assert _replayed_mlp_products(jaxpr.jaxpr) == 2 * replayed
 
 
+# where grouped-query attention puts its names: after the rotation,
+# on the products' outputs under QK-norm, and without a rotation
+ATTENTIONS = {"rope": {}, "qk_norm": {"qk_norm": True},
+              "nope": {"position_embedding_type": "nope"}}
+
+
+def _replayed_attn_products(jaxpr, model):
+    """(q / k / v products, ``wo`` products) in the remat's replay of
+    a TINY model's step: under ``gqa_proj`` the calls that take the
+    normed ``[B, T, D]`` input (``tp.col_parallel_heads``: the
+    rotation and QK-norm take ``[B, h, T, hd]``), under ``blk_attn``
+    beside them the one product with a ``[B, T, D]`` result (the
+    reference attention's two have four axes)."""
+    row = (TINY["batch_size"], T, model.dim)
+    proj = wo = 0
+    for eqn in _eqns(jaxpr):
+        stack = str(eqn.source_info.name_stack)
+        if "rematted_computation" not in stack or "blk_attn" not in stack:
+            continue
+        if "gqa_proj" in stack:
+            proj += (eqn.primitive.name == "custom_vjp_call"
+                     and any(v.aval.shape == row for v in eqn.invars))
+        else:
+            wo += (eqn.primitive.name == "dot_general"
+                   and eqn.outvars[0].aval.shape == row)
+    return proj, wo
+
+
+LOOPED = DECODERS.args[1][1]
+# plain and looped decoders under each (a looped stack does not
+# compose with ``nope``)
+ATTN_DECODERS = pytest.mark.parametrize("over", [
+    *ATTENTIONS.values(), LOOPED, dict(LOOPED, **ATTENTIONS["qk_norm"]),
+], ids=[*ATTENTIONS, "looped", "looped-qk_norm"])
+
+
+@ATTN_DECODERS
+@pytest.mark.parametrize("n_keep", [0, 1, "all"])
+def test_kept_attention_calls_replay_no_projection(n_keep, over):
+    """A call that keeps ``ATTN_RESIDUALS`` replays none of its four
+    products, a call that does not replays all four; the MLP's two
+    are replayed either way (no call keeps those here)."""
+    model = _step_model(0, n_keep_attn=n_keep, **over)
+    jaxpr = jax.make_jaxpr(model.train_step_fn)(*_step_args(model))
+    replayed = model.remat_calls - model.remat_kept_attn_calls
+    assert model.remat_calls == 2 * over.get("ut_steps", 1)
+    assert _replayed_attn_products(jaxpr.jaxpr, model) == (
+        3 * replayed, replayed)
+    assert _replayed_mlp_products(jaxpr.jaxpr) == 2 * model.remat_calls
+
+
+@pytest.mark.parametrize("over, kept_attn, mlp, attn", [
+    (dict(n_layers=3), 1, {2}, {2}),
+    (dict(n_layers=2, ut_steps=4, exit_beta=0.1), 3, {7}, {5, 6, 7}),
+    # the dense calls and the attention calls are not the same calls
+    (dict(n_layers=3, n_experts=4, moe_top_k=2, first_k_dense=1), 2,
+     {0}, {1, 2}),
+    (dict(n_layers=3, layer_types=["attention", "mamba", "mamba"],
+          mamba_n_heads=4, mamba_d_head=16, mamba_d_state=16,
+          mamba_chunk_size=16), 1, {2}, {0}),
+], ids=["plain", "looped", "first_dense", "hybrid"])
+def test_kept_calls_are_the_last_of_their_kind(over, kept_attn, mlp, attn):
+    model = Llama(dict(TINY, **over))
+    model.remat_kept_calls, model.remat_kept_attn_calls = 1, kept_attn
+    assert model._kept_calls() == (mlp, attn)
+
+
 @pytest.mark.parametrize(
-    "over", [{}, {"n_experts": 4, "moe_top_k": 2, "capacity_factor": None}],
-    ids=["dense", "moe"])
+    "over", [{}, {"n_experts": 4, "moe_top_k": 2, "capacity_factor": None},
+             ATTENTIONS["qk_norm"], ATTENTIONS["nope"]],
+    ids=["dense", "moe", "qk_norm", "nope"])
 def test_names_alone_leave_the_lowered_step_as_it_was(monkeypatch, over):
     """No call kept: ONE policy, the parent's; ``checkpoint_name``
-    lowers to nothing, so the step's text is the text without the two
-    names.  An expert layer has none to begin with."""
+    lowers to nothing, so the step's text is the text without the
+    MLP's two names and attention's four.  An expert layer has
+    attention's alone."""
     def text():
         model = _step_model(0, **over)
         text = model._train_step.lower(*_step_args(model)).as_text()
@@ -268,24 +347,53 @@ def test_names_alone_leave_the_lowered_step_as_it_was(monkeypatch, over):
     assert named == text()
 
 
-@DECODERS
-@pytest.mark.parametrize("tp", [1, 2])
-def test_all_calls_kept_is_bitwise_none_kept(tp, over):
-    """Loss and gradients (plain SGD at lr 1: the step's parameter
-    change) with every call's products kept against every call's
-    replayed."""
-    def step(n_keep):
-        model = _step_model(n_keep, tp=tp, **over)
-        before = jax.tree.map(np.asarray, model.params)
-        params, _, _, loss, *_ = model.train_step_fn(*_step_args(model))
-        grads = jax.tree.map(lambda a, b: a - np.asarray(b), before, params)
-        return float(loss), grads
+def _loss_and_grads(model, compiler_options=None):
+    """A step's loss and its gradients (plain SGD at lr 1: the step's
+    parameter change)."""
+    before = jax.tree.map(np.asarray, model.params)
+    args = _step_args(model)
+    step = model._train_step.lower(*args).compile(
+        compiler_options=compiler_options)
+    params, _, _, loss, *_ = step(*args)
+    grads = jax.tree.map(lambda a, b: a - np.asarray(b), before, params)
+    return float(loss), grads
 
-    (loss_kept, kept), (loss_none, none) = step("all"), step(0)
+
+def _assert_bitwise(kept, none, leaves):
+    (loss_kept, kept), (loss_none, none) = kept, none
     assert loss_kept == loss_none
     for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(none)):
         assert np.array_equal(a, b)
-    assert float(np.abs(kept["layers"][0]["w_gate"]).max()) > 0
+    for leaf in leaves:
+        assert float(np.abs(kept["layers"][0][leaf]).max()) > 0
+
+
+@DECODERS
+@pytest.mark.parametrize("tp", [1, 2])
+def test_all_calls_kept_is_bitwise_none_kept(tp, over):
+    """Loss and gradients with every call's products kept against
+    every call's replayed."""
+    _assert_bitwise(
+        *(_loss_and_grads(_step_model(n_keep, tp=tp, **over))
+          for n_keep in ("all", 0)),
+        leaves=["w_gate"])
+
+
+@ATTN_DECODERS
+@pytest.mark.parametrize("tp", [1, 2])
+def test_all_attention_calls_kept_is_bitwise_none_kept(tp, over):
+    """The same with every call's q, k, v and block output kept
+    against every call's rebuilt: the same products in the same
+    precision, run once instead of twice.  Both steps are compiled as
+    they are written: left to itself XLA:CPU fuses the replayed
+    residual add into its neighbours otherwise than the forward's,
+    and an ``mlp_norm`` gradient then differs in its last bit."""
+    _assert_bitwise(
+        *(_loss_and_grads(
+            _step_model(0, tp=tp, n_keep_attn=n_keep_attn, **over),
+            {"xla_backend_optimization_level": 0})
+          for n_keep_attn in ("all", 0)),
+        leaves=["wq", "wk", "wv", "wo"])
 
 
 def _cell_model(cell):
@@ -329,43 +437,87 @@ def test_estimate_reads_the_cells_peaks(cell):
     assert abs(estimate - LEDGER_PEAKS[cell]) < 0.6, estimate
 
 
-@pytest.mark.parametrize("cell, limit_gib, lo, hi", [
-    ("mistral7b_train_t4096", 15.75, 2, 2),     # ample: all calls
-    ("mistral7b_train_t4096", 12.0, 0, 0),      # below the estimate
-    ("mistral7b_train_t4096", None, 0, 0),      # no device limit
-    ("ouro_train_t4096", 15.75, 6, 14),
+@pytest.mark.parametrize("cell, limit_gib, n_mlp, n_attn", [
+    # at the chip's 15.75 GiB, the counts of the benchmark's six
+    # decoders (PERF.md §6, PR 49)
+    ("mistral7b_train_t4096", 15.75, 2, 2),         # ample: all calls
+    ("mellum2_train_t8192", 15.75, 0, 4),           # expert layers alone
+    ("olmoe_train_t4096", 15.75, 0, 1),
+    ("ouro_train_t4096", 15.75, 10, 1),             # what 10 MLP calls leave
+    # nine mamba calls and the attention call, every SwiGLU dense:
+    # ten MLP calls leave 0.018 GB, attention's call takes 0.084
+    ("granite4h_micro_train_t8192", 15.75, 10, 0),
+    ("glm47flash_train_t8192", 15.75, 1, 0),        # latent attention
+    ("mistral7b_train_t4096", 12.0, 0, 0),          # below the estimate
+    ("mistral7b_train_t4096", None, 0, 0),          # no device limit
     ("ouro_train_t4096", 64.0, 32, 32),
-    ("olmoe_train_t4096", 64.0, 0, 0),          # an expert layer
-    # nine mamba calls and the attention call, every SwiGLU dense
-    ("granite4h_micro_train_t8192", 15.75, 10, 10),
-    ("granite4h_micro_train_t8192", 13.5, 0, 1),
+    ("olmoe_train_t4096", 64.0, 0, 1),              # an expert layer
+    ("granite4h_micro_train_t8192", 64.0, 10, 1),
+    ("granite4h_micro_train_t8192", 13.5, 1, 0),
 ], ids=str)
-def test_keep_rule_from_shapes_and_the_limit(cell, limit_gib, lo, hi):
+def test_keep_rule_from_shapes_and_the_limit(cell, limit_gib, n_mlp, n_attn):
     model = _cell_model(cell)
     limit = None if limit_gib is None else int(limit_gib * GIB)
-    n_keep = model.remat_keep_calls(limit)
-    assert lo <= n_keep <= hi, n_keep
-    if limit and 0 < n_keep < model.remat_calls:
-        # the next call's copies would not have fitted
-        room = (limit - llama.REMAT_RESERVE_BYTES
-                - model.step_peak_estimate())
-        assert (n_keep * model.remat_kept_bytes_per_call <= room
-                < (n_keep + 1) * model.remat_kept_bytes_per_call)
+    assert model.remat_keep_calls(limit) == (n_mlp, n_attn)
+    if not limit:
+        return
+    # the MLP's copies first, attention's from what they leave; where
+    # not every call of a kind keeps its copies, the next call's would
+    # not have fitted
+    room = max(
+        limit - llama.REMAT_RESERVE_BYTES - model.step_peak_estimate(), 0)
+    for kept, per_call, calls in (
+        (n_mlp, model.remat_kept_bytes_per_call,
+         model.ut_steps * model.layer_kinds.count("dense")),
+        (n_attn, model.remat_kept_attn_bytes_per_call,
+         model.ut_steps * sum(model._gqa_layers)),
+    ):
+        assert kept * per_call <= room
+        if kept < calls:
+            assert room < (kept + 1) * per_call
+        room -= kept * per_call
+    model.remat_kept_calls, model.remat_kept_attn_calls = n_mlp, n_attn
+    assert model.remat_kept_bytes == (
+        n_mlp * model.remat_kept_bytes_per_call
+        + n_attn * model.remat_kept_attn_bytes_per_call)
 
 
 def test_expert_layer_names_no_product():
+    """An expert layer names no MLP product; its attention block
+    names what every grouped-query block names."""
     model = _cell_model("olmoe_train_t4096")
     assert model.remat_kept_bytes_per_call == 0
-    assert not set(MLP_RESIDUALS) & set(model.remat_saves)
+    assert not set(MLP_RESIDUALS + ATTN_RESIDUALS) & set(model.remat_saves)
+    # q, k, v of 16 heads of 128 and a row of 2048, 4 x 4096 tokens
+    assert model.remat_kept_attn_bytes_per_call == (
+        4 * 4096 * (3 * 16 * 128 + 2048) * 2)
+
+
+@pytest.mark.parametrize("cell, layers, per_token", [
+    # latent attention's cheap residual is the latent, not the heads
+    ("glm47flash_train_t8192", 0, 0),
+    # a mamba call counts nothing: the one attention layer in ten
+    # (32 heads and 8 key/value heads of 64, a row of 2048)
+    ("granite4h_micro_train_t8192", 1, (32 + 2 * 8) * 64 + 2048),
+    # k and v weigh what they weigh BEFORE the repeat (8 of 32 heads)
+    ("mistral7b_train_t4096", 2, (32 + 2 * 8) * 128 + 4096),
+], ids=["latent", "mamba", "gqa"])
+def test_attention_names_weigh_what_the_block_keeps(cell, layers, per_token):
+    model = _cell_model(cell)
+    n_tok = model.config["batch_size"] * model.seq_len
+    assert sum(model._gqa_layers) == layers
+    assert model.remat_kept_attn_bytes_per_call == n_tok * per_token * 2
 
 
 @pytest.mark.parametrize("over, keeps", [
-    ({}, 2), ({"remat": False}, 0), ({"pp": 2}, 0),
-    ({"n_experts": 4, "moe_top_k": 2}, 0),
-], ids=["remat", "no_remat", "pipeline", "moe"])
+    ({}, (2, 2)), ({"remat": False}, (0, 0)), ({"pp": 2}, (0, 0)),
+    ({"n_experts": 4, "moe_top_k": 2}, (0, 2)),
+    ({"tp": 2, "n_kv_heads": 2}, (2, 2)),
+], ids=["remat", "no_remat", "pipeline", "moe", "tp"])
 def test_keep_rule_bypasses(over, keeps):
     model = Llama(dict(TINY, n_layers=2, **over))
     assert model.remat_keep_calls(64 * GIB) == keeps
+    assert model.remat_keep_calls(None) == (0, 0)
 
 
 def test_compile_reads_the_devices_limit(monkeypatch):
@@ -381,7 +533,8 @@ def test_compile_reads_the_devices_limit(monkeypatch):
     model = Llama(dict(TINY, n_layers=2))
     model.build_model(n_replicas=1)
     model.compile_iter_fns(mesh=make_mesh(data=1, devices=jax.devices()[:1]))
-    assert (model.remat_kept_calls, seen) == (2, jax.devices()[:1])
+    assert (model.remat_kept_calls, model.remat_kept_attn_calls, seen) == (
+        2, 2, jax.devices()[:1])
 
 
 class _Device:
@@ -416,7 +569,11 @@ def test_worker_summary_counts_the_kept_calls(monkeypatch, limit_gib, kept):
         modelclass="Llama",
         config=dict(TINY, n_layers=1, n_epochs=1, seed=3), verbose=False,
     )
-    per_call = 2 * TINY["batch_size"] * T * FFN * 4
+    n_tok = TINY["batch_size"] * T
+    # the gate and the up product; q, k, v (2 heads and 1 of 16) and
+    # the block's output: float32
+    per_call = 2 * n_tok * FFN * 4 + n_tok * (4 * 16 + TINY["dim"]) * 4
     assert (res["remat_calls"], res["remat_kept_calls"],
-            res["remat_kept_bytes"]) == (1, kept, kept * per_call)
+            res["remat_kept_attn_calls"], res["remat_kept_bytes"]) == (
+        1, kept, kept, kept * per_call)
     assert res["remat_saves"] == list(FLASH_RESIDUALS)

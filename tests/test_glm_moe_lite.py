@@ -29,6 +29,7 @@ from jax.sharding import PartitionSpec as P
 
 from benchmark.reference import glm_moe_lite as ref
 from benchmark.run import program_knobs
+from test_flash_remat import _eqns
 from test_gqa_proj import rope_stride2
 from theanompi_tpu.models.llama import Llama, _heads, rms_norm, rope_tail
 from theanompi_tpu.parallel import make_mesh, moe
@@ -646,13 +647,33 @@ def test_remat_counts_the_calls_that_are_dense():
     assert model.layer_kinds == ("dense", "moe", "moe")
     per_call = model.remat_kept_bytes_per_call
     assert per_call == 2 * 2 * 32 * 96 * 4      # the DENSE width, fp32
-    assert model.remat_keep_calls(1 << 40) == 1     # never an expert call
+    # never an expert call; latent attention names nothing
+    assert model.remat_keep_calls(1 << 40) == (1, 0)
     model.remat_kept_calls = 1
-    assert model._kept_calls() == {0}
+    assert model._kept_calls() == ({0}, set())
     assert model.remat_saves[-1] == "moe_tile_plan"
+    # grouped-query attention's names are every layer's, dense or not
     plain = Llama(dict(n_layers=3, n_experts=4, capacity_factor=None))
-    assert plain.remat_keep_calls(1 << 40) == 0
-    assert Llama(dict(n_layers=3)).remat_keep_calls(1 << 40) == 3
+    assert plain.remat_keep_calls(1 << 40) == (0, 3)
+    assert Llama(dict(n_layers=3)).remat_keep_calls(1 << 40) == (3, 3)
+
+
+def test_mtp_block_is_the_stacks_own_layer_call():
+    """The MTP block runs the SAME checkpointed call as the stack's
+    expert layers (one trace, one private function of the lowered
+    text, as before the calls were built by what they keep): of the
+    step's four layer calls under the remat (dense, two expert, the
+    MTP block's) two are distinct."""
+    knobs, _ = rehearsal()
+    model = build(knobs)
+    x, y = model.put_batch(model.data.train_batch(0))
+    jaxpr = jax.make_jaxpr(model._train_step)(
+        model.params, model.opt_state, model.ef_state, x, y,
+        jnp.float32(1.0), *model._state_args())
+
+    calls = [eqn.params["jaxpr"] for eqn in _eqns(jaxpr.jaxpr)
+             if eqn.primitive.name == "remat2"]
+    assert (len(calls), len({id(call) for call in calls})) == (4, 2)
 
 
 def test_summary_names_the_mechanisms():

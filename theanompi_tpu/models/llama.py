@@ -43,7 +43,8 @@ the layout-invariance tests).  Per-layer ``jax.checkpoint`` (remat)
 bounds activation memory for long sequences; it keeps the layer's
 input and the flash kernel's two outputs and, for as many of the last
 layer calls as the device's memory holds, the dense MLP's gate and up
-products (``Llama.remat_keep_calls``).  Params are initialized
+products and then grouped-query attention's q, k, v and the attention
+block's output (``Llama.remat_keep_calls``).  Params are initialized
 *under jit with sharded out_shardings*, so the full 8B-scale parameter
 set never materializes on one device.
 
@@ -117,6 +118,14 @@ PyTree = Any
 # weight in compute dtype and no gap between the allocator's buffers
 # (PERF.md, PR 36, has the chip-less compiles it was fixed from)
 REMAT_RESERVE_BYTES = 1 << 30
+
+# ``jax.ad_checkpoint.checkpoint_name``s of what a grouped-query
+# attention block's backward reads and its replay would rebuild: q, k
+# and v as the flash backward takes them, but for the K/V repeat
+# (``Llama._gqa_qkv``), and the block's output, the FFN half's input
+# (``Llama._layer``).  A ``jax.checkpoint`` whose policy saves them
+# replays none of the four products (wq, wk, wv, wo)
+ATTN_RESIDUALS = ("attn_q", "attn_k", "attn_v", "attn_block_out")
 
 
 def _device_bytes_limit(devices) -> int | None:
@@ -576,10 +585,13 @@ class Llama(TMModel):
         if "moe" in self.layer_kinds and self.capacity_factor is None:
             saves += (TILE_PLAN_RESIDUAL,)
         self.remat_saves = saves if self.remat else ()
-        # how many of the LAST layer calls also keep ``MLP_RESIDUALS``
-        # ("remat_kept_calls" of the summary): ``compile_iter_fns``
-        # sets it from the shapes and the device's memory
+        # how many of the LAST dense layer calls also keep
+        # ``MLP_RESIDUALS`` and how many of the last grouped-query
+        # attention calls ``ATTN_RESIDUALS`` ("remat_kept_calls",
+        # "remat_kept_attn_calls" of the summary): ``compile_iter_fns``
+        # sets both from the shapes and the device's memory
         self.remat_kept_calls = 0
+        self.remat_kept_attn_calls = 0
         self.compute_dtype = jnp.dtype(c.get("compute_dtype", "bfloat16"))
         self.seed = int(c.get("seed", 42))
         self.n_epochs = int(c.get("n_epochs", 5))
@@ -1064,6 +1076,13 @@ class Llama(TMModel):
         return self.n_layers * self.ut_steps if self.remat else 0
 
     @property
+    def _local_tokens(self) -> int:
+        """Tokens of a step one device holds: ``B_loc x T_loc``."""
+        return int(self.config.get("batch_size", 8)) * (
+            self.seq_len // self.sp
+        )
+
+    @property
     def remat_kept_bytes_per_call(self) -> int:
         """Bytes of ``MLP_RESIDUALS`` one DENSE layer call keeps on a
         device: two ``[B_loc, T_loc, dense_ffn_dim / tp]`` in compute
@@ -1071,18 +1090,46 @@ class Llama(TMModel):
         neither."""
         if "dense" not in self.layer_kinds:
             return 0
-        n_tok = int(self.config.get("batch_size", 8)) * (
-            self.seq_len // self.sp
-        )
         return (
-            2 * n_tok * (self.dense_ffn_dim // self.tp)
+            2 * self._local_tokens * (self.dense_ffn_dim // self.tp)
+            * self.compute_dtype.itemsize
+        )
+
+    @property
+    def _gqa_layers(self) -> tuple[bool, ...]:
+        """For every layer, whether its mixer is grouped-query
+        attention: neither a mamba layer nor latent attention names
+        ``ATTN_RESIDUALS``."""
+        return tuple(
+            self.attention != "mla" and mixer == "attention"
+            for mixer in self.mixer_kinds
+        )
+
+    @property
+    def remat_kept_attn_bytes_per_call(self) -> int:
+        """Bytes of ``ATTN_RESIDUALS`` one grouped-query attention
+        call keeps on a device, in compute dtype: q ``[B_loc, H_loc,
+        T_loc, hd]``, k and v ``[B_loc, Hkv_loc, T_loc, hd]`` (before
+        the repeat) and the block's output ``[B_loc, T_loc, D]``; 0
+        for a model without such a call (latent attention, mamba
+        layers alone), which names none of them."""
+        if not any(self._gqa_layers):
+            return 0
+        heads = (self.n_heads + 2 * self.n_kv_heads) // self.tp
+        return (
+            self._local_tokens * (heads * self.head_dim + self.dim)
             * self.compute_dtype.itemsize
         )
 
     @property
     def remat_kept_bytes(self) -> int:
-        """Bytes of ``MLP_RESIDUALS`` the kept calls hold on a device."""
-        return self.remat_kept_calls * self.remat_kept_bytes_per_call
+        """Bytes of ``MLP_RESIDUALS`` and ``ATTN_RESIDUALS`` the kept
+        calls hold on a device."""
+        return (
+            self.remat_kept_calls * self.remat_kept_bytes_per_call
+            + self.remat_kept_attn_calls
+            * self.remat_kept_attn_bytes_per_call
+        )
 
     def _local_params(self, axis_sizes) -> tuple[int, int]:
         """(elements, bytes) of the parameters ONE device holds under
@@ -1108,7 +1155,8 @@ class Llama(TMModel):
 
     def step_peak_estimate(self) -> int:
         """Bytes one device holds at the train step's peak when no
-        call keeps ``MLP_RESIDUALS``, from shapes alone: every local
+        call keeps ``MLP_RESIDUALS`` or ``ATTN_RESIDUALS``, from
+        shapes alone: every local
         parameter's master, gradient and optimizer state; what each
         layer call keeps for its replay (its input, the flash kernel's
         output and logsumexp); the head's live set — one set of local
@@ -1146,19 +1194,35 @@ class Llama(TMModel):
             + attn_calls * kept_flash + head
         )
 
-    def remat_keep_calls(self, bytes_limit: int | None) -> int:
-        """How many of the last layer calls keep ``MLP_RESIDUALS``: as
-        many as fit between the step's estimated peak and the device's
-        ``bytes_limit`` less ``REMAT_RESERVE_BYTES``, of the calls
-        that are dense.  0 without a limit (the CPU), without remat,
-        without a dense layer and on the pipeline path, whose stage
-        function keeps the plain policy."""
-        per_call = self.remat_kept_bytes_per_call
-        if not (self.remat and bytes_limit and per_call) or self.pp > 1:
-            return 0
-        free = bytes_limit - REMAT_RESERVE_BYTES - self.step_peak_estimate()
-        dense_calls = self.ut_steps * self.layer_kinds.count("dense")
-        return int(min(max(free // per_call, 0), dense_calls))
+    def remat_keep_calls(self, bytes_limit: int | None) -> tuple[int, int]:
+        """``(n_mlp, n_attn)``: how many of the last DENSE layer calls
+        keep ``MLP_RESIDUALS`` and how many of the last grouped-query
+        attention calls keep ``ATTN_RESIDUALS``.  The room is what
+        lies between the step's estimated peak and the device's
+        ``bytes_limit`` less ``REMAT_RESERVE_BYTES``; the MLP's copies
+        fill it first, as many calls as fit, attention's take what
+        they leave (so a device keeps every MLP call it kept before
+        attention's were counted).  ``(0, 0)`` without a limit (the
+        CPU), without remat and on the pipeline path, whose stage
+        function keeps the plain policy; a model without a dense layer
+        keeps no MLP call, one without grouped-query attention (latent
+        attention, mamba layers alone) no attention call."""
+        if not (self.remat and bytes_limit) or self.pp > 1:
+            return 0, 0
+        free = max(
+            bytes_limit - REMAT_RESERVE_BYTES - self.step_peak_estimate(), 0
+        )
+        mlp_bytes = self.remat_kept_bytes_per_call
+        n_mlp = min(
+            free // mlp_bytes,
+            self.ut_steps * self.layer_kinds.count("dense"),
+        ) if mlp_bytes else 0
+        free -= n_mlp * mlp_bytes
+        attn_bytes = self.remat_kept_attn_bytes_per_call
+        n_attn = min(
+            free // attn_bytes, self.ut_steps * sum(self._gqa_layers)
+        ) if attn_bytes else 0
+        return n_mlp, n_attn
 
     def _mla_qkv(self, p, xn, pos):
         """Latent attention's projections, ``xn [B, T, D]`` -> ``q, k,
@@ -1252,6 +1316,11 @@ class Llama(TMModel):
             if self.sandwich_norm:
                 a = rms_norm(a, p["attn_out_norm"], eps)
             x = x + self._branch(a)
+            if self.attention != "mla":
+                # the FFN half's input, named beside q, k and v
+                # (``_gqa_qkv``): a call that keeps them replays
+                # neither ``wo`` nor the three projections
+                x = checkpoint_name(x, ATTN_RESIDUALS[3])
 
         if "router" not in p:
             return self._dense_ffn(p, x)
@@ -1379,7 +1448,8 @@ class Llama(TMModel):
         statistic is taken over that layout (``rms_norm(.., axes=(1,
         3))``), the
         rotation by the kind's table is one pass over the whole row
-        (``rope``)."""
+        (``rope``).  q, k and v carry the first three names of
+        ``ATTN_RESIDUALS``."""
         eps = self.norm_eps
         h_loc = self.n_heads // self.tp
         hkv_loc = self.n_kv_heads // self.tp
@@ -1387,8 +1457,19 @@ class Llama(TMModel):
         with jax.named_scope("gqa_proj"):
             q = tp_lib.col_parallel_heads(xn, p["wq"], h_loc)
             k = tp_lib.col_parallel_heads(xn, p["wk"], hkv_loc)
-            v = tp_lib.col_parallel_heads(xn, p["wv"], hkv_loc)
+            v = checkpoint_name(
+                tp_lib.col_parallel_heads(xn, p["wv"], hkv_loc),
+                ATTN_RESIDUALS[2],
+            )
+            # q and k are named for the layer's remat (``_forward``)
+            # where the backward reads them: the products' outputs
+            # under QK-norm (its backward reads those; the norm and
+            # the rotation, two elementwise passes, are replayed),
+            # else what the kernels take, after the rotation; k and v
+            # always before the repeat
             if self.qk_norm:
+                q = checkpoint_name(q, ATTN_RESIDUALS[0])
+                k = checkpoint_name(k, ATTN_RESIDUALS[1])
                 q = rms_norm(q, p["q_norm"].reshape(h_loc, 1, hd), eps,
                              self.n_heads * hd, axes=(1, 3))
                 k = rms_norm(k, p["k_norm"].reshape(hkv_loc, 1, hd), eps,
@@ -1397,6 +1478,9 @@ class Llama(TMModel):
                 inv_freq, factor = self._rope_tables[kind]
                 q = rope(q, pos, self.rope_theta, inv_freq, factor)
                 k = rope(k, pos, self.rope_theta, inv_freq, factor)
+            if not self.qk_norm:
+                q = checkpoint_name(q, ATTN_RESIDUALS[0])
+                k = checkpoint_name(k, ATTN_RESIDUALS[1])
             if self.sp == 1 and h_loc != hkv_loc:
                 k = jnp.repeat(k, h_loc // hkv_loc, axis=1)
                 v = jnp.repeat(v, h_loc // hkv_loc, axis=1)
@@ -1457,11 +1541,17 @@ class Llama(TMModel):
             # unnamed) the names never occur.  A dropless expert
             # layer's tile plan (``parallel/moe.py``) is kept the same
             # way: built once a layer call.  The LAST
-            # ``remat_kept_calls`` calls (the first the backward
+            # ``remat_kept_calls`` dense calls (the first the backward
             # reaches, so their copies live shortest) also keep the
-            # dense MLP's gate and up products; the norms, the
-            # projections around the kernel, ``swiglu`` and the down
-            # projection are replayed in every call.
+            # dense MLP's gate and up products, the last
+            # ``remat_kept_attn_calls`` grouped-query attention calls
+            # q, k, v and the attention block's output
+            # (``ATTN_RESIDUALS``).  Replayed in every call: the
+            # norms, ``swiglu`` and the down projection, the K/V
+            # repeat, QK-norm and the rotation after it; in a call
+            # that keeps no more than ``remat_saves``, the
+            # projections around the kernel and the MLP's two
+            # products as well.
             return jax.checkpoint(
                 fn,
                 policy=jax.checkpoint_policies.save_only_these_names(*names),
@@ -1469,24 +1559,22 @@ class Llama(TMModel):
 
         kinds = set(self.attn_kinds)
 
-        def variants(kind):
-            """(the layer call of an attention kind, the call that
-            also keeps the MLP's products)."""
+        @functools.cache
+        def layer_of(kind, extra):
+            """The layer call of an attention kind whose remat keeps
+            the names ``extra`` beside ``remat_saves``: one policy for
+            each ``extra`` that occurs (plain; + MLP; + attention;
+            + both)."""
             # a model of one plain kind calls the method itself
             fn = self._layer if kinds == {"full_attention"} else (
                 functools.partial(self._layer, attn_kind=kind)
             )
-            if not self.remat:
-                return fn, fn
-            plain = remat(fn, *self.remat_saves)
-            if not self.remat_kept_calls:
-                return plain, plain
-            return plain, remat(fn, *self.remat_saves, *MLP_RESIDUALS)
+            return remat(fn, *self.remat_saves, *extra) if (
+                self.remat) else fn
 
-        layers = {kind: variants(kind) for kind in kinds}
         # the last layer's kind: the MTP block's, and the pipeline's
         # (one kind of layer there)
-        layer = layers[self.attn_kinds[-1]][0]
+        layer = layer_of(self.attn_kinds[-1], ())
 
         moe = "moe" in self.layer_kinds
         aux = jnp.zeros((2,), jnp.float32)
@@ -1500,14 +1588,18 @@ class Llama(TMModel):
         )
         ssm = []        # a mamba layer's scan counters, in layer order
         if self.pp == 1:
-            kept = self._kept_calls()
+            kept_mlp, kept_attn = self._kept_calls()
 
             def stack(x, first_call=0):
                 moms = []
                 for call, (p, kind) in enumerate(
                     zip(params["layers"], self.attn_kinds), first_call
                 ):
-                    fn = layers[kind][call in kept]
+                    fn = layer_of(
+                        kind,
+                        MLP_RESIDUALS * (call in kept_mlp)
+                        + ATTN_RESIDUALS * (call in kept_attn),
+                    )
                     if "router" in p:
                         x, mom = fn(p, x, pos, next(bias_rows))
                         moms.append(mom)
@@ -1648,13 +1740,24 @@ class Llama(TMModel):
             logits = tp_lib.col_parallel(x, self._head_weight(params))
         return (logits, aux, routing) if with_aux else logits
 
-    def _kept_calls(self) -> frozenset:
-        """The layer calls whose remat also keeps ``MLP_RESIDUALS``:
-        the last ``remat_kept_calls`` of the calls that ARE dense (an
-        expert call names neither product)."""
-        kinds = self.layer_kinds * self.ut_steps
-        dense = [i for i, kind in enumerate(kinds) if kind == "dense"]
-        return frozenset(dense[len(dense) - self.remat_kept_calls:])
+    def _kept_calls(self) -> tuple[frozenset, frozenset]:
+        """The layer calls whose remat also keeps ``MLP_RESIDUALS``
+        and those whose remat also keeps ``ATTN_RESIDUALS``: the last
+        ``remat_kept_calls`` of the calls that ARE dense (an expert
+        call names neither product) and the last
+        ``remat_kept_attn_calls`` of those that run grouped-query
+        attention (latent attention and a mamba call name none)."""
+        def last(n, names_them):
+            calls = [
+                i for i, ok in enumerate(names_them * self.ut_steps) if ok
+            ]
+            return frozenset(calls[len(calls) - n:])
+
+        return (
+            last(self.remat_kept_calls,
+                 tuple(kind == "dense" for kind in self.layer_kinds)),
+            last(self.remat_kept_attn_calls, self._gqa_layers),
+        )
 
     def _mtp_hidden(self, params, x, next_ids, pos, layer, bias_rows):
         """The MTP module (depth 1) on the stack's output ``x [B, T,
@@ -1987,8 +2090,8 @@ class Llama(TMModel):
         # when the LOCAL vocab is >= 64k; an int pins the chunk
         # count; 0/1 forces the dense head.
         n_xent_chunks = self._n_xent_chunks = self._xent_chunks()
-        self.remat_kept_calls = self.remat_keep_calls(
-            _device_bytes_limit(mesh.devices.flat)
+        self.remat_kept_calls, self.remat_kept_attn_calls = (
+            self.remat_keep_calls(_device_bytes_limit(mesh.devices.flat))
         )
 
         # expert-sharded leaves exchange differently (see step below);
