@@ -1249,3 +1249,97 @@ def test_classifier_step_names_conv_and_batch_norm(chip, monkeypatch):
     named = [e["op_name"] for e in top.values() if e["op_name"]]
     assert all(re.fullmatch(_GLUE, n) for n in named), named
     assert len(top) <= 10, sorted(top)
+
+
+# -- query heads a layer, a gate a head, half a head rotated (PR 50) ---------
+
+# the cell's kind of step at the suite's widths: a dense full layer of
+# 2 heads, a window layer of 4 and an expert full layer of 2 over 2
+# key/value heads of 128, each gated; the full ones rotate 64 channels
+# under YaRN; 2 of 8 softmax-routed experts held, the gates times 2.5,
+# a shared expert
+_GATED = dict(
+    n_layers=3, n_heads=2, n_kv_heads=2, head_dim=128,
+    n_heads_per_layer=[2, 4, 2], attention_gate="per-head",
+    layer_types=["full_attention", "sliding_attention", "full_attention"],
+    sliding_window=128,
+    rope_parameters={
+        "full_attention": {
+            "rope_type": "yarn", "rope_theta": 500000, "factor": 128,
+            "original_max_position_embeddings": 64, "beta_fast": 32,
+            "beta_slow": 1, "partial_rotary_factor": 0.5},
+        "sliding_attention": {"rope_type": "default", "rope_theta": 10000},
+    },
+    n_experts=8, moe_top_k=2, ffn_dim=256, capacity_factor=None,
+    moe_route_scale=2.5, moe_experts_held=2, moe_shared_experts=1,
+    first_k_dense=1, dense_ffn_dim=512, moe_aux_coef=0.001,
+)
+
+
+def test_gated_step_compiles_with_each_kinds_heads_and_the_gates_scope(
+    chip, monkeypatch
+):
+    """Compiled for the v5e: the full layers' flash calls at 2 heads a
+    sequence and the window layer's at 4 (``[B H, T, hd]`` operands),
+    each under its own jit's name; the scope ``attn_gate`` in the
+    forward, the replay and the backward, inside ``blk_attn`` and the
+    kind's scope and outside ``gqa_proj``; every block named."""
+    import re
+
+    from benchmark.layer_metrics import _scopes
+
+    text = _llama_step_text(chip, monkeypatch, **_GATED)
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    full = [ln for ln in calls if "jit(_flash_jit)" in ln]
+    band = [ln for ln in calls if "jit(_flash_window_jit)" in ln]
+    assert len(full) == 2 * 3 and len(band) == 3
+    assert all(re.search(r"bf16\[4,256,128\]", ln) for ln in full), full
+    assert all(re.search(r"bf16\[8,256,128\]", ln) for ln in band), band
+    assert all("attn_full" in ln for ln in full)
+    assert all("attn_sliding" in ln for ln in band)
+    names = _scopes._under(text, "attn_gate")
+    lines = [ln for ln in text.splitlines()
+             if (m := re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", ln))
+             and m.group(1) in names]
+    seen = {"replay" if "rematted_computation" in ln
+            else "bwd" if "transpose(" in ln else "fwd" for ln in lines}
+    assert seen == {"fwd", "replay", "bwd"}
+    gated = [ln for ln in text.splitlines() if "/attn_gate/" in ln]
+    assert gated and all("blk_attn" in ln for ln in gated)
+    assert all("attn_full/attn_gate" in ln or "attn_sliding/attn_gate" in ln
+               for ln in gated)
+    assert not any("gqa_proj" in ln for ln in gated)
+    have, top, kernels, products = _step_blocks(text)
+    for block in ("blk_attn", "blk_ffn"):
+        assert {(block, ph) for ph in ("fwd", "replay", "bwd")} <= have
+    assert "other" not in {e["block"] for e in products.values()}
+
+
+@pytest.mark.parametrize("knobs, spelled", [
+    (dict(), dict(n_heads_per_layer=[2, 2], attention_gate=None)),
+    (dict(n_experts=8, moe_top_k=2, ffn_dim=256, capacity_factor=None),
+     dict(moe_route_scale=1.0, attention_gate=False)),
+    (dict(layer_types=["sliding_attention", "full_attention"],
+          sliding_window=128, head_dim=128,
+          rope_parameters={
+              "full_attention": {"rope_type": "default", "rope_theta": 1e4},
+              "sliding_attention": {"rope_type": "default",
+                                    "rope_theta": 1e4}}),
+     dict(n_heads_per_layer=[2, 2], rope_parameters={
+         "full_attention": {"rope_type": "default", "rope_theta": 1e4,
+                            "partial_rotary_factor": 1},
+         "sliding_attention": {"rope_type": "default", "rope_theta": 1e4,
+                               "partial_rotary_factor": 1.0}})),
+], ids=["dense", "softmax_moe", "kinds"])
+def test_the_new_knobs_at_their_defaults_lower_the_same_step(
+    chip, monkeypatch, knobs, spelled
+):
+    """``n_heads_per_layer`` at ``n_heads``, no gate, a routed scaling
+    factor of 1 and a partial rotary factor of 1 are today's step:
+    the lowered text does not change by a character (the older cells'
+    texts at their real sizes were held to the parent's the same way
+    when the knobs came; PERF.md section 6, PR 50)."""
+    plain = _llama_step_text(chip, monkeypatch, lowered=True, **knobs)
+    same = _llama_step_text(
+        chip, monkeypatch, lowered=True, **dict(knobs, **spelled))
+    assert plain == same

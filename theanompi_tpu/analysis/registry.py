@@ -163,6 +163,11 @@ PROFILE_SCOPES: dict[str, str] = {
     # ``_gqa_qkv``, PR 45); benchmark/layer_metrics/gqa_proj_ms.py
     # reads the label
     "gqa_proj": "gqa_proj",
+    # the sigmoid gate a query head between the attention kernels and
+    # ``wo``, inside ``blk_attn`` and a kind's scope, outside
+    # ``gqa_proj`` (models/llama.py ``_attn_gate``, PR 50);
+    # benchmark/layer_metrics/attn_gate_ms.py reads the label
+    "attn_gate": "attn_gate",
     # a state-space (mamba) layer's mixer under its block and the four
     # scopes inside it (models/llama.py ``_mamba_block``, ops/ssd.py
     # ``mamba_mixer``, PR 47); benchmark/layer_metrics/ssm_block_ms.py,

@@ -234,14 +234,18 @@ _rotate.defvjp(_rotate_fwd, _rotate_bwd)
 
 
 def rope_table(params: dict, head_dim: int):
-    """``(inv_freq float32[head_dim / 2], factor)`` of one entry of a
-    published ``rope_parameters``: ``rope_type`` ``"default"`` is
-    ``f_i = rope_theta ** (-2i / head_dim)`` and factor 1; ``"yarn"``
+    """``(inv_freq float32[r / 2], factor)`` of one entry of a
+    published ``rope_parameters``, over the ``r = head_dim *
+    partial_rotary_factor`` channels of a head that rotate (all of
+    them where the entry names no factor; the table is that of a head
+    of ``r`` channels, and ``rope`` passes the other ``head_dim - r``
+    as they are): ``rope_type`` ``"default"`` is ``f_i = rope_theta **
+    (-2i / r)`` and factor 1; ``"yarn"``
     (Peng et al. 2023, as the Hugging Face rotary utilities compute
     it) blends each pair between ``f_i`` and ``f_i / factor`` by how
     many turns it makes over ``original_max_position_embeddings``::
 
-        d(n) = head_dim ln(original / (2 pi n)) / (2 ln rope_theta)
+        d(n) = r ln(original / (2 pi n)) / (2 ln rope_theta)
         lo, hi = floor(d(beta_fast)), ceil(d(beta_slow))
         r_i = clip((i - lo) / (hi - lo), 0, 1)
         w_i = (f_i / factor) r_i + f_i (1 - r_i)
@@ -250,6 +254,7 @@ def rope_table(params: dict, head_dim: int):
     1`` where none is given).  Static, at every length."""
     kind = params.get("rope_type", "default")
     theta = float(params["rope_theta"])
+    head_dim = int(head_dim * float(params.get("partial_rotary_factor", 1.0)))
     half = head_dim // 2
     f = theta ** (-np.arange(half, dtype=np.float64) * 2 / head_dim)
     if kind == "default":
@@ -372,8 +377,23 @@ class Llama(TMModel):
     ``attention_multiplier`` (the scores' scale in place of ``head_dim
     ** -0.5``) and ``logits_scaling`` (divides the logits) are the
     hybrid decoders' four scalars; ``tie_word_embeddings``: ONE matrix
-    is embedding and head (``_head_weight``).  ``validate: false``:
-    the run holds no validation set.
+    is embedding and head (``_head_weight``).  ``n_heads_per_layer``
+    (the published ``num_attention_heads_per_layer``, its first
+    ``n_layers`` entries): query heads a LAYER over the one
+    ``n_kv_heads`` — the leaves ``wq``, ``wo`` and the gate's follow
+    it, the projections read it off the leaf; ``attention_gate:
+    "per-head"``: a leaf ``w_attn_gate [D, H_l]`` a layer and one
+    sigmoid a token and query head, from the block's normed input, on
+    the kernels' output before ``wo`` (``_attn_gate``, scope
+    ``attn_gate``; its mean a call rides out with the loss,
+    ``obs/gate.py``); an entry of ``rope_parameters`` may give a
+    ``partial_rotary_factor``: that share of a head's channels rotates
+    (``rope_table``, ``rotary_channels``); ``moe_route_scale`` also
+    scales the softmax router's renormalised picks.  These three make
+    the stack one described layer by layer (``attn_per_layer``): they
+    compose with ``tp`` and data parallelism and are refused where
+    ``layer_types`` is.  ``validate: false``: the run holds no
+    validation set.
     """
 
     def __init__(self, config: dict | None = None):
@@ -485,11 +505,30 @@ class Llama(TMModel):
         window = c.get("sliding_window")
         self.sliding_window = None if window is None else int(window)
         self.rope_parameters = c.get("rope_parameters")
+        # query heads a LAYER (the published
+        # ``num_attention_heads_per_layer``, its first ``n_layers``
+        # entries, as ``layer_types``) over the one ``n_kv_heads``;
+        # None: ``n_heads`` in every layer
+        per_layer = c.get("n_heads_per_layer")
+        self.heads_per_layer = (
+            (self.n_heads,) * self.n_layers if per_layer is None
+            else tuple(int(h) for h in per_layer[:self.n_layers])
+        )
+        # a sigmoid gate a query head on the kernels' output, before
+        # ``wo`` (``_attn_gate``; the published ``gating: "per-head"``)
+        gate = c.get("attention_gate")
+        if gate not in (None, False, "per-head", "per_head"):
+            raise ValueError(
+                f"attention_gate {gate!r}: the gate here is 'per-head' "
+                f"(one sigmoid a query head) or none"
+            )
+        self.attention_gate = bool(gate)
         # the stack's attention is described layer by layer (as the
         # published decoders with two kinds of layer do), not by
         # ``rope_theta`` and the one causal call alone
         self.attn_per_layer = not (
             types is None and window is None and self.rope_parameters is None
+            and per_layer is None and not self.attention_gate
         )
         unknown = set(self.attn_kinds) - {"full_attention",
                                           "sliding_attention", "mamba"}
@@ -547,6 +586,12 @@ class Llama(TMModel):
                 else rope_table(self.rope_parameters[kind], self.head_dim)
             )
             for kind in set(self.attn_kinds) - {"mamba"}
+        }
+        # the run summary's ``"rotary_channels"``: how many of a head's
+        # channels a kind rotates (its entry's ``partial_rotary_factor``)
+        self.rotary_channels = {
+            kind: self.head_dim if inv_freq is None else 2 * len(inv_freq)
+            for kind, (inv_freq, _) in sorted(self._rope_tables.items())
         }
         # multi-token prediction: ``mtp_depth`` (0 or 1) more blocks
         # of the last layer's kind after the stack, which predict the
@@ -609,6 +654,14 @@ class Llama(TMModel):
         )
         assert self.n_heads % self.tp == 0, "n_heads must divide by tp"
         assert self.n_kv_heads % self.tp == 0, "n_kv_heads must divide by tp"
+        # (a multiple of n_kv_heads divides by tp as n_kv_heads does)
+        assert len(self.heads_per_layer) == self.n_layers and all(
+            h % self.n_kv_heads == 0 for h in self.heads_per_layer
+        ), (
+            f"n_heads_per_layer names each of the {self.n_layers} layers "
+            f"a multiple of n_kv_heads {self.n_kv_heads}; got "
+            f"{self.heads_per_layer}"
+        )
         assert self.vocab % self.tp == 0, "vocab must divide by tp"
         assert self.ffn_dim % self.tp == 0, "ffn_dim must divide by tp"
         assert self.dense_ffn_dim % self.tp == 0, (
@@ -652,8 +705,9 @@ class Llama(TMModel):
                 ("first_k_dense", len(set(self.layer_kinds)) > 1),
                 ("mtp_depth", self.mtp_depth),
                 ("a selection bias", self.moe_select_bias),
-                ("layer_types (an attention kind, a window or a rotary "
-                 "table per layer)", self.attn_per_layer),
+                ("layer_types (an attention kind, a window, a rotary "
+                 "table or a head count per layer, an attention gate)",
+                 self.attn_per_layer),
                 ("a multiplier (embedding_multiplier, residual_multiplier, "
                  "attention_multiplier, logits_scaling)",
                  (self.embedding_multiplier, self.residual_multiplier,
@@ -696,9 +750,11 @@ class Llama(TMModel):
         if self.attn_per_layer and self.attention == "mla":
             raise NotImplementedError(
                 "attention: mla does not yet compose with layer_types, "
-                "sliding_window or rope_parameters: latent attention's "
-                "projections (_mla_qkv) rotate by the one rope_theta "
-                "and call the kernels without a window"
+                "sliding_window, rope_parameters, n_heads_per_layer or "
+                "attention_gate: latent attention's projections "
+                "(_mla_qkv) rotate by the one rope_theta, hold n_heads "
+                "in every layer and call the kernels without a window "
+                "or a gate"
             )
         if mixed and (self.pp > 1 or self.sp > 1 or self.ut_steps > 1):
             # pp stacks the layers' leaves on one leading dimension
@@ -814,6 +870,9 @@ class Llama(TMModel):
                 "wv": P(None, MODEL_AXIS),
                 "wo": P(MODEL_AXIS, None),
             })
+        if self.attention_gate and mixer != "mamba":
+            # a column a query head: sharded as wq's heads
+            layer["w_attn_gate"] = P(None, MODEL_AXIS)
         if self.qk_norm and mixer != "mamba":
             # over the whole projected width: sharded as its columns
             layer.update({"q_norm": P(MODEL_AXIS), "k_norm": P(MODEL_AXIS)})
@@ -860,7 +919,7 @@ class Llama(TMModel):
             jax.random.fold_in(key, 1), 8 * (self.n_layers + 1) + 1
         ))
 
-        def attention():
+        def attention(h, keys):
             if self.attention == "mla":
                 hq = self.n_heads * self.head_dim
                 hkv = self.n_heads * (
@@ -880,10 +939,12 @@ class Llama(TMModel):
                     ),
                 }
             return {
-                "wq": dense(next(keys), (d, self.n_heads * hd)),
+                "wq": dense(next(keys), (d, h * hd)),
                 "wk": dense(next(keys), (d, self.n_kv_heads * hd)),
                 "wv": dense(next(keys), (d, self.n_kv_heads * hd)),
-                "wo": dense(next(keys), (self.n_heads * hd, d)),
+                "wo": dense(next(keys), (h * hd, d)),
+                **({"w_attn_gate": dense(next(more), (d, h))}
+                   if self.attention_gate else {}),
             }
 
         def mamba(i):
@@ -895,16 +956,17 @@ class Llama(TMModel):
             )
 
         def one_layer(kind, keys, mixer="attention", i=0):
+            h = self.heads_per_layer[i]     # (the MTP block: the last's)
             lp = {
                 "attn_norm": jnp.ones((d,)),
-                **(mamba(i) if mixer == "mamba" else attention()),
+                **(mamba(i) if mixer == "mamba" else attention(h, keys)),
                 "mlp_norm": jnp.ones((d,)),
             }
             if self.attention == "mla" or mixer == "mamba":
                 for _ in range(4):
                     next(keys)  # keep key budget aligned (9 per layer)
             if self.qk_norm and mixer != "mamba":
-                lp["q_norm"] = jnp.ones((self.n_heads * hd,))
+                lp["q_norm"] = jnp.ones((h * hd,))
                 lp["k_norm"] = jnp.ones((self.n_kv_heads * hd,))
             if self.sandwich_norm:
                 lp["attn_out_norm"] = jnp.ones((d,))
@@ -978,6 +1040,7 @@ class Llama(TMModel):
                 "block": one_layer(
                     self.layer_kinds[-1],
                     iter(jax.random.split(jax.random.fold_in(key, 2), 9)),
+                    i=-1,
                 ),
                 "head_norm": jnp.ones((d,)),
             }
@@ -1105,30 +1168,47 @@ class Llama(TMModel):
             for mixer in self.mixer_kinds
         )
 
-    @property
-    def remat_kept_attn_bytes_per_call(self) -> int:
-        """Bytes of ``ATTN_RESIDUALS`` one grouped-query attention
-        call keeps on a device, in compute dtype: q ``[B_loc, H_loc,
-        T_loc, hd]``, k and v ``[B_loc, Hkv_loc, T_loc, hd]`` (before
-        the repeat) and the block's output ``[B_loc, T_loc, D]``; 0
-        for a model without such a call (latent attention, mamba
-        layers alone), which names none of them."""
-        if not any(self._gqa_layers):
+    def remat_kept_attn_bytes_of(self, layer: int) -> int:
+        """Bytes of ``ATTN_RESIDUALS`` the grouped-query attention
+        call of ``layer`` keeps on a device, in compute dtype: q
+        ``[B_loc, H_loc, T_loc, hd]`` at THAT layer's heads
+        (``heads_per_layer``), k and v ``[B_loc, Hkv_loc, T_loc, hd]``
+        (before the repeat) and the block's output ``[B_loc, T_loc,
+        D]``; 0 for a layer without such a call (latent attention, a
+        mamba layer), which names none of them."""
+        if not self._gqa_layers[layer]:
             return 0
-        heads = (self.n_heads + 2 * self.n_kv_heads) // self.tp
+        heads = (self.heads_per_layer[layer] + 2 * self.n_kv_heads) // self.tp
         return (
             self._local_tokens * (heads * self.head_dim + self.dim)
             * self.compute_dtype.itemsize
         )
 
     @property
+    def _gqa_call_bytes(self) -> list[int]:
+        """``remat_kept_attn_bytes_of`` every grouped-query attention
+        call of a step, in call order (a looped stack's passes one
+        after the other)."""
+        return [
+            self.remat_kept_attn_bytes_of(i)
+            for i, gqa in enumerate(self._gqa_layers) if gqa
+        ] * self.ut_steps
+
+    @property
+    def remat_kept_attn_bytes_per_call(self) -> int:
+        """The most a grouped-query attention call keeps (every
+        call's, where the layers have one head count); 0 for a model
+        without such a call."""
+        return max(self._gqa_call_bytes, default=0)
+
+    @property
     def remat_kept_bytes(self) -> int:
         """Bytes of ``MLP_RESIDUALS`` and ``ATTN_RESIDUALS`` the kept
         calls hold on a device."""
+        calls = self._gqa_call_bytes
         return (
             self.remat_kept_calls * self.remat_kept_bytes_per_call
-            + self.remat_kept_attn_calls
-            * self.remat_kept_attn_bytes_per_call
+            + sum(calls[len(calls) - self.remat_kept_attn_calls:])
         )
 
     def _local_params(self, axis_sizes) -> tuple[int, int]:
@@ -1174,12 +1254,20 @@ class Llama(TMModel):
             {MODEL_AXIS: self.tp, PIPE_AXIS: self.pp, EXPERT_AXIS: self.ep}
         )
         opt_copies = {"adam": 2, "sgd": 0}.get(self.opt_name, 1)
-        h_loc = self.n_heads // self.tp
         # every call keeps its input; an attention call the flash
-        # kernel's two outputs beside it, a mamba call nothing more
+        # kernel's two outputs beside it (at its layer's heads), a
+        # mamba call nothing more
         kept_input = n_tok * self.dim * isz
+        stack_heads = sum(
+            h for h, mixer in zip(self.heads_per_layer, self.mixer_kinds)
+            if mixer == "attention"
+        ) * self.ut_steps // self.pp
+        kept_heads = (
+            stack_heads + self.mtp_depth * self.heads_per_layer[-1]
+        ) // self.tp
         kept_flash = (
-            n_tok * h_loc * self.head_dim * isz + batch * h_loc * t_loc * 4
+            n_tok * kept_heads * self.head_dim * isz
+            + batch * kept_heads * t_loc * 4
         )
         head = 2 * n_tok * (
             self.vocab // self.tp // self._xent_chunks()
@@ -1188,10 +1276,9 @@ class Llama(TMModel):
         if exits > 1:
             head += 2 * exits * n_tok * self.dim * isz
         calls = self.n_layers * self.ut_steps // self.pp + self.mtp_depth
-        attn_calls = calls - self.mixer_kinds.count("mamba")
         return (
             param_bytes * (2 + opt_copies) + calls * kept_input
-            + attn_calls * kept_flash + head
+            + kept_flash + head
         )
 
     def remat_keep_calls(self, bytes_limit: int | None) -> tuple[int, int]:
@@ -1218,10 +1305,14 @@ class Llama(TMModel):
             self.ut_steps * self.layer_kinds.count("dense"),
         ) if mlp_bytes else 0
         free -= n_mlp * mlp_bytes
-        attn_bytes = self.remat_kept_attn_bytes_per_call
-        n_attn = min(
-            free // attn_bytes, self.ut_steps * sum(self._gqa_layers)
-        ) if attn_bytes else 0
+        # the LAST calls first (the first the backward reaches), each
+        # at its own layer's heads, while they fit
+        n_attn = 0
+        for attn_bytes in reversed(self._gqa_call_bytes):
+            if attn_bytes > free:
+                break
+            free -= attn_bytes
+            n_attn += 1
         return n_mlp, n_attn
 
     def _mla_qkv(self, p, xn, pos):
@@ -1286,7 +1377,9 @@ class Llama(TMModel):
         ``attn_kind`` (static): the layer's entry of ``attn_kinds``;
         ``"mamba"`` runs the state-space mixer in attention's place
         (``_mamba_block``) and returns ``(x, stats)``, the scan's two
-        counters.
+        counters.  A gated attention call (``attention_gate``) gives
+        its gate's counter last (``_attn_gate``): ``(x, open)``, ``(x,
+        mom, open)``.
 
         With MoE enabled returns ``(x, mom)`` where ``mom`` is the
         fp32 [2E+2] vector of this layer's aux-loss MOMENTS
@@ -1303,6 +1396,7 @@ class Llama(TMModel):
         if attn_kind == "mamba":
             x, stats = self._mamba_block(p, x)
             return self._dense_ffn(p, x), stats
+        gate_open = None
         with jax.named_scope("blk_attn"):
             xn = rms_norm(x, p["attn_norm"], eps)
             if self.attention == "mla":
@@ -1312,6 +1406,9 @@ class Llama(TMModel):
                 )
             else:
                 o = self._gqa(p, xn, pos, attn_kind)
+                if self.attention_gate:
+                    with self._kind_scope(attn_kind):
+                        o, gate_open = self._attn_gate(p, xn, o)
             a = tp_lib.row_parallel(_unheads(o), p["wo"]).astype(cdtype)
             if self.sandwich_norm:
                 a = rms_norm(a, p["attn_out_norm"], eps)
@@ -1323,7 +1420,8 @@ class Llama(TMModel):
                 x = checkpoint_name(x, ATTN_RESIDUALS[3])
 
         if "router" not in p:
-            return self._dense_ffn(p, x)
+            x = self._dense_ffn(p, x)
+            return x if gate_open is None else (x, gate_open)
         with jax.named_scope("blk_ffn"):
             xn = rms_norm(x, p["mlp_norm"], eps)
             y, aux = moe_ffn(
@@ -1354,7 +1452,8 @@ class Llama(TMModel):
                 ).astype(cdtype)
             if self.sandwich_norm:
                 y = rms_norm(y, p["mlp_out_norm"], eps)
-            return x + self._branch(y), mom
+            out = x + self._branch(y), mom
+            return out if gate_open is None else (*out, gate_open)
 
     def _branch(self, y):
         """A block's branch as it enters the residual sum: times
@@ -1409,14 +1508,17 @@ class Llama(TMModel):
         runs each kind under a scope of its own, ``attn_full`` /
         ``attn_sliding``."""
         if self.attn_per_layer:
-            scope = (
-                jax.named_scope("attn_sliding")
-                if kind == "sliding_attention"
-                else jax.named_scope("attn_full")
-            )
-            with scope:
+            with self._kind_scope(kind):
                 return self._gqa_kind(p, xn, pos, kind)
         return self._gqa_kind(p, xn, pos, kind)
+
+    @staticmethod
+    def _kind_scope(kind):
+        """The scope of an attention kind's layers in a model
+        described layer by layer."""
+        if kind == "sliding_attention":
+            return jax.named_scope("attn_sliding")
+        return jax.named_scope("attn_full")
 
     def _gqa_kind(self, p, xn, pos, kind):
         q, k, v = self._gqa_qkv(p, xn, pos, kind)
@@ -1436,6 +1538,26 @@ class Llama(TMModel):
         rep = self.n_heads // self.n_kv_heads
         return attn(q, k, v, SEQ_AXIS, causal=True, kv_rep=rep)
 
+    def _attn_gate(self, p, xn, o):
+        """The attention gate (``attention_gate``; the published
+        ``gating: "per-head"``, the headwise form of Qiu et al.,
+        arXiv:2505.06708) on the kernels' output ``o [B, H_loc, T,
+        hd]``, under the scope ``attn_gate``: ``g = sigmoid(xn
+        w_attn_gate)``, one number a token and query head from the
+        block's normed input, the product accumulated and the sigmoid
+        taken in float32; ``o`` times ``g`` over a head's channels,
+        before ``wo``.  Returns ``(o gated, the gate's counter)``: the
+        mean of ``g`` over this device's tokens and heads, no gradient
+        (``obs/gate.py``; 0.5 at a seed's weights — a mean at 0 is a
+        block switched off, at 1 a gate that gates nothing)."""
+        with jax.named_scope("attn_gate"):
+            g = jax.nn.sigmoid(jnp.einsum(
+                "btd,dh->bht", xn, p["w_attn_gate"].astype(xn.dtype),
+                preferred_element_type=jnp.float32,
+            ))
+            o = (o.astype(jnp.float32) * g[..., None]).astype(o.dtype)
+            return o, lax.stop_gradient(jnp.mean(g))
+
     def _gqa_qkv(self, p, xn, pos, kind):
         """Grouped-query attention's projections, ``xn [B, T, D]`` ->
         ``q [B, H_loc, T, hd]``, ``k`` and ``v`` for the attention
@@ -1451,9 +1573,10 @@ class Llama(TMModel):
         (``rope``).  q, k and v carry the first three names of
         ``ATTN_RESIDUALS``."""
         eps = self.norm_eps
-        h_loc = self.n_heads // self.tp
-        hkv_loc = self.n_kv_heads // self.tp
         hd = self.head_dim
+        # the LAYER's query heads here: its leaf's local columns
+        h_loc = p["wq"].shape[1] // hd
+        hkv_loc = self.n_kv_heads // self.tp
         with jax.named_scope("gqa_proj"):
             q = tp_lib.col_parallel_heads(xn, p["wq"], h_loc)
             k = tp_lib.col_parallel_heads(xn, p["wk"], hkv_loc)
@@ -1471,13 +1594,17 @@ class Llama(TMModel):
                 q = checkpoint_name(q, ATTN_RESIDUALS[0])
                 k = checkpoint_name(k, ATTN_RESIDUALS[1])
                 q = rms_norm(q, p["q_norm"].reshape(h_loc, 1, hd), eps,
-                             self.n_heads * hd, axes=(1, 3))
+                             h_loc * self.tp * hd, axes=(1, 3))
                 k = rms_norm(k, p["k_norm"].reshape(hkv_loc, 1, hd), eps,
                              self.n_kv_heads * hd, axes=(1, 3))
             if self.position_embedding_type == "rope":
                 inv_freq, factor = self._rope_tables[kind]
-                q = rope(q, pos, self.rope_theta, inv_freq, factor)
-                k = rope(k, pos, self.rope_theta, inv_freq, factor)
+                # a table narrower than the head (a kind's
+                # ``partial_rotary_factor``): the channels before it
+                # pass as they are
+                nope = hd - self.rotary_channels[kind]
+                q = rope(q, pos, self.rope_theta, inv_freq, factor, nope)
+                k = rope(k, pos, self.rope_theta, inv_freq, factor, nope)
             if not self.qk_norm:
                 q = checkpoint_name(q, ATTN_RESIDUALS[0])
                 k = checkpoint_name(k, ATTN_RESIDUALS[1])
@@ -1495,7 +1622,8 @@ class Llama(TMModel):
         return params["lm_head"]
 
     def _forward(self, params, ids, head=True, with_aux=False,
-                 net_state=None, mtp_ids=None, with_ssm=False):
+                 net_state=None, mtp_ids=None, with_ssm=False,
+                 with_gate=False):
         """ids [B_loc, T_loc] -> local vocab-shard logits [.., V/tp].
 
         ``net_state``: the step's state beside the parameters
@@ -1519,7 +1647,10 @@ class Llama(TMModel):
         (zeros when the model is dense), and the routing counters
         ``[L, E+1]`` of ``_routing_counters`` (None when dense).
         ``with_ssm=True`` (a stack with mamba layers): the scans'
-        counters ``[L_mamba, 2]`` come back last (``obs/ssm.py``)."""
+        counters ``[L_mamba, 2]`` come back last (``obs/ssm.py``).
+        ``with_gate=True`` (a model with an attention gate): what the
+        other arguments ask for comes back FIRST of a pair, the gated
+        calls' counters ``[calls]`` second (``obs/gate.py``)."""
         cdtype = self.compute_dtype
         t_loc = ids.shape[1]
         seq_idx = lax.axis_index(SEQ_AXIS)
@@ -1587,6 +1718,17 @@ class Llama(TMModel):
             else itertools.repeat(None)
         )
         ssm = []        # a mamba layer's scan counters, in layer order
+        gates = []      # a gated attention call's counter, in call order
+
+        def take_gate(res):
+            """A layer call's results less its gate's counter, which a
+            gated attention call gives last (``_layer``)."""
+            if not self.attention_gate:
+                return res
+            *res, gate_open = res
+            gates.append(gate_open)
+            return res[0] if len(res) == 1 else res
+
         if self.pp == 1:
             kept_mlp, kept_attn = self._kept_calls()
 
@@ -1601,20 +1743,23 @@ class Llama(TMModel):
                         + ATTN_RESIDUALS * (call in kept_attn),
                     )
                     if "router" in p:
-                        x, mom = fn(p, x, pos, next(bias_rows))
+                        x, mom = take_gate(fn(p, x, pos, next(bias_rows)))
                         moms.append(mom)
                     elif kind == "mamba":
                         x, stats = fn(p, x, pos)
                         ssm.append(stats)
                     else:
-                        x = fn(p, x, pos)
+                        x = take_gate(fn(p, x, pos))
                 return x, (jnp.stack(moms) if moms else None)
 
             if self.ut_steps == 1:
                 x, moms = stack(x)
                 if mtp_ids is not None:
                     mtp_x, mom = self._mtp_hidden(
-                        params, x, mtp_ids, pos, layer, bias_rows
+                        params, x, mtp_ids, pos,
+                        layer if not self.attention_gate
+                        else lambda *args: take_gate(layer(*args)),
+                        bias_rows,
                     )
                     if mom is not None:
                         moms = jnp.concatenate([moms, mom[None]])
@@ -1728,8 +1873,10 @@ class Llama(TMModel):
             # last of them is ``x``): the loss reads them all
             h = x if exits is None else exits
             if with_ssm:
-                return h, jnp.stack(ssm)
-            return (h, aux, routing) if with_aux else h
+                out = h, jnp.stack(ssm)
+            else:
+                out = (h, aux, routing) if with_aux else h
+            return (out, jnp.stack(gates)) if with_gate else out
         # logits stay in compute dtype: the xent/metric reductions
         # upcast to fp32 INSIDE their fused reads (tp.py), so an
         # .astype(f32) here would only materialize a second, 2x-wide
@@ -2117,6 +2264,7 @@ class Llama(TMModel):
         )
         picks = self.data.global_batch * self.seq_len * self.moe_top_k
         has_ssm = self.has_mamba
+        has_gate = self.attention_gate
 
         def step(params, opt_state, ef, x, y, lr, *state):
             # Pre-cast params to DP-VARYING before autodiff: if they
@@ -2162,17 +2310,21 @@ class Llama(TMModel):
                 more = dict(net_state=state[0]) if state else {}
                 if self.mtp_depth:
                     more["mtp_ids"] = y
+                out = self._forward(
+                    p, x, head=False, with_aux=bool(self.n_experts),
+                    with_ssm=has_ssm, with_gate=has_gate, **more
+                )
+                gate_open = ()
+                if has_gate:    # the gated calls' counters ride out last
+                    out, gate = out
+                    gate_open = (gate,)
                 if self.n_experts:
-                    h, aux, routing = self._forward(
-                        p, x, head=False, with_aux=True, **more
-                    )
+                    h, aux, routing = out
                     counters = (routing,)
                 elif has_ssm:
-                    h, ssm_stats = self._forward(
-                        p, x, head=False, with_ssm=True, **more
-                    )
+                    h, ssm_stats = out
                 else:
-                    h = self._forward(p, x, head=False, **more)
+                    h = out
                 # [N, D] rows; a looped decoder's R exits [R, N, D]
                 h2 = h.reshape(*h.shape[:-3], -1, h.shape[-1])
                 yf = yv.reshape(-1)
@@ -2210,6 +2362,7 @@ class Llama(TMModel):
                     err = lax.pmean(self._pp_value(err), SEQ_AXIS)
                 if has_ssm:
                     counters += (ssm_stats,)
+                counters += gate_open
                 if self.n_experts:
                     # MoE aux losses (layer-averaged in _forward,
                     # already globally token-averaged inside moe_ffn):
@@ -2241,12 +2394,18 @@ class Llama(TMModel):
                 counters[-1] = lax.pmean(counters[-1], dp_axes)
             if has_ssm:
                 # [L_mamba, 2]: the extreme over the replicas'
-                # sequences, the mean of their states' sizes
-                every = (*dp_axes, SEQ_AXIS)
-                counters[-1] = jnp.stack([
-                    lax.pmin(counters[-1][:, 0], every),
-                    lax.pmean(counters[-1][:, 1], every),
+                # sequences, the mean of their states' sizes (a gate's
+                # counters come after them)
+                every, at = (*dp_axes, SEQ_AXIS), -1 - has_gate
+                counters[at] = jnp.stack([
+                    lax.pmin(counters[at][:, 0], every),
+                    lax.pmean(counters[at][:, 1], every),
                 ], axis=1)
+            if has_gate:
+                # [calls]: the mean over every replica's tokens and
+                # every shard's heads
+                counters[-1] = lax.pmean(
+                    counters[-1], (*dp_axes, SEQ_AXIS, MODEL_AXIS))
             if state:
                 # after the optimizer, outside it and the exchange:
                 # each router's selection bias a step toward balance,
@@ -2271,10 +2430,11 @@ class Llama(TMModel):
         is_tpu = mesh.devices.flat[0].platform == "tpu"
         # a MoE step also gives out its routing counters [L, E+1]
         # (and each selection bias's largest size [L], where it has
-        # them), a looped decoder's its exit counters [2R + 1]
+        # them), a looped decoder's its exit counters [2R + 1], a
+        # mamba stack's its scans' [L_mamba, 2], a gated one's [calls]
         counter_out = self._counter_out_specs = (P(),) * (
             bool(self.n_experts) + len(state_specs) + (self.ut_steps > 1)
-            + has_ssm
+            + has_ssm + has_gate
         )
         self._compiler_options = xla_compiler_options(
             self.config,
@@ -2500,7 +2660,8 @@ class Llama(TMModel):
         """Hand a step's counters (device values; read with the loss
         at the recorder's next fence) to the recorder: a MoE's
         routing (with its selection biases' sizes), then a looped
-        decoder's exits."""
+        decoder's exits, a mamba stack's scans, an attention gate's
+        means."""
         counters = list(counters)
         if self.n_experts:
             recorder.moe_routing(
@@ -2516,6 +2677,8 @@ class Llama(TMModel):
             recorder.ut_exits(counters.pop(0))
         if self.has_mamba:
             recorder.ssm_scan(counters.pop(0))
+        if self.attention_gate:
+            recorder.attn_gate(counters.pop(0))
 
     def train_chunk(self, count: int, k: int, recorder: Recorder) -> None:
         if k == self._scan_k and self._train_scan is not None:

@@ -4,8 +4,9 @@ tracks, critical-path attribution), the step-phase profiler
 (``profiler.py``), Prometheus-style metrics text (``metrics.py``),
 the process's compile counter (``compile_meter.py``), a training
 run's set-up phases (``setup.py``), a MoE step's routing counters
-(``routing.py``), a looped decoder's exit counters (``exits.py``) and
-a mamba stack's scan counters (``ssm.py``).
+(``routing.py``), a looped decoder's exit counters (``exits.py``), a
+mamba stack's scan counters (``ssm.py``) and an attention gate's
+counters (``gate.py``).
 See docs/OBSERVABILITY.md; what reads these on the chip is under
 ``benchmark/`` (PERF.md section 3)."""
 
@@ -30,6 +31,7 @@ from theanompi_tpu.obs.setup import (  # noqa: F401
 from theanompi_tpu.obs.routing import last_moe_counters  # noqa: F401
 from theanompi_tpu.obs.exits import last_ut_counters  # noqa: F401
 from theanompi_tpu.obs.ssm import last_ssm_counters  # noqa: F401
+from theanompi_tpu.obs.gate import last_gate_counters  # noqa: F401
 from theanompi_tpu.obs.export import (  # noqa: F401
     chrome_trace,
     critical_path,
@@ -63,6 +65,7 @@ __all__ = [
     "format_critical_path",
     "format_profile",
     "gap_attribution",
+    "last_gate_counters",
     "last_moe_counters",
     "last_process_phases",
     "last_setup_phases",

@@ -119,7 +119,8 @@ def router_topk(x2, w_router, top_k: int, renormalize: bool = True, *,
     """fp32 router: returns (gates [N,k], expert ids [N,k], probs
     [N,E], logits [N,E]).  ``x2`` is [N, D].
 
-    ``scoring="softmax"``: the top-k of a softmax over the experts.
+    ``scoring="softmax"``: the top-k of a softmax over the experts,
+    renormalised, times ``scale`` (a routed scaling factor).
     ``scoring="sigmoid"``: every expert's score is its own sigmoid;
     the picks are the top-k of ``score + select_bias`` (``[E]``, read
     without a gradient: it is state a rule moves, see
@@ -144,6 +145,8 @@ def router_topk(x2, w_router, top_k: int, renormalize: bool = True, *,
     gates, eidx = lax.top_k(probs, top_k)          # [N, k]
     if renormalize:
         gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    if scale != 1.0:
+        gates = gates * scale
     return gates, eidx, probs, logits
 
 

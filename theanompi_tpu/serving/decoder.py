@@ -172,6 +172,19 @@ class LlamaDecoder:
                 f"multipliers (given: {multipliers}) — not yet "
                 "servable"
             )
+        if (model.attention_gate or len(set(model.heads_per_layer)) > 1
+                or set(model.rotary_channels.values()) != {model.head_dim}):
+            raise NotImplementedError(
+                "serving has one head count, no gate and whole-head "
+                "rotation: query heads a layer (heads_per_layer="
+                f"{model.heads_per_layer}) need a q projection, a GQA "
+                "repeat and a paged-kernel call shaped a layer; an "
+                f"attention gate (attention_gate={model.attention_gate}) "
+                "a product and a sigmoid a head between the kernel and "
+                "wo in prefill and decode; a partial rotary factor "
+                f"(rotary_channels={model.rotary_channels}) a table "
+                "narrower than the head in rope_at — not yet servable"
+            )
         if getattr(model, "attn_per_layer", False):
             raise NotImplementedError(
                 "serving has one cache lifetime and one rotary table: "

@@ -150,6 +150,9 @@ class Recorder:
         # a mamba stack's scan counters (obs/ssm.py), likewise
         self._pending_ssm = None
         self.ssm_counters: dict | None = None
+        # an attention gate's counters (obs/gate.py), likewise
+        self._pending_gate = None
+        self.attn_gate_counters: dict | None = None
         self.n_iter = 0
         self._last_print = 0
         # resilience bookkeeping (utils/supervisor.py): one entry per
@@ -302,8 +305,22 @@ class Recorder:
         _start_host_copy(stats)
         self._pending_ssm = stats
 
+    def attn_gate(self, means) -> None:
+        """An attention gate's counters ``[(K,) calls]`` of a step (or
+        K-step chunk), taken and read as :meth:`moe_routing` does;
+        only the newest step's are kept."""
+        _start_host_copy(means)
+        self._pending_gate = means
+
     def flush(self) -> None:
         """Materialize pending device values (this is the fence)."""
+        if self._pending_gate is not None:
+            from theanompi_tpu.obs.gate import gate_counters
+
+            a = np.asarray(self._pending_gate, np.float64)
+            self.attn_gate_counters = gate_counters(
+                a[-1] if a.ndim == 2 else a)
+            self._pending_gate = None
         if self._pending_ssm is not None:
             from theanompi_tpu.obs.ssm import ssm_counters
 

@@ -623,6 +623,12 @@ def run(
         # state-space scan's chunk (None without a mamba layer)
         "mixer_kinds": getattr(model, "mixer_kinds_count", None),
         "ssd_chunk": getattr(model, "ssd_chunk", None),
+        # query heads a layer, whether a sigmoid gate a head multiplies
+        # the attention kernels' output, and how many of a head's
+        # channels each attention kind rotates
+        "heads_per_layer": getattr(model, "heads_per_layer", None),
+        "attention_gate": getattr(model, "attention_gate", False),
+        "rotary_channels": getattr(model, "rotary_channels", None),
         # the tiles the scan's kernels took ({} where XLA's form runs)
         "ssd_kernel": getattr(model, "ssd_kernel", dict)(),
         "exchange_bucket_mb": exchange.bucket_mb,
@@ -662,6 +668,8 @@ def run(
         "ut_counters": recorder.ut_counters,
         # scan counters of a mamba stack's last fenced step
         "ssm_counters": recorder.ssm_counters,
+        # gate counters of a gated model's last fenced step
+        "attn_gate_counters": recorder.attn_gate_counters,
         "step_profile": step_prof,
         "loader": loader_stats,
         "recorder": recorder,
