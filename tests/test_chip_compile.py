@@ -133,7 +133,8 @@ def test_paged_attend_compiles_for_v5e(chip, dtype, hkv, rep, hd, nq):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("direction", ["forward", "backward", "layer_remat"])
+@pytest.mark.parametrize(
+    "direction", ["forward", "backward", "layer_remat", "layer_remat_kept"])
 def test_dropless_expert_layer_compiles_to_grouped_kernels(
     chip, monkeypatch, direction
 ):
@@ -142,7 +143,10 @@ def test_dropless_expert_layer_compiles_to_grouped_kernels(
     grouped product of ``moe_ffn``'s dropless path is a Mosaic kernel
     of the repo's own (``ops/grouped_matmul.py``) whose work follows
     the rows — three forward, nine with the backward, eleven under the
-    layer's remat — found by ``ragged-dot`` in its line, as the
+    layer's remat and nine again under a remat that keeps
+    ``MOE_RESIDUALS`` (the replay then holds neither the gate nor the
+    up product, and no gather of the ``k N`` sorted rows) — found by
+    ``ragged-dot`` in its line, as the
     benchmark's readers find it; none is XLA's rewrite of
     ``lax.ragged_dot`` (no ``ragged-dot-metadata`` table kernel); the
     tile plan is built once a layer call (the remat's replay holds
@@ -153,7 +157,7 @@ def test_dropless_expert_layer_compiles_to_grouped_kernels(
 
     from theanompi_tpu.ops import attention
     from theanompi_tpu.ops.grouped_matmul import TILE_PLAN_RESIDUAL
-    from theanompi_tpu.parallel.moe import moe_ffn
+    from theanompi_tpu.parallel.moe import MOE_RESIDUALS, moe_ffn
 
     e, k, d, f, n = 64, 8, 2048, 1024, 4096
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)  # as on the chip
@@ -181,6 +185,15 @@ def test_dropless_expert_layer_compiles_to_grouped_kernels(
             ),
             argnums=(0, 1, 2, 3, 4),
         ),
+        "layer_remat_kept": jax.value_and_grad(
+            jax.checkpoint(
+                forward,
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    TILE_PLAN_RESIDUAL, *MOE_RESIDUALS
+                ),
+            ),
+            argnums=(0, 1, 2, 3, 4),
+        ),
     }[direction]
     text = _compiled_text(
         fn, sds((1, n, d), jnp.bfloat16), sds((d, e), jnp.float32),
@@ -191,8 +204,14 @@ def test_dropless_expert_layer_compiles_to_grouped_kernels(
                if "tpu_custom_call" in line and "ragged-dot" in line]
     products = [p for p in kernels
                 if "ragged-dot-metadata" not in p.split("=", 1)[0]]
-    assert len(products) == dict(forward=3, backward=9,
-                                 layer_remat=11)[direction]
+    assert len(products) == dict(forward=3, backward=9, layer_remat=11,
+                                 layer_remat_kept=9)[direction]
+    replayed = [ln for ln in text.splitlines()
+                if "rematted_computation" in ln
+                and ("ragged-dot-fwd" in ln.split("=", 1)[0]
+                     or re.search(rf"= bf16\[{k * n},{d}\]\S* gather\(", ln)
+                     or (" sort(" in ln and f"[{k * n}]" in ln))]
+    assert bool(replayed) == (direction == "layer_remat"), replayed
     # the repo's own kernels, by name, and no table kernel of XLA's
     assert len(kernels) == len(products)
     assert all(re.match(r"\s*(ROOT )?%ragged-dot-(fwd|dlhs|drhs)\b", p)
@@ -586,15 +605,16 @@ def _step_blocks(text):
 
 
 def _llama_step_text(chip, monkeypatch, n_keep=0, lowered=False,
-                     n_keep_attn=0, **knobs):
+                     n_keep_attn=0, n_keep_moe=0, **knobs):
     """The compiled text (``lowered``: the lowered text, which names
     no source line outside the kernels' bodies) of a small ``Llama``'s
     real train step
     (``compile_iter_fns``: ``value_and_grad`` of ``loss_fn``, then
     ``ExchangePlan.apply``) for the v5e; the parameters are shapes, so
-    nothing is placed.  ``n_keep`` and ``n_keep_attn`` stand in for
-    the device's memory (a described device reports none: 0 calls
-    keep the MLP's products, 0 attention's)."""
+    nothing is placed.  ``n_keep``, ``n_keep_attn`` and ``n_keep_moe``
+    stand in for the device's memory (a described device reports none:
+    0 calls keep the MLP's products, 0 attention's, 0 the expert
+    layer's)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from theanompi_tpu.models.llama import Llama
@@ -606,7 +626,7 @@ def _llama_step_text(chip, monkeypatch, n_keep=0, lowered=False,
     rep = NamedSharding(mesh, P())
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)  # as on the chip
     monkeypatch.setattr(Llama, "remat_keep_calls",
-                        lambda self, limit: (n_keep, n_keep_attn))
+                        lambda self, limit: (n_keep, n_keep_attn, n_keep_moe))
     model = Llama(dict(dict(
         dim=256, n_layers=2, n_heads=2, n_kv_heads=2, ffn_dim=512,
         vocab=4096, seq_len=t, batch_size=b, compute_dtype="bfloat16",
@@ -805,8 +825,9 @@ _MLA_MOE = dict(
 )
 
 
+@pytest.mark.parametrize("n_keep_moe", [0, 1])
 def test_held_share_step_compiles_with_its_kernels_and_scopes(
-    chip, monkeypatch
+    chip, monkeypatch, n_keep_moe
 ):
     """The cell's kind of step (``_MLA_MOE``: a dense call, an expert
     call and the MTP module's expert call) compiled for the v5e: three
@@ -815,10 +836,16 @@ def test_held_share_step_compiles_with_its_kernels_and_scopes(
     two expert calls against leaves of the 2 experts held, over the
     static bound of 512 of the ``k * N`` = 1024 sorted rows (the held
     range shortens the visits at run time, not the grid) — three
-    forward, two replayed and six backward a call, all of them in the
-    loop over the windows of 512 rows, whose first pass every routing
-    runs and whose second only a routing past the bound; none over
-    1024 rows anywhere, and no second set beside the loop; window 0's
+    forward and six backward a call, and the gate and the up product
+    twice more: in the replay of a call whose remat does not keep
+    ``MOE_RESIDUALS`` (its forward loop again, for window 0's rows and
+    products; the down product dropped) and NOT in the replay of the
+    ``n_keep_moe`` stack calls whose remat does (the MTP block's keeps
+    the plain policy), and under the backward loop's ``cond`` for a
+    window past the first, kept or not; all of them in the loops over
+    the windows of 512 rows, whose first pass every routing runs and
+    whose second only a routing past the bound; none over
+    1024 rows anywhere, and no second set beside the loops; window 0's
     tile plan made outside the loop, once a call and not in a replay;
     the new scopes in every phase they have; every block named; and
     the step gives the selection bias back."""
@@ -827,7 +854,8 @@ def test_held_share_step_compiles_with_its_kernels_and_scopes(
 
     from benchmark.layer_metrics import _scopes
 
-    text = _llama_step_text(chip, monkeypatch, **_MLA_MOE)
+    text = _llama_step_text(chip, monkeypatch, n_keep_moe=n_keep_moe,
+                            **_MLA_MOE)
     assert _flash_kernels(text) == dict(fwd=3, dkv=3, dq=3)
     flash = [ln for ln in text.splitlines()
              if "tpu_custom_call" in ln and "_flash_jit" in ln]
@@ -844,7 +872,10 @@ def test_held_share_step_compiles_with_its_kernels_and_scopes(
 
     assert all(map(in_loop, products))
     assert Counter(map(phase, products)) == dict(
-        fwd=2 * 3, replay=2 * 2, bwd=2 * 6)
+        fwd=2 * 3, replay=(2 - n_keep_moe) * 2, bwd=2 * (6 + 2))
+    rebuilt = [ln for ln in products if "/cond/" in ln]
+    assert len(rebuilt) == 2 * 2 and all(
+        "ragged-dot-fwd" in ln and phase(ln) == "bwd" for ln in rebuilt)
     rows = 2 * 2 * 256              # k * N; the bound is half of them
     assert all(re.search(rf"\[{rows // 2},256\]", ln) for ln in products)
     assert not any(re.search(rf"\[{rows},256\]", ln) for ln in products)

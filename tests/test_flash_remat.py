@@ -21,6 +21,11 @@ calls, q, k, v and the attention block's output
 step's peak and the device's memory, the MLP's copies first.  The
 second half of this file counts the replayed products, holds
 all-kept against none-kept, and pins the rule and the estimate.
+
+A dropless expert call keeps, the same way and from what the other two
+sets leave, its sorted rows, their gate and up products and the sort's
+two results (``parallel.moe.MOE_RESIDUALS``): all ``k N`` rows where
+every expert is here, under a held range the first window's ``R``.
 """
 
 import re
@@ -36,7 +41,8 @@ from theanompi_tpu.models.llama import ATTN_RESIDUALS, Llama
 from theanompi_tpu.ops import attention
 from theanompi_tpu.ops.attention import FLASH_RESIDUALS, flash_attention_tpu
 from theanompi_tpu.ops.layers import MLP_RESIDUALS
-from theanompi_tpu.parallel import make_mesh
+from theanompi_tpu.parallel import make_mesh, moe
+from theanompi_tpu.parallel.moe import MOE_RESIDUALS
 
 B, H, T, D = 2, 2, 32, 16
 
@@ -187,7 +193,8 @@ def test_worker_summary_names_what_remat_keeps(remat):
     )
     assert res["remat_saves"] == (list(FLASH_RESIDUALS) if remat else [])
     # the CPU reports no memory limit: no call keeps more
-    assert (res["remat_kept_calls"], res["remat_kept_attn_calls"]) == (0, 0)
+    assert (res["remat_kept_calls"], res["remat_kept_attn_calls"],
+            res["remat_kept_moe_calls"]) == (0, 0, 0)
 
 
 def test_worker_summary_of_a_model_without_layer_remat():
@@ -222,17 +229,20 @@ def _replayed_mlp_products(jaxpr):
     )
 
 
-def _step_model(n_keep, tp=1, n_keep_attn=0, **over):
+def _step_model(n_keep, tp=1, n_keep_attn=0, n_keep_moe=0, **over):
     """A TINY model with its step built and ``n_keep`` (calls that
-    keep ``MLP_RESIDUALS``) and ``n_keep_attn`` (``ATTN_RESIDUALS``)
-    forced (the CPU reports no memory limit: ``compile_iter_fns``
-    leaves 0 and 0)."""
+    keep ``MLP_RESIDUALS``), ``n_keep_attn`` (``ATTN_RESIDUALS``) and
+    ``n_keep_moe`` (``MOE_RESIDUALS``) forced (the CPU reports no
+    memory limit: ``compile_iter_fns`` leaves 0 of each)."""
     model = Llama(dict(TINY, n_layers=2, optimizer="sgd", lr=1.0, tp=tp,
                        n_kv_heads=tp, **over))
     model.build_model(n_replicas=1)
     model.compile_iter_fns(
         mesh=make_mesh(data=1, model=tp, devices=jax.devices()[:tp]))
-    assert (model.remat_kept_calls, model.remat_kept_attn_calls) == (0, 0)
+    assert (model.remat_kept_calls, model.remat_kept_attn_calls,
+            model.remat_kept_moe_calls) == (0, 0, 0)
+    model.remat_kept_moe_calls = (
+        model.remat_calls if n_keep_moe == "all" else n_keep_moe)
     model.remat_kept_calls = (
         model.remat_calls if n_keep == "all" else n_keep)
     model.remat_kept_attn_calls = (
@@ -311,31 +321,45 @@ def test_kept_attention_calls_replay_no_projection(n_keep, over):
     assert _replayed_mlp_products(jaxpr.jaxpr) == 2 * model.remat_calls
 
 
-@pytest.mark.parametrize("over, kept_attn, mlp, attn", [
-    (dict(n_layers=3), 1, {2}, {2}),
-    (dict(n_layers=2, ut_steps=4, exit_beta=0.1), 3, {7}, {5, 6, 7}),
-    # the dense calls and the attention calls are not the same calls
+@pytest.mark.parametrize("over, kept_attn, mlp, attn, experts", [
+    (dict(n_layers=3), 1, {2}, {2}, set()),
+    (dict(n_layers=2, ut_steps=4, exit_beta=0.1), 3, {7}, {5, 6, 7}, set()),
+    # the dense calls, the attention calls and the expert calls are
+    # not the same calls
     (dict(n_layers=3, n_experts=4, moe_top_k=2, first_k_dense=1), 2,
-     {0}, {1, 2}),
+     {0}, {1, 2}, {2}),
     (dict(n_layers=3, layer_types=["attention", "mamba", "mamba"],
           mamba_n_heads=4, mamba_d_head=16, mamba_d_state=16,
-          mamba_chunk_size=16), 1, {2}, {0}),
+          mamba_chunk_size=16), 1, {2}, {0}, set()),
 ], ids=["plain", "looped", "first_dense", "hybrid"])
-def test_kept_calls_are_the_last_of_their_kind(over, kept_attn, mlp, attn):
+def test_kept_calls_are_the_last_of_their_kind(over, kept_attn, mlp, attn,
+                                               experts):
     model = Llama(dict(TINY, **over))
     model.remat_kept_calls, model.remat_kept_attn_calls = 1, kept_attn
-    assert model._kept_calls() == (mlp, attn)
+    model.remat_kept_moe_calls = 1
+    assert model._kept_calls() == (mlp, attn, experts)
+
+
+# an expert layer with every expert here (all ``k N`` sorted rows at
+# once) and one that holds 2 of 8 (192 picks in windows of 96 rows)
+EXPERTS = {
+    "moe": dict(n_experts=4, moe_top_k=2, capacity_factor=None),
+    "moe_held": dict(n_experts=8, moe_top_k=3, capacity_factor=None,
+                     moe_experts_held=2),
+}
+EXPERT_DECODERS = pytest.mark.parametrize(
+    "over", EXPERTS.values(), ids=EXPERTS)
 
 
 @pytest.mark.parametrize(
-    "over", [{}, {"n_experts": 4, "moe_top_k": 2, "capacity_factor": None},
-             ATTENTIONS["qk_norm"], ATTENTIONS["nope"]],
-    ids=["dense", "moe", "qk_norm", "nope"])
+    "over", [{}, *EXPERTS.values(), ATTENTIONS["qk_norm"],
+             ATTENTIONS["nope"]],
+    ids=["dense", *EXPERTS, "qk_norm", "nope"])
 def test_names_alone_leave_the_lowered_step_as_it_was(monkeypatch, over):
     """No call kept: ONE policy, the parent's; ``checkpoint_name``
     lowers to nothing, so the step's text is the text without the
-    MLP's two names and attention's four.  An expert layer has
-    attention's alone."""
+    MLP's two names, attention's four and the expert layer's five (an
+    expert layer has attention's and its own)."""
     def text():
         model = _step_model(0, **over)
         text = model._train_step.lower(*_step_args(model)).as_text()
@@ -343,7 +367,8 @@ def test_names_alone_leave_the_lowered_step_as_it_was(monkeypatch, over):
         return re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
 
     named = text()
-    monkeypatch.setattr(llama, "checkpoint_name", lambda x, name: x)
+    for module in (llama, moe):
+        monkeypatch.setattr(module, "checkpoint_name", lambda x, name: x)
     assert named == text()
 
 
@@ -396,6 +421,69 @@ def test_all_attention_calls_kept_is_bitwise_none_kept(tp, over):
         leaves=["wq", "wk", "wv", "wo"])
 
 
+def _replayed_expert_work(jaxpr, model):
+    """(gathers of the layer's sorted rows, gate / up grouped products,
+    sorts) in the remat's replay of a TINY expert model's step, loops
+    and custom rules opened: ``[R, D]`` gathers, ``[R, ffn_dim]``
+    grouped products and the two sorts of the ``k N`` picks under
+    ``rematted_computation`` — with all experts here at the replay's
+    top, under a held range in the forward loop it runs again.  (A
+    sub-jaxpr's name stacks are relative to the equation that holds
+    it.)"""
+    picks = model.moe_top_k * TINY["batch_size"] * T
+    rows = moe.held_rows_bound(picks, model.moe_experts_held,
+                               model.n_experts)
+    found = {"gather": 0, "ragged_dot_general": 0, "sort": 0}
+    shapes = {"gather": (rows, model.dim), "ragged_dot_general": (rows, FFN),
+              "sort": (picks,)}
+
+    def walk(jaxpr, stack):
+        for eqn in jaxpr.eqns:
+            here = f"{stack}/{eqn.source_info.name_stack}"
+            name = eqn.primitive.name
+            if name in found and "rematted_computation" in here:
+                found[name] += eqn.outvars[0].aval.shape == shapes[name]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, here)
+
+    walk(jaxpr, "")
+    return tuple(found.values())
+
+
+@EXPERT_DECODERS
+@pytest.mark.parametrize("n_keep", [0, 1, "all"])
+def test_kept_expert_calls_replay_no_gather_and_no_grouped_product(
+        n_keep, over):
+    """A call that keeps ``MOE_RESIDUALS`` replays no gather of its
+    sorted rows, neither the gate nor the up product and neither sort;
+    a call that does not replays them all (under a held range: its
+    forward loop, for window 0's three arrays).  What the backward
+    loop of a held call rebuilds for a window PAST the first stands
+    under a ``cond`` and no remat, kept or not."""
+    model = _step_model(0, n_keep_moe=n_keep, **over)
+    jaxpr = jax.make_jaxpr(model.train_step_fn)(*_step_args(model))
+    replayed = model.remat_calls - model.remat_kept_moe_calls
+    assert model.remat_calls == 2
+    assert _replayed_expert_work(jaxpr.jaxpr, model) == (
+        replayed, 2 * replayed, 2 * replayed)
+
+
+@EXPERT_DECODERS
+@pytest.mark.parametrize("tp", [1, 2])
+def test_all_expert_calls_kept_is_bitwise_none_kept(tp, over):
+    """Every expert call's rows and products kept against every
+    call's rebuilt, alone and with the FFN width over a ``model`` axis
+    (the mesh's ``expert`` axis is one device wide in both: a dropless
+    layer refuses a wider one).  Compiled as written, as attention's
+    comparison is."""
+    _assert_bitwise(
+        *(_loss_and_grads(
+            _step_model(0, tp=tp, n_keep_moe=n_keep_moe, **over),
+            {"xla_backend_optimization_level": 0})
+          for n_keep_moe in ("all", 0)),
+        leaves=["router", "we_gate", "we_up", "we_down"])
+
+
 def _cell_model(cell):
     """The ``Llama`` of a benchmark cell, from its configuration's
     program block (nothing is placed: parameters materialise in
@@ -437,60 +525,87 @@ def test_estimate_reads_the_cells_peaks(cell):
     assert abs(estimate - LEDGER_PEAKS[cell]) < 0.6, estimate
 
 
-@pytest.mark.parametrize("cell, limit_gib, n_mlp, n_attn", [
-    # at the chip's 15.75 GiB, the counts of the benchmark's six
-    # decoders (PERF.md §6, PR 49)
-    ("mistral7b_train_t4096", 15.75, 2, 2),         # ample: all calls
-    ("mellum2_train_t8192", 15.75, 0, 4),           # expert layers alone
-    ("olmoe_train_t4096", 15.75, 0, 1),
-    ("ouro_train_t4096", 15.75, 10, 1),             # what 10 MLP calls leave
+@pytest.mark.parametrize("cell, limit_gib, n_mlp, n_attn, n_moe", [
+    # at the chip's 15.75 GiB, the counts of the benchmark's seven
+    # decoders (PERF.md §6, PRs 49 and 51)
+    ("mistral7b_train_t4096", 15.75, 2, 2, 0),      # ample: all calls
+    ("mellum2_train_t8192", 15.75, 0, 4, 4),        # expert layers alone
+    ("olmoe_train_t4096", 15.75, 0, 1, 1),
+    ("ouro_train_t4096", 15.75, 10, 1, 0),          # what 10 MLP calls leave
     # nine mamba calls and the attention call, every SwiGLU dense:
     # ten MLP calls leave 0.018 GB, attention's call takes 0.084
-    ("granite4h_micro_train_t8192", 15.75, 10, 0),
-    ("glm47flash_train_t8192", 15.75, 1, 0),        # latent attention
-    ("mistral7b_train_t4096", 12.0, 0, 0),          # below the estimate
-    ("mistral7b_train_t4096", None, 0, 0),          # no device limit
-    ("ouro_train_t4096", 64.0, 32, 32),
-    ("olmoe_train_t4096", 64.0, 0, 1),              # an expert layer
-    ("granite4h_micro_train_t8192", 64.0, 10, 1),
-    ("granite4h_micro_train_t8192", 13.5, 1, 0),
+    ("granite4h_micro_train_t8192", 15.75, 10, 0, 0),
+    # latent attention; the stack's four expert calls, not the MTP
+    # block's
+    ("glm47flash_train_t8192", 15.75, 1, 0, 4),
+    # 0.052 GiB left by one MLP and five attention calls: one expert
+    # call's 0.049 fits
+    ("laguna_s21_train_t8192", 15.75, 1, 5, 1),
+    ("mellum2_train_t8192", 14.25, 0, 4, 2),        # two of four fit
+    ("mellum2_train_t8192", 13.0, 0, 3, 0),         # attention's first
+    ("mistral7b_train_t4096", 12.0, 0, 0, 0),       # below the estimate
+    ("mistral7b_train_t4096", None, 0, 0, 0),       # no device limit
+    ("ouro_train_t4096", 64.0, 32, 32, 0),
+    ("olmoe_train_t4096", 64.0, 0, 1, 1),           # an expert layer
+    ("granite4h_micro_train_t8192", 64.0, 10, 1, 0),
+    ("granite4h_micro_train_t8192", 13.5, 1, 0, 0),
 ], ids=str)
-def test_keep_rule_from_shapes_and_the_limit(cell, limit_gib, n_mlp, n_attn):
+def test_keep_rule_from_shapes_and_the_limit(cell, limit_gib, n_mlp, n_attn,
+                                             n_moe):
     model = _cell_model(cell)
     limit = None if limit_gib is None else int(limit_gib * GIB)
-    assert model.remat_keep_calls(limit) == (n_mlp, n_attn)
+    assert model.remat_keep_calls(limit) == (n_mlp, n_attn, n_moe)
     if not limit:
         return
-    # the MLP's copies first, attention's from what they leave; where
-    # not every call of a kind keeps its copies, the next call's would
-    # not have fitted
+    # the MLP's copies first, attention's from what they leave, the
+    # expert layer's from what both leave; where not every call of a
+    # kind keeps its copies, the next call's would not have fitted
     room = max(
         limit - llama.REMAT_RESERVE_BYTES - model.step_peak_estimate(), 0)
-    for kept, per_call, calls in (
-        (n_mlp, model.remat_kept_bytes_per_call,
-         model.ut_steps * model.layer_kinds.count("dense")),
-        (n_attn, model.remat_kept_attn_bytes_per_call,
-         model.ut_steps * sum(model._gqa_layers)),
+    taken = []
+    for kept, calls in (
+        (n_mlp, [model.remat_kept_bytes_per_call]
+         * model.ut_steps * model.layer_kinds.count("dense")),
+        (n_attn, model._gqa_call_bytes),        # each at its layer's heads
+        (n_moe, [model.remat_kept_moe_bytes_per_call]
+         * model.ut_steps * model.layer_kinds.count("moe")),
     ):
-        assert kept * per_call <= room
-        if kept < calls:
-            assert room < (kept + 1) * per_call
-        room -= kept * per_call
-    model.remat_kept_calls, model.remat_kept_attn_calls = n_mlp, n_attn
-    assert model.remat_kept_bytes == (
-        n_mlp * model.remat_kept_bytes_per_call
-        + n_attn * model.remat_kept_attn_bytes_per_call)
+        taken.append(sum(calls[len(calls) - kept:]))
+        assert taken[-1] <= room
+        if kept < len(calls):
+            assert room - taken[-1] < calls[len(calls) - kept - 1]
+        room -= taken[-1]
+    (model.remat_kept_calls, model.remat_kept_attn_calls,
+     model.remat_kept_moe_calls) = n_mlp, n_attn, n_moe
+    assert model.remat_kept_bytes == sum(taken)
 
 
-def test_expert_layer_names_no_product():
-    """An expert layer names no MLP product; its attention block
-    names what every grouped-query block names."""
-    model = _cell_model("olmoe_train_t4096")
-    assert model.remat_kept_bytes_per_call == 0
-    assert not set(MLP_RESIDUALS + ATTN_RESIDUALS) & set(model.remat_saves)
-    # q, k, v of 16 heads of 128 and a row of 2048, 4 x 4096 tokens
-    assert model.remat_kept_attn_bytes_per_call == (
-        4 * 4096 * (3 * 16 * 128 + 2048) * 2)
+@pytest.mark.parametrize("cell, rows, row_bytes", [
+    # every expert here: all 8 picks of 4 x 4096 tokens, a row of
+    # 2048 and two products of 1024
+    ("olmoe_train_t4096", 131072, (2048 + 2 * 1024) * 2),
+    # held ranges: twice the balanced share of the picks
+    ("mellum2_train_t8192", 65536, (2304 + 2 * 896) * 2),
+    ("glm47flash_train_t8192", 16384, (2048 + 2 * 1536) * 2),
+    ("laguna_s21_train_t8192", 5120, (3072 + 2 * 1024) * 2),
+], ids=["all_here", "held_16_of_64", "held_8_of_64", "held_8_of_256"])
+def test_expert_layer_weighs_its_rows_and_two_products(cell, rows, row_bytes):
+    """What a dropless expert call keeps: its ``R`` sorted rows with
+    their gate and up products in compute dtype and the sort's two
+    ``int32[k N]``.  It names no MLP product; its attention block
+    names what every grouped-query block names; no policy keeps the
+    names of any of the three sets in EVERY call."""
+    model = _cell_model(cell)
+    picks = model.moe_top_k * model.config["batch_size"] * model.seq_len
+    assert model.remat_kept_moe_bytes_per_call == (
+        rows * row_bytes + 2 * picks * 4)
+    assert not set(MLP_RESIDUALS + ATTN_RESIDUALS + MOE_RESIDUALS) & set(
+        model.remat_saves)
+    if cell == "olmoe_train_t4096":
+        assert model.remat_kept_bytes_per_call == 0
+        # q, k, v of 16 heads of 128 and a row of 2048, 4 x 4096 tokens
+        assert model.remat_kept_attn_bytes_per_call == (
+            4 * 4096 * (3 * 16 * 128 + 2048) * 2)
 
 
 @pytest.mark.parametrize("cell, layers, per_token", [
@@ -510,14 +625,19 @@ def test_attention_names_weigh_what_the_block_keeps(cell, layers, per_token):
 
 
 @pytest.mark.parametrize("over, keeps", [
-    ({}, (2, 2)), ({"remat": False}, (0, 0)), ({"pp": 2}, (0, 0)),
-    ({"n_experts": 4, "moe_top_k": 2}, (0, 2)),
-    ({"tp": 2, "n_kv_heads": 2}, (2, 2)),
-], ids=["remat", "no_remat", "pipeline", "moe", "tp"])
+    ({}, (2, 2, 0)), ({"remat": False}, (0, 0, 0)), ({"pp": 2}, (0, 0, 0)),
+    # the capacity path names nothing of its expert layer
+    ({"n_experts": 4, "moe_top_k": 2}, (0, 2, 0)),
+    (EXPERTS["moe"], (0, 2, 2)), (EXPERTS["moe_held"], (0, 2, 2)),
+    (dict(EXPERTS["moe"], remat=False), (0, 0, 0)),
+    (dict(EXPERTS["moe"], first_k_dense=1), (1, 2, 1)),
+    ({"tp": 2, "n_kv_heads": 2}, (2, 2, 0)),
+], ids=["remat", "no_remat", "pipeline", "moe_capacity", "moe", "moe_held",
+        "moe_no_remat", "moe_first_dense", "tp"])
 def test_keep_rule_bypasses(over, keeps):
     model = Llama(dict(TINY, n_layers=2, **over))
     assert model.remat_keep_calls(64 * GIB) == keeps
-    assert model.remat_keep_calls(None) == (0, 0)
+    assert model.remat_keep_calls(None) == (0, 0, 0)
 
 
 def test_compile_reads_the_devices_limit(monkeypatch):
@@ -533,8 +653,8 @@ def test_compile_reads_the_devices_limit(monkeypatch):
     model = Llama(dict(TINY, n_layers=2))
     model.build_model(n_replicas=1)
     model.compile_iter_fns(mesh=make_mesh(data=1, devices=jax.devices()[:1]))
-    assert (model.remat_kept_calls, model.remat_kept_attn_calls, seen) == (
-        2, 2, jax.devices()[:1])
+    assert (model.remat_kept_calls, model.remat_kept_attn_calls,
+            model.remat_kept_moe_calls, seen) == (2, 2, 0, jax.devices()[:1])
 
 
 class _Device:
@@ -557,8 +677,11 @@ def test_device_bytes_limit(stats, limit):
     assert llama._device_bytes_limit(map(_Device, stats)) == limit
 
 
+@pytest.mark.parametrize("over", [{}, *EXPERTS.values()],
+                         ids=["dense", *EXPERTS])
 @pytest.mark.parametrize("limit_gib, kept", [(None, 0), (64, 1)])
-def test_worker_summary_counts_the_kept_calls(monkeypatch, limit_gib, kept):
+def test_worker_summary_counts_the_kept_calls(monkeypatch, limit_gib, kept,
+                                              over):
     from theanompi_tpu.workers import bsp_worker
 
     if limit_gib:
@@ -567,13 +690,24 @@ def test_worker_summary_counts_the_kept_calls(monkeypatch, limit_gib, kept):
     res = bsp_worker.run(
         devices=[0], modelfile="theanompi_tpu.models.llama",
         modelclass="Llama",
-        config=dict(TINY, n_layers=1, n_epochs=1, seed=3), verbose=False,
+        config=dict(TINY, n_layers=1, n_epochs=1, seed=3, **over),
+        verbose=False,
     )
     n_tok = TINY["batch_size"] * T
-    # the gate and the up product; q, k, v (2 heads and 1 of 16) and
-    # the block's output: float32
-    per_call = 2 * n_tok * FFN * 4 + n_tok * (4 * 16 + TINY["dim"]) * 4
+    # q, k, v (2 heads and 1 of 16) and the block's output: float32
+    per_call = n_tok * (4 * 16 + TINY["dim"]) * 4
+    if over:
+        # the sorted rows laid out at a time with their gate and up
+        # products, and the sort's two int32 arrays
+        picks = over["moe_top_k"] * n_tok
+        rows = moe.held_rows_bound(picks, over.get("moe_experts_held"),
+                                   over["n_experts"])
+        per_call += rows * (TINY["dim"] + 2 * FFN) * 4 + 2 * picks * 4
+    else:
+        per_call += 2 * n_tok * FFN * 4     # the gate and the up product
     assert (res["remat_calls"], res["remat_kept_calls"],
-            res["remat_kept_attn_calls"], res["remat_kept_bytes"]) == (
-        1, kept, kept, kept * per_call)
-    assert res["remat_saves"] == list(FLASH_RESIDUALS)
+            res["remat_kept_attn_calls"], res["remat_kept_moe_calls"],
+            res["remat_kept_bytes"]) == (
+        1, 0 if over else kept, kept, kept if over else 0, kept * per_call)
+    assert res["remat_saves"] == [
+        *FLASH_RESIDUALS, *(["moe_tile_plan"] if over else [])]

@@ -647,15 +647,16 @@ def test_remat_counts_the_calls_that_are_dense():
     assert model.layer_kinds == ("dense", "moe", "moe")
     per_call = model.remat_kept_bytes_per_call
     assert per_call == 2 * 2 * 32 * 96 * 4      # the DENSE width, fp32
-    # never an expert call; latent attention names nothing
-    assert model.remat_keep_calls(1 << 40) == (1, 0)
-    model.remat_kept_calls = 1
-    assert model._kept_calls() == ({0}, set())
+    # never an expert call for the MLP's names; latent attention names
+    # nothing; the two expert calls their own
+    assert model.remat_keep_calls(1 << 40) == (1, 0, 2)
+    model.remat_kept_calls, model.remat_kept_moe_calls = 1, 1
+    assert model._kept_calls() == ({0}, set(), {2})
     assert model.remat_saves[-1] == "moe_tile_plan"
     # grouped-query attention's names are every layer's, dense or not
     plain = Llama(dict(n_layers=3, n_experts=4, capacity_factor=None))
-    assert plain.remat_keep_calls(1 << 40) == (0, 3)
-    assert Llama(dict(n_layers=3)).remat_keep_calls(1 << 40) == (3, 3)
+    assert plain.remat_keep_calls(1 << 40) == (0, 3, 3)
+    assert Llama(dict(n_layers=3)).remat_keep_calls(1 << 40) == (3, 3, 0)
 
 
 def test_mtp_block_is_the_stacks_own_layer_call():

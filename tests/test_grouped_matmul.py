@@ -171,8 +171,8 @@ def test_full_plan_is_the_prefix_plan_of_all_rows():
     for a, b in zip(gm._plan_arrays(full), gm._plan_arrays(held)):
         np.testing.assert_array_equal(a, b)
     out = jnp.ones((512, N))
-    assert gm._held_rows(out, full) is out          # nothing added
-    assert gm._held_rows(out, held) is not out
+    assert gm.held_rows(out, full) is out          # nothing added
+    assert gm.held_rows(out, held) is not out
 
 
 def test_held_layer_on_the_kernels_matches_ragged_dot(

@@ -560,14 +560,23 @@ def test_kept_attention_calls_weigh_their_own_layers_heads():
     room = llama.REMAT_RESERVE_BYTES + model.step_peak_estimate()
     # the dense layer's products first, then the last full layer's
     # call, then the window layers' before it
+    # ... and the expert calls' rows and products from what those
+    # leave: 5120 rows (twice 8 of 256 experts' share of 81920 picks)
+    # of 3072 and twice 1024, and the sort's two int32 arrays
+    moe = 5120 * (row + 2 * 1024) * 2 + 2 * 81920 * 4
+    assert model.remat_kept_moe_bytes_per_call == moe
+    attn = 2 * full + 3 * band
     for extra, want in [
-        (0, (0, 0)), (mlp, (1, 0)), (mlp + full, (1, 1)),
-        (mlp + full + band - 1, (1, 1)), (mlp + full + band, (1, 2)),
-        (mlp + 2 * full + 3 * band, (1, 5)),
+        (0, (0, 0, 0)), (mlp, (1, 0, 0)), (mlp + full, (1, 1, 0)),
+        (mlp + full + band - 1, (1, 1, 4)), (mlp + full + band, (1, 2, 0)),
+        (mlp + attn, (1, 5, 0)), (mlp + attn + moe, (1, 5, 1)),
+        (mlp + attn + 4 * moe - 1, (1, 5, 3)),
+        (mlp + attn + 5 * moe, (1, 5, 4)),
     ]:
         assert model.remat_keep_calls(room + extra) == want, extra
     model.remat_kept_calls, model.remat_kept_attn_calls = 1, 2
-    assert model.remat_kept_bytes == mlp + full + band
+    model.remat_kept_moe_calls = 3
+    assert model.remat_kept_bytes == mlp + full + band + 3 * moe
 
 
 # -- the file ------------------------------------------------------------------

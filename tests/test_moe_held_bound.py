@@ -32,7 +32,7 @@ ROUTINGS = {
 }
 
 
-def _picks(held_picks, seed=0):
+def _picks(held_picks, seed=0, held=HELD, e=E):
     """``eidx [N, K]``: token t picks ``held_picks[t]`` distinct held
     experts and the rest from those not held, in a shuffled slot
     order."""
@@ -40,22 +40,22 @@ def _picks(held_picks, seed=0):
     rows = []
     for h in held_picks:
         row = np.concatenate([
-            rng.choice(HELD, h, replace=False),
-            HELD + rng.choice(E - HELD, K - h, replace=False),
+            rng.choice(held, h, replace=False),
+            held + rng.choice(e - held, K - h, replace=False),
         ])
         rows.append(rng.permutation(row))
     return jnp.asarray(np.stack(rows), jnp.int32)
 
 
-def _operands(dtype, seed=1):
+def _operands(dtype, seed=1, held=HELD):
     rng = np.random.default_rng(seed)
 
     def arr(*shape, scale=1.0):
         return jnp.asarray(rng.standard_normal(shape) * scale, dtype)
 
     gates = jnp.asarray(rng.uniform(0.1, 1.0, (N, K)), jnp.float32)
-    return (arr(N, D), arr(HELD, D, F, scale=0.25),
-            arr(HELD, D, F, scale=0.25), arr(HELD, F, D, scale=0.25)), gates
+    return (arr(N, D), arr(held, D, F, scale=0.25),
+            arr(held, D, F, scale=0.25), arr(held, F, D, scale=0.25)), gates
 
 
 def full_length(x2, we_gate, we_up, we_down, gates, eidx):
@@ -65,8 +65,8 @@ def full_length(x2, we_gate, we_up, we_down, gates, eidx):
     flat_e = eidx.T.reshape(-1)
     order = jnp.argsort(flat_e, stable=True)
     inv = jnp.argsort(order)
-    sizes = jnp.sum(flat_e[:, None] == jnp.arange(HELD)[None], axis=0,
-                    dtype=jnp.int32)
+    sizes = jnp.sum(flat_e[:, None] == jnp.arange(we_gate.shape[0])[None],
+                    axis=0, dtype=jnp.int32)
     rows = x2[order % n]
     gate = gates.T.reshape(-1)[order]
     dt = x2.dtype
@@ -77,10 +77,10 @@ def full_length(x2, we_gate, we_up, we_down, gates, eidx):
     return jnp.sum(out[inv].reshape(k, n, -1).astype(jnp.float32), axis=0)
 
 
-def bounded(x2, we_gate, we_up, we_down, gates, eidx):
+def bounded(x2, we_gate, we_up, we_down, gates, eidx, e=E):
     return moe._dropless_experts(
-        x2, gates, eidx, we_gate, we_up, we_down, n_experts=E,
-        model_axis=None, held=HELD,
+        x2, gates, eidx, we_gate, we_up, we_down, n_experts=e,
+        model_axis=None, held=we_gate.shape[0],
     )
 
 
@@ -143,6 +143,63 @@ def test_bounded_layer_under_jit_and_remat(routing):
     for g, w in zip(got, want):
         np.testing.assert_allclose(g, w, rtol=1e-5,
                                    atol=1e-5 * np.abs(w).max())
+
+
+@pytest.mark.parametrize("held, e, held_picks, windows", [
+    (4, 16, 4, 2),      # every pick held: 160 rows in windows of 80
+    (3, 24, 2, 2),      # 80 held rows in windows of 40
+    (3, 24, 3, 3),      # 120
+], ids=["two_of_80", "two_of_40", "three_of_40"])
+def test_a_kept_call_past_its_bound(held, e, held_picks, windows):
+    """A routing past the bound under a layer call whose remat keeps
+    ``MOE_RESIDUALS``: window 0 is transposed from the arrays the
+    forward loop left, each window past it is rebuilt in the backward
+    loop, and the gradients are the unbounded layer's.  One loop body
+    a direction, and nothing of the layer in the remat's replay."""
+    bound = moe.held_rows_bound(PICKS, held, e)
+    eidx = _picks([held_picks] * N, seed=6, held=held, e=e)
+    assert -(-int(jnp.sum(eidx < held)) // bound) == windows
+    floats, gates = _operands(jnp.float32, seed=7, held=held)
+    want = _value_and_grads(full_length, floats, gates, eidx)
+    def step(policy):
+        return jax.jit(
+            lambda floats, gates, eidx: _value_and_grads(
+                jax.checkpoint(lambda *args: bounded(*args, e=e),
+                               policy=policy),
+                floats, gates, eidx))
+
+    def replayed(step):
+        """Primitives in the layer call's replay (the backward loop's
+        own remat of a window's second half stands inside that
+        loop)."""
+        return {
+            eqn.primitive.name
+            for eqn, stack in _stacked(
+                jax.make_jaxpr(step)(floats, gates, eidx).jaxpr)
+            if "rematted_computation" in stack
+            and "<while>" not in stack.split("rematted_computation")[0]
+        }
+
+    kept = step(jax.checkpoint_policies.save_only_these_names(
+        *moe.MOE_RESIDUALS))
+    for g, w in zip(kept(floats, gates, eidx), want):
+        np.testing.assert_allclose(g, w, rtol=1e-5,
+                                   atol=1e-5 * np.abs(w).max())
+    assert kept.lower(floats, gates, eidx).as_text().count(
+        "stablehlo.while") == 2
+    assert not {"while", "sort", "gather"} & replayed(kept)
+    assert {"while", "sort"} <= replayed(step(None))
+
+
+def _stacked(jaxpr, stack=""):
+    """Every equation with the name stack it runs under (a sub-jaxpr's
+    stacks are relative to the equation that holds it, whose primitive
+    stands in the stack as ``<name>``)."""
+    for eqn in jaxpr.eqns:
+        here = f"{stack}/{eqn.source_info.name_stack}"
+        yield eqn, here
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _stacked(sub, f"{here}<{eqn.primitive.name}>")
 
 
 def _layer(h, router, leaves, held, lo=0):
@@ -251,14 +308,19 @@ def test_nothing_to_skip_lowers_to_the_full_length_text(held, monkeypatch):
 def _float_rows(jaxpr, rows, found, loops=True):
     """Shapes of the float arrays of ``rows`` rows (a column of gates
     aside) that ``jaxpr`` makes anywhere: through calls, remats, custom
-    rules and — unless ``loops`` is false — ``while`` bodies."""
+    rules and — unless ``loops`` is false — ``while`` bodies.  Into a
+    set, or into a dict with the primitives that made each."""
     for eqn in jaxpr.eqns:
         for v in eqn.outvars:
             aval = v.aval
             if (len(getattr(aval, "shape", ())) == 2
                     and aval.shape[0] == rows and aval.shape[1] > 1
                     and jnp.issubdtype(aval.dtype, jnp.floating)):
-                found.add(aval.shape)
+                if isinstance(found, dict):
+                    found.setdefault(aval.shape, set()).add(
+                        eqn.primitive.name)
+                else:
+                    found.add(aval.shape)
         if loops or eqn.primitive.name != "while":
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 _float_rows(sub, rows, found, loops)
@@ -268,9 +330,13 @@ def _float_rows(jaxpr, rows, found, loops=True):
 def test_no_full_length_float_array_anywhere():
     """Forward and backward of the held layer write no ``[k·N, D]`` or
     ``[k·N, F]`` float array: ``[R, .]`` ones, all of them in the loop
-    over the windows (its first pass is the part every routing runs).
-    The same walk finds the long ones in the full-length layer.  One
-    loop each way, and none where there is nothing to skip."""
+    over the windows (its first pass is the part every routing runs)
+    but window 0's rows, gate and up products, which the forward loop
+    hands the backward one through its carry (``MOE_RESIDUALS``): the
+    carry's unfilled buffers (``lax.empty``), the loop's results and
+    their names.  The same walk
+    finds the long ones in the full-length layer.  One loop each way,
+    and none where there is nothing to skip."""
     floats, gates = _operands(jnp.float32)
     eidx = _picks(ROUTINGS["balance"])
 
@@ -281,7 +347,10 @@ def test_no_full_length_float_array_anywhere():
 
     assert not _float_rows(both(bounded), PICKS, set())
     assert _float_rows(both(bounded), R, set()) == {(R, D), (R, F)}
-    assert not _float_rows(both(bounded), R, set(), loops=False)
+    outside = {}
+    _float_rows(both(bounded), R, outside, loops=False)
+    assert set(outside) == {(R, D), (R, F)}
+    assert set().union(*outside.values()) == {"empty", "while", "name"}
     assert _float_rows(both(full_length), PICKS, set()) == {
         (PICKS, D), (PICKS, F)}
     text = jax.jit(

@@ -43,8 +43,9 @@ the layout-invariance tests).  Per-layer ``jax.checkpoint`` (remat)
 bounds activation memory for long sequences; it keeps the layer's
 input and the flash kernel's two outputs and, for as many of the last
 layer calls as the device's memory holds, the dense MLP's gate and up
-products and then grouped-query attention's q, k, v and the attention
-block's output (``Llama.remat_keep_calls``).  Params are initialized
+products, then grouped-query attention's q, k, v and the attention
+block's output, then a dropless expert layer's sorted rows with their
+gate and up products (``Llama.remat_keep_calls``).  Params are initialized
 *under jit with sharded out_shardings*, so the full 8B-scale parameter
 set never materializes on one device.
 
@@ -101,6 +102,8 @@ from theanompi_tpu.parallel import (
     split_microbatches,
 )
 from theanompi_tpu.parallel.moe import (
+    MOE_RESIDUALS,
+    held_rows_bound,
     moe_ffn,
     select_bias_step,
     shared_expert,
@@ -631,12 +634,15 @@ class Llama(TMModel):
             saves += (TILE_PLAN_RESIDUAL,)
         self.remat_saves = saves if self.remat else ()
         # how many of the LAST dense layer calls also keep
-        # ``MLP_RESIDUALS`` and how many of the last grouped-query
-        # attention calls ``ATTN_RESIDUALS`` ("remat_kept_calls",
-        # "remat_kept_attn_calls" of the summary): ``compile_iter_fns``
-        # sets both from the shapes and the device's memory
+        # ``MLP_RESIDUALS``, how many of the last grouped-query
+        # attention calls ``ATTN_RESIDUALS`` and how many of the last
+        # dropless expert calls ``MOE_RESIDUALS`` ("remat_kept_calls",
+        # "remat_kept_attn_calls", "remat_kept_moe_calls" of the
+        # summary): ``compile_iter_fns`` sets them from the shapes and
+        # the device's memory
         self.remat_kept_calls = 0
         self.remat_kept_attn_calls = 0
+        self.remat_kept_moe_calls = 0
         self.compute_dtype = jnp.dtype(c.get("compute_dtype", "bfloat16"))
         self.seed = int(c.get("seed", 42))
         self.n_epochs = int(c.get("n_epochs", 5))
@@ -1202,13 +1208,32 @@ class Llama(TMModel):
         return max(self._gqa_call_bytes, default=0)
 
     @property
+    def remat_kept_moe_bytes_per_call(self) -> int:
+        """Bytes of ``MOE_RESIDUALS`` one DROPLESS expert layer call
+        keeps on a device: the ``R`` sorted rows the layer lays out at
+        a time (``moe.held_rows_bound``: every pick, or under a held
+        range twice its balanced share) times a row ``[D]`` and its
+        gate and up products ``[ffn_dim / tp]`` in compute dtype, and
+        the sort's two ``int32[k N]``; 0 for a model without such a
+        layer (none, or the capacity path, which names nothing)."""
+        if "moe" not in self.layer_kinds or self.capacity_factor is not None:
+            return 0
+        picks = self.moe_top_k * self._local_tokens
+        rows = held_rows_bound(picks, self.moe_experts_held, self.n_experts)
+        return (
+            rows * (self.dim + 2 * self.ffn_dim // self.tp)
+            * self.compute_dtype.itemsize + 2 * picks * 4
+        )
+
+    @property
     def remat_kept_bytes(self) -> int:
-        """Bytes of ``MLP_RESIDUALS`` and ``ATTN_RESIDUALS`` the kept
-        calls hold on a device."""
+        """Bytes of ``MLP_RESIDUALS``, ``ATTN_RESIDUALS`` and
+        ``MOE_RESIDUALS`` the kept calls hold on a device."""
         calls = self._gqa_call_bytes
         return (
             self.remat_kept_calls * self.remat_kept_bytes_per_call
             + sum(calls[len(calls) - self.remat_kept_attn_calls:])
+            + self.remat_kept_moe_calls * self.remat_kept_moe_bytes_per_call
         )
 
     def _local_params(self, axis_sizes) -> tuple[int, int]:
@@ -1235,8 +1260,8 @@ class Llama(TMModel):
 
     def step_peak_estimate(self) -> int:
         """Bytes one device holds at the train step's peak when no
-        call keeps ``MLP_RESIDUALS`` or ``ATTN_RESIDUALS``, from
-        shapes alone: every local
+        call keeps ``MLP_RESIDUALS``, ``ATTN_RESIDUALS`` or
+        ``MOE_RESIDUALS``, from shapes alone: every local
         parameter's master, gradient and optimizer state; what each
         layer call keeps for its replay (its input, the flash kernel's
         output and logsumexp); the head's live set — one set of local
@@ -1281,21 +1306,27 @@ class Llama(TMModel):
             + kept_flash + head
         )
 
-    def remat_keep_calls(self, bytes_limit: int | None) -> tuple[int, int]:
-        """``(n_mlp, n_attn)``: how many of the last DENSE layer calls
-        keep ``MLP_RESIDUALS`` and how many of the last grouped-query
-        attention calls keep ``ATTN_RESIDUALS``.  The room is what
-        lies between the step's estimated peak and the device's
-        ``bytes_limit`` less ``REMAT_RESERVE_BYTES``; the MLP's copies
-        fill it first, as many calls as fit, attention's take what
-        they leave (so a device keeps every MLP call it kept before
-        attention's were counted).  ``(0, 0)`` without a limit (the
-        CPU), without remat and on the pipeline path, whose stage
-        function keeps the plain policy; a model without a dense layer
-        keeps no MLP call, one without grouped-query attention (latent
-        attention, mamba layers alone) no attention call."""
+    def remat_keep_calls(
+        self, bytes_limit: int | None
+    ) -> tuple[int, int, int]:
+        """``(n_mlp, n_attn, n_moe)``: how many of the last DENSE layer
+        calls keep ``MLP_RESIDUALS``, how many of the last
+        grouped-query attention calls keep ``ATTN_RESIDUALS`` and how
+        many of the stack's last dropless expert calls keep
+        ``MOE_RESIDUALS``.  The room is what lies between the step's
+        estimated peak and the device's ``bytes_limit`` less
+        ``REMAT_RESERVE_BYTES``; the MLP's copies fill it first, as
+        many calls as fit, attention's take what they leave and the
+        expert layer's what both leave (so a device keeps every call
+        it kept before the next set was counted).  ``(0, 0, 0)``
+        without a limit (the CPU), without remat and on the pipeline
+        path, whose stage function keeps the plain policy; a model
+        without a dense layer keeps no MLP call, one without
+        grouped-query attention (latent attention, mamba layers alone)
+        no attention call, one without a dropless expert layer no
+        expert call."""
         if not (self.remat and bytes_limit) or self.pp > 1:
-            return 0, 0
+            return 0, 0, 0
         free = max(
             bytes_limit - REMAT_RESERVE_BYTES - self.step_peak_estimate(), 0
         )
@@ -1313,7 +1344,11 @@ class Llama(TMModel):
                 break
             free -= attn_bytes
             n_attn += 1
-        return n_mlp, n_attn
+        moe_bytes = self.remat_kept_moe_bytes_per_call
+        n_moe = min(
+            free // moe_bytes, self.ut_steps * self.layer_kinds.count("moe")
+        ) if moe_bytes else 0
+        return n_mlp, n_attn, n_moe
 
     def _mla_qkv(self, p, xn, pos):
         """Latent attention's projections, ``xn [B, T, D]`` -> ``q, k,
@@ -1677,12 +1712,16 @@ class Llama(TMModel):
             # dense MLP's gate and up products, the last
             # ``remat_kept_attn_calls`` grouped-query attention calls
             # q, k, v and the attention block's output
-            # (``ATTN_RESIDUALS``).  Replayed in every call: the
+            # (``ATTN_RESIDUALS``), the last ``remat_kept_moe_calls``
+            # dropless expert calls their sorted rows, those rows'
+            # gate and up products and the sort's two results
+            # (``MOE_RESIDUALS``).  Replayed in every call: the
             # norms, ``swiglu`` and the down projection, the K/V
-            # repeat, QK-norm and the rotation after it; in a call
+            # repeat, QK-norm and the rotation after it, the router,
+            # the expert masters' casts and ``silu(g) * u``; in a call
             # that keeps no more than ``remat_saves``, the
-            # projections around the kernel and the MLP's two
-            # products as well.
+            # projections around the kernel, the MLP's two products
+            # and the expert layer's gather and two products as well.
             return jax.checkpoint(
                 fn,
                 policy=jax.checkpoint_policies.save_only_these_names(*names),
@@ -1694,8 +1733,8 @@ class Llama(TMModel):
         def layer_of(kind, extra):
             """The layer call of an attention kind whose remat keeps
             the names ``extra`` beside ``remat_saves``: one policy for
-            each ``extra`` that occurs (plain; + MLP; + attention;
-            + both)."""
+            each ``extra`` that occurs (plain; + the MLP's, attention's
+            or the expert layer's names, as the call keeps them)."""
             # a model of one plain kind calls the method itself
             fn = self._layer if kinds == {"full_attention"} else (
                 functools.partial(self._layer, attn_kind=kind)
@@ -1730,7 +1769,7 @@ class Llama(TMModel):
             return res[0] if len(res) == 1 else res
 
         if self.pp == 1:
-            kept_mlp, kept_attn = self._kept_calls()
+            kept_mlp, kept_attn, kept_moe = self._kept_calls()
 
             def stack(x, first_call=0):
                 moms = []
@@ -1740,7 +1779,8 @@ class Llama(TMModel):
                     fn = layer_of(
                         kind,
                         MLP_RESIDUALS * (call in kept_mlp)
-                        + ATTN_RESIDUALS * (call in kept_attn),
+                        + ATTN_RESIDUALS * (call in kept_attn)
+                        + MOE_RESIDUALS * (call in kept_moe),
                     )
                     if "router" in p:
                         x, mom = take_gate(fn(p, x, pos, next(bias_rows)))
@@ -1887,13 +1927,15 @@ class Llama(TMModel):
             logits = tp_lib.col_parallel(x, self._head_weight(params))
         return (logits, aux, routing) if with_aux else logits
 
-    def _kept_calls(self) -> tuple[frozenset, frozenset]:
-        """The layer calls whose remat also keeps ``MLP_RESIDUALS``
-        and those whose remat also keeps ``ATTN_RESIDUALS``: the last
+    def _kept_calls(self) -> tuple[frozenset, frozenset, frozenset]:
+        """The layer calls whose remat also keeps ``MLP_RESIDUALS``,
+        those whose remat also keeps ``ATTN_RESIDUALS`` and those
+        whose remat also keeps ``MOE_RESIDUALS``: the last
         ``remat_kept_calls`` of the calls that ARE dense (an expert
-        call names neither product) and the last
+        call names neither product), the last
         ``remat_kept_attn_calls`` of those that run grouped-query
-        attention (latent attention and a mamba call name none)."""
+        attention (latent attention and a mamba call name none) and
+        the last ``remat_kept_moe_calls`` of the expert calls."""
         def last(n, names_them):
             calls = [
                 i for i, ok in enumerate(names_them * self.ut_steps) if ok
@@ -1904,6 +1946,8 @@ class Llama(TMModel):
             last(self.remat_kept_calls,
                  tuple(kind == "dense" for kind in self.layer_kinds)),
             last(self.remat_kept_attn_calls, self._gqa_layers),
+            last(self.remat_kept_moe_calls,
+                 tuple(kind == "moe" for kind in self.layer_kinds)),
         )
 
     def _mtp_hidden(self, params, x, next_ids, pos, layer, bias_rows):
@@ -2237,8 +2281,9 @@ class Llama(TMModel):
         # when the LOCAL vocab is >= 64k; an int pins the chunk
         # count; 0/1 forces the dense head.
         n_xent_chunks = self._n_xent_chunks = self._xent_chunks()
-        self.remat_kept_calls, self.remat_kept_attn_calls = (
-            self.remat_keep_calls(_device_bytes_limit(mesh.devices.flat))
+        (self.remat_kept_calls, self.remat_kept_attn_calls,
+         self.remat_kept_moe_calls) = self.remat_keep_calls(
+            _device_bytes_limit(mesh.devices.flat)
         )
 
         # expert-sharded leaves exchange differently (see step below);

@@ -471,7 +471,7 @@ def _weights_call(lhs, dout, plan, out_dtype, *, tiles=None,
     )(*_plan_arrays(plan), lhs, dout)
 
 
-def _held_rows(out, plan):
+def held_rows(out, plan):
     """``out [M, .]`` of (a) / (b) under a prefix plan: the kernels
     never visit a tile past the groups' rows and write only a group's
     rows of the tile the prefix ends in, so what lies past it is
@@ -485,26 +485,24 @@ def _held_rows(out, plan):
                      jnp.zeros((), out.dtype))
 
 
-def _product(lhs, rhs, plan, interpret):
-    return _held_rows(
-        _rows_call(lhs, rhs, plan, transpose_rhs=False, interpret=interpret),
-        plan,
-    )
+def _product(lhs, rhs, plan, interpret, raw):
+    out = _rows_call(lhs, rhs, plan, transpose_rhs=False, interpret=interpret)
+    return out if raw else held_rows(out, plan)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _grouped(lhs, rhs, plan, interpret):
-    return _product(lhs, rhs, plan, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _grouped(lhs, rhs, plan, interpret, raw):
+    return _product(lhs, rhs, plan, interpret, raw)
 
 
-def _grouped_fwd(lhs, rhs, plan, interpret):
-    return _product(lhs, rhs, plan, interpret), (lhs, rhs, plan)
+def _grouped_fwd(lhs, rhs, plan, interpret, raw):
+    return _product(lhs, rhs, plan, interpret, raw), (lhs, rhs, plan)
 
 
-def _grouped_bwd(interpret, res, g):
+def _grouped_bwd(interpret, raw, res, g):
     lhs, rhs, plan = res
     return (
-        _held_rows(
+        held_rows(
             _rows_call(g, rhs, plan, transpose_rhs=True,
                        interpret=interpret),
             plan,
@@ -517,17 +515,23 @@ def _grouped_bwd(interpret, res, g):
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
-@functools.partial(jax.jit, static_argnums=(3,))
-def _grouped_jit(lhs, rhs, plan, interpret):
-    return _grouped(lhs, rhs, plan, interpret)
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _grouped_jit(lhs, rhs, plan, interpret, raw):
+    return _grouped(lhs, rhs, plan, interpret, raw)
 
 
-def grouped_matmul(lhs, rhs, plan: TilePlan, *, interpret: bool = False):
+def grouped_matmul(lhs, rhs, plan: TilePlan, *, interpret: bool = False,
+                   raw: bool = False):
     """``out[r] = lhs[r] @ rhs[group of r]`` for ``lhs [M, K]`` sorted
     by group, ``rhs [E, K, N]`` and the layer's ``plan``; ``[M, N]`` in
     ``lhs``'s dtype, fp32 accumulation.  Differentiable in ``lhs`` and
     ``rhs``; the backward reuses ``plan``.  ``interpret=True`` runs
-    the kernels in the Pallas interpreter (how the CPU tests do)."""
+    the kernels in the Pallas interpreter (how the CPU tests do).
+    ``raw``: under a prefix plan the rows past the prefix stay whatever
+    the kernel's buffer held, for a caller that reads the product
+    through ``held_rows`` itself (one that keeps the product for its
+    backward pass keeps the kernel's own output that way, not a second
+    copy behind the select)."""
     return _grouped_jit(
-        *same_vma(lhs, rhs.astype(lhs.dtype), plan), interpret
+        *same_vma(lhs, rhs.astype(lhs.dtype), plan), interpret, raw
     )

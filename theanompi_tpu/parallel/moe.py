@@ -66,6 +66,13 @@ Design:
   larger source than XLA gathers fast from (``_sum_in_kernel``) the
   picks are sorted by (expert, token) and a token's sum is formed by
   the kernel of ``ops/held_rows_sum.py`` over the window's rows.
+- **What the layer's remat may keep** (``MOE_RESIDUALS``): the
+  dropless path names its gathered sorted rows, their gate and up
+  products (under a held range the first window's, which the forward
+  loop leaves in its carry) and the sort's two results; a
+  ``jax.checkpoint`` whose policy saves them replays none of these,
+  one whose policy does not rebuilds them as before
+  (``Llama.remat_keep_calls`` keeps them for the calls that fit).
 - **Spans**: ``jax.named_scope``s ``moe_route`` (router, top-k, aux
   moments), ``moe_dispatch`` (plan + row gather / capacity buffers),
   ``moe_experts`` (the products; inside it ``moe_tile_plan``, the
@@ -200,19 +207,31 @@ def router_z_loss(logits, batch_axes=()):
     return lax.pmean(z, batch_axes) if batch_axes else z
 
 
-def _swiglu_experts(rows, we_gate, we_up, we_down, product, row_scale=None):
-    """The three expert products on rows already laid out for
-    ``product(lhs, w)`` (batched over capacity buffers, or grouped
-    over sorted rows).  ``row_scale`` (fp32, one per row) multiplies
-    the hidden activations inside their own fusion: the down product
-    is linear, so scaling its input rows scales its output rows."""
+def _gate_up(rows, we_gate, we_up, product):
+    """The gate and the up product of rows laid out for ``product(lhs,
+    w)`` (batched over capacity buffers, or grouped over sorted rows)."""
     dt = rows.dtype
-    g = product(rows, we_gate.astype(dt))
-    u = product(rows, we_up.astype(dt))
+    return product(rows, we_gate.astype(dt)), product(rows, we_up.astype(dt))
+
+
+def _gated_down(g, u, we_down, product, row_scale=None):
+    """``silu(g) * u`` through the down product.  ``row_scale`` (fp32,
+    one per row) multiplies the hidden activations inside their own
+    fusion: the down product is linear, so scaling its input rows
+    scales its output rows."""
+    dt = g.dtype
     h = jax.nn.silu(g) * u
     if row_scale is not None:
         h = (h.astype(jnp.float32) * row_scale[:, None]).astype(dt)
     return product(h, we_down.astype(dt))
+
+
+def _swiglu_experts(rows, we_gate, we_up, we_down, product, row_scale=None):
+    """The three expert products on ``rows``: ``_gate_up`` and
+    ``_gated_down``."""
+    return _gated_down(
+        *_gate_up(rows, we_gate, we_up, product), we_down, product, row_scale
+    )
 
 
 def shared_expert(x, w_gate, w_up, w_down, model_axis=MODEL_AXIS):
@@ -403,19 +422,33 @@ def _tile_plan(group_sizes, rows: int, d: int, f: int, dtype,
     return None
 
 
-def _product_over(group_sizes, plan):
+class _GroupedProduct:
     """``product(lhs [rows, .], w [E, ., .])`` of a layer call: the
     kernels over ``plan``, or ``lax.ragged_dot`` where there is none
-    (``_tile_plan``)."""
-    if plan is not None:
-        return lambda lhs, w: gmm.grouped_matmul(lhs, w, plan)
-    return lambda lhs, w: lax.ragged_dot(lhs, w, group_sizes)
+    (``_tile_plan``).  ``raw``: a product under a held range's prefix
+    plan as the kernel wrote it, whose rows past the held ones are
+    zeros only once read through ``held()`` — the select then fuses
+    into the reader, and what a layer call keeps of the product is
+    the kernel's own output."""
+
+    def __init__(self, group_sizes, plan):
+        self.group_sizes, self.plan = group_sizes, plan
+
+    def __call__(self, lhs, w, raw: bool = False):
+        if self.plan is None:
+            return lax.ragged_dot(lhs, w, self.group_sizes)
+        return gmm.grouped_matmul(lhs, w, self.plan,
+                                  raw=raw and self.plan.prefix)
+
+    def held(self, out):
+        return out if self.plan is None else gmm.held_rows(out, self.plan)
 
 
 def _grouped_product(group_sizes, rows: int, d: int, f: int, dtype,
                      prefix: bool = False):
-    """``_product_over`` the plan ``_tile_plan`` builds HERE."""
-    return _product_over(
+    """The ``_GroupedProduct`` over the plan ``_tile_plan`` builds
+    HERE."""
+    return _GroupedProduct(
         group_sizes, _tile_plan(group_sizes, rows, d, f, dtype, prefix)
     )
 
@@ -464,116 +497,221 @@ def _sum_in_kernel(rows: int, n: int, d: int, dtype) -> bool:
             and hrs.shapes_tile(rows, n, d))
 
 
-def _sorted_experts(x2, gates, we_gate, we_up, we_down, order, inv, product,
-                    *, k: int, model_axis, groups: int = 0):
-    """The sorted rows ``order`` lists through the layer: gathered,
-    the grouped SwiGLU ``product()`` gives with each row's gate folded
-    into its hidden activations, and each token's rows summed.  ``[N,
-    D]`` fp32.  ``order`` is all ``k·N`` picks with ``inv`` its
-    inverse, or a window of ``R`` of them with ``inv`` each pick's
-    place in that window (a pick outside it adds nothing, and the
-    gates get no gradient).  Every float array is as long as
-    ``order``."""
+# What a call of the dropless layer names for the layer's remat
+# (``jax.ad_checkpoint.checkpoint_name``; ``Llama.remat_keep_calls``
+# says which calls' policy saves them): the gathered sorted rows, their
+# gate and up products — under a held range the FIRST window's — and
+# the sort's two results.  A call that keeps them replays no gather of
+# its rows, neither grouped product and neither sort; ``silu(g) * u``,
+# the masters' casts, the router and the down product's operand stay
+# replayed.  The capacity path names none.
+MOE_RESIDUALS = ("moe_rows", "moe_gate", "moe_up", "moe_order", "moe_inv")
+
+
+def _sorted_rows(x2, we_gate, we_up, order, inv, product, *, k: int,
+                 groups: int = 0):
+    """The first half of the sorted rows' way through the layer: the
+    rows ``order`` lists gathered, their gate and their up product,
+    ``(rows [., D], g [., F], u [., F])`` — the three arrays of
+    ``MOE_RESIDUALS``, named on the values the second half and the
+    products' backward read."""
     with jax.named_scope("moe_dispatch"):
-        rows = _gather_sorted(x2, order, inv, k, groups)
+        rows = checkpoint_name(
+            _gather_sorted(x2, order, inv, k, groups), MOE_RESIDUALS[0]
+        )
+    with jax.named_scope("moe_experts"):
+        g, u = _gate_up(rows, we_gate, we_up,
+                        functools.partial(product, raw=True))
+        return (rows, checkpoint_name(g, MOE_RESIDUALS[1]),
+                checkpoint_name(u, MOE_RESIDUALS[2]))
+
+
+def _sorted_sum(g, u, gates, we_down, order, inv, product, *, k: int,
+                model_axis, groups: int = 0):
+    """The second half: each row's gate folded into ``silu(g) * u``,
+    the down product, and each token's rows summed.  ``[N, D]`` fp32."""
+    with jax.named_scope("moe_dispatch"):
         row_gate = _permute(gates.T.reshape(-1), order, inv)
     with jax.named_scope("moe_experts"):
-        out = _swiglu_experts(
-            rows, we_gate, we_up, we_down, product(), row_scale=row_gate,
-        )
+        out = _gated_down(product.held(g), product.held(u), we_down, product,
+                          row_scale=row_gate)
         if model_axis is not None:
             out = lax.psum(out, model_axis)               # close row-parallel
     with jax.named_scope("moe_combine"):
         return _sum_picks(out, order, inv, k, groups)
 
 
-def _window(w, floats, rest, *, k: int, bound: int, groups: int):
-    """Sorted rows ``[w * bound, (w + 1) * bound)`` of a held layer
-    call through ``_sorted_experts``, over ``(floats, rest)`` = ``((x2,
-    we_gate, we_up, we_down), (gates, order, inv, group_sizes,
-    plan))``: ``order`` padded to whole windows, ``plan`` window 0's,
-    made where the layer's remat keeps it (a later window makes its
-    own).  ``w`` is the loop's counter; ``groups``: ``_sum_picks``'."""
-    x2, we_gate, *leaves = floats
-    gates, order, inv, sizes, plan = rest
+def _sorted_experts(x2, gates, we_gate, we_up, we_down, order, inv, product,
+                    *, k: int, model_axis):
+    """All ``k·N`` sorted rows through the layer (``order`` every pick,
+    ``inv`` its inverse): ``_sorted_rows`` and ``_sorted_sum`` over
+    the grouped ``product()``.  ``[N, D]`` fp32."""
+    with jax.named_scope("moe_experts"):
+        grouped = product()
+    _, g, u = _sorted_rows(x2, we_gate, we_up, order, inv, grouped, k=k)
+    return _sorted_sum(g, u, gates, we_down, order, inv, grouped, k=k,
+                       model_axis=model_axis)
+
+
+# A held layer call runs over windows of ``bound`` sorted rows, each
+# over ``(floats, rest)`` = ``((x2, we_gate, we_up, we_down), (gates,
+# order, inv, group_sizes, plan))``: ``order`` padded to whole windows,
+# ``inv`` each pick's place among ALL sorted rows, ``plan`` window 0's,
+# made where the layer's remat keeps it (a later window makes its
+# own).  ``w`` is the window, a traced counter.
+
+def _window_picks(w, rest, *, bound: int, groups: int):
+    """``(order, inv)`` of window ``w``: its ``bound`` picks and each
+    pick's place in it (a pick outside it adds nothing to its token's
+    sum).  ``groups``: ``_sum_picks``'."""
+    _, order, inv, sizes, _ = rest
     with jax.named_scope("moe_dispatch"):
         order = lax.dynamic_slice(order, (w * bound,), (bound,))
-        inv = inv - w * bound
         if groups:          # the kernel's sum: no token past the held rows
             place = w * bound + jnp.arange(bound, dtype=jnp.int32)
             order = jnp.where(place < jnp.sum(sizes), order, -1)
+        return order, inv - w * bound
 
-    def product():
+
+def _window_product(w, floats, rest, *, bound: int):
+    """The grouped product over window ``w``'s rows."""
+    x2, we_gate, *_ = floats
+    *_, sizes, plan = rest
+    with jax.named_scope("moe_experts"):
         if plan is None:                # off the TPU: lax.ragged_dot
-            return _product_over(_window_sizes(sizes, w * bound, bound), None)
-        return _product_over(None, lax.cond(
+            return _GroupedProduct(
+                _window_sizes(sizes, w * bound, bound), None)
+        return _GroupedProduct(None, lax.cond(
             w == 0, lambda: plan, lambda: _tile_plan(
                 sizes, bound, *we_gate.shape[1:], x2.dtype, prefix=True,
                 window=w)))
 
-    return _sorted_experts(x2, gates, we_gate, *leaves, order, inv, product,
-                           k=k, model_axis=None, groups=groups)
+
+def _window(w, kept, floats, rest, *, k: int, bound: int, groups: int):
+    """Window ``w`` through the layer: ``(its part of every token's
+    sum, its rows, gate and up products)`` — the latter only where the
+    loop's carry ``kept`` holds such arrays (the rule's forward pass;
+    ``()`` in a forward pass nothing differentiates)."""
+    x2, we_gate, we_up, we_down = floats
+    order, inv = _window_picks(w, rest, bound=bound, groups=groups)
+    product = _window_product(w, floats, rest, bound=bound)
+    rows, g, u = _sorted_rows(x2, we_gate, we_up, order, inv, product,
+                              k=k, groups=groups)
+    y = _sorted_sum(g, u, rest[0], we_down, order, inv, product, k=k,
+                    model_axis=None, groups=groups)
+    return y, (rows, g, u) if kept else ()
 
 
-def _window_bwd(w, floats, rest, ct, **how):
-    """``_window`` replayed and transposed: ``ct``'s gradient to
-    ``floats``."""
-    replay = jax.checkpoint(
-        lambda floats: _window(w, floats, rest, **how), prevent_cse=False,
-    )
-    return jax.vjp(replay, floats)[1](ct)[0]
+def _window_bwd(w, kept, floats, rest, ct, *, k: int, bound: int,
+                groups: int):
+    """Window ``w`` transposed: ``(ct``'s gradient to ``floats``, the
+    window's rows, gate and up products).  Window 0 reads the three
+    from the carry ``kept``, where the forward pass left them (or the
+    layer's remat rebuilt them); a window past the first rebuilds its
+    own.  ``silu(g) * u`` and the down product's operand are replayed
+    either way, neither grouped product's forward kernel is."""
+    x2, we_gate, we_up, we_down = floats
+    order, inv = _window_picks(w, rest, bound=bound, groups=groups)
+    product = _window_product(w, floats, rest, bound=bound)
+    # (behind a barrier: XLA otherwise moves the replayed ``silu``'s
+    # exponential INTO the branches, a fourth array a call)
+    rows, g, u = lax.optimization_barrier(lax.cond(
+        w == 0, lambda: kept, lambda: _sorted_rows(
+            x2, we_gate, we_up, order, inv, product, k=k, groups=groups)))
+    # the second half replayed and transposed at (g, u) ...
+    dg, du, d_down = jax.vjp(jax.checkpoint(
+        lambda g, u, we_down: _sorted_sum(
+            g, u, rest[0], we_down, order, inv, product, k=k,
+            model_axis=None, groups=groups),
+        prevent_cse=False), g, u, we_down)[1](ct)
+    # ... and the first half's two products at ``rows``: a grouped
+    # product's own rule reads its operands alone
+    with jax.named_scope("moe_experts"):
+        d_rows, d_gate, d_up = jax.vjp(
+            lambda *operands: _gate_up(
+                *operands, functools.partial(product, raw=True)),
+            rows, we_gate, we_up)[1]((dg, du))
+    with jax.named_scope("moe_dispatch"):
+        dx2 = _sum_picks(d_rows, order, inv, k, groups).astype(x2.dtype)
+    return (dx2, d_gate, d_up, d_down), (rows, g, u)
 
 
-def _over_windows(one, zero, floats, rest, *more, bound: int, **how):
-    """``zero`` plus ``one(w, floats, rest, *more)`` over the windows
-    of ``bound`` sorted rows that hold rows of the held experts:
-    window 0 always, the others only for a routing past the bound.
-    ONE loop and one traced body for both: a second body for the
-    windows past the first — traced, lowered, its kernels compiled and
-    loaded beside the first, never run near balance — was 3 to 4 s of
-    a run's set-up (PERF.md, PR 44)."""
+def _over_windows(one, zero, kept, floats, rest, *more, bound: int,
+                  last_first: bool = False, **how):
+    """``zero`` plus the first result of ``one(w, kept, floats, rest,
+    *more)`` over the windows of ``bound`` sorted rows that hold rows
+    of the held experts — window 0 always, the others only for a
+    routing past the bound — and the last pass's second result, which
+    each pass hands the next as ``kept``.  ``last_first``: window 0
+    is the LAST pass (its ``kept`` is what the loop leaves).  ONE loop
+    and one traced body for both: a second body for the windows past
+    the first — traced, lowered, its kernels compiled and loaded
+    beside the first, never run near balance — was 3 to 4 s of a run's
+    set-up (PERF.md, PR 44)."""
     *_, group_sizes, _ = rest
     windows = jnp.maximum(1, -(-jnp.sum(group_sizes) // bound))
 
     def another(carry):
-        w, acc = carry
-        return w + 1, jax.tree.map(
-            lax.add, acc, one(w, floats, rest, *more, bound=bound, **how))
+        i, acc, kept = carry
+        add, kept = one(windows - 1 - i if last_first else i, kept, floats,
+                        rest, *more, bound=bound, **how)
+        return i + 1, jax.tree.map(lax.add, acc, add), kept
 
     # (the sums typed as the operands are, under a checked shard_map)
-    zero = gmm.same_vma(zero, floats)[0]
+    zero, kept = gmm.same_vma((zero, kept), floats)[0]
     return lax.while_loop(
         lambda carry: carry[0] < windows, another,
-        (jnp.zeros((), jnp.int32), zero),
-    )[1]
+        (jnp.zeros((), jnp.int32), zero, kept),
+    )[1:]
 
 
-def _all_windows(k, bound, groups, floats, rest):
+def _windows(k, bound, groups, floats, rest, keep: bool):
     """A held layer call, exact for every routing: the first ``bound``
     sorted rows through the layer, and — only where the held experts'
     rows pass them — the next ``bound`` and so on, each window's part
     of every token's sum added (``_over_windows``).  Near balance the
     loop runs one pass; at worst (every pick held) ``k·N / bound`` of
-    them.  No array is ever longer than ``bound``.
+    them.  No array is ever longer than ``bound``.  ``keep``: window
+    0's rows, gate and up products come back beside the sum, through
+    the loop's carry (each pass writes its own over the last one's and
+    window 0 is the last pass: no copy, no second body; the carry
+    starts as buffers nobody fills, ``lax.empty`` — no pass reads the
+    one before — where zeros were 0.9 ms a call of Mellum's step,
+    PERF.md, PR 51)."""
+    x2, we_gate, *_ = floats
+    kept = tuple(
+        lax.empty((bound, width), x2.dtype)
+        for width in (x2.shape[1], *(we_gate.shape[2],) * 2)
+    ) if keep else ()
+    return _over_windows(_window, jnp.zeros(x2.shape, jnp.float32), kept,
+                         floats, rest, k=k, bound=bound, groups=groups,
+                         last_first=True)
 
-    Differentiated by a rule of its own (``_held_windows``; a
-    ``while_loop`` has none): every window taken is replayed and
-    transposed, the windows' gradients summed in the same order.
+
+def _all_windows(k, bound, groups, floats, rest):
+    """``_windows``' sum.  Differentiated by a rule of its own
+    (``_held_windows``; a ``while_loop`` has none): the forward pass
+    also gives window 0's three arrays of ``MOE_RESIDUALS``, named, so
+    that a layer call whose remat saves them transposes window 0 from
+    them and one whose remat does not rebuilds them in its replay;
+    every window past the first is rebuilt and transposed in the
+    backward loop, the windows' gradients summed in their order.
     ``rest`` gets none: the gates of a held share carry no gradient
     (``moe_ffn``)."""
-    x2 = floats[0]
-    return _over_windows(_window, jnp.zeros(x2.shape, jnp.float32),
-                         floats, rest, k=k, bound=bound, groups=groups)
+    return _windows(k, bound, groups, floats, rest, keep=False)[0]
 
 
 def _held_windows_fwd(k, bound, groups, floats, rest):
-    return _all_windows(k, bound, groups, floats, rest), (floats, rest)
+    y, kept = _windows(k, bound, groups, floats, rest, keep=True)
+    return y, (floats, rest,
+               tuple(map(checkpoint_name, kept, MOE_RESIDUALS[:3])))
 
 
 def _held_windows_bwd(k, bound, groups, res, ct):
-    floats, _ = res
+    floats, rest, kept = res
     return _over_windows(_window_bwd, jax.tree.map(jnp.zeros_like, floats),
-                         *res, ct, k=k, bound=bound, groups=groups), None
+                         kept, floats, rest, ct, k=k, bound=bound,
+                         groups=groups)[0], None
 
 
 _held_windows = jax.custom_vjp(_all_windows, nondiff_argnums=(0, 1, 2))
@@ -627,6 +765,9 @@ def _dropless_experts(x2, gates, eidx, we_gate, we_up, we_down, *,
             (flat_e * n + picks % n if groups else flat_e, picks),
             num_keys=1, is_stable=True)
         _, inv = lax.sort((order, picks), num_keys=1)
+        # (named: a call whose remat keeps them replays neither sort)
+        order = checkpoint_name(order, MOE_RESIDUALS[3])
+        inv = checkpoint_name(inv, MOE_RESIDUALS[4])
         group_sizes = jnp.sum(
             flat_e[:, None] == jnp.arange(n_experts, dtype=jnp.int32)[None],
             axis=0, dtype=jnp.int32,

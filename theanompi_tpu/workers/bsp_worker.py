@@ -598,12 +598,15 @@ def run(
         "remat_saves": list(getattr(model, "remat_saves", ())),
         # of its "remat_calls" layer calls a step, the last
         # "remat_kept_calls" dense ones also keep the MLP's gate and
-        # up products and the last "remat_kept_attn_calls"
-        # grouped-query attention ones q, k, v and the attention
-        # block's output, "remat_kept_bytes" on a device in all
+        # up products, the last "remat_kept_attn_calls" grouped-query
+        # attention ones q, k, v and the attention block's output and
+        # the last "remat_kept_moe_calls" dropless expert ones their
+        # sorted rows with the gate and up products,
+        # "remat_kept_bytes" on a device in all
         "remat_calls": getattr(model, "remat_calls", 0),
         "remat_kept_calls": getattr(model, "remat_kept_calls", 0),
         "remat_kept_attn_calls": getattr(model, "remat_kept_attn_calls", 0),
+        "remat_kept_moe_calls": getattr(model, "remat_kept_moe_calls", 0),
         "remat_kept_bytes": getattr(model, "remat_kept_bytes", 0),
         # the flash kernels' tiles for the model's attention shape
         # ({} where no such kernel runs)
