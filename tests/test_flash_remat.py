@@ -649,32 +649,12 @@ def test_compile_reads_the_devices_limit(monkeypatch):
         seen.extend(devices)
         return 64 * GIB
 
-    monkeypatch.setattr(llama, "_device_bytes_limit", limit)
+    monkeypatch.setattr(llama, "device_bytes_limit", limit)
     model = Llama(dict(TINY, n_layers=2))
     model.build_model(n_replicas=1)
     model.compile_iter_fns(mesh=make_mesh(data=1, devices=jax.devices()[:1]))
     assert (model.remat_kept_calls, model.remat_kept_attn_calls,
             model.remat_kept_moe_calls, seen) == (2, 2, 0, jax.devices()[:1])
-
-
-class _Device:
-    def __init__(self, stats):
-        self._stats = stats
-
-    def memory_stats(self):
-        if isinstance(self._stats, Exception):
-            raise self._stats
-        return self._stats
-
-
-@pytest.mark.parametrize("stats, limit", [
-    ([{"bytes_limit": 7}, {"bytes_limit": 5}], 5),
-    ([{"bytes_limit": 7}, None], None),         # the CPU reports none
-    ([{"bytes_limit": 7}, {}], None),
-    ([jax.errors.JaxRuntimeError("described device")], None),
-], ids=["least", "cpu", "no_key", "described"])
-def test_device_bytes_limit(stats, limit):
-    assert llama._device_bytes_limit(map(_Device, stats)) == limit
 
 
 @pytest.mark.parametrize("over", [{}, *EXPERTS.values()],
@@ -685,7 +665,7 @@ def test_worker_summary_counts_the_kept_calls(monkeypatch, limit_gib, kept,
     from theanompi_tpu.workers import bsp_worker
 
     if limit_gib:
-        monkeypatch.setattr(llama, "_device_bytes_limit",
+        monkeypatch.setattr(llama, "device_bytes_limit",
                             lambda devices: limit_gib * GIB)
     res = bsp_worker.run(
         devices=[0], modelfile="theanompi_tpu.models.llama",

@@ -92,6 +92,18 @@ class TMModel:
             return None, None
         return plan.zero1_layout, plan.ef_layout
 
+    #: the devices' ``bytes_limit`` a model's keep rule read in
+    #: compile_iter_fns (None: the runtime gave none, or no such rule)
+    keep_bytes_limit: int | None = None
+
+    def keep_account(self, bytes_limit: int | None) -> dict | None:
+        """The rule's side of the run's memory account
+        (``obs/memory.py``): what a model that sizes what its step
+        keeps by the device's memory decided from and left
+        (``Llama.keep_account``); None for a model without such a
+        rule."""
+        return None
+
     def build_model(self, n_replicas: int = 1) -> None:
         raise NotImplementedError
 

@@ -77,6 +77,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from theanompi_tpu.models.base import TMModel
 from theanompi_tpu.models.data.lm_synthetic import MarkovLMData
+from theanompi_tpu.obs.memory import device_bytes_limit
 from theanompi_tpu.obs.setup import setup_phase
 from theanompi_tpu.ops.attention import (
     FLASH_RESIDUALS,
@@ -129,20 +130,6 @@ REMAT_RESERVE_BYTES = 1 << 30
 # (``Llama._layer``).  A ``jax.checkpoint`` whose policy saves them
 # replays none of the four products (wq, wk, wv, wo)
 ATTN_RESIDUALS = ("attn_q", "attn_k", "attn_v", "attn_block_out")
-
-
-def _device_bytes_limit(devices) -> int | None:
-    """The least ``bytes_limit`` the devices' runtimes report; None
-    where one reports none (the CPU; a described device, which has no
-    runtime to ask)."""
-    limits = []
-    for d in devices:
-        try:
-            stats = d.memory_stats() or {}
-        except jax.errors.JaxRuntimeError:
-            return None
-        limits.append(stats.get("bytes_limit"))
-    return min(limits) if limits and all(limits) else None
 
 
 # -- pure model math (runs on LOCAL shards inside shard_map) ----------------
@@ -1225,15 +1212,24 @@ class Llama(TMModel):
             * self.compute_dtype.itemsize + 2 * picks * 4
         )
 
+    def _kept_bytes(self, n_mlp: int, n_attn: int, n_moe: int) -> int:
+        """Bytes the last ``n_mlp`` dense, ``n_attn`` grouped-query
+        attention and ``n_moe`` dropless expert calls keep on a
+        device."""
+        calls = self._gqa_call_bytes
+        return (
+            n_mlp * self.remat_kept_bytes_per_call
+            + sum(calls[len(calls) - n_attn:])
+            + n_moe * self.remat_kept_moe_bytes_per_call
+        )
+
     @property
     def remat_kept_bytes(self) -> int:
         """Bytes of ``MLP_RESIDUALS``, ``ATTN_RESIDUALS`` and
         ``MOE_RESIDUALS`` the kept calls hold on a device."""
-        calls = self._gqa_call_bytes
-        return (
-            self.remat_kept_calls * self.remat_kept_bytes_per_call
-            + sum(calls[len(calls) - self.remat_kept_attn_calls:])
-            + self.remat_kept_moe_calls * self.remat_kept_moe_bytes_per_call
+        return self._kept_bytes(
+            self.remat_kept_calls, self.remat_kept_attn_calls,
+            self.remat_kept_moe_calls,
         )
 
     def _local_params(self, axis_sizes) -> tuple[int, int]:
@@ -1258,19 +1254,21 @@ class Llama(TMModel):
             nbytes += math.prod(dims) * leaf.dtype.itemsize
         return elems, nbytes
 
-    def step_peak_estimate(self) -> int:
+    def step_peak_terms(self) -> dict[str, int]:
         """Bytes one device holds at the train step's peak when no
         call keeps ``MLP_RESIDUALS``, ``ATTN_RESIDUALS`` or
-        ``MOE_RESIDUALS``, from shapes alone: every local
-        parameter's master, gradient and optimizer state; what each
-        layer call keeps for its replay (its input, the flash kernel's
-        output and logsumexp); the head's live set — one set of local
+        ``MOE_RESIDUALS``, from shapes alone, term by term:
+        ``params_grads_opt`` — every local parameter's master,
+        gradient and optimizer state; ``call_inputs`` and
+        ``flash_outputs`` — what each layer call keeps for its replay
+        (its input; the flash kernel's output and logsumexp);
+        ``head`` — the head's live set: one set of local
         logits and their gradient in compute dtype
         (``tp.dense_unembed_xent``, or one loop body of
         ``tp.exits_unembed_xent`` with the exits' stack and its
         gradient beside it; a streamed head holds a chunk of each).
-        The benchmark's three transformer cells read within 0.45 GiB
-        of it on the chip (``tests/test_flash_remat.py``)."""
+        Their sum is ``step_peak_estimate``; the run's memory account
+        gives them out (``keep_account``)."""
         isz = self.compute_dtype.itemsize
         batch = int(self.config.get("batch_size", 8))
         t_loc = self.seq_len // self.sp
@@ -1301,10 +1299,18 @@ class Llama(TMModel):
         if exits > 1:
             head += 2 * exits * n_tok * self.dim * isz
         calls = self.n_layers * self.ut_steps // self.pp + self.mtp_depth
-        return (
-            param_bytes * (2 + opt_copies) + calls * kept_input
-            + kept_flash + head
-        )
+        return {
+            "params_grads_opt": param_bytes * (2 + opt_copies),
+            "call_inputs": calls * kept_input,
+            "flash_outputs": kept_flash,
+            "head": head,
+        }
+
+    def step_peak_estimate(self) -> int:
+        """The sum of ``step_peak_terms``.  The benchmark's three
+        transformer cells read within 0.45 GiB of it on the chip
+        (``tests/test_flash_remat.py``)."""
+        return sum(self.step_peak_terms().values())
 
     def remat_keep_calls(
         self, bytes_limit: int | None
@@ -1349,6 +1355,43 @@ class Llama(TMModel):
             free // moe_bytes, self.ut_steps * self.layer_kinds.count("moe")
         ) if moe_bytes else 0
         return n_mlp, n_attn, n_moe
+
+    def keep_account(self, bytes_limit: int | None) -> dict | None:
+        """What ``remat_keep_calls`` decides from and leaves at
+        ``bytes_limit``, for the run's memory account
+        (``obs/memory.py``): the limit, ``reserve_bytes``, the
+        estimate's ``terms``, ``kept_bytes`` (``remat_kept_bytes`` at
+        the rule's counts), ``unkept_bytes`` (the residual sets of
+        every eligible call it did not keep, each at its own bytes),
+        ``free_bytes`` (what the third count left) and the
+        ``eligible`` and ``kept`` calls a kind.  None where the rule
+        does not run: no limit, no remat, the pipeline path."""
+        if not (self.remat and bytes_limit) or self.pp > 1:
+            return None
+        terms = self.step_peak_terms()
+        kinds = ("mlp", "attn", "moe")
+        kept = self.remat_keep_calls(bytes_limit)
+        eligible = (
+            self.ut_steps * self.layer_kinds.count("dense")
+            if self.remat_kept_bytes_per_call else 0,
+            len(self._gqa_call_bytes),
+            self.ut_steps * self.layer_kinds.count("moe")
+            if self.remat_kept_moe_bytes_per_call else 0,
+        )
+        kept_bytes = self._kept_bytes(*kept)
+        room = max(
+            bytes_limit - REMAT_RESERVE_BYTES - sum(terms.values()), 0
+        )
+        return {
+            "bytes_limit": int(bytes_limit),
+            "reserve_bytes": REMAT_RESERVE_BYTES,
+            "terms": terms,
+            "kept_bytes": kept_bytes,
+            "unkept_bytes": self._kept_bytes(*eligible) - kept_bytes,
+            "free_bytes": room - kept_bytes,
+            "eligible": dict(zip(kinds, eligible)),
+            "kept": dict(zip(kinds, kept)),
+        }
 
     def _mla_qkv(self, p, xn, pos):
         """Latent attention's projections, ``xn [B, T, D]`` -> ``q, k,
@@ -2281,9 +2324,10 @@ class Llama(TMModel):
         # when the LOCAL vocab is >= 64k; an int pins the chunk
         # count; 0/1 forces the dense head.
         n_xent_chunks = self._n_xent_chunks = self._xent_chunks()
+        self.keep_bytes_limit = device_bytes_limit(mesh.devices.flat)
         (self.remat_kept_calls, self.remat_kept_attn_calls,
          self.remat_kept_moe_calls) = self.remat_keep_calls(
-            _device_bytes_limit(mesh.devices.flat)
+            self.keep_bytes_limit
         )
 
         # expert-sharded leaves exchange differently (see step below);

@@ -3,7 +3,8 @@ bounded flight-recorder; ``export.py``: Perfetto export with counter
 tracks, critical-path attribution), the step-phase profiler
 (``profiler.py``), Prometheus-style metrics text (``metrics.py``),
 the process's compile counter (``compile_meter.py``), a training
-run's set-up phases (``setup.py``), a MoE step's routing counters
+run's set-up phases (``setup.py``) and its memory account
+(``memory.py``), a MoE step's routing counters
 (``routing.py``), a looped decoder's exit counters (``exits.py``), a
 mamba stack's scan counters (``ssm.py``) and an attention gate's
 counters (``gate.py``).
@@ -27,6 +28,11 @@ from theanompi_tpu.obs.setup import (  # noqa: F401
     last_process_phases,
     last_setup_phases,
     setup_phase,
+)
+from theanompi_tpu.obs.memory import (  # noqa: F401
+    MemoryAccount,
+    begin_memory_account,
+    last_memory_account,
 )
 from theanompi_tpu.obs.routing import last_moe_counters  # noqa: F401
 from theanompi_tpu.obs.exits import last_ut_counters  # noqa: F401
@@ -54,9 +60,11 @@ from theanompi_tpu.obs.profiler import (  # noqa: F401
 __all__ = [
     "CompileMeter",
     "DEFAULT_TRACE_SAMPLE",
+    "MemoryAccount",
     "SetupRecord",
     "StepProfile",
     "Tracer",
+    "begin_memory_account",
     "begin_setup",
     "child_context",
     "chrome_trace",
@@ -66,6 +74,7 @@ __all__ = [
     "format_profile",
     "gap_attribution",
     "last_gate_counters",
+    "last_memory_account",
     "last_moe_counters",
     "last_process_phases",
     "last_setup_phases",

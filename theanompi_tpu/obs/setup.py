@@ -85,6 +85,9 @@ class SetupRecord:
         self.t0 = clock()
         self._done: list[dict] = []
         self._stack: list[dict] = []
+        #: called with a phase's full name at its end, inside it (the
+        #: run's memory account samples there, obs/memory.py)
+        self.on_phase_end = None
         self._open(ROOT, self.t0)
 
     @property
@@ -124,6 +127,8 @@ class SetupRecord:
             try:
                 yield
             finally:
+                if self.on_phase_end is not None:
+                    self.on_phase_end(f"{ROOT}.{name}")
                 self._close(self.clock())
 
     def open_phase(self, name: str) -> None:

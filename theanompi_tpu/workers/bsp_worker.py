@@ -17,6 +17,11 @@ import os
 from typing import Any, Sequence
 
 from theanompi_tpu import launcher as _launcher
+from theanompi_tpu.obs.memory import (
+    FIRST_FENCE,
+    SUMMARY,
+    begin_memory_account,
+)
 from theanompi_tpu.obs.setup import begin_setup
 from theanompi_tpu.parallel import (
     ExchangePlan,
@@ -322,6 +327,10 @@ def run(
     mesh = _build_mesh(devices, cfg)
     n_replicas = dp_replicas(mesh)
     n_devices = int(mesh.devices.size)
+    # the run's memory account (obs/memory.py): a sample at the end of
+    # each set-up phase, at the first fence and at the summary
+    memory = begin_memory_account(mesh.devices.flat)
+    setup.on_phase_end = memory.sample
     if n_epochs is not None:
         cfg["n_epochs"] = n_epochs
     elastic_note = (
@@ -432,6 +441,7 @@ def run(
             # the warm-up (and the set-up) ends at the first fence
             if recorder.first_fence_end is not None:
                 setup.close(at=recorder.first_fence_end)
+                memory.sample(FIRST_FENCE)
                 compiled = meter.read()
             return
         if meter.programs == compiled["programs"]:
@@ -539,6 +549,10 @@ def run(
     # give an in-process host its normal SIGTERM semantics back
     _sup.uninstall_preemption_handler()
     setup.close()   # a run that never reached a fence ends it here
+    memory.sample(SUMMARY)      # what a step starts from
+    memory.rule = model.keep_account(model.keep_bytes_limit)
+    if verbose:
+        print(memory.format(), flush=True)
 
     # step-phase profiler (config knob "step_profile", ISSUE 15): one
     # profiled window AFTER training — per-scope decomposition with
@@ -590,6 +604,8 @@ def run(
         model.close_feed()  # park the streaming feed's producer thread
 
     last_val = recorder.val_records[-1] if recorder.val_records else {}
+    rule = memory.rule or {}
+    kept = rule.get("kept", {})
     return {
         "epochs": model.epoch,
         "exch_strategy": exchange.strategy.name,
@@ -602,12 +618,13 @@ def run(
         # attention ones q, k, v and the attention block's output and
         # the last "remat_kept_moe_calls" dropless expert ones their
         # sorted rows with the gate and up products,
-        # "remat_kept_bytes" on a device in all
+        # "remat_kept_bytes" on a device in all: the memory account's
+        # rule, under the names these fields had before it
         "remat_calls": getattr(model, "remat_calls", 0),
-        "remat_kept_calls": getattr(model, "remat_kept_calls", 0),
-        "remat_kept_attn_calls": getattr(model, "remat_kept_attn_calls", 0),
-        "remat_kept_moe_calls": getattr(model, "remat_kept_moe_calls", 0),
-        "remat_kept_bytes": getattr(model, "remat_kept_bytes", 0),
+        "remat_kept_calls": kept.get("mlp", 0),
+        "remat_kept_attn_calls": kept.get("attn", 0),
+        "remat_kept_moe_calls": kept.get("moe", 0),
+        "remat_kept_bytes": rule.get("kept_bytes", 0),
         # the flash kernels' tiles for the model's attention shape
         # ({} where no such kernel runs)
         # (a model whose attention is described layer by layer: a
@@ -661,6 +678,9 @@ def run(
         "resharded": bool(resharded),
         "trace_spans": trace_spans,
         "setup_phases": setup.as_dict(),
+        # the keep rule's terms and what it kept and left, the
+        # runtime's bytes sample by sample (obs/memory.py)
+        "memory": memory.as_dict(),
         # the seconds before this function's entry (obs/setup.py)
         "process_phases": setup.process_phases(),
         "compiles_after_warmup": late_compiles,
