@@ -1,18 +1,20 @@
-"""Time the three flash-attention kernels alone on the chip, one tile
+"""Time the two flash-attention kernels alone on the chip, one tile
 choice after another, and hold them to the dense reference.
 
-    chiprun -- python3 scripts/flash_sweep.py [--parent DIR] [--quick]
+    chiprun -- python3 scripts/flash_sweep.py [--parent DIR] [--grid 0]
 
 Each kernel is timed by the host clock over a chain of calls inside
 one jitted loop (an output feeds the next call, so nothing is elided);
 lines go to ``chiprun_out/flash_sweep.jsonl`` and to the output.
-``--parent DIR`` also times the kernels of a checkout
-(``DIR/theanompi_tpu/ops/attention.py``, same entry points) and
-reports both against the float32 reference.  The shape function
-``ops.attention._flash_tiles`` holds what this sweep chose (PERF.md,
-PR 32, whose own parent had (block_q, block_k) entry points: its rows
-of that table came from an adapter this file no longer carries);
-nothing in the package reads this file.
+``--parent DIR`` also times the backward of a checkout
+(``DIR/theanompi_tpu/ops/attention.py``) at its own tiles and holds
+this tree's gradients to its (the ``check`` lines): a checkout whose
+backward is the one kernel through the same entry point, one from
+before PR 54 (a dK/dV and a dQ kernel, ``_flash_bwd_call(..,
+dkv_tiles, dq_tiles, ..)``) as the ``pair`` and each of the two alone.
+The shape function ``ops.attention._flash_tiles`` holds what this
+sweep chose (PERF.md, PR 32 and PR 54); nothing in the package reads
+this file.
 """
 import argparse
 import importlib.util
@@ -43,47 +45,70 @@ def _time(fn, *args):
     return best / CHAIN * 1e3
 
 
-def timers(mod, causal, sm):
-    """{kernel: f(tiles, q, k, v, g, lse, delta) -> ms a call} for the
-    kernels of ``mod`` (an ``ops.attention``)."""
-
-    def fwd(tiles, q, k, v, g, lse, delta):
-        step = lambda _, q: mod._flash_fwd_call(  # noqa: E731
-            q, k, v, causal, sm, tiles, False)[0]
-        return _time(jax.jit(lambda q: jax.lax.fori_loop(0, CHAIN, step, q)), q)
-
-    def bwd(which):
-        def run(tiles, q, k, v, g, lse, delta):
-            # XLA drops the kernel whose result is unused
-            both = lambda q, k, v: mod._flash_bwd_call(  # noqa: E731
-                q, k, v, g, lse, delta, causal, sm,
-                tiles if which == "dkv" else _UNTIMED,
-                tiles if which == "dq" else _UNTIMED, False)
-            if which == "dkv":
-                step = lambda _, kv: both(q, *kv)[1:]  # noqa: E731
-                chain = jax.jit(lambda k, v: jax.lax.fori_loop(0, CHAIN, step, (k, v)))
-                return _time(chain, k, v)
-            step = lambda _, q: both(q, k, v)[0]  # noqa: E731
-            return _time(jax.jit(lambda q: jax.lax.fori_loop(0, CHAIN, step, q)), q)
-        return run
-
-    return dict(fwd=fwd, dkv=bwd("dkv"), dq=bwd("dq"))
+def _chain(step, carried, fixed):
+    """ms a call of ``step(*carried, *fixed) -> new carried``, chained.
+    Every operand is an argument of the jitted loop: one closed over
+    would be compiled in as a constant of hundreds of megabytes."""
+    loop = jax.jit(lambda carried, fixed: jax.lax.fori_loop(
+        0, CHAIN, lambda _, x: tuple(step(*x, *fixed)), carried))
+    return _time(loop, carried, fixed)
 
 
-_UNTIMED = A.FlashTiles(512, 512, 512)   # tiles of the backward kernel not being timed
+def backward(mod, plan, causal, sm, window=None):
+    """``f(q, k, v, g, lse, delta) -> (dq, dk, dv)`` of ``mod`` (an
+    ``ops.attention``) under ``plan``; a checkout from before PR 54
+    runs its pair, under its own entry point."""
+    if "bwd" in mod.FlashPlan._fields:
+        return lambda *a: mod._flash_bwd_call(*a, causal, sm, plan, False, window)
+    return lambda *a: mod._flash_bwd_call(
+        *a, causal, sm, plan.dkv, plan.dq, False, window)
 
 
-def check(mod, shape, seed):
-    """Max abs error of out / dq / dk / dv of ``mod``'s kernels
-    against dense float32 attention on the same bf16 inputs."""
+def timers(mod, causal, sm, window=None):
+    """{kernel: f(plan, q, k, v, g, lse, delta) -> ms a call}: ``fwd``,
+    ``bwd`` (the whole backward: one kernel, or a pair) and, of a
+    pair, ``dkv`` and ``dq`` alone (XLA drops the kernel whose result
+    is unused)."""
+
+    def run(plan):
+        return backward(mod, plan, causal, sm, window)
+
+    return dict(
+        fwd=lambda plan, q, k, v, *rest: _chain(
+            lambda q, k, v: mod._flash_fwd_call(
+                q, k, v, causal, sm, plan.fwd, False, window)[:1], (q,), (k, v)),
+        bwd=lambda plan, q, k, v, *rest: _chain(run(plan), (q, k, v), rest),
+        dkv=lambda plan, q, k, v, *rest: _chain(
+            lambda k, v, q, *r: run(plan)(q, k, v, *r)[1:], (k, v), (q, *rest)),
+        dq=lambda plan, q, k, v, *rest: _chain(
+            lambda q, *r: run(plan)(q, *r)[:1], (q,), (k, v, *rest)),
+    )
+
+
+def _draw(shape, seed):
     ks = jax.random.split(jax.random.key(seed), 4)
-    q, k, v, g = (jax.random.normal(kk, shape, jnp.float32).astype(jnp.bfloat16) for kk in ks)
+    return tuple(jax.random.normal(kk, shape, jnp.float32).astype(jnp.bfloat16) for kk in ks)
+
+
+def check(mod, shape, seed, window=None):
+    """Max abs error of out / dq / dk / dv of ``mod``'s kernels against
+    dense float32 attention on the same bf16 inputs, and the arrays."""
+    q, k, v, g = _draw(shape, seed)
     f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
-    ref, vjp = jax.vjp(lambda q, k, v: A.mha_reference(q, k, v, causal=True), f32(q), f32(k), f32(v))
+    ref, vjp = jax.vjp(
+        lambda q, k, v: A.mha_reference(q, k, v, causal=True, window=window),
+        f32(q), f32(k), f32(v))
     want = (ref,) + vjp(f32(g))
-    out, vjp = jax.vjp(lambda q, k, v: mod.flash_attention_tpu(q, k, v, causal=True), q, k, v)
+    out, vjp = jax.vjp(
+        lambda q, k, v: mod.flash_attention_tpu(q, k, v, causal=True, window=window),
+        q, k, v)
     got = (out,) + vjp(g)
-    return {n: float(jnp.max(jnp.abs(f32(a) - b))) for n, a, b in zip(("out", "dq", "dk", "dv"), got, want)}, got
+    return _errors(got, want), got
+
+
+def _errors(xs, ys):
+    return {n: float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))))
+            for n, a, b in zip(("out", "dq", "dk", "dv"), xs, ys)}
 
 
 def mxu_probe():
@@ -112,11 +137,24 @@ def mxu_probe():
         vs_bf16_lhs_only=err(rne(a64) @ b64), scale=float(np.max(np.abs(a64 @ b64))))
 
 
+# (batch-heads, T, head dim, window): the hybrid cell's call, the
+# Mistral, OLMoE and Ouro cells', GLM's, Laguna's full layers, Mellum's
+# and Laguna's window layers
+SHAPES = [
+    (32, 8192, 64, None), (64, 4096, 128, None), (40, 8192, 256, None),
+    (48, 8192, 128, None), (64, 8192, 128, 1024), (72, 8192, 128, 512),
+]
+# held to dense attention (and to ``--parent``'s): every head dim the
+# cells hand the kernels, and a band
+CHECKS = [(2048, 64, None), (2048, 128, None), (2048, 256, None), (2048, 128, 512)]
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--parent", default=None)
-    ap.add_argument("--quick", action="store_true")
-    ap.add_argument("--kernels", default="fwd,dkv,dq")
+    ap.add_argument("--grid", default="0,1,2,3",
+                    help="indices into SHAPES of the shapes also swept over a "
+                         "grid of tiles ('' for none)")
     ap.add_argument("--out", default="chiprun_out/flash_sweep.jsonl")
     args = ap.parse_args()
     os.makedirs(os.path.dirname(args.out), exist_ok=True)
@@ -137,45 +175,53 @@ def main():
         parent = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(parent)
 
-    for seed in (1, 2):
-        new, got_new = check(A, (1, 4, 1024, 128), seed)
-        emit(check="change", seed=seed, **new)
+    for (t, d, window), seed in itertools.product(CHECKS, (1, 2)):
+        line = dict(shape=[4, t, d], window=window, seed=seed)
+        new, got_new = check(A, (1, 4, t, d), seed, window)
+        emit(check="change", **line, **new)
         if parent:
-            old, got_old = check(parent, (1, 4, 1024, 128), seed)
-            emit(check="parent", seed=seed, **old)
-            emit(check="change_vs_parent", seed=seed, **{
-                n: float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))))
-                for n, a, b in zip(("out", "dq", "dk", "dv"), got_new, got_old)})
+            old, got_old = check(parent, (1, 4, t, d), seed, window)
+            emit(check="parent", **line, **old)
+            emit(check="change_vs_parent", **line, **_errors(got_new, got_old))
 
-    shape = (2, 32, 4096, 128)
-    kq = jax.random.split(jax.random.key(0), 4)
-    q, k, v, g = (jax.random.normal(kk, shape, jnp.float32).astype(jnp.bfloat16) for kk in kq)
-    lse = jnp.full(shape[:3], 8.0, jnp.float32)
-    delta = jnp.zeros(shape[:3], jnp.float32)
-    sm = shape[-1] ** -0.5
-    operands = (q, k, v, g, lse, delta)
+    grid = {int(i) for i in args.grid.split(",") if i}
+    for index, (bh, t, d, window) in enumerate(SHAPES):
+        shape = (1, bh, t, d)
+        q, k, v, g = _draw(shape, 0)
+        lse = jnp.full(shape[:3], 8.0, jnp.float32)
+        delta = jnp.zeros(shape[:3], jnp.float32)
+        operands = (q, k, v, g, lse, delta)
+        plan = A._flash_tiles(t, t, d, jnp.bfloat16, window)
+        mine = timers(A, True, d ** -0.5, window)
 
-    tiles_now = {n: tuple(t) for n, t in A._flash_tiles(4096, 4096, 128, jnp.bfloat16)._asdict().items()}
-    if parent:
-        for name, fn in timers(parent, True, sm).items():
-            emit(kernel=name, tree="parent", tiles=list(tiles_now[name]),
-                 ms=fn(A.FlashTiles(*tiles_now[name]), *operands))
-
-    if args.quick:
-        grid = {n: [t] for n, t in tiles_now.items()}
-    else:
-        base = [(r, m, s) for r, m in itertools.product((256, 512, 1024), (1024, 4096))
-                for s in (128, 256, 512) if not (s == 128 and m == 4096)]
-        base += [(1024, 1024, 1024), (512, 2048, 256), (512, 2048, 512), (512, 512, 512), (256, 256, 256)]
-        grid = dict(fwd=base, dkv=base, dq=base)
-    mine = timers(A, True, sm)
-    for name in args.kernels.split(","):
-        for tiles in grid[name]:
+        def timed(kernel, plan, tiles, tree="change", of=mine):
+            line = dict(kernel=kernel, tree=tree, shape=[bh, t, d], window=window,
+                        tiles=list(tiles))
             try:
-                ms = mine[name](A.FlashTiles(*tiles), *operands)
-                emit(kernel=name, tree="change", tiles=list(tiles), ms=ms)
+                emit(ms=of[kernel](plan, *operands), **line)
             except Exception as e:  # a refused tile is a row of the table
-                emit(kernel=name, tree="change", tiles=list(tiles), error=str(e)[:300])
+                emit(error=str(e)[:300], **line)
+
+        timed("fwd", plan, plan.fwd)
+        timed("bwd", plan, plan.bwd)
+        if parent:
+            theirs = parent._flash_tiles(t, t, d, jnp.bfloat16, window)
+            of = timers(parent, True, d ** -0.5, window)
+            if hasattr(theirs, "dkv"):
+                for kernel in ("bwd", "dkv", "dq"):
+                    timed(kernel, theirs, theirs.dq if kernel == "dq" else theirs.dkv,
+                          "parent", of)
+            else:
+                timed("bwd", theirs, theirs.bwd, "parent", of)
+        if index not in grid:
+            continue
+        majors = sorted({m for m in (1024, 2048, 4096, 8192) if m <= t})
+        for rows, major, sub in itertools.product((256, 512, 1024), majors, (256, 512)):
+            tiles = A.FlashTiles(rows, major, sub)
+            timed("bwd", A.FlashPlan(fwd=plan.fwd, bwd=tiles), tiles)
+        for major in majors:
+            tiles = A.FlashTiles(512, major, 512)
+            timed("fwd", A.FlashPlan(fwd=tiles, bwd=plan.bwd), tiles)
 
 
 if __name__ == "__main__":

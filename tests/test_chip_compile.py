@@ -53,20 +53,30 @@ def _compiled_text(fn, *shapes) -> str:
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
-# [B, H, T, hd] after the GQA repeat — what Llama._layer hands the
-# kernel: the proxy (16 heads of 64) and its hd-128 variant (8 of 128)
-# ... and what the hybrid cell hands it: ONE attention layer's 32
-# heads of 64 over 8192 positions (rows of half a register's lanes)
-FLASH_SHAPES = [(4, 16, 2048, 64), (4, 8, 2048, 128), (1, 32, 8192, 64)]
+# ([B, H, T, hd] after the GQA repeat — what Llama._layer hands the
+# kernel —, window): the proxy (16 heads of 64) and its hd-128 variant
+# (8 of 128); what the hybrid cell hands it, ONE attention layer's 32
+# heads of 64 over 8192 positions (rows of half a register's lanes);
+# the Mistral and OLMoE cells' call, GLM's, Mellum's window layers and
+# Laguna's (72 heads under a window as wide as the row block)
+FLASH_SHAPES = [
+    ((4, 16, 2048, 64), None), ((4, 8, 2048, 128), None),
+    ((1, 32, 8192, 64), None), ((2, 32, 4096, 128), None),
+    ((2, 20, 8192, 256), None), ((2, 32, 8192, 128), 1024),
+    ((1, 72, 8192, 128), 512),
+]
 
 
-@pytest.mark.parametrize("shape", FLASH_SHAPES, ids=str)
+@pytest.mark.parametrize("shape,window", FLASH_SHAPES, ids=str)
 @pytest.mark.parametrize("direction", ["forward", "backward"])
-def test_flash_attention_compiles_for_v5e(chip, shape, direction):
+def test_flash_attention_compiles_for_v5e(chip, shape, window, direction):
+    """Mosaic takes the kernels at the cells' shapes — its VMEM and
+    alignment refusals show here — and the backward compiles to ONE
+    custom call that returns three arrays."""
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
 
     def forward(q, k, v):
-        return flash_attention_tpu(q, k, v, causal=True)
+        return flash_attention_tpu(q, k, v, causal=True, window=window)
 
     def backward(q, k, v):
         return jax.grad(
@@ -74,18 +84,22 @@ def test_flash_attention_compiles_for_v5e(chip, shape, direction):
             argnums=(0, 1, 2),
         )(q, k, v)
 
-    fn = forward if direction == "forward" else backward
-    assert "tpu_custom_call" in _compiled_text(fn, x, x, x)
+    text = _compiled_text(forward if direction == "forward" else backward,
+                          x, x, x)
+    kernels = _flash_kernels(text.replace("_flash_window_jit", "_flash_jit"))
+    assert kernels == (dict(fwd=1) if direction == "forward"
+                       else dict(fwd=1, dkv=1))
 
 
-def test_cells_flash_shape_compiles_to_the_three_kernels_the_reader_knows(chip):
+def test_cells_flash_shape_compiles_to_the_two_kernels_the_reader_knows(chip):
     """The benchmark's two transformer cells hand the kernels
     ``[2, 32, 4096, 128]`` bf16 (OLMoE ``[4, 16, ...]``: the same 64
     batch-heads).  Forward and backward in one program compile to
-    exactly three ``_flash_jit`` custom calls, and the benchmark's
+    exactly two ``_flash_jit`` custom calls, and the benchmark's
     ``flash_attention_roofline`` reader tells them apart by what they
-    return — ``(out, f32 logsumexp)``, ``(dk, dv)``, ``dq`` — whatever
-    tiles the shape function chose."""
+    return — ``(out, f32 logsumexp)`` and a tuple without a float32
+    array, ``(dq, dk, dv)``, which it names ``dkv`` (and counts short:
+    PERF.md §7) — whatever tiles the shape function chose."""
     from benchmark import hlo_read
     from benchmark.layer_metrics.flash_attention_roofline import kernel_kind
 
@@ -103,11 +117,49 @@ def test_cells_flash_shape_compiles_to_the_three_kernels_the_reader_knows(chip):
         for line in hlo_read.custom_calls(text).values()
         if "_flash_jit" in line
     )
-    assert kinds == ["dkv", "dq", "fwd"], kinds
-    assert _flash_kernels(text) == dict(fwd=1, dkv=1, dq=1)
+    assert kinds == ["dkv", "fwd"], kinds
+    assert _flash_kernels(text) == dict(fwd=1, dkv=1)
     # the statistics cross the kernels' edge lane-dense: no
     # [B*H, T, 1] array, whose tiles are 128 times its values
     assert "f32[64,4096,1]" not in text and "f32[2,32,4096,1]" not in text
+
+
+def test_glm_flash_shape_compiles_within_its_vmem_limit(chip):
+    """GLM's latent attention hands the kernels ``[2, 20, 8192, 256]``
+    bf16: the backward's float32 dQ sum is 8 MiB and its output block
+    4 MiB twice, over Mosaic's default scope of 16 MiB with the
+    operands beside them, so the call names its own limit
+    (``_bwd_vmem_limit``) and compiles for the v5e within it: two
+    custom calls, the backward's config holding that limit."""
+    import re
+
+    from theanompi_tpu.ops import attention
+
+    x = jax.ShapeDtypeStruct((2, 20, 8192, 256), jnp.bfloat16, sharding=chip)
+
+    def both(q, k, v):
+        out, vjp = jax.vjp(
+            lambda *a: flash_attention_tpu(*a, causal=True), q, k, v
+        )
+        return out, vjp(out)
+
+    text = _compiled_text(both, x, x, x)
+    assert _flash_kernels(text) == dict(fwd=1, dkv=1)
+    limit = attention._bwd_vmem_limit(8192, 256, jnp.bfloat16)
+    assert limit == (16 + 8 + 8) << 20
+    backward = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and "_flash_jit" in line
+        and "f32[" not in line.split("custom-call(")[0]
+    ]
+    assert len(backward) == 1
+    scoped, used = (
+        int(re.search(
+            rf'"{key}":\[{{"memory_space":"1","offset":"0","size":"(\d+)"}}\]',
+            backward[0]).group(1))
+        for key in ("scoped_memory_configs", "used_scoped_memory_configs")
+    )
+    assert scoped == limit and 16 << 20 < used <= limit, (scoped, used)
 
 
 @pytest.mark.parametrize("nq", [1, 4], ids=["decode", "verify4"])
@@ -414,20 +466,20 @@ def test_dense_mlp_backward_feeds_its_products_arrays(chip):
 def _flash_kernels(text):
     """How many flash kernels of each kind a compiled text holds: the
     ``tpu_custom_call``s whose ``op_name`` holds ``_flash_jit``, told
-    apart by what they return, as the benchmark's
-    ``flash_attention_roofline`` does: ``(out, logsumexp)`` is the
-    forward kernel, ``(dk, dv)`` and ``dq`` the two backward ones."""
+    apart by what they return through the benchmark's own
+    ``kernel_kind``: ``(out, logsumexp)`` is the forward kernel, a
+    tuple without a float32 array — ``(dq, dk, dv)`` — the backward
+    one, under the reader's name for it, ``dkv`` (it counts the call
+    as the ``(dk, dv)`` kernel it was written for, so short: PERF.md
+    §7)."""
     import collections
-    import re
 
-    kinds = collections.Counter()
-    for line in text.splitlines():
-        if "tpu_custom_call" not in line or "_flash_jit" not in line:
-            continue
-        result = re.search(r"=\s*(\(.*?\)|\S+)\s+custom-call\(", line).group(1)
-        kinds["dq" if not result.startswith("(")
-              else "fwd" if "f32[" in result else "dkv"] += 1
-    return kinds
+    from benchmark.layer_metrics.flash_attention_roofline import kernel_kind
+
+    return collections.Counter(
+        kernel_kind(line) for line in text.splitlines()
+        if "tpu_custom_call" in line and "_flash_jit" in line
+    )
 
 
 @pytest.mark.parametrize(
@@ -440,8 +492,8 @@ def test_layer_backward_replays_no_flash_forward_kernel(
 ):
     """A ``Llama`` at small widths (256 wide, 2 heads of 128, 2 x 256
     tokens) through ``_forward``, a loss and ``jax.grad``: the
-    compiled text holds three flash kernels a layer — forward, dK/dV,
-    dQ — because the layer's remat keeps ``FLASH_RESIDUALS``.  With
+    compiled text holds two flash kernels a layer — forward and
+    backward — because the layer's remat keeps ``FLASH_RESIDUALS``.  With
     the policy bypassed (full remat: the program before PR 29) the
     same function holds one more forward kernel for every layer whose
     replay XLA did not merge with its forward (it merges the LAST
@@ -488,9 +540,9 @@ def test_layer_backward_replays_no_flash_forward_kernel(
         ), params, ids))
 
     kept, full = kernels(False), kernels(True)
-    assert kept == dict(fwd=n_layers, dkv=n_layers, dq=n_layers)
+    assert kept == dict(fwd=n_layers, dkv=n_layers)
     assert 1 <= full["fwd"] - n_layers <= n_layers, full
-    assert (full["dkv"], full["dq"]) == (n_layers, n_layers), full
+    assert full["dkv"] == n_layers, full
 
 
 def test_looped_decoder_step_compiles_with_its_scopes_and_one_exit_alive(
@@ -499,7 +551,7 @@ def test_looped_decoder_step_compiles_with_its_scopes_and_one_exit_alive(
     """A looped ``Llama`` (2 layers x 3 passes, sandwich norms, 256
     wide, 2 heads of 128, 2 x 256 tokens, vocabulary 4096) through
     ``_forward``, ``_exit_loss`` over the exits' dense head and
-    ``jax.grad``, compiled for the v5e: three flash kernels a layer
+    ``jax.grad``, compiled for the v5e: two flash kernels a layer
     CALL (the layer's remat keeps ``FLASH_RESIDUALS`` in every pass),
     both scopes the benchmark's readers look for in the text, forward
     and backward, the exits' ``[N, V]`` logits never stacked over the
@@ -546,7 +598,7 @@ def test_looped_decoder_step_compiles_with_its_scopes_and_one_exit_alive(
         grad, mesh=mesh, in_specs=(P(), batch, batch), out_specs=P(),
     ), params, ids, ids)
     calls = passes * layers
-    assert _flash_kernels(text) == dict(fwd=calls, dkv=calls, dq=calls)
+    assert _flash_kernels(text) == dict(fwd=calls, dkv=calls)
     for scope in ("jvp(ut_stack)", "transpose(jvp(ut_stack))",
                   "jvp(ut_exit)", "transpose(jvp(ut_exit))"):
         assert scope in text, scope
@@ -724,7 +776,7 @@ def test_hybrid_step_compiles_with_the_mixers_scopes_in_every_phase(
     """A stack with mamba layers through the model's own train step,
     compiled for the v5e: the mixer's block in all three phases beside
     the attention layer's, its four scopes in the text, the ONE
-    attention layer's three flash kernels at head dim 64, the scan's
+    attention layer's two flash kernels at head dim 64, the scan's
     two named kernels under ``blk_ssm/ssd_scan`` in forward, replay
     and backward with no ``[.., chunk, chunk]``-a-head array left
     beside them, every fusion that holds a product under a block, and
@@ -746,7 +798,7 @@ def test_hybrid_step_compiles_with_the_mixers_scopes_in_every_phase(
     for scope in ("ssm_proj", "ssm_conv", "ssd_scan", "ssm_gate_norm"):
         assert f"blk_ssm/{scope}/" in text or f"blk_ssm)/{scope}/" in text
     calls = hlo_read.custom_calls(text)
-    assert len([ln for ln in calls.values() if "_flash_jit" in ln]) == 3
+    assert len([ln for ln in calls.values() if "_flash_jit" in ln]) == 2
     assert {e["block"] for e in kernels.values()} == {"blk_attn", "blk_ssm"}
     # the scan: one forward kernel a mamba layer in the forward and one
     # in the replay, one backward kernel, each under the mixer's block
@@ -830,7 +882,7 @@ def test_held_share_step_compiles_with_its_kernels_and_scopes(
     chip, monkeypatch, n_keep_moe
 ):
     """The cell's kind of step (``_MLA_MOE``: a dense call, an expert
-    call and the MTP module's expert call) compiled for the v5e: three
+    call and the MTP module's expert call) compiled for the v5e: two
     flash kernels a layer CALL at head dim 256 (the remat keeps the
     kernel's outputs in the MTP block too); the grouped kernels of the
     two expert calls against leaves of the 2 experts held, over the
@@ -856,7 +908,7 @@ def test_held_share_step_compiles_with_its_kernels_and_scopes(
 
     text = _llama_step_text(chip, monkeypatch, n_keep_moe=n_keep_moe,
                             **_MLA_MOE)
-    assert _flash_kernels(text) == dict(fwd=3, dkv=3, dq=3)
+    assert _flash_kernels(text) == dict(fwd=3, dkv=3)
     flash = [ln for ln in text.splitlines()
              if "tpu_custom_call" in ln and "_flash_jit" in ln]
     assert all(re.search(r"bf16\[\d+,256,256\]", ln) for ln in flash), flash
@@ -897,7 +949,7 @@ def test_held_share_step_compiles_with_its_kernels_and_scopes(
                 else "bwd" if "transpose(" in ln else "fwd" for ln in lines}
         assert len(seen) == phases, (scope, seen)
     # the MTP module's kernels lie under its scope
-    assert sum("jvp(mtp)" in ln for ln in flash) == 3
+    assert sum("jvp(mtp)" in ln for ln in flash) == 2
 
 
 def test_latent_attention_hands_the_kernels_products_not_joins(
@@ -909,7 +961,7 @@ def test_latent_attention_hands_the_kernels_products_not_joins(
     weight-shaped ones (``wkv_b`` itself, its gradient and its Adam
     state, ``[128, 2 * 448(, 1)]`` or ``[128, 2, 448]``): ``wkv_b``'s
     product is never written whole and cut at lane 192, and its
-    gradient never re-assembled.  The three flash kernels a block
+    gradient never re-assembled.  The two flash kernels a block
     take the operands they took — q, k, v (and the gradients)
     ``[B * H, T, 256]`` — and ``mla_proj``'s instructions, the
     products among them, show in the forward, the replay and the
@@ -928,7 +980,7 @@ def test_latent_attention_hands_the_kernels_products_not_joins(
     assert joined and joined <= {
         f"{rank},{heads * width}", f"{rank},{heads * width},1",
         f"{rank},{heads},{width}"}, joined
-    assert _flash_kernels(text) == dict(fwd=3, dkv=3, dq=3)
+    assert _flash_kernels(text) == dict(fwd=3, dkv=3)
     operand = rf"bf16\[{2 * heads},256,256\]"       # [B * H, T, hd]
     for ln in text.splitlines():
         if "tpu_custom_call" in ln and "_flash_jit" in ln:
@@ -965,7 +1017,7 @@ def test_grouped_query_attention_hands_the_kernels_products_not_relays(
     ``copy`` or a ``transpose`` of a ``[B, H, T, hd]`` activation
     (what is copied there is a weight's bf16 cast or a ``[T, hd]``
     rotary table); the whole text holds no stride-2 ``slice`` and no
-    array of a row's halves; three flash kernels a layer take what
+    array of a row's halves; two flash kernels a layer take what
     they took."""
     import re
 
@@ -974,7 +1026,7 @@ def test_grouped_query_attention_hands_the_kernels_products_not_relays(
     heads, kv_heads, hd, b, t = 4, 2, 128, 2, 256
     text = _llama_step_text(chip, monkeypatch, dim=512, n_heads=heads,
                             n_kv_heads=kv_heads)
-    assert _flash_kernels(text) == dict(fwd=2, dkv=2, dq=2)
+    assert _flash_kernels(text) == dict(fwd=2, dkv=2)
     # ``rope``'s old ``x[..., 0::2]`` / ``x[..., 1::2]``: a stride-2
     # slice, which XLA:TPU runs over arrays of the pairs' halves
     assert not re.findall(r"slice=\{[^}]*\[\d+:\d+:2\][^}]*\}", text)
@@ -1012,10 +1064,10 @@ def _window_kernels(text):
     ))
 
 
-def test_cells_window_shape_compiles_to_three_kernels_of_their_own_name(chip):
+def test_cells_window_shape_compiles_to_two_kernels_of_their_own_name(chip):
     """The ``mellum`` cell's window layers hand the kernels ``[2, 32,
     8192, 128]`` bf16 under a window of 1024.  Forward and backward in
-    one program compile for the v5e to exactly three custom calls
+    one program compile for the v5e to exactly two custom calls
     under ``_flash_window_jit`` — and none whose line holds
     ``_flash_jit``, the name ``flash_attention_roofline`` holds every
     call it matches to the causal triangle's count by — told apart by
@@ -1034,14 +1086,14 @@ def test_cells_window_shape_compiles_to_three_kernels_of_their_own_name(chip):
 
     text = _compiled_text(both, x, x, x)
     calls = hlo_read.custom_calls(text)
-    assert len(calls) == 3
+    assert len(calls) == 2
     assert not any("_flash_jit" in line for line in calls.values())
     kinds = sorted(
         kernel_kind(line) for line in calls.values()
         if "_flash_window_jit" in line
     )
-    assert kinds == ["dkv", "dq", "fwd"], kinds
-    assert _window_kernels(text) == dict(fwd=1, dkv=1, dq=1)
+    assert kinds == ["dkv", "fwd"], kinds
+    assert _window_kernels(text) == dict(fwd=1, dkv=1)
     assert "f32[64,8192,1]" not in text
 
 
@@ -1068,8 +1120,8 @@ def test_mixed_attention_step_compiles_with_each_kinds_kernels_and_scopes(
     chip, monkeypatch
 ):
     """The cell's kind of step compiled for the v5e: the full layer's
-    three flash kernels under ``_flash_jit`` and the window layer's
-    three under ``_flash_window_jit`` (no fourth: the remat keeps both
+    two flash kernels under ``_flash_jit`` and the window layer's
+    two under ``_flash_window_jit`` (no third: the remat keeps both
     kinds' forward outputs), each against operands of the PUBLISHED
     head dim; every kernel, window or full, forward and backward,
     carries ``blk_attn`` and its kind's scope in its own ``op_name``
@@ -1082,11 +1134,11 @@ def test_mixed_attention_step_compiles_with_each_kinds_kernels_and_scopes(
     from benchmark.layer_metrics import _scopes
 
     text = _llama_step_text(chip, monkeypatch, **_WINDOW_MOE)
-    assert _flash_kernels(text) == dict(fwd=1, dkv=1, dq=1)
-    assert _window_kernels(text) == dict(fwd=1, dkv=1, dq=1)
+    assert _flash_kernels(text) == dict(fwd=1, dkv=1)
+    assert _window_kernels(text) == dict(fwd=1, dkv=1)
     flash = [ln for ln in text.splitlines()
              if "tpu_custom_call" in ln and "_flash" in ln]
-    assert len(flash) == 6
+    assert len(flash) == 4
     assert all(re.search(r"bf16\[8,256,128\]", ln) for ln in flash), flash
     for ln in flash:
         op_name = re.search(r'op_name="([^"]*)"', ln).group(1)
@@ -1107,9 +1159,9 @@ def test_mixed_attention_step_compiles_with_each_kinds_kernels_and_scopes(
         seen = {"replay" if "rematted_computation" in ln
                 else "bwd" if "transpose(" in ln else "fwd" for ln in lines}
         assert seen == {"fwd", "replay", "bwd"}, (scope, seen)
-        # three kernels a kind lie under its scope
+        # two kernels a kind lie under its scope
         assert sum("tpu_custom_call" in ln and "_flash" in ln
-                   for ln in lines) == 3, scope
+                   for ln in lines) == 2, scope
 
 
 # three small decoders that take the paths of the Mistral, OLMoE and
@@ -1149,7 +1201,10 @@ def test_older_decoders_lower_to_the_text_they_had(chip, monkeypatch, name):
     functions apart; recorded anew in PR 45, which MEANT to move all
     three: grouped-query attention's products write the kernels'
     layout and the rotation is one pass, so the gathers, scatters and
-    half-row arrays of the stride-2 slices went) is what it was.
+    half-row arrays of the stride-2 slices went; and in PR 54, which
+    MEANT to: one backward kernel a layer call where there were two,
+    so a custom call, its operands' reshapes and the dQ kernel's
+    tiles went from each) is what it was.
     After a change that is MEANT to move one of them, or another jax,
     the assertion shows what moved; record anew with
     ``_text_census``."""
@@ -1173,7 +1228,7 @@ def test_kept_calls_replay_no_gate_or_up_product(chip, monkeypatch, n_keep):
     """The same step with the last ``n_keep`` of its 2 layer calls
     keeping ``MLP_RESIDUALS``: the compiled text holds a gate and an
     up product (a ``[.., 512]`` result under ``blk_ffn``) in the
-    replay of the calls that keep neither, and in no other; the three
+    replay of the calls that keep neither, and in no other; the two
     flash kernels a layer stay."""
     text = _llama_step_text(chip, monkeypatch, n_keep=n_keep)
     replayed = [
@@ -1182,7 +1237,7 @@ def test_kept_calls_replay_no_gate_or_up_product(chip, monkeypatch, n_keep):
         and "blk_ffn" in ln and ",512]" in ln.split(" convolution(")[0]
     ]
     assert len(replayed) == 2 * (2 - n_keep), replayed
-    assert _flash_kernels(text) == dict(fwd=2, dkv=2, dq=2)
+    assert _flash_kernels(text) == dict(fwd=2, dkv=2)
 
 
 @pytest.mark.parametrize("knobs, n_keep_attn", [
@@ -1197,7 +1252,7 @@ def test_kept_attention_calls_replay_no_projection(
     three projections (products under ``gqa_proj`` at the default
     precision; the rotation's own is at the highest) and ``wo`` (a
     ``[B, T, D]`` result under ``blk_attn``) in the replay of the
-    calls that keep none, and in no other; the three flash kernels a
+    calls that keep none, and in no other; the two flash kernels a
     layer stay."""
     text = _llama_step_text(chip, monkeypatch, n_keep_attn=n_keep_attn,
                             dim=512, n_heads=4, n_kv_heads=2, **knobs)
@@ -1212,7 +1267,7 @@ def test_kept_attention_calls_replay_no_projection(
           and "[2,256,512]" in ln.split(" convolution(")[0]]
     assert (len(projections), len(wo)) == (
         3 * (2 - n_keep_attn), 2 - n_keep_attn), replayed
-    assert _flash_kernels(text) == dict(fwd=2, dkv=2, dq=2)
+    assert _flash_kernels(text) == dict(fwd=2, dkv=2)
 
 
 def test_classifier_step_names_conv_and_batch_norm(chip, monkeypatch):
@@ -1323,7 +1378,7 @@ def test_gated_step_compiles_with_each_kinds_heads_and_the_gates_scope(
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     full = [ln for ln in calls if "jit(_flash_jit)" in ln]
     band = [ln for ln in calls if "jit(_flash_window_jit)" in ln]
-    assert len(full) == 2 * 3 and len(band) == 3
+    assert len(full) == 2 * 2 and len(band) == 2
     assert all(re.search(r"bf16\[4,256,128\]", ln) for ln in full), full
     assert all(re.search(r"bf16\[8,256,128\]", ln) for ln in band), band
     assert all("attn_full" in ln for ln in full)

@@ -1,5 +1,7 @@
-"""The three flash-attention kernels against dense attention, in the
-Pallas interpreter, over the tilings their shape function can choose:
+"""The two flash-attention kernels (forward; the one backward kernel
+that builds each score tile once for dQ, dK and dV) against dense
+attention, in the Pallas interpreter, over the tilings their shape
+function can choose:
 one block, several outer blocks, several score tiles inside a block —
 so that every case set holds a tile wholly under the diagonal (the
 body without a mask), one the diagonal crosses and one that is
@@ -37,7 +39,7 @@ from theanompi_tpu.ops.attention import (
 
 B, H = 1, 2
 
-# (T_q, T_k, (rows, major, sub) of forward and dQ, of dK/dV)
+# (T_q, T_k, (rows, major, sub) of the forward, of the backward)
 TILINGS = {
     "one_block": (32, 32, (32, 32, 32), (32, 32, 32)),
     "outer_blocks": (64, 64, (16, 16, 16), (16, 16, 16)),
@@ -192,13 +194,13 @@ def _kernels(q, k, v, g, causal, on_q, on_k, window=None):
     )
     dq, dk, dv = _flash_bwd_call(
         q, k, v, g, lse, delta, causal, sm,
-        FlashTiles(*on_k), FlashTiles(*on_q), True, window,
+        FlashPlan(FlashTiles(*on_q), FlashTiles(*on_k)), True, window,
     )
     return out, lse, dq, dk, dv
 
 
-def _assert_close(got, want, label):
-    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), got, want):
+def _assert_close(got, want, label, names=("out", "lse", "dq", "dk", "dv")):
+    for name, a, b in zip(names, got, want):
         tol = 2e-5 if name in ("out", "lse") else 2e-4
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=tol, atol=tol,
@@ -232,9 +234,97 @@ def test_window_kernels_match_dense_attention(rng, tiling, window):
                       (tiling, "no window"))
 
 
+def _gradients(q, k, v, g, causal, window=None, **blocks):
+    """(dq, dk, dv) of ``flash_attention_tpu`` in the interpreter and of
+    dense attention: the shape function's own plan, or ``blocks``."""
+    _, vjp = jax.vjp(
+        lambda q, k, v: flash_attention_tpu(
+            q, k, v, causal=causal, window=window, interpret=True, **blocks),
+        q, k, v,
+    )
+    _, dense = jax.vjp(
+        lambda q, k, v: mha_reference(q, k, v, causal=causal, window=window),
+        q, k, v,
+    )
+    return vjp(g), dense(g)
+
+
+@pytest.mark.parametrize("d", [64, 128, 256], ids=lambda d: f"hd{d}")
+@pytest.mark.parametrize(
+    "causal,window",
+    [(True, None), (True, 512), (True, 1024), (False, None)],
+    ids=["causal", "w512", "w1024", "full"],
+)
+def test_backward_kernel_at_the_shape_functions_tiles(rng, causal, window, d):
+    """The one backward kernel under the tiles ``_flash_tiles`` gives
+    the cells (512 rows, a walked block of several score tiles, the
+    band's two or three steps a key block) at T 2048, two batch-heads
+    (the dQ scratch is one head's: the second has to find it zeroed),
+    every head dim the cells hand it: dQ summed over four key blocks
+    in the scratch, dK and dV over the walked queries."""
+    q, k, v, g = _operands(rng, 2048, 2048, d)
+    plan = _flash_tiles(2048, 2048, d, q.dtype, window)
+    assert plan.bwd.rows == 512 and 2048 // plan.bwd.rows == 4
+    got, want = _gradients(q, k, v, g, causal, window)
+    _assert_close(got, want, (causal, window, d), ("dq", "dk", "dv"))
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize(
+    "t_q,t_k", [(1024, 2048), (2048, 1024), (1536, 512)], ids=str,
+)
+def test_backward_kernel_where_queries_and_keys_differ(rng, t_q, t_k, causal):
+    """Ring attention's visiting block and a decoder's prefix: the dQ
+    scratch is ``[T_q, d]`` whatever ``T_k``, and under causality a
+    key block no query sees (``T_k > T_q``) folds nothing into it."""
+    q, k, v, g = _operands(rng, t_q, t_k, 128)
+    got, want = _gradients(q, k, v, g, causal)
+    _assert_close(got, want, (t_q, t_k, causal), ("dq", "dk", "dv"))
+
+
+@pytest.mark.parametrize(
+    "t,blocks", [
+        (60, dict(block_q=20, block_k=12)),
+        (60, dict(block_q=12, block_k=20, bwd_block_q=30, bwd_block_k=10)),
+        (68, {}),                       # no aligned block: the whole axes
+        (72, dict(bwd_block_q=24, bwd_block_k=36)),
+    ], ids=str,
+)
+@pytest.mark.parametrize("window", [None, 17], ids=["causal", "w17"])
+@pytest.mark.parametrize("d", [64, 128], ids=["hd64", "hd128"])
+def test_backward_kernel_under_ragged_explicit_blocks(
+    rng, d, t, blocks, window
+):
+    """Blocks no vector register tiles (the interpreter runs them; the
+    chip is never handed one): the scratch's rows are addressed by the
+    walked block's first query whatever its size."""
+    q, k, v, g = _operands(rng, t, t, d)
+    got, want = _gradients(q, k, v, g, True, window, **blocks)
+    _assert_close(got, want, (t, blocks, window), ("dq", "dk", "dv"))
+
+
+def test_backward_refuses_a_dq_sum_vmem_cannot_hold():
+    """The one limit of the one-kernel backward, told where it is met
+    and not deep in Mosaic: a call whose float32 dQ sum and output
+    block take it past ``_VMEM_MOST`` (64k queries at head dim 128 in
+    bf16 are the last that fit; a row under a register's lanes counts
+    as the 128 lanes VMEM gives it)."""
+    for t, d in ((65536, 128), (32768, 256), (65536, 64)):
+        assert attention._bwd_vmem_limit(t, d, "bfloat16") == attention._VMEM_MOST
+    x = jax.ShapeDtypeStruct((1, 1, 131072, 128), jnp.bfloat16)
+    stat = jax.ShapeDtypeStruct((1, 1, 131072), jnp.float32)
+    plan = _flash_tiles(131072, 131072, 128, "bfloat16")
+    with pytest.raises(ValueError, match="shard the sequence"):
+        jax.eval_shape(
+            lambda q, k, v, g, lse, delta: _flash_bwd_call(
+                q, k, v, g, lse, delta, True, 0.1, plan, False),
+            x, x, x, x, stat, stat,
+        )
+
+
 @pytest.mark.parametrize("tiling,window", BAND_CASES, ids=str)
 def test_the_walk_visits_exactly_the_bands_tiles(tiling, window):
-    """For each of the three kernels the score tiles the walk folds
+    """For each of the two kernels the score tiles the walk folds
     (``walked_tiles``: the kernels' own ``_band`` and
     ``_sub_block_kind`` on integers) are exactly those that hold a
     visible pair of the explicit ``[T_q, T_k]`` mask — none outside
@@ -304,13 +394,14 @@ def test_bf16_gradients_stay_within_rounding_of_the_parents(rng, tiling):
 
 
 # every (T, head_dim, dtype) the repo's models and tests hand the
-# shape function: the cells (4096 x 128), chip_smoke's proxy and the
+# shape function: the cells (4096 x 128, 8192 x 64 / 128 / 256),
+# chip_smoke's proxy and the
 # chip-less compiles (2048 x 64 / 128, 256 x 128), the serving
 # decoder's prefill buckets, ring shards, the float32 test lengths
 MODEL_SHAPES = [
     (t, d, dtype)
     for dtype in ("bfloat16", "float32")
-    for d in (16, 64, 128)
+    for d in (16, 64, 128, 256)
     for t in (8, 16, 24, 32, 40, 60, 64, 68, 96, 128, 192, 256, 512, 640,
               1000, 1024, 1280, 2048, 3000, 4096, 8192, 16384, 32768)
 ]
@@ -324,6 +415,14 @@ def test_shape_function(monkeypatch, t, d, dtype):
         assert plan is None                    # the dense path, as before
         return
     sublane = 8 if dtype == "float32" else 16
+    # the backward is the one kernel at every shape (its form is the
+    # plan's: ``fwd`` and ``bwd``, head dim 64 — the hybrid cell's —
+    # among them), over a walked block that counts a row as VMEM
+    # holds it: at least a register's lanes wide
+    assert plan._fields == ("fwd", "bwd")
+    assert plan.bwd.major * max(d, 128) * np.dtype(dtype).itemsize <= (
+        1 << 20) or plan.bwd.major == 128
+    assert plan.bwd == plan.fwd or d < 128
     for rows, major, sub in plan:
         assert t % rows == 0 and t % major == 0 and major % sub == 0
         for block in (rows, major, sub):
@@ -353,10 +452,10 @@ def test_summary_counts_the_masked_tiles(monkeypatch):
     monkeypatch.setattr(
         attention, "_flash_tiles",
         lambda *a: FlashPlan(FlashTiles(512, 4096, 512),
-                             FlashTiles(512, 4096, 256),
-                             FlashTiles(1024, 1024, 1024)),
+                             FlashTiles(512, 4096, 256)),
     )
     got = flash_tiles_summary(4096, 4096, 128, "bfloat16")
+    assert set(got) == {"fwd", "bwd"}           # the kernels a shape runs
     assert got["fwd"] == {
         # 8 row blocks see 1..8 tiles of 512 keys: 36, one crossed each
         "outer": [512, 4096], "inner": [512, 512], "tiles": 36,
@@ -364,9 +463,10 @@ def test_summary_counts_the_masked_tiles(monkeypatch):
     }
     # 8 key blocks are seen by 2, 4, .. 16 tiles of 256 queries, two
     # of them crossed by the diagonal
-    assert got["dkv"]["masked_share"] == round(16 / 72, 4)
-    # the parent's tiles: 4 of the 10 visited carry the diagonal
-    assert got["dq"]["masked_share"] == 0.4
+    assert got["bwd"] == {
+        "outer": [512, 4096], "inner": [512, 256], "tiles": 72,
+        "masked_share": round(16 / 72, 4),
+    }
 
 
 def test_summary_counts_the_bands_tiles(monkeypatch):
@@ -375,7 +475,8 @@ def test_summary_counts_the_bands_tiles(monkeypatch):
     the first two blocks one and two; a fetched block is a tile."""
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     got = flash_tiles_summary(8192, 8192, 128, "bfloat16", window=1024)
-    for kernel in ("fwd", "dkv", "dq"):
+    assert set(got) == {"fwd", "bwd"}
+    for kernel in ("fwd", "bwd"):
         assert got[kernel] == {
             "outer": [512, 512], "inner": [512, 512],
             "tiles": 1 + 2 + 14 * 3,
@@ -383,7 +484,9 @@ def test_summary_counts_the_bands_tiles(monkeypatch):
         }, kernel
     plan = _flash_tiles(8192, 8192, 128, "bfloat16", 1024)
     assert _band_steps(8192, 8192, plan.fwd, True, 1024) == 3
-    assert _band_steps(8192, 8192, plan.dkv, False, 1024) == 3
+    assert _band_steps(8192, 8192, plan.bwd, False, 1024) == 3
+    # the same band at the hybrid cell's head dim
+    assert flash_tiles_summary(8192, 8192, 64, "bfloat16", window=1024) == got
     # the full layer beside them walks the triangle: 136 tiles
     full = flash_tiles_summary(8192, 8192, 128, "bfloat16")
     assert full["fwd"]["tiles"] == 16 * 17 // 2
@@ -409,5 +512,11 @@ def test_worker_summary_names_the_tiles(monkeypatch):
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     tiles = Llama(dict(config, seq_len=4096, dim=256)).flash_tiles()
-    assert set(tiles) == {"fwd", "dkv", "dq"}
+    assert set(tiles) == {"fwd", "bwd"}
     assert all(0 < k["masked_share"] < 0.5 for k in tiles.values())
+    # the hybrid cell's shape: the forward walks the whole axis, the
+    # backward half of it a grid step (PERF.md, PR 54)
+    tiles = Llama(dict(config, seq_len=8192, dim=128,
+                       compute_dtype="bfloat16")).flash_tiles()
+    assert (tiles["fwd"]["outer"], tiles["bwd"]["outer"]) == (
+        [512, 8192], [512, 4096])

@@ -1,7 +1,7 @@
 """What the per-layer remat keeps, and that keeping it skips a kernel.
 
-A layer's attention needs three kernels: forward, dK/dV, dQ.  Under
-``jax.checkpoint`` with no policy the backward runs a fourth, the
+A layer's attention needs two kernels: forward and backward.  Under
+``jax.checkpoint`` with no policy the backward runs a third, the
 forward kernel again, because the backward rule of ``_flash`` reads
 the forward rule's own ``out`` and ``lse``.  ``ops/attention.py``
 names those two (``FLASH_RESIDUALS``) and ``Llama._forward`` saves
@@ -113,7 +113,7 @@ def test_policy_drops_the_replayed_forward_kernel(causal, t_k):
     layer, args = _layer(causal, t_k), _inputs()
     kept = jax.make_jaxpr(_grad(layer, _model_policy()))(*args)
     full = jax.make_jaxpr(_grad(layer, None))(*args)
-    assert (_count(kept.jaxpr), _count(full.jaxpr)) == (3, 4)
+    assert (_count(kept.jaxpr), _count(full.jaxpr)) == (2, 3)
 
 
 @CASES
@@ -132,11 +132,11 @@ def test_a_name_after_the_call_skips_nothing():
     still runs again, for the logsumexp.  Do not put it back."""
     layer, args = _layer(True, T, name_after_call="attn_out"), _inputs()
     policy = jax.checkpoint_policies.save_only_these_names("attn_out")
-    assert _count(jax.make_jaxpr(_grad(layer, policy))(*args).jaxpr) == 4
+    assert _count(jax.make_jaxpr(_grad(layer, policy))(*args).jaxpr) == 3
     both = jax.checkpoint_policies.save_only_these_names(
         "attn_out", *FLASH_RESIDUALS
     )
-    assert _count(jax.make_jaxpr(_grad(layer, both))(*args).jaxpr) == 3
+    assert _count(jax.make_jaxpr(_grad(layer, both))(*args).jaxpr) == 2
 
 
 TINY = dict(
@@ -155,11 +155,11 @@ def test_model_says_what_its_remat_keeps(remat):
 
 @pytest.mark.parametrize(
     "over,per_layer",
-    [({}, 3), ({"remat": False}, 3), ({"saves": ()}, 4),
-     ({"n_experts": 4, "moe_top_k": 2, "capacity_factor": None}, 3)],
+    [({}, 2), ({"remat": False}, 2), ({"saves": ()}, 3),
+     ({"n_experts": 4, "moe_top_k": 2, "capacity_factor": None}, 2)],
     ids=["remat", "no_remat", "policy_bypassed", "moe"],
 )
-def test_train_step_runs_three_flash_kernels_a_layer(
+def test_train_step_runs_two_flash_kernels_a_layer(
     monkeypatch, over, per_layer
 ):
     """The model's own train step, traced with the kernel path taken

@@ -391,14 +391,15 @@ def test_the_cells_window_is_the_kernels_row_block(monkeypatch):
     for tiles in plan:
         assert tuple(tiles) == (512, 512, 512)
     assert attention._band_steps(8192, 8192, plan.fwd, True, 512) == 2
-    assert attention._band_steps(8192, 8192, plan.dkv, False, 512) == 2
+    assert attention._band_steps(8192, 8192, plan.bwd, False, 512) == 2
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     cell = Llama(dict(program_knobs(CONFIG), n_train=2, n_val=1))
     tiles = cell.flash_tiles()
-    for kernel in ("fwd", "dkv", "dq"):
+    for kernel in ("fwd", "bwd"):
         assert tiles["sliding_attention"][kernel] == {
             "outer": [512, 512], "inner": [512, 512], "tiles": 31,
             "masked_share": 1.0}
+    assert set(tiles["full_attention"]) == {"fwd", "bwd"}
     assert tiles["full_attention"]["fwd"]["tiles"] == 136
 
 

@@ -534,6 +534,7 @@ def test_summary_names_the_mechanisms(monkeypatch):
     assert tiles["sliding_attention"]["fwd"]["tiles"] == 45
     assert tiles["full_attention"]["fwd"]["tiles"] == 136
     assert tiles["sliding_attention"]["fwd"]["outer"] == [512, 512]
+    assert all(set(kind) == {"fwd", "bwd"} for kind in tiles.values())
     # and the plain models keep the flat summary
     assert set(Llama(dict(seq_len=4096, dim=1024)).flash_tiles()) == {
-        "fwd", "dkv", "dq"}
+        "fwd", "bwd"}

@@ -1744,7 +1744,7 @@ class Llama(TMModel):
             # every call's replay skips the flash forward kernel: its
             # output and logsumexp (named in its forward rule,
             # ``ops/attention.py``) are kept, [B, H_loc, T, hd] and 4
-            # bytes a row, so the backward runs dK/dV and dQ only.
+            # bytes a row, so the backward runs the backward kernel only.
             # Where the kernel does not run (dense path;
             # ``ring_attention``, whose own vjp calls the kernels
             # unnamed) the names never occur.  A dropless expert

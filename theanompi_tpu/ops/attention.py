@@ -13,7 +13,8 @@ for the long-context configs).  Design:
   the ring-attention loop (``parallel/ring_attention.py``) and any
   sequential blockwise scan share this exact function, so cross-device
   ring results match single-device attention bit-for-bit in fp32.
-- ``flash_attention`` — fused Pallas kernels (forward, dK/dV, dQ: a
+- ``flash_attention`` — fused Pallas kernels (forward, and ONE
+  backward that builds each score tile once for dQ, dK and dV: a
   block of rows resident, the other axis walked in [rows, sub] score
   tiles, f32 accumulators in scratch, tiles chosen from the shapes by
   ``_flash_tiles``) with the same signature; ``mha_reference``
@@ -147,8 +148,8 @@ def block_attn_finish(carry, dtype):
 # Pallas TPU flash attention
 # ---------------------------------------------------------------------------
 #
-# Three kernels: forward, dK/dV, dQ.  Each keeps a block of ``rows``
-# resident (queries in the forward and dQ, keys in dK/dV), fetches the
+# Two kernels: forward and backward.  Each keeps a block of ``rows``
+# resident (queries in the forward, keys in the backward), fetches the
 # other axis ``major`` positions a grid step and folds that block in
 # ``sub`` positions at a time, so the live score tile is [rows, sub]
 # however large the fetched block.  Under causality a sub-block wholly
@@ -166,17 +167,24 @@ def block_attn_finish(carry, dtype):
 # starts at the band's first block (``_band``): queries walk the keys
 # behind them, keys the queries ahead of them.
 #
-# The forward and dQ hold queries on the tile's sublanes, so their
-# products stream the query block against a latched [sub, d] piece of
-# K or V; dK/dV holds the KEYS there (the tile is the transposed
-# scores, ``k @ q^T``), so its products stream the key block against
-# [sub, d] pieces of Q and dO and none contracts over a tile's rows.
+# The forward holds queries on the tile's sublanes, so its products
+# stream the query block against a latched [sub, d] piece of K or V;
+# the backward holds the KEYS there (the tile is the transposed
+# scores, ``k @ q^T``), so its products for dV and dK stream the key
+# block against [sub, d] pieces of Q and dO, and ONE contracts over
+# the tile's rows: ``ds^T @ k``, the walked queries' dQ, summed over
+# the key blocks in a float32 ``[T_q, d]`` scratch that lives for the
+# batch-head.  One tile serves all three gradients: 5 products a tile
+# (S, dP, dV, dK, dQ), its ``exp``, mask and ``ds`` once (a dK/dV and
+# a dQ kernel, the form until PR 54, computed 7 and both twice).
 # The rows' statistics cross the kernels' edge lane-dense,
-# ``f32[B*H, 1, T]``: dK/dV's tile takes them as rows as they are.
+# ``f32[B*H, 1, T]``: the backward's tile takes them as rows as they
+# are.
 
 _LANES = 128      # lanes of a vector register: width of the statistics' scratch
 _NT = (((1,), (1,)), ((), ()))    # a @ b^T, the matrix unit's native form
 _NN = (((1,), (0,)), ((), ()))    # a @ b
+_TN = (((0,), (0,)), ((), ()))    # a^T @ b: Mosaic transposes the tile
 
 
 class FlashTiles(NamedTuple):
@@ -189,10 +197,9 @@ class FlashTiles(NamedTuple):
 
 
 class FlashPlan(NamedTuple):
-    """The three kernels' tilings for one attention shape."""
+    """The two kernels' tilings for one attention shape."""
     fwd: FlashTiles
-    dkv: FlashTiles
-    dq: FlashTiles
+    bwd: FlashTiles
 
 
 def _scaled(x, sm_scale):
@@ -228,17 +235,6 @@ def _as_row(x):
     wide = jnp.broadcast_to(x[:, :1], (rows, rows))
     return jnp.sum(jnp.where(_diagonal(rows), wide, 0.0), axis=0,
                    keepdims=True)
-
-
-def _as_col(x):
-    """Lane-dense [1, rows] -> lane-replicated [rows, _LANES]."""
-    rows = x.shape[1]
-    if rows % _LANES == 0:
-        return jnp.broadcast_to(x, (_LANES, rows)).T
-    wide = jnp.broadcast_to(x, (rows, rows))
-    col = jnp.sum(jnp.where(_diagonal(rows), wide, 0.0), axis=1,
-                  keepdims=True)
-    return jnp.broadcast_to(col, (rows, _LANES))
 
 
 def _visible(q_start, k_start, shape, q_axis, window=None):
@@ -422,21 +418,30 @@ def _on_tpu() -> bool:
     return default_devices()[0].platform == "tpu"
 
 
-def _flash_bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_acc, dv_acc, *, sm_scale, causal, sub, window=None, n_walked=None
+def _flash_bwd_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+    dq_acc, dk_acc, dv_acc, *, sm_scale, causal, sub, window=None,
+    n_walked=None
 ):
-    """dK/dV for one kv block: grid (bh, kv-block, q-block), the q dim
-    sequential so the [rows, d] accumulators live in scratch.  The
-    tile is the TRANSPOSED scores, keys on its sublanes and ``sub``
-    queries on its lanes: logsumexp and delta come as the rows they
-    are stored as, and both accumulating products are plain
-    ``tile @ [sub, d]``."""
-    qi = pl.program_id(2)
+    """The whole backward for one kv block: grid (bh, kv-block,
+    q-block), both inner dims sequential.  The tile is the TRANSPOSED
+    scores, keys on its sublanes and ``sub`` queries on its lanes:
+    logsumexp and delta come as the rows they are stored as, and it is
+    built ONCE for all three gradients.  dK and dV sum over the walked
+    queries in [rows, d] scratch and leave with their kv block; dQ
+    sums over the kv blocks in a float32 [T_q, d] scratch that lives
+    for the whole batch-head (``ds^T @ k`` into the walked queries'
+    rows of it: the one product that contracts over a tile's rows) and
+    leaves, scaled on the float32 sum, at the batch-head's last cell."""
+    kj, qi = pl.program_id(1), pl.program_id(2)
     rows = k_ref.shape[1]
     major = q_ref.shape[1]
-    k_start = pl.program_id(1) * rows
+    k_start = kj * rows
     q_start, live = _walked_start(window, n_walked, False, rows, major, sub)
+
+    @pl.when((kj == 0) & (qi == 0))
+    def _init_head():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
     @pl.when(qi == 0)
     def _init():
@@ -455,61 +460,25 @@ def _flash_bwd_dkv_kernel(
             )
         dv_acc[...] += _dot(p.astype(do.dtype), do, _NN)    # p^T @ dO
         dp = _dot(v_ref[0], do, _NT)                        # (dO @ V^T)^T
-        ds = p * (dp - delta_ref[0, :, cols])
+        ds = (p * (dp - delta_ref[0, :, cols])).astype(qs.dtype)
         # the scale rides in ``qs``: ds^T @ (scale * Q)
-        dk_acc[...] += _dot(ds.astype(qs.dtype), qs, _NN)
+        dk_acc[...] += _dot(ds, qs, _NN)
+        at = pl.ds(pl.multiple_of(q_start + c * sub, sub), sub)
+        dq_acc[at, :] += _dot(ds, k_ref[0], _TN)            # ds^T @ K
 
     _walk(fold, major // sub, sub, causal, q_start, k_start, rows, False,
           window, live)
 
-    @pl.when(qi == pl.num_programs(2) - 1)
+    last = qi == pl.num_programs(2) - 1
+
+    @pl.when(last)
     def _finish():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
-
-def _flash_bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-    qs_ref, lse_col, delta_col, dq_acc, *, sm_scale, causal, sub,
-    window=None, n_walked=None
-):
-    """dQ for one q block: grid (bh, q-block, kv-block), kv sequential.
-    Queries on the tile's sublanes as in the forward; the block's
-    logsumexp and delta are turned from rows to lane-replicated
-    columns once a q block."""
-    ki = pl.program_id(2)
-    rows = q_ref.shape[1]
-    major = k_ref.shape[1]
-    q_start = pl.program_id(1) * rows
-    k_start, live = _walked_start(window, n_walked, True, rows, major, sub)
-
-    @pl.when(ki == 0)
-    def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
-        qs_ref[...] = _scaled(q_ref[0], sm_scale)
-        lse_col[...] = _as_col(lse_ref[0])
-        delta_col[...] = _as_col(delta_ref[0])
-
-    def fold(c, masked):
-        cols = pl.ds(c * sub, sub)
-        k_blk = k_ref[0, cols, :]
-        s = _dot(qs_ref[...], k_blk, _NT)                   # [rows, sub]
-        p = jnp.exp(s - _lanes(lse_col[...], sub))
-        if masked:
-            p = jnp.where(
-                _visible(q_start, k_start + c * sub, p.shape, 0, window),
-                p, 0.0,
-            )
-        dp = _dot(do_ref[0], v_ref[0, cols, :], _NT)        # dO @ V^T
-        ds = p * (dp - _lanes(delta_col[...], sub))
-        dq_acc[...] += _dot(ds.astype(k_blk.dtype), k_blk, _NN)
-
-    _walk(fold, major // sub, sub, causal, k_start, q_start, rows, True,
-          window, live)
-
-    @pl.when(ki == pl.num_programs(2) - 1)
-    def _finish():
-        # the scale once, on the float32 [rows, d] sum
+    @pl.when(last & (kj == pl.num_programs(1) - 1))
+    def _finish_head():
+        # the scale once, on the float32 [T_q, d] sum
         dq_ref[0] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
 
 
@@ -526,11 +495,13 @@ def _flash_dims(q, k, rows_of_q: bool, tiles: FlashTiles):
     return b, h, t, t_k, d
 
 
-_SEM = lambda *names: pltpu.CompilerParams(  # noqa: E731
-    dimension_semantics=tuple(
-        getattr(pltpu.GridDimensionSemantics, n) for n in names
+def _SEM(*names, vmem_limit_bytes=None):
+    return pltpu.CompilerParams(
+        dimension_semantics=tuple(
+            getattr(pltpu.GridDimensionSemantics, n) for n in names
+        ),
+        vmem_limit_bytes=vmem_limit_bytes,
     )
-)
 
 
 def _walked_index(causal, tiles: FlashTiles, rows_are_queries: bool, n_walked,
@@ -635,16 +606,41 @@ def _flash_fwd(q, k, v, causal, sm_scale, plan, interpret, window):
     return out, (q, k, v, out, lse)
 
 
+# The backward keeps a batch-head's dQ sum and its output block whole
+# in VMEM: ``[T_q, d]`` in float32, and twice in the activations' dtype
+# (Pallas double-buffers an output block), a row of either as wide as
+# a register's lanes at least — beside what the default scope already
+# holds (the resident and walked blocks, the tiles).  Of a v5e's
+# 128 MiB:
+_VMEM_SCOPE = 16 << 20          # Mosaic's default scoped limit
+_VMEM_MOST = 80 << 20           # the most a backward call may ask for
+
+
+def _bwd_vmem_limit(t_q, head_dim, dtype) -> int:
+    """The scoped VMEM limit a backward call asks for, in bytes."""
+    import numpy as np
+
+    row = max(head_dim, _LANES)
+    return _VMEM_SCOPE + t_q * row * (4 + 2 * np.dtype(dtype).itemsize)
+
+
 def _flash_bwd_call(
-    q, k, v, g, lse, delta, causal, sm_scale, dkv_tiles, dq_tiles, interpret,
-    window=None,
+    q, k, v, g, lse, delta, causal, sm_scale, plan, interpret, window=None,
 ):
-    """Backward kernels against EXPLICIT (lse, delta) residuals
-    (fp32 [B,H,T]).  Factored out of ``_flash_bwd`` so ring
-    attention can run the same kernels per visiting KV block with the
-    GLOBAL logsumexp/delta (the standard ring-attention backward)."""
-    b, h, t, t_k, d = _flash_dims(q, k, False, dkv_tiles)
-    _flash_dims(q, k, True, dq_tiles)
+    """The backward kernel (``plan.bwd``) against EXPLICIT (lse,
+    delta) residuals (fp32 [B,H,T]).  Factored out of ``_flash_bwd``
+    so ring attention can run the same kernel per visiting KV block
+    with the GLOBAL logsumexp/delta (the standard ring-attention
+    backward)."""
+    tiles = plan.bwd
+    b, h, t, t_k, d = _flash_dims(q, k, False, tiles)
+    vmem_limit = _bwd_vmem_limit(t, d, q.dtype)
+    if vmem_limit > _VMEM_MOST:
+        raise ValueError(
+            f"flash backward keeps a batch-head's dQ [{t}, {d}] in VMEM: "
+            f"{vmem_limit} bytes with its block, over {_VMEM_MOST} — shard "
+            f"the sequence (ring attention, sp > 1)"
+        )
     qs = q.reshape(b * h, t, d)
     ks = k.reshape(b * h, t_k, d)
     vs = v.reshape(b * h, t_k, d)
@@ -653,9 +649,9 @@ def _flash_bwd_call(
     delta = delta.reshape(b * h, 1, t)
     vma = jax.typeof(qs).vma
 
-    rows, major, sub = dkv_tiles
-    steps, band = _walked_axis(t_k, t, dkv_tiles, False, window)
-    walked = _walked_index(causal, dkv_tiles, False, t // major, window)
+    rows, major, sub = tiles
+    steps, band = _walked_axis(t_k, t, tiles, False, window)
+    walked = _walked_index(causal, tiles, False, t // major, window)
     q_spec = pl.BlockSpec(
         (1, major, d), lambda i, kj, qi: (i, walked(kj, qi), 0)
     )
@@ -663,57 +659,35 @@ def _flash_bwd_call(
     r_spec = pl.BlockSpec(
         (1, 1, major), lambda i, kj, qi: (i, 0, walked(kj, qi))
     )
-    dk, dv = pl.pallas_call(
+    dq, dk, dv = pl.pallas_call(
         functools.partial(
-            _flash_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal, sub=sub,
+            _flash_bwd_kernel, sm_scale=sm_scale, causal=causal, sub=sub,
             **band,
         ),
         out_shape=(
+            jax.ShapeDtypeStruct((b * h, t, d), q.dtype, vma=vma),
             jax.ShapeDtypeStruct((b * h, t_k, d), k.dtype, vma=vma),
             jax.ShapeDtypeStruct((b * h, t_k, d), v.dtype, vma=vma),
         ),
         grid=(b * h, t_k // rows, steps),
         in_specs=[q_spec, k_spec, k_spec, q_spec, r_spec, r_spec],
-        out_specs=(k_spec, k_spec),
-        scratch_shapes=[
-            pltpu.VMEM((rows, d), jnp.float32),
-            pltpu.VMEM((rows, d), jnp.float32),
-        ],
-        compiler_params=_SEM("PARALLEL", "PARALLEL", "ARBITRARY"),
-        interpret=interpret,
-    )(qs, ks, vs, dos, lse, delta)
-
-    rows, major, sub = dq_tiles
-    steps, band = _walked_axis(t, t_k, dq_tiles, True, window)
-    walked = _walked_index(causal, dq_tiles, True, t_k // major, window)
-    q_spec = pl.BlockSpec((1, rows, d), lambda i, qi, kj: (i, qi, 0))
-    k_spec = pl.BlockSpec(
-        (1, major, d), lambda i, qi, kj: (i, walked(qi, kj), 0)
-    )
-    r_spec = pl.BlockSpec((1, 1, rows), lambda i, qi, kj: (i, 0, qi))
-    dq = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dq_kernel, sm_scale=sm_scale, causal=causal, sub=sub,
-            **band,
+        out_specs=(
+            # one block a batch-head: written at its last cell
+            pl.BlockSpec((1, t, d), lambda i, kj, qi: (i, 0, 0)),
+            k_spec, k_spec,
         ),
-        out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype, vma=vma),
-        grid=(b * h, t // rows, steps),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, r_spec, r_spec],
-        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((rows, d), q.dtype),
-            pltpu.VMEM((rows, _LANES), jnp.float32),
-            pltpu.VMEM((rows, _LANES), jnp.float32),
+            pltpu.VMEM((t, d), jnp.float32),
+            pltpu.VMEM((rows, d), jnp.float32),
             pltpu.VMEM((rows, d), jnp.float32),
         ],
-        compiler_params=_SEM("PARALLEL", "PARALLEL", "ARBITRARY"),
+        # the kv dim carries the dQ scratch, the q dim dK's and dV's
+        compiler_params=_SEM(
+            "PARALLEL", "ARBITRARY", "ARBITRARY", vmem_limit_bytes=vmem_limit
+        ),
         interpret=interpret,
     )(qs, ks, vs, dos, lse, delta)
-    return (
-        dq.reshape(q.shape),
-        dk.reshape(k.shape),
-        dv.reshape(v.shape),
-    )
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 def _flash_bwd(causal, sm_scale, plan, interpret, window, res, g):
@@ -722,8 +696,7 @@ def _flash_bwd(causal, sm_scale, plan, interpret, window, res, g):
     # [B, H, T] like the logsumexp
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     return _flash_bwd_call(
-        q, k, v, g, lse, delta, causal, sm_scale, plan.dkv, plan.dq,
-        interpret, window,
+        q, k, v, g, lse, delta, causal, sm_scale, plan, interpret, window,
     )
 
 
@@ -735,11 +708,11 @@ def flash_attention_tpu(
     bwd_block_q=None, bwd_block_k=None, interpret=False, window=None,
 ):
     """Fused flash attention, fully differentiable (custom_vjp with
-    Pallas dQ and dK/dV kernels — the standard two-kernel backward with
-    the logsumexp residual).  q,k,v: [B, H, T, D].  The kernels' tiles
-    come from the shapes (``_flash_tiles``); explicit blocks are for
-    tests and sweeps and mean un-subdivided blocks of that size — T
-    (and T_k) must then be divisible by them.  ``interpret=True`` runs
+    one Pallas backward kernel for dQ, dK and dV against the logsumexp
+    residual: ``_flash_bwd_kernel``).  q,k,v: [B, H, T, D].  The
+    kernels' tiles come from the shapes (``_flash_tiles``); explicit
+    blocks are for tests and sweeps and mean un-subdivided blocks of
+    that size — T (and T_k) must then be divisible by them.  ``interpret=True`` runs
     the kernels in the Pallas interpreter (any backend; how the tests
     exercise them).  ``window`` (causal only): a query sees itself
     and the ``window - 1`` keys before it; one no query's reach falls
@@ -774,8 +747,7 @@ def flash_attention_tpu(
         bq, bk = min(block_q or t, t), min(block_k or t_k, t_k)
         gq, gk = min(bwd_block_q or bq, t), min(bwd_block_k or bk, t_k)
         plan = FlashPlan(
-            fwd=FlashTiles(bq, bk, bk), dkv=FlashTiles(gk, gq, gq),
-            dq=FlashTiles(gq, gk, gk),
+            fwd=FlashTiles(bq, bk, bk), bwd=FlashTiles(gk, gq, gq)
         )
     if window is not None:
         return _flash_window_jit(
@@ -830,15 +802,22 @@ def _auto_block(t: int, dtype=None) -> int | None:
     return None
 
 
-# What ``_flash_tiles`` aims for, the same in all three kernels: the
+# What ``_flash_tiles`` aims for, the same in both kernels: the
 # sweep on a v5e at the cells' shape, 64 x 4096 x 128 bf16 (PERF.md,
 # PR 32).  Score tiles of [512, 512]: at 256 columns every kernel is
 # 25-40 % slower (twice the folds for the same scores), at 1024 the
-# forward and dQ compute more above the diagonal than they save; 256
-# rows lose 8-14 % to shorter products, 1024 compute a quarter of their
+# forward computes more above the diagonal than it saves; 256 rows
+# lose 8-14 % to shorter products, 1024 compute a quarter of their
 # scores above the diagonal (512: an eighth).  The walked axis whole
 # where a block of it is at most 1 MiB (4096 x 128 bf16): K and V
-# cross HBM once a head, a query block takes one grid step.
+# cross HBM once a head, a query block takes one grid step.  The one
+# backward kernel read the same at 64 x 4096 x 128, 40 x 8192 x 256
+# (PR 53's sweep) and 32 x 8192 x 64 (PERF.md, PR 54) — and TWICE the
+# time wherever ``rows * major``, the scores a grid step unrolls,
+# passed what 512 rows of a 1 MiB block come to: its walked block
+# counts a row as VMEM holds it, a register's lanes wide at least
+# (at head dim 64 the whole 8192-row axis reads 19.2 ms, 4096 rows
+# 9.2).
 _ROWS = 512
 _SUB = 512
 _MAJOR_BYTES = 1 << 20
@@ -856,12 +835,13 @@ def _fit(t: int, cap: int) -> int:
 
 
 def _flash_tiles(t_q, t_k, head_dim, dtype, window=None) -> FlashPlan | None:
-    """The three kernels' tiles for an attention shape, or ``None``
+    """The two kernels' tiles for an attention shape, or ``None``
     where an axis has no aligned block (``_auto_block``: the dense
     path).  One rule for every kernel, from what the kernel can see:
     the resident block is the axis cut to the swept row count, the
     walked block as much of the other axis as the swept byte count
-    holds at this head dim and dtype, folded at the swept tile width.
+    holds at this head dim and dtype (the backward's at a row of a
+    register's lanes at least), folded at the swept tile width.
     Under a ``window`` the walked block is at most half the window
     (and a score tile at least): a row block's band is ``rows + window
     - 1`` positions wherever it lies, and a fetched block that
@@ -873,17 +853,19 @@ def _flash_tiles(t_q, t_k, head_dim, dtype, window=None) -> FlashPlan | None:
 
     if not _auto_block(t_q, dtype) or not _auto_block(t_k, dtype):
         return None
-    row_bytes = head_dim * np.dtype(dtype).itemsize
+    itemsize = np.dtype(dtype).itemsize
 
-    def tiles(t_rows, t_walk):
-        most = max(_MAJOR_BYTES // row_bytes, _LANES)
+    def tiles(t_rows, t_walk, row_width):
+        most = max(_MAJOR_BYTES // (row_width * itemsize), _LANES)
         if window is not None:
             most = min(most, max(window // 2, _SUB))
         major = _fit(t_walk, most)
         return FlashTiles(_fit(t_rows, _ROWS), major, _fit(major, _SUB))
 
-    on_q = tiles(t_q, t_k)
-    return FlashPlan(fwd=on_q, dkv=tiles(t_k, t_q), dq=on_q)
+    return FlashPlan(
+        fwd=tiles(t_q, t_k, head_dim),
+        bwd=tiles(t_k, t_q, max(head_dim, _LANES)),
+    )
 
 
 def walked_tiles(t_rows, t_walk, tiles: FlashTiles, rows_are_queries,
@@ -911,19 +893,19 @@ def walked_tiles(t_rows, t_walk, tiles: FlashTiles, rows_are_queries,
 
 def flash_tiles_summary(t_q, t_k, head_dim, dtype, causal=True,
                         window=None) -> dict:
-    """For the run summary's ``"flash_tiles"``: per kernel the outer
-    tile ``[rows, major]``, the inner ``[rows, sub]``, the score tiles
-    it visits (a batch-head) and the share of them that take the
-    masked body — static, from the shapes.  Empty where
-    ``flash_attention`` takes the dense path (off the TPU, or a length
-    no block tiles)."""
+    """For the run summary's ``"flash_tiles"``: per kernel (``fwd``,
+    and ``bwd``: the one backward kernel) the outer tile ``[rows,
+    major]``, the inner ``[rows, sub]``, the score tiles it visits (a
+    batch-head) and the share of them that take the masked body —
+    static, from the shapes.  Empty where ``flash_attention`` takes
+    the dense path (off the TPU, or a length no block tiles)."""
     window = _binding_window(window, t_q, causal)
     plan = _flash_tiles(t_q, t_k, head_dim, dtype, window)
     if plan is None or not _on_tpu():
         return {}
     out = {}
     for kernel, tiles in plan._asdict().items():
-        on_q = kernel != "dkv"
+        on_q = kernel == "fwd"
         t_rows, t_walk = (t_q, t_k) if on_q else (t_k, t_q)
         visited = walked_tiles(t_rows, t_walk, tiles, on_q, causal, window)
         out[kernel] = {

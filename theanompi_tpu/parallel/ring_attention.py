@@ -116,7 +116,7 @@ def _ring_flash_fwd(q, k, v, axis_name, causal, sm_scale, kv_rep, plan,
 
 def _ring_flash_bwd(axis_name, causal, sm_scale, kv_rep, plan,
                     interpret, res, g):
-    """Ring backward: each hop runs the flash dQ and dK/dV kernels
+    """Ring backward: each hop runs the flash backward kernel
     against the GLOBAL (lse, delta) residuals; dK/dV accumulators
     circulate WITH the KV blocks, so after the full ring each block's
     gradient arrives home with all devices' contributions summed."""
@@ -138,7 +138,7 @@ def _ring_flash_bwd(axis_name, causal, sm_scale, kv_rep, plan,
         visible = _hop_visible(my_idx, src, causal)
         dq_i, dk_i, dv_i = _flash_bwd_call(
             q, _rep(k_cur, kv_rep), _rep(v_cur, kv_rep), g, lse, delta,
-            causal and step == 0, sm_scale, plan.dkv, plan.dq, interpret,
+            causal and step == 0, sm_scale, plan, interpret,
         )
         dq = dq + jnp.where(visible, dq_i.astype(jnp.float32), 0.0)
         dk_cur = dk_cur + jnp.where(
