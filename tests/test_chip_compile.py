@@ -278,6 +278,74 @@ def test_dropless_expert_layer_compiles_to_grouped_kernels(
     assert not any("rematted_computation" in line for line in plan)
 
 
+def test_latent_two_product_expert_layer_compiles_at_its_cells_shapes(
+    chip, monkeypatch
+):
+    """Nemotron-3-Super's expert block as its cell runs it (4096 wide,
+    a 1024 latent, 8 of 512 two-product experts of 2688 held, 22
+    picks, 16384 tokens), with the backward: the grouped products are
+    the repo's kernels at the bound's 11264 rows over ``[1024, 2688]``
+    and ``[2688, 1024]`` blocks — no block whole (5.5 MB), the second
+    contraction cut in three of 896 — none of them a gate product; the
+    dispatch gathers rows of the LATENT's width, never the model's;
+    the two projections stand under ``moe_latent``; and the window of
+    23 MB keeps XLA's gathers for the tokens' sums (no ``held-rows-sum``
+    kernel)."""
+    import re
+
+    from theanompi_tpu.ops import attention
+    from theanompi_tpu.ops import grouped_matmul as gmm
+    from theanompi_tpu.parallel.moe import held_rows_bound, moe_ffn
+
+    e, held, k, d, lat, f, n = 512, 8, 22, 4096, 1024, 2688, 16384
+    rows = held_rows_bound(k * n, held, e)
+    assert rows == 11264
+    assert gmm._rows_tiles(lat, f, jnp.bfloat16) == (1024, 896)
+    assert gmm._rows_tiles(f, lat, jnp.bfloat16) == (896, 1024)
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)  # as on the chip
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def loss(x, router, bias, wu, wd, down, up):
+        y, _ = moe_ffn(
+            x, router, None, wu, wd, n_experts=e, top_k=k,
+            capacity_factor=None, expert_axis=None, model_axis=None,
+            scoring="sigmoid", select_bias=bias, route_scale=5.0,
+            held=held, latent=(down, up),
+        )
+        return jnp.sum(y.astype(jnp.float32))
+
+    text = _compiled_text(
+        jax.value_and_grad(loss, argnums=(0, 3, 4, 5, 6)),
+        sds((2, n // 2, d), jnp.bfloat16), sds((d, e), jnp.float32),
+        sds((e,), jnp.float32), sds((held, lat, f), jnp.float32),
+        sds((held, f, lat), jnp.float32), sds((d, lat), jnp.float32),
+        sds((lat, d), jnp.float32),
+    )
+    products = [line for line in text.splitlines()
+                if "tpu_custom_call" in line and "ragged-dot" in line]
+    assert products and all(
+        re.match(r"\s*(ROOT )?%ragged-dot-(fwd|dlhs|drhs)\b", p)
+        for p in products)
+    assert {re.match(r"\s*(ROOT )?%ragged-dot-(\w+)", p).group(2)
+            for p in products} == {"fwd", "dlhs", "drhs"}
+    # the bound's rows at the latent's and the experts' widths, or a
+    # held weight's gradient: never a row of the model's width
+    assert all(re.search(
+        rf"= (bf16\[{rows},({lat}|{f})\]|\w+\[{held},({lat},{f}|{f},{lat})\])",
+        p) for p in products), products
+    assert re.search(rf"= bf16\[{rows},{lat}\]\S* gather\(", text)
+    assert not re.search(rf"\[{rows},{d}\]|\[{k * n},{d}\]", text)
+    assert "held-rows-sum" not in text
+    latent = [line for line in text.splitlines()
+              if "moe_latent" in line and re.search(r" (dot|convolution)\(",
+                                                     line)]
+    assert latent and not any(
+        scope in line for line in latent
+        for scope in ("moe_experts", "moe_shared", "moe_dispatch"))
+
+
 def test_held_layer_too_large_to_gather_from_sums_in_the_kernel(
     chip, monkeypatch
 ):

@@ -172,19 +172,21 @@ def test_defaults_leave_an_older_decoder_as_it_was():
     assert "lm_head" in plain.param_specs()
 
 
+MAMBA_REFUSAL = "a mamba layer or block (layer_types) does not yet compose"
 REFUSED = [
-    (dict(tp=2), "a mamba layer (layer_types) does not yet compose with"),
-    (dict(n_experts=4, moe_top_k=2),
-     "a mamba layer (layer_types) does not yet compose with"),
-    (dict(n_experts=4, moe_top_k=2, ep=2, capacity_factor=1.25),
-     "a mamba layer (layer_types) does not yet compose with"),
+    (dict(tp=2), MAMBA_REFUSAL),
     (dict(attention="mla", q_lora_rank=8, kv_lora_rank=8,
           qk_nope_head_dim=4, qk_rope_head_dim=4, v_head_dim=8),
-     "a mamba layer (layer_types) does not yet compose with"),
+     MAMBA_REFUSAL),
     (dict(layer_types=["mamba", "sliding_attention"] * 5, sliding_window=4),
-     "a mamba layer (layer_types) does not yet compose with"),
-    (dict(mtp_depth=1),
-     "a mamba layer (layer_types) does not yet compose with"),
+     MAMBA_REFUSAL),
+    (dict(mtp_depth=1), MAMBA_REFUSAL),
+    # the same stack as BLOCKS of one sublayer (``layer_types`` a
+    # pattern: a mixer or an attention alone) refuses what blocks do
+    # not run
+    (dict(layer_types="M*" * 5, tp=2), MAMBA_REFUSAL),
+    (dict(layer_types="M*" * 5, mtp_depth=1),
+     "a block pattern (layer_types as a string"),
     (dict(sp=2), "does not yet compose with pipeline parallelism, sequence"),
     (dict(pp=2), "does not yet compose with pipeline parallelism, sequence"),
     (dict(ut_steps=2),
@@ -193,7 +195,8 @@ REFUSED = [
 
 
 @pytest.mark.parametrize("over, sentence", REFUSED, ids=[
-    "tp", "experts", "ep", "mla", "window", "mtp", "sp", "pp", "ut_steps"])
+    "tp", "mla", "window", "mtp", "blocks_tp", "blocks_mtp", "sp", "pp",
+    "ut_steps"])
 def test_a_mamba_stack_refuses_what_it_has_not_been_run_with(over, sentence):
     with pytest.raises(NotImplementedError) as e:
         Llama(_small(**over))

@@ -529,7 +529,7 @@ def test_serving_is_refused(knob):
             model.make_decoder(paged=paged)
     doc = (ROOT / "docs/REFUSALS.md").read_text()
     assert "serving has one head count, no gate and whole-head rotation" in doc
-    assert "## Declared refusals (21)" in doc
+    assert "## Declared refusals (25)" in doc
 
 
 # -- what the remat keeps ----------------------------------------------------------

@@ -168,6 +168,11 @@ PROFILE_SCOPES: dict[str, str] = {
     # ``gqa_proj`` (models/llama.py ``_attn_gate``, PR 50);
     # benchmark/layer_metrics/attn_gate_ms.py reads the label
     "attn_gate": "attn_gate",
+    # the two projections around routed experts that live in a latent,
+    # inside ``blk_ffn``, outside the routed path's four scopes and
+    # ``moe_shared`` (parallel/moe.py ``moe_ffn`` with ``latent``,
+    # PR 55); benchmark/layer_metrics/moe_latent_ms.py reads the label
+    "moe_latent": "moe_latent",
     # a state-space (mamba) layer's mixer under its block and the four
     # scopes inside it (models/llama.py ``_mamba_block``, ops/ssd.py
     # ``mamba_mixer``, PR 47); benchmark/layer_metrics/ssm_block_ms.py,

@@ -45,7 +45,8 @@ input and the flash kernel's two outputs and, for as many of the last
 layer calls as the device's memory holds, the dense MLP's gate and up
 products, then grouped-query attention's q, k, v and the attention
 block's output, then a dropless expert layer's sorted rows with their
-gate and up products (``Llama.remat_keep_calls``).  Params are initialized
+gate and up products — two-product experts' one
+(``Llama.remat_keep_calls``).  Params are initialized
 *under jit with sharded out_shardings*, so the full 8B-scale parameter
 set never materializes on one device.
 
@@ -383,7 +384,28 @@ class Llama(TMModel):
     the stack one described layer by layer (``attn_per_layer``): they
     compose with ``tp`` and data parallelism and are refused where
     ``layer_types`` is.  ``validate: false``: the run holds no
-    validation set.
+    validation set.  ``layer_types`` as a STRING (the published
+    ``hybrid_override_pattern``, its first ``n_layers`` characters)
+    describes the stack BLOCK BY BLOCK, each ONE sublayer with its one
+    norm and its one residual add: ``M`` a Mamba-2 mixer, ``*``
+    attention, ``E`` an expert layer (``block_pattern``; two mixers
+    may follow each other; the leaves a block holds say which half it
+    is, ``_layer``); an expert layer may stand in a stack with mamba
+    layers or blocks, the selection bias beside the scans' counters.
+    ``hidden_act: "relu2"``: the routed and the shared experts are
+    ``relu(x W_up) ** 2 W_down``, two products and no gate leaf;
+    ``moe_latent_dim``: the routed experts live in a latent — the
+    router reads the block's normed input at ``dim``, the experts its
+    projection (leaves ``w_lat_down``, ``w_lat_up``; scope
+    ``moe_latent``); ``moe_shared_dim``: the shared expert's width
+    where it is no multiple of ``ffn_dim``.  A HEAD SHARE:
+    ``n_heads_held`` / ``n_kv_heads_held`` beside ``n_heads`` /
+    ``n_kv_heads`` and ``mamba_heads_held`` / ``mamba_groups_held``
+    beside ``mamba_n_heads`` / ``mamba_n_groups`` (the published
+    counts) make the leaves hold one rank's heads, with their whole
+    B/C groups, by itself, as ``moe_experts_held`` does for the
+    experts: the partial output product is the rank's part of the
+    block's result (``head_share``; refused under ``tp > 1``).
     """
 
     def __init__(self, config: dict | None = None):
@@ -399,6 +421,34 @@ class Llama(TMModel):
         # a configuration value where the published one is not dim /
         # n_heads (wq [dim, n_heads * head_dim], wo back to dim)
         self.head_dim = int(c.get("head_dim", self.dim // self.n_heads))
+        # a HEAD SHARE: of the published ``n_heads`` / ``n_kv_heads``
+        # the leaves hold ``n_heads_held`` / ``n_kv_heads_held``, what
+        # one of the ranks that share a layer's heads holds, by itself
+        # (as ``moe_experts_held`` is beside ``n_experts``): the
+        # partial ``wo`` product is that rank's part of the block's
+        # result, and nothing stands in for the other ranks'
+        self.head_share = {}
+        if c.get("n_heads_held") is not None:
+            held = int(c["n_heads_held"])
+            kv_held = int(c.get("n_kv_heads_held", 0))
+            ranks = self.n_heads // max(held, 1)
+            if (held < 1 or held * ranks != self.n_heads
+                    or kv_held != max(1, self.n_kv_heads // ranks)
+                    or max(ranks, self.n_kv_heads)
+                    % min(ranks, self.n_kv_heads)):
+                raise ValueError(
+                    f"n_heads_held {held} / n_kv_heads_held {kv_held}: a "
+                    f"head share is one of R ranks' whole heads, R = "
+                    f"n_heads / n_heads_held dividing n_heads "
+                    f"{self.n_heads}, with max(1, n_kv_heads / R) of the "
+                    f"{self.n_kv_heads} key/value heads (a key/value head "
+                    f"may lie on several ranks, never in parts)"
+                )
+            self.head_share.update(
+                n_heads=(held, self.n_heads),
+                n_kv_heads=(kv_held, self.n_kv_heads),
+            )
+            self.n_heads, self.n_kv_heads = held, kv_held
         # "gqa": wq/wk/wv/wo at one head dim.  "mla": latent attention
         # (``_mla_qkv``): q and k/v each through a low-rank
         # down-projection with its own RMSNorm; a head's q and k are a
@@ -460,11 +510,32 @@ class Llama(TMModel):
         # shared experts: a dense SwiGLU of that many expert widths
         # that every token goes through, beside the routed ones
         self.moe_shared_experts = int(c.get("moe_shared_experts", 0))
+        # the FFNs' form: "silu" is the SwiGLU (gate, up and down
+        # products); "relu2" (the published ``mlp_hidden_act``) is
+        # ``relu(x W_up) ** 2 W_down``, TWO products and no gate leaf,
+        # for the routed experts and the shared one
+        self.hidden_act = str(c.get("hidden_act", "silu"))
+        if self.hidden_act not in ("silu", "relu2"):
+            raise ValueError(
+                f"hidden_act {self.hidden_act!r}: 'silu' (SwiGLU) or "
+                f"'relu2' (relu(.)^2, two products)"
+            )
+        # the shared expert's width where it is not a whole number of
+        # expert widths' worth (``moe_shared_experts * ffn_dim``)
+        self.moe_shared_dim = int(c.get(
+            "moe_shared_dim", self.moe_shared_experts * self.ffn_dim))
+        # experts in a LATENT: the router reads the block's normed
+        # input at ``dim``, the routed experts its projection to
+        # ``moe_latent_dim`` (their leaves are that wide) and their
+        # sum goes back up to ``dim``; the shared expert stays at
+        # ``dim`` (``moe.moe_ffn``'s ``latent``)
+        latent = c.get("moe_latent_dim")
+        self.moe_latent_dim = None if latent is None else int(latent)
         # the first layers of an expert model that are dense SwiGLUs
         # of ``dense_ffn_dim`` (an expert's width is ``ffn_dim``)
         self.first_k_dense = int(c.get("first_k_dense", 0))
         self.dense_ffn_dim = int(c.get("dense_ffn_dim", self.ffn_dim))
-        # the kind of every layer, "moe" or "dense"
+        # the FFN kind of every layer, "moe" or "dense"
         self.layer_kinds = tuple(
             "moe" if self.n_experts and i >= self.first_k_dense else "dense"
             for i in range(self.n_layers)
@@ -477,6 +548,14 @@ class Llama(TMModel):
         # kind rotates by its own entry of ``rope_parameters`` where
         # that is given (``rope_table``), else by ``rope_theta``
         types = c.get("layer_types")
+        # a STRING (the published ``hybrid_override_pattern``, its
+        # first ``n_layers`` characters) describes the stack BLOCK BY
+        # BLOCK, each ONE sublayer with its one norm and its one
+        # residual add — ``M`` a Mamba-2 mixer, ``*`` attention, ``E``
+        # an expert layer — so a "layer" of the tuples below is a
+        # block, and the half it does not have is ``"none"``
+        self.block_pattern = (
+            types[:self.n_layers] if isinstance(types, str) else None)
         # ``"attention"`` (the hybrid decoders' name for a full layer)
         # is ``"full_attention"``; ``"mamba"`` is no attention at all
         # but a state-space MIXER (``mixer_kinds``, ``ops/ssd.py``)
@@ -487,9 +566,42 @@ class Llama(TMModel):
                 for t in types[:self.n_layers]
             )
         )
-        # the mixer of every layer, "attention" or "mamba"
+        if self.block_pattern is not None:
+            unknown = set(self.block_pattern) - set("M*E-")
+            if (unknown or self.first_k_dense
+                    or len(self.block_pattern) != self.n_layers
+                    or ("E" in self.block_pattern) != bool(self.n_experts)):
+                raise ValueError(
+                    f"layer_types as a string names each of the "
+                    f"{self.n_layers} BLOCKS 'M' (a mamba mixer), '*' "
+                    f"(attention), 'E' (an expert layer: n_experts > 0, "
+                    f"and only then) or '-' (a dense FFN), and stands in "
+                    f"first_k_dense's place too; got "
+                    f"{self.block_pattern!r} (unknown {sorted(unknown)}), "
+                    f"n_experts {self.n_experts}, first_k_dense "
+                    f"{self.first_k_dense}"
+                )
+            if "-" in self.block_pattern or c.get("mtp_depth"):
+                raise NotImplementedError(
+                    "a block pattern (layer_types as a string: blocks of "
+                    "ONE sublayer) does not yet run a dense FFN block "
+                    "('-': the blocks run are 'M', '*' and 'E') nor "
+                    "compose with a multi-token-prediction module, whose "
+                    "block is a mixer AND an FFN (pattern "
+                    f"{self.block_pattern!r}, mtp_depth "
+                    f"{c.get('mtp_depth')})"
+                )
+            self.attn_kinds = tuple(
+                {"M": "mamba", "*": "full_attention", "E": "none"}[b]
+                for b in self.block_pattern
+            )
+            self.layer_kinds = tuple(
+                "moe" if b == "E" else "none" for b in self.block_pattern)
+        # the mixer of every layer, "attention" or "mamba" ("none": a
+        # block that is an FFN alone)
         self.mixer_kinds = tuple(
-            "mamba" if k == "mamba" else "attention" for k in self.attn_kinds
+            {"mamba": "mamba", "none": "none"}.get(k, "attention")
+            for k in self.attn_kinds
         )
         self.has_mamba = "mamba" in self.mixer_kinds
         window = c.get("sliding_window")
@@ -520,8 +632,11 @@ class Llama(TMModel):
             types is None and window is None and self.rope_parameters is None
             and per_layer is None and not self.attention_gate
         )
-        unknown = set(self.attn_kinds) - {"full_attention",
-                                          "sliding_attention", "mamba"}
+        unknown = set(self.attn_kinds) - {
+            "full_attention", "sliding_attention", "mamba",
+            # (an expert BLOCK's place in a pattern, never a list's)
+            *(("none",) if self.block_pattern is not None else ()),
+        }
         if unknown or len(self.attn_kinds) != self.n_layers:
             raise ValueError(
                 f"layer_types names each of the {self.n_layers} layers "
@@ -549,8 +664,34 @@ class Llama(TMModel):
             expand = int(c.get("mamba_expand", 2))
             assert inner == expand * self.dim, (
                 f"mamba_n_heads x mamba_d_head = {inner} is not "
-                f"mamba_expand {expand} x dim {self.dim}"
+                f"mamba_expand {expand} x dim {self.dim}: the PUBLISHED "
+                f"counts tie the scan's channels to the width; a head "
+                f"share (mamba_heads_held, mamba_groups_held) may hold "
+                f"fewer heads and groups of the same sizes, nothing else"
             )
+            if c.get("mamba_heads_held") is not None:
+                # the mixer's head share: one rank's state heads and
+                # its WHOLE B/C groups, so the gated norm's groups
+                # (``inner / n_groups`` channels) lie on a rank each
+                # and a share needs no statistic of another's
+                held = int(c["mamba_heads_held"])
+                groups_held = int(c.get("mamba_groups_held", 0))
+                heads, groups = (
+                    self._mamba["n_heads"], self._mamba["n_groups"])
+                if (held < 1 or heads % held or groups_held < 1
+                        or groups_held * heads != groups * held):
+                    raise ValueError(
+                        f"mamba_heads_held {held} / mamba_groups_held "
+                        f"{groups_held}: a mixer's head share is one of R "
+                        f"ranks' heads with their whole B/C groups, R "
+                        f"dividing mamba_n_heads {heads} and "
+                        f"mamba_n_groups {groups} alike"
+                    )
+                self.head_share.update(
+                    mamba_n_heads=(held, heads),
+                    mamba_n_groups=(groups_held, groups),
+                )
+                self._mamba.update(n_heads=held, n_groups=groups_held)
         # the hybrid decoders' scalars: ``embedding_multiplier`` on
         # the looked-up rows, ``residual_multiplier`` on each branch
         # before its residual add, ``attention_multiplier`` in place of
@@ -575,7 +716,7 @@ class Llama(TMModel):
                 (None, 1.0) if self.rope_parameters is None
                 else rope_table(self.rope_parameters[kind], self.head_dim)
             )
-            for kind in set(self.attn_kinds) - {"mamba"}
+            for kind in set(self.attn_kinds) - {"mamba", "none"}
         }
         # the run summary's ``"rotary_channels"``: how many of a head's
         # channels a kind rotates (its entry's ``partial_rotary_factor``)
@@ -687,9 +828,9 @@ class Llama(TMModel):
         else:
             assert self.ep == 1, "ep > 1 requires n_experts > 0"
             assert not (self.first_k_dense or self.moe_shared_experts
-                        or self.moe_experts_held), (
-                "first_k_dense, moe_shared_experts and moe_experts_held "
-                "need n_experts > 0"
+                        or self.moe_experts_held or self.moe_latent_dim), (
+                "first_k_dense, moe_shared_experts, moe_experts_held and "
+                "moe_latent_dim need n_experts > 0"
             )
         assert self.ut_steps >= 1, self.ut_steps
         mixed = [
@@ -712,21 +853,41 @@ class Llama(TMModel):
             ) if on
         ]
         if self.has_mamba and (
-            self.tp > 1 or self.n_experts or self.attention == "mla"
+            self.tp > 1 or self.attention == "mla"
             or "sliding_attention" in self.attn_kinds or self.mtp_depth
         ):
             raise NotImplementedError(
-                "a mamba layer (layer_types) does not yet compose with "
-                "tensor parallelism, expert layers (so none with expert "
-                "parallelism), latent attention, sliding-window layers or "
-                f"a multi-token-prediction module (tp {self.tp}, "
-                f"n_experts {self.n_experts}, ep {self.ep}, attention "
+                "a mamba layer or block (layer_types) does not yet "
+                "compose with tensor parallelism, latent "
+                "attention, sliding-window layers or a "
+                f"multi-token-prediction module (tp {self.tp}, attention "
                 f"{self.attention}, kinds {sorted(set(self.attn_kinds))}, "
                 f"mtp_depth {self.mtp_depth}): the scan's heads and its "
-                "state are not sharded over the model axis, and the "
-                "hybrid stack has been run with dense SwiGLUs and "
-                "grouped-query full layers alone; use tp=1, n_experts=0, "
-                "attention gqa, no sliding_attention layer and mtp_depth 0"
+                "state are not sharded over the model axis (a head share, "
+                "mamba_heads_held, runs by itself), and the hybrid stack "
+                "has been run with grouped-query full attention alone; "
+                "use tp=1, attention gqa, no sliding_attention layer and "
+                "mtp_depth 0"
+            )
+        if self.hidden_act == "relu2" and "dense" in self.layer_kinds:
+            raise NotImplementedError(
+                "hidden_act: relu2 (two products, no gate leaf) is the "
+                "routed and the shared experts': a dense FFN (n_experts "
+                f"{self.n_experts}, first_k_dense {self.first_k_dense}) "
+                "keeps its SwiGLU, whose remat names a gate and an up "
+                "product"
+            )
+        if self.head_share and (
+            self.tp > 1 or self.attention == "mla" or per_layer is not None
+        ):
+            raise NotImplementedError(
+                "a head share (n_heads_held, mamba_heads_held: one "
+                "rank's heads by itself) does not yet compose with tensor "
+                "parallelism, latent attention or n_heads_per_layer (tp "
+                f"{self.tp}, attention {self.attention}): the share "
+                "stands in the model axis' place, and the held counts "
+                "are one pair for the stack; use tp=1, attention gqa and "
+                "one n_heads"
             )
         if self.position_embedding_type == "nope" and self.attention == "mla":
             raise NotImplementedError(
@@ -835,8 +996,13 @@ class Llama(TMModel):
 
     def _layer_specs(self, kind: str, mixer: str = "attention") -> dict:
         """PartitionSpec per leaf of one layer of FFN ``kind`` and
-        ``mixer`` kind."""
-        layer = {"attn_norm": P(None), "mlp_norm": P(None)}
+        ``mixer`` kind; ``"none"`` (a block of a ``layer_types``
+        pattern): the half the block does not have, with its norm."""
+        layer = {
+            norm: P(None) for norm, half in (
+                ("attn_norm", mixer), ("mlp_norm", kind))
+            if half != "none"
+        }
         if mixer == "mamba":
             # refused under tp > 1: every leaf whole on every device
             layer.update({
@@ -845,7 +1011,7 @@ class Llama(TMModel):
                 "ssm_a_log": P(None), "ssm_d": P(None),
                 "ssm_norm": P(None), "ssm_out": P(None, None),
             })
-        elif self.attention == "mla":
+        elif mixer == "attention" and self.attention == "mla":
             # heads over the model axis (the up-projections' columns,
             # the output projection's rows); the two down-projections
             # and their norms act on the full width: replicated
@@ -856,22 +1022,26 @@ class Llama(TMModel):
                 "wkv_b": P(None, MODEL_AXIS),
                 "wo": P(MODEL_AXIS, None),
             })
-        else:
+        elif mixer == "attention":
             layer.update({
                 "wq": P(None, MODEL_AXIS),
                 "wk": P(None, MODEL_AXIS),
                 "wv": P(None, MODEL_AXIS),
                 "wo": P(MODEL_AXIS, None),
             })
-        if self.attention_gate and mixer != "mamba":
+        if self.attention_gate and mixer == "attention":
             # a column a query head: sharded as wq's heads
             layer["w_attn_gate"] = P(None, MODEL_AXIS)
-        if self.qk_norm and mixer != "mamba":
+        if self.qk_norm and mixer == "attention":
             # over the whole projected width: sharded as its columns
             layer.update({"q_norm": P(MODEL_AXIS), "k_norm": P(MODEL_AXIS)})
         if self.sandwich_norm:
             # over the full width of each branch's (psum'd) output
-            layer.update({"attn_out_norm": P(None), "mlp_out_norm": P(None)})
+            layer.update({
+                name: P(None) for name, half in (
+                    ("attn_out_norm", mixer), ("mlp_out_norm", kind))
+                if half != "none"
+            })
         if kind == "moe":
             # experts sharded over the expert axis, FFN dim over model
             layer.update({
@@ -880,13 +1050,21 @@ class Llama(TMModel):
                 "we_up": P(EXPERT_AXIS, None, MODEL_AXIS),
                 "we_down": P(EXPERT_AXIS, MODEL_AXIS, None),
             })
+            if self.moe_latent_dim:
+                # the two latent projections act on the full width
+                layer.update({
+                    "w_lat_down": P(None, None), "w_lat_up": P(None, None),
+                })
             if self.moe_shared_experts:
                 layer.update({
                     "ws_gate": P(None, MODEL_AXIS),
                     "ws_up": P(None, MODEL_AXIS),
                     "ws_down": P(MODEL_AXIS, None),
                 })
-        else:
+            if self.hidden_act == "relu2":  # two products: no gate leaf
+                layer.pop("we_gate")
+                layer.pop("ws_gate", None)
+        elif kind == "dense":
             layer.update({
                 "w_gate": P(None, MODEL_AXIS),
                 "w_up": P(None, MODEL_AXIS),
@@ -950,49 +1128,67 @@ class Llama(TMModel):
 
         def one_layer(kind, keys, mixer="attention", i=0):
             h = self.heads_per_layer[i]     # (the MTP block: the last's)
+            # (a block of a ``layer_types`` pattern holds ONE half and
+            # its norm)
             lp = {
-                "attn_norm": jnp.ones((d,)),
-                **(mamba(i) if mixer == "mamba" else attention(h, keys)),
-                "mlp_norm": jnp.ones((d,)),
+                **({} if mixer == "none" else {"attn_norm": jnp.ones((d,))}),
+                **({} if mixer == "none" else
+                   mamba(i) if mixer == "mamba" else attention(h, keys)),
+                **({} if kind == "none" else {"mlp_norm": jnp.ones((d,))}),
             }
             if self.attention == "mla" or mixer == "mamba":
                 for _ in range(4):
                     next(keys)  # keep key budget aligned (9 per layer)
-            if self.qk_norm and mixer != "mamba":
+            if self.qk_norm and mixer == "attention":
                 lp["q_norm"] = jnp.ones((h * hd,))
                 lp["k_norm"] = jnp.ones((self.n_kv_heads * hd,))
             if self.sandwich_norm:
-                lp["attn_out_norm"] = jnp.ones((d,))
-                lp["mlp_out_norm"] = jnp.ones((d,))
+                if mixer != "none":
+                    lp["attn_out_norm"] = jnp.ones((d,))
+                if kind != "none":
+                    lp["mlp_out_norm"] = jnp.ones((d,))
             if kind == "moe":
                 e = self.n_experts
                 # the leaves hold the experts that are here
                 eh = e if self.moe_experts_held is None else (
                     self.moe_experts_held
                 )
+                # what the routed experts read: the width, or the latent
+                de = self.moe_latent_dim or d
                 # per-expert fan-in/out scales (the generic shape-based
                 # scale would key on E instead of D/F for 3-D tensors)
                 lp.update({
                     "router": dense(next(keys), (d, e)),
                     "we_gate": dense(
-                        next(keys), (eh, d, f), (2.0 / (d + f)) ** 0.5
+                        next(keys), (eh, de, f), (2.0 / (de + f)) ** 0.5
                     ),
                     "we_up": dense(
-                        next(keys), (eh, d, f), (2.0 / (d + f)) ** 0.5
+                        next(keys), (eh, de, f), (2.0 / (de + f)) ** 0.5
                     ),
                     "we_down": dense(
-                        next(keys), (eh, f, d), (2.0 / (f + d)) ** 0.5
+                        next(keys), (eh, f, de), (2.0 / (f + de)) ** 0.5
                     ),
                 })
                 next(keys)  # keep key budget aligned (9 per layer)
+                if self.moe_latent_dim:
+                    # (a stream of its own, as the mixers' is)
+                    k_dn, k_up = jax.random.split(
+                        jax.random.fold_in(jax.random.fold_in(key, 4), i))
+                    lp.update({
+                        "w_lat_down": dense(k_dn, (d, de)),
+                        "w_lat_up": dense(k_up, (de, d)),
+                    })
                 if self.moe_shared_experts:
-                    fs = self.moe_shared_experts * f
+                    fs = self.moe_shared_dim
                     lp.update({
                         "ws_gate": dense(next(more), (d, fs)),
                         "ws_up": dense(next(more), (d, fs)),
                         "ws_down": dense(next(more), (fs, d)),
                     })
-            else:
+                if self.hidden_act == "relu2":      # no gate leaf
+                    lp.pop("we_gate")
+                    lp.pop("ws_gate", None)
+            elif kind == "dense":
                 fd = self.dense_ffn_dim
                 lp.update({
                     "w_gate": dense(next(keys), (d, fd)),
@@ -1082,7 +1278,7 @@ class Llama(TMModel):
             return tiles("full_attention")
         # a model described layer by layer: a summary a kind
         return {kind: tiles(kind)
-                for kind in sorted(set(self.attn_kinds) - {"mamba"})}
+                for kind in sorted(set(self.attn_kinds) - {"mamba", "none"})}
 
     def window_of(self, kind: str) -> int | None:
         """The keys a query of a layer of ``kind`` sees, itself
@@ -1094,13 +1290,23 @@ class Llama(TMModel):
         """The run summary's ``"attention_kinds"``: layers of each
         (a mamba layer is no attention: ``mixer_kinds_count``)."""
         return {k: self.attn_kinds.count(k)
-                for k in sorted(set(self.attn_kinds) - {"mamba"})}
+                for k in sorted(set(self.attn_kinds) - {"mamba", "none"})}
 
     @property
     def mixer_kinds_count(self) -> dict:
         """The run summary's ``"mixer_kinds"``: layers of each."""
         return {k: self.mixer_kinds.count(k)
-                for k in sorted(set(self.mixer_kinds))}
+                for k in sorted(set(self.mixer_kinds) - {"none"})}
+
+    @property
+    def block_kinds_count(self) -> dict | None:
+        """The run summary's ``"block_kinds"``: blocks of each
+        character of a ``layer_types`` pattern; None for a stack of whole
+        layers."""
+        if self.block_pattern is None:
+            return None
+        return {b: self.block_pattern.count(b)
+                for b in sorted(set(self.block_pattern))}
 
     @property
     def ssd_chunk(self) -> int | None:
@@ -1207,8 +1413,13 @@ class Llama(TMModel):
             return 0
         picks = self.moe_top_k * self._local_tokens
         rows = held_rows_bound(picks, self.moe_experts_held, self.n_experts)
+        # a row as the experts read it (``moe_latent_dim`` wide under a
+        # latent) and its products before the down projection: gate
+        # and up, or under ``hidden_act: relu2`` the ONE
+        products = 1 if self.hidden_act == "relu2" else 2
         return (
-            rows * (self.dim + 2 * self.ffn_dim // self.tp)
+            rows * ((self.moe_latent_dim or self.dim)
+                    + products * self.ffn_dim // self.tp)
             * self.compute_dtype.itemsize + 2 * picks * 4
         )
 
@@ -1454,10 +1665,13 @@ class Llama(TMModel):
         row ``[E]`` of the selection bias, if the model has one);
         ``attn_kind`` (static): the layer's entry of ``attn_kinds``;
         ``"mamba"`` runs the state-space mixer in attention's place
-        (``_mamba_block``) and returns ``(x, stats)``, the scan's two
-        counters.  A gated attention call (``attention_gate``) gives
-        its gate's counter last (``_attn_gate``): ``(x, open)``, ``(x,
-        mom, open)``.
+        (``_mamba_block``) and gives the scan's two counters last,
+        ``(x, stats)`` or ``(x, mom, stats)``.  A gated attention call
+        (``attention_gate``) gives its gate's counter last
+        (``_attn_gate``): ``(x, open)``, ``(x, mom, open)``.  The
+        halves a layer has are those whose norm ``p`` holds: a block
+        of a pattern is a mixer alone (no ``mlp_norm``) or an
+        expert layer alone (no ``attn_norm``, ``attn_kind`` "none").
 
         With MoE enabled returns ``(x, mom)`` where ``mom`` is the
         fp32 [2E+2] vector of this layer's aux-loss MOMENTS
@@ -1466,14 +1680,67 @@ class Llama(TMModel):
         exactly; ``_aux_from_moments`` forms the losses.  Dense blocks
         return just ``x``."""
         cdtype = self.compute_dtype
-        hd = self.head_dim
         eps = self.norm_eps
         # the block names of the step program (``blk_*``, PERF.md §3):
         # metadata only; ``benchmark/layer_metrics/_blocks.py`` joins
         # them with a trace's device time
+        # what the mixer half gives beside ``x``, last of the results
+        tail = ()
         if attn_kind == "mamba":
             x, stats = self._mamba_block(p, x)
-            return self._dense_ffn(p, x), stats
+            tail = (stats,)
+        elif "attn_norm" in p:
+            x, gate_open = self._attn_block(p, x, pos, attn_kind)
+            if gate_open is not None:
+                tail = (gate_open,)
+        # (a block of a ``layer_types`` pattern is ONE half: a mixer without
+        # ``mlp_norm``, an expert layer without ``attn_norm``)
+        if "mlp_norm" not in p:
+            return (x, *tail) if tail else x
+        if "router" not in p:
+            x = self._dense_ffn(p, x)
+            return (x, *tail) if tail else x
+        with jax.named_scope("blk_ffn"):
+            xn = rms_norm(x, p["mlp_norm"], eps)
+            y, aux = moe_ffn(
+                xn, p["router"], p.get("we_gate"), p["we_up"], p["we_down"],
+                n_experts=self.n_experts,
+                top_k=self.moe_top_k,
+                capacity_factor=self.capacity_factor,
+                expert_axis=EXPERT_AXIS,
+                model_axis=MODEL_AXIS,
+                # aux losses globalize over the token-sharding axes
+                # (layout-invariant; set in compile_iter_fns)
+                batch_axes=(*self._dp_axes, SEQ_AXIS),
+                renormalize=self.moe_renormalize,
+                scoring=self.moe_scoring,
+                select_bias=select_bias,
+                route_scale=self.moe_route_scale,
+                held=self.moe_experts_held,
+                **({"latent": (p["w_lat_down"], p["w_lat_up"])}
+                   if "w_lat_down" in p else {}),
+            )
+            mom = jnp.concatenate(
+                [aux["f"], aux["p"], aux["z"][None],
+                 aux["dropped"][None]]
+            ).astype(jnp.float32)
+            y = y.astype(cdtype)
+            if "ws_up" in p:
+                y = y + shared_expert(
+                    xn, p.get("ws_gate"), p["ws_up"], p["ws_down"],
+                    MODEL_AXIS,
+                ).astype(cdtype)
+            if self.sandwich_norm:
+                y = rms_norm(y, p["mlp_out_norm"], eps)
+            return x + self._branch(y), mom, *tail
+
+    def _attn_block(self, p, x, pos, attn_kind):
+        """A layer's attention on the residual stream ``x``, from
+        ``attn_norm`` to the residual add (``blk_attn``).  Returns
+        ``(x, the gate's counter or None)``."""
+        cdtype = self.compute_dtype
+        hd = self.head_dim
+        eps = self.norm_eps
         gate_open = None
         with jax.named_scope("blk_attn"):
             xn = rms_norm(x, p["attn_norm"], eps)
@@ -1496,42 +1763,7 @@ class Llama(TMModel):
                 # (``_gqa_qkv``): a call that keeps them replays
                 # neither ``wo`` nor the three projections
                 x = checkpoint_name(x, ATTN_RESIDUALS[3])
-
-        if "router" not in p:
-            x = self._dense_ffn(p, x)
-            return x if gate_open is None else (x, gate_open)
-        with jax.named_scope("blk_ffn"):
-            xn = rms_norm(x, p["mlp_norm"], eps)
-            y, aux = moe_ffn(
-                xn, p["router"], p["we_gate"], p["we_up"], p["we_down"],
-                n_experts=self.n_experts,
-                top_k=self.moe_top_k,
-                capacity_factor=self.capacity_factor,
-                expert_axis=EXPERT_AXIS,
-                model_axis=MODEL_AXIS,
-                # aux losses globalize over the token-sharding axes
-                # (layout-invariant; set in compile_iter_fns)
-                batch_axes=(*self._dp_axes, SEQ_AXIS),
-                renormalize=self.moe_renormalize,
-                scoring=self.moe_scoring,
-                select_bias=select_bias,
-                route_scale=self.moe_route_scale,
-                held=self.moe_experts_held,
-            )
-            mom = jnp.concatenate(
-                [aux["f"], aux["p"], aux["z"][None],
-                 aux["dropped"][None]]
-            ).astype(jnp.float32)
-            y = y.astype(cdtype)
-            if "ws_gate" in p:
-                y = y + shared_expert(
-                    xn, p["ws_gate"], p["ws_up"], p["ws_down"],
-                    MODEL_AXIS,
-                ).astype(cdtype)
-            if self.sandwich_norm:
-                y = rms_norm(y, p["mlp_out_norm"], eps)
-            out = x + self._branch(y), mom
-            return out if gate_open is None else (*out, gate_open)
+        return x, gate_open
 
     def _branch(self, y):
         """A block's branch as it enters the residual sum: times
@@ -1725,7 +1957,8 @@ class Llama(TMModel):
         (zeros when the model is dense), and the routing counters
         ``[L, E+1]`` of ``_routing_counters`` (None when dense).
         ``with_ssm=True`` (a stack with mamba layers): the scans'
-        counters ``[L_mamba, 2]`` come back last (``obs/ssm.py``).
+        counters ``[L_mamba, 2]`` come back last, after the expert
+        layers' pair where there is one (``obs/ssm.py``).
         ``with_gate=True`` (a model with an attention gate): what the
         other arguments ask for comes back FIRST of a pair, the gated
         calls' counters ``[calls]`` second (``obs/gate.py``)."""
@@ -1825,14 +2058,19 @@ class Llama(TMModel):
                         + ATTN_RESIDUALS * (call in kept_attn)
                         + MOE_RESIDUALS * (call in kept_moe),
                     )
-                    if "router" in p:
-                        x, mom = take_gate(fn(p, x, pos, next(bias_rows)))
-                        moms.append(mom)
-                    elif kind == "mamba":
-                        x, stats = fn(p, x, pos)
+                    res = (fn(p, x, pos, next(bias_rows)) if "router" in p
+                           else fn(p, x, pos))
+                    if kind == "mamba":     # its scan's counters, last
+                        *res, stats = res
                         ssm.append(stats)
+                        res = res[0] if len(res) == 1 else res
+                    elif kind != "none":
+                        res = take_gate(res)
+                    if "router" in p:
+                        x, mom = res
+                        moms.append(mom)
                     else:
-                        x = take_gate(fn(p, x, pos))
+                        x = res
                 return x, (jnp.stack(moms) if moms else None)
 
             if self.ut_steps == 1:
@@ -1955,10 +2193,11 @@ class Llama(TMModel):
             # a looped decoder gives its R exits [R, B, T, D] (the
             # last of them is ``x``): the loss reads them all
             h = x if exits is None else exits
+            out = (h, aux, routing) if with_aux else (h,)
             if with_ssm:
-                out = h, jnp.stack(ssm)
-            else:
-                out = (h, aux, routing) if with_aux else h
+                out = (*out, jnp.stack(ssm))
+            if len(out) == 1:
+                out, = out
             return (out, jnp.stack(gates)) if with_gate else out
         # logits stay in compute dtype: the xent/metric reductions
         # upcast to fp32 INSIDE their fused reads (tp.py), so an
@@ -2407,11 +2646,12 @@ class Llama(TMModel):
                 if has_gate:    # the gated calls' counters ride out last
                     out, gate = out
                     gate_open = (gate,)
+                if has_ssm:     # the scans' counters come last
+                    *out, ssm_stats = out
+                    out = out[0] if len(out) == 1 else out
                 if self.n_experts:
                     h, aux, routing = out
                     counters = (routing,)
-                elif has_ssm:
-                    h, ssm_stats = out
                 else:
                     h = out
                 # [N, D] rows; a looped decoder's R exits [R, N, D]

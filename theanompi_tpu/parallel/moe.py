@@ -66,6 +66,17 @@ Design:
   larger source than XLA gathers fast from (``_sum_in_kernel``) the
   picks are sorted by (expert, token) and a token's sum is formed by
   the kernel of ``ops/held_rows_sum.py`` over the window's rows.
+- **Two forms of expert**: a SwiGLU (gate, up and down products),
+  or, where the layer is given no gate weights (``we_gate`` ``None``),
+  ``relu2(x W_up) W_down`` — TWO products (``_gate_up`` gives no gate
+  product and every tuple of ``(rows, gate, up)`` below holds ``None``
+  in its place, through the loop's carry and the backward rule
+  alike); ``shared_expert`` likewise.
+- **Experts in a latent** (``moe_ffn``'s ``latent``): the router reads
+  the block's input at full width, the experts its projection ``x
+  w_down`` — ONE product before the dispatch, so the sort gathers rows
+  of the latent's width — and the picks' sum goes back up through
+  ``w_up``; both under the scope ``moe_latent``.
 - **What the layer's remat may keep** (``MOE_RESIDUALS``): the
   dropless path names its gathered sorted rows, their gate and up
   products (under a held range the first window's, which the forward
@@ -76,7 +87,8 @@ Design:
 - **Spans**: ``jax.named_scope``s ``moe_route`` (router, top-k, aux
   moments), ``moe_dispatch`` (plan + row gather / capacity buffers),
   ``moe_experts`` (the products; inside it ``moe_tile_plan``, the
-  kernels' tile plan) and ``moe_combine`` (un-permute, gates) are in
+  kernels' tile plan), ``moe_combine`` (un-permute, gates) and, around
+  a latent's two projections, ``moe_latent`` are in
   the metadata of every instruction of the layer, forward and
   backward (docs/OBSERVABILITY.md).
 
@@ -207,27 +219,37 @@ def router_z_loss(logits, batch_axes=()):
     return lax.pmean(z, batch_axes) if batch_axes else z
 
 
+def relu2(u):
+    """``relu(u) ** 2`` (the published ``mlp_hidden_act: relu2``), in
+    float32."""
+    return jnp.square(jax.nn.relu(u.astype(jnp.float32)))
+
+
 def _gate_up(rows, we_gate, we_up, product):
     """The gate and the up product of rows laid out for ``product(lhs,
-    w)`` (batched over capacity buffers, or grouped over sorted rows)."""
+    w)`` (batched over capacity buffers, or grouped over sorted rows).
+    ``we_gate`` ``None`` (experts of TWO products, ``relu2(rows W_up)
+    W_down``): no gate product, ``(None, u)``."""
     dt = rows.dtype
+    if we_gate is None:
+        return None, product(rows, we_up.astype(dt))
     return product(rows, we_gate.astype(dt)), product(rows, we_up.astype(dt))
 
 
 def _gated_down(g, u, we_down, product, row_scale=None):
-    """``silu(g) * u`` through the down product.  ``row_scale`` (fp32,
-    one per row) multiplies the hidden activations inside their own
-    fusion: the down product is linear, so scaling its input rows
-    scales its output rows."""
-    dt = g.dtype
-    h = jax.nn.silu(g) * u
+    """``silu(g) * u`` — without a gate product ``relu2(u)`` — through
+    the down product.  ``row_scale`` (fp32, one per row) multiplies
+    the hidden activations inside their own fusion: the down product
+    is linear, so scaling its input rows scales its output rows."""
+    dt = u.dtype
+    h = relu2(u) if g is None else jax.nn.silu(g) * u
     if row_scale is not None:
-        h = (h.astype(jnp.float32) * row_scale[:, None]).astype(dt)
-    return product(h, we_down.astype(dt))
+        h = h.astype(jnp.float32) * row_scale[:, None]
+    return product(h.astype(dt), we_down.astype(dt))
 
 
 def _swiglu_experts(rows, we_gate, we_up, we_down, product, row_scale=None):
-    """The three expert products on ``rows``: ``_gate_up`` and
+    """The expert products on ``rows``: ``_gate_up`` and
     ``_gated_down``."""
     return _gated_down(
         *_gate_up(rows, we_gate, we_up, product), we_down, product, row_scale
@@ -236,13 +258,17 @@ def _swiglu_experts(rows, we_gate, we_up, we_down, product, row_scale=None):
 
 def shared_expert(x, w_gate, w_up, w_down, model_axis=MODEL_AXIS):
     """The expert EVERY token goes through, beside the routed ones: a
-    dense SwiGLU with no routing and no gate, under the scope
+    dense SwiGLU — ``w_gate`` ``None``: ``relu2(x W_up) W_down``, two
+    products — with no routing and no gate, under the scope
     ``moe_shared``.  ``w_gate``/``w_up`` ``[D, F_loc]`` column-sharded
     and ``w_down`` ``[F_loc, D]`` row-sharded over ``model_axis``
     (``None``: replicated)."""
     with jax.named_scope("moe_shared"):
         dt = x.dtype
-        h = swiglu(x @ w_gate.astype(dt), x @ w_up.astype(dt))
+        if w_gate is None:
+            h = relu2(x @ w_up.astype(dt)).astype(dt)
+        else:
+            h = swiglu(x @ w_gate.astype(dt), x @ w_up.astype(dt))
         y = h @ w_down.astype(dt)
         return lax.psum(y, model_axis) if model_axis is not None else y
 
@@ -500,12 +526,22 @@ def _sum_in_kernel(rows: int, n: int, d: int, dtype) -> bool:
 # What a call of the dropless layer names for the layer's remat
 # (``jax.ad_checkpoint.checkpoint_name``; ``Llama.remat_keep_calls``
 # says which calls' policy saves them): the gathered sorted rows, their
-# gate and up products — under a held range the FIRST window's — and
-# the sort's two results.  A call that keeps them replays no gather of
+# gate and up products (two-product experts: the one, under the up
+# product's name) — under a held range the FIRST window's — and the
+# sort's two results.  A call that keeps them replays no gather of
 # its rows, neither grouped product and neither sort; ``silu(g) * u``,
 # the masters' casts, the router and the down product's operand stay
 # replayed.  The capacity path names none.
 MOE_RESIDUALS = ("moe_rows", "moe_gate", "moe_up", "moe_order", "moe_inv")
+
+
+def _named(arrays, names):
+    """``arrays`` under ``names`` for the layer's remat; ``None`` (the
+    gate product of two-product experts) stays ``None``."""
+    return tuple(
+        a if a is None else checkpoint_name(a, name)
+        for a, name in zip(arrays, names)
+    )
 
 
 def _sorted_rows(x2, we_gate, we_up, order, inv, product, *, k: int,
@@ -522,8 +558,7 @@ def _sorted_rows(x2, we_gate, we_up, order, inv, product, *, k: int,
     with jax.named_scope("moe_experts"):
         g, u = _gate_up(rows, we_gate, we_up,
                         functools.partial(product, raw=True))
-        return (rows, checkpoint_name(g, MOE_RESIDUALS[1]),
-                checkpoint_name(u, MOE_RESIDUALS[2]))
+        return (rows, *_named((g, u), MOE_RESIDUALS[1:3]))
 
 
 def _sorted_sum(g, u, gates, we_down, order, inv, product, *, k: int,
@@ -533,7 +568,8 @@ def _sorted_sum(g, u, gates, we_down, order, inv, product, *, k: int,
     with jax.named_scope("moe_dispatch"):
         row_gate = _permute(gates.T.reshape(-1), order, inv)
     with jax.named_scope("moe_experts"):
-        out = _gated_down(product.held(g), product.held(u), we_down, product,
+        out = _gated_down(g if g is None else product.held(g),
+                          product.held(u), we_down, product,
                           row_scale=row_gate)
         if model_axis is not None:
             out = lax.psum(out, model_axis)               # close row-parallel
@@ -575,7 +611,7 @@ def _window_picks(w, rest, *, bound: int, groups: int):
 
 def _window_product(w, floats, rest, *, bound: int):
     """The grouped product over window ``w``'s rows."""
-    x2, we_gate, *_ = floats
+    x2, _, we_up, _ = floats
     *_, sizes, plan = rest
     with jax.named_scope("moe_experts"):
         if plan is None:                # off the TPU: lax.ragged_dot
@@ -583,7 +619,7 @@ def _window_product(w, floats, rest, *, bound: int):
                 _window_sizes(sizes, w * bound, bound), None)
         return _GroupedProduct(None, lax.cond(
             w == 0, lambda: plan, lambda: _tile_plan(
-                sizes, bound, *we_gate.shape[1:], x2.dtype, prefix=True,
+                sizes, bound, *we_up.shape[1:], x2.dtype, prefix=True,
                 window=w)))
 
 
@@ -678,10 +714,11 @@ def _windows(k, bound, groups, floats, rest, keep: bool):
     starts as buffers nobody fills, ``lax.empty`` — no pass reads the
     one before — where zeros were 0.9 ms a call of Mellum's step,
     PERF.md, PR 51)."""
-    x2, we_gate, *_ = floats
+    x2, we_gate, we_up, _ = floats
+    f = we_up.shape[2]
     kept = tuple(
-        lax.empty((bound, width), x2.dtype)
-        for width in (x2.shape[1], *(we_gate.shape[2],) * 2)
+        None if width is None else lax.empty((bound, width), x2.dtype)
+        for width in (x2.shape[1], None if we_gate is None else f, f)
     ) if keep else ()
     return _over_windows(_window, jnp.zeros(x2.shape, jnp.float32), kept,
                          floats, rest, k=k, bound=bound, groups=groups,
@@ -703,8 +740,7 @@ def _all_windows(k, bound, groups, floats, rest):
 
 def _held_windows_fwd(k, bound, groups, floats, rest):
     y, kept = _windows(k, bound, groups, floats, rest, keep=True)
-    return y, (floats, rest,
-               tuple(map(checkpoint_name, kept, MOE_RESIDUALS[:3])))
+    return y, (floats, rest, _named(kept, MOE_RESIDUALS[:3]))
 
 
 def _held_windows_bwd(k, bound, groups, res, ct):
@@ -772,7 +808,7 @@ def _dropless_experts(x2, gates, eidx, we_gate, we_up, we_down, *,
             flat_e[:, None] == jnp.arange(n_experts, dtype=jnp.int32)[None],
             axis=0, dtype=jnp.int32,
         )
-    shape = (*we_gate.shape[1:], x2.dtype)
+    shape = (*we_up.shape[1:], x2.dtype)
     if bound == k * n:
         return _sorted_experts(
             x2, gates, we_gate, we_up, we_down, order, inv,
@@ -794,7 +830,8 @@ def _dropless_experts(x2, gates, eidx, we_gate, we_up, we_down, *,
     # leaves are cast out here too: their gradients then come back as
     # the kernels write them, in the compute dtype
     floats, gates = gmm.same_vma(
-        (x2, *(w.astype(x2.dtype) for w in (we_gate, we_up, we_down))),
+        (x2, *(w if w is None else w.astype(x2.dtype)
+               for w in (we_gate, we_up, we_down))),
         lax.stop_gradient(gates),
     )
     y = _held_windows(
@@ -895,8 +932,11 @@ def moe_ffn(
     select_bias=None,
     route_scale: float = 1.0,
     held: int | None = None,
+    latent=None,
 ):
-    """MoE SwiGLU FFN on local token shards (call inside shard_map).
+    """The mixture-of-experts FFN on local token shards (call inside
+    shard_map): routed experts that are SwiGLUs (three products), or,
+    with ``we_gate`` ``None``, ``relu2(x W_up) W_down`` (two).
 
     - ``x``: [B, T_loc, D] activations (any float dtype; expert
       matmuls run in ``x.dtype``, routing/combine in fp32).
@@ -926,6 +966,12 @@ def moe_ffn(
       ``k·N``; a routing that sends the held experts more rows than
       that is computed all the same, ``R`` rows at a time
       (``_dropless_experts``), and ``aux["dropped"]`` stays 0.
+    - ``latent``: ``(w_down [D, D_lat], w_up [D_lat, D])``, replicated:
+      the ROUTER reads ``x`` at full width and the experts its
+      projection ``x w_down`` (the expert leaves are ``D_lat`` wide):
+      one product before the dispatch, so the gathered rows are
+      ``D_lat`` wide, and one after the picks' sum, ``y = (sum of the
+      picks) w_up``; both under the scope ``moe_latent``.
 
     Returns ``(y [B, T_loc, D], aux)`` with ``aux = {"lb": load
     balance loss, "z": router z-loss, "f": [E] pick fractions, "p":
@@ -946,8 +992,8 @@ def moe_ffn(
     ep = lax.axis_size(expert_axis) if expert_axis is not None else 1
     assert e % ep == 0, f"n_experts {e} must divide by ep {ep}"
     if held is None:
-        assert we_gate.shape[0] == e // ep, (
-            f"expert leaf holds {we_gate.shape[0]} experts, expected "
+        assert we_up.shape[0] == e // ep, (
+            f"expert leaf holds {we_up.shape[0]} experts, expected "
             f"{e}/{ep} = {e // ep}"
         )
     else:
@@ -955,7 +1001,7 @@ def moe_ffn(
             "a held range of the experts runs dropless and by itself "
             "(capacity_factor=None, no expert axis)"
         )
-        assert we_gate.shape[0] == held <= e, (we_gate.shape, held, e)
+        assert we_up.shape[0] == held <= e, (we_up.shape, held, e)
 
     with jax.named_scope("moe_route"):
         gates, eidx, probs, logits = router_topk(
@@ -977,6 +1023,10 @@ def moe_ffn(
             "z": router_z_loss(logits, batch_axes),
         }
 
+    if latent is not None:
+        with jax.named_scope("moe_latent"):
+            x2 = x2 @ latent[0].astype(x2.dtype)
+
     if capacity_factor is None:
         assert ep == 1, (
             "dropless MoE (capacity_factor=None) runs with ep == 1 only: "
@@ -996,4 +1046,8 @@ def moe_ffn(
         if batch_axes:
             dropped = lax.psum(dropped, batch_axes)
     aux["dropped"] = dropped
-    return y.astype(x.dtype).reshape(b, t, d), aux
+    y = y.astype(x.dtype)
+    if latent is not None:
+        with jax.named_scope("moe_latent"):
+            y = y @ latent[1].astype(x.dtype)
+    return y.reshape(b, t, d), aux

@@ -142,6 +142,20 @@ class LlamaDecoder:
                 "build_model() + compile_iter_fns() (then load() for "
                 "checkpoint weights) before serving"
             )
+        if (model.block_pattern is not None or model.head_share
+                or model.hidden_act != "silu" or model.moe_latent_dim):
+            raise NotImplementedError(
+                "serving runs whole layers at whole head counts: blocks "
+                f"of one sublayer (layer_types={model.block_pattern!r}) "
+                "need a cache a block KIND, none for an expert block, a "
+                "window and a state for a mixer; a head share "
+                f"(head_share={model.head_share}) gives one rank's "
+                "partial result, which no decoder here sums; two-product "
+                f"experts (hidden_act={model.hidden_act!r}) and experts "
+                f"in a latent (moe_latent_dim={model.moe_latent_dim}) are "
+                "the expert layer's, which serving does not run — not "
+                "yet servable"
+            )
         if (model.attention == "mla" or model.moe_shared_experts
                 or model.mtp_depth):
             raise NotImplementedError(

@@ -642,6 +642,13 @@ def run(
         # layers of each mixer kind ("attention", "mamba") and the
         # state-space scan's chunk (None without a mamba layer)
         "mixer_kinds": getattr(model, "mixer_kinds_count", None),
+        # blocks of each kind of a stack described block by block
+        # (``layer_types`` a string: "M" / "*" / "E"; None for whole layers),
+        # and the heads and groups a head share HOLDS beside the
+        # published counts, ``{count: [held, published]}`` ({}: all)
+        "block_kinds": getattr(model, "block_kinds_count", None),
+        "head_share": {k: list(v) for k, v in
+                       getattr(model, "head_share", {}).items()},
         "ssd_chunk": getattr(model, "ssd_chunk", None),
         # query heads a layer, whether a sigmoid gate a head multiplies
         # the attention kernels' output, and how many of a head's
