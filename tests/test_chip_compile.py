@@ -1020,6 +1020,79 @@ def test_held_share_step_compiles_with_its_kernels_and_scopes(
     assert sum("jvp(mtp)" in ln for ln in flash) == 2
 
 
+def _route_phases(text, primitive, opcode=None):
+    """``{phase: n}`` of the instructions of a compiled step (those of
+    ``opcode``, if given) whose ``op_name`` lies under ``moe_route``
+    and names ``primitive`` after it."""
+    import re
+    from collections import Counter
+
+    return Counter(
+        "replay" if "rematted_computation" in ln
+        else "bwd" if "transpose(" in ln else "fwd"
+        for ln in text.splitlines()
+        if (opcode is None or f" {opcode}(" in ln) and re.search(
+            rf'op_name="[^"]*moe_route/[^"]*\b{primitive}\b', ln))
+
+
+@pytest.mark.parametrize("n_keep_moe", [0, 1])
+def test_sigmoid_router_step_gathers_no_picked_score(
+    chip, monkeypatch, n_keep_moe
+):
+    """The held-share step's two expert calls (a stack layer's and the
+    MTP module's) under a sigmoid router with a selection bias: the
+    picked scores leave the pass that picks them (PERF.md, PR 56), so
+    no ``gather`` stands under ``moe_route`` in the forward pass, the
+    replay or the backward pass, and its sorts there are ONE a router
+    call, forward and replay (a call that keeps ``MOE_RESIDUALS`` still
+    replays its router), none in the backward pass: a held share's
+    gates carry no gradient.  The plain form in the program's place
+    shows what the assertion looks for."""
+    from theanompi_tpu.parallel import moe
+
+    text = _llama_step_text(chip, monkeypatch, n_keep_moe=n_keep_moe,
+                            **_MLA_MOE)
+    assert not _route_phases(text, "gather")
+    assert _route_phases(text, "sort", "sort") == dict(fwd=2, replay=2)
+    if n_keep_moe:
+        return
+
+    def plain(scores, chosen, top_k):
+        _, eidx = jax.lax.top_k(chosen, top_k)
+        return jnp.take_along_axis(scores, eidx, axis=-1), eidx
+
+    monkeypatch.setattr(moe, "_picked_scores", plain)
+    text = _llama_step_text(chip, monkeypatch, **_MLA_MOE)
+    assert set(_route_phases(text, "gather")) == {"fwd", "replay"}
+
+
+@pytest.mark.parametrize("n, e, k", [(16384, 512, 22), (16384, 64, 4)],
+                         ids=["nemotron", "glm"])
+def test_sigmoid_router_compiles_to_one_sort_at_the_cells_shapes(chip, n, e, k):
+    """``router_topk``'s sigmoid branch at the two cells' shapes, for
+    the v5e: ONE sort of three operands along the experts (the key,
+    the scores, the indices) and two ``[N, k]`` slices of its results;
+    no gather, no packing of (row, column) pairs for one, and no
+    ``[N, k, E]`` array."""
+    import re
+
+    from theanompi_tpu.parallel.moe import router_topk
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=chip)
+
+    text = _compiled_text(
+        lambda x2, w, b: router_topk(x2, w, k, True, scoring="sigmoid",
+                                     select_bias=b, scale=2.5)[:2],
+        sds((n, 128)), sds((128, e)), sds((e,)))
+    sorts = [ln for ln in text.splitlines() if " sort(" in ln]
+    assert len(sorts) == 1
+    assert re.search(rf"= \(s32\[{n},{e}\]\S*, f32\[{n},{e}\]\S*, "
+                     rf"s32\[{n},{e}\]\S*\) sort\(", sorts[0]), sorts[0]
+    assert " gather(" not in text and "GatherScatterIndices" not in text
+    assert f"[{n},{k},{e}]" not in text
+
+
 def test_latent_attention_hands_the_kernels_products_not_joins(
     chip, monkeypatch
 ):

@@ -10,7 +10,11 @@ Design:
   deterministic top-k selection; the selected gates are renormalized
   to sum to one (the Mixtral convention) so an all-identical-experts
   MoE reproduces its dense FFN exactly — the anchor the unit tests
-  assert.
+  assert.  A SIGMOID router picks by ``score + bias`` and gates by the
+  scores alone, so ``top_k``'s own values are no use to it: its picked
+  scores ride the ONE sort that picks them, as a second result behind
+  the key (``_picked_scores``), and are never gathered element by
+  element afterwards (PERF.md, PR 56).
 - **Dispatch** is capacity-based and *slot-major*: every token's
   1st-choice slot is ranked before any token's 2nd choice, positions
   come from one cumulative sum over a [k·N, E] one-hot, and tokens
@@ -132,6 +136,49 @@ def moe_capacity(
     return max(8, min(c, -(-n_tokens // 8) * 8))
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _picked_scores(scores, chosen, top_k: int):
+    """``(take_along_axis(scores, eidx), eidx)`` for ``eidx`` the
+    ``lax.top_k`` of ``chosen`` (``[N, E]`` float32 both), bit for bit
+    and tie for tie, WITHOUT the element gather: ONE stable sort along
+    the experts carries the scores and their indices behind the key.
+    The key is whole numbers — a float's bits, the negative ones
+    flipped, order as the floats do, ``~`` turns the order round — so
+    equal ``chosen`` are equal keys and the stable sort leaves the
+    lower index first, as ``top_k`` does (``chosen`` is never ``-0``:
+    a sigmoid is not, and ``s + b`` rounds to ``+0`` where it is
+    zero).  A gather of ``k N`` single elements costs 10 ns each on a
+    TPU, 3.68 ms at 22 picks of 512 for 16 384 tokens beside a sort of
+    1.46 (PERF.md, PR 56).  ``chosen`` only picks and gets no
+    gradient."""
+    return _picked_scores_fwd(scores, chosen, top_k)[0]
+
+
+def _picked_scores_fwd(scores, chosen, top_k):
+    bits = lax.bitcast_convert_type(chosen, jnp.int32)
+    key = ~jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    iota = lax.broadcasted_iota(jnp.int32, chosen.shape, chosen.ndim - 1)
+    _, picked, eidx = lax.sort(
+        (key, scores, iota), dimension=-1, num_keys=1, is_stable=True
+    )
+    picked, eidx = picked[..., :top_k], eidx[..., :top_k]
+    return (picked, eidx), (eidx, jnp.arange(chosen.shape[-1], dtype=jnp.int32))
+
+
+def _picked_scores_bwd(top_k, res, cts):
+    """The gather's transpose — the cotangent of pick j of token n
+    added at ``[n, eidx[n, j]]`` — as a compare and a sum over the k
+    picks (a token's picks differ, so one term of each sum is not
+    zero): no scatter, and not ``lax.sort``'s own rule, which
+    gathers."""
+    eidx, experts = res
+    hit = eidx[..., None] == experts
+    return jnp.sum(jnp.where(hit, cts[0][..., None], 0.0), axis=-2), None
+
+
+_picked_scores.defvjp(_picked_scores_fwd, _picked_scores_bwd)
+
+
 def router_topk(x2, w_router, top_k: int, renormalize: bool = True, *,
                 scoring: str = "softmax", select_bias=None,
                 scale: float = 1.0):
@@ -145,7 +192,10 @@ def router_topk(x2, w_router, top_k: int, renormalize: bool = True, *,
     without a gradient: it is state a rule moves, see
     ``select_bias_step``), the gates the picked SCORES, without the
     bias, renormalised (``+ 1e-20``) and times ``scale``; ``probs``
-    are then the scores."""
+    are then the scores.  The picked scores come out of the sort that
+    picks (``_picked_scores``: the scores are an operand of it), bit
+    for bit what ``take_along_axis(scores, eidx)`` gives, ties
+    included."""
     logits = x2.astype(jnp.float32) @ w_router.astype(jnp.float32)
     if scoring == "sigmoid":
         scores = jax.nn.sigmoid(logits)
@@ -154,8 +204,7 @@ def router_topk(x2, w_router, top_k: int, renormalize: bool = True, *,
             chosen = scores + lax.stop_gradient(
                 select_bias.astype(jnp.float32)
             )
-        _, eidx = lax.top_k(chosen, top_k)
-        gates = jnp.take_along_axis(scores, eidx, axis=-1)
+        gates, eidx = _picked_scores(scores, chosen, top_k)
         if renormalize:
             gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
         return gates * scale, eidx, scores, logits
